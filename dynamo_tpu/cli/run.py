@@ -33,8 +33,8 @@ import time
 from typing import Optional
 
 # DYN_TPU_PLATFORM=cpu lets auxiliary processes (frontends, prefill workers on
-# a host without a free chip) run on CPU even when the environment pins a TPU
-# plugin. Must be applied before any model/engine import touches jax.
+# a host without a free chip) run on CPU on a host whose JAX defaults to the
+# TPU. Must be applied before any model/engine import touches jax.
 from dynamo_tpu.runtime.envknobs import env_raw
 
 _platform = env_raw("DYN_TPU_PLATFORM")
@@ -298,7 +298,18 @@ def build_engine(out_spec: str, flags: argparse.Namespace):
                 extra = json.load(f)
         from ..engine_jax.compile_cache import enable_compile_cache
 
-        enable_compile_cache()
+        cache_dir = enable_compile_cache()
+        import jax
+
+        # the process that holds the chip names it: a driver that must stay
+        # off JAX while this server runs reads the device from this line
+        dev = jax.devices()[0]
+        logger.info("device %s", json.dumps({
+            "platform": dev.platform, "kind": dev.device_kind,
+            "count": len(jax.devices()), "compile_cache": cache_dir,
+            # the chip a launcher handed this process (runtime/chips.py)
+            "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+        }))
         core = build_jax_serving_engine(
             card,
             max_batch_size=flags.max_batch_size,
@@ -310,7 +321,8 @@ def build_engine(out_spec: str, flags: argparse.Namespace):
             host_cache_blocks=flags.host_cache_blocks,
             **extra,
         )
-        core.warmup()  # compile the step functions off the request path
+        # compile the step functions off the request path
+        logger.info("warmup %s", json.dumps(core.warmup()))
         if getattr(flags, "wire", "openai") == "token":
             # token wire: the CORE engine serves the endpoint directly
             # (PreprocessedRequest dicts in, LLMEngineOutput dicts out);
@@ -354,13 +366,16 @@ async def build_remote_client(out_spec: str, flags: argparse.Namespace):
     return client, drt
 
 
-async def run_http(chat_engine, completions_engine, model_name: str, flags: argparse.Namespace) -> None:
+async def run_http(chat_engine, completions_engine, model_name: str,
+                   flags: argparse.Namespace, core_engine=None) -> None:
     manager = ModelManager()
     if chat_engine is not None:
         manager.add_chat_model(model_name, chat_engine)
     if completions_engine is not None:
         manager.add_completions_model(model_name, completions_engine)
-    service = HttpService(manager, host=flags.host, port=flags.port)
+    service = HttpService(
+        manager, host=flags.host, port=flags.port, engine=core_engine
+    )
     logger.info("serving model %r on port %d", model_name, flags.port)
     await service.run()
 
@@ -705,7 +720,8 @@ async def amain(argv: list[str]) -> None:
 async def _serve_frontend(in_spec, chat_engine, completions_engine, model_name,
                           flags, core_engine) -> None:
     if in_spec == "http":
-        await run_http(chat_engine, completions_engine, model_name, flags)
+        await run_http(chat_engine, completions_engine, model_name, flags,
+                       core_engine=core_engine)
     elif in_spec == "text":
         await run_text(chat_engine, model_name)
     elif in_spec.startswith("batch:"):

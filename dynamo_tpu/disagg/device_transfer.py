@@ -44,24 +44,29 @@ def device_transfer_supported() -> bool:
     would report a capability that breaks on the first real peer."""
     global _supported
     if _supported is None:
-        try:
-            import jax
+        import jax
 
-            if jax.devices()[0].platform not in ("tpu",):
-                logger.info(
-                    "device-path KV transfer: platform %r lacks cross-process "
-                    "PJRT transfer hooks; using the host-staged path",
-                    jax.devices()[0].platform,
-                )
-                _supported = False
-                return False
-            from jax.experimental import transfer  # noqa: F401
+        platform = jax.devices()[0].platform
+        if platform != "tpu":
+            logger.info(
+                "device-path KV transfer: platform %r lacks cross-process "
+                "PJRT transfer hooks; using the host-staged path", platform,
+            )
+            _supported = False
+            return False
+        try:
+            from jax.experimental import transfer
 
             s = transfer.start_transfer_server(jax.devices()[0].client)
             _probe_roundtrip(s)
             _supported = True
         except Exception as e:
-            logger.info("device-path KV transfer unavailable: %s", str(e)[:200])
+            # the probe failing ON A TPU means KV moves host-staged on
+            # hardware built for the device path — say so, with the cause
+            logger.warning(
+                "device-path KV transfer probe failed on a TPU; using the "
+                "host-staged path: %r", e,
+            )
             _supported = False
     return _supported
 

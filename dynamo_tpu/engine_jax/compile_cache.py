@@ -1,6 +1,6 @@
 """Persistent XLA compilation cache.
 
-Real-chip compiles of the serving step functions run 14-15 s each; the
+Compiling the serving step functions takes seconds each on the chip; the
 persistent cache makes every compile after the first process launch a
 disk load. Mirrors the reference's philosophy of keeping startup cost off
 the request path (its engines load prebuilt CUDA binaries; XLA's unit of
@@ -12,8 +12,6 @@ from __future__ import annotations
 import os
 import sys
 import threading
-
-from dynamo_tpu.runtime.envknobs import env_raw
 
 _DEFAULT = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(__file__))), ".jax_cache")
 
@@ -54,20 +52,22 @@ def compile_counts() -> dict[str, int]:
         return dict(_COMPILES)
 
 
-def enable_compile_cache(path: str | None = None) -> str:
-    """Point JAX's compilation cache at a repo-local directory.
+def enable_compile_cache() -> str:
+    """Switch on JAX's persistent compilation cache; returns its directory.
 
-    Call before the first jit dispatch. DYN_TPU_COMPILE_CACHE overrides the
-    location; setting it to "0" disables the cache entirely.
+    Call before the first jit dispatch. Where ``JAX_COMPILATION_CACHE_DIR``
+    is set, JAX reads it itself and no directory is set in code (the path is
+    part of the cache key, so whoever places the cache from outside must be
+    the only one naming it); where it is not, the cache lives at the fixed
+    ``<checkout>/.jax_cache``. ``JAX_ENABLE_COMPILATION_CACHE=false`` is
+    JAX's own off switch.
     """
-    env = env_raw("DYN_TPU_COMPILE_CACHE")
-    if env == "0":
-        return ""
-    target = path or env or _DEFAULT
-    os.makedirs(target, exist_ok=True)
-
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", target)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    return target
+    target = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if target:
+        return target
+    os.makedirs(_DEFAULT, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", _DEFAULT)
+    return _DEFAULT

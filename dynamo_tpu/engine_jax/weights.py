@@ -67,68 +67,71 @@ def _hf_tensors(model_path: str) -> Optional[Dict[str, np.ndarray]]:
     return out
 
 
-def load_params(card: ModelDeploymentCard, config: LlamaConfig, seed: int = 0):
+def load_params(
+    card: ModelDeploymentCard, config: LlamaConfig, seed: int = 0,
+    shardings=None,
+):
     """Load llama weights (safetensors or GGUF) into the stacked pytree,
-    or random-init when the card has no weight artifacts."""
+    or random-init when the card has no weight artifacts.
+
+    ``shardings`` (a NamedSharding pytree, models/llama.py param_shardings)
+    places every leaf DIRECTLY into its mesh sharding: random init runs
+    under jit with ``out_shardings`` (each device generates only its
+    shards), file weights go host → device leaf by leaf (each device
+    receives only its shards). The whole tree never exists on one device —
+    a model that needs the mesh to fit (qwen2.5-7b bf16 is 15.2 GB against
+    16 GB of HBM) could not load otherwise."""
     if card.gguf_path:
         from dynamo_tpu.llm.gguf import gguf_params, read_gguf
 
-        return gguf_params(read_gguf(card.gguf_path), config)
+        return jax.device_put(
+            gguf_params(read_gguf(card.gguf_path), config), shardings
+        )
     tensors = _hf_tensors(card.model_path) if card.model_path else None
     if tensors is None:
         logger.info("no safetensors found for %s: random-initializing", card.display_name)
-        return init_params(jax.random.PRNGKey(seed), config)
-    return params_from_hf(tensors, config)
+        # always under jit, sharded or not: every process of a deployment
+        # (decode workers, prefill workers, a mesh engine and its one-chip
+        # comparison) then runs the same program and holds the same values
+        init = jax.jit(
+            lambda: init_params(jax.random.PRNGKey(seed), config),
+            out_shardings=shardings,
+        )
+        return init()
+    # host → device leaf by leaf (shardings=None: the default device)
+    return jax.device_put(params_from_hf(tensors, config), shardings)
 
 
-def _mlp_weights(tensors: Dict[str, np.ndarray], c: LlamaConfig) -> Dict[str, Any]:
+def _mlp_weights(c: LlamaConfig, stack, lin) -> Dict[str, Any]:
     """Dense llama/qwen2 MLP or mixtral sparse-MoE expert weights, stacked
-    [L, ...] (and [L, X, ...] over experts). HF mixtral names:
-    block_sparse_moe.gate (router) + experts.M.{w1,w3,w2} = gate/up/down."""
-    dt = c.dtype
-
-    def lin(name: str) -> np.ndarray:
-        return np.ascontiguousarray(tensors[name].T)
-
+    [L, ...] (and [L, X, ...] over experts), on the host; ``stack``/``lin``
+    are params_from_hf's helpers. HF mixtral names: block_sparse_moe.gate
+    (router) + experts.M.{w1,w3,w2} = gate/up/down."""
     if c.num_experts > 1:
-        def experts(fmt: str) -> jnp.ndarray:
-            return jnp.asarray(
-                np.stack([
-                    np.stack([
-                        lin(fmt.format(i, x)) for x in range(c.num_experts)
-                    ])
-                    for i in range(c.num_layers)
-                ]),
-                dt,
-            )
+        def experts(fmt: str) -> np.ndarray:
+            return np.stack([
+                np.stack([lin(fmt.format(i, x)) for x in range(c.num_experts)])
+                for i in range(c.num_layers)
+            ]).astype(c.dtype)
 
         return {
-            "moe_router": jnp.asarray(
-                np.stack([
-                    lin(f"model.layers.{i}.block_sparse_moe.gate.weight")
-                    for i in range(c.num_layers)
-                ]),
-                jnp.float32,
+            "moe_router": stack(
+                "model.layers.{}.block_sparse_moe.gate.weight", lin, np.float32
             ),
             "w_gate": experts("model.layers.{}.block_sparse_moe.experts.{}.w1.weight"),
             "w_up": experts("model.layers.{}.block_sparse_moe.experts.{}.w3.weight"),
             "w_down": experts("model.layers.{}.block_sparse_moe.experts.{}.w2.weight"),
         }
     return {
-        "w_gate": jnp.asarray(
-            np.stack([lin(f"model.layers.{i}.mlp.gate_proj.weight") for i in range(c.num_layers)]), dt
-        ),
-        "w_up": jnp.asarray(
-            np.stack([lin(f"model.layers.{i}.mlp.up_proj.weight") for i in range(c.num_layers)]), dt
-        ),
-        "w_down": jnp.asarray(
-            np.stack([lin(f"model.layers.{i}.mlp.down_proj.weight") for i in range(c.num_layers)]), dt
-        ),
+        "w_gate": stack("model.layers.{}.mlp.gate_proj.weight", lin),
+        "w_up": stack("model.layers.{}.mlp.up_proj.weight", lin),
+        "w_down": stack("model.layers.{}.mlp.down_proj.weight", lin),
     }
 
 
 def params_from_hf(tensors: Dict[str, np.ndarray], config: LlamaConfig):
-    """HF llama naming → framework pytree (transposed to [in, out] layout)."""
+    """HF llama naming → framework pytree (transposed to [in, out] layout)
+    of HOST arrays in their final dtypes; load_params places the leaves."""
     c = config
     dt = c.dtype
 
@@ -139,18 +142,17 @@ def params_from_hf(tensors: Dict[str, np.ndarray], config: LlamaConfig):
         # HF nn.Linear stores [out, in]; we use [in, out]
         return np.ascontiguousarray(get(name).T)
 
-    def stack(fmt: str, transform) -> jnp.ndarray:
-        return jnp.asarray(
-            np.stack([transform(fmt.format(i)) for i in range(c.num_layers)]), dt
-        )
+    def stack(fmt: str, transform=get, dtype=dt) -> np.ndarray:
+        return np.stack(
+            [transform(fmt.format(i)) for i in range(c.num_layers)]
+        ).astype(dtype)
 
     params = {
-        "embed": jnp.asarray(get("model.embed_tokens.weight"), dt),
-        "final_norm": jnp.asarray(get("model.norm.weight"), jnp.float32),
+        "embed": get("model.embed_tokens.weight").astype(dt),
+        "final_norm": get("model.norm.weight").astype(np.float32),
         "layers": {
-            "attn_norm": jnp.asarray(
-                np.stack([get(f"model.layers.{i}.input_layernorm.weight") for i in range(c.num_layers)]),
-                jnp.float32,
+            "attn_norm": stack(
+                "model.layers.{}.input_layernorm.weight", dtype=np.float32
             ),
             "wq": stack("model.layers.{}.self_attn.q_proj.weight", lin),
             "wk": stack("model.layers.{}.self_attn.k_proj.weight", lin),
@@ -158,26 +160,22 @@ def params_from_hf(tensors: Dict[str, np.ndarray], config: LlamaConfig):
             "wo": stack("model.layers.{}.self_attn.o_proj.weight", lin),
             **(
                 {
-                    "bq": jnp.asarray(np.stack(
-                        [get(f"model.layers.{i}.self_attn.q_proj.bias") for i in range(c.num_layers)]
-                    ), jnp.float32),
-                    "bk": jnp.asarray(np.stack(
-                        [get(f"model.layers.{i}.self_attn.k_proj.bias") for i in range(c.num_layers)]
-                    ), jnp.float32),
-                    "bv": jnp.asarray(np.stack(
-                        [get(f"model.layers.{i}.self_attn.v_proj.bias") for i in range(c.num_layers)]
-                    ), jnp.float32),
+                    b: stack(
+                        "model.layers.{}.self_attn.%s_proj.bias" % b[1],
+                        dtype=np.float32,
+                    )
+                    for b in ("bq", "bk", "bv")
                 }
                 if c.qkv_bias
                 else {}
             ),
-            "mlp_norm": jnp.asarray(
-                np.stack([get(f"model.layers.{i}.post_attention_layernorm.weight") for i in range(c.num_layers)]),
-                jnp.float32,
+            "mlp_norm": stack(
+                "model.layers.{}.post_attention_layernorm.weight",
+                dtype=np.float32,
             ),
-            **_mlp_weights(tensors, c),
+            **_mlp_weights(c, stack, lin),
         },
     }
     if not c.tie_embeddings:
-        params["lm_head"] = jnp.asarray(np.ascontiguousarray(get("lm_head.weight").T), dt)
+        params["lm_head"] = lin("lm_head.weight").astype(dt)
     return params

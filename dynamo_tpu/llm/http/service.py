@@ -105,8 +105,13 @@ class HttpService:
         port: int = 8080,
         metrics_prefix: str = "dynamo_frontend",
         qos=None,
+        engine=None,
     ):
         self.manager = manager or ModelManager()
+        # in-process core engine (in=http out=jax): its metrics_snapshot()
+        # is served at /debug/engine — the one-process counterpart of the
+        # stats endpoint a distributed worker registers
+        self._engine = engine
         self.host = host
         self.port = port
         self.metrics = ServiceMetrics(metrics_prefix)
@@ -146,6 +151,7 @@ class HttpService:
                 web.get("/debug/traces", self._debug_traces),
                 web.get("/debug/slo", self._debug_slo),
                 web.get("/debug/profile", self._debug_profile),
+                web.get("/debug/engine", self._debug_engine),
             ]
         )
 
@@ -291,6 +297,21 @@ class HttpService:
         state.pop("records", None)  # summary view: keep the payload small
         state.pop("events", None)
         return web.json_response(state)
+
+    async def _debug_engine(self, _request: web.Request) -> web.Response:
+        """The in-process engine's ``metrics_snapshot()`` (404 on a frontend
+        whose engines are remote: ask the worker's stats endpoint)."""
+        if self._engine is None:
+            raise web.HTTPNotFound(text="no in-process engine")
+        import jax  # the in-process engine has long since imported it
+
+        snap = self._engine.metrics_snapshot()
+        # per-device allocator counters (peak_bytes_in_use …), where the
+        # backend reports them: only the process holding the chip can ask
+        snap["device_memory"] = [
+            dict(d.memory_stats() or {}, id=d.id) for d in jax.local_devices()
+        ]
+        return web.json_response(snap)
 
     async def _debug_slo(self, _request: web.Request) -> web.Response:
         """SLO / burn-rate report: the edge's own objectives (fed from the

@@ -610,42 +610,12 @@ def _paged_window_attention(
     partial is merged with the standard flash-decoding combine. The pool
     stays read-only inside the dispatch — the kernel tier gets the same
     no-per-step-scatter decode structure as the jnp path."""
-    from dynamo_tpu.ops.attention import _v2_supported, _v4_supported
-    from dynamo_tpu.ops.pallas.paged_attention import (
-        paged_attention_decode,
-        paged_attention_decode_sharded,
-        paged_attention_decode_v2,
-        paged_attention_decode_v4,
-        v4_plan,
-    )
+    from dynamo_tpu.ops.attention import paged_decode
 
-    b, _, h_, d = q.shape
-    lengths = jnp.maximum(base, 0)
-    q1 = q[:, 0]
-    plan = v4_plan(
-        b, k_page.shape[1], c.num_kv_heads, d, k_page.dtype.itemsize,
-        block_tables.shape[1],
+    o_p, m_p, l_p = paged_decode(
+        q[:, 0], k_page, v_page, block_tables, jnp.maximum(base, 0),
+        mesh=mesh, interpret=interpret, return_stats=True,
     )
-    if mesh is not None:
-        o_p, m_p, l_p = paged_attention_decode_sharded(
-            q1, k_page, v_page, block_tables, lengths, mesh=mesh,
-            interpret=interpret, return_stats=True,
-        )
-    elif _v4_supported(c.num_kv_heads, d) and plan is not None:
-        o_p, m_p, l_p = paged_attention_decode_v4(
-            q1, k_page, v_page, block_tables, lengths,
-            pages_per_chunk=plan, interpret=interpret, return_stats=True,
-        )
-    elif _v2_supported(d):
-        o_p, m_p, l_p = paged_attention_decode_v2(
-            q1, k_page, v_page, block_tables, lengths,
-            interpret=interpret, return_stats=True,
-        )
-    else:
-        o_p, m_p, l_p = paged_attention_decode(
-            q1, k_page, v_page, block_tables, lengths,
-            interpret=interpret, return_stats=True,
-        )
     num_w, m_w, l_w = _window_only_attention(c, q, base, wk, wv, wslot, soft_cap)
 
     m_p = jnp.maximum(m_p, -1e30)

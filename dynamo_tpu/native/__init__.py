@@ -11,15 +11,19 @@ Components:
 - ``codec_core.cc``  — two-part framed codec pack/verify (reference
   `codec/two_part.rs`): length-prefixed header+body frames with checksums.
 
-Build model: ``load(name)`` compiles ``{name}.cc`` → ``_lib/{name}.so``
-(g++ -O2 -shared -fPIC) keyed on source mtime, then ctypes-loads it.
-Pure-Python fallbacks keep every feature working when no toolchain exists;
-callers treat ``load() is None`` as "use the portable path".
+Build model: ``load(name)`` compiles ``{name}.cc`` →
+``_lib/{name}.<sha256 of the source>.so`` (g++ -O2 -shared -fPIC), then
+ctypes-loads it. The key is the source's CONTENT: a binary copied along
+with a tree (mtimes do not survive a copy in any useful order) can only be
+loaded by the source it was built from. Pure-Python twins keep every
+feature working when no toolchain exists; callers treat ``load() is None``
+as "use the portable path", and ``loaded()`` says which one is in use.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -37,7 +41,8 @@ _cache: dict = {}
 
 
 def load(name: str) -> Optional[ctypes.CDLL]:
-    """Compile (if stale) and load the named native component.
+    """Compile (if not built from this source yet) and load the named
+    native component.
 
     Returns None — and logs once — when the toolchain or source is missing
     or compilation fails; callers fall back to the Python implementation.
@@ -59,17 +64,23 @@ def load(name: str) -> Optional[ctypes.CDLL]:
         return _cache.setdefault(name, lib)
 
 
+def loaded() -> dict:
+    """name → True (native library in use) / False (Python twin), for every
+    component this process has asked for."""
+    with _lock:
+        return {name: lib is not None for name, lib in _cache.items()}
+
+
 def _build_and_load(name: str) -> Optional[ctypes.CDLL]:
     src = os.path.join(_DIR, f"{name}.cc")
     if not os.path.exists(src):
         logger.warning("native source %s missing", src)
         return None
-    so = os.path.join(_LIB_DIR, f"{name}.so")
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    so = os.path.join(_LIB_DIR, f"{name}.{digest}.so")
     try:
-        if (
-            not os.path.exists(so)
-            or os.path.getmtime(so) < os.path.getmtime(src)
-        ):
+        if not os.path.exists(so):
             os.makedirs(_LIB_DIR, exist_ok=True)
             # per-process tmp: concurrent builders must not clobber each
             # other's half-written output (os.replace is atomic)

@@ -24,42 +24,49 @@ from dynamo_tpu.runtime.envknobs import env_str
 
 @lru_cache(maxsize=1)
 def _platform_is_tpu() -> bool:
-    try:
-        dev = jax.devices()[0]
-        return dev.platform == "tpu" or dev.device_kind.startswith("TPU")
-    except Exception:
-        return False
+    # no exception handling: a backend that fails to initialise must fail
+    # the caller, not silently select the dense tier
+    return jax.devices()[0].platform == "tpu"
 
 
 def _select_pallas(head_dim: int) -> bool:
-    """One fresh-read policy for the decode attention implementation.
+    """One fresh-read policy for the T==1 attention implementation of the
+    plain ``forward`` path.
 
-    DYN_TPU_ATTENTION=pallas|jnp forces the choice; auto uses the
-    multi-page double-buffered kernel (paged_attention_decode_v2) on TPU
-    whenever the head dim is lane-aligned (D % 128 == 0 — Mosaic DMA slices
-    must align to the 128-lane tiling); the lane-batched v4 schedule widens
-    this to kvh*d % 128 == 0 where callers know kvh (see _v4_supported —
-    d=64 GQA models like llama3.2-1b qualify). Measured on v5e at D=128:
-    v4 streams at the practical HBM ceiling and beats the dense tier at 8k
-    context. Env vars are read at trace time, so tests and
-    operators can flip them live. Callers with a cache sharded over a mesh
-    pass ``mesh=`` so the kernel runs under shard_map (Mosaic kernels have
-    no GSPMD partitioning rule; shard_map sidesteps auto-partitioning).
+    DYN_TPU_ATTENTION=pallas|jnp forces the choice; auto uses the Pallas
+    decode kernel on TPU whenever the head dim is lane-aligned (D % 128 ==
+    0 — Mosaic DMA slices must align to the 128-lane tiling). Which kernel
+    schedule runs is :func:`decode_schedule`'s choice. Env vars are read at
+    trace time, so tests and operators can flip them live. Callers with a
+    cache sharded over a mesh pass ``mesh=`` so the kernel runs under
+    shard_map (Mosaic kernels have no GSPMD partitioning rule; shard_map
+    sidesteps auto-partitioning).
     """
     mode = env_str("DYN_TPU_ATTENTION", "auto")
     if mode == "pallas":
         return True
     if mode == "jnp":
         return False
-    # note: callers with kvh in hand get the wider fused-lane rule via
-    # _v4_supported below (d=64 GQA models qualify through kvh*d % 128)
-    return _platform_is_tpu() and _v2_supported(head_dim)
+    return _platform_is_tpu() and head_dim % 128 == 0
 
 
-def _v2_supported(head_dim: int) -> bool:
-    """Single home for the Mosaic DMA-slice alignment constraint (128-lane
-    tiling): both auto-selection and the v2-vs-v1 dispatch consult it."""
-    return head_dim % 128 == 0
+def _v2_supported(head_dim: int, num_kv_heads: int, itemsize: int = 2) -> bool:
+    """Single home for the Mosaic DMA-slice alignment constraints of the
+    per-lane v2 schedule, as the v5e compiler states them (asked ahead of
+    time, tests/test_aot_compile_tpu.py): its page slices
+    ``[bs, KVH, D]`` must align to the 128-lane tiling in D, and — for
+    packed (sub-32-bit) dtypes — to the sublane tiling in KVH, which is
+    min(8, next_pow2(KVH)) rows: KVH of 2, 4 or a multiple of 8 compiles;
+    1, 3, 5, 6, 12 are refused ("Slice shape along dimension 2 must be
+    aligned to tiling"). ``num_kv_heads`` is the count the kernel SEES —
+    per tp shard under shard_map, so every tp layout that leaves one KV
+    head per shard (qwen2.5-7b tp=4, 70B tp=8) is excluded here."""
+    if head_dim % 128:
+        return False
+    if itemsize >= 4:
+        return True
+    tile = min(8, max(2, 1 << (num_kv_heads - 1).bit_length()))
+    return num_kv_heads % tile == 0
 
 
 def _v4_supported(num_kv_heads: int, head_dim: int) -> bool:
@@ -68,6 +75,66 @@ def _v4_supported(num_kv_heads: int, head_dim: int) -> bool:
     on the fused width — d=64 GQA models (llama-1b: 8×64=512) qualify even
     though the per-lane v2 schedule's d%128 rule excludes them."""
     return (num_kv_heads * head_dim) % 128 == 0
+
+
+def decode_schedule(
+    n_lanes: int, block_size: int, num_kv_heads: int, head_dim: int,
+    itemsize: int, max_blocks: int, sharded: bool = False,
+) -> Tuple[str, Optional[int]]:
+    """Which Pallas decode schedule serves these shapes: ``("v4", pages_
+    per_chunk)``, ``("v2", None)`` or ``("v1", None)``. The choice follows
+    from shapes alone. ``num_kv_heads`` is per tp shard when ``sharded``
+    (the kernel runs per shard under shard_map, where only the per-lane
+    schedules are used). v1 — one page per grid step, BlockSpec-pipelined —
+    has no DMA-slice alignment constraint and is the schedule of last
+    resort."""
+    if not sharded and _v4_supported(num_kv_heads, head_dim):
+        from dynamo_tpu.ops.pallas.paged_attention import v4_plan
+
+        plan = v4_plan(
+            n_lanes, block_size, num_kv_heads, head_dim, itemsize, max_blocks
+        )
+        if plan is not None:
+            return "v4", plan
+    if _v2_supported(head_dim, num_kv_heads, itemsize):
+        return "v2", None
+    return "v1", None
+
+
+def paged_decode(
+    q: jax.Array,  # [S, H, D]
+    k_cache: jax.Array,  # [N, bs, KVH, D]
+    v_cache: jax.Array,
+    block_tables: jax.Array,  # [S, MB]
+    lengths: jax.Array,  # [S]; 0 = padding lane
+    *,
+    mesh=None,
+    scale: Optional[float] = None,
+    interpret: bool = False,
+    return_stats: bool = False,
+):
+    """The Pallas decode kernel under the schedule :func:`decode_schedule`
+    picks (per tp shard under shard_map when ``mesh`` is given)."""
+    from dynamo_tpu.ops.pallas import paged_attention as pk
+
+    kw = dict(scale=scale, interpret=interpret, return_stats=return_stats)
+    if mesh is not None:
+        return pk.paged_attention_decode_sharded(
+            q, k_cache, v_cache, block_tables, lengths, mesh=mesh, **kw
+        )
+    _, bs, kvh, d = k_cache.shape
+    name, plan = decode_schedule(
+        q.shape[0], bs, kvh, d, k_cache.dtype.itemsize, block_tables.shape[1]
+    )
+    if name == "v4":
+        # lane-batched single-program schedule: one loop drives every
+        # lane's DMA+compute (the per-lane grid's fixed cost / n_lanes)
+        return pk.paged_attention_decode_v4(
+            q, k_cache, v_cache, block_tables, lengths,
+            pages_per_chunk=plan, **kw
+        )
+    fn = pk.paged_attention_decode_v2 if name == "v2" else pk.paged_attention_decode
+    return fn(q, k_cache, v_cache, block_tables, lengths, **kw)
 
 
 def decode_uses_pallas(
@@ -99,11 +166,8 @@ def decode_uses_pallas(
       regime with zero extra HBM.
 
     Usability: TPU platform, and on a sharded mesh the head axes must split
-    evenly over tp (shard_map divisibility). Shapes where kvh*d % 128 == 0
-    take the lane-batched v4 schedule (fused-lane pages — includes the
-    d=64 GQA families); d % 128 == 0 takes v2; anything else falls back to
-    the per-page-grid v1 schedule, which has no DMA-slice alignment
-    constraint.
+    evenly over tp (shard_map divisibility). The kernel schedule is
+    :func:`decode_schedule`'s choice.
     """
     mode = env_str("DYN_TPU_ATTENTION", "auto")
     if mode == "jnp":
@@ -214,45 +278,11 @@ def paged_attention(
         # mesh (e.g. tp=16 over KVH=8) keeps the GSPMD-partitioned jnp path
         use_pallas = False
     if t == 1 and soft_cap is None and use_pallas:
-        from dynamo_tpu.ops.pallas.paged_attention import (
-            paged_attention_decode,
-            paged_attention_decode_sharded,
-            paged_attention_decode_v2,
-            paged_attention_decode_v4,
-            v4_plan,
-        )
-
         lengths = jnp.maximum(q_positions[:, 0] + 1, 0)  # padding (pos<0) → 0
-        interpret = jax.devices()[0].platform == "cpu"
-        plan = v4_plan(
-            q.shape[0], k_cache.shape[1], kvh, d, k_cache.dtype.itemsize,
-            block_tables.shape[1],
+        out = paged_decode(
+            q[:, 0], k_cache, v_cache, block_tables, lengths, mesh=mesh,
+            scale=scale, interpret=jax.devices()[0].platform == "cpu",
         )
-        if mesh is not None:
-            # sharded cache: run the kernel per tp shard under shard_map
-            out = paged_attention_decode_sharded(
-                q[:, 0], k_cache, v_cache, block_tables, lengths, mesh=mesh,
-                scale=scale, interpret=interpret,
-            )
-        elif _v4_supported(kvh, d) and plan is not None:
-            # lane-batched single-program schedule: one loop drives every
-            # lane's DMA+compute (the per-lane grid's fixed cost / n_lanes)
-            out = paged_attention_decode_v4(
-                q[:, 0], k_cache, v_cache, block_tables, lengths, scale=scale,
-                pages_per_chunk=plan, interpret=interpret,
-            )
-        elif _v2_supported(d):
-            out = paged_attention_decode_v2(
-                q[:, 0], k_cache, v_cache, block_tables, lengths, scale=scale,
-                interpret=interpret,
-            )
-        else:
-            # lane-misaligned head dim: the per-page-grid schedule (no DMA
-            # slicing constraint) still works when forced
-            out = paged_attention_decode(
-                q[:, 0], k_cache, v_cache, block_tables, lengths, scale=scale,
-                interpret=interpret,
-            )
         return out[:, None]
 
     k = gather_pages(k_cache, block_tables)  # [B, S, KVH, D]

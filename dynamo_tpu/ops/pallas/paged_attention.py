@@ -656,7 +656,7 @@ def paged_attention_decode_sharded(
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from dynamo_tpu.ops.attention import _v2_supported
+    from dynamo_tpu.ops.attention import decode_schedule
     from dynamo_tpu.parallel.mesh import AXIS_TP
 
     d = q.shape[-1]
@@ -667,8 +667,15 @@ def paged_attention_decode_sharded(
     kvspec = P(None, None, tp, None)
 
     def local(qs, ks, vs, tbl, ln):
-        # head_dim is not sharded, so the v2 lane-alignment rule is unchanged
-        if _v2_supported(d):
+        # the alignment rule sees the PER-SHARD kv-head count: one KV head
+        # per shard (qwen2.5-7b tp=4, 70B tp=8) is refused by the compiler
+        # under v2 and takes the unconstrained v1 schedule
+        _, bs, kvh_local, _ = ks.shape
+        name, _ = decode_schedule(
+            qs.shape[0], bs, kvh_local, d, ks.dtype.itemsize, tbl.shape[1],
+            sharded=True,
+        )
+        if name == "v2":
             return paged_attention_decode_v2(
                 qs, ks, vs, tbl, ln, scale=scale,
                 pages_per_chunk=pages_per_chunk, interpret=interpret,

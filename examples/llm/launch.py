@@ -22,6 +22,10 @@ except ImportError:  # configs are optional
     yaml = None
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, REPO)
+
+from dynamo_tpu.runtime.chips import place_workers  # noqa: E402
+
 GRAPHS = ("agg", "agg_router", "disagg", "disagg_router")
 
 
@@ -77,19 +81,22 @@ def main() -> None:
     for k, v in (overrides.get("worker") or {}).items():
         worker_flags += [f"--{k.replace('_', '-')}", str(v)]
     disagg = args.graph.startswith("disagg")
-    for _ in range(args.workers):
+    # every engine process (workers + the prefill worker) gets one distinct
+    # chip through the environment libtpu reads; this parent stays off JAX
+    chip_envs = place_workers(args.workers + (1 if disagg else 0))
+    for i in range(args.workers):
         procs.append(spawn([
             "-m", "dynamo_tpu.cli.run", "in=dyn://dynamo.backend.generate",
             "out=jax", *worker_flags,
             *(["--disagg", "decode", "--max-local-prefill-length",
                str(args.max_local_prefill_length)] if disagg else []),
-        ]))
+        ], chip_envs[i]))
     if disagg:
         procs.append(spawn([
             "-m", "dynamo_tpu.cli.run", "in=prefill:dynamo", "out=jax",
             "--model-path", args.model_path,
             "--statestore", ss, "--bus", bus,
-        ]))
+        ], chip_envs[-1]))
 
     print(f"[launch] {args.graph}: frontend http://127.0.0.1:{args.port} "
           f"({args.workers} worker(s){' + prefill' if disagg else ''}, "

@@ -419,3 +419,34 @@ def test_int8_quantized_engine(params, run):
         assert agree >= 3, (toks, ref)
     finally:
         eng.close()
+
+
+@pytest.mark.parametrize("placed_from_outside", [True, False])
+def test_compile_cache_placement(monkeypatch, tmp_path, placed_from_outside):
+    """JAX_COMPILATION_CACHE_DIR set: enable_compile_cache() names no
+    directory in code (JAX reads the variable itself) and returns that path;
+    unset: the fixed <checkout>/.jax_cache."""
+    import os
+
+    from dynamo_tpu.engine_jax import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    min_secs = jax.config.jax_persistent_cache_min_compile_time_secs
+    sentinel = str(tmp_path / "whatever-was-configured")
+    jax.config.update("jax_compilation_cache_dir", sentinel)
+    try:
+        if placed_from_outside:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+            assert compile_cache.enable_compile_cache() == "/some/dir"
+            assert jax.config.jax_compilation_cache_dir == sentinel
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            want = os.path.join(repo, ".jax_cache")
+            assert compile_cache.enable_compile_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs", min_secs
+        )

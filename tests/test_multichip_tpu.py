@@ -1,19 +1,20 @@
-"""Staged first real multi-chip session (VERDICT r4 item 8).
+"""Staged tests for paths that need ≥2 REAL TPU chips in one process.
 
-Everything multi-chip in this repo is validated on virtual CPU meshes; the
-moment ≥2 REAL TPU chips appear, THIS module is the prepared evidence run.
-All tests are marked ``tpu`` and skip unless real multi-chip hardware is
-present — run with::
+The proof that the multi-chip main paths run on real chips is
+``python3 chip_smoke.py --chips 4`` (run through the chip tool; see
+docs/multihost_serving.md "Running on real chips"): mesh-sharded serving,
+tp=4 Qwen2.5-7B through cli.run, four one-chip replicas behind the router.
+This module keeps what that script does not reach. All tests are marked
+``tpu`` and skip unless real multi-chip hardware is present — run with::
 
     DYN_TPU_TESTS_REAL=1 python -m pytest tests/test_multichip_tpu.py -m tpu -v
 
-(the env var stops conftest from forcing the virtual CPU mesh; see
-docs/multihost_serving.md "First real multi-chip session" for the full
-runbook). Covers, in dependency order:
+(the env var stops conftest from forcing the virtual CPU mesh). Covers, in
+dependency order:
 
 1. device-plane probe + one real chip-to-chip KV pull
    (disagg/device_transfer.py has only ever run against fakes off-TPU);
-2. sharded int8 decode on a real tp mesh (the headline serving mode);
+2. sharded int8 decode on a real tp mesh;
 3. a 2-chip disaggregated serve: prefill engine and decode engine on
    DIFFERENT chips, KV over the device plane.
 """
