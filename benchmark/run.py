@@ -1,0 +1,378 @@
+#!/usr/bin/env python3
+"""One run of one cell of the benchmark.
+
+    python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+
+This process never imports JAX while the server lives. It writes the model
+directory, starts ONE child (``server_child.py``: ``dynamo_tpu.cli.run.amain``
+``in=http out=jax``), waits for ready, sends two probes, runs pre-roll, window
+and drain over HTTP, reads the counters, sends a third probe, stops the child
+and prints one JSON line. A traced run then holds one more greedy answer against
+the plain reference (``reference_child.py``, a second short-lived child). Without a TPU named by the child the run fails
+(``--rehearse`` relaxes that, at a tiny width, and prints no device metric).
+See README.md for the files a cell, a configuration, a generator and a metric
+are made of.
+"""
+
+from __future__ import annotations
+
+import argparse
+import asyncio
+import importlib.util
+import json
+import os
+import random
+import shutil
+import subprocess
+import sys
+import time
+
+import aiohttp
+
+T_START = time.perf_counter()
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+HERE = os.path.join(ROOT, "benchmark")
+sys.path.insert(0, ROOT)
+
+from benchmark import bytes_and_flops, client, serving, stats, trace_reduce, traffic  # noqa: E402
+from benchmark.serving import BenchFailure, check  # noqa: E402
+
+# under one KV block, so that all three probes take the same path (no prefix hit)
+PROBE_PROMPT_TOKENS, PROBE_OUTPUT_TOKENS = 12, 16
+# the answer held against the reference: two prefill chunks (the second reads
+# history) and six decode dispatches
+REFERENCE_PROMPT_TOKENS, REFERENCE_OUTPUT_TOKENS = 150, 24
+TRACE_AT_S, TRACE_FOR_S = 5.0, 4.0
+FOLDERS = {"end_to_end": "end_to_end", "per_layer": "layer_metrics"}
+
+
+def load_json(*parts):
+    with open(os.path.join(*parts)) as f:
+        return json.load(f)
+
+
+def load_readers(kind: str) -> dict:
+    """``<kind>/<name>.py`` -> module, by listing the directory."""
+    found = {}
+    folder = os.path.join(HERE, kind)
+    for fname in sorted(os.listdir(folder)):
+        if not fname.endswith(".py") or fname.startswith("_"):
+            continue
+        spec = importlib.util.spec_from_file_location(
+            f"benchmark_{kind}_{fname[:-3].replace('.', '_')}", os.path.join(folder, fname))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        found[mod.NAME] = mod
+    return found
+
+
+def registered(bench: dict, kind: str, cell_name: str) -> list:
+    """The metrics of ``kind`` that BENCHMARK.json registers for this cell."""
+    return [m for m in bench[kind]
+            if "workloads" not in m or cell_name in m["workloads"]]
+
+
+def read_metrics(bench: dict, kind: str, cell_name: str, ctx: dict) -> dict:
+    readers, out = load_readers(FOLDERS[kind]), {}
+    for entry in registered(bench, kind, cell_name):
+        check(entry["name"] in readers,
+              f"BENCHMARK.json registers {entry['name']!r} but {FOLDERS[kind]}/ has no reader of it")
+        value = readers[entry["name"]].read(ctx)
+        if value is not None:  # nothing to read: the metric is left out
+            out[entry["name"]] = {"value": value, "unit": entry["unit"]}
+    return out
+
+
+async def sample_engine(clock, stop, session, port: int, seconds: float, into: list):
+    """``GET /debug/engine`` at 2 Hz over the window."""
+    url = f"http://127.0.0.1:{port}/debug/engine"
+    while not stop.is_set() and clock() < seconds:
+        if clock() >= 0.0:
+            try:
+                async with session.get(url) as resp:
+                    snap = await resp.json()
+                into.append({k: snap.get(k) for k in (
+                    "request_active_slots", "request_total_slots",
+                    "num_requests_waiting", "kv_active_blocks", "kv_total_blocks")}
+                    | {"t": clock()})
+            except (aiohttp.ClientError, asyncio.TimeoutError, ValueError):
+                pass  # a sample lost is a sample lost
+        await asyncio.sleep(0.5)
+
+
+async def sample_cpu(clock, stop, session, child, seconds: float, into: dict):
+    """The child's CPU seconds at both ends of the window."""
+    await asyncio.sleep(max(0.0, -clock()))
+    into["start"] = child.cpu_seconds()
+    await asyncio.sleep(max(0.0, seconds - clock()))
+    into["end"] = child.cpu_seconds()
+
+
+async def trigger_trace(clock, stop, session, trace_dir: str, seconds: float):
+    at = min(TRACE_AT_S, seconds * 0.3)
+    length = min(TRACE_FOR_S, seconds * 0.4)
+    await asyncio.sleep(max(0.0, at - clock()))
+    open(os.path.join(trace_dir, "start"), "w").close()
+    await asyncio.sleep(length)
+    open(os.path.join(trace_dir, "stop"), "w").close()
+
+
+def engine_state(port: int) -> dict:
+    status, raw = serving.http_json(port, "GET", "/debug/engine")
+    check(status == 200, f"/debug/engine: HTTP {status}")
+    return json.loads(raw)
+
+
+class Launch:
+    """Everything one run needs before the server answers: the cell, its
+    configuration, the model directory, the schedule, and the child."""
+
+    def __init__(self, workload: str, seed: int, trace: bool, rehearse: bool):
+        self.bench = load_json(ROOT, "BENCHMARK.json")
+        entry = next((w for w in self.bench["workloads"] if w["name"] == workload), None)
+        check(entry is not None, f"no workload {workload!r} in BENCHMARK.json")
+        self.cell = load_json(HERE, "workloads", f"{workload}.json")
+        cfg_entry = next(c for c in self.bench["configs"] if c["name"] == entry["config"])
+        self.cfg = load_json(ROOT, cfg_entry["file"])
+        self.serve = self.cfg["serving"]
+        self.chips = self.serve["chips"]
+        check(entry["config"] == self.cell["config"] and entry["chips"] == self.chips,
+              "the cell's file and BENCHMARK.json disagree on configuration or chips")
+        self.shape = {k: v for k, v in self.cfg.items() if not isinstance(v, dict)
+                      and k not in ("source", "reduced", "assumed", "deployment")}
+        if rehearse:
+            self.shape = load_json(HERE, "rehearse.json")["shape"]
+        self.scratch = os.path.join(ROOT, ".bench_runs", workload)
+        shutil.rmtree(self.scratch, ignore_errors=True)
+        os.makedirs(self.scratch)
+        self.model_dir = os.path.join(self.scratch, self.cell["config"])
+        self.model = os.path.basename(self.model_dir)
+        self.words = serving.write_model_dir(self.model_dir, self.shape, seed)
+        self.plain = [w for w in self.words[4:] if w not in serving.SPECIALS]
+        engine_args = dict(self.serve["engine_args"])
+        args_file = os.path.join(self.scratch, "engine_args.json")
+        with open(args_file, "w") as f:
+            json.dump(engine_args, f)
+        self.env = env = dict(self.serve.get("env") or {})
+        if rehearse:
+            env["JAX_PLATFORMS"] = "cpu"
+            env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={self.chips}"
+        self.trace_dir = os.path.join(self.scratch, "trace")
+        own = []
+        if trace:
+            os.makedirs(self.trace_dir)
+            own = ["--trace-dir", self.trace_dir]
+        self.port = serving.free_port()
+        self.child = serving.Child(
+            [sys.executable, os.path.join(HERE, "server_child.py"), *own, "--",
+             "in=http", "out=jax", "--model-path", self.model_dir,
+             "--host", "127.0.0.1", "--port", str(self.port),
+             *self.serve["server_flags"], "--extra-engine-args", args_file],
+            os.path.join(self.scratch, "server.log"), env,
+        )
+        self.rehearse = rehearse
+
+    def against_reference(self, prompt: str, answer: str) -> dict:
+        """The verdict of ``reference_child.py`` on one greedy answer. Run it
+        only after the server has exited: it needs the chip."""
+        word_id = {w: i for i, w in enumerate(self.words)}
+        case_file = os.path.join(self.scratch, "reference_case.json")
+        with open(case_file, "w") as f:
+            json.dump({"prompt_ids": [word_id[w] for w in prompt.split()],
+                       "output_ids": [word_id.get(w, 0) for w in answer.split()]}, f)
+        child = serving.Child(
+            [sys.executable, os.path.join(HERE, "reference_child.py"),
+             "--model-dir", self.model_dir, "--case", case_file,
+             "--seed", str(self.serve["engine_args"].get("seed", 0)),
+             "--reference", self.cfg.get("reference", "reference")],
+            os.path.join(self.scratch, "reference.log"), self.env,
+        )
+        try:
+            child.proc.wait(timeout=self.serve.get("ready_timeout_s", 900))
+        except subprocess.TimeoutExpired:
+            pass
+        finally:
+            child.stop()
+        verdicts = child.log_json("reference")
+        return verdicts[0] if verdicts else {"agrees": False, "log": child.log()[-1500:]}
+
+    def wait_ready(self) -> dict:
+        """The device the child named, once it serves. No TPU, no run."""
+        dev = serving.wait_device(self.child)
+        if not self.rehearse:
+            check(dev["platform"] == "tpu",
+                  f"no TPU: JAX found platform {dev['platform']!r}")
+        check(dev["count"] >= self.chips,
+              f"the cell needs {self.chips} chips, JAX found {dev['count']}")
+        self.peaks = None if self.rehearse else bytes_and_flops.load_peaks(dev["kind"])
+        serving.wait_http(self.child, self.port, self.serve.get("ready_timeout_s", 900))
+        return dev
+
+
+def run(args) -> int:
+    go = Launch(args.workload, args.seed, bool(args.trace), args.rehearse)
+    bench, cell, cfg, shape, chips = go.bench, go.cell, go.cfg, go.shape, go.chips
+    child, port, model, plain, trace_dir = go.child, go.port, go.model, go.plain, go.trace_dir
+    schedule = traffic.build_schedule(cell, args.seed, args.seconds)
+    samples, cpu, trace_done = [], {}, True
+    try:
+        dev = go.wait_ready()
+        peaks = go.peaks
+        ready_s = time.perf_counter() - T_START
+        probe_prompt = traffic.prompt_text(
+            plain, PROBE_PROMPT_TOKENS, random.Random(args.seed + 1))
+        probes = [asyncio.run(client.probe(port, model, probe_prompt, PROBE_OUTPUT_TOKENS))
+                  for _ in range(2)]
+        before = engine_state(port)
+
+        background = [lambda c, s, sess: sample_cpu(c, s, sess, child, args.seconds, cpu)]
+        if args.trace:
+            background += [
+                lambda c, s, sess: sample_engine(c, s, sess, port, args.seconds, samples),
+                lambda c, s, sess: trigger_trace(c, s, sess, trace_dir, args.seconds),
+            ]
+        window_at = {}
+        result = asyncio.run(client.run_window(
+            port, model, plain, schedule, args.seconds,
+            on_window=lambda: window_at.setdefault("t", time.perf_counter()),
+            background=background,
+        ))
+        after = engine_state(port)
+        probes.append(asyncio.run(client.probe(port, model, probe_prompt, PROBE_OUTPUT_TOKENS)))
+        if args.trace:
+            long_prompt = traffic.prompt_text(
+                plain, REFERENCE_PROMPT_TOKENS, random.Random(args.seed + 2))
+            long_probe = asyncio.run(client.probe(
+                port, model, long_prompt, REFERENCE_OUTPUT_TOKENS))
+            deadline = time.monotonic() + 90
+            while not os.path.exists(os.path.join(trace_dir, "done")):
+                if time.monotonic() > deadline or child.proc.poll() is not None:
+                    trace_done = False
+                    break
+                time.sleep(0.1)
+    finally:
+        child.stop()
+
+    # -- everything below runs after the child has exited ----------------------
+    records = result["records"]
+    if args.keep_records:
+        with open(args.keep_records, "w") as f:
+            json.dump([{k: v for k, v in r.items() if k != "text"} for r in records], f)
+    summary = stats.summarize(records, args.seconds)
+    setup_s = window_at["t"] - T_START
+    reasons = []
+    if not all(p["ok"] for p in probes):
+        reasons.append("a probe request failed: " + str([p.get("error") or p.get("usage") for p in probes]))
+    if len({p.get("text") for p in probes}) != 1:
+        reasons.append("the greedy probe returned different text before and after the window")
+    if after["jit_recompiles"] != before["jit_recompiles"]:
+        reasons.append(f"jit_recompiles rose {before['jit_recompiles']} -> {after['jit_recompiles']} over the window")
+    tiers = after.get("attention_tiers") or {}
+    if not tiers:
+        reasons.append("the engine reported no compiled attention tier")
+    if dev["platform"] == "tpu" and any(t.get("interpret") for t in tiers.values()):
+        reasons.append(f"an interpreted kernel on a TPU: {tiers}")
+    if summary["failed"]:
+        bad = [r for r in records if r["in_window"] and not r["ok"]][:3]
+        reasons.append(f"{summary['failed']} requests failed, e.g. " + str(
+            [{k: r.get(k) for k in ("status", "finish_reason", "usage", "error", "max_tokens")} for r in bad]))
+    if summary["attempted"] == 0:
+        reasons.append("no request fell due inside the window")
+
+    verdict = None
+    if args.trace:
+        if not long_probe["ok"]:
+            reasons.append(f"the request held against the reference failed: {long_probe.get('error')}")
+        verdict = go.against_reference(long_prompt, long_probe.get("text", ""))
+        if not verdict["agrees"]:
+            reasons.append(f"the probe's answer disagrees with the plain reference: {verdict}")
+
+    reduced = None
+    if args.trace and trace_done:
+        path = trace_reduce.find_xplane(trace_dir)
+        if path:
+            reduced = trace_reduce.read_xplane(
+                path, device_marker="CPU" if args.rehearse else "TPU")
+            if args.keep_trace:
+                trace_reduce.save_reduced(reduced, args.keep_trace)
+    memory = [d.get("peak_bytes_in_use") for d in after.get("device_memory") or []]
+    memory = [m for m in memory if m is not None]
+    device = {"platform": dev["platform"], "kind": dev["kind"], "count": dev["count"],
+              "memory_peak_bytes": max(memory) if memory else None}
+    ctx = {
+        "cell": cell, "cell_name": args.workload, "config": cfg, "shape": shape,
+        "chips": chips, "seconds": args.seconds, "peaks": peaks, "device": device,
+        "records": records, "summary": summary, "setup_s": setup_s,
+        "engine_samples": samples, "engine_before": before, "engine_after": after,
+        "child_cpu_s": (cpu["end"] - cpu["start"]) if len(cpu) == 2 else None,
+        "trace": reduced, "rehearse": args.rehearse,
+    }
+    info = {
+        "workload": args.workload, "seed": args.seed, "seconds": args.seconds,
+        "samples": summary["samples"], "ready_s": ready_s, "drain_s": result["drain_s"],
+        "warmup_s": (child.log_json("warmup") or [None])[0],
+        "compile_cache": dev.get("compile_cache"),
+        "jit_recompiles": [before["jit_recompiles"], after["jit_recompiles"]],
+        "against_reference": verdict,
+        "tiers": sorted({t.get("tier") for t in tiers.values()}),
+        "mean_prompt_tokens": summary["mean_prompt_tokens"],
+        "mean_output_tokens": summary["mean_output_tokens"],
+        "end_to_end": {k: summary[k] for k in (
+            "ttft_mean_ms", "ttft_p50_ms", "ttft_p90_ms",
+            "tpot_mean_ms", "tpot_p50_ms", "tpot_p90_ms",
+            "output_tokens_per_s", "client_lag_p90_ms")},
+        "token_count_mismatches": sum(
+            1 for r in records if r["ok"]
+            and sum(n for _, n in r["token_times"]) != r["got_tokens"]),
+        "setup_s": setup_s, "not_correct_because": reasons,
+    }
+    print(json.dumps({"info": info}), flush=True)
+
+    kind = "per_layer" if args.trace else "end_to_end"
+    metrics = read_metrics(bench, kind, args.workload, ctx)
+    line = {"correct": not reasons, "attempted": summary["attempted"],
+            "failed": summary["failed"], "metrics": metrics, "device": device}
+    if args.rehearse:
+        # a CPU run is never written under the name of a device metric
+        line["metrics"] = {}
+        line["rehearsal"] = {k: v["value"] for k, v in metrics.items()}
+    if args.trace and reduced is not None and reduced["devices"]:
+        span = trace_reduce.span_ns(reduced)
+        device["busy_s"] = trace_reduce.busy_seconds(reduced)
+        device["window_s"] = (span[1] - span[0]) / 1e9 if span else 0.0
+        line["breakdown"] = {
+            "device_ops": trace_reduce.top_ops(reduced, 10),
+            "idle_gaps": trace_reduce.idle_gaps(reduced, 5),
+        }
+    elif args.trace and not args.rehearse:
+        raise BenchFailure(
+            "the traced run read no device event: "
+            + json.dumps((reduced or {}).get("planes_seen", "no xplane file"))[:1500])
+    print(json.dumps(line), flush=True)
+    return 0
+
+
+def main() -> int:
+    p = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    p.add_argument("--workload", required=True)
+    p.add_argument("--seed", type=int, default=0)
+    p.add_argument("--seconds", type=float, default=None)
+    p.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    p.add_argument("--rehearse", action="store_true",
+                   help="tiny width on the CPU; prints no device metric")
+    p.add_argument("--keep-records", default=None,
+                   help="also write the per-request records (JSON) here")
+    p.add_argument("--keep-trace", default=None,
+                   help="also write the reduced trace (gzipped JSON) here")
+    args = p.parse_args()
+    if args.seconds is None:
+        args.seconds = float(load_json(ROOT, "BENCHMARK.json")["run_seconds"])
+    try:
+        return run(args)
+    except BenchFailure as e:
+        print(f"benchmark failed: {e}", file=sys.stderr, flush=True)
+        return 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())
