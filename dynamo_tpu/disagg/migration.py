@@ -337,10 +337,9 @@ class MigrationCoordinator:
                     iid, wid, taddr, _ = targets[rr % len(targets)]
                     rr += 1
                     ok = await self._migrate_one(cp, iid, wid, taddr)
-                    if ok:
+                    if ok:  # counted by _migrate_one, with the hand-over
                         stats["migrated"] += 1
                         stats["blocks_moved"] += cp["n_blocks"]
-                        note_migration(blocks=cp["n_blocks"])
                     else:
                         stats["failed"] += 1
                         note_migration(failed=True)
@@ -455,10 +454,15 @@ class MigrationCoordinator:
                     ),
                 )
                 return False
-            await _engine_call(
-                self.engine,
-                lambda: self.engine.finish_migrated(rid, iid, wid, cp["mid"]),
-            )
+            def _hand_over() -> None:
+                # counted on the engine thread, with the directive: once the
+                # stream has left, a stop() of this coordinator (the worker
+                # shutting down, its drain done) may cancel this task before
+                # it runs again, and the client's count would be one ahead
+                self.engine.finish_migrated(rid, iid, wid, cp["mid"])
+                note_migration(blocks=cp["n_blocks"])
+
+            await _engine_call(self.engine, _hand_over)
             return True
 
 

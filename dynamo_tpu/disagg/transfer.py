@@ -23,6 +23,7 @@ from dynamo_tpu.runtime import faults as _FAULTS
 from dynamo_tpu.runtime import integrity, tracing
 from dynamo_tpu.runtime.codec import TwoPartMessage, read_frame, write_frame
 from dynamo_tpu.runtime.integrity import KvIntegrityError
+from dynamo_tpu.runtime.netutil import TrackedServer
 
 logger = logging.getLogger(__name__)
 
@@ -157,24 +158,22 @@ class KvTransferServer:
         self.host = host
         self.port = port
         self.device_plane = device_plane
-        self._server: Optional[asyncio.AbstractServer] = None
+        self._server: Optional[TrackedServer] = None
         # label the corrupt-fault gate matches on (a drill targets ONE
         # worker's outbound pages); attach points override it with the
         # advertised transfer address
         self.fault_addr = ""
 
     async def start(self) -> None:
-        self._server = await asyncio.start_server(self._handle, self.host, self.port)
-        if self.port == 0:
-            self.port = self._server.sockets[0].getsockname()[1]
+        self._server = TrackedServer(self._handle, self.host, self.port)
+        self.port = await self._server.start()
         if not self.fault_addr:
             self.fault_addr = f"{self.host}:{self.port}"
         logger.info("kv transfer server on %s:%d", self.host, self.port)
 
     async def stop(self) -> None:
         if self._server:
-            self._server.close()
-            await self._server.wait_closed()
+            await self._server.stop()
 
     async def _handle(self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter) -> None:
         try:

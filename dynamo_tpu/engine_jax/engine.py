@@ -521,6 +521,11 @@ class JaxServingEngine(AsyncEngine):
         # leader's opcode stream — an extra input would skew it), so the
         # watchdog is single-chip for now, like int8 KV.
         self._watchdog = self._integrity is not None and mesh is None
+        # seal-time checksums pull the sealed pages to this host: on a
+        # multi-process mesh the pool spans devices no one process can
+        # address (device_get raises), so those engines seal unchecked and
+        # say so through the kv_seal_checksums gauge
+        self._seal_checksums = self._integrity is not None and not self._multihost
         # label the fault gates match on ("corrupt"/"poison" drills target
         # ONE worker in a fleet); attach_kv_publishing stamps the worker id
         self._fault_addr = "engine"
@@ -528,9 +533,7 @@ class JaxServingEngine(AsyncEngine):
             self.num_blocks, engine_config.kv_block_size, event_sink=event_sink,
             host_pool=self.host_pool,
             offload=self._offload_blocks if self.host_pool is not None else None,
-            checksum=(
-                self._block_checksums if self._integrity is not None else None
-            ),
+            checksum=self._block_checksums if self._seal_checksums else None,
         )
 
         # attention impl is auto-selected (platform + head-dim rule,
@@ -856,8 +859,14 @@ class JaxServingEngine(AsyncEngine):
         """Host array → device array usable by the step fns. On a
         process-spanning mesh this builds a REPLICATED global array (every
         process holds the full value — the multihost lockstep contract);
-        otherwise a plain transfer."""
-        a = np.asarray(host_arr)
+        otherwise a plain transfer. The device array gets a PRIVATE copy
+        of the host bytes: the CPU backend aliases a numpy buffer that
+        happens to be 64-byte aligned instead of copying it, and the step
+        loop rewrites its lane arrays (tables, last tokens, positions) in
+        place while earlier dispatches may still be pending — an aliased
+        input then changes under a running program (wrong tokens, seen as
+        tests that failed only on a loaded machine)."""
+        a = np.array(host_arr)
         if not self._multihost:
             return jnp.asarray(a)
         from jax.sharding import NamedSharding, PartitionSpec
@@ -3914,6 +3923,7 @@ class JaxServingEngine(AsyncEngine):
             # engine-local watchdog trips (the process-global trip/
             # quarantine counters ride attach_kv_publishing)
             "watchdog_trips": self.watchdog_trips,
+            "kv_seal_checksums": int(self._seal_checksums),
             "attention_tiers": self._attention_tiers(),
         }
         if self._perf is not None:

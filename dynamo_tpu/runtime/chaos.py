@@ -862,16 +862,36 @@ class ChaosRunner:
             seed=self.schedule.seed,
         )
 
+    def _pace(self) -> float:
+        """Seconds a token: the load has to span the schedule horizon —
+        otherwise every stream finishes before the first fault lands and
+        the run exercises nothing."""
+        return max(self.schedule.horizon * 0.7 / self.max_tokens, 0.005)
+
     async def _build_engine(self, i: int):
         if self._shared_engines is not None:
             return self._shared_engines[i]
         if self.engine_factory is not None:
             return await asyncio.to_thread(self.engine_factory, i)
-        # pace the mock so the load actually spans the schedule horizon —
-        # otherwise every stream finishes before the first fault lands and
-        # the run exercises nothing
-        delay = max(self.schedule.horizon * 0.7 / self.max_tokens, 0.005)
-        return MockChaosWorker(f"w{i}", delay=delay)
+        return MockChaosWorker(f"w{i}", delay=self._pace())
+
+    def _pace_engines(self, on: bool) -> None:
+        """Hold real engines to the mock's pace while the schedule runs (a
+        tiny engine on an idle machine decodes its 20-30 tokens in a tenth
+        of a second): one sleep ahead of the engine's own per-dispatch
+        ``slow`` fault gate, on the engine thread. The goldens, computed
+        before, run unpaced."""
+        delay = self._pace()
+        for e in self._engines:
+            if not on:
+                e.__dict__.pop("_slow_fault", None)  # the class's again
+                continue
+
+            def paced(gate=e._slow_fault) -> None:
+                time.sleep(delay)
+                gate()
+
+            e._slow_fault = paced
 
     async def _golden(self, engine, prompt: List[int]) -> List[int]:
         if self.mock:
@@ -1040,6 +1060,8 @@ class ChaosRunner:
             await client.wait_for_instances(n, timeout=10)
 
             faults.install(inj)
+            if not self.mock:
+                self._pace_engines(True)
 
             results = [
                 StreamResult(index=i, prompt=prompts[i], golden=goldens[i])
@@ -1232,6 +1254,8 @@ class ChaosRunner:
             return report
         finally:
             faults.uninstall()
+            if not self.mock:
+                self._pace_engines(False)
             install_observer(prev_observer)
             if client is not None:
                 await client.close()
@@ -1249,20 +1273,30 @@ class ChaosRunner:
     async def _settle(self) -> None:
         """Poll the fleet quiescent: zero live requests, zero allocated KV
         blocks, zero staged migrations on every worker — the conservation
-        invariants judge whatever is left at the bound."""
+        invariants judge whatever is left at the bound. A staged migration
+        whose client resumed elsewhere is freed by its target's TTL sweep
+        (the designed clean-up, 30 s by default): while stages are all
+        that is outstanding the settle waits that sweep out, so an unclaimed
+        stage reads as a leak only when the sweep failed to free it."""
         loop = asyncio.get_running_loop()
         t0 = loop.time()
-        while loop.time() - t0 < self.settle_bound:
-            busy = False
+        sweep = max(
+            (e._migration_ttl() for e in self._engines
+             if hasattr(e, "_migration_ttl")),
+            default=0.0,
+        )
+        while True:
+            live = staged = blocks = 0
             for e in self._engines:
                 snap = e.metrics_snapshot()
-                if (
-                    e.live_request_count()
-                    or snap.get("kv_active_blocks")
-                    or snap.get("migrate_staged")
-                ):
-                    busy = True
-                    break
-            if not busy:
+                live += e.live_request_count()
+                blocks += snap.get("kv_active_blocks") or 0
+                staged += snap.get("migrate_staged") or 0
+            if not (live or blocks or staged):
+                return
+            waited = loop.time() - t0
+            if waited >= self.settle_bound and (
+                live or not staged or waited >= self.settle_bound + sweep
+            ):
                 return
             await asyncio.sleep(0.1)

@@ -22,17 +22,9 @@ if os.environ.get("DYN_TPU_TESTS_REAL") != "1":
     _ensure_devices(8)
 
 import asyncio  # noqa: E402
+import signal  # noqa: E402
 
 import pytest  # noqa: E402
-
-
-def pytest_configure(config):
-    config.addinivalue_line("markers", "tpu: requires real TPU hardware")
-    config.addinivalue_line("markers", "slow: long-running test")
-    config.addinivalue_line(
-        "markers",
-        "chaos: composition chaos plane (seeded fault-schedule runs)",
-    )
 
 
 @pytest.fixture(scope="session")
@@ -54,113 +46,76 @@ def run():
     return _run
 
 
+# Seconds each phase of one test (set-up, call, tear-down) may take: the
+# slowest test measured alone is under 30 s. A test that needs more says so
+# with @pytest.mark.timeout(N).
+DEFAULT_TEST_LIMIT_S = 120
+# Once the limit has struck, the alarm strikes again this often until the
+# phase has unwound: asyncio.run cancels its tasks on the way out, and one
+# that swallows the cancellation (or the TimeoutError) would wait again.
+_UNWIND_S = 10
+
+
+@pytest.hookimpl(wrapper=True)
+def _limited(item):
+    """Fail a test that outlives its limit. pytest-timeout is not installed
+    where these tests run, so SIGALRM does it: tests run in their process's
+    main thread (under xdist too), and the signal interrupts a selector, a
+    lock or a subprocess wait alike. Armed around each phase and not by a
+    fixture, so that no alarm can strike while pytest writes its report."""
+    mark = item.get_closest_marker("timeout")
+    limit = mark.args[0] if mark else DEFAULT_TEST_LIMIT_S
+
+    def on_alarm(signum, frame):
+        signal.setitimer(signal.ITIMER_REAL, _UNWIND_S)
+        raise TimeoutError(f"{item.nodeid} exceeded its limit of {limit} s")
+
+    previous = signal.signal(signal.SIGALRM, on_alarm)
+    signal.setitimer(signal.ITIMER_REAL, limit)
+    try:
+        return (yield)
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
+
+
+pytest_runtest_setup = pytest_runtest_call = pytest_runtest_teardown = _limited
+
+
+# runtime modules with process-global state and a reset_for_tests(): one
+# test's outages, quarantine latch, dispatch records, fail-slow verdict or
+# armed chaos observer must not bleed into the next test's assertions
+_RESET_AFTER_EACH_TEST = (
+    "control_plane", "integrity", "profiling", "straggler", "chaos",
+)
+
+
 @pytest.fixture(autouse=True)
-def _reset_control_plane_state():
-    """Zero the process-global control-plane connectivity tracker after
-    each test: statestore/bus clients note outages into it, and a test
-    that legitimately bounced a server must not leave a later test's
-    /health reading 'degraded' (imported lazily — same contract as the
-    health-monitor guard below)."""
+def _clean_process_globals():
+    """After each test: reset the process-global state above and fail a test
+    that leaked a background task — a drain-migration coordinator or a
+    HealthMonitor left running keeps freezing streams / reaping state under
+    every later test. Modules are looked up in sys.modules, never imported:
+    the guard must not drag runtime modules into tests that never touch
+    them."""
     yield
-    import sys
-
-    cp = sys.modules.get("dynamo_tpu.runtime.control_plane")
-    if cp is not None:
-        cp.reset_for_tests()
-
-
-@pytest.fixture(autouse=True)
-def _no_leaked_migrations():
-    """Fail any test that leaves a drain-migration coordinator task running
-    past teardown: a leaked drain task keeps freezing/shipping streams in
-    the background of every later test (imported lazily — the HealthMonitor
-    guard pattern). Also zero the process-global migration counters so one
-    test's drains can't bleed into another's gauge assertions."""
-    yield
-    import sys
-
+    for name in _RESET_AFTER_EACH_TEST:
+        mod = sys.modules.get(f"dynamo_tpu.runtime.{name}")
+        if mod is not None:
+            mod.reset_for_tests()
     mig = sys.modules.get("dynamo_tpu.disagg.migration")
-    if mig is None:
-        return
-    leaked = mig.live_coordinators()
-    assert not leaked, (
-        f"{len(leaked)} MigrationCoordinator drain task(s) leaked past test "
-        f"teardown — stop() the coordinator (or shutdown() its "
+    leaked_drains = mig.live_coordinators() if mig else []
+    if mig:
+        mig.reset_migration_counters()
+    health = sys.modules.get("dynamo_tpu.runtime.health")
+    leaked_monitors = health.live_monitors() if health else []
+    assert not leaked_drains, (
+        f"{len(leaked_drains)} MigrationCoordinator drain task(s) leaked "
+        f"past test teardown — stop() the coordinator (or shutdown() its "
         f"DistributedRuntime)"
     )
-    mig.reset_migration_counters()
-
-
-@pytest.fixture(autouse=True)
-def _reset_integrity_state():
-    """Drop the process-global integrity tracker after each test: one
-    test's corruption trips or quarantine latch must not leave a later
-    test's health checks reading 'quarantined' (imported lazily — the
-    control-plane reset pattern above)."""
-    yield
-    import sys
-
-    integ = sys.modules.get("dynamo_tpu.runtime.integrity")
-    if integ is not None:
-        integ.reset_for_tests()
-
-
-@pytest.fixture(autouse=True)
-def _reset_profiling_state():
-    """Drop the process-global profiling timeline / frontend CPU
-    accumulator / lag sampler after each test: one test's dispatch
-    records must not bleed into another's summary or zero-overhead
-    assertions (imported lazily — the control-plane reset pattern)."""
-    yield
-    import sys
-
-    prof = sys.modules.get("dynamo_tpu.runtime.profiling")
-    if prof is not None:
-        prof.reset_for_tests()
-
-
-@pytest.fixture(autouse=True)
-def _reset_straggler_state():
-    """Drop the process-global straggler detector and verdict latch after
-    each test: one test's dispatch samples or latched fail-slow verdict
-    must not leave a later test's health checks reading 'suspect'
-    (imported lazily — the control-plane reset pattern)."""
-    yield
-    import sys
-
-    strag = sys.modules.get("dynamo_tpu.runtime.straggler")
-    if strag is not None:
-        strag.reset_for_tests()
-
-
-@pytest.fixture(autouse=True)
-def _reset_chaos_state():
-    """Drop the process-global chaos observer and its once-only env probe
-    after each test: one test's armed observer (or noted events) must not
-    bleed into another's invariant or zero-overhead assertions (imported
-    lazily — the control-plane reset pattern)."""
-    yield
-    import sys
-
-    ch = sys.modules.get("dynamo_tpu.runtime.chaos")
-    if ch is not None:
-        ch.reset_for_tests()
-
-
-@pytest.fixture(autouse=True)
-def _no_leaked_health_monitors():
-    """Fail any test that leaves a HealthMonitor check task running past
-    teardown: a leaked monitor keeps reaping/draining state in the
-    background of every later test (imported lazily — the guard must not
-    drag runtime modules into tests that never touch them)."""
-    yield
-    import sys
-
-    health = sys.modules.get("dynamo_tpu.runtime.health")
-    if health is None:
-        return
-    leaked = health.live_monitors()
-    assert not leaked, (
-        f"{len(leaked)} HealthMonitor task(s) leaked past test teardown — "
-        f"stop() the monitor (or shutdown() its DistributedRuntime)"
+    assert not leaked_monitors, (
+        f"{len(leaked_monitors)} HealthMonitor task(s) leaked past test "
+        f"teardown — stop() the monitor (or shutdown() its "
+        f"DistributedRuntime)"
     )

@@ -30,6 +30,7 @@ import asyncio
 import concurrent.futures
 import json
 import os
+import time
 
 import pytest
 
@@ -51,6 +52,8 @@ from dynamo_tpu.runtime.chaos import (
     shrink_schedule,
 )
 from dynamo_tpu.runtime.faults import FaultInjector, FaultRule
+
+from .fixtures import engines_held_back
 
 
 # -- knobs + zero overhead -----------------------------------------------------
@@ -361,6 +364,40 @@ class TestInvariantSuite:
         assert got.count("conservation.pages") == 2  # blocks + live reqs
         assert "conservation.staged" in got
 
+    @pytest.mark.parametrize("stuck, wait_s", [
+        ("staged", 0.4),  # an unclaimed stage: waited out until swept
+        ("live", 0.1),    # a stream that never ends: judged at the bound
+    ])
+    def test_settle_waits_out_the_stage_sweep_only(self, stuck, wait_s):
+        """An unclaimed stage is freed by its target's TTL sweep, later
+        than the settle bound: the settle waits that out before the
+        conservation invariants may call it a leak — and nothing else."""
+
+        class Worker:
+            t0 = time.monotonic()
+
+            def _outstanding(self):
+                return int(time.monotonic() - self.t0 < 0.4)
+
+            def live_request_count(self):
+                return self._outstanding() if stuck == "live" else 0
+
+            def metrics_snapshot(self):
+                n = self._outstanding() if stuck == "staged" else 0
+                return {"kv_active_blocks": 6 * n, "migrate_staged": n}
+
+            def _migration_ttl(self):
+                return 5.0
+
+        runner = ChaosRunner(
+            ChaosSchedule(seed=1, n_workers=1, horizon=1.0, events=()),
+            settle_bound=0.1,
+        )
+        runner._engines = [Worker()]
+        t0 = time.monotonic()
+        asyncio.run(runner._settle())
+        assert wait_s <= time.monotonic() - t0 < wait_s + 0.3
+
     def test_ledger_equations_exact(self):
         # journal says 2 disruptions-followed, client ledger says 1: the
         # two ledgers over the same events MUST agree token-for-token
@@ -553,11 +590,13 @@ class TestDisabledIntegrityCaught:
         content-addressed prefix cache and would poison every later test
         that shares the fixture engines."""
         monkeypatch.setenv("DYN_TPU_KV_INTEGRITY", "0")
-        # seed 11 pinned: its drawn offset (14823, an exponent byte) is one
-        # the 28-token greedy continuation provably diverges on — smaller
-        # mantissa flips can be numerically invisible to argmax
+        # seed 23 pinned: its drawn offset (9499, an exponent byte of a
+        # layer-0 V page of the prompt) is one the greedy continuation
+        # diverges on from its first adopted token — most one-bit flips are
+        # numerically invisible to argmax (14 of 16 seeds tried; the
+        # once-pinned 11 among them since the model's numerics moved)
         sched = ChaosSchedule(
-            seed=11, n_workers=2, horizon=4.0,
+            seed=23, n_workers=2, horizon=4.0,
             events=(ChaosEvent(t=0.3, kind="corrupt", worker=0),
                     ChaosEvent(t=0.5, kind="drain", worker=0, duration=1.0)),
         )
@@ -576,11 +615,16 @@ class TestDisabledIntegrityCaught:
             ctx = Context(_payload(prompt, 28))
             gen = src.generate(ctx)
             got = []
-            async for item in gen:
-                got.extend((item.data or {}).get("token_ids", []))
-                if len(got) >= 4:
-                    break
-            cp = _call(src, src.export_migratable)[0]
+            # hold the source back while the test reads its 4 tokens: a
+            # free-running engine is frozen wherever the machine's load
+            # lets it get to, and how many tokens were computed on clean
+            # pages decides whether (and where) the flip shows
+            with engines_held_back(0.15):
+                async for item in gen:
+                    got.extend((item.data or {}).get("token_ids", []))
+                    if len(got) >= 4:
+                        break
+                cp = _call(src, src.export_migratable)[0]
             emitted = cp["token_ids"][len(prompt):]
             pages = _call(src, lambda: src.extract_for_migration(
                 cp["request_id"]
@@ -727,6 +771,9 @@ def _pair_schedules():
 
 @pytest.mark.chaos
 class TestPairwiseSmoke:
+    # nine paced schedules of 3 s each plus settle: 46 s on an idle machine,
+    # 62-86 s beside six other test files
+    @pytest.mark.timeout(300)
     def test_pairwise_matrix_zero_violations(self, chaos_engines):
         """ISSUE 19 acceptance: the fixed-seed pairwise matrix over the
         six headline kinds runs on 3 real tiny engines under 2x streaming

@@ -265,6 +265,25 @@ def test_queue_backpressure_falls_back_local():
     assert not policy2.should_remote(5)  # short → local
 
 
+def test_transfer_server_stop_returns_with_idle_peer_connected(run):
+    """A peer that keeps its transfer connection pooled (the migration
+    client does) must not hold the server's stop(): on Python 3.12 a bare
+    Server.wait_closed() waits until every accepted connection has gone."""
+    from dynamo_tpu.disagg.transfer import KvTransferServer
+
+    async def go():
+        server = KvTransferServer(engine=None, host="127.0.0.1", port=0)
+        await server.start()
+        _, writer = await asyncio.open_connection("127.0.0.1", server.port)
+        await asyncio.sleep(0.05)  # let the server accept it
+        try:
+            await asyncio.wait_for(server.stop(), 2.0)
+        finally:
+            writer.close()
+
+    run(go())
+
+
 def test_remote_prefill_request_roundtrip():
     req = RemotePrefillRequest(
         request_id="r1", engine_id="e1", token_ids=[1, 2, 3],

@@ -217,6 +217,9 @@ class TestMalformedFrames:
                 return
             writer.write(codec.encode(codec.TwoPartMessage(b"[1, 2, 3]", b"")))
             await writer.drain()
+            # the buggy server then hangs up; an unclosed writer would also
+            # hold Server.wait_closed() below for ever on Python 3.12
+            writer.close()
 
         async def go():
             server = await asyncio.start_server(fake_server, "127.0.0.1", 0)

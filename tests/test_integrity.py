@@ -53,6 +53,8 @@ from dynamo_tpu.runtime.integrity import (
 from dynamo_tpu.runtime.resilience import ResiliencePolicy
 from dynamo_tpu.runtime.statestore import StateStoreServer
 
+from .fixtures import engines_held_back
+
 NO_BUS = "127.0.0.1:1"
 
 
@@ -409,11 +411,12 @@ async def _freeze_mid_stream(engine, prompt, max_tokens, k):
     ctx = Context(_payload(prompt, max_tokens))
     gen = engine.generate(ctx)
     got = []
-    async for item in gen:
-        got.extend((item.data or {}).get("token_ids", []))
-        if len(got) >= k:
-            break
-    cps = _call(engine, engine.export_migratable)
+    with engines_held_back():
+        async for item in gen:
+            got.extend((item.data or {}).get("token_ids", []))
+            if len(got) >= k:
+                break
+        cps = _call(engine, engine.export_migratable)
     assert len(cps) == 1
     return cps[0], got, gen
 

@@ -45,6 +45,8 @@ from dynamo_tpu.runtime.faults import FaultInjector, FaultRule
 from dynamo_tpu.runtime.resilience import ResiliencePolicy, StreamJournal
 from dynamo_tpu.runtime.statestore import StateStoreServer
 
+from .fixtures import engines_held_back
+
 NO_BUS = "127.0.0.1:1"
 
 
@@ -184,11 +186,12 @@ async def _freeze_mid_stream(engine, prompt, max_tokens, k):
     ctx = Context(_payload(prompt, max_tokens))
     gen = engine.generate(ctx)
     got = []
-    async for item in gen:
-        got.extend((item.data or {}).get("token_ids", []))
-        if len(got) >= k:
-            break
-    cps = _call(engine, engine.export_migratable)
+    with engines_held_back():
+        async for item in gen:
+            got.extend((item.data or {}).get("token_ids", []))
+            if len(got) >= k:
+                break
+        cps = _call(engine, engine.export_migratable)
     assert len(cps) == 1, f"expected 1 migratable stream, got {len(cps)}"
     return cps[0], got, gen
 
@@ -281,11 +284,12 @@ class TestEngineStageAdopt:
             ctx = Context(dict(req))
             gen = src.generate(ctx)
             got = []
-            async for item in gen:
-                got.extend((item.data or {}).get("token_ids", []))
-                if len(got) >= 4:
-                    break
-            cp = _call(src, src.export_migratable)[0]
+            with engines_held_back():
+                async for item in gen:
+                    got.extend((item.data or {}).get("token_ids", []))
+                    if len(got) >= 4:
+                        break
+                cp = _call(src, src.export_migratable)[0]
             emitted = cp["token_ids"][len(prompt):]
             pages = _call(src, lambda: src.extract_for_migration(
                 cp["request_id"]

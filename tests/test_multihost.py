@@ -6,12 +6,9 @@ CLI uses (cli/run.py init_multihost), build a global 2-device mesh, and run
 a psum across hosts — proving process bring-up, cross-process device
 visibility, and a collective over the joined runtime."""
 
-import os
-import socket
-import subprocess
-import sys
-
 import pytest
+
+from .fixtures import run_ranks
 
 _WORKER = r"""
 import os, sys
@@ -47,29 +44,8 @@ print(f"OK rank {rank}")
 
 @pytest.mark.timeout(120)
 def test_two_process_distributed_bringup(tmp_path):
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    addr = f"127.0.0.1:{s.getsockname()[1]}"
-    s.close()
-
     script = tmp_path / "worker.py"
     script.write_text(_WORKER)
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["JAX_PLATFORMS"] = "cpu"
-    env.pop("XLA_FLAGS", None)
-
-    procs = [
-        subprocess.Popen(
-            [sys.executable, str(script), str(rank), addr],
-            env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-        )
-        for rank in (0, 1)
-    ]
-    outs = []
-    for p in procs:
-        out, _ = p.communicate(timeout=100)
-        outs.append(out.decode())
-    for rank, (p, out) in enumerate(zip(procs, outs)):
-        assert p.returncode == 0, f"rank {rank} failed:\n{out}"
+    outs = run_ranks(script, n_ranks=2, deadline_s=100)
+    for rank, out in enumerate(outs):
         assert f"OK rank {rank}" in out, out

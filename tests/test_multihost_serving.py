@@ -11,12 +11,10 @@ single-process engine on the same params.
 """
 
 import json
-import os
-import socket
-import subprocess
-import sys
 
 import pytest
+
+from .fixtures import run_ranks
 
 _WORKER = r"""
 import os, sys
@@ -180,31 +178,9 @@ def test_multihost_serving_matches_single_process(tmp_path):
     assert len(exp_lp_toks) == 6 and len(exp_lp_vals) == 6
 
     # two-process serve over the global mesh
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    addr = f"127.0.0.1:{s.getsockname()[1]}"
-    s.close()
-
     script = tmp_path / "serve_worker.py"
     script.write_text(_WORKER)
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["JAX_PLATFORMS"] = "cpu"
-    env.pop("XLA_FLAGS", None)
-
-    procs = [
-        subprocess.Popen(
-            [sys.executable, str(script), str(rank), addr],
-            env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-        )
-        for rank in (0, 1)
-    ]
-    outs = []
-    for p in procs:
-        out, _ = p.communicate(timeout=280)
-        outs.append(out.decode())
-    for rank, (p, out) in enumerate(zip(procs, outs)):
-        assert p.returncode == 0, f"rank {rank} failed:\n{out}"
+    outs = run_ranks(script, n_ranks=2, deadline_s=240)
     assert "FOLLOWER DONE" in outs[1], outs[1]
 
     line = next(l for l in outs[0].splitlines() if l.startswith("TOKENS "))
