@@ -79,6 +79,7 @@ from dynamo_tpu.models.llama import (
     lm_head,
     make_kv_cache,
     quantize_kv,
+    take_blocks,
 )
 from dynamo_tpu.engine_jax.compile_cache import compile_count, record_compile
 from dynamo_tpu.runtime import faults as faults_mod
@@ -927,8 +928,9 @@ class JaxServingEngine(AsyncEngine):
             # The decode scan is windowed in BOTH attention tiers: the pool is
             # READ-ONLY inside the scan; each step's K/V go to a [L, S, W]
             # window buffer riding the carry (models/llama.py forward_window),
-            # flushed to pages in ONE scatter per dispatch — per-step pool
-            # scatters cost more than the step's whole matmul work on TPU.
+            # flushed to pages after it by ONE in-place scatter per pool array
+            # (flush_window) — the dispatch never copies or slices the pool,
+            # so its cost follows the lanes, not the pool's size.
             # Only the history read differs (ops/attention.py
             # decode_uses_pallas): the jnp tier pre-gathers pages to a dense
             # buffer once per dispatch (per-step gathers lower to serialized
@@ -1138,8 +1140,8 @@ class JaxServingEngine(AsyncEngine):
                 )
             else:
                 # history/fresh split (models/llama.py forward_chunk): the
-                # page scatter runs off the attention critical path instead
-                # of serializing scatter -> gather -> einsum per layer
+                # layer loop only reads the pool; the layers' fresh K/V are
+                # written after it by one in-place scatter per pool array
                 h, cache = forward_chunk(
                     params, cfg, tokens, positions, cache, tables,
                     hidden_only=True, with_history=with_history,
@@ -3180,11 +3182,8 @@ class JaxServingEngine(AsyncEngine):
         arrays with ``as_device`` (same-host transfers keep pages on-device
         and let XLA reshard at the destination's inject boundary).
         MUST run on the engine thread (e.g. via post())."""
-        idx = jnp.asarray(block_ids, jnp.int32)
-        arrs = [self.cache["k"][:, idx], self.cache["v"][:, idx]]
-        if self._kv_quantized:
-            arrs.append(self.cache["k_scale"][:, idx])
-            arrs.append(self.cache["v_scale"][:, idx])
+        taken = take_blocks(self.cache, jnp.asarray(block_ids, jnp.int32))
+        arrs = [taken[name] for name in ("k", "v", "k_scale", "v_scale") if name in taken]
         if as_device:
             out = list(arrs)
         else:
@@ -3695,18 +3694,15 @@ class JaxServingEngine(AsyncEngine):
         harvested by :meth:`_harvest_spills` once ready. ``pairs`` entries
         are ``(hash, block_id, crc)`` — the seal-time content checksum
         rides into the host tier with its block (None with integrity off)."""
-        idx = jnp.asarray([bid for _, bid, _ in pairs], jnp.int32)
-        k = self.cache["k"][:, idx]
-        v = self.cache["v"][:, idx]
-        k.copy_to_host_async()
-        v.copy_to_host_async()
-        ks = vs = None
-        if self._kv_quantized:
-            ks = self.cache["k_scale"][:, idx]
-            vs = self.cache["v_scale"][:, idx]
-            ks.copy_to_host_async()
-            vs.copy_to_host_async()
-        self._pending_spills.append((pairs, k, v, ks, vs))
+        taken = take_blocks(
+            self.cache, jnp.asarray([bid for _, bid, _ in pairs], jnp.int32)
+        )
+        for a in taken.values():
+            a.copy_to_host_async()
+        self._pending_spills.append((
+            pairs, taken["k"], taken["v"],
+            taken.get("k_scale"), taken.get("v_scale"),
+        ))
 
     def _harvest_spills(self, force: bool = False) -> None:
         """Move completed async spills into the host pool (engine thread).

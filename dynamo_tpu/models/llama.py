@@ -531,6 +531,28 @@ def _window_attention(
     return out.reshape(b, 1, h_, d).astype(q.dtype)
 
 
+def _pool_pages(kv_cache: KVCache) -> KVCache:
+    """The pool's arrays as ``[L * N, bs, ...]`` views: page p of layer l is row
+    ``l * N + p``. The step programs read the pool through ONE such row index;
+    a slice by layer, or an index on the block axis alone
+    (``pool[:, block_tables]``), makes the TPU compiler copy pool-sized buffers
+    before it gathers. Take the views OUTSIDE a loop that gathers from them:
+    reshaped inside, the pool rides the loop in a layout of the compiler's
+    choosing, behind a whole-pool copy."""
+    return {name: a.reshape(-1, *a.shape[2:]) for name, a in kv_cache.items()}
+
+
+@jax.jit
+def take_blocks(kv_cache: KVCache, block_ids: jax.Array) -> KVCache:
+    """Copy blocks ``block_ids`` [n] of every layer out of the pool:
+    ``pool[:, block_ids]`` ([L, n, bs, ...]) of each of its arrays, read through
+    :func:`_pool_pages` — indexed on the block axis alone, the eager gather
+    copied the whole pool into another layout first."""
+    l, n = kv_cache["k"].shape[:2]
+    rows = jnp.arange(l)[:, None] * n + block_ids
+    return {name: a[rows] for name, a in _pool_pages(kv_cache).items()}
+
+
 def gather_history(
     kv_cache: KVCache, block_tables: jax.Array, out_dtype: Any = None
 ) -> Tuple[jax.Array, jax.Array]:
@@ -541,18 +563,25 @@ def gather_history(
     ``out_dtype``): the HBM read of the gather — the decode-roofline half
     that int8 KV halves — moves int8 bytes; the dequantized dense buffer is
     the transient working set the in-scan einsums already needed."""
-    l, _, bs = kv_cache["k"].shape[:3]
-    b, mb = block_tables.shape
-    hk = kv_cache["k"][:, block_tables]  # [L, B, MB, bs, KVH, D]
-    hv = kv_cache["v"][:, block_tables]
-    shape = (l, b, mb * bs) + hk.shape[4:]
-    if kv_cache_quantized(kv_cache):
-        dt = out_dtype or jnp.bfloat16
-        ks = kv_cache["k_scale"][:, block_tables]  # [L, B, MB, bs]
-        vs = kv_cache["v_scale"][:, block_tables]
-        hk = dequantize_kv(hk, ks, dt)
-        hv = dequantize_kv(hv, vs, dt)
-    return hk.reshape(shape), hv.reshape(shape)
+    from dynamo_tpu.ops.attention import gather_pages
+
+    l, n = kv_cache["k"].shape[:2]
+    pages = _pool_pages(kv_cache)
+    quantized = kv_cache_quantized(kv_cache)
+    dt = out_dtype or jnp.bfloat16
+
+    def layer_history(_, layer):
+        rows = layer * n + block_tables
+        hk, hv = gather_pages(pages["k"], rows), gather_pages(pages["v"], rows)
+        if quantized:
+            hk = dequantize_kv(hk, gather_pages(pages["k_scale"], rows), dt)
+            hv = dequantize_kv(hv, gather_pages(pages["v_scale"], rows), dt)
+        return None, (hk, hv)
+
+    # a loop over layers, not one gather of every layer's pages: the pool views
+    # ride the loop as they are, where the one gather would have the compiler
+    # copy the whole pool into the layout the attention wants of the history
+    return jax.lax.scan(layer_history, None, jnp.arange(l))[1]
 
 
 def _window_only_attention(
@@ -785,59 +814,43 @@ def forward_chunk(
     layer's critical path. Here attention = flash-merge of a pool-history
     partial (pages < each lane's chunk start — by construction everything
     already flushed) with an in-chunk causal partial over the fresh K/V in
-    hand, so the page scatter (still needed for later chunks/decode) runs
-    OFF the critical path, concurrent with the attention math.
+    hand. The pool is READ-ONLY inside the layer loop and never sliced by
+    layer (the history gather indexes the whole pool, layer included); the
+    layers' fresh K/V leave the loop stacked and ONE in-place scatter writes
+    them after it (:func:`write_kv_to_pool`), so the dispatch costs what its
+    lanes touch, whatever the pool's size.
 
     ``with_history=False`` compiles out the pool gather + history partial
     entirely — the caller guarantees every lane starts at position 0 (a
     fresh admission wave's first chunk, THE TTFT-critical dispatch; the
     masked-out history partial still materializes layer-sized f32 score
     buffers, ~20 ms of a ~100 ms chunk at serving scale on v5e)."""
-    from dynamo_tpu.ops.attention import gather_pages, write_kv_to_pages
+    from dynamo_tpu.ops.attention import gather_pages, write_kv_to_pool
 
     c = config
     scale = c.head_dim ** -0.5
     h = embed_lookup(params, tokens, c.dtype)  # [B, C, E]
     chunk_start = jnp.where(positions[:, 0] >= 0, positions[:, 0], 0)  # [B]
     quantized = kv_cache_quantized(kv_cache)
+    num_blocks = kv_cache["k"].shape[1]
+    pages = _pool_pages(kv_cache)
 
-    def layer_body(carry, xs):
-        if quantized:
-            lp, k_page, v_page, ks_page, vs_page = xs
-        else:
-            lp, k_page, v_page = xs
-        hidden = carry
+    def layer_body(hidden, xs):
+        lp, layer = xs
         b, t = positions.shape
 
         q, k, v = project_qkv(lp, c, hidden, positions)
-        if quantized:
-            # the chunk's fresh K/V quantize per token before the scatter;
-            # the in-chunk causal partial below still attends the exact
-            # pre-quantization values (they're in hand — no reason to round)
-            kq, vq, kss, vss = quantize_kv(k, v)
-            new_k, new_v = write_kv_to_pages(
-                k_page, v_page, kq, vq, positions, block_tables
-            )
-            new_ks, new_vs = write_kv_to_pages(
-                ks_page, vs_page, kss, vss, positions, block_tables
-            )
-        else:
-            new_k, new_v = write_kv_to_pages(
-                k_page, v_page, k, v, positions, block_tables
-            )
         num_s, m_s, l_s = _chunk_self_partial(c, q, k, v, positions, scale)
         if with_history:
-            # history partial reads the PRE-SCATTER pool: masked to
-            # < chunk_start, those pages are identical either way, and using
-            # the old buffers keeps the gather independent of the scatter
-            gk = gather_pages(k_page, block_tables)
-            gv = gather_pages(v_page, block_tables)
+            rows = layer * num_blocks + block_tables
+            gk = gather_pages(pages["k"], rows)
+            gv = gather_pages(pages["v"], rows)
             if quantized:
                 # dequant on the GATHERED lanes only (O(context), never
                 # O(pool)); gather_pages is trailing-dim agnostic so the
-                # [N, bs] scale tables gather like [B, Smax] vectors
-                gks = gather_pages(ks_page, block_tables)
-                gvs = gather_pages(vs_page, block_tables)
+                # [L * N, bs] scale tables gather like [B, Smax] vectors
+                gks = gather_pages(pages["k_scale"], rows)
+                gvs = gather_pages(pages["v_scale"], rows)
                 gk = dequantize_kv(gk, gks, hidden.dtype)
                 gv = dequantize_kv(gv, gvs, hidden.dtype)
             num_h, m_h, l_h = _history_partial(
@@ -861,23 +874,18 @@ def forward_chunk(
         ).astype(hidden.dtype)
 
         hidden = hidden + matw(attn.reshape(b, t, c.q_dim), lp["wo"])
-        out = mlp_block(lp, c, hidden, positions)
-        if quantized:
-            return out, (new_k, new_v, new_ks, new_vs)
-        return out, (new_k, new_v)
+        # the fresh K/V quantize per token on their way to the pages; the
+        # in-chunk causal partial above attended the exact values in hand
+        fresh = quantize_kv(k, v) if quantized else (k, v)
+        return mlp_block(lp, c, hidden, positions), fresh
 
-    if quantized:
-        h, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(
-            layer_body, h,
-            (params["layers"], kv_cache["k"], kv_cache["v"],
-             kv_cache["k_scale"], kv_cache["v_scale"]),
-        )
-        cache = {"k": new_k, "v": new_v, "k_scale": new_ks, "v_scale": new_vs}
-    else:
-        h, (new_k, new_v) = jax.lax.scan(
-            layer_body, h, (params["layers"], kv_cache["k"], kv_cache["v"])
-        )
-        cache = {"k": new_k, "v": new_v}
+    h, fresh = jax.lax.scan(
+        layer_body, h, (params["layers"], jnp.arange(c.num_layers))
+    )
+    cache = {
+        name: write_kv_to_pool(kv_cache[name], new, positions, block_tables)
+        for name, new in zip(("k", "v", "k_scale", "v_scale"), fresh)
+    }
     h = rms_norm(h, params["final_norm"], c.rms_norm_eps)
     if hidden_only:
         return h, cache
@@ -980,48 +988,24 @@ def flush_window(
     max_pos: int,
 ) -> KVCache:
     """Scatter a decode dispatch's window buffer into the paged pool — ONE
-    scatter per layer per dispatch instead of one per layer per step. Lanes
-    that were padding (base < 0) or ran past ``max_pos`` mid-dispatch get
-    position −1, which :func:`write_kv_to_pages` drops."""
-    from dynamo_tpu.ops.attention import write_kv_to_pages
+    in-place scatter per pool array per dispatch, every layer at once
+    (:func:`write_kv_to_pool`). Lanes that were padding (base < 0) or ran
+    past ``max_pos`` mid-dispatch get position −1, which the scatter drops.
+    An int8 pool quantizes the window once (per-token scales); values and
+    scale tables take the same scatter."""
+    from dynamo_tpu.ops.attention import write_kv_to_pool
 
     w = window_k.shape[2]
     fpos = base[:, None] + jnp.arange(w)[None, :]  # [B, W]
     valid = (base[:, None] >= 0) & (fpos <= max_pos)
     fpos = jnp.where(valid, fpos, -1)
-
+    fresh = (window_k, window_v)
     if kv_cache_quantized(kv_cache):
-        # quantize the whole window once (per-token scales), then scatter
-        # values and scales with the same index math — write_kv_to_pages is
-        # trailing-dim agnostic, so the [L, N, bs] scale tables ride the
-        # [B, W] scale vectors through the identical drop-masked scatter
-        wkq, wvq, wks, wvs = quantize_kv(window_k, window_v)
-
-        def layer_flush_q(carry, xs):
-            kl, vl, ksl, vsl, wkl, wvl, wksl, wvsl = xs
-            kl, vl = write_kv_to_pages(kl, vl, wkl, wvl, fpos, block_tables)
-            ksl, vsl = write_kv_to_pages(
-                ksl, vsl, wksl, wvsl, fpos, block_tables
-            )
-            return carry, (kl, vl, ksl, vsl)
-
-        _, (nk, nv, nks, nvs) = jax.lax.scan(
-            layer_flush_q, 0,
-            (kv_cache["k"], kv_cache["v"], kv_cache["k_scale"],
-             kv_cache["v_scale"], wkq, wvq, wks, wvs),
-        )
-        return {"k": nk, "v": nv, "k_scale": nks, "v_scale": nvs}
-
-    def layer_flush(carry, xs):
-        kl, vl, wkl, wvl = xs
-        kl, vl = write_kv_to_pages(kl, vl, wkl, wvl, fpos, block_tables)
-        return carry, (kl, vl)
-
-    _, (nk, nv) = jax.lax.scan(
-        layer_flush, 0,
-        (kv_cache["k"], kv_cache["v"], window_k, window_v),
-    )
-    return {"k": nk, "v": nv}
+        fresh = quantize_kv(window_k, window_v)
+    return {
+        name: write_kv_to_pool(kv_cache[name], new, fpos, block_tables)
+        for name, new in zip(("k", "v", "k_scale", "v_scale"), fresh)
+    }
 
 
 def forward(

@@ -197,6 +197,29 @@ def _tp_divisible(mesh, h: int, kvh: int) -> bool:
     return h % tp == 0 and kvh % tp == 0
 
 
+def _page_rows(
+    positions: jax.Array,  # [B, T] absolute position in sequence; < 0 = padding
+    block_tables: jax.Array,  # [B, max_blocks] physical page ids
+    num_blocks: int,
+    block_size: int,
+) -> jax.Array:
+    """Row of each position in a ``[num_blocks * block_size, ...]`` view of one
+    layer's pages ([B, T] int32). Padding and out-of-table positions get the
+    out-of-range row ``num_blocks * block_size``, which a ``mode="drop"``
+    scatter drops; without it XLA's clamping would silently write into the
+    wrong physical page."""
+    max_blocks = block_tables.shape[1]
+    logical_block = positions // block_size
+    phys = jnp.take_along_axis(
+        block_tables, jnp.clip(logical_block, 0, max_blocks - 1), axis=1
+    )
+    valid = (positions >= 0) & (logical_block < max_blocks)
+    return jnp.where(
+        valid, phys * block_size + positions % block_size,
+        num_blocks * block_size,
+    )
+
+
 def write_kv_to_pages(
     k_cache: jax.Array,  # [num_blocks, block_size, KVH, D]
     v_cache: jax.Array,
@@ -205,32 +228,51 @@ def write_kv_to_pages(
     positions: jax.Array,  # [B, T] absolute position in sequence; < 0 = padding
     block_tables: jax.Array,  # [B, max_blocks] physical page ids
 ) -> Tuple[jax.Array, jax.Array]:
-    """Scatter new K/V vectors into their pages; padding positions are dropped."""
-    num_blocks, block_size = k_cache.shape[0], k_cache.shape[1]
-    b, t = positions.shape
-    max_blocks = block_tables.shape[1]
+    """Scatter new K/V vectors into ONE layer's pages; padding positions are
+    dropped."""
+    num_blocks, block_size = k_cache.shape[:2]
+    rows = _page_rows(positions, block_tables, num_blocks, block_size).reshape(-1)
 
-    logical_block = positions // block_size  # [B, T]
-    slot = positions % block_size
-    phys = jnp.take_along_axis(
-        block_tables, jnp.clip(logical_block, 0, max_blocks - 1), axis=1
-    )  # [B, T]
-    flat_idx = phys * block_size + slot
-    # padding or out-of-table positions → out-of-range index, dropped by the
-    # scatter (mode="drop"); without this, XLA's clamping would silently write
-    # into the wrong physical page
-    valid = (positions >= 0) & (logical_block < max_blocks)
-    flat_idx = jnp.where(valid, flat_idx, num_blocks * block_size)
+    def put(cache, new):
+        flat = cache.reshape(num_blocks * block_size, *cache.shape[2:])
+        flat = flat.at[rows].set(
+            new.reshape(rows.shape[0], *new.shape[2:]), mode="drop"
+        )
+        return flat.reshape(cache.shape)
 
-    flat_k = k_cache.reshape(num_blocks * block_size, *k_cache.shape[2:])
-    flat_v = v_cache.reshape(num_blocks * block_size, *v_cache.shape[2:])
-    flat_k = flat_k.at[flat_idx.reshape(-1)].set(
-        k_new.reshape(b * t, *k_new.shape[2:]), mode="drop"
+    return put(k_cache, k_new), put(v_cache, v_new)
+
+
+def write_kv_to_pool(
+    pool: jax.Array,  # [L, num_blocks, block_size, ...] one array of the pool
+    new: jax.Array,  # [L, B, T, ...] every layer's fresh rows
+    positions: jax.Array,  # [B, T] absolute position in sequence; < 0 = padding
+    block_tables: jax.Array,  # [B, max_blocks] physical page ids
+) -> jax.Array:
+    """Scatter every layer's fresh rows into the whole pool at once; padding
+    and out-of-table positions are dropped as in :func:`write_kv_to_pages`.
+    Trailing-dim agnostic: K/V pages and the int8 pool's ``[L, N, bs]`` scale
+    tables take the same call.
+
+    The ONE flat index over ``[L * num_blocks * block_size, ...]`` is the
+    point: on a donated pool XLA compiles it to a bare in-place scatter, so a
+    step program's cost follows the rows it writes and not the pool's size. An
+    index per axis (``pool.at[:, rows]``) makes the TPU compiler copy the
+    whole pool into another layout and back
+    (tests/test_aot_compile_tpu.py holds the compiled program to this)."""
+    n_layers, num_blocks, block_size = pool.shape[:3]
+    per_layer = num_blocks * block_size
+    rows = _page_rows(positions, block_tables, num_blocks, block_size).reshape(-1)
+    idx = jnp.where(
+        rows < per_layer,
+        jnp.arange(n_layers)[:, None] * per_layer + rows,
+        n_layers * per_layer,
+    ).reshape(-1)
+    flat = pool.reshape(n_layers * per_layer, *pool.shape[3:])
+    flat = flat.at[idx].set(
+        new.reshape(idx.shape[0], *new.shape[3:]), mode="drop"
     )
-    flat_v = flat_v.at[flat_idx.reshape(-1)].set(
-        v_new.reshape(b * t, *v_new.shape[2:]), mode="drop"
-    )
-    return flat_k.reshape(k_cache.shape), flat_v.reshape(v_cache.shape)
+    return flat.reshape(pool.shape)
 
 
 def gather_pages(

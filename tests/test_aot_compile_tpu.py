@@ -1,5 +1,5 @@
-"""The main path's Pallas decode kernels, compiled for the chip without the
-chip: the TPU compiler installed beside JAX compiles for a *described*
+"""The main path's Pallas decode kernels and the step programs' KV-pool
+handling, compiled for the chip without the chip: the TPU compiler installed beside JAX compiles for a *described*
 ``v5e:2x2`` (on-chip-measurement guide §2, third rehearsal). Interpret-mode
 tests cannot see what it refuses — slices not aligned to the tiling, too much
 scoped VMEM — so these few compiles guard every later PR at no chip time.
@@ -12,11 +12,16 @@ fixtures or tests, and all such tests live in THIS file so one xdist worker
 owns them.
 """
 
+import dataclasses
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from dynamo_tpu.models import llama
 from dynamo_tpu.ops.attention import _v2_supported, decode_schedule
 from dynamo_tpu.ops.pallas import paged_attention as pk
 
@@ -127,3 +132,249 @@ def test_v2_alignment_rule():
     assert [k for k in (1, 2, 3, 4, 5, 6, 8, 12, 16, 24, 32)
             if _v2_supported(128, k)] == [2, 4, 8, 16, 24, 32]
     assert _v2_supported(128, 1, itemsize=4)
+
+
+# -- the step programs never copy the KV pool --------------------------------
+#
+# forward_chunk and flush_window (models/llama.py) take the pool donated, read
+# it by index and write it with one flat scatter (ops/attention.py
+# write_kv_to_pool). The compiled HLO, not the Python, is the specification:
+# an index per axis, or the pool as scan xs -> ys, compiles to whole-pool
+# copies that cost every dispatch 15 ms per GB of pool on the v5e (PERF.md).
+
+POOL_BLOCKS = (49157, 12289)  # primes: no activation shares their element count
+INT8_POOL_BLOCKS = (12289, 6143)  # the int8 programs compile slowly above these
+LANES, CHUNK, TABLE, WINDOW, MAX_POS = 32, 128, 128, 4, 2047
+POOL_LAYERS = 4  # the layer loop is one scan: its HLO does not depend on depth
+# ops that may yield something pool-sized: views, plumbing, the in-place write
+POOL_OPS_ALLOWED = {"parameter", "get-tuple-element", "bitcast", "tuple", "scatter"}
+
+
+def _chunk(params, cache, cfg, tokens, positions, tables, *, with_history):
+    return llama.forward_chunk(
+        params, cfg, tokens, positions, cache, tables,
+        hidden_only=True, with_history=with_history,
+    )
+
+
+def _flush(params, cache, cfg, tokens, positions, tables):
+    w = (cfg.num_layers, LANES, WINDOW, cfg.num_kv_heads, cfg.head_dim)
+    wk = wv = jnp.zeros(w, cfg.dtype)
+    return llama.flush_window(cache, tables, positions[:, 0], wk, wv, MAX_POS)
+
+
+def _decode(params, cache, cfg, tokens, positions, tables):
+    """The engine's dense-tier decode dispatch (engine.py _build_decode_fn,
+    greedy): history gathered once, WINDOW steps, one flush. What attends the
+    history decides the layout the compiler wants of it — and, given the
+    chance, of the whole pool — so the steps are the real ones."""
+    base = positions[:, 0]
+    hk, hv = llama.gather_history(cache, tables, out_dtype=cfg.dtype)
+    w = (cfg.num_layers, LANES, WINDOW, cfg.num_kv_heads, cfg.head_dim)
+
+    def step(carry, k):
+        toks, wk, wv = carry
+        logits, wk, wv = llama.forward_window(
+            params, cfg, toks, base + k, ("dense", hk, hv), base, wk, wv, k
+        )
+        return (jnp.argmax(logits, -1).astype(jnp.int32), wk, wv), None
+
+    zeros = jnp.zeros(w, cfg.dtype)
+    (toks, wk, wv), _ = jax.lax.scan(
+        step, (tokens[:, 0], zeros, zeros), jnp.arange(WINDOW)
+    )
+    return toks, llama.flush_window(cache, tables, base, wk, wv, MAX_POS)
+
+
+POOL_PROGRAMS = {
+    "chunk": (_chunk, dict(with_history=True)),
+    "chunk_first": (_chunk, dict(with_history=False)),
+    "flush": (_flush, {}),
+    "decode": (_decode, {}),
+}
+
+
+def _compile_pool_program(program, cfg, num_blocks, quantized, mesh, one_chip):
+    fn, kw = POOL_PROGRAMS[program]
+    params = jax.eval_shape(lambda: llama.init_params(jax.random.PRNGKey(0), cfg))
+    cache = jax.eval_shape(
+        lambda: llama.make_kv_cache(cfg, num_blocks, BS, quantized=quantized)
+    )
+    if mesh is None:
+        rep = one_chip
+        param_sh = jax.tree.map(lambda _: one_chip, params)
+        cache_sh = jax.tree.map(lambda _: one_chip, cache)
+    else:
+        from dynamo_tpu.parallel.mesh import kv_cache_sharding
+
+        rep = NamedSharding(mesh, P())
+        param_sh = llama.param_shardings(cfg, mesh)
+        cache_sh = jax.tree.map(lambda _: kv_cache_sharding(mesh), cache)
+
+    def shaped(tree, shardings):
+        return jax.tree.map(
+            lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+            tree, shardings,
+        )
+
+    ints = [
+        jax.ShapeDtypeStruct(shape, jnp.int32, sharding=rep)
+        for shape in ((LANES, CHUNK), (LANES, CHUNK), (LANES, TABLE))
+    ]
+    jitted = jax.jit(
+        lambda params, cache, *a: fn(params, cache, cfg, *a, **kw),
+        donate_argnums=(1,),
+    )
+    return jitted.lower(
+        shaped(params, param_sh), shaped(cache, cache_sh), *ints
+    ).compile()
+
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(")
+_ARRAY = re.compile(r"\w+\[([\d,]*)\]")
+
+
+def _pool_sized_instructions(hlo: str, sizes: set) -> list:
+    """(opcode, name) of every instruction with an output of one of ``sizes``
+    elements, in whatever layout or view, that is not in POOL_OPS_ALLOWED. A
+    fusion counts as the scatter it holds when its root is that scatter; a
+    ``while`` may carry a pool-sized element through unchanged (the chunk
+    program's layer loop reads the pool), never a changed one."""
+    def matches(shape: str) -> list:
+        return [
+            i for i, dims in enumerate(_ARRAY.findall(shape))
+            if math.prod(int(d) for d in dims.split(",") if d) in sizes
+        ]
+
+    computations, name = {}, None
+    for line in re.sub(r"/\*.*?\*/", "", hlo).splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            computations[name] = []
+        elif name is not None and line.strip() != "}":
+            computations[name].append(line)
+
+    def root(computation: str) -> str:
+        return next(l for l in computations[computation] if "ROOT " in l)
+
+    found = []
+    for lines in computations.values():
+        for line in lines:
+            m = _INSTRUCTION.match(line)
+            if not m:
+                continue
+            inst, shape, op = m.groups()
+            hit = matches(shape)
+            if not hit or op in POOL_OPS_ALLOWED:
+                continue
+            if op == "fusion":
+                called = re.search(r"calls=%?([\w.\-]+)", line).group(1)
+                if _INSTRUCTION.match(root(called)).group(3) == "scatter":
+                    continue
+            if op == "while":
+                body = re.search(r"body=%?([\w.\-]+)", line).group(1)
+                operands = re.search(r"tuple\((.*?)\)", root(body)).group(1)
+                operands = [o.strip().lstrip("%") for o in operands.split(",")]
+                passed_through = all(
+                    any(
+                        re.search(
+                            rf"%?{re.escape(operands[i])} = .* get-tuple-element\("
+                            rf".*index={i}(?:,|$)", l,
+                        )
+                        for l in computations[body]
+                    )
+                    for i in hit
+                )
+                if passed_through:
+                    continue
+            found.append((op, inst))
+    return found
+
+
+@pytest.mark.parametrize("layout", ["bf16", "bf16_tp4", "int8"])
+@pytest.mark.parametrize("program", list(POOL_PROGRAMS))
+def test_step_programs_never_copy_the_kv_pool(request, one_chip, program, layout):
+    """At two pool sizes, for the described v5e: nothing the size of a pool
+    array or of one layer's slice of it comes out of any op but views and the
+    in-place scatter, the donated pool aliases the output in full, and the
+    program's temporary memory does not grow with the pool.
+
+    Qwen2.5-1.5B's widths on one chip; Qwen2.5-7B's where the layout needs its
+    four KV heads — tp=4 (a head per shard), and the int8 pool: the compiler
+    stores ``s8[L, N, 16, 2, 128]`` slot-major within a page
+    (``{4,2,3,1,0:T(8,128)(4,1)}``) and relays the whole pool around any write
+    by token, before this write path and after it (PERF.md section 7), while
+    with four heads the int8 pages stay put. The int8 pool's ``[L, N, bs]``
+    scale tables (1/128 of its bytes) are held to the alias only, and the int8
+    programs to no bound on their temporary memory: the compiler stores the
+    tables block-minor and relays them whole to gather a page's scales."""
+    mesh = request.getfixturevalue("tp4_mesh") if layout == "bf16_tp4" else None
+    cfg = dataclasses.replace(
+        llama.LLAMA_PRESETS["qwen2.5-1.5b" if layout == "bf16" else "qwen2.5-7b"],
+        num_layers=POOL_LAYERS,
+    )
+    shards = 1 if mesh is None else mesh.shape["tp"]
+    quantized = layout == "int8"
+    blocks = INT8_POOL_BLOCKS if quantized else POOL_BLOCKS
+    temps = []
+    for num_blocks in blocks:
+        compiled = _compile_pool_program(
+            program, cfg, num_blocks, quantized, mesh, one_chip
+        )
+        pool = jax.eval_shape(
+            lambda: llama.make_kv_cache(cfg, num_blocks, BS, quantized=quantized)
+        )
+        pages = pool["k"].size // shards
+        assert _pool_sized_instructions(
+            compiled.as_text(), {pages, pages // cfg.num_layers}
+        ) == []
+        memory = compiled.memory_analysis()
+        pool_bytes = sum(a.size * a.dtype.itemsize for a in pool.values()) // shards
+        # the scale tables' tiled layout pads them by a few KB
+        assert pool_bytes <= memory.alias_size_in_bytes < pool_bytes * 1.001
+        temps.append(memory.temp_size_in_bytes)
+    if quantized:
+        return
+    # the assertion that cannot be fooled: a temporary the size of the pages,
+    # or of one layer's slice of them, grows by that slice of the added blocks
+    # or more; where the compiler places lane-sized buffers moves the figure by
+    # up to 25 MB either way
+    added_slice = (
+        (blocks[0] - blocks[1]) * BS * cfg.num_kv_heads * cfg.head_dim
+        * pool["k"].dtype.itemsize // shards
+    )
+    assert temps[0] - temps[1] < added_slice // 4, temps
+
+
+@pytest.mark.parametrize("layout", ["bf16", "bf16_tp4"])
+def test_taking_blocks_out_never_copies_the_kv_pool(request, one_chip, layout):
+    """take_blocks (host-tier spills and KV transfers, between dispatches):
+    the eager ``pool[:, block_ids]`` it replaces copied the whole pool into
+    another layout to gather two dozen blocks."""
+    mesh = request.getfixturevalue("tp4_mesh") if layout == "bf16_tp4" else None
+    cfg = dataclasses.replace(
+        llama.LLAMA_PRESETS["qwen2.5-1.5b" if mesh is None else "qwen2.5-7b"],
+        num_layers=POOL_LAYERS,
+    )
+    if mesh is None:
+        rep = cache_sh = one_chip
+    else:
+        from dynamo_tpu.parallel.mesh import kv_cache_sharding
+
+        rep, cache_sh = NamedSharding(mesh, P()), kv_cache_sharding(mesh)
+    temps = []
+    for num_blocks in POOL_BLOCKS:
+        pool = jax.eval_shape(lambda: llama.make_kv_cache(cfg, num_blocks, BS))
+        compiled = llama.take_blocks.lower(
+            jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=cache_sh), pool
+            ),
+            jax.ShapeDtypeStruct((24,), jnp.int32, sharding=rep),
+        ).compile()
+        pages = pool["k"].size // (1 if mesh is None else mesh.shape["tp"])
+        assert _pool_sized_instructions(
+            compiled.as_text(), {pages, pages // cfg.num_layers}
+        ) == []
+        temps.append(compiled.memory_analysis().temp_size_in_bytes)
+    assert temps[0] == temps[1]

@@ -8,12 +8,24 @@ import pytest
 
 from dynamo_tpu.models.llama import (
     LLAMA_PRESETS,
+    dequantize_kv,
+    flush_window,
     forward,
+    forward_chunk,
+    forward_window,
+    gather_history,
     init_params,
     make_kv_cache,
     param_shardings,
+    quantize_kv,
+    take_blocks,
 )
-from dynamo_tpu.ops.attention import gather_pages, paged_attention, write_kv_to_pages
+from dynamo_tpu.ops.attention import (
+    gather_pages,
+    paged_attention,
+    write_kv_to_pages,
+    write_kv_to_pool,
+)
 from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
 
 import dataclasses
@@ -161,3 +173,261 @@ def test_tp_sharded_forward_matches_single_device(tiny_model):
     )
     np.testing.assert_allclose(sharded[0], ref_logits, rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(sharded[1]["k"], ref_cache["k"], rtol=1e-5, atol=1e-5)
+
+
+# -- the step programs' pool handling vs the plain forward path --------------
+#
+# forward_chunk and the decode dispatch (gather_history -> forward_window x W
+# -> flush_window) read the pool by (layer, page) and write it with one
+# scatter; `forward` writes layer by layer (write_kv_to_pages). Same rows, same
+# values, nothing else touched.
+
+LANES, MB, N_BLOCKS, CHUNK, WINDOW = 3, 3, 10, 6, 4  # a table holds 24 positions
+
+# per case: each lane's start (history length; -1 = padding lane), how many of
+# its chunk positions are real, its block table, and the decode's max_pos
+POOL_CASES = {
+    # lane 1 is padding throughout; lane 2's chunk is part padding
+    "padding_lanes": dict(
+        start=[5, -1, 0], valid=[6, 0, 4],
+        tables=[[1, 2, 3], [0, 0, 0], [4, 5, 6]], max_pos=23),
+    # lane 0's window runs past max_pos mid-dispatch (decode only)
+    "past_max_pos": dict(
+        start=[18, 3, 9], valid=[6, 6, 6],
+        tables=[[1, 2, 3], [4, 5, 6], [7, 8, 9]], max_pos=19),
+    # lane 0's positions run past the 24 its table holds
+    "past_table": dict(
+        start=[21, 3, 9], valid=[6, 6, 6],
+        tables=[[1, 2, 3], [4, 5, 6], [7, 8, 9]], max_pos=100),
+    # lanes 0 and 1 share the page of their common 8-token prefix
+    "shared_prefix": dict(
+        start=[8, 8, 2], valid=[6, 6, 6],
+        tables=[[1, 2, 3], [1, 4, 5], [7, 8, 9]], max_pos=23),
+    # every lane at position 0: the history partial is compiled out (chunk only)
+    "first_chunk": dict(
+        start=[0, -1, 0], valid=[6, 0, 3],
+        tables=[[1, 2, 3], [0, 0, 0], [4, 5, 6]], max_pos=23),
+}
+
+
+# one compile per program for all cases (eager, every call compiles its scan anew)
+_forward = jax.jit(lambda p, tk, ps, c, bt: forward(p, CFG, tk, ps, c, bt))
+_forward_chunk = jax.jit(
+    lambda p, tk, ps, c, bt, with_history=True: forward_chunk(
+        p, CFG, tk, ps, c, bt, with_history=with_history),
+    static_argnames="with_history",
+)
+_forward_window = jax.jit(
+    lambda p, tk, ps, hk, hv, base, wk, wv, k: forward_window(
+        p, CFG, tk, ps, ("dense", hk, hv), base, wk, wv, k)
+)
+_gather_history = jax.jit(lambda c, bt: gather_history(c, bt, out_dtype=jnp.float32))
+_flush_window = jax.jit(flush_window, static_argnames="max_pos")
+
+
+def _case(name):
+    c = POOL_CASES[name]
+    start = np.array(c["start"])
+    return start, np.array(c["valid"]), jnp.array(c["tables"], jnp.int32), c["max_pos"]
+
+
+def _sentinel_pool(quantized=False):
+    """A pool with something in every row, so a row written by mistake shows."""
+    cache = make_kv_cache(CFG, N_BLOCKS, BLOCK, dtype=jnp.float32, quantized=quantized)
+    key = jax.random.PRNGKey(7)
+    out = {}
+    for i, (name, a) in enumerate(sorted(cache.items())):
+        r = jax.random.normal(jax.random.fold_in(key, i), a.shape)
+        out[name] = (r * 40).astype(a.dtype) if a.dtype == jnp.int8 else jnp.abs(r) + 0.5
+    return out
+
+
+def _with_history(params, cache, start, tables, tokens_hist):
+    """The pool after each lane's first ``start`` tokens went through ``forward``."""
+    t = np.arange(MB * BLOCK)[None]
+    pos = np.where(t < start[:, None], t, -1)
+    return _forward(params, tokens_hist, jnp.asarray(pos), cache, tables)[1]
+
+
+def _written_rows(positions, tables):
+    """bool [N_BLOCKS, BLOCK]: the rows these positions own through the tables."""
+    rows = np.zeros((N_BLOCKS, BLOCK), bool)
+    for b, lane in enumerate(np.asarray(positions)):
+        for p in lane:
+            if 0 <= p < MB * BLOCK:
+                rows[int(tables[b, p // BLOCK]), p % BLOCK] = True
+    return rows
+
+
+def _assert_same_pool(got, ref, before, written):
+    """Rows the dispatch does not own are untouched, bit for bit; the rows it
+    owns hold what ``forward`` wrote — bit for bit in layer 0, where both run
+    the same arithmetic, and to float32 rounding above it (the attention
+    feeding the deeper layers is merged from two partials)."""
+    for name in ("k", "v"):
+        g, r, b0 = (np.asarray(x[name]) for x in (got, ref, before))
+        np.testing.assert_array_equal(g[:, ~written], b0[:, ~written])
+        np.testing.assert_array_equal(g[0][written], r[0][written])
+        np.testing.assert_allclose(g[:, written], r[:, written], rtol=1e-5, atol=1e-6)
+
+
+def _chunk_inputs(start, valid):
+    tokens = jax.random.randint(jax.random.PRNGKey(11), (LANES, CHUNK), 0, CFG.vocab_size)
+    j = np.arange(CHUNK)[None]
+    positions = np.where((start[:, None] >= 0) & (j < valid[:, None]), start[:, None] + j, -1)
+    return tokens, positions
+
+
+@pytest.mark.parametrize(
+    "case", ["padding_lanes", "past_table", "shared_prefix", "first_chunk"]
+)
+def test_chunk_dispatch_writes_the_pool_as_forward_does(tiny_model, case):
+    params = tiny_model
+    start, valid, tables, _ = _case(case)
+    hist = jax.random.randint(jax.random.PRNGKey(9), (LANES, MB * BLOCK), 0, CFG.vocab_size)
+    if case == "shared_prefix":
+        hist = hist.at[1, :8].set(hist[0, :8])
+    before = _with_history(params, _sentinel_pool(), start, tables, hist)
+    tokens, positions = _chunk_inputs(start, valid)
+
+    ref_logits, ref = _forward(params, tokens, jnp.asarray(positions), before, tables)
+    logits, got = _forward_chunk(
+        params, tokens, jnp.asarray(positions), before, tables,
+        with_history=case != "first_chunk",
+    )
+    _assert_same_pool(got, ref, before, _written_rows(positions, tables))
+    # a query past its table attends no page of its own in `forward`
+    real = (positions >= 0) & (positions < MB * BLOCK)
+    np.testing.assert_allclose(
+        np.asarray(logits)[real], np.asarray(ref_logits)[real], rtol=1e-4, atol=1e-4
+    )
+
+
+def _decode_dispatch(params, cache, tables, base, tokens, max_pos):
+    """One decode dispatch as the engine builds it: history gathered once, W
+    windowed steps, one flush. Returns (logits [B, W, V], positions, pool)."""
+    hk, hv = _gather_history(cache, tables)
+    w = (CFG.num_layers, LANES, WINDOW, CFG.num_kv_heads, CFG.head_dim)
+    wk, wv = jnp.zeros(w, jnp.float32), jnp.zeros(w, jnp.float32)
+    pos, logits, positions = jnp.asarray(base), [], []
+    for k in range(WINDOW):
+        lg, wk, wv = _forward_window(
+            params, tokens[:, k], pos, hk, hv, jnp.asarray(base), wk, wv, jnp.int32(k)
+        )
+        logits.append(lg)
+        positions.append(np.asarray(pos))
+        pos = jnp.where((pos >= 0) & (pos < max_pos), pos + 1, -1)
+    cache = _flush_window(cache, tables, jnp.asarray(base), wk, wv, max_pos=max_pos)
+    return jnp.stack(logits, 1), np.stack(positions, 1), cache
+
+
+def _decode_by_forward(params, cache, tables, tokens, positions):
+    logits = []
+    for k in range(WINDOW):
+        lg, cache = _forward(
+            params, tokens[:, k:k + 1], jnp.asarray(positions[:, k:k + 1]), cache, tables
+        )
+        logits.append(lg[:, 0])
+    return jnp.stack(logits, 1), cache
+
+
+@pytest.mark.parametrize(
+    "case", ["padding_lanes", "past_max_pos", "past_table", "shared_prefix"]
+)
+def test_decode_flush_writes_the_pool_as_forward_does(tiny_model, case):
+    params = tiny_model
+    start, _, tables, max_pos = _case(case)
+    hist = jax.random.randint(jax.random.PRNGKey(9), (LANES, MB * BLOCK), 0, CFG.vocab_size)
+    if case == "shared_prefix":
+        hist = hist.at[1, :8].set(hist[0, :8])
+    before = _with_history(params, _sentinel_pool(), start, tables, hist)
+    tokens = jax.random.randint(jax.random.PRNGKey(13), (LANES, WINDOW), 0, CFG.vocab_size)
+    base = start.astype(np.int32)
+
+    logits, positions, got = _decode_dispatch(params, before, tables, base, tokens, max_pos)
+    ref_logits, ref = _decode_by_forward(params, before, tables, tokens, positions)
+    written = _written_rows(positions, tables)
+    assert written.sum() == sum(
+        min(WINDOW, max_pos + 1 - s, MB * BLOCK - s) for s in start if s >= 0
+    )
+    _assert_same_pool(got, ref, before, written)
+    real = (positions >= 0) & (positions < MB * BLOCK)
+    np.testing.assert_allclose(
+        np.asarray(logits)[real], np.asarray(ref_logits)[real], rtol=1e-4, atol=1e-4
+    )
+
+
+@pytest.mark.parametrize("program", ["chunk", "decode"])
+def test_int8_pool_and_its_scale_tables_hold_the_quantized_float_rows(tiny_model, program):
+    """An int8 pool after a dispatch: the rows the dispatch owns hold
+    ``quantize_kv`` of what the float pool's dispatch wrote, values and
+    per-token scales; every other row and scale is untouched, bit for bit."""
+    params = tiny_model
+    start, valid, tables, max_pos = _case("padding_lanes")
+    tokens, positions = _chunk_inputs(np.where(start >= 0, 0, -1), valid)
+    before_q, before_f = _sentinel_pool(quantized=True), _sentinel_pool()
+    if program == "chunk":
+        # every lane at position 0: no history, so both pools see one arithmetic
+        _, got = _forward_chunk(params, tokens, jnp.asarray(positions), before_q, tables)
+        _, ref = _forward_chunk(params, tokens, jnp.asarray(positions), before_f, tables)
+    else:
+        # history: the chunk above; the float pool holds its dequantized rows
+        _, before_q = _forward_chunk(params, tokens, jnp.asarray(positions), before_q, tables)
+        before_f = {
+            n: dequantize_kv(before_q[n], before_q[n + "_scale"], jnp.float32)
+            for n in ("k", "v")
+        }
+        base = np.where(start >= 0, valid, -1).astype(np.int32)
+        dec = jax.random.randint(jax.random.PRNGKey(13), (LANES, WINDOW), 0, CFG.vocab_size)
+        _, positions, got = _decode_dispatch(params, before_q, tables, base, dec, max_pos)
+        _, _, ref = _decode_dispatch(params, before_f, tables, base, dec, max_pos)
+    written = _written_rows(positions, tables)
+    kq, vq, ks, vs = quantize_kv(ref["k"], ref["v"])
+    for name, want in (("k", kq), ("v", vq), ("k_scale", ks), ("v_scale", vs)):
+        g, b0, want = (np.asarray(x) for x in (got[name], before_q[name], want))
+        np.testing.assert_array_equal(g[:, ~written], b0[:, ~written])
+        if g.dtype == np.int8:
+            # the decode's float history is the int8 one dequantized: the two
+            # hidden states agree to rounding, so a value on a step may flip
+            assert np.abs(g[:, written].astype(int) - want[:, written]).max() <= (
+                0 if program == "chunk" else 1
+            )
+        else:
+            np.testing.assert_allclose(g[:, written], want[:, written], rtol=1e-5)
+
+
+@pytest.mark.parametrize("table", ["pages", "scales"])
+@pytest.mark.parametrize(
+    "case", ["padding_lanes", "past_table", "shared_prefix", "first_chunk"]
+)
+def test_one_scatter_equals_the_scatter_per_layer(case, table):
+    """write_kv_to_pool against the idiom it replaces in the step programs —
+    write_kv_to_pages layer by layer — bit for bit, pages and scale tables."""
+    start, valid, tables, _ = _case(case)
+    _, positions = _chunk_inputs(start, valid)
+    positions = jnp.asarray(positions)
+    trail = (CFG.num_kv_heads, CFG.head_dim) if table == "pages" else ()
+    key = jax.random.PRNGKey(17)
+    pool = jax.random.normal(key, (CFG.num_layers, N_BLOCKS, BLOCK) + trail)
+    new = jax.random.normal(
+        jax.random.fold_in(key, 1), (CFG.num_layers, LANES, CHUNK) + trail
+    )
+    want = jnp.stack([
+        write_kv_to_pages(pool[l], pool[l], new[l], new[l], positions, tables)[0]
+        for l in range(CFG.num_layers)
+    ])
+    got = write_kv_to_pool(pool, new, positions, tables)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    written = _written_rows(positions, tables)
+    np.testing.assert_array_equal(np.asarray(got)[:, ~written], np.asarray(pool)[:, ~written])
+    assert (np.asarray(got)[:, written] != np.asarray(pool)[:, written]).all()
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["pages", "int8_with_scales"])
+def test_take_blocks_is_the_block_axis_index(quantized):
+    pool = _sentinel_pool(quantized=quantized)
+    ids = jnp.array([7, 0, 3, 3, 9], jnp.int32)
+    taken = take_blocks(pool, ids)
+    assert set(taken) == set(pool)
+    for name, a in pool.items():
+        np.testing.assert_array_equal(np.asarray(taken[name]), np.asarray(a[:, ids]))
