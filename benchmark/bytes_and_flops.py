@@ -3,15 +3,31 @@
 Kept with the benchmark so that no PR that claims a gain can move the
 denominator. ``shape`` is the published config.json (the ``shape`` group of
 ``configs/<name>.json``). bf16 everywhere: 2 bytes per element.
+
+The functions below count a Llama/Qwen2 dense decoder. Another architecture
+brings a module of its own under ``benchmark/`` with the same
+``param_count``, ``kv_bytes_per_token``, ``decode_step_stream_bytes`` and
+``prefill_chunk_flops``, and its configuration's file names it under the key
+``bytes_and_flops`` (as ``reference`` names its plain reference); readers
+take the functions from ``for_config(config)``.
 """
 
 from __future__ import annotations
 
+import importlib
 import json
 import os
+import sys
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 BYTES = 2  # bf16
+
+
+def for_config(config: dict):
+    """The module that counts this configuration: the one its file names
+    under ``bytes_and_flops``, or this one."""
+    name = config.get("bytes_and_flops")
+    return importlib.import_module(f"benchmark.{name}") if name else sys.modules[__name__]
 
 
 def head_dim(shape: dict) -> int:

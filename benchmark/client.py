@@ -20,13 +20,16 @@ from . import stats, traffic
 DRAIN_FLOOR_S = 15.0
 
 
-def request_body(model: str, prompt: str, max_tokens: int) -> dict:
-    return {
+def request_body(model: str, prompt: str, max_tokens: int, logprobs: int = None) -> dict:
+    body = {
         "model": model, "prompt": prompt, "max_tokens": max_tokens,
         "temperature": 0, "stream": True,
         "stream_options": {"include_usage": True},
         "nvext": {"ignore_eos": True},
     }
+    if logprobs is not None:  # only the answer held against the reference asks
+        body["logprobs"] = logprobs
+    return body
 
 
 async def stream_one(session, url: str, body: dict, rec: dict, clock) -> None:
@@ -55,6 +58,9 @@ async def stream_one(session, url: str, body: dict, rec: dict, clock) -> None:
                 for choice in chunk.get("choices") or ():
                     if choice.get("finish_reason"):
                         finish = choice["finish_reason"]
+                    if choice.get("logprobs"):
+                        rec.setdefault("top_logprobs", []).extend(
+                            choice["logprobs"]["top_logprobs"])
                     text = choice.get("text")
                     if text:
                         # the word-level tokenizer: one word per token
@@ -158,15 +164,16 @@ async def run_window(port: int, model: str, plain_words: list, schedule: dict,
     return {"records": sent, "drain_s": drain_s}
 
 
-async def probe(port: int, model: str, prompt: str, max_tokens: int) -> dict:
-    """One greedy request alone; returns its record (with ``text``)."""
+async def probe(port: int, model: str, prompt: str, max_tokens: int, logprobs: int = None) -> dict:
+    """One greedy request alone; returns its record (with ``text``, and with
+    ``logprobs`` asked ``top_logprobs``: per token, word -> log-probability)."""
     rec = new_record(-1, len(prompt.split()), max_tokens)
     t0 = time.perf_counter()
     timeout = aiohttp.ClientTimeout(total=120)
     async with aiohttp.ClientSession(timeout=timeout) as session:
         await stream_one(
             session, f"http://127.0.0.1:{port}/v1/completions",
-            request_body(model, prompt, max_tokens), rec,
+            request_body(model, prompt, max_tokens, logprobs), rec,
             lambda: time.perf_counter() - t0,
         )
     return rec

@@ -38,9 +38,11 @@ def _rope(x, cos, sin):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def logits(params: dict, shape: dict, tokens, at) -> jax.Array:
+def logits(params: dict, shape: dict, tokens, at, dot=_dot) -> jax.Array:
     """Float32 logits [len(at), vocab] of the next token at the positions
-    ``at`` of the sequence ``tokens`` ([T] token ids, causal attention)."""
+    ``at`` of the sequence ``tokens`` ([T] token ids, causal attention).
+    ``dot`` is the product against a weight matrix; only the control of
+    ``correct`` (reference_control.py) passes another."""
     heads, kv_heads = shape["num_attention_heads"], shape["num_key_value_heads"]
     d = shape.get("head_dim") or shape["hidden_size"] // heads
     eps, t = shape["rms_norm_eps"], tokens.shape[0]
@@ -51,7 +53,7 @@ def logits(params: dict, shape: dict, tokens, at) -> jax.Array:
 
     def layer(x, lp):
         h = _rms(x, lp["attn_norm"], eps)
-        q, k, v = _dot(h, lp["wq"]), _dot(h, lp["wk"]), _dot(h, lp["wv"])
+        q, k, v = dot(h, lp["wq"]), dot(h, lp["wk"]), dot(h, lp["wv"])
         if "bq" in lp:
             q, k, v = q + _f32(lp["bq"]), k + _f32(lp["bk"]), v + _f32(lp["bv"])
         q = _rope(q.reshape(t, heads, d), cos, sin)
@@ -62,12 +64,12 @@ def logits(params: dict, shape: dict, tokens, at) -> jax.Array:
         scores = jnp.einsum("thd,shd->hts", q, k, precision=HIGHEST) / math.sqrt(d)
         probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
         attn = jnp.einsum("hts,shd->thd", probs, v, precision=HIGHEST)
-        x = x + _dot(attn.reshape(t, heads * d), lp["wo"])
+        x = x + dot(attn.reshape(t, heads * d), lp["wo"])
         h = _rms(x, lp["mlp_norm"], eps)
-        x = x + _dot(jax.nn.silu(_dot(h, lp["w_gate"])) * _dot(h, lp["w_up"]), lp["w_down"])
+        x = x + dot(jax.nn.silu(dot(h, lp["w_gate"])) * dot(h, lp["w_up"]), lp["w_down"])
         return x, None
 
     x, _ = jax.lax.scan(layer, _f32(params["embed"][tokens]), params["layers"])
     x = _rms(x[at], params["final_norm"], eps)
     head = params["embed"].T if shape.get("tie_word_embeddings") else params["lm_head"]
-    return _dot(x, head)
+    return dot(x, head)

@@ -42,6 +42,9 @@ PROBE_PROMPT_TOKENS, PROBE_OUTPUT_TOKENS = 12, 16
 # the answer held against the reference: two prefill chunks (the second reads
 # history) and six decode dispatches
 REFERENCE_PROMPT_TOKENS, REFERENCE_OUTPUT_TOKENS = 150, 24
+# ... with the log-probabilities of its 20 likeliest tokens at every position
+# (the most the server gives): 480 numbers to hold against the reference's
+REFERENCE_TOP_LOGPROBS = 20
 TRACE_AT_S, TRACE_FOR_S = 5.0, 4.0
 FOLDERS = {"end_to_end": "end_to_end", "per_layer": "layer_metrics"}
 
@@ -117,6 +120,41 @@ async def trigger_trace(clock, stop, session, trace_dir: str, seconds: float):
     open(os.path.join(trace_dir, "stop"), "w").close()
 
 
+def device_report(dev: dict, engine: dict) -> dict:
+    """The result line's ``device``: what the child named at start-up, and the
+    allocator's peak on the fullest chip from ``/debug/engine``."""
+    memory = [d.get("peak_bytes_in_use") for d in engine.get("device_memory") or []]
+    memory = [m for m in memory if m is not None]
+    return {"platform": dev["platform"], "kind": dev["kind"], "count": dev["count"],
+            "memory_peak_bytes": max(memory) if memory else None}
+
+
+def weights_floor_bytes(account: dict, chips: int) -> float:
+    """The least a chip holds of a model sharded evenly: 0.98 of the whole
+    model's bytes (``memory_account_bytes.weights``) / chips."""
+    return 0.98 * account["weights"] / chips
+
+
+def weights_not_held(account: dict, chips: int, device: dict):
+    """Why the server does not hold the weights its configuration's file says
+    it has, or None. The allocator's peak on the fullest chip is at least
+    ``weights_floor_bytes``: a tree that maps the config.json onto a smaller
+    model than the file accounts for fails here, in every run. No upper limit,
+    and nothing about the pool: the program sizes that itself. Off the TPU (a
+    rehearsal at a tiny shape) there is nothing to hold."""
+    if device["platform"] != "tpu":
+        return None
+    need, peak = weights_floor_bytes(account, chips), device["memory_peak_bytes"]
+    if peak is None:
+        return (f"the device reported no memory_peak_bytes to hold against the "
+                f"{account['weights']} B of weights the configuration accounts for")
+    if peak < need:
+        return (f"memory_peak_bytes {peak} is under 0.98 x weights {account['weights']} B "
+                f"/ {chips} chips = {need:.0f} B: the server does not hold the model "
+                f"the configuration's file describes")
+    return None
+
+
 def engine_state(port: int) -> dict:
     status, raw = serving.http_json(port, "GET", "/debug/engine")
     check(status == 200, f"/debug/engine: HTTP {status}")
@@ -129,17 +167,23 @@ class Launch:
 
     def __init__(self, workload: str, seed: int, trace: bool, rehearse: bool):
         self.bench = load_json(ROOT, "BENCHMARK.json")
-        entry = next((w for w in self.bench["workloads"] if w["name"] == workload), None)
-        check(entry is not None, f"no workload {workload!r} in BENCHMARK.json")
-        self.cell = load_json(HERE, "workloads", f"{workload}.json")
-        cfg_entry = next(c for c in self.bench["configs"] if c["name"] == entry["config"])
+        cell_file = os.path.join(HERE, "workloads", f"{workload}.json")
+        check(os.path.exists(cell_file), f"no workload {workload!r}: no {cell_file}")
+        self.cell = load_json(cell_file)
+        cfg_entry = next((c for c in self.bench["configs"] if c["name"] == self.cell["config"]), None)
+        check(cfg_entry is not None, f"no configuration {self.cell['config']!r} in BENCHMARK.json")
         self.cfg = load_json(ROOT, cfg_entry["file"])
         self.serve = self.cfg["serving"]
         self.chips = self.serve["chips"]
-        check(entry["config"] == self.cell["config"] and entry["chips"] == self.chips,
+        # a cell whose file is here and that BENCHMARK.json does not list is
+        # parked (PERF.md 7): it runs by hand, rehearses and sweeps, the driver never asks for it
+        entry = next((w for w in self.bench["workloads"] if w["name"] == workload), None)
+        check(entry is None
+              or (entry["config"] == self.cell["config"] and entry["chips"] == self.chips),
               "the cell's file and BENCHMARK.json disagree on configuration or chips")
         self.shape = {k: v for k, v in self.cfg.items() if not isinstance(v, dict)
-                      and k not in ("source", "reduced", "assumed", "deployment")}
+                      and k not in ("source", "reduced", "assumed", "deployment",
+                                    "reference", "bytes_and_flops")}
         if rehearse:
             self.shape = load_json(HERE, "rehearse.json")["shape"]
         self.scratch = os.path.join(ROOT, ".bench_runs", workload)
@@ -172,19 +216,30 @@ class Launch:
         )
         self.rehearse = rehearse
 
-    def against_reference(self, prompt: str, answer: str) -> dict:
-        """The verdict of ``reference_child.py`` on one greedy answer. Run it
-        only after the server has exited: it needs the chip."""
+    def against_reference(self, answers: list, control: str = None) -> list:
+        """The verdicts of ``reference_child.py`` on greedy answers, given as
+        the records ``client.probe`` returned (with ``prompt``, ``text`` and
+        ``top_logprobs``). Run it only after the server has exited: it
+        needs the chip. ``control`` names a module to put in the program's
+        place as well (``correct_readings.py``; never in a benchmark run)."""
         word_id = {w: i for i, w in enumerate(self.words)}
+        # set from readings on the chip (PERF.md 2); a rehearsal's tiny shape has none
+        limits = {} if self.rehearse else self.cfg.get("correct_limits", {})
         case_file = os.path.join(self.scratch, "reference_case.json")
         with open(case_file, "w") as f:
-            json.dump({"prompt_ids": [word_id[w] for w in prompt.split()],
-                       "output_ids": [word_id.get(w, 0) for w in answer.split()]}, f)
+            json.dump([{"prompt_ids": [word_id[w] for w in a["prompt"].split()],
+                        "output_ids": [word_id.get(w, 0) for w in a.get("text", "").split()],
+                        "top_logprobs": [[[word_id.get(w.strip(), 0), lp] for w, lp in top.items()]
+                                         for top in a.get("top_logprobs", ())]}
+                       for a in answers], f)
         child = serving.Child(
             [sys.executable, os.path.join(HERE, "reference_child.py"),
              "--model-dir", self.model_dir, "--case", case_file,
              "--seed", str(self.serve["engine_args"].get("seed", 0)),
-             "--reference", self.cfg.get("reference", "reference")],
+             "--reference", self.cfg.get("reference", "reference"),
+             *(["--logprob-rms-limit", str(limits["logprob_rms"])] if "logprob_rms" in limits else []),
+             *(["--control", control] if control else []),
+             "--", *self.serve["server_flags"]],
             os.path.join(self.scratch, "reference.log"), self.env,
         )
         try:
@@ -194,7 +249,8 @@ class Launch:
         finally:
             child.stop()
         verdicts = child.log_json("reference")
-        return verdicts[0] if verdicts else {"agrees": False, "log": child.log()[-1500:]}
+        missing = {"agrees": False, "log": child.log()[-1500:]}
+        return verdicts + [missing] * (len(answers) - len(verdicts))
 
     def wait_ready(self) -> dict:
         """The device the child named, once it serves. No TPU, no run."""
@@ -243,7 +299,8 @@ def run(args) -> int:
             long_prompt = traffic.prompt_text(
                 plain, REFERENCE_PROMPT_TOKENS, random.Random(args.seed + 2))
             long_probe = asyncio.run(client.probe(
-                port, model, long_prompt, REFERENCE_OUTPUT_TOKENS))
+                port, model, long_prompt, REFERENCE_OUTPUT_TOKENS, REFERENCE_TOP_LOGPROBS))
+            long_probe["prompt"] = long_prompt
             deadline = time.monotonic() + 90
             while not os.path.exists(os.path.join(trace_dir, "done")):
                 if time.monotonic() > deadline or child.proc.poll() is not None:
@@ -283,7 +340,7 @@ def run(args) -> int:
     if args.trace:
         if not long_probe["ok"]:
             reasons.append(f"the request held against the reference failed: {long_probe.get('error')}")
-        verdict = go.against_reference(long_prompt, long_probe.get("text", ""))
+        verdict = go.against_reference([long_probe])[0]
         if not verdict["agrees"]:
             reasons.append(f"the probe's answer disagrees with the plain reference: {verdict}")
 
@@ -295,10 +352,10 @@ def run(args) -> int:
                 path, device_marker="CPU" if args.rehearse else "TPU")
             if args.keep_trace:
                 trace_reduce.save_reduced(reduced, args.keep_trace)
-    memory = [d.get("peak_bytes_in_use") for d in after.get("device_memory") or []]
-    memory = [m for m in memory if m is not None]
-    device = {"platform": dev["platform"], "kind": dev["kind"], "count": dev["count"],
-              "memory_peak_bytes": max(memory) if memory else None}
+    device = device_report(dev, after)
+    not_held = weights_not_held(cfg["memory_account_bytes"], chips, device)
+    if not_held:
+        reasons.append(not_held)
     ctx = {
         "cell": cell, "cell_name": args.workload, "config": cfg, "shape": shape,
         "chips": chips, "seconds": args.seconds, "peaks": peaks, "device": device,
@@ -309,18 +366,21 @@ def run(args) -> int:
     }
     info = {
         "workload": args.workload, "seed": args.seed, "seconds": args.seconds,
+        "registered": any(w["name"] == args.workload for w in bench["workloads"]),
         "samples": summary["samples"], "ready_s": ready_s, "drain_s": result["drain_s"],
         "warmup_s": (child.log_json("warmup") or [None])[0],
         "compile_cache": dev.get("compile_cache"),
         "jit_recompiles": [before["jit_recompiles"], after["jit_recompiles"]],
         "against_reference": verdict,
+        "memory_peak_bytes_and_weights_floor": [
+            device["memory_peak_bytes"], weights_floor_bytes(cfg["memory_account_bytes"], chips)],
         "tiers": sorted({t.get("tier") for t in tiers.values()}),
         "mean_prompt_tokens": summary["mean_prompt_tokens"],
         "mean_output_tokens": summary["mean_output_tokens"],
         "end_to_end": {k: summary[k] for k in (
             "ttft_mean_ms", "ttft_p50_ms", "ttft_p90_ms",
             "tpot_mean_ms", "tpot_p50_ms", "tpot_p90_ms",
-            "output_tokens_per_s", "client_lag_p90_ms")},
+            "output_tokens_per_s", "client_lag_p90_ms", "longest_silence_ms")},
         "token_count_mismatches": sum(
             1 for r in records if r["ok"]
             and sum(n for _, n in r["token_times"]) != r["got_tokens"]),

@@ -50,6 +50,19 @@ def drain_limit_s(records: list, floor_s: float) -> float:
     return max(floor_s, 2.0 * longest * percentile(tpots, 50) / 1e3)
 
 
+def longest_silence_ms(records: list, window_s: float):
+    """The longest stretch of the window in which no token of any request
+    arrived. With several streams open the server answers every dispatch, a
+    few hundred milliseconds apart at most: a far longer silence is a server
+    (or a client) that stood still, which an open loop pays for many times
+    over in its mean TTFT (PERF.md 7)."""
+    times = sorted(t for r in records for t, _ in r.get("token_times", ()) if 0.0 <= t < window_s)
+    if not times:
+        return None
+    edges = [0.0] + times + [window_s]
+    return max(b - a for a, b in zip(edges, edges[1:])) * 1e3
+
+
 def summarize(records: list, window_s: float) -> dict:
     """Metrics over the requests that fell due inside the window; tokens per
     second over every token that arrived inside it, whoever sent it."""
@@ -75,6 +88,7 @@ def summarize(records: list, window_s: float) -> dict:
         "output_tokens_per_s": tokens_in_window / window_s,
         "output_tokens_in_window": tokens_in_window,
         "client_lag_p90_ms": percentile(lags, 90),
+        "longest_silence_ms": longest_silence_ms(records, window_s),
         "mean_prompt_tokens": (
             sum(r["prompt_tokens"] for r in window) / len(window) if window else None
         ),

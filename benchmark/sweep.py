@@ -16,9 +16,11 @@ with the knee; the cell's file then takes its ``rate_rps`` by hand.
 
 PR 23's first rule (15-20 s windows, TTFT of the last third against the
 first) passed every rate: a request lives 11-16 s and the window ended before
-the slots were full. This rule has been rehearsed on the CPU only; the rates
-of the cells in BENCHMARK.json are set by measured occupancy (their files say
-how), not by a knee.
+the slots were full. This rule ran on the chip in PR 26 (Qwen2.5-1.5B, chat
+lengths, 1.5-3.0 requests/s): every rate passed, 3.0/s at 0.914 of its offer
+with the median TTFT doubled against 2.7/s, so the knee it names is a lower
+bound where the highest rate given still passes; read ``ttft_p50_ms`` beside
+``sustained``. The cell's file says which share of the knee its rate is.
 """
 
 from __future__ import annotations
