@@ -70,12 +70,14 @@ from dynamo_tpu.llm.protocols.common import (
 )
 from dynamo_tpu.models.llama import (
     LlamaConfig,
+    chunk_history_tiles,
     dequantize_kv,
     flush_window,
     forward,
     forward_chunk,
     forward_window,
     gather_history,
+    history_tiles_full,
     lm_head,
     make_kv_cache,
     quantize_kv,
@@ -757,6 +759,11 @@ class JaxServingEngine(AsyncEngine):
         # that also carried a decode lane — the chunked-prefill interleaving
         # bound the ITL-isolation test asserts against the step budget
         self.prefill_interleave_max = 0
+        # tiles of pool history the history-bearing chunk dispatches read (the
+        # program's own trip count, from the same positions), and what the
+        # block tables' full width would have been
+        self.chunk_history_tiles_read = 0
+        self.chunk_history_tiles_full = 0
 
         # (with_logprobs, with_penalties, with_sampling) variants, compiled
         # lazily per need
@@ -2304,6 +2311,12 @@ class JaxServingEngine(AsyncEngine):
             s is not None and (s.prefill_pos is None or s.prefill_pos > 0)
             for s in self._slots
         )
+        if want_history and self._pp == 1 and self._sp == 1:
+            bs, mb = cfg.kv_block_size, cfg.max_blocks_per_seq
+            self.chunk_history_tiles_read += int(
+                chunk_history_tiles(positions, bs, mb)
+            )
+            self.chunk_history_tiles_full += history_tiles_full(bs, mb)
         if want_pen:
             self._sync_counts(list(self._slots))
         counts_in = self._counts if want_pen else self._dummy_counts
@@ -3903,6 +3916,10 @@ class JaxServingEngine(AsyncEngine):
             "spec_drafted_tokens": self.spec_drafted_total,
             "spec_accepted_tokens": self.spec_accepted_total,
             "kv_quantized": int(self._kv_quantized),
+            # how far the chunk program's history loop engages: tiles read
+            # over the tiles of the block tables' full width (cumulative)
+            "chunk_history_tiles_read": self.chunk_history_tiles_read,
+            "chunk_history_tiles_full": self.chunk_history_tiles_full,
             # mid-stream resume: re-admissions this engine served (the
             # client-side resume counters live in runtime/resilience.py)
             "resumed_requests": self.resumed_requests,

@@ -795,6 +795,50 @@ def _chunk_self_partial(
     )
 
 
+# positions a trip of forward_chunk's history loop reads (a whole number of
+# pages: `history_tile` rounds it to the pool's block size). Settled on the
+# v5e at Qwen2.5-1.5B's serving shapes: 128 reads as 256 does, 512 slower at
+# every length (PERF.md 6, PR 30)
+HISTORY_TILE = 256
+
+
+def history_tile(block_size: int, table_blocks: int) -> int:
+    """Positions in one tile of the chunk program's history loop: whole
+    pages, and no more of them than a block table has."""
+    return min(max(HISTORY_TILE // block_size, 1), table_blocks) * block_size
+
+
+def history_tiles_full(block_size: int, table_blocks: int) -> int:
+    """Tiles that cover a whole block table (the last may hang over it)."""
+    return -(-table_blocks * block_size // history_tile(block_size, table_blocks))
+
+
+def chunk_history_tiles(positions, block_size: int, table_blocks: int):
+    """Trips of :func:`forward_chunk`'s history loop for ``positions`` [B, C]:
+    the tiles that hold the longest history of the dispatch. A lane's history
+    is what lies below its first query (``positions[:, 0]``; a padding lane,
+    < 0, has none), and no lane's reaches past its block table. Written for a
+    traced array (the program's own trip count) and for a numpy one (the
+    host's count of what the program will read) alike."""
+    tile = history_tile(block_size, table_blocks)
+    longest = positions[:, 0].max().clip(0, table_blocks * block_size)
+    return (longest + tile - 1) // tile
+
+
+def _merge_partials(p, q):
+    """Flash merge of two attention partials over disjoint keys, each
+    (numerator [B,T,H,D] f32, row max [B,H,T], denominator [B,H,T])."""
+    (num_p, m_p, l_p), (num_q, m_q, l_q) = p, q
+    m = jnp.maximum(m_p, m_q)
+    a_p = jnp.exp(m_p - m)
+    a_q = jnp.exp(m_q - m)
+    num = (
+        num_p * a_p.transpose(0, 2, 1)[..., None]
+        + num_q * a_q.transpose(0, 2, 1)[..., None]
+    )
+    return num, m, a_p * l_p + a_q * l_q
+
+
 def forward_chunk(
     params: Params,
     config: LlamaConfig,
@@ -820,53 +864,74 @@ def forward_chunk(
     them after it (:func:`write_kv_to_pool`), so the dispatch costs what its
     lanes touch, whatever the pool's size.
 
+    The history is read a tile of :func:`history_tile` positions at a time,
+    and only as many tiles as the longest history of THIS dispatch fills
+    (:func:`chunk_history_tiles`, a traced scalar): the block tables are as
+    wide as ``max_model_len``, the scores over them f32, and what the live
+    sequences hold is a fraction of that. Each tile's partial folds into a
+    running one by the flash merge; no trip leaves the empty partial, which
+    the merge with the chunk's own turns into that alone.
+
     ``with_history=False`` compiles out the pool gather + history partial
     entirely — the caller guarantees every lane starts at position 0 (a
-    fresh admission wave's first chunk, THE TTFT-critical dispatch; the
-    masked-out history partial still materializes layer-sized f32 score
-    buffers, ~20 ms of a ~100 ms chunk at serving scale on v5e)."""
+    fresh admission wave's first chunk, THE TTFT-critical dispatch)."""
     from dynamo_tpu.ops.attention import gather_pages, write_kv_to_pool
 
     c = config
     scale = c.head_dim ** -0.5
     h = embed_lookup(params, tokens, c.dtype)  # [B, C, E]
-    chunk_start = jnp.where(positions[:, 0] >= 0, positions[:, 0], 0)  # [B]
+    b, t = positions.shape
     quantized = kv_cache_quantized(kv_cache)
-    num_blocks = kv_cache["k"].shape[1]
+    num_blocks, block_size = kv_cache["k"].shape[1:3]
+    table_blocks = block_tables.shape[1]
     pages = _pool_pages(kv_cache)
+    tile_blocks = history_tile(block_size, table_blocks) // block_size
+    # what a lane has in the pool: below its first query, within its table
+    history_len = jnp.clip(positions[:, 0], 0, table_blocks * block_size)  # [B]
+    n_tiles = chunk_history_tiles(positions, block_size, table_blocks)
+    # whole tiles: the columns added point at page 0 and lie past every history
+    max_tiles = history_tiles_full(block_size, table_blocks)
+    tables = jnp.pad(
+        block_tables, ((0, 0), (0, max_tiles * tile_blocks - table_blocks))
+    )
 
     def layer_body(hidden, xs):
         lp, layer = xs
-        b, t = positions.shape
 
         q, k, v = project_qkv(lp, c, hidden, positions)
-        num_s, m_s, l_s = _chunk_self_partial(c, q, k, v, positions, scale)
+        part = _chunk_self_partial(c, q, k, v, positions, scale)
         if with_history:
-            rows = layer * num_blocks + block_tables
-            gk = gather_pages(pages["k"], rows)
-            gv = gather_pages(pages["v"], rows)
-            if quantized:
-                # dequant on the GATHERED lanes only (O(context), never
-                # O(pool)); gather_pages is trailing-dim agnostic so the
-                # [L * N, bs] scale tables gather like [B, Smax] vectors
-                gks = gather_pages(pages["k_scale"], rows)
-                gvs = gather_pages(pages["v_scale"], rows)
-                gk = dequantize_kv(gk, gks, hidden.dtype)
-                gv = dequantize_kv(gv, gvs, hidden.dtype)
-            num_h, m_h, l_h = _history_partial(
-                c, q, gk, gv, chunk_start, positions, scale
+            rows = layer * num_blocks + tables
+
+            def tile(i, acc):
+                """Fold tile ``i``'s partial into ``acc``: its pages hold
+                positions ``i * tile`` onwards."""
+                cols = jax.lax.dynamic_slice_in_dim(
+                    rows, i * tile_blocks, tile_blocks, axis=1
+                )
+                gk = gather_pages(pages["k"], cols)
+                gv = gather_pages(pages["v"], cols)
+                if quantized:
+                    # dequant on the GATHERED lanes only (O(context), never
+                    # O(pool)); gather_pages is trailing-dim agnostic so the
+                    # [L * N, bs] scale tables gather like [B, S] vectors
+                    gks = gather_pages(pages["k_scale"], cols)
+                    gvs = gather_pages(pages["v_scale"], cols)
+                    gk = dequantize_kv(gk, gks, hidden.dtype)
+                    gv = dequantize_kv(gv, gvs, hidden.dtype)
+                start = i * tile_blocks * block_size
+                return _merge_partials(acc, _history_partial(
+                    c, q, gk, gv, history_len - start, positions, scale
+                ))
+
+            empty = (
+                jnp.zeros((b, t, c.num_heads, c.head_dim), jnp.float32),
+                jnp.full((b, c.num_heads, t), -1e30, jnp.float32),
+                jnp.zeros((b, c.num_heads, t), jnp.float32),
             )
-            m_t = jnp.maximum(m_h, m_s)
-            a_h = jnp.exp(m_h - m_t)
-            a_s = jnp.exp(m_s - m_t)
-            den = a_h * l_h + a_s * l_s
-            num = (
-                num_h * a_h.transpose(0, 2, 1)[..., None]
-                + num_s * a_s.transpose(0, 2, 1)[..., None]
-            )
-        else:
-            den = l_s
-            num = num_s
+            hist = jax.lax.fori_loop(0, n_tiles, tile, empty)
+            part = _merge_partials(hist, part)
+        num, _, den = part
         attn = jnp.where(
             (den > 0.0).transpose(0, 2, 1)[..., None],
             num / jnp.maximum(den, 1e-30).transpose(0, 2, 1)[..., None],

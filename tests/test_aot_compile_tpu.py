@@ -347,6 +347,24 @@ def test_step_programs_never_copy_the_kv_pool(request, one_chip, program, layout
     assert temps[0] - temps[1] < added_slice // 4, temps
 
 
+# Temporary memory of the chunk program at Qwen2.5-1.5B's serving shapes (32
+# lanes x 128 positions, tables of 2,048 positions, the 6,144-block pool, full
+# depth), by the described v5e's compiler:
+#   665,385,472 B  with scores over the tables' whole width (PR 26's tree):
+#                  the f32[32,2,6,128,2048] buffer alone is 402,653,184 B
+#   266,414,080 B  with the history read a tile of 256 positions at a time
+CHUNK_TEMP_LIMIT = 450_000_000
+
+
+def test_chunk_program_holds_no_scores_as_wide_as_the_block_tables(one_chip):
+    """A change that brings the ``[..., 2048]`` score tensor back into the
+    chunk program's layer loop fails here, without a chip."""
+    cfg = llama.LLAMA_PRESETS["qwen2.5-1.5b"]
+    compiled = _compile_pool_program("chunk", cfg, 6144, False, None, one_chip)
+    assert compiled.memory_analysis().temp_size_in_bytes < CHUNK_TEMP_LIMIT
+    assert re.findall(rf"f32\[[\d,]*,{TABLE * BS}\]", compiled.as_text()) == []
+
+
 @pytest.mark.parametrize("layout", ["bf16", "bf16_tp4"])
 def test_taking_blocks_out_never_copies_the_kv_pool(request, one_chip, layout):
     """take_blocks (host-tier spills and KV transfers, between dispatches):

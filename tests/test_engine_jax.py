@@ -212,6 +212,25 @@ def test_chunked_prefill_parity(params, run):
         eng.close()
 
 
+def test_chunk_history_counters_count_the_tiles_the_program_reads(params, run):
+    """One 700-token prompt alone, in chunks of 128 under a 1,024-position
+    table (four tiles of 256): the chunk at 0 has no history and runs the
+    history-free program; those at 128 ... 640 read ceil(start / 256) tiles
+    each, 1 + 1 + 2 + 2 + 3, where the tables' full width is four a dispatch.
+    The answer's other tokens come from the decode program, which counts none."""
+    cfg = EngineConfig(max_slots=1, kv_block_size=16, max_model_len=1024, prefill_chunk=128)
+    eng = JaxServingEngine(CFG, params, cfg)
+    try:
+        before = eng.metrics_snapshot()
+        assert (before["chunk_history_tiles_read"], before["chunk_history_tiles_full"]) == (0, 0)
+        toks, finish = run(collect_tokens(eng, [(7 * i + 3) % 100 for i in range(700)], max_tokens=3))
+        assert (len(toks), finish) == (3, "length")
+        after = eng.metrics_snapshot()
+        assert (after["chunk_history_tiles_read"], after["chunk_history_tiles_full"]) == (9, 20)
+    finally:
+        eng.close()
+
+
 def test_warmup_compiles_before_serving(params, run):
     cfg = EngineConfig(max_slots=2, kv_block_size=8, max_model_len=64, prefill_chunk=16)
     eng = JaxServingEngine(CFG, params, cfg)

@@ -7,7 +7,9 @@ import numpy as np
 import pytest
 
 from dynamo_tpu.models.llama import (
+    HISTORY_TILE,
     LLAMA_PRESETS,
+    chunk_history_tiles,
     dequantize_kv,
     flush_window,
     forward,
@@ -259,16 +261,17 @@ def _written_rows(positions, tables):
     return rows
 
 
-def _assert_same_pool(got, ref, before, written):
+def _assert_same_pool(got, ref, before, written, atol=1e-6):
     """Rows the dispatch does not own are untouched, bit for bit; the rows it
     owns hold what ``forward`` wrote — bit for bit in layer 0, where both run
     the same arithmetic, and to float32 rounding above it (the attention
-    feeding the deeper layers is merged from two partials)."""
+    feeding the deeper layers is merged from two partials; ``atol`` for sums
+    over a thousand positions of history taken in another order)."""
     for name in ("k", "v"):
         g, r, b0 = (np.asarray(x[name]) for x in (got, ref, before))
         np.testing.assert_array_equal(g[:, ~written], b0[:, ~written])
         np.testing.assert_array_equal(g[0][written], r[0][written])
-        np.testing.assert_allclose(g[:, written], r[:, written], rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(g[:, written], r[:, written], rtol=1e-5, atol=atol)
 
 
 def _chunk_inputs(start, valid):
@@ -394,6 +397,86 @@ def test_int8_pool_and_its_scale_tables_hold_the_quantized_float_rows(tiny_model
             )
         else:
             np.testing.assert_allclose(g[:, written], want[:, written], rtol=1e-5)
+
+
+# -- the chunk program's history loop ------------------------------------------
+#
+# forward_chunk reads the pool's history a tile of HISTORY_TILE positions at a
+# time, as many tiles as the longest history of the dispatch fills, and folds
+# the tiles' partials together; `forward` attends every page of the table at
+# once. A table of four tiles, its pages scattered over the pool.
+
+T_BLOCK, T_MB, T_LANES, T_CHUNK = 16, 4 * HISTORY_TILE // 16, 4, 8
+T_WIDTH = T_MB * T_BLOCK
+
+# per case: each lane's history length (-1 = padding lane), how many queries it
+# brings (1 = a decode lane riding the dispatch), and the tiles the loop reads
+TILE_CASES = {
+    "no_history": ([0, 0, 0, -1], [8, 3, 1, 0], 0),
+    "one_position": ([1, 0, -1, 1], [8, 8, 0, 1], 1),
+    "a_tile_less_one": ([HISTORY_TILE - 1, 17, -1, 100], [8, 8, 0, 1], 1),
+    "a_tile": ([HISTORY_TILE, 17, -1, 100], [8, 8, 0, 1], 1),
+    "a_tile_and_one": ([HISTORY_TILE + 1, 17, -1, 100], [8, 8, 0, 1], 2),
+    # one prefilling lane deep in its prompt, one at its start, two decode lanes
+    "lanes_of_very_different_lengths": ([700, 0, 300, 699], [8, 8, 1, 1], 3),
+    # the longest history is a decode lane's
+    "decode_lane_longest": ([10, 600, -1, 5], [8, 1, 0, 8], 3),
+    # lane 0's chunk ends on the table's last position, lane 1 decodes into it
+    "full_width": ([T_WIDTH - 8, T_WIDTH - 1, 40, -1], [8, 1, 8, 0], 4),
+}
+
+
+_traced_tiles = jax.jit(chunk_history_tiles, static_argnums=(1, 2))
+
+
+@pytest.fixture(scope="module")
+def tiled_pool(tiny_model):
+    """A float pool holding T_WIDTH tokens of history for every lane, written
+    by ``forward``; the lanes' tables; the int8 pool of the same rows, and the
+    float pool that holds exactly what the int8 one dequantizes to."""
+    n_blocks = T_LANES * T_MB + 5
+    tables = np.random.default_rng(3).permutation(n_blocks)[: T_LANES * T_MB]
+    tables = jnp.asarray(tables.reshape(T_LANES, T_MB).astype(np.int32))
+    hist = jax.random.randint(jax.random.PRNGKey(21), (T_LANES, T_WIDTH), 0, CFG.vocab_size)
+    pos = jnp.broadcast_to(jnp.arange(T_WIDTH), (T_LANES, T_WIDTH))
+    empty = make_kv_cache(CFG, n_blocks, T_BLOCK, dtype=jnp.float32)
+    _, pool = _forward(tiny_model, hist, pos, empty, tables)
+    kq, vq, ks, vs = quantize_kv(pool["k"], pool["v"])
+    int8 = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+    rounded = {n: dequantize_kv(int8[n], int8[n + "_scale"], jnp.float32) for n in ("k", "v")}
+    return tables, {"float": (pool, pool), "int8": (int8, rounded)}
+
+
+@pytest.mark.parametrize("pool", ["float", "int8"])
+@pytest.mark.parametrize("case", list(TILE_CASES))
+def test_chunk_history_loop_attends_what_forward_attends(tiny_model, tiled_pool, case, pool):
+    """Rows past a lane's history hold its OLD tokens' keys (the pool was
+    filled to the table's end), so a tile read too far, or a mask off by one,
+    shows in the logits."""
+    start, valid, tiles = TILE_CASES[case]
+    tables, pools = tiled_pool
+    before, before_as_float = pools[pool]
+    start, valid = np.array(start), np.array(valid)
+    tokens = jax.random.randint(jax.random.PRNGKey(11), (T_LANES, T_CHUNK), 0, CFG.vocab_size)
+    j = np.arange(T_CHUNK)[None]
+    positions = np.where((start[:, None] >= 0) & (j < valid[:, None]), start[:, None] + j, -1)
+
+    # the host's count of the tiles (numpy) is the program's trip count (traced)
+    assert chunk_history_tiles(positions, T_BLOCK, T_MB) == tiles
+    assert int(_traced_tiles(jnp.asarray(positions), T_BLOCK, T_MB)) == tiles
+
+    ref_logits, ref = _forward(tiny_model, tokens, jnp.asarray(positions), before_as_float, tables)
+    logits, got = _forward_chunk(tiny_model, tokens, jnp.asarray(positions), before, tables)
+    real = positions >= 0
+    np.testing.assert_allclose(
+        np.asarray(logits)[real], np.asarray(ref_logits)[real], rtol=1e-4, atol=1e-4
+    )
+    if pool == "float":
+        written = np.zeros((before["k"].shape[1], T_BLOCK), bool)
+        for lane, p in zip(*np.nonzero(real)):
+            at = positions[lane, p]
+            written[int(tables[lane, at // T_BLOCK]), at % T_BLOCK] = True
+        _assert_same_pool(got, ref, before, written, atol=1e-5)
 
 
 @pytest.mark.parametrize("table", ["pages", "scales"])
