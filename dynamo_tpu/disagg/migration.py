@@ -400,7 +400,7 @@ class MigrationCoordinator:
         ):
             async def _ship() -> None:
                 await faults.migrate_gate("transfer", taddr)
-                pages = await _engine_call(
+                pages, crcs = await _engine_call(
                     self.engine,
                     lambda: self.engine.extract_for_migration(rid),
                 )
@@ -412,14 +412,11 @@ class MigrationCoordinator:
                     "tenant": cp["tenant"],
                     "level": cp["level"],
                 }
-                if len(pages) > 4 and pages[4] is not None:
+                if crcs is not None:
                     # per-block content checksums ride the checkpoint: the
                     # target verifies the page set BEFORE staging a byte
-                    meta["crcs"] = pages[4]
-                await self.client.migrate(
-                    taddr, meta, pages[0], pages[1],
-                    (pages[2], pages[3]) if pages[2] is not None else None,
-                )
+                    meta["crcs"] = crcs
+                await self.client.migrate(taddr, meta, pages)
 
             try:
                 # one bound over the WHOLE ship (fault gate + extraction +

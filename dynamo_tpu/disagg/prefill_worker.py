@@ -32,15 +32,14 @@ import math
 import uuid
 from typing import Dict, List, Optional, Tuple
 
-import numpy as np
-
 from dynamo_tpu.disagg.protocols import (
     PREFILL_QUEUE,
     TRANSFER_KEY_PREFIX,
     RemotePrefillRequest,
 )
 from dynamo_tpu.disagg.transfer import KvTransferClient, _engine_call
-from dynamo_tpu.engine_jax.allocator import KvDtypeMismatch
+from dynamo_tpu.kv import pages as kv_pages
+from dynamo_tpu.kv.pages import KvDtypeMismatch
 from dynamo_tpu.runtime import tracing
 
 logger = logging.getLogger(__name__)
@@ -90,19 +89,18 @@ class PrefillEngine:
         token_ids: List[int],
         cached_tokens: int,
         sampling: dict,
-        prefix_kv: Optional[Tuple] = None,
+        prefix_kv: Optional[kv_pages.Pages] = None,
         as_device: bool = False,
-    ) -> Tuple[int, object, object, Optional[Tuple], int]:
-        """Compute the prompt KV; return (first_token, k_pages, v_pages,
-        scales, computed_tokens) covering blocks from
+    ) -> Tuple[int, kv_pages.Pages, int]:
+        """Compute the prompt KV; return (first_token, pages,
+        computed_tokens), the pages covering blocks from
         ``cached_tokens // block_size`` onward.
 
-        ``prefix_kv`` = (k, v, scales) pages for the full blocks of
-        ``token_ids[:cached_tokens]`` read from the decode worker (scales is
-        None for native pools, (k_scale, v_scale) for int8 pools): they are
+        ``prefix_kv`` = the pages of the full blocks of
+        ``token_ids[:cached_tokens]`` read from the decode worker: they are
         seeded into the engine's prefix cache first, so the engine computes
         only the suffix. ``as_device=True`` returns jax arrays (same-host
-        device path). Returns (first_token, k, v, scales, computed)."""
+        device path)."""
         from dynamo_tpu.llm.protocols.common import (
             PreprocessedRequest,
             SamplingOptions,
@@ -116,14 +114,11 @@ class PrefillEngine:
                 f"prompt {n} exceeds prefill max_model_len {self.max_model_len}"
             )
         if prefix_kv is not None and cached_tokens % self.block_size == 0:
-            k_pre, v_pre, pre_scales = prefix_kv
             try:
                 seeded = await _engine_call(
                     self.engine,
                     lambda: self.engine.seed_external_prefix(
-                        token_ids[:cached_tokens], k_pre, v_pre,
-                        pre_scales[0] if pre_scales else None,
-                        pre_scales[1] if pre_scales else None,
+                        token_ids[:cached_tokens], prefix_kv
                     ),
                 )
             except KvDtypeMismatch as e:
@@ -182,9 +177,8 @@ class PrefillEngine:
                     ctx.id, first_block, n_blocks, as_device=as_device
                 )
 
-            k, v, ks, vs = await _engine_call(self.engine, extract)
-            scales = (ks, vs) if ks is not None else None
-            return first_token, k, v, scales, self._computed.pop(ctx.id, -1)
+            pages = await _engine_call(self.engine, extract)
+            return first_token, pages, self._computed.pop(ctx.id, -1)
         except BaseException:
             self.engine.post(lambda: self.engine.release_held(ctx.id))
             raise
@@ -192,9 +186,9 @@ class PrefillEngine:
     def prefill(
         self, token_ids: List[int], cached_tokens: int, sampling: dict,
         as_device: bool = False,
-    ) -> Tuple[int, np.ndarray, np.ndarray]:
-        """Synchronous convenience wrapper (no prefix read-back, native-pool
-        page set). Safe to call with or without a running event loop —
+    ) -> Tuple[int, kv_pages.Pages]:
+        """Synchronous convenience wrapper (no prefix read-back). Safe to
+        call with or without a running event loop —
         inside one, the request runs on a private loop in a worker thread
         (and blocks the caller, like any sync compute would)."""
         coro = self.prefill_request(
@@ -203,13 +197,11 @@ class PrefillEngine:
         try:
             asyncio.get_running_loop()
         except RuntimeError:
-            tok, k, v, _, _ = asyncio.run(coro)
-            return tok, k, v
+            return asyncio.run(coro)[:2]
         import concurrent.futures
 
         with concurrent.futures.ThreadPoolExecutor(1) as ex:
-            tok, k, v, _, _ = ex.submit(asyncio.run, coro).result()
-            return tok, k, v
+            return ex.submit(asyncio.run, coro).result()[:2]
 
 
 def _validate_request(req, engine: "PrefillEngine") -> None:
@@ -226,11 +218,11 @@ def _validate_request(req, engine: "PrefillEngine") -> None:
         )
 
 
-def _validate_pages(req, k) -> None:
-    if k.shape[1] != len(req.block_ids):
+def _validate_pages(req, pages) -> None:
+    if kv_pages.count(pages) != len(req.block_ids):
         raise ValueError(
-            f"page count mismatch: computed {k.shape[1]}, decode expects "
-            f"{len(req.block_ids)} (block_size skew?)"
+            f"page count mismatch: computed {kv_pages.count(pages)}, decode "
+            f"expects {len(req.block_ids)} (block_size skew?)"
         )
 
 
@@ -349,7 +341,7 @@ async def run_prefill_worker(
             prefix_kv = None
             if req.cached_tokens > 0 and req.prefix_block_ids:
                 try:
-                    k_pre, v_pre, pre_scales, got_hashes = await transfer.read_blocks(
+                    read, got_hashes = await transfer.read_blocks(
                         addr, req.prefix_block_ids
                     )
                     from dynamo_tpu.kv.tokens import compute_block_hashes_for_seq
@@ -363,7 +355,7 @@ async def run_prefill_worker(
                         salt=bytes.fromhex(req.salt_hex) if req.salt_hex else None,
                     )
                     if list(got_hashes) == list(expect):
-                        prefix_kv = (k_pre, v_pre, pre_scales)
+                        prefix_kv = read
                     else:
                         logger.warning(
                             "prefix pages for %s changed since enqueue "
@@ -375,11 +367,11 @@ async def run_prefill_worker(
                         "prefix read_blocks failed for %s; recomputing full "
                         "prompt", req.request_id, exc_info=True,
                     )
-            tok, k, v, scales, computed = await engine.prefill_request(
+            tok, pages, computed = await engine.prefill_request(
                 req.token_ids, req.cached_tokens, req.sampling,
                 prefix_kv=prefix_kv, as_device=local_engine is not None,
             )
-            _validate_pages(req, k)
+            _validate_pages(req, pages)
             # the decode worker can be mid-bounce exactly when the pages are
             # ready: retry transport failures within the policy budget,
             # RE-RESOLVING the transfer address each time — a restarted
@@ -388,8 +380,7 @@ async def run_prefill_worker(
             for attempt in range(1, policy.max_attempts + 1):
                 try:
                     await transfer.send_blocks(
-                        addr, req.request_id, tok, req.block_ids, k, v,
-                        scales=scales,
+                        addr, req.request_id, tok, req.block_ids, pages
                     )
                     break
                 except (ConnectionError, OSError, asyncio.IncompleteReadError):
@@ -424,7 +415,7 @@ async def run_prefill_worker(
                 "prefilled %s%s (%d tokens, computed %d → %d pages)",
                 req.request_id,
                 " locally via device path" if local_engine is not None else "",
-                len(req.token_ids), computed, k.shape[1],
+                len(req.token_ids), computed, kv_pages.count(pages),
             )
         except Exception as e:
             # the failure is reported in-band (send_failure / local

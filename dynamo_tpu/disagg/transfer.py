@@ -16,9 +16,8 @@ import json
 import logging
 from typing import Dict, Optional
 
-import numpy as np
-
-from dynamo_tpu.engine_jax.allocator import KvDtypeMismatch, MigrationRejected
+from dynamo_tpu.kv import pages as kv_pages
+from dynamo_tpu.kv.pages import KvDtypeMismatch, MigrationRejected
 from dynamo_tpu.runtime import faults as _FAULTS
 from dynamo_tpu.runtime import integrity, tracing
 from dynamo_tpu.runtime.codec import TwoPartMessage, read_frame, write_frame
@@ -32,53 +31,11 @@ class _NoDevicePeer(Exception):
     """Peer has no device plane: fall back to the host-staged path."""
 
 
-def _pack_pages(k, v, scales, crcs=None) -> tuple:
-    """Frame header fields + body for a page set that may carry int8 scale
-    tables. Body layout: k | v | k_scale | v_scale (k and v are always the
-    same dtype+shape, as are the two scale tables, so two byte lengths
-    describe all four segments). Headers WITHOUT ``kv_dtype`` are exactly
-    the pre-int8 wire form — old peers reading a native-pool frame see no
-    difference, and a new reader treats their frames as scale-less.
-    ``crcs`` (per-block content checksums, docs/resilience.md §Silent
-    corruption) is the same kind of optional header extension: frames
-    without it — pre-integrity peers, DYN_TPU_KV_INTEGRITY=0 senders —
-    still parse everywhere; receivers simply cannot verify them."""
-    k_raw, v_raw = _pack(k), _pack(v)
-    header = {
-        "dtype": k.dtype.name, "shape": list(k.shape), "k_bytes": len(k_raw),
-    }
-    body = k_raw + v_raw
-    if scales is not None:
-        ks, vs = scales
-        ks_raw, vs_raw = _pack(ks), _pack(vs)
-        header["kv_dtype"] = "int8"
-        header["scale_dtype"] = ks.dtype.name
-        header["scale_shape"] = list(ks.shape)
-        header["ks_bytes"] = len(ks_raw)
-        body += ks_raw + vs_raw
-    if crcs is not None:
-        header["crcs"] = [int(c) for c in crcs]
-    return header, body
+# the refusal a peer from before the int8 layout gets when the pool is int8
+_OLD_PEER = "kv_dtype int8: peer lacks scale-table support"
 
 
-def _unpack_pages(h: dict, body: bytes) -> tuple:
-    """Inverse of :func:`_pack_pages`: returns (k, v, scales) where scales
-    is None for native-dtype frames (including frames from pre-int8 peers)
-    or an (k_scale, v_scale) pair."""
-    k_len = h["k_bytes"]
-    k = _unpack(body[:k_len], h["dtype"], h["shape"])
-    v = _unpack(body[k_len : 2 * k_len], h["dtype"], h["shape"])
-    if h.get("kv_dtype") != "int8":
-        return k, v, None
-    ks_len = h["ks_bytes"]
-    off = 2 * k_len
-    ks = _unpack(body[off : off + ks_len], h["scale_dtype"], h["scale_shape"])
-    vs = _unpack(body[off + ks_len : off + 2 * ks_len], h["scale_dtype"],
-                 h["scale_shape"])
-    return k, v, (ks, vs)
-
-
-def _sender_crcs(engine, ids, k, v, ks, vs):
+def _sender_crcs(engine, ids, pages):
     """Per-block content checksums a sender ships next to its pages:
     seal-registry values where the block is sealed (those catch storage
     rot between seal and send), extract-time values otherwise (wire-scope
@@ -87,21 +44,11 @@ def _sender_crcs(engine, ids, k, v, ks, vs):
     the engine thread when ``engine`` has a crc registry."""
     if not integrity.enabled():
         return None
-    ids = list(ids)
-    regs = (
-        engine.block_crcs_of(ids)
-        if hasattr(engine, "block_crcs_of") else [-1] * len(ids)
+    sealed = (
+        engine.block_crcs_of(list(ids))
+        if hasattr(engine, "block_crcs_of") else None
     )
-    out = []
-    for i, c in enumerate(regs):
-        if c is None or c < 0:
-            c = integrity.entry_checksum(
-                k[:, i], v[:, i],
-                ks[:, i] if ks is not None else None,
-                vs[:, i] if vs is not None else None,
-            )
-        out.append(int(c))
-    return out
+    return kv_pages.checksums(pages, sealed)
 
 
 def _engine_call(engine, fn):
@@ -129,18 +76,6 @@ def _engine_call(engine, fn):
 
     engine.post(run)
     return fut
-
-
-def _pack(arr: np.ndarray) -> bytes:
-    # bfloat16 isn't a standard numpy dtype everywhere: ship as raw bytes +
-    # dtype string (ml_dtypes provides bfloat16 in this stack)
-    return arr.tobytes()
-
-
-def _unpack(raw: bytes, dtype: str, shape) -> np.ndarray:
-    import ml_dtypes  # noqa: F401  (registers bfloat16 with numpy)
-
-    return np.frombuffer(raw, dtype=np.dtype(dtype)).reshape(shape)
 
 
 class KvTransferServer:
@@ -183,8 +118,14 @@ class KvTransferServer:
                 except (asyncio.IncompleteReadError, ConnectionError):
                     return
                 h = json.loads(frame.header)
+
+                async def reply(**fields):
+                    await write_frame(writer, TwoPartMessage(
+                        json.dumps({"id": h.get("id"), **fields}).encode(), b""
+                    ))
+
                 if h.get("op") == "kv_blocks":
-                    k, v, scales = _unpack_pages(h, frame.body)
+                    pages = kv_pages.unpack(h, frame.body)
                     # content verification BEFORE the engine sees a byte
                     # (docs/resilience.md §Silent corruption): a frame that
                     # fails its travelling checksums nacks typed — the
@@ -193,30 +134,26 @@ class KvTransferServer:
                     # prefill, corrupt pages never land in the pool
                     if h.get("crcs") is not None and integrity.enabled():
                         try:
-                            integrity.verify_pages(
-                                k, v, scales, h["crcs"], where="kv_blocks",
+                            kv_pages.verify(
+                                pages, h["crcs"], where="kv_blocks"
                             )
                         except KvIntegrityError as e:
                             integrity.note_remote_failure("kv_blocks")
                             self.engine.fail_remote_prefill(
                                 h["request_id"], f"kv integrity: {e}"
                             )
-                            await write_frame(writer, TwoPartMessage(
-                                json.dumps({
-                                    "id": h.get("id"), "ok": False,
-                                    "int8": True,
-                                    "code": "KvIntegrityError",
-                                    "error": str(e),
-                                }).encode(), b""))
+                            await reply(
+                                ok=False, int8=True, code="KvIntegrityError",
+                                error=str(e),
+                            )
                             continue
                     # dtype skew (an int8 frame into a native pool, or a
                     # pre-int8 peer's frame into an int8 pool) surfaces as a
                     # typed fallback inside complete_remote_prefill — never
                     # as corrupt pages
                     self.engine.complete_remote_prefill(
-                        h["request_id"], h["first_token"], h["block_ids"], k, v,
-                        scales[0] if scales else None,
-                        scales[1] if scales else None,
+                        h["request_id"], h["first_token"], h["block_ids"],
+                        pages,
                     )
                 elif h.get("op") == "read_blocks":
                     # prefill worker reading this decode worker's cached
@@ -226,29 +163,22 @@ class KvTransferServer:
                     # since the request was enqueued — stale reads would
                     # otherwise poison its prefix cache with wrong KV.
                     def _extract(ids=h["block_ids"]):
-                        k, v, ks, vs = self.engine.extract_blocks(ids)
+                        pages = self.engine.extract_blocks(ids)
                         return (
-                            k, v, ks, vs, self.engine.block_hashes_of(ids),
-                            _sender_crcs(self.engine, ids, k, v, ks, vs),
+                            pages, self.engine.block_hashes_of(ids),
+                            _sender_crcs(self.engine, ids, pages),
                         )
 
-                    k, v, ks, vs, hashes, crcs = await _engine_call(
+                    pages, hashes, crcs = await _engine_call(
                         self.engine, _extract
                     )
-                    if ks is not None and not h.get("int8_ok"):
+                    if not (kv_pages.is_native(pages) or h.get("int8_ok")):
                         # pre-int8 peer reading an int8 pool: its fixed
                         # two-segment unpack would misparse the 4-segment
                         # body — refuse with a typed error instead
-                        await write_frame(writer, TwoPartMessage(
-                            json.dumps({
-                                "id": h.get("id"), "ok": False, "int8": True,
-                                "error": "kv_dtype int8: peer lacks scale-"
-                                         "table support",
-                            }).encode(), b""))
+                        await reply(ok=False, int8=True, error=_OLD_PEER)
                         continue
-                    hdr, body = _pack_pages(
-                        k, v, (ks, vs) if ks is not None else None, crcs=crcs,
-                    )
+                    hdr, body = kv_pages.pack(pages, crcs)
                     if _FAULTS.current() is not None:
                         # wire leg of the silent-corruption drill: a rotten
                         # worker SERVING its cached pages — the flip is
@@ -269,49 +199,38 @@ class KvTransferServer:
                     # device path: stage the pages on the device plane and
                     # return a pull descriptor instead of the bytes
                     if self.device_plane is None:
-                        await write_frame(writer, TwoPartMessage(
-                            json.dumps({"id": h.get("id"), "ok": False,
-                                        "error": "no device plane"}).encode(), b""))
+                        await reply(ok=False, error="no device plane")
                         continue
 
                     def _extract_dev(ids=h["block_ids"]):
-                        k, v, ks, vs = self.engine.extract_blocks(
-                            ids, as_device=True
+                        return (
+                            self.engine.extract_blocks(ids, as_device=True),
+                            self.engine.block_hashes_of(ids),
                         )
-                        return k, v, ks, vs, self.engine.block_hashes_of(ids)
 
-                    k, v, ks, vs, hashes = await _engine_call(
+                    pages, hashes = await _engine_call(
                         self.engine, _extract_dev
                     )
-                    if ks is not None and not h.get("int8_ok"):
+                    if not (kv_pages.is_native(pages) or h.get("int8_ok")):
                         # pre-int8 peer: it would pull the 4-array stage,
                         # keep [k, v], and inject raw int8 values as native
                         # KV — silent corruption. Refuse instead; its TCP
                         # fallback then fails loudly.
-                        await write_frame(writer, TwoPartMessage(
-                            json.dumps({
-                                "id": h.get("id"), "ok": False, "int8": True,
-                                "error": "kv_dtype int8: peer lacks scale-"
-                                         "table support",
-                            }).encode(), b""))
+                        await reply(ok=False, int8=True, error=_OLD_PEER)
                         continue
-                    staged = [k, v] if ks is None else [k, v, ks, vs]
-                    uid, specs = self.device_plane.stage(staged)
-                    await write_frame(writer, TwoPartMessage(
-                        json.dumps({
-                            "id": h.get("id"), "ok": True, "int8": True,
-                            "uuid": uid, "specs": specs, "hashes": hashes,
-                            "dev_addr": self.device_plane.address(),
-                            **({"kv_dtype": "int8"} if ks is not None else {}),
-                        }).encode(), b""))
+                    uid, specs = self.device_plane.stage(
+                        kv_pages.arrays(pages)
+                    )
+                    await reply(
+                        ok=True, int8=True, uuid=uid, specs=specs,
+                        hashes=hashes, dev_addr=self.device_plane.address(),
+                    )
                     continue
                 elif h.get("op") == "kv_blocks_dev":
                     # prefill staged its computed pages; pull them into our
                     # device memory, then inject
                     if self.device_plane is None:
-                        await write_frame(writer, TwoPartMessage(
-                            json.dumps({"id": h.get("id"), "ok": False,
-                                        "error": "no device plane"}).encode(), b""))
+                        await reply(ok=False, error="no device plane")
                         continue
                     pulled = await asyncio.to_thread(
                         self.device_plane.pull,
@@ -319,9 +238,7 @@ class KvTransferServer:
                     )
                     self.engine.complete_remote_prefill(
                         h["request_id"], h["first_token"], h["block_ids"],
-                        pulled[0], pulled[1],
-                        pulled[2] if len(pulled) > 2 else None,
-                        pulled[3] if len(pulled) > 3 else None,
+                        kv_pages.from_arrays(pulled),
                     )
                 elif h.get("op") == "release_dev":
                     # client pulled: free the staged device arrays now
@@ -335,7 +252,7 @@ class KvTransferServer:
                     # inject + seal) or raises a typed rejection — the nack
                     # below tells the source to degrade that stream to the
                     # resume path; nothing is ever partially staged.
-                    k, v, scales = _unpack_pages(h, frame.body)
+                    pages = kv_pages.unpack(h, frame.body)
                     meta = h.get("migrate") or {}
                     # quarantine × migration composition (docs/chaos.md): a
                     # latch landing mid-ship must abort the in-flight
@@ -346,22 +263,16 @@ class KvTransferServer:
                     # can be a beat stale; the typed nack degrades the
                     # stream to the resume path, same as any rejection.
                     if integrity.enabled() and integrity.quarantined():
-                        await write_frame(writer, TwoPartMessage(
-                            json.dumps({
-                                "id": h.get("id"), "ok": False, "int8": True,
-                                "code": "MigrationRejected",
-                                "error": "target quarantined: refusing to "
-                                         "stage migrated KV pages",
-                            }).encode(), b""))
+                        await reply(
+                            ok=False, int8=True, code="MigrationRejected",
+                            error="target quarantined: refusing to stage "
+                                  "migrated KV pages",
+                        )
                         continue
                     try:
                         res = await _engine_call(
                             self.engine,
-                            lambda: self.engine.stage_migration(
-                                meta, k, v,
-                                scales[0] if scales else None,
-                                scales[1] if scales else None,
-                            ),
+                            lambda: self.engine.stage_migration(meta, pages),
                         )
                     except (MigrationRejected, KvDtypeMismatch,
                             KeyError, ValueError, TypeError) as e:
@@ -371,26 +282,16 @@ class KvTransferServer:
                         # against itself and degrades the stream to resume
                         if isinstance(e, KvIntegrityError):
                             integrity.note_remote_failure("migrate_stage")
-                        await write_frame(writer, TwoPartMessage(
-                            json.dumps({
-                                "id": h.get("id"), "ok": False, "int8": True,
-                                "code": type(e).__name__, "error": str(e),
-                            }).encode(), b""))
+                        await reply(
+                            ok=False, int8=True, code=type(e).__name__,
+                            error=str(e),
+                        )
                         continue
-                    await write_frame(writer, TwoPartMessage(
-                        json.dumps({
-                            "id": h.get("id"), "ok": True, "int8": True,
-                            "staged": res,
-                        }).encode(), b""))
+                    await reply(ok=True, int8=True, staged=res)
                     continue
                 elif h.get("op") == "prefill_failed":
                     self.engine.fail_remote_prefill(h["request_id"], h.get("message", ""))
-                await write_frame(
-                    writer,
-                    TwoPartMessage(json.dumps(
-                        {"id": h.get("id"), "ok": True, "int8": True}
-                    ).encode(), b""),
-                )
+                await reply(ok=True, int8=True)
         finally:
             writer.close()
 
@@ -411,8 +312,8 @@ class LocalKvTransfer:
         self.decode = decode_engine
 
     async def send_blocks(
-        self, address: str, request_id: str, first_token: int, block_ids, k, v,
-        scales=None,
+        self, address: str, request_id: str, first_token: int, block_ids,
+        pages,
     ) -> None:
         # address ignored: the target is in-process
         tracing.record_event_span(
@@ -423,8 +324,7 @@ class LocalKvTransfer:
                         "request_id": request_id},
         )
         self.decode.complete_remote_prefill(
-            request_id, first_token, list(block_ids), k, v,
-            scales[0] if scales else None, scales[1] if scales else None,
+            request_id, first_token, list(block_ids), pages
         )
 
     async def send_failure(self, address: str, request_id: str, message: str) -> None:
@@ -432,15 +332,15 @@ class LocalKvTransfer:
 
     async def read_blocks(self, address: str, block_ids) -> tuple:
         """Device path: pages come back as jax arrays, never touching host.
-        Returns (k, v, scales, hashes) — scales is None for native pools,
-        (k_scale, v_scale) for int8 pools; hashes ride along for the same
-        staleness validation as the TCP path."""
+        Returns (pages, hashes); hashes ride along for the same staleness
+        validation as the TCP path."""
         ids = list(block_ids)
 
         def _extract():
-            k, v, ks, vs = self.decode.extract_blocks(ids, as_device=True)
-            scales = (ks, vs) if ks is not None else None
-            return k, v, scales, self.decode.block_hashes_of(ids)
+            return (
+                self.decode.extract_blocks(ids, as_device=True),
+                self.decode.block_hashes_of(ids),
+            )
 
         return await _engine_call(self.decode, _extract)
 
@@ -511,16 +411,13 @@ class KvTransferClient:
         request_id: str,
         first_token: int,
         block_ids,
-        k,
-        v,
-        scales=None,
+        pages,
     ) -> None:
         # kv_transfer span: the wire (or device-fabric) time of shipping the
         # computed pages — nests under the prefill worker's request span via
-        # the ambient contextvar. ``scales`` = (k_scale, v_scale) per-token
-        # tables when the pages come from an int8 pool; the header then
-        # carries kv_dtype so the receiver can refuse a layout it doesn't
-        # speak instead of writing corrupt pages.
+        # the ambient contextvar. The frame's header says what layout the
+        # pages have, so the receiver can refuse one it doesn't speak
+        # instead of writing corrupt pages.
         with tracing.span(
             "disagg.kv_transfer",
             parent=tracing.current_span(),
@@ -535,34 +432,26 @@ class KvTransferClient:
             # (their fixed two-segment unpack fails loudly, never injects),
             # and its ack teaches us the capability for later transfers.
             if self._use_dev(address) and (
-                scales is None or self._int8_peers.get(address, False)
+                kv_pages.is_native(pages)
+                or self._int8_peers.get(address, False)
             ):
                 try:
                     await self._send_blocks_dev(
-                        address, request_id, first_token, block_ids, k, v,
-                        scales,
+                        address, request_id, first_token, block_ids, pages
                     )
                     if tspan is not None:
                         tspan.set_attribute("path", "device")
                     return
                 except _NoDevicePeer:
                     self._dev_peers[address] = False  # fall through to TCP
-            k, v = np.asarray(k), np.asarray(v)
-            if scales is not None:
-                scales = (np.asarray(scales[0]), np.asarray(scales[1]))
+            pages = kv_pages.to_host(pages)
             # content checksums travel with the pages (header extension;
             # receivers without the plane ignore them). Computed BEFORE the
             # corrupt-fault gate below — the drill models post-checksum
             # corruption, which is what the receiver's verify must catch.
-            crcs = (
-                integrity.page_checksums(
-                    k, v,
-                    scales[0] if scales is not None else None,
-                    scales[1] if scales is not None else None,
-                ) if integrity.enabled() else None
-            )
+            crcs = kv_pages.checksums(pages) if integrity.enabled() else None
             reader, writer = await self._conn(address)
-            header, body = _pack_pages(k, v, scales, crcs=crcs)
+            header, body = kv_pages.pack(pages, crcs)
             if _FAULTS.current() is not None:
                 body = _FAULTS.corrupt_pages(
                     "transfer", self.fault_addr or address, body
@@ -602,14 +491,13 @@ class KvTransferClient:
                 )
 
     async def _send_blocks_dev(
-        self, address, request_id, first_token, block_ids, k, v, scales=None
+        self, address, request_id, first_token, block_ids, pages
     ) -> None:
         import jax.numpy as jnp
 
-        arrs = [jnp.asarray(k), jnp.asarray(v)]
-        if scales is not None:
-            arrs += [jnp.asarray(scales[0]), jnp.asarray(scales[1])]
-        uid, specs = self.device_plane.stage(arrs)
+        uid, specs = self.device_plane.stage(
+            [jnp.asarray(a) for a in kv_pages.arrays(pages)]
+        )
         try:
             reader, writer = await self._conn(address)
             header = {
@@ -635,11 +523,10 @@ class KvTransferClient:
 
     async def read_blocks(self, address: str, block_ids) -> tuple:
         """Pull KV pages from a decode worker's pool by physical id.
-        Returns (k, v, scales, hashes): [L, n, bs, KVH, D] pages, the
-        (k_scale, v_scale) per-token tables when the peer's pool is int8
-        (None otherwise — including pre-int8 peers), plus each page's
-        registered content hash (-1 = no longer registered). Device-path
-        when both ends have a plane, host-staged TCP otherwise."""
+        Returns (pages, hashes): the page set in the layout of the peer's
+        pool (a pre-int8 peer's is native), plus each page's registered
+        content hash (-1 = no longer registered). Device-path when both
+        ends have a plane, host-staged TCP otherwise."""
         with tracing.span(
             "disagg.kv_transfer",
             parent=tracing.current_span(),
@@ -672,23 +559,21 @@ class KvTransferClient:
             self._note_caps(address, h)
             if h.get("ok") is False:
                 raise KvDtypeMismatch(h.get("error", "peer refused page read"))
-            k, v, scales = _unpack_pages(h, frame.body)
+            pages = kv_pages.unpack(h, frame.body)
             if h.get("crcs") is not None and integrity.enabled():
                 # the peer's cached pages must match the checksums sealed
                 # when they were computed: rot in ITS pool/wire surfaces
                 # here as a typed error — callers recompute instead of
                 # seeding corrupt KV into their own prefix cache
                 try:
-                    integrity.verify_pages(
-                        k, v, scales, h["crcs"], where="read_blocks",
-                    )
+                    kv_pages.verify(pages, h["crcs"], where="read_blocks")
                 except KvIntegrityError:
                     integrity.note_remote_failure("read_blocks")
                     raise
             if tspan is not None:
                 tspan.set_attribute("path", "tcp")
                 tspan.set_attribute("bytes", len(frame.body))
-            return k, v, scales, h.get("hashes") or [-1] * k.shape[1]
+            return pages, h.get("hashes") or [-1] * kv_pages.count(pages)
 
     async def _read_blocks_dev(self, address: str, block_ids) -> tuple:
         reader, writer = await self._conn(address)
@@ -721,9 +606,8 @@ class KvTransferClient:
                     b"",
                 ))
                 await read_frame(reader)
-        scales = (pulled[2], pulled[3]) if len(pulled) > 3 else None
         return (
-            pulled[0], pulled[1], scales,
+            kv_pages.from_arrays(pulled),
             h.get("hashes") or [-1] * len(block_ids),
         )
 
@@ -741,8 +625,7 @@ class KvTransferClient:
             )
             await read_frame(reader)
 
-    async def migrate(self, address: str, meta: dict, k, v,
-                      scales=None) -> dict:
+    async def migrate(self, address: str, meta: dict, pages) -> dict:
         """Ship one live-migrating stream's checkpoint + history pages to
         ``address`` atomically (docs/resilience.md §Live migration). The
         target stages the pages ahead of the re-homed client's admission;
@@ -751,14 +634,12 @@ class KvTransferClient:
         failures raise as usual — the caller degrades the stream to the
         resume path in every failure case. Returns the ack's ``staged``
         summary."""
-        k, v = np.asarray(k), np.asarray(v)
-        if scales is not None:
-            scales = (np.asarray(scales[0]), np.asarray(scales[1]))
+        pages = kv_pages.to_host(pages)
         with tracing.span(
             "disagg.kv_transfer",
             parent=tracing.current_span(),
             phase="kv_transfer",
-            attributes={"op": "migrate", "pages": int(k.shape[1]),
+            attributes={"op": "migrate", "pages": kv_pages.count(pages),
                         "address": address,
                         "request_id": meta.get("request_id", "")},
         ) as tspan:
@@ -767,7 +648,7 @@ class KvTransferClient:
             # checksums); the corrupt-fault gate below models a source
             # whose bytes rot AFTER checksumming — the target's staging
             # verify must nack it
-            header, body = _pack_pages(k, v, scales)
+            header, body = kv_pages.pack(pages)
             if _FAULTS.current() is not None:
                 body = _FAULTS.corrupt_pages(
                     "transfer", self.fault_addr or address, body

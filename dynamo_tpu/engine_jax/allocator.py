@@ -23,23 +23,9 @@ from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Protocol, Sequence, Tuple
 
+from dynamo_tpu.kv import pages as kv_pages
 from dynamo_tpu.kv.tokens import TokenBlockSequence, compute_block_hashes_for_seq
-
-
-class KvDtypeMismatch(TypeError):
-    """KV pages and the target pool disagree on the storage layout (int8
-    pages+scales vs native dtype). Raised instead of writing mismatched
-    bytes into the pool — a dtype skew must surface as a clean typed error,
-    never as silently corrupt pages. The disagg transfer plane maps it to a
-    prefill-failure reply so the decode side falls back to local prefill."""
-
-
-class MigrationRejected(RuntimeError):
-    """A target engine refused to stage a live-migrated stream (out of KV
-    blocks, block-size/page-count mismatch, history longer than its
-    max_model_len). Typed so the transfer plane's ``migrate`` op nacks
-    cleanly and the source degrades that stream to the ordinary resume
-    path — never a torn page set (docs/resilience.md §Live migration)."""
+from dynamo_tpu.runtime import integrity
 
 
 class KvEventSink(Protocol):
@@ -68,15 +54,13 @@ class SequenceAllocation:
     # single-tenant path — no per-tenant dict is ever touched.
     tenant: str = ""
     level: int = 0
-    # host-tier prefix hits: (logical block index, sequence hash, k, v,
-    # k_scale, v_scale, crc) with the content captured at probe time (a
-    # later offload into the LRU pool can't invalidate them). The scale
-    # entries are None for native-dtype pools and [L, bs] float32 tables
-    # for int8 pools — scales travel WITH their pages through every tier;
+    # host-tier prefix hits: (logical block index, sequence hash, block,
+    # crc) with the content (one block of kv/pages.py) captured at probe
+    # time (a later offload into the LRU pool can't invalidate them);
     # ``crc`` is the seal-time content checksum (None with integrity off),
     # already VERIFIED at probe time. The engine must inject each into
     # block_ids[index] before any compute touches the sequence.
-    host_hits: List[Tuple[int, int, Any, Any, Any, Any, Any]] = field(default_factory=list)
+    host_hits: List[Tuple[int, int, Any, Any]] = field(default_factory=list)
     # full-prompt block hashes this sequence advertised as in-flight (it will
     # compute + seal them); unregistered on free if still unsealed
     pending_hashes: List[int] = field(default_factory=list)
@@ -107,13 +91,12 @@ class HostKvPool:
 
     def __init__(self, max_blocks: int):
         self.max_blocks = max_blocks
-        # hash → (k, v, k_scale, v_scale, crc); scales are None for native-
-        # dtype pools and per-token tables for int8 pools — the pool is
-        # payload-agnostic so both layouts ride the same LRU. ``crc`` is the
-        # block's seal-time content checksum (None with the integrity plane
-        # off / from pre-integrity spills): verified at rehit so bad host
-        # RAM surfaces as a prefix miss, never as corrupt device pages.
-        self._data: "OrderedDict[int, Tuple[Any, Any, Any, Any, Any]]" = OrderedDict()
+        # hash → (block, crc): one block of kv/pages.py, whatever members
+        # its pool has. ``crc`` is the block's seal-time content checksum
+        # (None with the integrity plane off / from pre-integrity spills):
+        # verified at rehit so bad host RAM surfaces as a prefix miss,
+        # never as corrupt device pages.
+        self._data: "OrderedDict[int, Tuple[Any, Any]]" = OrderedDict()
         self.hits = 0
         self.offloaded = 0
 
@@ -123,16 +106,16 @@ class HostKvPool:
     def __len__(self) -> int:
         return len(self._data)
 
-    def put(self, h: int, k, v, k_scale=None, v_scale=None, crc=None) -> None:
+    def put(self, h: int, block, crc=None) -> None:
         if h in self._data:
             self._data.move_to_end(h)
             return
         while len(self._data) >= self.max_blocks:
             self._data.popitem(last=False)
-        self._data[h] = (k, v, k_scale, v_scale, crc)
+        self._data[h] = (block, crc)
         self.offloaded += 1
 
-    def get(self, h: int) -> Optional[Tuple[Any, Any, Any, Any, Any]]:
+    def get(self, h: int) -> Optional[Tuple[Any, Any]]:
         item = self._data.get(h)
         if item is not None:
             self._data.move_to_end(h)
@@ -352,21 +335,20 @@ class BlockAllocator:
         # RAM) is dropped from the pool and treated as a prefix miss: the
         # chain ends and the prompt recomputes from there, corrupt KV never
         # reaches the device pool.
-        host_hits: List[Tuple[int, int, Any, Any, Any, Any, Any]] = []
+        host_hits: List[Tuple[int, int, Any, Any]] = []
         if self.host_pool is not None:
             j = len(reused)
             while j < max_cacheable:
                 item = self.host_pool.get(seq_hashes[j])
                 if item is None:
                     break
-                if self._checksum is not None and item[4] is not None:
-                    from dynamo_tpu.runtime import integrity
-
-                    if integrity.entry_checksum(*item[:4]) != item[4]:
+                block, crc = item
+                if self._checksum is not None and crc is not None:
+                    if kv_pages.block_checksum(block) != crc:
                         self.host_pool.discard(seq_hashes[j])
                         integrity.note_trip("kv", where="host_rehit")
                         break
-                host_hits.append((j, seq_hashes[j]) + tuple(item))
+                host_hits.append((j, seq_hashes[j], block, crc))
                 j += 1
 
         # shared in-flight prefill: if the next missing block is being
@@ -393,7 +375,7 @@ class BlockAllocator:
         # host-hit blocks become valid device content once the engine injects
         # them; register their hashes so the next request hits the device tier
         stored: List[Tuple[int, List[int]]] = []
-        for idx, h, *rest in host_hits:
+        for idx, h, _, crc in host_hits:
             bid = block_ids[idx]
             prior = self._hash_of.get(bid)
             if prior is not None and prior != h:
@@ -401,10 +383,10 @@ class BlockAllocator:
             if h not in self._by_hash:
                 self._by_hash[h] = bid
                 self._hash_of[bid] = h
-                if self._checksum is not None and rest[4] is not None:
+                if self._checksum is not None and crc is not None:
                     # the (verified) host entry's seal checksum describes
                     # the bytes about to be injected into this page
-                    self._crc_of[bid] = rest[4]
+                    self._crc_of[bid] = crc
                 stored.append(
                     (h, list(token_ids[idx * self.block_size : (idx + 1) * self.block_size]))
                 )

@@ -16,7 +16,7 @@ import threading
 _DEFAULT = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(__file__))), ".jax_cache")
 
 # process-global count of jitted-program builds (engine step-fn variants,
-# counts syncs, inject scatters). A steady-state engine compiles a handful
+# counts syncs). A steady-state engine compiles a handful
 # at boot and then NEVER again — a climbing count mid-traffic means some
 # shape leaked into a jit signature and every bump stalled decode for a
 # full compile. Surfaced live as ForwardPassMetrics.jit_recompiles.

@@ -44,9 +44,7 @@ from dynamo_tpu.engine_jax.allocator import (
     BlockAllocator,
     HostKvPool,
     InflightPrefix,
-    KvDtypeMismatch,
     KvEventSink,
-    MigrationRejected,
     SequenceAllocation,
 )
 from dynamo_tpu.engine_jax.drafter import (
@@ -81,9 +79,10 @@ from dynamo_tpu.models.llama import (
     lm_head,
     make_kv_cache,
     quantize_kv,
-    take_blocks,
 )
 from dynamo_tpu.engine_jax.compile_cache import compile_count, record_compile
+from dynamo_tpu.kv import pages as kv_pages
+from dynamo_tpu.kv.pages import KvDtypeMismatch, MigrationRejected
 from dynamo_tpu.runtime import faults as faults_mod
 from dynamo_tpu.runtime import integrity as integrity_mod
 from dynamo_tpu.runtime import profiling as profiling_mod
@@ -639,11 +638,10 @@ class JaxServingEngine(AsyncEngine):
         self._hold_ids: set = set()
         self._held_allocs: Dict[str, SequenceAllocation] = {}
 
-        # host-tier spills in flight: (pairs, k_dev, v_dev, k_scale_dev,
-        # v_scale_dev) whose async host copies haven't been harvested into
-        # the host pool yet (scale entries None for native-dtype pools)
+        # host-tier spills in flight: (pairs, device pages) whose async host
+        # copies haven't been harvested into the host pool yet
         self._pending_spills: Deque[
-            Tuple[List[Tuple[int, int]], Any, Any, Any, Any]
+            Tuple[List[Tuple[int, int, Any]], kv_pages.Pages]
         ] = deque()
 
         # live in-flight migration (disagg/migration.py, docs/resilience.md
@@ -3187,27 +3185,21 @@ class JaxServingEngine(AsyncEngine):
         (called from the engine thread; submit must be thread-safe)."""
         self._remote_policy = policy
 
-    def extract_blocks(self, block_ids: List[int], as_device: bool = False):
-        """Copy KV pages out of the pool: ``(k, v, k_scale, v_scale)`` with
-        pages [L, n, bs, KVH, D] ×2 and, for int8 pools, the per-token scale
-        tables [L, n, bs] ×2 (None on native-dtype pools — scales travel
-        WITH their pages through every transfer tier). Host numpy, or device
+    def extract_blocks(
+        self, block_ids: List[int], as_device: bool = False
+    ) -> kv_pages.Pages:
+        """Copy KV pages out of the pool: a page set of kv/pages.py, every
+        member of the pool ``[L, n, bs, ...]`` (scale tables travel WITH
+        their pages through every transfer tier). Host numpy, or device
         arrays with ``as_device`` (same-host transfers keep pages on-device
         and let XLA reshard at the destination's inject boundary).
         MUST run on the engine thread (e.g. via post())."""
-        taken = take_blocks(self.cache, jnp.asarray(block_ids, jnp.int32))
-        arrs = [taken[name] for name in ("k", "v", "k_scale", "v_scale") if name in taken]
+        taken = kv_pages.take(self.cache, block_ids)
         if as_device:
-            out = list(arrs)
-        else:
-            for a in arrs:
-                a.copy_to_host_async()
-            # dynlint: allow-host-sync(page extraction for KV transfer; off
-            # the decode loop, copies started async above)
-            out = [np.asarray(x) for x in jax.device_get(arrs)]
-        while len(out) < 4:
-            out.append(None)
-        return tuple(out)
+            return taken
+        # dynlint: allow-host-sync(page extraction for KV transfer; off the
+        # decode loop, every member's copy started before the first wait)
+        return kv_pages.to_host(taken)
 
     def block_hashes_of(self, block_ids: List[int]) -> List[int]:
         """The allocator-registered content hash per physical page (-1 for a
@@ -3230,40 +3222,26 @@ class JaxServingEngine(AsyncEngine):
         the integrity plane's steady-state cost — one small device→host
         copy per sealed block, knob-gated by DYN_TPU_KV_INTEGRITY. MUST
         run on the engine thread (note_tokens_computed call sites)."""
-        k, v, ks, vs = self.extract_blocks(block_ids)
-        return integrity_mod.page_checksums(k, v, ks, vs)
+        return kv_pages.checksums(self.extract_blocks(block_ids))
 
     def seed_external_prefix(
-        self, token_ids: List[int], k_pages, v_pages,
-        k_scale=None, v_scale=None,
+        self, token_ids: List[int], pages: kv_pages.Pages
     ) -> int:
         """Register externally-computed prefix KV (pages read from another
         worker) into this engine's prefix cache: allocator registration +
-        page injection, atomically on the engine thread. ``k_pages`` covers
-        ALL full blocks of ``token_ids`` ([L, n_full, bs, KVH, D]); already-
-        cached blocks are skipped. int8 pools require the matching per-token
-        scale tables ([L, n_full, bs]). Returns the number of blocks seeded.
+        page injection, atomically on the engine thread. ``pages`` covers
+        ALL full blocks of ``token_ids``; already-cached blocks are skipped.
+        Returns the number of blocks seeded.
         MUST run on the engine thread (via post())."""
-        if self._kv_quantized != (k_scale is not None):
-            # check BEFORE touching the allocator: a mismatch must not leave
-            # seeded-but-never-injected hashes in the prefix cache
-            raise KvDtypeMismatch(
-                "pool kv_dtype is %s but pages %s scale tables" % (
-                    "int8" if self._kv_quantized else "native",
-                    "lack" if k_scale is None else "carry",
-                )
-            )
+        # check BEFORE touching the allocator: a mismatch must not leave
+        # seeded-but-never-injected hashes in the prefix cache
+        kv_pages.check(self.cache, pages)
         pairs = self.allocator.seed_cached(token_ids)
         if not pairs:
             return 0
-        block_ids = [bid for _, bid in pairs]
-        sel = [i for i, _ in pairs]
-        if isinstance(k_pages, jax.Array):
-            sel = jnp.asarray(sel, jnp.int32)
         self.inject_blocks(
-            block_ids, k_pages[:, sel], v_pages[:, sel],
-            k_scale[:, sel] if k_scale is not None else None,
-            v_scale[:, sel] if v_scale is not None else None,
+            [bid for _, bid in pairs],
+            kv_pages.select(pages, [i for i, _ in pairs]),
         )
         return len(pairs)
 
@@ -3351,31 +3329,23 @@ class JaxServingEngine(AsyncEngine):
     def extract_for_migration(self, request_id: str):
         """Copy a frozen sequence's computed-history pages out of the pool:
         blocks covering positions 0..N-2 (the last sampled token was never
-        fed, so its position has no KV anywhere). Returns ``(k, v,
-        k_scale, v_scale, crcs)`` — ``crcs`` is the per-block content
-        checksum list the migrate frame ships (seal-time registry values
-        where the block is sealed, extract-time values for the partial
-        tail; None with the integrity plane off). MUST run on the engine
-        thread."""
+        fed, so its position has no KV anywhere). Returns ``(pages,
+        crcs)`` — ``crcs`` is the per-block content checksum list the
+        migrate frame ships (seal-time registry values where the block is
+        sealed, extract-time values for the partial tail; None with the
+        integrity plane off). MUST run on the engine thread."""
         seq = self._migrating_out[request_id]  # KeyError → coordinator aborts
         n_hist = len(seq.prompt) + len(seq.generated) - 1
         n_blocks = (n_hist + self.config.kv_block_size - 1) // self.config.kv_block_size
         bids = seq.alloc.block_ids[:n_blocks]
-        k, v, ks, vs = self.extract_blocks(bids)
+        pages = self.extract_blocks(bids)
         crcs = None
         if self._integrity is not None:
             # seal-time checksums where the owner can vouch for the block
             # (catches HBM rot between seal and drain); the unsealed tail
             # gets extract-time checksums — wire-scope protection only
-            crcs = self.block_crcs_of(bids)
-            for i, c in enumerate(crcs):
-                if c < 0:
-                    crcs[i] = integrity_mod.entry_checksum(
-                        k[:, i], v[:, i],
-                        ks[:, i] if ks is not None else None,
-                        vs[:, i] if vs is not None else None,
-                    )
-        return k, v, ks, vs, crcs
+            crcs = kv_pages.checksums(pages, self.block_crcs_of(bids))
+        return pages, crcs
 
     def finish_migrated(self, request_id: str, target_instance: str,
                         target_worker: str, mid: str) -> None:
@@ -3477,8 +3447,7 @@ class JaxServingEngine(AsyncEngine):
             ttl = self._staged_ttl = MigrationPolicy.from_env().staged_ttl
         return ttl
 
-    def stage_migration(self, meta: dict, k_np, v_np, k_scale=None,
-                        v_scale=None) -> dict:
+    def stage_migration(self, meta: dict, pages: kv_pages.Pages) -> dict:
         """Target side: adopt a migrating stream's KV pages ahead of its
         client's re-homed admission. Validates layout, allocates for the
         full N-token history, injects the wire pages over everything the
@@ -3498,24 +3467,13 @@ class JaxServingEngine(AsyncEngine):
                 f"{self.config.max_model_len}"
             )
         bs = self.config.kv_block_size
-        if self._kv_quantized != (k_scale is not None):
-            raise KvDtypeMismatch(
-                "pool kv_dtype is %s but migrated pages %s scale tables" % (
-                    "int8" if self._kv_quantized else "native",
-                    "lack" if k_scale is None else "carry",
-                )
-            )
-        if k_np.shape[2] != bs:
-            raise MigrationRejected(
-                f"migrated pages have block_size {k_np.shape[2]}, engine "
-                f"uses {bs}"
-            )
+        kv_pages.check(self.cache, pages)
         n_hist = len(toks) - 1
         n_blocks = (n_hist + bs - 1) // bs
-        if k_np.shape[1] != n_blocks:
+        if kv_pages.count(pages) != n_blocks:
             raise MigrationRejected(
-                f"page set covers {k_np.shape[1]} blocks, history needs "
-                f"{n_blocks}"
+                f"page set covers {kv_pages.count(pages)} blocks, history "
+                f"needs {n_blocks}"
             )
         tenant = str(meta.get("tenant") or "")
         level = int(meta.get("level") or 0)
@@ -3527,11 +3485,7 @@ class JaxServingEngine(AsyncEngine):
             # wire hop) raises typed — the nack degrades the stream to the
             # resume path and the SOURCE counts the trip against itself.
             # Never a torn staged entry: nothing was allocated yet.
-            integrity_mod.verify_pages(
-                k_np, v_np,
-                (k_scale, v_scale) if k_scale is not None else None,
-                meta["crcs"], where="migrate_stage",
-            )
+            kv_pages.verify(pages, meta["crcs"], where="migrate_stage")
         alloc = self.allocator.allocate_sequence(
             toks, wait_inflight=False, tenant=tenant, level=level
         )
@@ -3548,11 +3502,7 @@ class JaxServingEngine(AsyncEngine):
             if n_dev < n_blocks:
                 self.inject_blocks(
                     alloc.block_ids[n_dev:n_blocks],
-                    k_np[:, n_dev:n_blocks], v_np[:, n_dev:n_blocks],
-                    k_scale[:, n_dev:n_blocks]
-                    if k_scale is not None else None,
-                    v_scale[:, n_dev:n_blocks]
-                    if v_scale is not None else None,
+                    kv_pages.select(pages, slice(n_dev, n_blocks)),
                 )
             # seal the computed history: full blocks register in the prefix
             # cache — the migrated prefix is now a cluster-adopted cache
@@ -3618,78 +3568,19 @@ class JaxServingEngine(AsyncEngine):
                     mid, n_blocks,
                 )
 
-    def _inject_fn(self):
-        if not hasattr(self, "_inject_jit"):
-            record_compile("inject")
-
-            def inject(cache_arr, idx, vals):
-                # padded idx entries are out of range → dropped by the scatter
-                return cache_arr.at[:, idx].set(vals, mode="drop")
-
-            self._inject_jit = jax.jit(inject, donate_argnums=(0,))
-        return self._inject_jit
-
-    def inject_blocks(
-        self, block_ids: List[int], k_np, v_np, k_scale=None, v_scale=None
-    ) -> None:
-        """Write transferred KV pages into HBM at the given physical pages.
-        MUST run on the engine thread. Donated update (no cache-sized copy);
-        the page count is padded to a power of two so at most log2(max_blocks)
-        shapes ever compile — an unpadded count would recompile the donated
-        scatter (and stall decode) for every distinct transfer size.
+    def inject_blocks(self, block_ids: List[int], pages: kv_pages.Pages) -> None:
+        """Write transferred KV pages into HBM at the given physical pages
+        (:func:`kv_pages.put`: a donated update, no cache-sized copy).
+        MUST run on the engine thread.
 
         Accepts host numpy (staged transfers) or jax arrays (the same-host
         device path: pages flow device→device, resharding across meshes —
         including differing tp — handled by XLA at the jit boundary).
 
-        int8 pools require matching per-token scale tables ([L, n, bs] ×2);
-        a layout mismatch raises :class:`KvDtypeMismatch` before any byte
-        lands — corrupt pages are strictly worse than a failed transfer."""
-        if self._kv_quantized != (k_scale is not None):
-            raise KvDtypeMismatch(
-                "pool kv_dtype is %s but injected pages %s scale tables" % (
-                    "int8" if self._kv_quantized else "native",
-                    "lack" if k_scale is None else "carry",
-                )
-            )
-        n = len(block_ids)
-        bucket = 1
-        while bucket < n:
-            bucket *= 2
-        idx = np.full((bucket,), self.num_blocks, np.int32)  # out-of-range pad
-        idx[:n] = block_ids
-        dt = self.cache["k"].dtype
-
-        def pad(vals):
-            if isinstance(vals, jax.Array):
-                widths = [(0, 0), (0, bucket - n)] + [(0, 0)] * (vals.ndim - 2)
-                out = jnp.pad(vals, widths)
-                # commit onto THIS engine's devices: jax.device_put reshards
-                # across meshes, but jit's device check rejects an input
-                # committed to a different mesh (split-chip prefill/decode)
-                if self.mesh is not None:
-                    from dynamo_tpu.parallel.mesh import kv_cache_sharding
-
-                    return jax.device_put(out, kv_cache_sharding(self.mesh))
-                return jax.device_put(out, next(iter(self.cache["k"].devices())))
-            out = np.zeros((vals.shape[0], bucket) + vals.shape[2:], vals.dtype)
-            out[:, :n] = vals
-            return out
-
-        fn = self._inject_fn()
-        idx_dev = jnp.asarray(idx)
-        self.cache["k"] = fn(self.cache["k"], idx_dev, jnp.asarray(pad(k_np), dt))
-        self.cache["v"] = fn(self.cache["v"], idx_dev, jnp.asarray(pad(v_np), dt))
-        if k_scale is not None:
-            # scale tables ride the same padded scatter ([L, n, bs] slots in
-            # place of [L, n, bs, KVH, D] pages — pad() is rank-agnostic)
-            sdt = self.cache["k_scale"].dtype
-            self.cache["k_scale"] = fn(
-                self.cache["k_scale"], idx_dev, jnp.asarray(pad(k_scale), sdt)
-            )
-            self.cache["v_scale"] = fn(
-                self.cache["v_scale"], idx_dev, jnp.asarray(pad(v_scale), sdt)
-            )
+        Pages of another layout than the pool's (an int8 pool wants its
+        scale tables, a native one none) raise :class:`KvDtypeMismatch`
+        before any byte lands."""
+        self.cache = kv_pages.put(self.cache, block_ids, pages)
 
     # -- host KV tier ---------------------------------------------------------
 
@@ -3707,15 +3598,10 @@ class JaxServingEngine(AsyncEngine):
         harvested by :meth:`_harvest_spills` once ready. ``pairs`` entries
         are ``(hash, block_id, crc)`` — the seal-time content checksum
         rides into the host tier with its block (None with integrity off)."""
-        taken = take_blocks(
-            self.cache, jnp.asarray([bid for _, bid, _ in pairs], jnp.int32)
-        )
+        taken = kv_pages.take(self.cache, [bid for _, bid, _ in pairs])
         for a in taken.values():
             a.copy_to_host_async()
-        self._pending_spills.append((
-            pairs, taken["k"], taken["v"],
-            taken.get("k_scale"), taken.get("v_scale"),
-        ))
+        self._pending_spills.append((pairs, taken))
 
     def _harvest_spills(self, force: bool = False) -> None:
         """Move completed async spills into the host pool (engine thread).
@@ -3727,63 +3613,44 @@ class JaxServingEngine(AsyncEngine):
         if len(self._pending_spills) > 8:
             force = True
         while self._pending_spills:
-            pairs, k, v, ks, vs = self._pending_spills[0]
-            if not force and not (k.is_ready() and v.is_ready()):
+            pairs, taken = self._pending_spills[0]
+            if not force and not all(a.is_ready() for a in taken.values()):
                 return
             self._pending_spills.popleft()
             # dynlint: allow-host-sync(host-tier spill harvest: only taken
             # once is_ready(), or force-drained while the engine is idle)
-            k_np = np.asarray(jax.device_get(k))
-            v_np = np.asarray(jax.device_get(v))  # dynlint: allow-host-sync(ditto)
-            if ks is not None:
-                # dynlint: allow-host-sync(scale tables ride the same spill)
-                ks_np = np.asarray(jax.device_get(ks))
-                vs_np = np.asarray(jax.device_get(vs))  # dynlint: allow-host-sync(ditto)
+            pages = kv_pages.to_host(taken)
             if faults_mod.current() is not None:
                 # host-tier leg of the silent-corruption drill: the
                 # "corrupt" action bit-flips the spilled copy — bad host
                 # RAM; the seal-time crc must catch it at rehit
-                k_np = faults_mod.corrupt_array(
-                    "engine", self._fault_addr, k_np
+                first = kv_pages.members(pages)[0]
+                pages[first] = faults_mod.corrupt_array(
+                    "engine", self._fault_addr, pages[first]
                 )
             for i, (h, _, crc) in enumerate(pairs):
-                # copies, not views: a view would pin the whole batch array
-                # in host RAM for as long as any one entry stays in the pool
-                self.host_pool.put(
-                    h,
-                    np.ascontiguousarray(k_np[:, i]),
-                    np.ascontiguousarray(v_np[:, i]),
-                    np.ascontiguousarray(ks_np[:, i]) if ks is not None else None,
-                    np.ascontiguousarray(vs_np[:, i]) if ks is not None else None,
-                    crc=crc,
-                )
+                self.host_pool.put(h, kv_pages.block(pages, i), crc=crc)
 
     def _inject_host_hits(self, alloc: SequenceAllocation) -> None:
         """Load host-tier prefix hits back into the sequence's device pages
-        (engine thread only). Runs before any compute touches the sequence.
-        int8 pools carry their per-token scale tables through the same hop
-        (allocator host_hits 6-tuples)."""
+        (engine thread only). Runs before any compute touches the sequence."""
         hits = alloc.host_hits
-        block_ids = [alloc.block_ids[h[0]] for h in hits]
-        k = np.stack([h[2] for h in hits], axis=1)
-        v = np.stack([h[3] for h in hits], axis=1)
-        ks = vs = None
-        if hits[0][4] is not None:
-            ks = np.stack([h[4] for h in hits], axis=1)
-            vs = np.stack([h[5] for h in hits], axis=1)
         alloc.host_hits = []
-        self.inject_blocks(block_ids, k, v, ks, vs)
+        self.inject_blocks(
+            [alloc.block_ids[idx] for idx, _, _, _ in hits],
+            kv_pages.stack([block for _, _, block, _ in hits]),
+        )
 
     def complete_remote_prefill(
         self, request_id: str, first_token: int, block_ids: List[int],
-        k_np, v_np, k_scale=None, v_scale=None,
+        pages: kv_pages.Pages,
     ) -> None:
         """Called (any thread) when a prefill worker's KV lands for a waiting
         sequence: injects pages, registers the prompt KV, emits the first
-        token, and queues the sequence for a decode slot. int8 pools expect
-        the per-token scale tables; a layout mismatch (peer without dtype
-        support, or a native peer shipping into an int8 pool) falls the
-        request back to local prefill instead of writing corrupt pages."""
+        token, and queues the sequence for a decode slot. Pages that do not
+        fit the pool (a peer without dtype support, a native peer shipping
+        into an int8 pool, another block size) fall the request back to
+        local prefill instead of writing corrupt pages."""
 
         def apply():
             seq = self._awaiting.pop(request_id, None)
@@ -3793,25 +3660,15 @@ class JaxServingEngine(AsyncEngine):
             # inject only the pages the prefill worker computed (suffix after
             # any prefix-cache hit)
             if block_ids:
-                bs = self.config.kv_block_size
-                if k_np.shape[2] != bs:
-                    logger.error(
-                        "remote prefill for %s has block_size %d, engine uses %d"
-                        " — falling back to local prefill",
-                        request_id, k_np.shape[2], bs,
-                    )
-                    self._awaiting[request_id] = seq
-                    self.fail_remote_prefill(request_id, "block_size mismatch")
-                    return
                 try:
-                    self.inject_blocks(block_ids, k_np, v_np, k_scale, v_scale)
-                except KvDtypeMismatch as e:
+                    self.inject_blocks(block_ids, pages)
+                except (KvDtypeMismatch, MigrationRejected) as e:
                     logger.error(
                         "remote prefill for %s: %s — falling back to local "
                         "prefill", request_id, e,
                     )
                     self._awaiting[request_id] = seq
-                    self.fail_remote_prefill(request_id, f"kv_dtype mismatch: {e}")
+                    self.fail_remote_prefill(request_id, f"pages do not fit: {e}")
                     return
             self.allocator.note_tokens_computed(seq.alloc, seq.prompt[seq.alloc.cached_tokens:])
             seq.first_token_t = time.perf_counter()

@@ -542,17 +542,6 @@ def _pool_pages(kv_cache: KVCache) -> KVCache:
     return {name: a.reshape(-1, *a.shape[2:]) for name, a in kv_cache.items()}
 
 
-@jax.jit
-def take_blocks(kv_cache: KVCache, block_ids: jax.Array) -> KVCache:
-    """Copy blocks ``block_ids`` [n] of every layer out of the pool:
-    ``pool[:, block_ids]`` ([L, n, bs, ...]) of each of its arrays, read through
-    :func:`_pool_pages` — indexed on the block axis alone, the eager gather
-    copied the whole pool into another layout first."""
-    l, n = kv_cache["k"].shape[:2]
-    rows = jnp.arange(l)[:, None] * n + block_ids
-    return {name: a[rows] for name, a in _pool_pages(kv_cache).items()}
-
-
 def gather_history(
     kv_cache: KVCache, block_tables: jax.Array, out_dtype: Any = None
 ) -> Tuple[jax.Array, jax.Array]:

@@ -50,7 +50,7 @@ import time
 import zlib
 from collections import deque
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, Iterable, Optional, Sequence
 
 import numpy as np
 
@@ -145,60 +145,33 @@ def enabled() -> bool:
 # ---------------------------------------------------------------------------
 
 
-def _arr_crc(crc: int, arr: Any) -> int:
-    # tobytes() on an ascontiguousarray: works for every dtype in the KV
-    # tiers (bf16 via ml_dtypes has no stable buffer protocol everywhere)
-    return zlib.crc32(np.ascontiguousarray(arr).tobytes(), crc)
-
-
-def entry_checksum(k, v, k_scale=None, v_scale=None) -> int:
-    """crc32 over ONE block's page bytes ([L, bs, KVH, D] ×2, plus the
-    [L, bs] scale tables for int8 pools) — chained k | v | k_scale |
-    v_scale, matching :func:`page_checksums` per-block order."""
-    crc = _arr_crc(0, k)
-    crc = _arr_crc(crc, v)
-    if k_scale is not None:
-        crc = _arr_crc(crc, k_scale)
-        crc = _arr_crc(crc, v_scale)
+def checksum(arrays: Iterable[Any]) -> int:
+    """crc32 chained over the bytes of ``arrays``, in the order given. What
+    the arrays are (which members a KV block has, and in what order) is
+    ``kv/pages.py``'s to say."""
+    crc = 0
+    for arr in arrays:
+        # tobytes() on an ascontiguousarray: works for every dtype in the KV
+        # tiers (bf16 via ml_dtypes has no stable buffer protocol everywhere)
+        crc = zlib.crc32(np.ascontiguousarray(arr).tobytes(), crc)
     return crc
 
 
-def page_checksums(k, v, k_scale=None, v_scale=None) -> List[int]:
-    """Per-block crc32 over a stacked page set ([L, n, bs, KVH, D] ×2 and,
-    for int8 pools, [L, n, bs] scale tables ×2): the wire/header form every
-    transfer tier ships next to the pages."""
-    n = k.shape[1]
-    out: List[int] = []
-    for i in range(n):
-        out.append(entry_checksum(
-            k[:, i], v[:, i],
-            k_scale[:, i] if k_scale is not None else None,
-            v_scale[:, i] if v_scale is not None else None,
-        ))
-    return out
+def verify(checksum_of: Callable[[int], int], n_blocks: int,
+           crcs: Optional[Sequence[Optional[int]]], where: str = "") -> None:
+    """Hold ``n_blocks`` received blocks to their travelling checksums;
+    ``checksum_of(i)`` hashes block ``i`` and is asked only where the sender
+    vouched for it.
 
-
-def verify_pages(k, v, scales, crcs: Optional[Sequence[Optional[int]]],
-                 where: str = "") -> None:
-    """Verify a received page set against its travelling checksums.
-
-    ``crcs`` entries of ``None``/negative mean "sender had no checksum for
-    this block" (partial block, pre-integrity peer) and are skipped — a
-    checksum-less frame always parses. Raises :class:`KvIntegrityError` at
-    the first mismatching block, BEFORE any byte can land in a pool."""
-    if crcs is None:
-        return
-    ks, vs = (scales if scales is not None else (None, None))
-    n = min(len(crcs), k.shape[1])
-    for i in range(n):
-        want = crcs[i]
+    ``crcs`` of ``None``, and entries of ``None``/negative, mean "sender had
+    no checksum for this block" (partial block, pre-integrity peer) and are
+    skipped — a checksum-less frame always parses. Raises
+    :class:`KvIntegrityError` at the first mismatching block, BEFORE any
+    byte can land in a pool."""
+    for i, want in zip(range(n_blocks), crcs or ()):
         if want is None or (isinstance(want, int) and want < 0):
             continue
-        got = entry_checksum(
-            k[:, i], v[:, i],
-            ks[:, i] if ks is not None else None,
-            vs[:, i] if vs is not None else None,
-        )
+        got = checksum_of(i)
         if got != int(want):
             raise KvIntegrityError(
                 f"KV block {i} failed its content checksum"

@@ -365,34 +365,62 @@ def test_chunk_program_holds_no_scores_as_wide_as_the_block_tables(one_chip):
     assert re.findall(rf"f32\[[\d,]*,{TABLE * BS}\]", compiled.as_text()) == []
 
 
-@pytest.mark.parametrize("layout", ["bf16", "bf16_tp4"])
-def test_taking_blocks_out_never_copies_the_kv_pool(request, one_chip, layout):
-    """take_blocks (host-tier spills and KV transfers, between dispatches):
-    the eager ``pool[:, block_ids]`` it replaces copied the whole pool into
-    another layout to gather two dozen blocks."""
+@pytest.mark.parametrize("layout", ["bf16", "bf16_tp4", "int8"])
+@pytest.mark.parametrize("program", ["take", "put"])
+def test_taking_blocks_out_never_copies_the_kv_pool(request, one_chip, program, layout):
+    """kv/pages.py's two programs (host-tier spills and re-hits, KV transfers,
+    migration; between dispatches), held to the step programs' rule. The
+    eager ``pool[:, block_ids]`` that ``take`` replaces copied the whole pool
+    into another layout to gather two dozen blocks, and so did the
+    ``pool.at[:, block_ids].set`` that ``put`` replaces to write them: put's
+    donated pool aliases the output in full and nothing pool-sized comes out
+    of any op but views and the in-place scatter. The int8 pool is held as
+    the step programs' is: to the alias, and to no bound on its temporaries."""
+    from dynamo_tpu.kv import pages as kv_pages
+
     mesh = request.getfixturevalue("tp4_mesh") if layout == "bf16_tp4" else None
+    quantized = layout == "int8"
     cfg = dataclasses.replace(
-        llama.LLAMA_PRESETS["qwen2.5-1.5b" if mesh is None else "qwen2.5-7b"],
+        llama.LLAMA_PRESETS["qwen2.5-1.5b" if layout == "bf16" else "qwen2.5-7b"],
         num_layers=POOL_LAYERS,
     )
     if mesh is None:
         rep = cache_sh = one_chip
+        shards = 1
     else:
         from dynamo_tpu.parallel.mesh import kv_cache_sharding
 
         rep, cache_sh = NamedSharding(mesh, P()), kv_cache_sharding(mesh)
-    temps = []
-    for num_blocks in POOL_BLOCKS:
-        pool = jax.eval_shape(lambda: llama.make_kv_cache(cfg, num_blocks, BS))
-        compiled = llama.take_blocks.lower(
-            jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=cache_sh), pool
+        shards = mesh.shape["tp"]
+    taken = 32 if program == "put" else 24  # put pads to a power of two
+
+    def shaped(tree, blocks=None):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape if blocks is None else a.shape[:1] + (blocks,) + a.shape[2:],
+                a.dtype, sharding=cache_sh,
             ),
-            jax.ShapeDtypeStruct((24,), jnp.int32, sharding=rep),
-        ).compile()
-        pages = pool["k"].size // (1 if mesh is None else mesh.shape["tp"])
+            tree,
+        )
+
+    temps = []
+    for num_blocks in INT8_POOL_BLOCKS if quantized else POOL_BLOCKS:
+        pool = jax.eval_shape(
+            lambda: llama.make_kv_cache(cfg, num_blocks, BS, quantized=quantized)
+        )
+        args = [shaped(pool), jax.ShapeDtypeStruct((taken,), jnp.int32, sharding=rep)]
+        if program == "put":
+            args.append(shaped(pool, taken))
+        compiled = kv_pages.programs()[program == "put"].lower(*args).compile()
+        memory = compiled.memory_analysis()
+        if program == "put":
+            pool_bytes = sum(a.size * a.dtype.itemsize for a in pool.values()) // shards
+            assert pool_bytes <= memory.alias_size_in_bytes < pool_bytes * 1.001
+        if quantized:
+            continue
+        pages = pool["k"].size // shards
         assert _pool_sized_instructions(
             compiled.as_text(), {pages, pages // cfg.num_layers}
         ) == []
-        temps.append(compiled.memory_analysis().temp_size_in_bytes)
-    assert temps[0] == temps[1]
+        temps.append(memory.temp_size_in_bytes)
+    assert quantized or temps[0] == temps[1]

@@ -626,7 +626,7 @@ class TestDisabledIntegrityCaught:
                         break
                 cp = _call(src, src.export_migratable)[0]
             emitted = cp["token_ids"][len(prompt):]
-            pages = _call(src, lambda: src.extract_for_migration(
+            pages, _ = _call(src, lambda: src.extract_for_migration(
                 cp["request_id"]
             ))
             tgt = _engine(tiny, max_slots=2)
@@ -642,8 +642,7 @@ class TestDisabledIntegrityCaught:
                     f"127.0.0.1:{server.port}",
                     {k: cp[k] for k in ("mid", "request_id", "token_ids",
                                         "emitted", "tenant", "level")},
-                    pages[0], pages[1],
-                    (pages[2], pages[3]) if pages[2] is not None else None,
+                    pages,
                 )
             log = [{"seq": d.seq, "plane": d.plane, "addr": d.addr,
                     "point": d.point, "op_index": d.op_index,

@@ -61,16 +61,16 @@ class FakeEngine:
     def post(self, fn):
         fn()
 
-    def complete_remote_prefill(self, request_id, first_token, block_ids, k, v,
-                                k_scale=None, v_scale=None):
+    def complete_remote_prefill(self, request_id, first_token, block_ids, pages):
         self.completed.append((request_id, first_token, block_ids,
-                              np.asarray(k).copy(), np.asarray(v).copy()))
+                              np.asarray(pages["k"]).copy(),
+                              np.asarray(pages["v"]).copy()))
 
     def fail_remote_prefill(self, request_id, message):
         self.completed.append(("FAIL", request_id, message))
 
     def extract_blocks(self, ids, as_device=False):
-        return self.pages_k, self.pages_v, None, None
+        return {"k": self.pages_k, "v": self.pages_v}
 
     def block_hashes_of(self, ids):
         return [7] * len(ids)
@@ -104,15 +104,15 @@ def test_device_path_send_and_read():
 
         k = np.ones((2, 2, 4), np.float32)
         v = k * 2
-        await client.send_blocks(addr, "req-1", 42, [5, 6], k, v)
+        await client.send_blocks(addr, "req-1", 42, [5, 6], {"k": k, "v": v})
         assert len(eng.completed) == 1
         rid, tok, ids, got_k, got_v = eng.completed[0]
         assert (rid, tok, ids) == ("req-1", 42, [5, 6])
         assert np.array_equal(got_k, k) and np.array_equal(got_v, v)
 
-        rk, rv, scales, hashes = await client.read_blocks(addr, [1, 2, 3])
-        assert scales is None
-        assert np.array_equal(np.asarray(rk), eng.pages_k)
+        read, hashes = await client.read_blocks(addr, [1, 2, 3])
+        assert set(read) == {"k", "v"}
+        assert np.array_equal(np.asarray(read["k"]), eng.pages_k)
         assert hashes == [7, 7, 7]
         assert reg.pulls == 2  # one per direction — the bulk used the fabric
         assert not reg.staged or len(reg.staged) <= 1  # send released its stage
@@ -136,14 +136,14 @@ def test_mixed_fleet_falls_back_to_tcp():
         addr = f"127.0.0.1:{server.port}"
 
         k = np.ones((2, 2, 4), np.float32)
-        await client.send_blocks(addr, "req-2", 9, [1], k, k)
+        await client.send_blocks(addr, "req-2", 9, [1], {"k": k, "v": k})
         assert eng.completed and eng.completed[0][0] == "req-2"
         assert reg.pulls == 0  # fabric never used
         assert client._dev_peers[addr] is False  # remembered: no retry storm
 
-        rk, rv, scales, hashes = await client.read_blocks(addr, [1, 2, 3])
-        assert scales is None
-        assert np.array_equal(rk, eng.pages_k)
+        read, hashes = await client.read_blocks(addr, [1, 2, 3])
+        assert set(read) == {"k", "v"}
+        assert np.array_equal(read["k"], eng.pages_k)
 
         await client.close()
         await server.stop()

@@ -63,8 +63,8 @@ def test_prefill_engine_pages_match_serving_engine(params):
     pre = PrefillEngine(CFG, params, max_model_len=128, block_size=BLOCK)
     prompt = list(range(1, 21))  # 20 tokens → 3 blocks
 
-    tok, k, v = pre.prefill(prompt, cached_tokens=0, sampling={})
-    assert k.shape[1] == 3
+    tok, pages = pre.prefill(prompt, cached_tokens=0, sampling={})
+    assert pages["k"].shape[1] == 3
 
     # run the same prompt locally on the decode engine and compare its pages
     async def local():

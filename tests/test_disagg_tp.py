@@ -163,22 +163,22 @@ def test_tp1_prefill_feeds_tp2_decode(params, run, device_path):
         sub = policy.request
         assert sub is not None, "engine never submitted the remote prefill"
 
-        first_tok, k, v = prefill.prefill(
+        first_tok, pages = prefill.prefill(
             sub["token_ids"], sub["cached_tokens"], sub["sampling"],
             as_device=device_path,
         )
         if device_path:
-            assert isinstance(k, jax.Array)
+            assert isinstance(pages["k"], jax.Array)
             xfer = LocalKvTransfer(decode)
             await xfer.send_blocks(
-                "", sub["request_id"], first_tok, sub["block_ids"], k, v
+                "", sub["request_id"], first_tok, sub["block_ids"], pages
             )
         else:
             import numpy as np
 
-            assert isinstance(k, np.ndarray)
+            assert isinstance(pages["k"], np.ndarray)
             decode.complete_remote_prefill(
-                sub["request_id"], first_tok, sub["block_ids"], k, v
+                sub["request_id"], first_tok, sub["block_ids"], pages
             )
         return await task
 

@@ -483,9 +483,9 @@ def test_int8_kv_host_pool_offload_and_rehit_parity(params, run):
         run(collect(eng, prompt_b, max_tokens=4))
         assert eng.host_pool.offloaded > 0
         # spilled entries carry their scale tables
-        entry = next(iter(eng.host_pool._data.values()))
-        assert entry[2] is not None and entry[3] is not None
-        assert entry[0].dtype == np.int8
+        block, _ = next(iter(eng.host_pool._data.values()))
+        assert {"k_scale", "v_scale"} <= set(block)
+        assert block["k"].dtype == np.int8
         hits_before = eng.host_pool.hits
         t2, _, _ = run(collect(eng, prompt_a, max_tokens=4))
         assert eng.host_pool.hits > hits_before

@@ -7,7 +7,7 @@ Coverage:
   (monkeypatched tracker/checksum constructors: nothing is ever built, no
   crc is ever computed, the jitted programs keep the pre-integrity
   signature);
-- checksum plumbing units: page/entry checksums, verify_pages semantics
+- checksum plumbing units: page/block checksums, verify semantics
   (checksum-less frames always parse), the trip tracker's threshold/window
   latch under an injected clock, and quarantine source semantics;
 - host-tier rehit verification on a REAL tiny engine: a bit-flipped host
@@ -40,6 +40,7 @@ import pytest
 
 from dynamo_tpu.disagg import migration as mig_mod
 from dynamo_tpu.disagg.migration import attach_migration
+from dynamo_tpu.kv import pages as kv_pages
 from dynamo_tpu.runtime import faults, integrity, resilience
 from dynamo_tpu.runtime.annotated import Annotated
 from dynamo_tpu.runtime.distributed import DistributedRuntime
@@ -104,31 +105,34 @@ class TestChecksums:
         k = np.arange(2 * n * 4 * 2 * 3, dtype=np.float32).reshape(
             2, n, 4, 2, 3
         )
-        return k, k + 0.5
+        return {"k": k, "v": k + 0.5}
 
     def test_page_and_entry_checksums_agree(self):
-        k, v = self._pages()
-        crcs = integrity.page_checksums(k, v)
+        pages = self._pages()
+        crcs = kv_pages.checksums(pages)
         assert len(crcs) == 3
         for i in range(3):
-            assert crcs[i] == integrity.entry_checksum(k[:, i], v[:, i])
+            assert crcs[i] == integrity.checksum(
+                [pages["k"][:, i], pages["v"][:, i]]
+            )
         # scales change the checksum (they travel WITH their pages)
         ks = np.ones((2, 3, 4), np.float32)
-        assert integrity.page_checksums(k, v, ks, ks) != crcs
+        assert kv_pages.checksums(dict(pages, k_scale=ks, v_scale=ks)) != crcs
 
     def test_verify_pages_semantics(self):
-        k, v = self._pages()
-        crcs = integrity.page_checksums(k, v)
-        integrity.verify_pages(k, v, None, crcs)  # clean: no raise
-        integrity.verify_pages(k, v, None, None)  # checksum-less frame
+        pages = self._pages()
+        crcs = kv_pages.checksums(pages)
+        kv_pages.verify(pages, crcs)  # clean: no raise
+        kv_pages.verify(pages, None)  # checksum-less frame
         # -1 / None entries mean "sender can't vouch": skipped
-        integrity.verify_pages(k, v, None, [-1, None, crcs[2]])
-        bad = np.array(k)
+        kv_pages.verify(pages, [-1, None, crcs[2]])
+        bad = np.array(pages["k"])
         bad.view(np.uint8).reshape(-1)[7] ^= 0x10
+        bad = dict(pages, k=bad)
         with pytest.raises(KvIntegrityError):
-            integrity.verify_pages(bad, v, None, crcs, where="unit")
+            kv_pages.verify(bad, crcs, where="unit")
         # the corrupted block is skippable ⇒ no raise
-        integrity.verify_pages(bad, v, None, [-1, crcs[1], crcs[2]])
+        kv_pages.verify(bad, [-1, crcs[1], crcs[2]])
 
 
 # -- trip tracker + quarantine latch -------------------------------------------
@@ -256,8 +260,7 @@ class TestZeroOverheadGuard:
             raise AssertionError("constructed/computed with integrity off")
 
         monkeypatch.setattr(integrity, "IntegrityTracker", _boom)
-        monkeypatch.setattr(integrity, "page_checksums", _boom)
-        monkeypatch.setattr(integrity, "entry_checksum", _boom)
+        monkeypatch.setattr(integrity, "checksum", _boom)
 
         eng = _engine(tiny, host_cache_blocks=8)
         try:
@@ -270,13 +273,11 @@ class TestZeroOverheadGuard:
         finally:
             eng.close()
         # transfer senders ship NO crcs header (pre-integrity wire form)
-        from dynamo_tpu.disagg.transfer import _pack_pages, _sender_crcs
+        from dynamo_tpu.disagg.transfer import _sender_crcs
 
-        assert _sender_crcs(object(), [0], None, None, None, None) is None
-        hdr, _ = _pack_pages(
-            np.zeros((1, 1, 2, 1, 1), np.float32),
-            np.zeros((1, 1, 2, 1, 1), np.float32), None, crcs=None,
-        )
+        assert _sender_crcs(object(), [0], None) is None
+        zeros = np.zeros((1, 1, 2, 1, 1), np.float32)
+        hdr, _ = kv_pages.pack({"k": zeros, "v": zeros})
         assert "crcs" not in hdr
 
     def test_integrity_on_seals_checksums(self, tiny, run):
@@ -313,10 +314,10 @@ class TestHostTierRehit:
             assert eng.host_pool.offloaded > 0
             assert len(eng.host_pool) > 0
             # flip one byte in every host entry's k pages (the pool's copy)
-            for h, entry in list(eng.host_pool._data.items()):
-                bad = np.array(entry[0])
+            for h, (block, crc) in list(eng.host_pool._data.items()):
+                bad = np.array(block["k"])
                 bad.view(np.uint8).reshape(-1)[3] ^= 0x40
-                eng.host_pool._data[h] = (bad,) + tuple(entry[1:])
+                eng.host_pool._data[h] = (dict(block, k=bad), crc)
             hits_before = eng.host_pool.hits
             t2 = run(_collect(eng, prompt_a, 4))
             assert t2 == t1, "recompute after the dropped hit must be exact"
@@ -434,7 +435,7 @@ class TestMigrationStagingIntegrity:
                 cp, got, gen = await _freeze_mid_stream(
                     src, list(range(4, 28)), 24, 4
                 )
-                k, v, ks, vs, crcs = _call(
+                pages, crcs = _call(
                     src, lambda: src.extract_for_migration(cp["request_id"])
                 )
                 assert crcs is not None and len(crcs) == cp["n_blocks"]
@@ -443,15 +444,16 @@ class TestMigrationStagingIntegrity:
                     "emitted": cp["emitted"], "tenant": "", "level": 0,
                     "crcs": crcs,
                 }
-                bad = np.array(k)
+                bad = np.array(pages["k"])
                 bad.view(np.uint8).reshape(-1)[11] ^= 0x01
+                bad = dict(pages, k=bad)
                 free_before = dst.allocator.free_blocks
                 with pytest.raises(KvIntegrityError):
-                    _call(dst, lambda: dst.stage_migration(meta, bad, v))
+                    _call(dst, lambda: dst.stage_migration(meta, bad))
                 assert dst.allocator.free_blocks == free_before
                 assert dst._staged_migrations == {}
                 # clean pages stage fine — the failure was the bytes
-                res = _call(dst, lambda: dst.stage_migration(meta, k, v))
+                res = _call(dst, lambda: dst.stage_migration(meta, pages))
                 assert res["mid"] == cp["mid"]
                 _call(src, lambda: src.abort_migration(cp["request_id"]))
                 async for _ in gen:
@@ -475,7 +477,8 @@ class _PageEngine:
             2, n, 4, 2, 3
         )
         self.v = self.k + 1.0
-        self._crcs = integrity.page_checksums(self.k, self.v)
+        self.pages = {"k": self.k, "v": self.v}
+        self._crcs = kv_pages.checksums(self.pages)
         if corrupt_after_seal:
             # storage rot AFTER seal: registry crcs describe the clean
             # bytes, the pool holds flipped ones
@@ -487,8 +490,7 @@ class _PageEngine:
         fn()
 
     def extract_blocks(self, ids, as_device=False):
-        sel = list(ids)
-        return self.k[:, sel], self.v[:, sel], None, None
+        return kv_pages.select(self.pages, list(ids))
 
     def block_hashes_of(self, ids):
         return [100 + i for i in ids]
@@ -496,7 +498,7 @@ class _PageEngine:
     def block_crcs_of(self, ids):
         return [self._crcs[i] for i in ids]
 
-    def complete_remote_prefill(self, rid, first, bids, k, v, ks=None, vs=None):
+    def complete_remote_prefill(self, rid, first, bids, pages):
         self.completed.append((rid, first, list(bids)))
 
     def fail_remote_prefill(self, rid, msg):
@@ -536,10 +538,10 @@ class TestTransferIntegrity:
             srv = KvTransferServer(eng, host="127.0.0.1", port=0)
             await srv.start()
             client = KvTransferClient()
-            k, v, scales, hashes = await client.read_blocks(
+            pages, hashes = await client.read_blocks(
                 f"127.0.0.1:{srv.port}", [0, 1]
             )
-            assert np.array_equal(k, eng.k)
+            assert np.array_equal(pages["k"], eng.k)
             assert hashes == [100, 101]
             assert integrity.counters()["kv_integrity_remote_failures_total"] == 0
             await client.close()
@@ -568,8 +570,7 @@ class TestTransferIntegrity:
             with faults.active(inj):
                 with pytest.raises(KvIntegrityError):
                     await client.send_blocks(
-                        f"127.0.0.1:{srv.port}", "r1", 7, [0, 1],
-                        eng.k, eng.v,
+                        f"127.0.0.1:{srv.port}", "r1", 7, [0, 1], eng.pages
                     )
             assert eng.completed == []
             assert eng.failed and eng.failed[0][0] == "r1"
@@ -578,7 +579,7 @@ class TestTransferIntegrity:
             assert c["kv_integrity_remote_failures_total"] == 1  # receiver's
             # without the injector the same transfer completes
             await client.send_blocks(
-                f"127.0.0.1:{srv.port}", "r2", 7, [0, 1], eng.k, eng.v,
+                f"127.0.0.1:{srv.port}", "r2", 7, [0, 1], eng.pages
             )
             assert eng.completed and eng.completed[0][0] == "r2"
             await client.close()

@@ -25,6 +25,7 @@ from dynamo_tpu.disagg.transfer import (
     LocalKvTransfer,
 )
 from dynamo_tpu.engine_jax.engine import EngineConfig, JaxServingEngine
+from dynamo_tpu.kv import pages as kv_pages
 from dynamo_tpu.llm.protocols.common import (
     PreprocessedRequest,
     SamplingOptions,
@@ -137,28 +138,27 @@ def test_int8_disagg_tcp_round_trip(params, run):
             sub = policy.request
             assert sub is not None
 
-            tok, k, v, scales, _ = await prefill.prefill_request(
+            tok, pages, _ = await prefill.prefill_request(
                 sub["token_ids"], sub["cached_tokens"], sub["sampling"]
             )
-            assert k.dtype == np.int8
-            assert scales is not None and scales[0].dtype == np.float32
+            assert pages["k"].dtype == np.int8
+            assert pages["k_scale"].dtype == np.float32
             await client.send_blocks(
-                addr, sub["request_id"], tok, sub["block_ids"], k, v,
-                scales=scales,
+                addr, sub["request_id"], tok, sub["block_ids"], pages
             )
             toks = await asyncio.wait_for(task, 30)
             assert toks == golden
 
             # read the decode side's pages back over TCP: values AND scales
-            rk, rv, rscales, hashes = await client.read_blocks(
+            read, hashes = await client.read_blocks(
                 addr, sub["block_ids"][:2]
             )
-            assert rk.dtype == np.int8
-            assert rscales is not None
-            np.testing.assert_array_equal(np.asarray(rk), np.asarray(k)[:, :2])
-            np.testing.assert_array_equal(
-                np.asarray(rscales[0]), np.asarray(scales[0])[:, :2]
-            )
+            assert read["k"].dtype == np.int8
+            assert set(read) == set(pages)
+            for m in ("k", "k_scale"):
+                np.testing.assert_array_equal(
+                    np.asarray(read[m]), np.asarray(pages[m])[:, :2]
+                )
         finally:
             await client.close()
             await server.stop()
@@ -203,24 +203,21 @@ def test_int8_local_transfer_round_trip(params, run):
             prefill.engine = prefill_eng
             prefill._computed = {}
             prefill.last_computed_tokens = -1
-            tok, k, v, scales, _ = await prefill.prefill_request(
+            tok, pages, _ = await prefill.prefill_request(
                 sub["token_ids"], sub["cached_tokens"], sub["sampling"],
                 as_device=True,
             )
-            assert isinstance(k, jax.Array) and scales is not None
+            assert isinstance(pages["k"], jax.Array) and "k_scale" in pages
             xfer = LocalKvTransfer(decode)
             await xfer.send_blocks(
-                "", sub["request_id"], tok, sub["block_ids"], k, v,
-                scales=scales,
+                "", sub["request_id"], tok, sub["block_ids"], pages
             )
             toks = await asyncio.wait_for(task, 30)
             assert toks == golden
 
             # device-path read-back returns scales too
-            rk, rv, rscales, hashes = await xfer.read_blocks(
-                "", sub["block_ids"][:1]
-            )
-            assert rscales is not None and isinstance(rk, jax.Array)
+            read, hashes = await xfer.read_blocks("", sub["block_ids"][:1])
+            assert "k_scale" in read and isinstance(read["k"], jax.Array)
         finally:
             prefill_eng.close()
             decode.close()
@@ -249,13 +246,13 @@ def test_native_frame_into_int8_pool_falls_back_cleanly(params, run, caplog):
             task = asyncio.create_task(_collect(decode, prompt))
             await asyncio.to_thread(policy.submitted.wait, 10.0)
             sub = policy.request
-            tok, k, v, scales, _ = await prefill.prefill_request(
+            tok, pages, _ = await prefill.prefill_request(
                 sub["token_ids"], sub["cached_tokens"], sub["sampling"]
             )
-            assert scales is None  # native pool: no scale tables
+            assert set(pages) == {"k", "v"}  # native pool: no scale tables
             with caplog.at_level(logging.ERROR, "dynamo_tpu.engine_jax.engine"):
                 decode.complete_remote_prefill(
-                    sub["request_id"], tok, sub["block_ids"], k, v
+                    sub["request_id"], tok, sub["block_ids"], pages
                 )
                 toks = await asyncio.wait_for(task, 30)
             # fell back to LOCAL prefill → exact int8-engine output
@@ -366,7 +363,7 @@ def test_send_blocks_transport_failure_then_typed_fallback(params, run):
             task = asyncio.create_task(_collect(decode, prompt))
             await asyncio.to_thread(policy.submitted.wait, 10.0)
             sub = policy.request
-            tok, k, v, scales, _ = await prefill.prefill_request(
+            tok, pages, _ = await prefill.prefill_request(
                 sub["token_ids"], sub["cached_tokens"], sub["sampling"]
             )
             inj = FaultInjector([FaultRule(
@@ -375,8 +372,7 @@ def test_send_blocks_transport_failure_then_typed_fallback(params, run):
             with faults_mod.active(inj):
                 with pytest.raises((ConnectionError, OSError)):
                     await client.send_blocks(
-                        addr, sub["request_id"], tok, sub["block_ids"], k, v,
-                        scales=scales,
+                        addr, sub["request_id"], tok, sub["block_ids"], pages
                     )
             # retries exhausted: the worker reports in-band (fresh dial —
             # the failed conn was identity-evicted by send_blocks)
@@ -401,15 +397,18 @@ def test_inject_blocks_dtype_mismatch_is_typed(params):
         EngineConfig(max_slots=2, kv_block_size=BLOCK, max_model_len=128),
     )
     try:
-        pages = np.zeros((CFG.num_layers, 1, BLOCK, CFG.num_kv_heads,
-                          CFG.head_dim), np.float32)
+        page = np.zeros((CFG.num_layers, 1, BLOCK, CFG.num_kv_heads,
+                         CFG.head_dim), np.float32)
         scales = np.ones((CFG.num_layers, 1, BLOCK), np.float32)
+        native = {"k": page, "v": page}
         with pytest.raises(KvDtypeMismatch):
-            int8_eng.inject_blocks([0], pages, pages)  # scales missing
+            int8_eng.inject_blocks([0], native)  # scales missing
         with pytest.raises(KvDtypeMismatch):
-            native_eng.inject_blocks([0], pages, pages, scales, scales)
+            native_eng.inject_blocks(
+                [0], dict(native, k_scale=scales, v_scale=scales)
+            )
         with pytest.raises(KvDtypeMismatch):
-            int8_eng.seed_external_prefix(list(range(BLOCK)), pages, pages)
+            int8_eng.seed_external_prefix(list(range(BLOCK)), native)
     finally:
         int8_eng.close()
         native_eng.close()
@@ -457,8 +456,8 @@ def test_pre_int8_peer_read_refused_typed(params, run):
 
             client = KvTransferClient()
             try:
-                rk, rv, rscales, _ = await client.read_blocks(addr, block_ids)
-                assert rk.dtype == np.int8 and rscales is not None
+                read, _ = await client.read_blocks(addr, block_ids)
+                assert read["k"].dtype == np.int8 and "k_scale" in read
                 assert client._int8_peers[addr] is True
             finally:
                 await client.close()
@@ -501,20 +500,20 @@ def test_int8_send_avoids_device_plane_until_peer_proven(run):
 
         client._send_blocks_dev = fake_dev
         k = np.zeros((1, 1, BLOCK, 1, 4), np.int8)
-        scales = (np.ones((1, 1, BLOCK), np.float32),
-                  np.ones((1, 1, BLOCK), np.float32))
+        scale = np.ones((1, 1, BLOCK), np.float32)
+        int8 = {"k": k, "v": k, "k_scale": scale, "v_scale": scale}
         try:
             # unproven peer + int8 scales → TCP, not the device plane
-            await client.send_blocks(addr, "r1", 1, [0], k, k, scales=scales)
+            await client.send_blocks(addr, "r1", 1, [0], int8)
             assert not dev_calls and len(eng.calls) == 1
             assert client._int8_peers.get(addr) is True
             # capability proven → device plane
-            await client.send_blocks(addr, "r2", 1, [0], k, k, scales=scales)
+            await client.send_blocks(addr, "r2", 1, [0], int8)
             assert len(dev_calls) == 1
             # native pages were never gated on the capability
             client._int8_peers.clear()
             f32 = k.astype(np.float32)
-            await client.send_blocks(addr, "r3", 1, [0], f32, f32)
+            await client.send_blocks(addr, "r3", 1, [0], {"k": f32, "v": f32})
             assert len(dev_calls) == 2
         finally:
             await client.close()
@@ -539,10 +538,8 @@ def test_dtype_skew_prefix_readback_recomputes_not_fails(params, run, caplog):
 
         hashes = compute_block_hashes_for_seq(prompt[:24], BLOCK)
         block_ids = [decode.allocator._by_hash[h] for h in hashes]
-        k, v, scales, _ = await LocalKvTransfer(decode).read_blocks(
-            "", block_ids
-        )
-        assert scales is not None
+        read, _ = await LocalKvTransfer(decode).read_blocks("", block_ids)
+        assert "k_scale" in read
         decode.close()
 
         golden = JaxServingEngine(CFG, params, dataclasses.replace(
@@ -557,10 +554,8 @@ def test_dtype_skew_prefix_readback_recomputes_not_fails(params, run, caplog):
             with caplog.at_level(
                 logging.WARNING, "dynamo_tpu.disagg.prefill_worker"
             ):
-                tok, _, _, _, computed = await prefill.prefill_request(
-                    prompt, 24, {},
-                    prefix_kv=(np.asarray(k), np.asarray(v),
-                               (np.asarray(scales[0]), np.asarray(scales[1]))),
+                tok, _, computed = await prefill.prefill_request(
+                    prompt, 24, {}, prefix_kv=kv_pages.to_host(read),
                 )
             assert tok == want[0]
             assert computed == len(prompt)  # full recompute, no seeded prefix
@@ -587,8 +582,8 @@ def test_int8_prefix_readback_seeds_prefill_engine(params, run):
         hashes = compute_block_hashes_for_seq(prompt[:24], BLOCK)
         block_ids = [decode.allocator._by_hash[h] for h in hashes]
         xfer = LocalKvTransfer(decode)
-        k, v, scales, got_hashes = await xfer.read_blocks("", block_ids)
-        assert scales is not None
+        read, got_hashes = await xfer.read_blocks("", block_ids)
+        assert "k_scale" in read
         assert list(got_hashes) == list(hashes)
 
         pre = JaxServingEngine(
@@ -601,10 +596,7 @@ def test_int8_prefix_readback_seeds_prefill_engine(params, run):
         def seed():
             fut.get_loop().call_soon_threadsafe(
                 fut.set_result,
-                pre.seed_external_prefix(
-                    prompt[:24], np.asarray(k), np.asarray(v),
-                    np.asarray(scales[0]), np.asarray(scales[1]),
-                ),
+                pre.seed_external_prefix(prompt[:24], kv_pages.to_host(read)),
             )
 
         pre.post(seed)

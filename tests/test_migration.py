@@ -37,6 +37,7 @@ import pytest
 
 from dynamo_tpu.disagg import migration as mig_mod
 from dynamo_tpu.disagg.migration import MigrationPolicy, attach_migration
+from dynamo_tpu.kv import pages as kv_pages
 from dynamo_tpu.runtime import faults, resilience
 from dynamo_tpu.runtime.annotated import Annotated
 from dynamo_tpu.runtime.distributed import DistributedRuntime
@@ -225,7 +226,7 @@ class TestEngineStageAdopt:
             cp, got, gen = await _freeze_mid_stream(src, prompt, 14, 5)
             emitted = cp["token_ids"][len(prompt):]
             assert emitted == golden[:len(emitted)]
-            pages = _call(src, lambda: src.extract_for_migration(
+            pages, _ = _call(src, lambda: src.extract_for_migration(
                 cp["request_id"]
             ))
 
@@ -234,7 +235,7 @@ class TestEngineStageAdopt:
                     ("mid", "request_id", "token_ids", "emitted", "tenant",
                      "level")}
             staged = _call(tgt, lambda: tgt.stage_migration(
-                meta, pages[0], pages[1], pages[2], pages[3]
+                meta, pages,
             ))
             assert staged["cached_tokens"] == len(cp["token_ids"]) - 1
             _call(src, lambda: src.finish_migrated(
@@ -291,14 +292,14 @@ class TestEngineStageAdopt:
                         break
                 cp = _call(src, src.export_migratable)[0]
             emitted = cp["token_ids"][len(prompt):]
-            pages = _call(src, lambda: src.extract_for_migration(
+            pages, _ = _call(src, lambda: src.extract_for_migration(
                 cp["request_id"]
             ))
             tgt = _engine(tiny)
             _call(tgt, lambda: tgt.stage_migration(
                 {k: cp[k] for k in ("mid", "request_id", "token_ids",
                                     "emitted", "tenant", "level")},
-                pages[0], pages[1], pages[2], pages[3],
+                pages,
             ))
             _call(src, lambda: src.finish_migrated(
                 cp["request_id"], "i", "w", cp["mid"]
@@ -327,16 +328,13 @@ class TestEngineStageAdopt:
         """Target OOM, page-set/block-size mismatch, dtype skew: every
         rejection is typed and leaves the target pool untouched — never a
         torn page set."""
-        from dynamo_tpu.engine_jax.allocator import (
-            KvDtypeMismatch,
-            MigrationRejected,
-        )
+        from dynamo_tpu.kv.pages import KvDtypeMismatch, MigrationRejected
 
         async def go():
             src = _engine(tiny)
             prompt = list(range(7, 27))
             cp, got, gen = await _freeze_mid_stream(src, prompt, 10, 3)
-            pages = _call(src, lambda: src.extract_for_migration(
+            pages, _ = _call(src, lambda: src.extract_for_migration(
                 cp["request_id"]
             ))
             meta = {k: cp[k] for k in ("mid", "request_id", "token_ids",
@@ -347,7 +345,7 @@ class TestEngineStageAdopt:
             free0 = oom.allocator.free_blocks
             with pytest.raises(MigrationRejected):
                 _call(oom, lambda: oom.stage_migration(
-                    meta, pages[0], pages[1], pages[2], pages[3]
+                    meta, pages,
                 ))
             assert oom.allocator.free_blocks == free0, "torn OOM stage"
             oom.close()
@@ -356,7 +354,7 @@ class TestEngineStageAdopt:
             bs = _engine(tiny, kv_block_size=16)
             with pytest.raises(MigrationRejected):
                 _call(bs, lambda: bs.stage_migration(
-                    meta, pages[0], pages[1], pages[2], pages[3]
+                    meta, pages,
                 ))
             bs.close()
 
@@ -364,7 +362,7 @@ class TestEngineStageAdopt:
             tr = _engine(tiny)
             with pytest.raises(MigrationRejected):
                 _call(tr, lambda: tr.stage_migration(
-                    meta, pages[0][:, :1], pages[1][:, :1], None, None
+                    meta, kv_pages.select(pages, slice(0, 1))
                 ))
             tr.close()
 
@@ -372,7 +370,7 @@ class TestEngineStageAdopt:
             q = _engine(tiny, kv_dtype="int8")
             with pytest.raises(KvDtypeMismatch):
                 _call(q, lambda: q.stage_migration(
-                    meta, pages[0], pages[1], None, None
+                    meta, {m: pages[m] for m in ("k", "v")}
                 ))
             q.close()
 
@@ -380,8 +378,7 @@ class TestEngineStageAdopt:
             ok = _engine(tiny)
             with pytest.raises(MigrationRejected):
                 _call(ok, lambda: ok.stage_migration(
-                    dict(meta, token_ids=[1]), pages[0], pages[1],
-                    pages[2], pages[3],
+                    dict(meta, token_ids=[1]), pages,
                 ))
             ok.close()
 
@@ -400,7 +397,7 @@ class TestEngineStageAdopt:
             src = _engine(tiny)
             prompt = list(range(11, 31))
             cp, got, gen = await _freeze_mid_stream(src, prompt, 10, 3)
-            pages = _call(src, lambda: src.extract_for_migration(
+            pages, _ = _call(src, lambda: src.extract_for_migration(
                 cp["request_id"]
             ))
             tgt = _engine(tiny)
@@ -408,7 +405,7 @@ class TestEngineStageAdopt:
             _call(tgt, lambda: tgt.stage_migration(
                 {k: cp[k] for k in ("mid", "request_id", "token_ids",
                                     "emitted", "tenant", "level")},
-                pages[0], pages[1], pages[2], pages[3],
+                pages,
             ))
             assert len(tgt._staged_migrations) == 1
             deadline = asyncio.get_running_loop().time() + 8.0
@@ -503,7 +500,7 @@ class TestTransferMigrateOp:
             KvTransferClient,
             KvTransferServer,
         )
-        from dynamo_tpu.engine_jax.allocator import MigrationRejected
+        from dynamo_tpu.kv.pages import MigrationRejected
 
         async def go():
             control = _engine(tiny)
@@ -514,7 +511,7 @@ class TestTransferMigrateOp:
             src = _engine(tiny)
             cp, got, gen = await _freeze_mid_stream(src, prompt, 10, 4)
             emitted = cp["token_ids"][len(prompt):]
-            pages = _call(src, lambda: src.extract_for_migration(
+            pages, _ = _call(src, lambda: src.extract_for_migration(
                 cp["request_id"]
             ))
             tgt = _engine(tiny)
@@ -525,8 +522,7 @@ class TestTransferMigrateOp:
             meta = {k: cp[k] for k in ("mid", "request_id", "token_ids",
                                        "emitted", "tenant", "level")}
             staged = await client.migrate(
-                addr, meta, pages[0], pages[1],
-                (pages[2], pages[3]) if pages[2] is not None else None,
+                addr, meta, pages,
             )
             assert staged["cached_tokens"] == len(cp["token_ids"]) - 1
             assert len(tgt._staged_migrations) == 1
@@ -536,8 +532,7 @@ class TestTransferMigrateOp:
             with pytest.raises(MigrationRejected):
                 await client.migrate(
                     addr, dict(meta, mid="bad", token_ids=[1]),
-                    pages[0], pages[1],
-                    (pages[2], pages[3]) if pages[2] is not None else None,
+                    pages,
                 )
             assert len(tgt._staged_migrations) == 1  # only the good one
 
@@ -573,14 +568,14 @@ class TestTransferMigrateOp:
             KvTransferClient,
             KvTransferServer,
         )
-        from dynamo_tpu.engine_jax.allocator import MigrationRejected
+        from dynamo_tpu.kv.pages import MigrationRejected
         from dynamo_tpu.runtime import integrity
 
         async def go():
             src = _engine(tiny)
             prompt = list(range(17, 43))
             cp, got, gen = await _freeze_mid_stream(src, prompt, 10, 4)
-            pages = _call(src, lambda: src.extract_for_migration(
+            pages, _ = _call(src, lambda: src.extract_for_migration(
                 cp["request_id"]
             ))
             tgt = _engine(tiny)
@@ -590,7 +585,6 @@ class TestTransferMigrateOp:
             addr = f"127.0.0.1:{server.port}"
             meta = {k: cp[k] for k in ("mid", "request_id", "token_ids",
                                        "emitted", "tenant", "level")}
-            scales = (pages[2], pages[3]) if pages[2] is not None else None
 
             # the latch lands between freeze and ship — the in-flight
             # migration must die with the typed rejection, not stage
@@ -598,13 +592,13 @@ class TestTransferMigrateOp:
                 source="store", reason="operator order mid-migration"
             )
             with pytest.raises(MigrationRejected, match="quarantined"):
-                await client.migrate(addr, meta, pages[0], pages[1], scales)
+                await client.migrate(addr, meta, pages)
             assert len(tgt._staged_migrations) == 0
 
             # unquarantine: the SAME client connection ships it clean
             integrity.clear_quarantine(None)
             staged = await client.migrate(
-                addr, meta, pages[0], pages[1], scales
+                addr, meta, pages
             )
             assert staged["cached_tokens"] == len(cp["token_ids"]) - 1
             assert len(tgt._staged_migrations) == 1

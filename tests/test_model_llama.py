@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dynamo_tpu.kv import pages as kv_pages
 from dynamo_tpu.models.llama import (
     HISTORY_TILE,
     LLAMA_PRESETS,
@@ -20,7 +21,6 @@ from dynamo_tpu.models.llama import (
     make_kv_cache,
     param_shardings,
     quantize_kv,
-    take_blocks,
 )
 from dynamo_tpu.ops.attention import (
     gather_pages,
@@ -507,10 +507,10 @@ def test_one_scatter_equals_the_scatter_per_layer(case, table):
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["pages", "int8_with_scales"])
-def test_take_blocks_is_the_block_axis_index(quantized):
+def test_taking_pages_is_the_block_axis_index(quantized):
     pool = _sentinel_pool(quantized=quantized)
     ids = jnp.array([7, 0, 3, 3, 9], jnp.int32)
-    taken = take_blocks(pool, ids)
+    taken = kv_pages.take(pool, ids)
     assert set(taken) == set(pool)
     for name, a in pool.items():
         np.testing.assert_array_equal(np.asarray(taken[name]), np.asarray(a[:, ids]))
