@@ -5,12 +5,17 @@ Architecture (TPU-first, cf. SURVEY.md §7 stage 4):
 - **Fixed batch slots**: `max_slots` decode lanes; a request occupies one slot
   from first token to finish. All decode steps run ONE jitted function with
   static shapes — no recompilation, ever.
-- **Chunked, batched prefill**: every step with a prefilling lane runs ONE
-  compiled `[slots, prefill_chunk]` function in which prefilling lanes consume
-  up to `prefill_chunk` prompt tokens while decode lanes advance one token —
-  prefill never runs batch-1 and never blocks decode for more than a chunk.
-  Prompts longer than a chunk just take several steps (long-context prefill is
-  chunked by construction; no shape depends on prompt length).
+- **Chunked, batched prefill**: a host step with a prefilling lane runs ONE
+  compiled `[rows, prefill_chunk]` function that holds one row per lane that
+  prefills and nothing else: `rows` is the smallest rung of a short ladder
+  (`chunk_row_ladder`: `max_slots` and at most two rungs under it) that holds
+  them, so the dispatch costs what its prefilling lanes need. The lanes that
+  decode are never rows of it: they advance through the decode program in the
+  same host step, and both programs are dispatched before either result is
+  fetched. (On a mesh the decode lanes still ride the chunk dispatch at the
+  full width, one token each: `_rides`, which says why.) Prompts longer than
+  a chunk just take several steps (long-context prefill is chunked by
+  construction; no shape depends on prompt length).
 - **Paged KV**: allocator (allocator.py) maps sequences onto a page pool in
   HBM with content-addressed prefix reuse; the model writes-then-attends
   through block tables (models/llama.py), making prefix hits free.
@@ -217,13 +222,13 @@ class EngineConfig:
     # default 3)
     spec_ngram: Optional[int] = None
     # multi-tenant QoS (runtime/qos.py): prefill duty-cycle budget — the
-    # AVERAGE prefill tokens allowed per engine dispatch while decode
-    # lanes are live. A chunk dispatch costs full [S, C] compute and
-    # advances decode lanes only one token, so isolation works by pacing
-    # chunk-dispatch frequency: one chunk, then ~chunk/budget pure
-    # pipelined decode dispatches. Long prompts raise their OWN TTFT
-    # instead of spiking every decode lane's ITL; an engine with no
-    # decode lanes prefills at full speed. None = read
+    # AVERAGE prefill tokens allowed per host step while decode lanes
+    # are live: one step with a chunk dispatch, then ~chunk/budget steps
+    # of the decode program alone. Long prompts raise their OWN TTFT
+    # instead of every decode lane's ITL; an engine with no decode lanes
+    # prefills at full speed. (Written when a chunk dispatch cost full
+    # [S, C] compute and carried decode lanes one token; it now costs its
+    # prefilling rows and they run beside it: ROADMAP D6.) None = read
     # DYN_TPU_PREFILL_BUDGET (clamped; default 0 = unlimited, the pre-QoS
     # behavior).
     prefill_budget: Optional[int] = None
@@ -396,16 +401,73 @@ class _Inflight:
     the current machine.
     """
 
-    __slots__ = ("out", "lps", "top_ids", "top_lps", "tokens", "positions", "lanes")
+    __slots__ = ("out", "lps", "top_ids", "top_lps", "tokens", "positions",
+                 "lanes", "sealing")
 
-    def __init__(self, out, lps, top_ids, top_lps, tokens, positions, lanes):
+    def __init__(self, out, lps, top_ids, top_lps, tokens, positions, lanes,
+                 sealing=None):
         self.out = out  # [S, k_steps] device
         self.lps = lps  # [S, k_steps] device, chosen-token logprobs
         self.top_ids = top_ids  # [S, k_steps, P] device
         self.top_lps = top_lps  # [S, k_steps, P] device
         self.tokens = tokens  # [S] device, final carry
         self.positions = positions  # [S] device, final carry
-        self.lanes = lanes  # List[Optional[_Seq]] snapshot
+        # the lanes that were LIVE in this dispatch, by slot: None for an
+        # empty slot and for a lane that was prefilling then (position -1
+        # in-jit: its row and its carry are garbage). Decided at dispatch,
+        # because a later chunk can finish that lane's prefill before this
+        # dispatch is processed, after which it reads as a decode lane.
+        self.lanes = lanes  # List[Optional[_Seq]]
+        self.sealing = sealing  # Optional[_SealPages]: the blocks it fills
+
+
+class _SealPages:
+    """The pages of the blocks a dispatched program fills to their end, taken
+    off the pool by a program enqueued right behind it, their host copy
+    started then. Those blocks seal when the dispatch's result is processed,
+    and the seal-time checksum needs their bytes on the host: read there and
+    then, the read would queue behind whatever was dispatched since (the
+    decode program of the same host step, 48 ms) and hold the step loop for
+    as long."""
+
+    __slots__ = ("pages", "where", "_host")
+
+    def __init__(self, pages, where):
+        self.pages = pages  # kv_pages.Pages on the device, copy in flight
+        self.where = where  # Dict[int, int]: block id -> its column in pages
+        self._host = None
+
+    def host(self):
+        if self._host is None:
+            # dynlint: allow-host-sync(seal-time checksum: the copy was
+            # started when the pages were taken, a dispatch ago)
+            self._host = kv_pages.to_host(self.pages)
+        return self._host
+
+
+class _ChunkInflight:
+    """A chunk dispatch whose result has not been fetched: the device handles
+    of its sampled tokens, and per live row the lane, its sequence and the
+    prompt tokens it fed. It lives inside one host step (`_prefill_step`)."""
+
+    __slots__ = ("fetch", "rows", "sealing", "t_step", "t_disp")
+
+    def __init__(self, fetch, rows, sealing, t_step, t_disp):
+        self.fetch = fetch  # (sampled [R],) or (sampled, lp, top_ids, top_lps)
+        self.rows = rows  # List[Tuple[int, _Seq, List[int]]]: lane, seq, fed
+        self.sealing = sealing  # Optional[_SealPages]: the blocks it fills
+        self.t_step = t_step
+        self.t_disp = t_disp  # 0.0 = this dispatch is not profiled
+
+
+def chunk_row_ladder(max_slots: int) -> List[int]:
+    """The row counts a chunk dispatch may have, ascending: ``max_slots`` (an
+    admission wave fills every lane at once) and at most two rungs under it,
+    an eighth and a quarter of the slots. Under load a request prefills for a
+    few of the host steps it lives, so 1-4 of 32 lanes prefill in most chunk
+    dispatches and up to a quarter in nearly all the rest (PERF.md 6, PR 32);
+    each rung is one more program to compile and warm."""
+    return sorted({r for r in (max_slots // 8, max_slots // 4) if r >= 1} | {max_slots})
 
 
 class JaxServingEngine(AsyncEngine):
@@ -753,8 +815,8 @@ class JaxServingEngine(AsyncEngine):
             if self._qos is not None and self._qos.slot_frac > 0
             else 0
         )
-        # high-water mark of prefill tokens computed in a single dispatch
-        # that also carried a decode lane — the chunked-prefill interleaving
+        # high-water mark of prefill tokens computed in a single host step
+        # beside a live decode lane — the chunked-prefill interleaving
         # bound the ITL-isolation test asserts against the step budget
         self.prefill_interleave_max = 0
         # tiles of pool history the history-bearing chunk dispatches read (the
@@ -762,11 +824,19 @@ class JaxServingEngine(AsyncEngine):
         # block tables' full width would have been
         self.chunk_history_tiles_read = 0
         self.chunk_history_tiles_full = 0
+        # how full the chunk dispatches are (cumulative): positions computed
+        # (rows x prefill_chunk), prompt tokens among them, rows dispatched,
+        # rows that held a prefilling lane, and dispatches by row count
+        self.chunk_positions_dispatched = 0
+        self.chunk_tokens_fed = 0
+        self.chunk_rows_dispatched = 0
+        self.chunk_rows_live = 0
+        self.chunk_dispatches_by_rows: Dict[int, int] = {}
 
         # (with_logprobs, with_penalties, with_sampling) variants, compiled
-        # lazily per need
+        # lazily per need; the chunk's key also holds (with_history, rows)
         self._decode_fns: Dict[Tuple[bool, bool, bool], Any] = {}
-        self._chunk_fns: Dict[Tuple[bool, bool, bool], Any] = {}
+        self._chunk_fns: Dict[Tuple[bool, bool, bool, bool, int], Any] = {}
         # speculative-verify variants (same key space); never built with
         # spec_k == 0 — asserted by the zero-overhead guard test
         self._verify_fns: Dict[Tuple[bool, bool, bool], Any] = {}
@@ -832,6 +902,48 @@ class JaxServingEngine(AsyncEngine):
                 )
             if self._pp > 1:
                 raise ValueError("pp and sp cannot be combined yet")
+
+        # the pages of the blocks that the dispatch being processed fills
+        # (_SealPages), where _block_checksums looks first
+        self._sealing: Optional[_SealPages] = None
+
+        # row counts of the chunk program (chunk_row_ladder). A process-
+        # spanning mesh broadcasts fixed shapes to its followers, pipeline
+        # stages microbatch the row axis and the sp forward has run at one
+        # width only: those engines pack their rows like any other, into the
+        # one rung every engine has.
+        S = engine_config.max_slots
+        # On a mesh the host step is the one it was before the rows were
+        # packed: the decode lanes RIDE the chunk dispatch, one token each at
+        # the full width, the decode program runs only in steps in which no
+        # lane prefills, and sealed blocks are read when they seal, not
+        # ahead (`_sealing_sizes` empty). Not for the engine's sake: packed
+        # rows with the decode program beside them read -48 % TTFT and 2.6 x
+        # the tokens/s under tp=4, and the read-ahead alone +8 % tokens/s
+        # (PERF.md 6, PR 32). The benchmark's tracer exports every device
+        # event of four chips inside a time limit that the parent's rate
+        # already nearly fills, and a cell whose traced run fails refuses the
+        # change (PERF.md 7, ROADMAP B0). When that is repaired: `_rides =
+        # False`.
+        self._rides = mesh is not None
+        self._chunk_rungs: List[int] = (
+            [S] if self._rides or self._multihost or self._pp > 1 or self._sp > 1
+            else chunk_row_ladder(S)
+        )
+        # the block counts _take_sealing reads ahead, ascending. The largest
+        # is what a decode dispatch or a chunk dispatch of a rung under
+        # max_slots can fill (an admission wave at the full width seals
+        # through the plain read: nothing is dispatched behind it that it
+        # would wait for); under it powers of four, so that a read is at most
+        # four times what was asked for and few shapes of the take program
+        # compile (`warmup` runs each once, and `setup_s` is judged).
+        per_row = -(-engine_config.prefill_chunk // engine_config.kv_block_size)
+        most = max(S, max(
+            [r for r in self._chunk_rungs if r < S], default=0
+        ) * per_row)
+        self._sealing_sizes: List[int] = [] if self._rides else [
+            4 ** e for e in range(16) if 4 ** e < most
+        ] + [most]
 
         # which attention tier the decode programs hold, and whether the
         # kernel is built in Pallas interpret mode (the CPU route of the
@@ -1093,15 +1205,20 @@ class JaxServingEngine(AsyncEngine):
         return fn
 
     def _chunk(self, want_lp: bool, want_pen: bool = False,
-               want_sample: bool = True, want_history: bool = True):
+               want_sample: bool = True, want_history: bool = True,
+               rows: Optional[int] = None):
+        """The chunk variant at ``rows`` rows (a rung of ``_chunk_rungs``;
+        default ``max_slots``). One jitted function serves every row count:
+        the key keeps the programs apart that ``warmup`` compiled ahead."""
         if self._pp > 1 or self._sp > 1:
             want_history = True  # pp/sp forwards have no history-free variant
-        key = (want_lp, want_pen, want_sample, want_history)
+        rows = self.config.max_slots if rows is None else rows
+        key = (want_lp, want_pen, want_sample, want_history, rows)
         fn = self._chunk_fns.get(key)
         if fn is None:
             record_compile("chunk", detail=(
                 f"lp={want_lp} pen={want_pen} sample={want_sample} "
-                f"history={want_history} [S={self.config.max_slots},"
+                f"history={want_history} [R={rows},"
                 f"C={self.config.prefill_chunk}]"
             ))
             fn = self._chunk_fns[key] = self._build_chunk_fn(
@@ -1112,22 +1229,23 @@ class JaxServingEngine(AsyncEngine):
     def _build_chunk_fn(self, with_lp: bool = False, with_pen: bool = False,
                         with_sample: bool = True, with_history: bool = True):
         cfg = self.model_config
-        S = self.config.max_slots
         n_top = self.config.top_logprobs
         wd = self._watchdog
         wd_limit = self._integrity.logit_limit if wd else 0.0
 
         def chunk(params, cache, counts, tokens, positions, tables, sample_at,
-                  step_ctr, ipack, fpack, wdf=None):
+                  lanes, step_ctr, ipack, fpack, wdf=None):
             step_key = jax.random.fold_in(jax.random.PRNGKey(0), step_ctr)
             seeds, topk = ipack[0], ipack[1]
             temp, topp, freqp, presp = fpack[0], fpack[1], fpack[2], fpack[3]
-            # tokens/positions: [S, C] (−1 positions = padding); sample_at: [S]
-            # index of the token whose logits to sample, −1 → output unused.
-            # One shape serves any mix of prefilling and decoding lanes.
-            # The LM head runs on the gathered [S, E] sample positions only —
-            # never on the full [S, C, E] chunk (at C=128 that head matmul and
-            # its [S, C, vocab] float32 logits dwarf the useful work and sat
+            # tokens/positions: [R, C] (−1 positions = padding), one row per
+            # prefilling lane, packed to the front; sample_at: [R] index of
+            # the token whose logits to sample, −1 → output unused; lanes:
+            # [R] the slot of each row (max_slots = a padding row), which is
+            # its row of the [S, V] penalty counts. R is the inputs' own.
+            # The LM head runs on the gathered [R, E] sample positions only —
+            # never on the full [R, C, E] chunk (at C=128 that head matmul and
+            # its [R, C, vocab] float32 logits dwarf the useful work and sat
             # directly on the TTFT critical path).
             if self._pp > 1:
                 from dynamo_tpu.parallel.pipeline import pipeline_forward
@@ -1151,8 +1269,8 @@ class JaxServingEngine(AsyncEngine):
                     params, cfg, tokens, positions, cache, tables,
                     hidden_only=True, with_history=with_history,
                 )
-            hs = h[jnp.arange(S), jnp.clip(sample_at, 0)]  # [S, E]
-            sel = lm_head(params, cfg, hs)  # [S, V]
+            hs = h[jnp.arange(tokens.shape[0]), jnp.clip(sample_at, 0)]  # [R, E]
+            sel = lm_head(params, cfg, hs)  # [R, V]
             if wd:
                 # output watchdog: poison-drill substitution + per-lane
                 # non-finite/exploding flag → WATCHDOG_TOKEN sentinel
@@ -1165,7 +1283,10 @@ class JaxServingEngine(AsyncEngine):
             else:
                 keys = None
             sampled_from = (
-                apply_penalties(sel, counts, freqp, presp) if with_pen else sel
+                apply_penalties(
+                    sel, counts.at[lanes].get(mode="fill", fill_value=0),
+                    freqp, presp,
+                ) if with_pen else sel
             )
             nxt = sample_tokens(sampled_from, keys, temp, topk, topp,
                                 greedy_only=not with_sample)
@@ -1174,7 +1295,7 @@ class JaxServingEngine(AsyncEngine):
                     bad & (sample_at >= 0), jnp.int32(WATCHDOG_TOKEN), nxt
                 )
             if with_pen:
-                counts = update_counts(counts, nxt, sample_at >= 0)
+                counts = update_counts(counts, nxt, sample_at >= 0, rows=lanes)
             if with_lp:
                 lp, tids, tlps = token_logprobs(sel, nxt, n_top)
                 return nxt, lp, tids, tlps, cache, counts
@@ -1349,11 +1470,14 @@ class JaxServingEngine(AsyncEngine):
     def _release_counts(self) -> None:
         """No penalized lane is running: free the [S, V] device buffer and
         the strong _Seq references held by the row tracking. Rebuilt from
-        out_tokens on the next penalized admission. The multihost leader
-        broadcasts the release — followers drop theirs on non-penalized
-        dispatches, but an IDLE engine sends no dispatches, and without the
-        marker each follower would hold the buffer until unrelated traffic
-        arrived."""
+        out_tokens on the next penalized admission. Called after a dispatch
+        that penalized nothing; while some OTHER lane is penalized (the
+        chunk and the decode program of one host step each see only their
+        own lanes) the buffer stays, or every host step would rebuild it.
+        The multihost leader broadcasts the release, which is what the
+        followers drop theirs on."""
+        if any(s is not None and s.penalized for s in self._slots):
+            return
         if self._counts is not None:
             self._counts = None
             self._counts_lanes = [None] * self.config.max_slots
@@ -1425,10 +1549,14 @@ class JaxServingEngine(AsyncEngine):
         over abstract shapes — nothing executes, so no donation hazard — and
         the variants compile CONCURRENTLY in a thread pool (XLA releases the
         GIL), cutting first-boot wall time to roughly the slowest single
-        program. ``variants="greedy"`` compiles only the three
-        greedy-serving programs (big-model boots where every extra program
-        costs its compile time again); the lp/pen variants stay
-        lazy in every mode (rare; first use compiles once).
+        program. ``variants="greedy"`` compiles only the greedy-serving
+        programs (big-model boots where every extra program costs its
+        compile time again); the lp/pen variants stay lazy in every mode
+        (rare; first use compiles once). The chunk program is compiled at
+        every rung of ``_chunk_rungs``: both history variants at
+        ``max_slots`` rows, and under it the greedy history-bearing program
+        alone (zero trips of its loop is the no-history case; a sampled
+        variant at a small rung compiles at first use, like lp/pen).
 
         Mesh engines keep the executing warmup: AOT avals would need the
         exact input shardings, and on a multi-process mesh the warmup
@@ -1439,46 +1567,72 @@ class JaxServingEngine(AsyncEngine):
         S, C, MB = cfg.max_slots, cfg.prefill_chunk, cfg.max_blocks_per_seq
         timings: Dict[str, float] = {}
         sample_set = (False,) if variants == "greedy" else (False, True)
+        # (rows, want_sample, want_history) of every chunk program to compile
+        chunk_set = [
+            (S, want_sample, want_history)
+            for want_sample in sample_set for want_history in (False, True)
+        ] + [(rows, False, True) for rows in self._chunk_rungs if rows < S]
+
+        def chunk_name(rows, want_sample, want_history):
+            at = "" if rows == S else f",rows={rows}"
+            return f"chunk(sample={want_sample},history={want_history}{at})"
+
+        def warm_sealing():
+            # the take program at every block count _take_sealing pads to
+            # (executed: it reads the pool and writes nothing)
+            if not self._seal_checksums or not self._sealing_sizes:
+                return
+            t0 = time.perf_counter()
+            for n in self._sealing_sizes:
+                # dynlint: allow-host-sync(warmup compile barrier, pre-serving)
+                jax.block_until_ready(kv_pages.take(self.cache, [0] * n))
+            timings["take_blocks"] = round(time.perf_counter() - t0, 2)
 
         if self.mesh is not None:
-            neg = np.full((S, C), -1, np.int32)
-            zeros_sc = np.zeros((S, C), np.int32)
-            tables = np.zeros((S, MB), np.int32)
-            svec_i = np.zeros((S,), np.int32)
-            svec_f = np.zeros((S,), np.float32)
-            ones_f = np.ones((S,), np.float32)
+            def packs(rows):
+                fpack = np.zeros((4, rows), np.float32)
+                fpack[1] = 1.0  # top_p
+                return (self._put(np.zeros((2, rows), np.int32)),
+                        self._put(fpack))
+
             ctr = self._put(np.int32(0))
-            ipack = self._put(np.stack([svec_i, svec_i]))
-            fpack = self._put(np.stack([svec_f, ones_f, svec_f, svec_f]))
+            for rows, want_sample, want_history in chunk_set:
+                # every row a padding row: nothing is written, nothing sampled
+                t0 = time.perf_counter()
+                out, self.cache, self._dummy_counts = self._chunk(
+                    False, False, want_sample, want_history, rows
+                )(
+                    self.params, self.cache, self._dummy_counts,
+                    self._put(np.zeros((rows, C), np.int32)),
+                    self._put(np.full((rows, C), -1, np.int32)),
+                    self._put(np.zeros((rows, MB), np.int32)),
+                    self._put(np.full((rows,), -1, np.int32)),
+                    self._put(np.full((rows,), S, np.int32)), ctr,
+                    *packs(rows),
+                )
+                # dynlint: allow-host-sync(warmup compile barrier, pre-serving)
+                jax.device_get(out)
+                timings[chunk_name(rows, want_sample, want_history)] = round(
+                    time.perf_counter() - t0, 2
+                )
+            tables = self._put(np.zeros((S, MB), np.int32))
+            svec_i = np.zeros((S,), np.int32)
+            ipack, fpack = packs(S)
             for want_sample in sample_set:
-                for want_history in (False, True):
-                    t0 = time.perf_counter()
-                    out, self.cache, self._dummy_counts = self._chunk(
-                        False, False, want_sample, want_history
-                    )(
-                        self.params, self.cache, self._dummy_counts,
-                        self._put(zeros_sc), self._put(neg), self._put(tables),
-                        self._put(np.full((S,), -1, np.int32)), ctr,
-                        ipack, fpack,
-                    )
-                    # dynlint: allow-host-sync(warmup compile barrier, pre-serving)
-                    jax.device_get(out)
-                    timings[
-                        f"chunk(sample={want_sample},history={want_history})"
-                    ] = round(time.perf_counter() - t0, 2)
                 t0 = time.perf_counter()
                 out, _, _, self.cache, self._dummy_counts = self._decode(
                     False, False, want_sample
                 )(
                     self.params_decode, self.cache, self._dummy_counts,
                     self._put(svec_i), self._put(np.full((S,), -1, np.int32)),
-                    self._put(tables), ctr, ipack, fpack,
+                    tables, ctr, ipack, fpack,
                 )
                 # dynlint: allow-host-sync(warmup compile barrier, pre-serving)
                 jax.device_get(out)
                 timings[f"decode(sample={want_sample})"] = round(
                     time.perf_counter() - t0, 2
                 )
+            warm_sealing()
             return timings
 
         from concurrent.futures import ThreadPoolExecutor
@@ -1503,15 +1657,18 @@ class JaxServingEngine(AsyncEngine):
         wd_tail = (sd((), jnp.int32),) if self._watchdog else ()
 
         jobs = []
+        for rows, want_sample, want_history in chunk_set:
+            rvec = sd((rows,), jnp.int32)
+            jobs.append((
+                chunk_name(rows, want_sample, want_history),
+                self._chunk(False, False, want_sample, want_history, rows),
+                (p_sd, cache_sd, counts_sd, sd((rows, C), jnp.int32),
+                 sd((rows, C), jnp.int32), sd((rows, MB), jnp.int32), rvec,
+                 rvec, ctr, sd((2, rows), jnp.int32),
+                 sd((4, rows), jnp.float32)) + wd_tail,
+                ("chunk", False, False, want_sample, want_history, rows),
+            ))
         for want_sample in sample_set:
-            for want_history in (False, True):
-                jobs.append((
-                    f"chunk(sample={want_sample},history={want_history})",
-                    self._chunk(False, False, want_sample, want_history),
-                    (p_sd, cache_sd, counts_sd, sd((S, C), jnp.int32),
-                     sd((S, C), jnp.int32), tbl, svec, ctr, ip, fp) + wd_tail,
-                    ("chunk", False, False, want_sample, want_history),
-                ))
             jobs.append((
                 f"decode(sample={want_sample})",
                 self._decode(False, False, want_sample),
@@ -1529,16 +1686,28 @@ class JaxServingEngine(AsyncEngine):
                     ("verify", False, False, want_sample),
                 ))
 
+        # tracing is Python and holds the interpreter lock, compiling (or
+        # loading from the persistent cache) is XLA's and does not: trace in
+        # turn, the sampled programs first (theirs is the longest compile),
+        # so that each compile starts when its trace ends instead of all of
+        # them after all the traces
+        tracing_turn = threading.Lock()
+        jobs.sort(key=lambda job: not job[3][3])  # key = (kind, lp, pen, sample, ...)
+
         def compile_one(job):
             name, fn, args, key = job
             if not hasattr(fn, "lower"):  # already a compiled executable
                 return key, fn
             t0 = time.perf_counter()
-            compiled = fn.lower(*args).compile()
+            with tracing_turn:
+                lowered = fn.lower(*args)
+            compiled = lowered.compile()
             timings[name] = round(time.perf_counter() - t0, 2)
             return key, compiled
 
-        with ThreadPoolExecutor(max_workers=min(6, len(jobs))) as ex:
+        with ThreadPoolExecutor(max_workers=min(8, len(jobs)) + 1) as ex:
+            # the take programs execute while the step programs compile
+            sealing = ex.submit(warm_sealing)
             for key, compiled in ex.map(compile_one, jobs):
                 # serve straight off the compiled executable
                 if key[0] == "chunk":
@@ -1547,6 +1716,7 @@ class JaxServingEngine(AsyncEngine):
                     self._verify_fns[key[1:]] = compiled
                 else:
                     self._decode_fns[key[1:]] = compiled
+            sealing.result()
         return timings
 
     # -- AsyncEngine interface ----------------------------------------------
@@ -2128,16 +2298,16 @@ class JaxServingEngine(AsyncEngine):
             and any(s.prefill_pos is None for s in active)
         ):
             # duty-cycled interleave (DYN_TPU_PREFILL_BUDGET, docs/qos.md):
-            # a chunk dispatch costs full [S, C] compute no matter how few
-            # real tokens it feeds, and it advances decode lanes by ONE
-            # token where a pipelined decode dispatch advances them
-            # decode_steps — so isolation comes from dispatch FREQUENCY,
-            # not from shrinking a dispatch. Every dispatch earns `budget`
-            # tokens of prefill credit; a chunk dispatch spends what it
-            # consumed. While in debt, prefill lanes sit the dispatch out
-            # and decode runs at full pipelined speed: on average at most
-            # `budget` prefill tokens ride each dispatch, so a long prompt
-            # stretches its OWN TTFT instead of every decode lane's ITL.
+            # every host step earns `budget` tokens of prefill credit; a
+            # chunk dispatch spends what it consumed. While in debt, prefill
+            # lanes sit the step out and the decode program runs alone: on
+            # average at most `budget` prefill tokens ride each host step,
+            # so a long prompt stretches its OWN TTFT and not the decode
+            # lanes' wait for the chip. (The knob dates from a chunk
+            # dispatch that cost full [S, C] compute and carried the decode
+            # lanes one token forward; a chunk dispatch now costs its
+            # prefilling rows and the decode lanes run beside it, so there
+            # is less left for it to protect: ROADMAP D6.)
             # Idle decode ⇒ this path never taken: prefill at full speed.
             self._prefill_debt = max(
                 self._prefill_debt - self._prefill_budget, 0.0
@@ -2145,13 +2315,10 @@ class JaxServingEngine(AsyncEngine):
             if self._prefill_debt > 0:
                 self._decode_step()
                 return
-            self._drain_inflight()
-            self._chunk_step(paced=True)
+            self._prefill_step(paced=True)
             return
         if prefilling:
-            # chunk prefill needs each decode lane's true last token host-side
-            self._drain_inflight()
-            self._chunk_step()
+            self._prefill_step()
         elif (
             self._spec_k > 0
             and self._dispatch_hook is None
@@ -2173,266 +2340,271 @@ class JaxServingEngine(AsyncEngine):
         else:
             self._decode_step()
 
-    def _chunk_step(self, paced: bool = False) -> None:
-        """One [slots, prefill_chunk] dispatch: prefilling lanes consume up to
-        a chunk of prompt; decode lanes advance one token. A whole admission
-        wave prefills in ceil(longest_suffix / chunk) dispatches instead of
-        one serial batch-1 dispatch per request (the round-1 18 s TTFT).
+    def _prefill_step(self, paced: bool = False) -> None:
+        """One host step in which some lane prefills. A chunk dispatch holds
+        one row per lane that prefills; a lane that decodes is never a row
+        of it and advances through the decode program in the same step. The
+        chunk goes first (a first token is what a caller waits for), and
+        both programs are dispatched before either result is fetched, so
+        the step leaves the device idle once and not twice. (Where `_rides`,
+        the lanes that decode are rows of the chunk dispatch instead and the
+        decode program sits the step out.)
 
-        ``paced`` (the prefill-budget duty cycle, _dispatch_step): decode
-        lanes are live, so total prefill consumption is capped at ONE
-        chunk, handed to the most-starved tenant's lanes first, and the
-        consumed tokens are charged to the prefill debt that keeps the
-        following dispatches pure-decode."""
-        cfg = self.config
-        S, C = cfg.max_slots, cfg.prefill_chunk
-        tl = self._timeline
+        ``paced`` (the prefill-budget duty cycle, _dispatch_step): total
+        prefill consumption is capped at ONE chunk, handed to the
+        most-starved tenant's lanes first, and the consumed tokens are
+        charged to the prefill debt that keeps the following steps
+        pure-decode."""
         t_step = (
             time.perf_counter()
-            if tl is not None or self._straggler is not None else 0.0
+            if self._timeline is not None or self._straggler is not None
+            else 0.0
         )
-        for seq in [s for s in self._slots if s is not None]:
-            if seq.slot is None:
-                # an earlier lane's class-aware reclaim preempted this one
-                # mid-pass: it left the slots (alloc freed) but is still in
-                # the snapshot — touching it would grow a None alloc
-                continue
-            if seq.ctx.context.is_stopped:
-                self._finish(seq, FinishReason.CANCELLED)
-            elif seq.prefill_pos is None:
-                # decode lane writes KV at position total_len-1
-                need = min(seq.total_len, cfg.max_model_len)
-                if self._fair is not None and self._budget_denies_grow(seq, need):
-                    self._preempt(seq)  # over-share tenant pays, not others
-                elif not self.allocator.grow(seq.alloc, need):
-                    victim = self._preempt_victim_for(seq)
-                    self._preempt(victim)
-                    if victim is not seq and not self.allocator.grow(
-                        seq.alloc, need
-                    ):
-                        self._preempt(seq)
-        if tl is not None:
-            # the loop above is grow/evict work: the allocator share of
-            # this dispatch's host overhead
-            self._prof_alloc_us += (time.perf_counter() - t_step) * 1e6
+        if self._rides:
+            # a riding lane's row starts from its last token, host-side
+            self._drain_inflight()
+        # cancellations and the decode lanes' growth first: either may free a
+        # lane's blocks (a preemption's victim can be a prefilling lane),
+        # which no dispatched program may still write
+        self._prepare_lanes()
         if not any(self._slots):
             return
+        chunk = self._chunk_dispatch(paced, t_step)
+        decode = (
+            None if self._rides and chunk is not None
+            else self._decode_dispatch(profile=False)
+        )
+        if chunk is not None:
+            self._chunk_finish(chunk)
+        prev = decode[0] if decode is not None else None
+        if prev is not None:
+            self._process_chunk(prev, defer_free=True)
 
+    def _chunk_dispatch(self, paced: bool, t_step: float) -> Optional[_ChunkInflight]:
+        """Build and dispatch one [rows, prefill_chunk] program over the lanes
+        that prefill, packed to the front; ``rows`` is the smallest rung of
+        ``_chunk_rungs`` that holds them, the rest padding (positions -1, as
+        an empty lane has). Prefilling lanes consume up to a chunk of
+        prompt each; a whole admission wave prefills in ceil(longest_suffix
+        / chunk) dispatches. Where `_rides`, the lanes that decode are rows
+        too, one token each. Returns the dispatch for `_chunk_finish`, or
+        None when no lane takes a prompt token (all budgeted out)."""
+        cfg = self.config
+        S, C, MB = cfg.max_slots, cfg.prefill_chunk, cfg.max_blocks_per_seq
+        tl = self._timeline
+        pre = [
+            i for i in range(S)
+            if self._slots[i] is not None
+            and self._slots[i].prefill_pos is not None
+        ]
         # paced dispatch (prefill-budget duty cycle): one chunk's worth of
         # prefill total this dispatch, most-starved tenant's lanes first —
-        # fairness decides WHOSE long prompt advances while decode lanes
-        # ride along. allow=None is the unpaced fast path (identical to
-        # pre-budget behavior).
+        # fairness decides WHOSE long prompt advances beside the decode
+        # lanes. allow=None is the unpaced fast path.
         allow: Optional[Dict[int, int]] = None
-        if paced:
-            pre = [
-                i for i in range(S)
-                if self._slots[i] is not None
-                and self._slots[i].prefill_pos is not None
+        if paced and pre:
+            if self._fair is not None and len(pre) > 1:
+                pre.sort(key=lambda i: self._fair.vt(self._slots[i].tenant))
+            rem = [
+                len(self._slots[i].prompt) - self._slots[i].prefill_pos
+                for i in pre
             ]
-            if pre:
-                if self._fair is not None and len(pre) > 1:
-                    pre.sort(key=lambda i: self._fair.vt(self._slots[i].tenant))
-                rem = [
-                    len(self._slots[i].prompt) - self._slots[i].prefill_pos
-                    for i in pre
-                ]
-                allow = dict(zip(
-                    pre, qos_mod.split_prefill_budget(rem, C, C),
-                ))
-
-        tokens = np.zeros((S, C), np.int32)
-        positions = np.full((S, C), -1, np.int32)
-        sample_at = np.full((S,), -1, np.int32)
-        consumed: List[Optional[List[int]]] = [None] * S
-        n_prefill = 0
-        has_decode = False
-        for i in range(S):
+            allow = dict(zip(pre, qos_mod.split_prefill_budget(rem, C, C)))
+        take: List[Tuple[int, int]] = []  # (lane, prompt tokens it feeds)
+        for i in sorted(pre):
             seq = self._slots[i]
-            self._tables[i, :] = 0
-            self._temp[i] = 0.0
-            self._topk[i] = 0
-            self._topp[i] = 1.0
-            self._seeds[i] = 0
-            self._freqp[i] = 0.0
-            self._presp[i] = 0.0
-            if seq is None:
-                continue
-            self._tables[i, : len(seq.alloc.block_ids)] = seq.alloc.block_ids
-            self._temp[i] = seq.temperature
-            self._topk[i] = seq.top_k
-            self._topp[i] = seq.top_p
-            self._seeds[i] = seq.seed & 0x7FFFFFFF
-            self._freqp[i] = seq.freq_pen
-            self._presp[i] = seq.pres_pen
-            if seq.prefill_pos is not None:
-                n = min(C, len(seq.prompt) - seq.prefill_pos)
-                if allow is not None:
-                    n = min(n, allow.get(i, 0))
-                if n <= 0:
-                    continue  # budgeted out of this step; advances next one
-                chunk_toks = seq.prompt[seq.prefill_pos : seq.prefill_pos + n]
-                tokens[i, :n] = chunk_toks
-                positions[i, :n] = np.arange(seq.prefill_pos, seq.prefill_pos + n)
-                if seq.prefill_pos + n == len(seq.prompt):
-                    sample_at[i] = n - 1
-                consumed[i] = chunk_toks
-                n_prefill += n
+            n = min(C, len(seq.prompt) - seq.prefill_pos)
+            if allow is not None:
+                n = min(n, allow.get(i, 0))
+            if n > 0:  # else budgeted out of this step; advances next one
+                take.append((i, n))
+        if not take:
+            return None
+        n_prefill = sum(n for _, n in take)
+        if self._rides:
+            # (lane, 0): a decode lane rides along, one token forward
+            take = sorted(take + [
+                (i, 0) for i, s in enumerate(self._slots)
+                if s is not None and s.prefill_pos is None
+            ])
+
+        rows = next(r for r in self._chunk_rungs if r >= len(take))
+        tokens = np.zeros((rows, C), np.int32)
+        positions = np.full((rows, C), -1, np.int32)
+        tables = np.zeros((rows, MB), np.int32)
+        sample_at = np.full((rows,), -1, np.int32)
+        lanes = np.full((rows,), S, np.int32)  # S = a padding row
+        ipack_np = np.zeros((2, rows), np.int32)  # seeds, topk
+        fpack_np = np.zeros((4, rows), np.float32)  # temp, topp, freqp, presp
+        fpack_np[1] = 1.0
+        fed: List[Tuple[int, _Seq, List[int]]] = []  # lane, seq, its tokens
+        filled: List[int] = []  # blocks this dispatch fills: they seal at its finish
+        for r, (i, n) in enumerate(take):
+            seq = self._slots[i]
+            lanes[r] = i
+            tables[r, : len(seq.alloc.block_ids)] = seq.alloc.block_ids
+            ipack_np[:, r] = (seq.seed & 0x7FFFFFFF, seq.top_k)
+            fpack_np[:, r] = (
+                seq.temperature, seq.top_p, seq.freq_pen, seq.pres_pen
+            )
+            if n == 0:  # a riding decode lane: its last token, at its place
+                start, n = seq.total_len - 1, 1
+                chunk_toks = [seq.generated[-1] if seq.generated else seq.prompt[-1]]
+                sample_at[r] = 0
             else:
-                fed = seq.generated[-1] if seq.generated else seq.prompt[-1]
-                tokens[i, 0] = fed
-                positions[i, 0] = seq.total_len - 1
-                sample_at[i] = 0
-                consumed[i] = [fed]
-                has_decode = True
+                start = seq.prefill_pos
+                chunk_toks = seq.prompt[start : start + n]
+                if start + n == len(seq.prompt):
+                    sample_at[r] = n - 1
+            filled += self._blocks_filled(seq.alloc, start, n)
+            tokens[r, :n] = chunk_toks
+            positions[r, :n] = np.arange(start, start + n)
+            fed.append((i, seq, chunk_toks))
+        has_decode = any(
+            s is not None and s.prefill_pos is None for s in self._slots
+        )
         if has_decode and n_prefill > self.prefill_interleave_max:
-            # interleaving bound: the most prefill work any dispatch ever
-            # put in front of a live decode lane (the ITL-isolation tests
-            # assert it stays ≤ one chunk under pacing, vs the full prompt
-            # on the unbudgeted control leg)
+            # interleaving bound: the most prefill work any host step ever
+            # put beside a live decode lane (the ITL-isolation tests assert
+            # it stays ≤ one chunk under pacing, vs the full prompt on the
+            # unbudgeted control leg)
             self.prefill_interleave_max = n_prefill
         if paced and has_decode:
             self._prefill_debt += n_prefill
+        self.chunk_positions_dispatched += rows * C
+        self.chunk_tokens_fed += n_prefill + sum(1 for _, n in take if n == 0)
+        self.chunk_rows_dispatched += rows
+        self.chunk_rows_live += len(take)
+        self.chunk_dispatches_by_rows[rows] = (
+            self.chunk_dispatches_by_rows.get(rows, 0) + 1
+        )
 
         self._step_counter += 1
-        want_lp = any(
-            s is not None and s.logprobs is not None for s in self._slots
-        )
-        want_pen = any(s is not None and s.penalized for s in self._slots)
-        want_sample = any(
-            s is not None and s.temperature > 0.0 for s in self._slots
-        )
-        # a fresh admission wave's first chunk (every lane starting at
+        seqs = [seq for _, seq, _ in fed]
+        want_lp = any(s.logprobs is not None for s in seqs)
+        want_pen = any(s.penalized for s in seqs)
+        want_sample = any(s.temperature > 0.0 for s in seqs)
+        # a fresh admission wave's first chunk (every row starting at
         # position 0) attends nothing in the pool: compile out the history
-        # gather + partial — this is THE TTFT-critical dispatch
-        want_history = any(
-            s is not None and (s.prefill_pos is None or s.prefill_pos > 0)
-            for s in self._slots
+        # gather + partial — this is THE TTFT-critical dispatch. Only the
+        # full width has that program; under it, zero trips of the history
+        # loop are the no-history case.
+        want_history = rows < S or any(
+            s.prefill_pos is None or s.prefill_pos > 0 for s in seqs
         )
         if want_history and self._pp == 1 and self._sp == 1:
-            bs, mb = cfg.kv_block_size, cfg.max_blocks_per_seq
+            bs = cfg.kv_block_size
             self.chunk_history_tiles_read += int(
-                chunk_history_tiles(positions, bs, mb)
+                chunk_history_tiles(positions, bs, MB)
             )
-            self.chunk_history_tiles_full += history_tiles_full(bs, mb)
+            self.chunk_history_tiles_full += history_tiles_full(bs, MB)
         if want_pen:
             self._sync_counts(list(self._slots))
         counts_in = self._counts if want_pen else self._dummy_counts
-        ipack_np = np.stack([self._seeds, self._topk])
-        fpack_np = np.stack([self._temp, self._topp, self._freqp, self._presp])
         if self._dispatch_hook is not None:
             # multihost leader: followers run the SAME dispatch in lockstep
             self._dispatch_hook(
                 "chunk",
                 dict(lp=want_lp, pen=want_pen, sample=want_sample,
                      history=want_history, step=self._step_counter),
-                dict(tokens=tokens, positions=positions, tables=self._tables,
-                     sample_at=sample_at, ipack=ipack_np, fpack=fpack_np),
+                dict(tokens=tokens, positions=positions, tables=tables,
+                     sample_at=sample_at, lanes=lanes, ipack=ipack_np,
+                     fpack=fpack_np),
             )
         args = (
             self.params, self.cache, counts_in, self._put(tokens),
-            self._put(positions),
-            self._m_tables.get(self._tables), self._put(sample_at),
-            self._put(np.int32(self._step_counter)),
-            self._m_ipack.get(ipack_np),
-            self._m_fpack.get(fpack_np),
+            self._put(positions), self._put(tables), self._put(sample_at),
+            self._put(lanes), self._put(np.int32(self._step_counter)),
+            self._put(ipack_np), self._put(fpack_np),
         ) + self._wd_args()
         self._slow_fault()
         prof = tl is not None and tl.should_sample()
         t_disp = time.perf_counter() if prof else 0.0
+        *fetch, self.cache, counts_out = self._chunk(
+            want_lp, want_pen, want_sample, want_history, rows
+        )(*args)
         # copy_to_host_async right after dispatch: started here, the
         # device→host copy overlaps the chunk's own compute instead of
         # starting cold at get time (the saving is not measured on the
         # current machine)
-        if want_lp:
-            sampled, lp, tids, tlps, self.cache, counts_out = self._chunk(
-                True, want_pen, want_sample, want_history
-            )(*args)
-            for arr in (sampled, lp, tids, tlps):
-                arr.copy_to_host_async()
-            # dynlint: allow-host-sync(leader sync: one fetch per chunk
-            # dispatch, overlapped by copy_to_host_async above)
-            sampled_np, lp_np, tids_np, tlps_np = jax.device_get(
-                (sampled, lp, tids, tlps)
-            )
-        else:
-            sampled, self.cache, counts_out = self._chunk(
-                False, want_pen, want_sample, want_history
-            )(*args)
-            sampled.copy_to_host_async()
-            # dynlint: allow-host-sync(leader sync: one fetch per chunk dispatch)
-            sampled_np = jax.device_get(sampled)
-            lp_np = tids_np = tlps_np = None
-        t_fetch = time.perf_counter() if prof else 0.0
+        for arr in fetch:
+            arr.copy_to_host_async()
+        sealing = self._take_sealing(filled)
+        # the counts go on now, not when the result is fetched: the decode
+        # program of this host step takes them next
         if want_pen:
             self._counts = counts_out
         else:
             self._dummy_counts = counts_out
             self._release_counts()
+        return _ChunkInflight(tuple(fetch), fed, sealing, t_step, t_disp)
 
-        for i in range(S):
-            seq = self._slots[i]
-            if seq is None or consumed[i] is None:
-                continue
-            self._seal_timed(seq.alloc, consumed[i])
+    def _chunk_finish(self, chunk: _ChunkInflight) -> None:
+        """Fetch a chunk dispatch's sampled tokens and hand each row's back
+        to its lane: seal what it fed, and where the prompt is through, emit
+        the first token. A lane that finishes here was inert in every decode
+        dispatch still in flight, so its blocks are free at once."""
+        tl = self._timeline
+        # dynlint: allow-host-sync(leader sync: one fetch per chunk dispatch,
+        # overlapped by copy_to_host_async at dispatch)
+        fetched = jax.device_get(chunk.fetch)
+        t_fetch = time.perf_counter() if chunk.t_disp else 0.0
+        sampled_np = fetched[0]
+        lp_np, tids_np, tlps_np = fetched[1:] if len(fetched) > 1 else (None,) * 3
+        self._sealing = chunk.sealing
+        for r, (lane, seq, toks) in enumerate(chunk.rows):
+            if seq.slot != lane:
+                continue  # left its lane since the dispatch
+            self._seal_timed(seq.alloc, toks)
+            tok = int(sampled_np[r])
             lpinfo = (
-                (float(lp_np[i]), tids_np[i], tlps_np[i])
-                if lp_np is not None
-                else None
+                (float(lp_np[r]), tids_np[r], tlps_np[r])
+                if lp_np is not None else None
             )
-            tok = int(sampled_np[i])
-            if seq.prefill_pos is not None:
-                if self._fair is not None and seq.tenant:
-                    # prefill progress bills the tenant's virtual clock
-                    # (decode tokens bill in _emit_token/_emit_token_run)
-                    self._fair.charge(seq.tenant, len(consumed[i]), seq.weight)
-                seq.prefill_pos += len(consumed[i])
-                if seq.prefill_pos >= len(seq.prompt):
-                    if self._watchdog and tok < 0:
-                        # watchdog sentinel on the lane's FIRST token: no
-                        # token has reached the client yet, but the stream
-                        # still ends typed + in-band so the caller re-homes
-                        self._watchdog_trip(seq)
-                        continue
-                    seq.prefill_pos = None
-                    seq.first_token_t = time.perf_counter()
-                    self._emit_token(seq, tok, lpinfo=lpinfo)
-            else:
+            if seq.prefill_pos is None:  # rode along (`_rides`)
                 if self._watchdog and tok < 0:
                     self._watchdog_trip(seq)
-                    continue
-                self._emit_token(seq, tok, lpinfo=lpinfo)
-        if prof:
+                else:
+                    self._emit_token(seq, tok, lpinfo=lpinfo)
+                continue
+            if self._fair is not None and seq.tenant:
+                # prefill progress bills the tenant's virtual clock
+                # (decode tokens bill in _emit_token/_emit_token_run)
+                self._fair.charge(seq.tenant, len(toks), seq.weight)
+            seq.prefill_pos += len(toks)
+            if seq.prefill_pos < len(seq.prompt):
+                continue
+            if self._watchdog and tok < 0:
+                # watchdog sentinel on the lane's FIRST token: no token has
+                # reached the client yet, but the stream still ends typed +
+                # in-band so the caller re-homes
+                self._watchdog_trip(seq)
+                continue
+            seq.prefill_pos = None
+            seq.first_token_t = time.perf_counter()
+            self._emit_token(seq, tok, lpinfo=lpinfo)
+        self._sealing = None
+        n_tokens = sum(len(toks) for _, _, toks in chunk.rows)
+        if chunk.t_disp:
             self._note_dispatch(
-                tl, "chunk", t_step, t_disp, t_fetch, time.perf_counter(),
-                batch=sum(1 for c in consumed if c is not None),
-                tokens=sum(len(c) for c in consumed if c),
+                tl, "chunk", chunk.t_step, chunk.t_disp, t_fetch,
+                time.perf_counter(), batch=len(chunk.rows), tokens=n_tokens,
             )
         elif tl is not None:
             # unsampled dispatch: drop the accrued allocator share so it
             # can't pile up across the sampling stride and misattribute
             self._prof_alloc_us = 0.0
         if self._straggler is not None:
-            self._straggler_tick(
-                "chunk", t_step, sum(len(c) for c in consumed if c)
-            )
+            self._straggler_tick("chunk", chunk.t_step, n_tokens)
 
-    def _decode_step(self) -> None:
-        """Pipelined decode: dispatch chunk N+1 off the previous dispatch's
-        device-resident carry, THEN fetch + process chunk N. The host↔device
-        round trip and the host-side token processing overlap the next
-        chunk's execution. Blocks owned by sequences that
-        finish mid-pipeline receive up to one chunk of speculative garbage
-        writes, so their allocations are parked in ``_zombie_allocs`` and
-        freed only once the in-flight chunk has been fetched."""
+    def _prepare_lanes(self) -> None:
+        """Before a host step dispatches anything: end the cancelled lanes
+        and grow the decode lanes' allocations. Either can free blocks (a
+        cancellation's, a preemption victim's), so whatever is in flight is
+        drained first wherever that happens."""
         cfg = self.config
-        S, k = cfg.max_slots, cfg.decode_steps
+        k = cfg.decode_steps
         tl = self._timeline
-        t_step = (
-            time.perf_counter()
-            if tl is not None or self._straggler is not None else 0.0
-        )
-
         stopped = [s for s in self._slots if s is not None and s.ctx.context.is_stopped]
         if stopped:
             self._drain_inflight()
@@ -2442,8 +2614,8 @@ class JaxServingEngine(AsyncEngine):
 
         # capacity: this chunk writes positions total_len-1 .. total_len-2+k,
         # and the next (speculative) chunk another k past that. Prefilling
-        # lanes (paced duty cycle: they sit decode dispatches out) neither
-        # grow nor dispatch here.
+        # lanes hold their whole prompt's blocks from admission and are no
+        # row of the decode program: they neither grow nor dispatch there.
         t_grow = time.perf_counter() if tl is not None else 0.0
         while True:
             ok = True
@@ -2475,23 +2647,72 @@ class JaxServingEngine(AsyncEngine):
             # grow/evict/preempt work: the allocator share of this
             # dispatch's host overhead
             self._prof_alloc_us += (time.perf_counter() - t_grow) * 1e6
-        active = [
-            s for s in self._slots
-            if s is not None and s.prefill_pos is None
-        ]
-        if not active:
-            return
 
-        lanes = list(self._slots)
+    def _live_lanes(self) -> List[Optional["_Seq"]]:
+        """By slot, the sequences the decode program advances: a prefilling
+        lane is none of them (position -1 keeps it inert in-jit)."""
+        return [
+            s if s is not None and s.prefill_pos is None else None
+            for s in self._slots
+        ]
+
+    def _decode_step(self) -> None:
+        """Pipelined decode: dispatch chunk N+1 off the previous dispatch's
+        device-resident carry, THEN fetch + process chunk N. The host↔device
+        round trip and the host-side token processing overlap the next
+        chunk's execution. Blocks owned by sequences that
+        finish mid-pipeline receive up to one chunk of speculative garbage
+        writes, so their allocations are parked in ``_zombie_allocs`` and
+        freed only once the in-flight chunk has been fetched."""
+        tl = self._timeline
+        t_step = (
+            time.perf_counter()
+            if tl is not None or self._straggler is not None else 0.0
+        )
+        self._prepare_lanes()
+        decode = self._decode_dispatch(profile=True)
+        if decode is None:
+            return
+        prev, n_active, t_disp, t_fetch = decode
+        if prev is not None:
+            self._process_chunk(prev, defer_free=True)
+        k = self.config.decode_steps
+        if t_disp:
+            self._note_dispatch(
+                tl, "decode", t_step, t_disp, t_fetch, time.perf_counter(),
+                batch=n_active, tokens=n_active * k,
+            )
+        elif tl is not None:
+            self._prof_alloc_us = 0.0
+        if self._straggler is not None:
+            self._straggler_tick("decode", t_step, n_active * k)
+
+    def _decode_dispatch(
+        self, profile: bool
+    ) -> Optional[Tuple[Optional[_Inflight], int, float, float]]:
+        """Dispatch the decode program over the live lanes (after
+        `_prepare_lanes`) and return (the dispatch it displaced, still to be
+        processed; live lanes; the profiled dispatch's two clock readings,
+        0.0 when it is not sampled). None when nothing was dispatched: no
+        lane decodes, or none needs more than what is in flight."""
+        cfg = self.config
+        S, k = cfg.max_slots, cfg.decode_steps
+        tl = self._timeline
+        live = self._live_lanes()
         if self._inflight is not None and any(
-            a is not b for a, b in zip(self._inflight.lanes, lanes)
+            a is not b for a, b in zip(self._inflight.lanes, live)
         ):
-            # lane set changed since the in-flight dispatch: its carry no
-            # longer matches; fall back to host-built inputs
+            # the live set changed since the in-flight dispatch (a lane
+            # left, or one finished its prefill: the same _Seq, but inert
+            # in that dispatch's carry): the carry no longer matches; fall
+            # back to host-built inputs
             self._drain_inflight()
-            lanes = list(self._slots)
-            if not any(lanes):
-                return
+            live = self._live_lanes()
+        n_active = sum(1 for s in live if s is not None)
+        if not n_active:
+            # nothing left to carry forward: what is in flight ends here
+            self._drain_inflight()
+            return None
 
         # Don't dispatch a chunk nothing needs: if every active lane provably
         # reaches a length stop within the already-in-flight chunk, a
@@ -2510,19 +2731,16 @@ class JaxServingEngine(AsyncEngine):
                 return False
             return True
 
-        if not any(
-            lane_needs_more(s) for s in lanes
-            if s is not None and s.prefill_pos is None
-        ):
+        if not any(lane_needs_more(s) for s in live if s is not None):
             self._drain_inflight()
-            return
+            return None
 
         for i in range(S):
-            seq = self._slots[i]
+            seq = live[i]
             self._tables[i, :] = 0
-            if seq is None or seq.prefill_pos is not None:
-                # empty lane — or a prefilling lane sitting this paced
-                # decode dispatch out (position -1 keeps it inert in-jit)
+            if seq is None:
+                # empty lane — or a prefilling lane, which is a row of the
+                # chunk program and not of this one
                 self._positions[i] = -1
                 self._last_tokens[i] = 0
                 self._temp[i] = 0.0
@@ -2543,6 +2761,16 @@ class JaxServingEngine(AsyncEngine):
             self._presp[i] = seq.pres_pen
 
         use_carry = self._inflight is not None
+        # the blocks this dispatch fills (they seal when it is processed). A
+        # lane riding the carry is k positions further than the host has
+        # emitted: what is in flight comes first.
+        ahead = k if use_carry else 0
+        filled: List[int] = []
+        for seq in live:
+            if seq is not None:
+                filled += self._blocks_filled(
+                    seq.alloc, seq.total_len - 1 + ahead, k
+                )
         if use_carry:
             toks_in, pos_in = self._inflight.tokens, self._inflight.positions
         else:
@@ -2550,15 +2778,11 @@ class JaxServingEngine(AsyncEngine):
             pos_in = self._put(self._positions)
 
         self._step_counter += 1
-        live = [
-            s if (s is not None and s.prefill_pos is None) else None
-            for s in lanes
-        ]
         want_lp = any(s is not None and s.logprobs is not None for s in live)
         want_pen = any(s is not None and s.penalized for s in live)
         want_sample = any(s is not None and s.temperature > 0.0 for s in live)
         if want_pen:
-            self._sync_counts(lanes)
+            self._sync_counts(list(self._slots))
         counts_in = self._counts if want_pen else self._dummy_counts
         ipack_np = np.stack([self._seeds, self._topk])
         fpack_np = np.stack([self._temp, self._topp, self._freqp, self._presp])
@@ -2578,8 +2802,9 @@ class JaxServingEngine(AsyncEngine):
             self._m_fpack.get(fpack_np),
         ) + self._wd_args()
         self._slow_fault()
-        prof = tl is not None and tl.should_sample()
+        prof = profile and tl is not None and tl.should_sample()
         t_disp = time.perf_counter() if prof else 0.0
+        t_fetch = 0.0
         if want_lp:
             out, lps, tids, tlps, toks2, pos2, self.cache, counts_out = (
                 self._decode(True, want_pen, want_sample)(*args)
@@ -2602,7 +2827,9 @@ class JaxServingEngine(AsyncEngine):
             self._dummy_counts = counts_out
             self._release_counts()
         prev, self._inflight = (
-            self._inflight, _Inflight(out, lps, tids, tlps, toks2, pos2, lanes)
+            self._inflight,
+            _Inflight(out, lps, tids, tlps, toks2, pos2, live,
+                      self._take_sealing(filled)),
         )
         # start the host copies now: by the time this chunk is processed (one
         # pipelined dispatch later) the fetch has ridden the previous chunk's
@@ -2610,17 +2837,7 @@ class JaxServingEngine(AsyncEngine):
         for arr in (out, lps, tids, tlps):
             if arr is not None:
                 arr.copy_to_host_async()
-        if prev is not None:
-            self._process_chunk(prev, defer_free=True)
-        if prof:
-            self._note_dispatch(
-                tl, "decode", t_step, t_disp, t_fetch, time.perf_counter(),
-                batch=len(active), tokens=len(active) * k,
-            )
-        elif tl is not None:
-            self._prof_alloc_us = 0.0
-        if self._straggler is not None:
-            self._straggler_tick("decode", t_step, len(active) * k)
+        return prev, n_active, t_disp, t_fetch
 
     def _emit_token_run(
         self,
@@ -2726,12 +2943,11 @@ class JaxServingEngine(AsyncEngine):
             out = jax.device_get(chunk.out)
             lps = tids = tlps = None
         out = np.asarray(out)  # [S, k_steps]
+        self._sealing = chunk.sealing
         for i, seq in enumerate(chunk.lanes):
             if seq is None or seq.slot != i:
-                continue  # empty lane, or finished in an earlier chunk
-            if seq.prefill_pos is not None:
-                # prefilling lane that sat a paced decode dispatch out
-                # (position -1 in-jit): its row is garbage, not tokens
+                # not live in this dispatch (empty, or prefilling then: its
+                # row is garbage, not tokens), or finished in an earlier chunk
                 continue
             self._emit_token_run(
                 seq,
@@ -2739,6 +2955,7 @@ class JaxServingEngine(AsyncEngine):
                 (lps[i], tids[i], tlps[i]) if lps is not None else None,
                 defer_free=defer_free,
             )
+        self._sealing = None
         if self._perf is not None:
             self._perf.note_decode(
                 self.total_generated_tokens - tokens_before,
@@ -3221,8 +3438,35 @@ class JaxServingEngine(AsyncEngine):
         sealed pages' bytes and crc them (runtime/integrity.py). This is
         the integrity plane's steady-state cost — one small device→host
         copy per sealed block, knob-gated by DYN_TPU_KV_INTEGRITY. MUST
-        run on the engine thread (note_tokens_computed call sites)."""
+        run on the engine thread (note_tokens_computed call sites). Where
+        the dispatch being processed had these blocks' pages taken as it
+        was dispatched (_take_sealing), their bytes are on the host already."""
+        ahead = self._sealing
+        if ahead is not None and all(b in ahead.where for b in block_ids):
+            return kv_pages.checksums(kv_pages.select(
+                ahead.host(), [ahead.where[b] for b in block_ids]
+            ))
         return kv_pages.checksums(self.extract_blocks(block_ids))
+
+    def _take_sealing(self, filled: List[int]) -> Optional[_SealPages]:
+        """Enqueue, behind the program just dispatched, the read of the blocks
+        it fills to their end (``filled``, by `_blocks_filled`), and start
+        their copy to the host. The list is padded with its last id to one
+        of `_sealing_sizes`."""
+        n = len(filled)
+        if not self._seal_checksums or not 0 < n <= max(self._sealing_sizes, default=0):
+            return None
+        size = next(b for b in self._sealing_sizes if b >= n)
+        pages = kv_pages.take(self.cache, filled + filled[-1:] * (size - n))
+        for a in pages.values():
+            a.copy_to_host_async()
+        return _SealPages(pages, {b: j for j, b in enumerate(filled)})
+
+    def _blocks_filled(self, alloc: SequenceAllocation, start: int, n: int) -> List[int]:
+        """The blocks of ``alloc`` that positions ``[start, start + n)`` fill
+        to their end: those seal once the positions are noted as computed."""
+        bs = self.config.kv_block_size
+        return alloc.block_ids[start // bs : (start + n) // bs]
 
     def seed_external_prefix(
         self, token_ids: List[int], pages: kv_pages.Pages
@@ -3777,6 +4021,20 @@ class JaxServingEngine(AsyncEngine):
             # over the tiles of the block tables' full width (cumulative)
             "chunk_history_tiles_read": self.chunk_history_tiles_read,
             "chunk_history_tiles_full": self.chunk_history_tiles_full,
+            # how full the chunk dispatches are (cumulative): positions
+            # computed (rows x prefill_chunk) and the prompt tokens among
+            # them, rows dispatched and the rows that held a prefilling
+            # lane, and how often each rung of the row ladder was taken
+            "chunk_positions_dispatched": self.chunk_positions_dispatched,
+            "chunk_tokens_fed": self.chunk_tokens_fed,
+            "chunk_rows_dispatched": self.chunk_rows_dispatched,
+            "chunk_rows_live": self.chunk_rows_live,
+            "chunk_dispatches_by_rows": {
+                # keyed as JSON sends it. .copy(): one atomic C-level op
+                # (the engine thread adds rungs without holding _cond)
+                str(r): n
+                for r, n in sorted(self.chunk_dispatches_by_rows.copy().items())
+            },
             # mid-stream resume: re-admissions this engine served (the
             # client-side resume counters live in runtime/resilience.py)
             "resumed_requests": self.resumed_requests,

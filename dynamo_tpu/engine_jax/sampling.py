@@ -18,6 +18,8 @@ top_p >= 1 → no top-p.
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
@@ -51,13 +53,18 @@ def apply_penalties(
 
 
 def update_counts(
-    counts: jax.Array,  # [B, V] int32
+    counts: jax.Array,  # [S, V] int32
     tokens: jax.Array,  # [B] int32 sampled this step
     active: jax.Array,  # [B] bool — lanes whose sample is real (not padding)
+    rows: Optional[jax.Array] = None,  # [B] int32 — the count row of each lane
 ) -> jax.Array:
-    """Scatter-add this step's sampled tokens into the count buffer."""
-    b = counts.shape[0]
-    return counts.at[jnp.arange(b), tokens].add(active.astype(counts.dtype))
+    """Scatter-add this step's sampled tokens into the count buffer. ``rows``
+    names each lane's row where the lanes are not the buffer's rows in order
+    (a chunk dispatch's packed rows); a row index past the buffer is dropped."""
+    add = active.astype(counts.dtype)
+    if rows is None:
+        return counts.at[jnp.arange(counts.shape[0]), tokens].add(add)
+    return counts.at[rows, tokens].add(add, mode="drop")
 
 
 def sample_tokens(

@@ -94,6 +94,7 @@ class LeaderBroadcaster:
                 arrays["positions"].astype(np.int32),
                 arrays["tables"].astype(np.int32),
                 arrays["sample_at"].astype(np.int32),
+                arrays["lanes"].astype(np.int32),
                 arrays["ipack"].astype(np.int32),
                 arrays["fpack"].astype(np.float32),
             )
@@ -170,21 +171,25 @@ def follower_serve(model_config, params, engine_config, mesh, engine=None) -> No
         want_pen = bool(hdr[6])
         counts_in = eng._counts if want_pen else counts
         if op == OP_CHUNK:
-            tokens, positions, tables, sample_at, ipack, fpack = _broadcast((
+            # the leader packs one row per prefilling lane; an engine on a
+            # process-spanning mesh has the one-rung ladder, so the packed
+            # rows arrive at the fixed [S, C] shape (engine._chunk_rungs)
+            tokens, positions, tables, sample_at, lanes, ipack, fpack = _broadcast((
                 np.zeros((S, C), np.int32), np.zeros((S, C), np.int32),
-                np.zeros((S, MB), np.int32), z_i,
+                np.zeros((S, MB), np.int32), z_i, z_i,
                 np.zeros((2, S), np.int32), np.zeros((4, S), np.float32),
             ))
             fn = eng._chunk(want_lp, want_pen, want_sample, want_history)
             res = fn(
                 eng.params, eng.cache, counts_in, eng._put(tokens),
-                eng._put(positions), eng._m_tables.get(tables),
-                eng._put(sample_at), eng._put(np.int32(step)),
-                eng._m_ipack.get(ipack), eng._m_fpack.get(fpack),
+                eng._put(positions), eng._put(tables),
+                eng._put(sample_at), eng._put(lanes),
+                eng._put(np.int32(step)), eng._put(ipack), eng._put(fpack),
             )
             # lp variants return (sampled, lp, ids, lps, cache, counts)
             eng.cache, counts_out = res[-2], res[-1]
-            carry = None  # leader also drains its pipeline around chunks
+            # the decode carry stays: the leader dispatches its decode program
+            # beside a chunk off the same carry (use_carry rides the header)
         else:
             tokens, positions, tables, ipack, fpack = _broadcast((
                 z_i, z_i, np.zeros((S, MB), np.int32),
@@ -204,12 +209,13 @@ def follower_serve(model_config, params, engine_config, mesh, engine=None) -> No
             eng.cache, counts_out = res[-2], res[-1]
             carry = (res[-4], res[-3])
         # mirror the leader's counts bookkeeping: penalized dispatches carry
-        # the real buffer forward; others update the dummy and release
+        # the real buffer forward, others the dummy; the buffer is dropped
+        # when the leader says so (OP_COUNTS_RELEASE), which it does after a
+        # dispatch that penalized nothing unless another lane still needs it
         if want_pen:
             eng._counts = counts_out
         else:
             counts = counts_out
-            eng._counts = None
 
 
 def _process_index() -> int:

@@ -194,7 +194,7 @@ POOL_PROGRAMS = {
 }
 
 
-def _compile_pool_program(program, cfg, num_blocks, quantized, mesh, one_chip):
+def _compile_pool_program(program, cfg, num_blocks, quantized, mesh, one_chip, rows=LANES):
     fn, kw = POOL_PROGRAMS[program]
     params = jax.eval_shape(lambda: llama.init_params(jax.random.PRNGKey(0), cfg))
     cache = jax.eval_shape(
@@ -219,7 +219,7 @@ def _compile_pool_program(program, cfg, num_blocks, quantized, mesh, one_chip):
 
     ints = [
         jax.ShapeDtypeStruct(shape, jnp.int32, sharding=rep)
-        for shape in ((LANES, CHUNK), (LANES, CHUNK), (LANES, TABLE))
+        for shape in ((rows, CHUNK), (rows, CHUNK), (rows, TABLE))
     ]
     jitted = jax.jit(
         lambda params, cache, *a: fn(params, cache, cfg, *a, **kw),
@@ -347,6 +347,36 @@ def test_step_programs_never_copy_the_kv_pool(request, one_chip, program, layout
     assert temps[0] - temps[1] < added_slice // 4, temps
 
 
+@pytest.mark.parametrize("rung", [0, 1, 2])
+@pytest.mark.parametrize("layout, slots", [("bf16", 32), ("bf16_tp4", 16)])
+def test_every_rung_of_the_chunk_row_ladder_compiles_in_place(request, one_chip, layout, slots, rung):
+    """The chunk program at each row count the engine dispatches it at
+    (``chunk_row_ladder``: 4 / 8 / 32 rows of the one-chip cell's 32 slots with
+    two KV heads, 2 / 4 / 16 of the four-chip cell's 16 with one KV head a
+    shard), for the described v5e: it compiles, the donated pool aliases the
+    output in full, and nothing pool-sized comes out of any op but views and
+    the in-place scatter. The history-bearing program, the only one a rung
+    under ``max_slots`` has. (A mesh engine dispatches the top rung alone
+    while its decode lanes ride the chunk, ``engine._rides``: the rungs under
+    it are held here for the day that is lifted.)"""
+    from dynamo_tpu.engine_jax.engine import chunk_row_ladder
+
+    rows = chunk_row_ladder(slots)[rung]
+    mesh = request.getfixturevalue("tp4_mesh") if layout == "bf16_tp4" else None
+    cfg = dataclasses.replace(
+        llama.LLAMA_PRESETS["qwen2.5-1.5b" if layout == "bf16" else "qwen2.5-7b"],
+        num_layers=POOL_LAYERS,
+    )
+    shards = 1 if mesh is None else mesh.shape["tp"]
+    num_blocks = POOL_BLOCKS[1]
+    compiled = _compile_pool_program("chunk", cfg, num_blocks, False, mesh, one_chip, rows=rows)
+    pool = jax.eval_shape(lambda: llama.make_kv_cache(cfg, num_blocks, BS))
+    pages = pool["k"].size // shards
+    assert _pool_sized_instructions(compiled.as_text(), {pages, pages // cfg.num_layers}) == []
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in pool.values()) // shards
+    assert pool_bytes <= compiled.memory_analysis().alias_size_in_bytes < pool_bytes * 1.001
+
+
 # Temporary memory of the chunk program at Qwen2.5-1.5B's serving shapes (32
 # lanes x 128 positions, tables of 2,048 positions, the 6,144-block pool, full
 # depth), by the described v5e's compiler:
@@ -363,6 +393,20 @@ def test_chunk_program_holds_no_scores_as_wide_as_the_block_tables(one_chip):
     compiled = _compile_pool_program("chunk", cfg, 6144, False, None, one_chip)
     assert compiled.memory_analysis().temp_size_in_bytes < CHUNK_TEMP_LIMIT
     assert re.findall(rf"f32\[[\d,]*,{TABLE * BS}\]", compiled.as_text()) == []
+
+
+def test_the_smallest_rung_of_the_chunk_program_holds_an_eighth_of_the_temporaries(one_chip):
+    """At full depth and the served pool, 4 rows of 32: the program's
+    temporaries follow its rows (PERF.md 4 records the figure), so a rung
+    that went back to computing every lane fails here."""
+    from dynamo_tpu.engine_jax.engine import chunk_row_ladder
+
+    cfg = llama.LLAMA_PRESETS["qwen2.5-1.5b"]
+    rows = chunk_row_ladder(LANES)[0]
+    compiled = _compile_pool_program("chunk", cfg, 6144, False, None, one_chip, rows=rows)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"chunk program at {rows} rows: temp_size_in_bytes {temp}")
+    assert temp < CHUNK_TEMP_LIMIT * rows // LANES * 2
 
 
 @pytest.mark.parametrize("layout", ["bf16", "bf16_tp4", "int8"])

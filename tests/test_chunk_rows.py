@@ -1,0 +1,463 @@
+"""A chunk dispatch holds one row per lane that prefills; a lane that decodes is
+never a row of it (engine_jax/engine.py ``_prefill_step``).
+
+The engine is driven one host step at a time on the test's own thread
+(``_admit`` + ``_dispatch_step``, what ``_step_loop`` runs; no engine thread is
+started), so that each hazard is met on the step it belongs to and not by
+luck: a decode dispatch in flight while a later chunk finishes a lane's
+prefill, a lane joining the decode set, a lane finishing with its blocks still
+written. Tiny model, float32, CPU: greedy output of every request is held
+against the same request served alone."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dynamo_tpu.engine_jax.compile_cache import compile_count
+from dynamo_tpu.engine_jax.engine import (
+    _FINISHED,
+    EngineConfig,
+    JaxServingEngine,
+    _Seq,
+    chunk_row_ladder,
+)
+from dynamo_tpu.llm.protocols.common import (
+    PreprocessedRequest,
+    SamplingOptions,
+    StopConditions,
+)
+from dynamo_tpu.models.llama import LLAMA_PRESETS, init_params
+from dynamo_tpu.runtime.engine import Context
+
+CFG = dataclasses.replace(LLAMA_PRESETS["tiny"], dtype=jnp.float32)
+# ladder [1, 2, 8]: three prefilling lanes already outnumber the small rungs
+ENGINE_CFG = EngineConfig(
+    max_slots=8, kv_block_size=8, max_model_len=160, prefill_chunk=16, decode_steps=4
+)
+CHUNK = ENGINE_CFG.prefill_chunk
+
+
+class _Inline:
+    """Stands where a request's event loop does: items land in its queue at once."""
+
+    def is_closed(self):
+        return False
+
+    def call_soon_threadsafe(self, fn, *args):
+        fn(*args)
+
+
+def prompt_of(n, salt):
+    return [(salt * 31 + 7 * i + 3) % 97 + 1 for i in range(n)]
+
+
+def submit(eng, prompt, max_tokens, **sampling):
+    req = PreprocessedRequest(
+        token_ids=list(prompt),
+        stop_conditions=StopConditions(max_tokens=max_tokens, ignore_eos=True),
+        sampling_options=SamplingOptions(**sampling),
+    )
+    seq = _Seq(Context(req), req, _Inline())
+    eng._pending.append(seq)
+    return seq
+
+
+def step(eng):
+    eng._admit()
+    eng._dispatch_step()
+
+
+def busy(eng):
+    return bool(eng._pending or any(eng._slots) or eng._inflight is not None)
+
+
+def run_out(eng, limit=400):
+    for _ in range(limit):
+        if not busy(eng):
+            return
+        step(eng)
+    raise AssertionError("the engine did not come to rest")
+
+
+def answer(seq):
+    """(tokens, log-probabilities, finish reason) of everything emitted so far."""
+    toks, lps, finish = [], [], None
+    while not seq.out_queue.empty():
+        item = seq.out_queue.get_nowait()
+        if item is _FINISHED:
+            continue
+        d = item.data or {}
+        toks.extend(d.get("token_ids", []))
+        lps.extend(d.get("log_probs") or [])
+        finish = d.get("finish_reason") or finish
+    return toks, lps, finish
+
+
+@pytest.fixture(scope="module")
+def params():
+    return init_params(jax.random.PRNGKey(0), CFG)
+
+
+@pytest.fixture(scope="module")
+def alone(params):
+    """The same request served with nothing beside it, on an engine of its own
+    (one for the module: its programs compile once)."""
+    eng = JaxServingEngine(CFG, params, ENGINE_CFG)
+    known = {}
+
+    def serve(prompt, max_tokens, **sampling):
+        key = (tuple(prompt), max_tokens, tuple(sorted(sampling.items())))
+        if key not in known:
+            seq = submit(eng, prompt, max_tokens, **sampling)
+            run_out(eng)
+            known[key] = answer(seq)
+        return known[key]
+
+    yield serve
+    eng.close()
+
+
+@pytest.fixture(scope="module")
+def shared(params):
+    eng = JaxServingEngine(CFG, params, ENGINE_CFG)
+    yield eng
+    eng.close()
+
+
+@pytest.fixture()
+def eng(shared):
+    assert not busy(shared)
+    yield shared
+    run_out(shared)
+    assert shared.allocator.active_blocks == 0 and not shared._zombie_allocs
+
+
+def mesh_engine(params, **axes):
+    from dynamo_tpu.models.llama import param_shardings
+    from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
+
+    mesh = make_mesh(MeshConfig(**axes))
+    return JaxServingEngine(
+        CFG, jax.device_put(params, param_shardings(CFG, mesh)), ENGINE_CFG, mesh=mesh
+    )
+
+
+# -- the ladder ----------------------------------------------------------------
+
+
+@pytest.mark.parametrize("slots, want", [
+    (1, [1]), (2, [2]), (4, [1, 4]), (16, [2, 4, 16]), (32, [4, 8, 32]),
+])
+def test_the_ladder_has_max_slots_and_at_most_two_rungs_under_it(slots, want):
+    ladder = chunk_row_ladder(slots)
+    assert ladder == want
+    assert ladder == sorted(set(ladder)) and ladder[0] >= 1 and ladder[-1] == slots
+    assert len(ladder) <= 3
+
+
+@pytest.mark.parametrize("axes", [dict(pp=2), dict(sp=2), dict(tp=2)], ids=["pp", "sp", "tp"])
+def test_an_engine_on_a_mesh_has_the_one_rung_ladder(params, axes):
+    """A pipeline's stages microbatch the row axis, the sp forward has run at
+    one width and a process-spanning mesh broadcasts fixed shapes; and on
+    every mesh the decode lanes still ride the chunk dispatch (``_rides``:
+    the benchmark's tracer, not the engine, asks for it), so every lane can
+    be a row: ``[max_slots]`` through the same host code."""
+    eng = mesh_engine(params, **axes)
+    try:
+        assert eng._rides and eng._chunk_rungs == [ENGINE_CFG.max_slots]
+    finally:
+        eng.close()
+
+
+# -- token for token -------------------------------------------------------------
+
+# (arrives at host step, prompt tokens, answer tokens, sampling): prompts of one
+# to five chunks, so that lanes prefill beside lanes that decode in most steps
+MIXED = [
+    (0, 9, 28, {}), (2, 40, 12, {}), (2, 70, 9, {}), (3, 17, 14, {}),
+    (5, 33, 10, {}), (9, 16, 6, {}), (9, 50, 8, {}),
+]
+# five lanes start their prefill in one step beside one that decodes: more
+# than the largest small rung (2 of 8) holds
+WAVE = [(0, 9, 24, {})] + [(3, 20 + 9 * i, 8, {}) for i in range(5)]
+
+
+def with_sampling(schedule, which, **sampling):
+    return [(at, n, m, dict(s, **sampling) if i in which else s)
+            for i, (at, n, m, s) in enumerate(schedule)]
+
+
+def serve_schedule(eng, schedule, on_step=None, salt=0):
+    seqs, t = {}, 0
+    while busy(eng) or len(seqs) < len(schedule):
+        for i, (at, n, m, sampling) in enumerate(schedule):
+            if at == t:
+                seqs[i] = submit(eng, prompt_of(n, salt + i), m, **sampling)
+        if on_step is not None:
+            on_step(t, seqs)
+        step(eng)
+        t += 1
+        assert t < 400
+    return [answer(seqs[i]) for i in range(len(schedule))]
+
+
+@pytest.mark.parametrize("salt, schedule", [
+    (0, MIXED),
+    (10, with_sampling(MIXED, {1, 3, 4}, logprobs=0)),
+    (20, with_sampling(MIXED, {0, 2}, frequency_penalty=1.5, presence_penalty=0.5)),
+    (30, WAVE),
+], ids=["plain", "logprobs", "a_penalised_lane", "more_lanes_prefill_than_the_small_rungs_hold"])
+def test_every_request_under_mixed_traffic_answers_as_it_does_alone(eng, alone, salt, schedule):
+    """``salt`` gives each case prompts of its own: no case finds another's
+    prefix in the cache, so each prefills all its chunks."""
+    by_rows, rows_live = dict(eng.chunk_dispatches_by_rows), eng.chunk_rows_live
+    got = serve_schedule(eng, schedule, salt=salt)
+    for i, (at, n, m, sampling) in enumerate(schedule):
+        toks, lps, finish = got[i]
+        want_toks, want_lps, _ = alone(prompt_of(n, salt + i), m, **sampling)
+        assert (toks, finish) == (want_toks, "length"), i
+        assert len(toks) == m
+        if "logprobs" in sampling:
+            assert len(lps) == m
+            np.testing.assert_allclose(lps, want_lps, atol=2e-5)
+    # a row for every chunk of every prompt, and none for a lane that decodes
+    assert eng.chunk_rows_live - rows_live == sum(-(-n // CHUNK) for _, n, _, _ in schedule)
+    if schedule is WAVE:  # five lanes at once took the top rung
+        assert eng.chunk_dispatches_by_rows[ENGINE_CFG.max_slots] > by_rows.get(ENGINE_CFG.max_slots, 0)
+
+
+@pytest.mark.parametrize("salt, schedule", [
+    (50, MIXED),
+    (60, with_sampling(MIXED, {1, 3, 4}, logprobs=0)),
+    (70, with_sampling(MIXED, {0, 2}, frequency_penalty=1.5, presence_penalty=0.5)),
+], ids=["plain", "logprobs", "a_penalised_lane"])
+def test_on_a_mesh_the_decode_lanes_ride_the_chunk_and_answer_as_alone(params, salt, schedule):
+    """``_rides``: a lane that decodes is a row of every chunk dispatch, one
+    token forward, and the decode program runs in no step in which a lane
+    prefills. Held against the same requests served one at a time on a mesh
+    engine of their own (a sharded sum rounds as a sharded sum)."""
+    eng, one = mesh_engine(params, tp=2), mesh_engine(params, tp=2)
+    try:
+        decodes = []
+        dispatch = eng._decode_dispatch
+        eng._decode_dispatch = lambda **kw: decodes.append(
+            any(s is not None and s.prefill_pos is not None for s in eng._slots)
+        ) or dispatch(**kw)
+        got = serve_schedule(eng, schedule, salt=salt)
+        for i, (at, n, m, sampling) in enumerate(schedule):
+            seq = submit(one, prompt_of(n, salt + i), m, **sampling)
+            run_out(one)
+            want_toks, want_lps, _ = answer(seq)
+            toks, lps, finish = got[i]
+            assert (toks, finish) == (want_toks, "length"), i
+            if "logprobs" in sampling:
+                np.testing.assert_allclose(lps, want_lps, atol=2e-5)
+        assert decodes and not any(decodes)
+        assert set(eng.chunk_dispatches_by_rows) == {ENGINE_CFG.max_slots}
+        # more rows than chunks of prompt: the riders
+        assert eng.chunk_rows_live > sum(-(-n // CHUNK) for _, n, _, _ in schedule)
+        assert eng.allocator.active_blocks == 0 and not eng._zombie_allocs
+    finally:
+        eng.close()
+        one.close()
+
+
+def test_a_request_cancelled_mid_prefill_frees_its_lane_and_moves_no_other(eng, alone):
+    def cancel(t, seqs):
+        # request 2 (70 tokens, five chunks) has fed two chunks by now
+        if t == 4:
+            assert 0 < seqs[2].prefill_pos < 70
+            seqs[2].ctx.context.stop_generating()
+
+    got = serve_schedule(eng, MIXED, on_step=cancel, salt=40)
+    for i, (at, n, m, sampling) in enumerate(MIXED):
+        if i == 2:
+            assert got[i] == ([], [], "cancelled")
+        else:
+            assert got[i][0] == alone(prompt_of(n, 40 + i), m)[0], i
+
+
+def test_a_preempted_lane_answers_as_it_does_alone(params, alone):
+    """A pool too small for its lanes: a decode lane's growth fails, the
+    victim gives its blocks back and prefills again beside the lanes that
+    decode, prompt and answer so far as its prompt."""
+    cfg = dataclasses.replace(ENGINE_CFG, max_slots=4, num_kv_blocks=14)
+    eng = JaxServingEngine(CFG, params, cfg)
+    try:
+        schedule = [(0, 30, 40, {}), (0, 28, 40, {}), (1, 26, 30, {})]
+        got = serve_schedule(eng, schedule)
+        assert eng.preemptions >= 1
+        for i, (at, n, m, _) in enumerate(schedule):
+            assert got[i][0] == alone(prompt_of(n, i), m)[0], i
+        assert eng.allocator.active_blocks == 0 and not eng._zombie_allocs
+    finally:
+        eng.close()
+
+
+# -- the hazards, each on the step it belongs to -----------------------------------
+
+
+def test_a_decode_dispatch_in_flight_emits_nothing_for_a_lane_that_prefilled_then(eng, alone):
+    """Lane B's last chunk is dispatched while the decode dispatch of the step
+    before, in which B was inert, is still in flight: by the time that one is
+    processed B reads as a decode lane, and its row there is garbage."""
+    a = submit(eng, prompt_of(9, 50), 40)
+    step(eng)  # A prefills
+    step(eng)  # A decodes: one dispatch in flight
+    assert eng._inflight is not None and eng._inflight.lanes[a.slot] is a
+    b = submit(eng, prompt_of(2 * CHUNK, 51), 6)
+    step(eng)  # B's first chunk, beside A's decode dispatch
+    assert b.prefill_pos == CHUNK
+    before = eng._inflight
+    assert before.lanes[b.slot] is None and before.lanes[a.slot] is a
+    step(eng)  # B's last chunk; `before` is processed after it
+    assert b.prefill_pos is None and eng._inflight is not before
+    toks, _, _ = answer(b)
+    assert toks == alone(prompt_of(2 * CHUNK, 51), 6)[0][:1]  # the first token, no garbage run
+    assert eng._inflight.lanes[b.slot] is None  # still inert in the newest dispatch
+    run_out(eng)
+    assert answer(b)[0] == alone(prompt_of(2 * CHUNK, 51), 6)[0][1:]
+    assert answer(a)[0] == alone(prompt_of(9, 50), 40)[0]
+
+
+def test_a_lane_that_finishes_prefill_joins_the_decode_set_from_host_built_inputs(eng, alone):
+    """The lane's ``_Seq`` is the one the in-flight dispatch saw prefilling, so
+    identity says nothing changed; its carry there is (0, -1). The next
+    decode dispatch drains the pipeline and builds its inputs on the host."""
+    a = submit(eng, prompt_of(9, 60), 40)
+    b = submit(eng, prompt_of(CHUNK + 3, 61), 9)
+    step(eng)  # both prefill (A done)
+    step(eng)  # B's last chunk beside A's first decode dispatch
+    assert b.prefill_pos is None
+    inert = eng._inflight
+    assert inert.lanes[a.slot] is a and inert.lanes[b.slot] is None
+    assert int(np.asarray(inert.positions)[b.slot]) == -1
+    step(eng)  # B joins: host-built inputs, not that carry
+    assert eng._inflight is not inert and eng._inflight.lanes[b.slot] is b
+    run_out(eng)
+    assert answer(b)[0] == alone(prompt_of(CHUNK + 3, 61), 9)[0]
+    assert answer(a)[0] == alone(prompt_of(9, 60), 40)[0]
+
+
+def test_penalty_counts_are_read_and_written_at_the_lane_not_the_row(eng, alone):
+    """B prefills as row 0 of its dispatch from lane 1: its first token counts
+    in row 1 of the [S, V] buffer, and lane 0's row stays as it was."""
+    a = submit(eng, prompt_of(9, 70), 30)
+    step(eng)
+    step(eng)
+    b = submit(eng, prompt_of(12, 71), 12, frequency_penalty=1.5)
+    step(eng)  # B's only chunk: one row, lane 1
+    assert (a.slot, b.slot) == (0, 1) and b.prefill_pos is None
+    first = b.generated[0]
+    counts = np.asarray(eng._counts)
+    assert counts[1, first] == 1 and counts[1].sum() == 1 and counts[0].sum() == 0
+    run_out(eng)
+    assert answer(b)[0] == alone(prompt_of(12, 71), 12, frequency_penalty=1.5)[0]
+    assert answer(a)[0] == alone(prompt_of(9, 70), 30)[0]
+
+
+def test_a_finished_lanes_blocks_wait_for_the_dispatch_in_flight_when_a_chunk_goes_next(eng):
+    """A reaches its length while a decode dispatch that still writes its
+    blocks is in flight: they are parked. The next step admits C into the
+    lane and dispatches a chunk first; the blocks stay parked (and out of C's
+    allocation) until the decode dispatch has been fetched."""
+    a = submit(eng, prompt_of(9, 80), 1 + ENGINE_CFG.decode_steps)
+    keep = submit(eng, prompt_of(11, 82), 40)  # keeps the pipeline going
+    step(eng)  # both prefill: the first token each
+    step(eng)  # decode dispatch 1
+    step(eng)  # decode dispatch 2, then 1 processed: A is done, parked
+    assert a.slot is None and len(eng._zombie_allocs) == 1
+    parked = set(eng._zombie_allocs[0].block_ids)
+    assert eng._inflight is not None and eng._inflight.lanes[0] is a
+    c = submit(eng, prompt_of(20, 83), 3)
+    seen = {}
+    dispatch = eng._decode_dispatch
+
+    def watched(profile):
+        # the chunk has been dispatched by now, the decode dispatch not yet drained
+        seen["parked"] = [set(z.block_ids) for z in eng._zombie_allocs]
+        seen["chunks"] = sum(eng.chunk_dispatches_by_rows.values())
+        return dispatch(profile)
+
+    eng._decode_dispatch = watched
+    chunks = sum(eng.chunk_dispatches_by_rows.values())
+    try:
+        step(eng)
+    finally:
+        del eng._decode_dispatch
+    assert seen == {"parked": [parked], "chunks": chunks + 1}
+    assert c.slot == 0 and not parked & set(c.alloc.block_ids)
+    assert not eng._zombie_allocs  # freed once the dispatch was fetched
+    run_out(eng)
+    assert len(answer(keep)[0]) == 40
+
+
+# -- the counters and the programs ---------------------------------------------
+
+
+def test_the_counters_say_how_full_the_chunk_dispatches_are(params):
+    eng = JaxServingEngine(CFG, params, ENGINE_CFG)
+    try:
+        snap = eng.metrics_snapshot()
+        assert [snap[k] for k in (
+            "chunk_positions_dispatched", "chunk_tokens_fed", "chunk_rows_dispatched",
+            "chunk_rows_live", "chunk_dispatches_by_rows")] == [0, 0, 0, 0, {}]
+        a = submit(eng, prompt_of(9, 0), 30)
+        step(eng)  # one row of 16 positions, 9 tokens
+        submit(eng, prompt_of(40, 1), 2)  # 16 + 16 + 8, one row each, beside A
+        for _ in range(3):
+            step(eng)
+        submit(eng, prompt_of(5, 2), 2)
+        submit(eng, prompt_of(20, 3), 2)  # two rows, then one
+        run_out(eng)
+        snap = eng.metrics_snapshot()
+        assert snap["chunk_dispatches_by_rows"] == {"1": 5, "2": 1}
+        assert snap["chunk_rows_dispatched"] == 7 and snap["chunk_rows_live"] == 7
+        assert snap["chunk_positions_dispatched"] == 7 * CHUNK
+        assert snap["chunk_tokens_fed"] == 9 + 40 + 5 + 20
+        assert len(answer(a)[0]) == 30
+    finally:
+        eng.close()
+
+
+def test_warmup_compiles_every_rung_and_serving_compiles_nothing_more(params):
+    eng = JaxServingEngine(CFG, params, ENGINE_CFG)
+    try:
+        eng.warmup("greedy")
+        rows = sorted(k[4] for k in eng._chunk_fns)
+        # both history variants at max_slots, the history-bearing one under it
+        assert rows == [1, 2, 8, 8]
+        assert all(k[3] for k in eng._chunk_fns if k[4] < 8)
+        compiled = compile_count()
+        serve_schedule(eng, WAVE)
+        serve_schedule(eng, MIXED)
+        assert compile_count() == compiled
+        assert set(eng.chunk_dispatches_by_rows) == {1, 2, 8}
+    finally:
+        eng.close()
+
+
+def test_seal_time_checksums_come_from_the_pages_taken_as_the_program_was_dispatched(params):
+    """The blocks a chunk or decode dispatch fills are read off the pool by a
+    program enqueued right behind it, so the seal-time checksum never waits
+    behind a later dispatch: no seal goes through the plain read, and every
+    checksum is the one the pool's bytes give."""
+    from dynamo_tpu.kv import pages as kv_pages
+
+    eng = JaxServingEngine(CFG, params, ENGINE_CFG)
+    try:
+        assert eng._seal_checksums
+        plain, extract = [], eng.extract_blocks
+        eng.extract_blocks = lambda ids, **kw: (plain.append(list(ids)), extract(ids, **kw))[1]
+        serve_schedule(eng, MIXED, salt=90)
+        assert plain == []
+        del eng.extract_blocks
+        sealed = dict(eng.allocator._crc_of)
+        assert len(sealed) >= sum(n // ENGINE_CFG.kv_block_size for _, n, _, _ in MIXED)
+        for bid, crc in sealed.items():
+            assert crc == kv_pages.checksums(eng.extract_blocks([bid]))[0], bid
+    finally:
+        eng.close()

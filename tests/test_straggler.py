@@ -916,9 +916,14 @@ class TestStragglerChaosGate:
                 prompts = [[17 + i, 23 + 2 * i, 5 + 3 * i]
                            for i in range(n_requests)]
                 controls = await _goldens(tiny, prompts, max_t)
-                # warm every engine's jit caches off the timed path
+                # warm every engine's jit caches off the timed path: the step
+                # programs by a request, and the reads of the pages a dispatch
+                # fills (engine._take_sealing: one program a block count, and
+                # two lanes that seal in one dispatch are a count of their own)
                 for i, eng in enumerate(engines):
                     await _collect(eng, [3 + i, 5, 7], 4)
+                    for n in eng._sealing_sizes:
+                        eng.extract_blocks([0] * n)
 
                 # -- phase 0: no-fault control ITL + zero false positives --
                 ctl = await asyncio.gather(*[

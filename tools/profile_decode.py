@@ -223,14 +223,14 @@ def main():
     flops = 2.0 * (pbytes / 2) * S * C  # params(count) ≈ bytes/2 for bf16
 
     for hist in (True, False):
-        cfn = engine2._chunk(False, False, False, hist)
+        cfn = engine2._chunk(False, False, False, hist, S)
         cache3 = engine2.cache
         counts3 = engine2._dummy_counts
 
         def ccall(cache, counts):
             nxt, cache, counts = cfn(
                 params, cache, counts, ptoks, ppos, tables, sample_at,
-                step_ctr, ipack, fpack,
+                jnp.arange(S, dtype=jnp.int32), step_ctr, ipack, fpack,
             )
             return nxt, cache, counts
 
