@@ -64,6 +64,9 @@ class SequenceAllocation:
     # full-prompt block hashes this sequence advertised as in-flight (it will
     # compute + seal them); unregistered on free if still unsealed
     pending_hashes: List[int] = field(default_factory=list)
+    # prompt tokens the prefix cache held and the allocation did not take
+    # (``allocate_sequence(reuse=False)``: pages alone are not the request)
+    declined_tokens: int = 0
 
 
 class InflightPrefix:
@@ -303,7 +306,7 @@ class BlockAllocator:
 
     def allocate_sequence(
         self, token_ids: Sequence[int], wait_inflight: bool = True,
-        tenant: str = "", level: int = 0,
+        tenant: str = "", level: int = 0, reuse: bool = True,
     ) -> Optional[SequenceAllocation]:
         """Allocate pages for a prompt, reusing prefix-cached blocks.
 
@@ -315,6 +318,11 @@ class BlockAllocator:
         prefixes). The last prompt token is never served from cache: its
         logits are needed to sample the first output token, so at least one
         position is computed.
+
+        ``reuse=False`` declines every hit (a model whose pages are half of a
+        request: the slot's recurrent state does not come with them), waits
+        for nobody's in-flight prefix, and says on the allocation how many
+        tokens it passed over (``declined_tokens``).
         """
         seq_hashes = compute_block_hashes_for_seq(token_ids, self.block_size, self.salt)
         self.probe_tokens += len(token_ids)
@@ -327,6 +335,10 @@ class BlockAllocator:
             if bid is None:
                 break
             reused.append(bid)
+        declined = 0
+        if not reuse:
+            declined, reused, max_cacheable = len(reused) * self.block_size, [], 0
+            wait_inflight = False
 
         # host tier continues the chain where the device tier missed; content
         # is captured now so later evictions from the pool can't invalidate
@@ -427,6 +439,7 @@ class BlockAllocator:
             pending_hashes=pending,
             tenant=tenant,
             level=level,
+            declined_tokens=declined,
         )
 
     def seed_cached(self, token_ids: Sequence[int]) -> List[Tuple[int, int]]:

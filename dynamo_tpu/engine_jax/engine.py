@@ -71,6 +71,7 @@ from dynamo_tpu.llm.protocols.common import (
     LLMEngineOutput,
     PreprocessedRequest,
 )
+from dynamo_tpu.models import module_for
 from dynamo_tpu.models.llama import (
     LlamaConfig,
     chunk_history_tiles,
@@ -263,7 +264,7 @@ class _Seq:
         "first_token_t", "admit_t", "remote", "remote_deadline", "prefill_pos",
         "freq_pen", "pres_pen", "out_tokens", "joined_inflight", "wait_hash",
         "drafter", "spec_drafted", "spec_accepted", "tenant", "level",
-        "weight", "resumed", "migrated",
+        "weight", "resumed", "migrated", "prefix_declined",
     )
 
     def __init__(self, ctx: Context, request: PreprocessedRequest, loop) -> None:
@@ -324,6 +325,7 @@ class _Seq:
         self.remote = False  # prefill dispatched to a remote prefill worker
         self.remote_deadline: Optional[float] = None
         self.joined_inflight = False  # parked behind a concurrent identical prefix
+        self.prefix_declined = 0  # cached prompt tokens passed over (a slot model)
         self.wait_hash: Optional[int] = None  # the in-flight hash it's parked on
         # next prompt position to compute while prefilling; None = decoding
         self.prefill_pos: Optional[int] = None
@@ -404,10 +406,11 @@ class _Inflight:
     """
 
     __slots__ = ("out", "lps", "top_ids", "top_lps", "tokens", "positions",
-                 "lanes", "sealing")
+                 "lanes", "sealing", "sums")
 
     def __init__(self, out, lps, top_ids, top_lps, tokens, positions, lanes,
-                 sealing=None):
+                 sealing=None, sums=None):
+        self.sums = sums  # device, a slot model's counters of this dispatch
         self.out = out  # [S, k_steps] device
         self.lps = lps  # [S, k_steps] device, chosen-token logprobs
         self.top_ids = top_ids  # [S, k_steps, P] device
@@ -486,6 +489,21 @@ class JaxServingEngine(AsyncEngine):
     ):
         self.model_config = model_config
         self.config = engine_config
+        # the model module, picked in one place (models.module_for). A module
+        # with ``make_slot_state`` keeps state per SLOT beside the pages (a
+        # recurrent layer's): the chunk and decode programs carry it, and
+        # whatever hands pages over without it is refused by name
+        # (`_refuse_for_state`)
+        self.model = module_for(model_config)
+        self._slot_model = hasattr(self.model, "make_slot_state")
+        if self._slot_model and (
+            mesh is not None or engine_config.quantize
+            or (engine_config.kv_dtype or env_kv_dtype()) == "int8"
+        ):
+            raise ValueError(
+                f"{type(model_config).__name__} runs on one device, with "
+                "bf16 weights and native pages"
+            )
         if engine_config.quantize == "int8-all":
             # int8 for BOTH phases, bf16 tree dropped: the fit mode for
             # models whose bf16 weights alone exceed the chip (llama3-8b =
@@ -539,7 +557,9 @@ class JaxServingEngine(AsyncEngine):
             engine_config.spec_k if engine_config.spec_k is not None
             else env_spec_k()
         )
-        self._spec_k = max(0, min(int(sk), MAX_SPEC_K))
+        # a drafted token that is rejected would have to be taken out of
+        # the slot's recurrent state again: such a model never drafts
+        self._spec_k = 0 if self._slot_model else max(0, min(int(sk), MAX_SPEC_K))
         self._spec_ngram = (
             engine_config.spec_ngram if engine_config.spec_ngram is not None
             else env_spec_ngram()
@@ -570,6 +590,8 @@ class JaxServingEngine(AsyncEngine):
         self._multihost = mesh is not None and jax.process_count() > 1
         self._dispatch_hook = None  # multihost leader: broadcast dispatches
         self.num_blocks = engine_config.resolve_num_blocks()
+        if engine_config.host_cache_blocks > 0:
+            self._refuse_for_state("the host tier")
         self.host_pool = (
             HostKvPool(engine_config.host_cache_blocks)
             if engine_config.host_cache_blocks > 0
@@ -609,11 +631,6 @@ class JaxServingEngine(AsyncEngine):
         # to jnp. The pool is created ON-device via out_shardings (zeros
         # never round-trip the host, and on a multi-process mesh each host
         # materializes only its shards — device_put cannot span processes).
-        cshape = (
-            model_config.num_layers, self.num_blocks,
-            engine_config.kv_block_size, model_config.num_kv_heads,
-            model_config.head_dim,
-        )
         cdtype = cache_dtype or model_config.dtype
         # compute dtype of attention inputs: int8 pages dequantize into this
         # (and the decode window buffers are allocated in it — never in the
@@ -623,16 +640,34 @@ class JaxServingEngine(AsyncEngine):
             from dynamo_tpu.parallel.mesh import kv_cache_sharding
 
             sh = kv_cache_sharding(mesh)
+            cshape = (
+                model_config.num_layers, self.num_blocks,
+                engine_config.kv_block_size, model_config.num_kv_heads,
+                model_config.head_dim,
+            )
             make = jax.jit(
                 lambda: {"k": jnp.zeros(cshape, cdtype), "v": jnp.zeros(cshape, cdtype)},
                 out_shardings={"k": sh, "v": sh},
             )
             self.cache = make()
         else:
-            self.cache = make_kv_cache(
+            # the caller's dtype or the module's own (llama: the model's)
+            self.cache = self.model.make_kv_cache(
                 model_config, self.num_blocks, engine_config.kv_block_size,
-                dtype=cdtype, quantized=self._kv_quantized,
+                dtype=cache_dtype, quantized=self._kv_quantized,
             )
+        # the slots' state, one value the model module owns: the step
+        # programs take it and hand it back, nothing here looks inside
+        self.slot_state = (
+            self.model.make_slot_state(model_config, engine_config.max_slots)
+            if self._slot_model else None
+        )
+        # sums the slot model's programs return (its module's COUNTERS),
+        # added up by the host as their dispatches are fetched
+        self.model_counters: Dict[str, int] = {
+            name: 0 for name in getattr(self.model, "COUNTERS", ())
+        }
+        self.prefix_hits_declined = 0
 
         S = engine_config.max_slots
         MB = engine_config.max_blocks_per_seq
@@ -855,15 +890,20 @@ class JaxServingEngine(AsyncEngine):
 
         mc, ec = model_config, engine_config
         dtype_size = jnp.dtype(cache_dtype or mc.dtype).itemsize
-        hist_bytes = (
-            2 * mc.num_layers * ec.max_slots * ec.max_blocks_per_seq
-            * ec.kv_block_size * mc.num_kv_heads * mc.head_dim * dtype_size
-        )
-        self._decode_dense = not decode_uses_pallas(
-            mc.head_dim, mesh, mc.num_heads, mc.num_kv_heads,
-            dense_history_bytes=hist_bytes,
-            dense_history_budget=ec.dense_history_max_bytes,
-        )
+        if self._slot_model:
+            # its module gathers the paged members to a dense buffer once a
+            # dispatch: the jnp tier, and no kernel
+            self._decode_dense = True
+        else:
+            hist_bytes = (
+                2 * mc.num_layers * ec.max_slots * ec.max_blocks_per_seq
+                * ec.kv_block_size * mc.num_kv_heads * mc.head_dim * dtype_size
+            )
+            self._decode_dense = not decode_uses_pallas(
+                mc.head_dim, mesh, mc.num_heads, mc.num_kv_heads,
+                dense_history_bytes=hist_bytes,
+                dense_history_budget=ec.dense_history_max_bytes,
+            )
         if self._kv_quantized:
             # the Pallas kernel has no fused dequant: int8 pools pin the
             # dense decode-history tier (gather_history dequantizes). The
@@ -1031,8 +1071,10 @@ class JaxServingEngine(AsyncEngine):
             )
             return sel, bad
 
-        def decode(params, cache, counts, tokens, positions, tables, step_ctr,
-                   ipack, fpack, wdf=None):
+        def sampler(step_ctr, ipack, fpack, wdf):
+            """One step's sampling for this dispatch: ``(logits [S, V],
+            positions, counts, k) -> (next tokens, next positions, counts,
+            what the step hands the host)``, the same in every body below."""
             # ipack [2,S] int32 = (seeds, topk); fpack [4,S] f32 =
             # (temp, topp, freqp, presp). Packed so a dispatch uploads at
             # most two small host arrays (each upload is a separate
@@ -1042,6 +1084,68 @@ class JaxServingEngine(AsyncEngine):
             step_key = jax.random.fold_in(jax.random.PRNGKey(0), step_ctr)
             seeds, topk = ipack[0], ipack[1]
             temp, topp, freqp, presp = fpack[0], fpack[1], fpack[2], fpack[3]
+
+            def sample_step(sel, pos, counts, k):
+                if wd:
+                    sel, bad = _wd_bad(sel, wdf)
+                if with_sample:
+                    kk = jax.random.fold_in(step_key, k)
+                    keys = jax.vmap(lambda s: jax.random.fold_in(kk, s))(seeds)
+                else:
+                    keys = None  # unused by the greedy-only sampler
+                sampled_from = (
+                    apply_penalties(sel, counts, freqp, presp)
+                    if with_pen else sel
+                )
+                nxt = sample_tokens(sampled_from, keys, temp, topk, topp,
+                                    greedy_only=not with_sample)
+                if wd:
+                    nxt = jnp.where(
+                        bad & (pos >= 0), jnp.int32(WATCHDOG_TOKEN), nxt
+                    )
+                if with_pen:
+                    counts = update_counts(counts, nxt, pos >= 0)
+                new_pos = jnp.where((pos >= 0) & (pos < max_pos), pos + 1, -1)
+                if with_lp:
+                    lp, tids, tlps = token_logprobs(sel, nxt, n_top)
+                    return nxt, new_pos, counts, (nxt, lp, tids, tlps)
+                return nxt, new_pos, counts, nxt
+
+            return sample_step
+
+        def slot_major(out):
+            """The scan's stacked outputs [k_steps, S, ...] as the host reads
+            them, slot-major."""
+            if with_lp:
+                out, lps, tids, tlps = out
+                return (out.T, lps.T, tids.transpose(1, 0, 2),
+                        tlps.transpose(1, 0, 2))
+            return (out.T,)
+
+        if self._slot_model:
+            def decode(params, cache, state, counts, tokens, positions,
+                       tables, step_ctr, ipack, fpack, wdf=None):
+                # a model whose layers keep state per slot beside the pages
+                # (models.module_for): its module scans the steps itself, over
+                # the slots' state, and hands back the sums its layers count
+                sample_step = sampler(step_ctr, ipack, fpack, wdf)
+
+                def sample(sel, pos, counts, k):
+                    nxt, _, counts, out = sample_step(sel, pos, counts, k)
+                    return nxt, counts, out
+
+                toks, pos, counts, out, cache, state, sums = self.model.decode(
+                    params, cfg, tokens, positions, cache, tables, state,
+                    k_steps, max_pos, sample, counts,
+                )
+                return (*slot_major(out), toks, pos, sums, cache, state, counts)
+
+            # the trace names a program after its function: jit_decode
+            return jax.jit(decode, donate_argnums=(1, 2, 3))
+
+        def decode(params, cache, counts, tokens, positions, tables, step_ctr,
+                   ipack, fpack, wdf=None):
+            sample_step = sampler(step_ctr, ipack, fpack, wdf)
             # tokens/positions: [S]; tables: [S, MB]. Scans k_steps forward+
             # sample iterations, feeding each sampled token back in — one
             # dispatch yields [S, k_steps] tokens. The final carry (tokens,
@@ -1079,44 +1183,16 @@ class JaxServingEngine(AsyncEngine):
                         params, cfg, toks[:, None], pos[:, None], cache,
                         tables, self.mesh,
                     )
-                    if with_sample:
-                        kk = jax.random.fold_in(step_key, k)
-                        keys = jax.vmap(lambda s: jax.random.fold_in(kk, s))(seeds)
-                    else:
-                        keys = None
-                    sel = logits[:, 0]
-                    if wd:
-                        sel, bad = _wd_bad(sel, wdf)
-                    sampled_from = (
-                        apply_penalties(sel, counts, freqp, presp)
-                        if with_pen else sel
+                    nxt, new_pos, counts, out = sample_step(
+                        logits[:, 0], pos, counts, k
                     )
-                    nxt = sample_tokens(sampled_from, keys, temp, topk, topp,
-                                        greedy_only=not with_sample)
-                    if wd:
-                        nxt = jnp.where(
-                            bad & (pos >= 0),
-                            jnp.int32(WATCHDOG_TOKEN), nxt,
-                        )
-                    if with_pen:
-                        counts = update_counts(counts, nxt, pos >= 0)
-                    new_pos = jnp.where((pos >= 0) & (pos < max_pos), pos + 1, -1)
-                    if with_lp:
-                        lp, tids, tlps = token_logprobs(sel, nxt, n_top)
-                        return (nxt, new_pos, cache, counts), (nxt, lp, tids, tlps)
-                    return (nxt, new_pos, cache, counts), nxt
+                    return (nxt, new_pos, cache, counts), out
 
                 (toks, pos, cache, counts), out = jax.lax.scan(
                     body_pp, (tokens, positions, cache, counts),
                     jnp.arange(k_steps),
                 )
-                if with_lp:
-                    out, lps, tids, tlps = out
-                    return (
-                        out.T, lps.T, tids.transpose(1, 0, 2),
-                        tlps.transpose(1, 0, 2), toks, pos, cache, counts,
-                    )
-                return out.T, toks, pos, cache, counts
+                return (*slot_major(out), toks, pos, cache, counts)
 
             base = positions
             wshape = (
@@ -1136,30 +1212,8 @@ class JaxServingEngine(AsyncEngine):
                     sel, wk, wv = forward_window(
                         params, cfg, toks, pos, history, base, wk, wv, k,
                     )
-                    if wd:
-                        sel, bad = _wd_bad(sel, wdf)
-                    if with_sample:
-                        kk = jax.random.fold_in(step_key, k)
-                        keys = jax.vmap(lambda s: jax.random.fold_in(kk, s))(seeds)
-                    else:
-                        keys = None  # unused by the greedy-only sampler
-                    sampled_from = (
-                        apply_penalties(sel, counts, freqp, presp)
-                        if with_pen else sel
-                    )
-                    nxt = sample_tokens(sampled_from, keys, temp, topk, topp,
-                                        greedy_only=not with_sample)
-                    if wd:
-                        nxt = jnp.where(
-                            bad & (pos >= 0), jnp.int32(WATCHDOG_TOKEN), nxt
-                        )
-                    if with_pen:
-                        counts = update_counts(counts, nxt, pos >= 0)
-                    new_pos = jnp.where((pos >= 0) & (pos < max_pos), pos + 1, -1)
-                    if with_lp:
-                        lp, tids, tlps = token_logprobs(sel, nxt, n_top)
-                        return (nxt, new_pos, counts, wk, wv), (nxt, lp, tids, tlps)
-                    return (nxt, new_pos, counts, wk, wv), nxt
+                    nxt, new_pos, counts, out = sample_step(sel, pos, counts, k)
+                    return (nxt, new_pos, counts, wk, wv), out
 
                 return jax.lax.scan(
                     body, (tokens, positions, counts, wk0, wv0),
@@ -1185,14 +1239,7 @@ class JaxServingEngine(AsyncEngine):
                 )
             (toks, pos, counts, wk, wv), out = done
             cache = flush_window(cache, tables, base, wk, wv, max_pos)
-            # outputs are scan-stacked [k_steps, S, ...] → slot-major
-            if with_lp:
-                out, lps, tids, tlps = out
-                return (
-                    out.T, lps.T, tids.transpose(1, 0, 2),
-                    tlps.transpose(1, 0, 2), toks, pos, cache, counts,
-                )
-            return out.T, toks, pos, cache, counts
+            return (*slot_major(out), toks, pos, cache, counts)
 
         if self._multihost:
             # leader must device_get sampled tokens/carries: pin every output
@@ -1234,8 +1281,8 @@ class JaxServingEngine(AsyncEngine):
         """The chunk variant at ``rows`` rows (a rung of ``_chunk_rungs``;
         default ``max_slots``). One jitted function serves every row count:
         the key keeps the programs apart that ``warmup`` compiled ahead."""
-        if self._pp > 1 or self._sp > 1:
-            want_history = True  # pp/sp forwards have no history-free variant
+        if self._pp > 1 or self._sp > 1 or self._slot_model:
+            want_history = True  # these forwards have no history-free variant
         rows = self.config.max_slots if rows is None else rows
         key = (want_lp, want_pen, want_sample, want_history, rows)
         fn = self._chunk_fns.get(key)
@@ -1257,44 +1304,20 @@ class JaxServingEngine(AsyncEngine):
         wd = self._watchdog
         wd_limit = self._integrity.logit_limit if wd else 0.0
 
-        def chunk(params, cache, counts, tokens, positions, tables, sample_at,
-                  lanes, step_ctr, ipack, fpack, wdf=None):
+        def sampling_inputs(step_ctr, ipack, fpack):
+            """Unpacked at the head of the program, where the parent's text
+            has them: the programs of `models/llama.py` stay what they were,
+            to the character (and the compile cache's entries with them)."""
             step_key = jax.random.fold_in(jax.random.PRNGKey(0), step_ctr)
-            seeds, topk = ipack[0], ipack[1]
-            temp, topp, freqp, presp = fpack[0], fpack[1], fpack[2], fpack[3]
-            # tokens/positions: [R, C] (−1 positions = padding), one row per
-            # prefilling lane, packed to the front; sample_at: [R] index of
-            # the token whose logits to sample, −1 → output unused; lanes:
-            # [R] the slot of each row (max_slots = a padding row), which is
-            # its row of the [S, V] penalty counts. R is the inputs' own.
-            # The LM head runs on the gathered [R, E] sample positions only —
-            # never on the full [R, C, E] chunk (at C=128 that head matmul and
-            # its [R, C, vocab] float32 logits dwarf the useful work and sat
-            # directly on the TTFT critical path).
-            if self._pp > 1:
-                from dynamo_tpu.parallel.pipeline import pipeline_forward
+            return (step_key, ipack[0], ipack[1],
+                    fpack[0], fpack[1], fpack[2], fpack[3])
 
-                h, cache = pipeline_forward(
-                    params, cfg, tokens, positions, cache, tables, self.mesh,
-                    hidden_only=True,
-                )
-            elif self._sp > 1:
-                from dynamo_tpu.models.llama import forward_chunk_sp
-
-                h, cache = forward_chunk_sp(
-                    params, cfg, tokens, positions, cache, tables, self.mesh,
-                    hidden_only=True,
-                )
-            else:
-                # history/fresh split (models/llama.py forward_chunk): the
-                # layer loop only reads the pool; the layers' fresh K/V are
-                # written after it by one in-place scatter per pool array
-                h, cache = forward_chunk(
-                    params, cfg, tokens, positions, cache, tables,
-                    hidden_only=True, with_history=with_history,
-                )
-            hs = h[jnp.arange(tokens.shape[0]), jnp.clip(sample_at, 0)]  # [R, E]
-            sel = lm_head(params, cfg, hs)  # [R, V]
+        def sample_rows(params, h, counts, sample_at, lanes, inputs, wdf):
+            """The rows' sampled tokens off the chunk's hidden states: (what
+            the host fetches, the penalty counts)."""
+            step_key, seeds, topk, temp, topp, freqp, presp = inputs
+            hs = h[jnp.arange(h.shape[0]), jnp.clip(sample_at, 0)]  # [R, E]
+            sel = self.model.lm_head(params, cfg, hs)  # [R, V]
             if wd:
                 # output watchdog: poison-drill substitution + per-lane
                 # non-finite/exploding flag → WATCHDOG_TOKEN sentinel
@@ -1321,9 +1344,66 @@ class JaxServingEngine(AsyncEngine):
             if with_pen:
                 counts = update_counts(counts, nxt, sample_at >= 0, rows=lanes)
             if with_lp:
-                lp, tids, tlps = token_logprobs(sel, nxt, n_top)
-                return nxt, lp, tids, tlps, cache, counts
-            return nxt, cache, counts
+                return (nxt, *token_logprobs(sel, nxt, n_top)), counts
+            return (nxt,), counts
+
+        if self._slot_model:
+            def chunk(params, cache, state, counts, tokens, positions,
+                      tables, sample_at, lanes, step_ctr, ipack, fpack,
+                      wdf=None):
+                # a model whose layers keep state per slot beside the pages
+                # (models.module_for): a row starts from its slot's state and
+                # leaves it behind; the sums its layers count go to the host
+                inputs = sampling_inputs(step_ctr, ipack, fpack)
+                h, cache, state, sums = self.model.forward_chunk(
+                    params, cfg, tokens, positions, cache, tables, state, lanes,
+                )
+                fetch, counts = sample_rows(
+                    params, h, counts, sample_at, lanes, inputs, wdf
+                )
+                return (*fetch, sums, cache, state, counts)
+
+            # the trace names a program after its function: jit_chunk
+            return jax.jit(chunk, donate_argnums=(1, 2, 3))
+
+        def chunk(params, cache, counts, tokens, positions, tables, sample_at,
+                  lanes, step_ctr, ipack, fpack, wdf=None):
+            # tokens/positions: [R, C] (−1 positions = padding), one row per
+            # prefilling lane, packed to the front; sample_at: [R] index of
+            # the token whose logits to sample, −1 → output unused; lanes:
+            # [R] the slot of each row (max_slots = a padding row), which is
+            # its row of the [S, V] penalty counts. R is the inputs' own.
+            # The LM head runs on the gathered [R, E] sample positions only —
+            # never on the full [R, C, E] chunk (at C=128 that head matmul and
+            # its [R, C, vocab] float32 logits dwarf the useful work and sat
+            # directly on the TTFT critical path).
+            inputs = sampling_inputs(step_ctr, ipack, fpack)
+            if self._pp > 1:
+                from dynamo_tpu.parallel.pipeline import pipeline_forward
+
+                h, cache = pipeline_forward(
+                    params, cfg, tokens, positions, cache, tables, self.mesh,
+                    hidden_only=True,
+                )
+            elif self._sp > 1:
+                from dynamo_tpu.models.llama import forward_chunk_sp
+
+                h, cache = forward_chunk_sp(
+                    params, cfg, tokens, positions, cache, tables, self.mesh,
+                    hidden_only=True,
+                )
+            else:
+                # history/fresh split (models/llama.py forward_chunk): the
+                # layer loop only reads the pool; the layers' fresh K/V are
+                # written after it by one in-place scatter per pool array
+                h, cache = forward_chunk(
+                    params, cfg, tokens, positions, cache, tables,
+                    hidden_only=True, with_history=with_history,
+                )
+            fetch, counts = sample_rows(
+                params, h, counts, sample_at, lanes, inputs, wdf
+            )
+            return (*fetch, cache, counts)
 
         if self._multihost:
             rep, cache_sh = self._io_shardings()
@@ -1411,6 +1491,38 @@ class JaxServingEngine(AsyncEngine):
         return jax.jit(verify, donate_argnums=(1, 2))
 
     # -- penalty-count buffer -------------------------------------------------
+
+    def _state_args(self, params) -> tuple:
+        """The leading arguments of a step program: the weights, the pool,
+        and for a slot model the slots' state behind it."""
+        if self._slot_model:
+            return (params, self.cache, self.slot_state)
+        return (params, self.cache)
+
+    def _take_state(self, result: tuple) -> tuple:
+        """Take the pool (and a slot model's state) a step program handed
+        back, in front of the penalty counts at the end of ``result``; what
+        is left is what the host fetches, and the counts."""
+        if self._slot_model:
+            *rest, self.cache, self.slot_state, counts = result
+        else:
+            *rest, self.cache, counts = result
+        return (*rest, counts)
+
+    def _add_model_counters(self, sums) -> None:
+        for name, n in zip(self.model.COUNTERS, sums):
+            self.model_counters[name] += int(n)
+
+    def _refuse_for_state(self, what: str) -> None:
+        """A slot model's pages are half of a request: the slots' recurrent
+        state does not travel with them (snapshots at block boundaries are
+        ROADMAP M5), so whatever would hand pages over without it is refused
+        by name instead of served wrongly."""
+        if self._slot_model:
+            raise kv_pages.StateNotPortable(
+                f"{what}: {type(self.model_config).__name__} keeps state per "
+                "slot beside its pages, and that state does not follow pages yet"
+            )
 
     def _slow_fault(self) -> None:
         """The ``slow`` fault action at the engine dispatch point
@@ -1594,7 +1706,9 @@ class JaxServingEngine(AsyncEngine):
         # (rows, want_sample, want_history) of every chunk program to compile
         chunk_set = [
             (S, want_sample, want_history)
-            for want_sample in sample_set for want_history in (False, True)
+            for want_sample in sample_set
+            # a slot model's chunk program has one form (`_chunk`)
+            for want_history in ((True,) if self._slot_model else (False, True))
         ] + [(rows, False, True) for rows in self._chunk_rungs if rows < S]
 
         def chunk_name(rows, want_sample, want_history):
@@ -1669,6 +1783,10 @@ class JaxServingEngine(AsyncEngine):
             lambda a: sd(a.shape, a.dtype), self.params_decode
         )
         cache_sd = jax.tree.map(lambda a: sd(a.shape, a.dtype), self.cache)
+        # the pool, and behind it a slot model's state
+        pool_sd = (cache_sd,) + ((jax.tree.map(
+            lambda a: sd(a.shape, a.dtype), self.slot_state
+        ),) if self._slot_model else ())
         counts_sd = jax.tree.map(
             lambda a: sd(a.shape, a.dtype), self._dummy_counts
         )
@@ -1686,7 +1804,7 @@ class JaxServingEngine(AsyncEngine):
             jobs.append((
                 chunk_name(rows, want_sample, want_history),
                 self._chunk(False, False, want_sample, want_history, rows),
-                (p_sd, cache_sd, counts_sd, sd((rows, C), jnp.int32),
+                (p_sd, *pool_sd, counts_sd, sd((rows, C), jnp.int32),
                  sd((rows, C), jnp.int32), sd((rows, MB), jnp.int32), rvec,
                  rvec, ctr, sd((2, rows), jnp.int32),
                  sd((4, rows), jnp.float32)) + wd_tail,
@@ -1696,7 +1814,7 @@ class JaxServingEngine(AsyncEngine):
             jobs.append((
                 f"decode(sample={want_sample})",
                 self._decode(False, False, want_sample),
-                (pd_sd, cache_sd, counts_sd, svec, svec, tbl, ctr, ip, fp)
+                (pd_sd, *pool_sd, counts_sd, svec, svec, tbl, ctr, ip, fp)
                 + wd_tail,
                 ("decode", False, False, want_sample),
             ))
@@ -2244,15 +2362,19 @@ class JaxServingEngine(AsyncEngine):
         """allocate_sequence with the allocator time accrued into the next
         dispatch record (profiling armed) — the bare call otherwise."""
         tl = self._timeline
-        if tl is None:
-            return self.allocator.allocate_sequence(
-                seq.prompt, tenant=seq.tenant, level=seq.level
-            )
-        t = time.perf_counter()
+        t = time.perf_counter() if tl is not None else 0.0
+        # a slot model takes no prefix hit: the pages would come without the
+        # slot's state, so it prefills from position 0 (`_refuse_for_state`)
         alloc = self.allocator.allocate_sequence(
-            seq.prompt, tenant=seq.tenant, level=seq.level
+            seq.prompt, tenant=seq.tenant, level=seq.level,
+            reuse=not self._slot_model,
         )
-        self._prof_alloc_us += (time.perf_counter() - t) * 1e6
+        if tl is not None:
+            self._prof_alloc_us += (time.perf_counter() - t) * 1e6
+        # None (no room) and an InflightPrefix (wait for it) decline nothing
+        if getattr(alloc, "declined_tokens", 0):
+            self.prefix_hits_declined += 1
+            seq.prefix_declined = alloc.declined_tokens
         return alloc
 
     def _seal_timed(self, alloc, toks) -> None:
@@ -2518,10 +2640,13 @@ class JaxServingEngine(AsyncEngine):
         )
         if want_history and self._pp == 1 and self._sp == 1:
             bs = cfg.kv_block_size
-            self.chunk_history_tiles_read += int(
+            full = history_tiles_full(bs, MB)
+            # a slot model's chunk attends every row's whole table, whatever
+            # it holds (models/kimi_linear.py: mla_attend)
+            self.chunk_history_tiles_read += full if self._slot_model else int(
                 chunk_history_tiles(positions, bs, MB)
             )
-            self.chunk_history_tiles_full += history_tiles_full(bs, MB)
+            self.chunk_history_tiles_full += full
         if want_pen:
             self._sync_counts(list(self._slots))
         counts_in = self._counts if want_pen else self._dummy_counts
@@ -2535,8 +2660,8 @@ class JaxServingEngine(AsyncEngine):
                      sample_at=sample_at, lanes=lanes, ipack=ipack_np,
                      fpack=fpack_np),
             )
-        args = (
-            self.params, self.cache, counts_in, self._put(tokens),
+        args = self._state_args(self.params) + (
+            counts_in, self._put(tokens),
             self._put(positions), self._put(tables), self._put(sample_at),
             self._put(lanes), self._put(np.int32(self._step_counter)),
             self._put(ipack_np), self._put(fpack_np),
@@ -2544,9 +2669,9 @@ class JaxServingEngine(AsyncEngine):
         self._slow_fault()
         prof = tl is not None and tl.should_sample()
         t_disp = time.perf_counter() if prof else 0.0
-        *fetch, self.cache, counts_out = self._chunk(
+        *fetch, counts_out = self._take_state(self._chunk(
             want_lp, want_pen, want_sample, want_history, rows
-        )(*args)
+        )(*args))
         # copy_to_host_async right after dispatch: started here, the
         # device→host copy overlaps the chunk's own compute instead of
         # starting cold at get time (the saving is not measured on the
@@ -2573,6 +2698,9 @@ class JaxServingEngine(AsyncEngine):
         # overlapped by copy_to_host_async at dispatch)
         fetched = jax.device_get(chunk.fetch)
         t_fetch = time.perf_counter() if chunk.t_disp else 0.0
+        if self._slot_model:
+            *fetched, sums = fetched
+            self._add_model_counters(sums)
         sampled_np = fetched[0]
         lp_np, tids_np, tlps_np = fetched[1:] if len(fetched) > 1 else (None,) * 3
         self._sealing = chunk.sealing
@@ -2799,7 +2927,8 @@ class JaxServingEngine(AsyncEngine):
             bs, MB = cfg.kv_block_size, cfg.max_blocks_per_seq
             full = S * history_tiles_full(bs, MB)
             self.decode_history_tiles_full += full
-            self.decode_history_tiles_read += full if self._rides else int(
+            # a mesh engine and a slot model gather every table's full width
+            self.decode_history_tiles_read += full if self._rides or self._slot_model else int(
                 decode_history_tiles(
                     np.where(self._positions < 0, -1, self._positions + ahead),
                     bs, MB,
@@ -2828,8 +2957,8 @@ class JaxServingEngine(AsyncEngine):
                 dict(tokens=self._last_tokens, positions=self._positions,
                      tables=self._tables, ipack=ipack_np, fpack=fpack_np),
             )
-        args = (
-            self.params_decode, self.cache, counts_in, toks_in, pos_in,
+        args = self._state_args(self.params_decode) + (
+            counts_in, toks_in, pos_in,
             self._m_tables.get(self._tables),
             self._put(np.int32(self._step_counter)),
             self._m_ipack.get(ipack_np),
@@ -2839,15 +2968,12 @@ class JaxServingEngine(AsyncEngine):
         prof = profile and tl is not None and tl.should_sample()
         t_disp = time.perf_counter() if prof else 0.0
         t_fetch = 0.0
-        if want_lp:
-            out, lps, tids, tlps, toks2, pos2, self.cache, counts_out = (
-                self._decode(True, want_pen, want_sample)(*args)
-            )
-        else:
-            out, toks2, pos2, self.cache, counts_out = self._decode(
-                False, want_pen, want_sample
-            )(*args)
-            lps = tids = tlps = None
+        *done, counts_out = self._take_state(
+            self._decode(want_lp, want_pen, want_sample)(*args)
+        )
+        sums = done.pop() if self._slot_model else None
+        out, *lp_out, toks2, pos2 = done
+        lps, tids, tlps = lp_out if want_lp else (None, None, None)
         if prof:
             # the profiling contract: block-until-ready device time for the
             # SAMPLED dispatch (serializes this one dispatch of the
@@ -2863,7 +2989,7 @@ class JaxServingEngine(AsyncEngine):
         prev, self._inflight = (
             self._inflight,
             _Inflight(out, lps, tids, tlps, toks2, pos2, live,
-                      self._take_sealing(filled)),
+                      self._take_sealing(filled), sums),
         )
         # start the host copies now: by the time this chunk is processed (one
         # pipelined dispatch later) the fetch has ridden the previous chunk's
@@ -2977,6 +3103,9 @@ class JaxServingEngine(AsyncEngine):
             out = jax.device_get(chunk.out)
             lps = tids = tlps = None
         out = np.asarray(out)  # [S, k_steps]
+        if chunk.sums is not None:
+            # dynlint: allow-host-sync(pipelined fetch: the dispatch is done, `out` came with it)
+            self._add_model_counters(jax.device_get(chunk.sums))
         self._sealing = chunk.sealing
         for i, seq in enumerate(chunk.lanes):
             if seq is None or seq.slot != i:
@@ -3323,7 +3452,13 @@ class JaxServingEngine(AsyncEngine):
             tracing.record_span(
                 "engine.prefill", prefill_start, first, parent=parent,
                 phase="prefill",
-                attributes={"remote": True} if seq.remote else None,
+                attributes={
+                    **({"remote": True} if seq.remote else {}),
+                    # a slot model passed these cached tokens over: their
+                    # pages would have come without the slot's state
+                    **({"prefix_hit_declined_for_state": seq.prefix_declined}
+                       if seq.prefix_declined else {}),
+                } or None,
             )
             decode_attrs: Dict[str, Any] = {"tokens": seq.emitted}
             if seq.spec_drafted:
@@ -3434,6 +3569,7 @@ class JaxServingEngine(AsyncEngine):
         """policy must provide should_remote(uncached_len)->bool and
         submit(request_id, token_ids, block_ids, cached_tokens, sampling)
         (called from the engine thread; submit must be thread-safe)."""
+        self._refuse_for_state("disaggregated prefill")
         self._remote_policy = policy
 
     def extract_blocks(
@@ -3445,6 +3581,7 @@ class JaxServingEngine(AsyncEngine):
         arrays with ``as_device`` (same-host transfers keep pages on-device
         and let XLA reshard at the destination's inject boundary).
         MUST run on the engine thread (e.g. via post())."""
+        self._refuse_for_state("a transfer of pages out of the pool")
         taken = kv_pages.take(self.cache, block_ids)
         if as_device:
             return taken
@@ -3480,7 +3617,7 @@ class JaxServingEngine(AsyncEngine):
             return kv_pages.checksums(kv_pages.select(
                 ahead.host(), [ahead.where[b] for b in block_ids]
             ))
-        return kv_pages.checksums(self.extract_blocks(block_ids))
+        return kv_pages.checksums(kv_pages.to_host(kv_pages.take(self.cache, block_ids)))
 
     def _take_sealing(self, filled: List[int]) -> Optional[_SealPages]:
         """Enqueue, behind the program just dispatched, the read of the blocks
@@ -3513,6 +3650,7 @@ class JaxServingEngine(AsyncEngine):
         MUST run on the engine thread (via post())."""
         # check BEFORE touching the allocator: a mismatch must not leave
         # seeded-but-never-injected hashes in the prefix cache
+        self._refuse_for_state("a prefix seeded from another worker")
         kv_pages.check(self.cache, pages)
         pairs = self.allocator.seed_cached(token_ids)
         if not pairs:
@@ -3574,6 +3712,7 @@ class JaxServingEngine(AsyncEngine):
         checkpoint per stream. Frozen sequences stop decoding but keep
         their allocation until finish/abort/unfreeze. MUST run on the
         engine thread (via post())."""
+        self._refuse_for_state("export_migratable")
         self._drain_inflight()  # commit speculative writes; host state final
         out: List[dict] = []
         bs = self.config.kv_block_size
@@ -3736,6 +3875,7 @@ class JaxServingEngine(AsyncEngine):
         fresh position. Any rejection raises BEFORE pool state changes
         beyond a rolled-back allocation: never a torn page set. MUST run on
         the engine thread."""
+        self._refuse_for_state("stage_migration")
         toks = [int(t) for t in meta["token_ids"]]
         if len(toks) < 2:
             raise MigrationRejected("history too short to migrate")
@@ -4060,6 +4200,10 @@ class JaxServingEngine(AsyncEngine):
             # reads them all: `_rides`)
             "decode_history_tiles_read": self.decode_history_tiles_read,
             "decode_history_tiles_full": self.decode_history_tiles_full,
+            # a slot model's own sums (its module's COUNTERS; none otherwise)
+            # and the prefix hits it declined for want of the slot's state
+            **self.model_counters,
+            "prefix_hits_declined": self.prefix_hits_declined,
             # how full the chunk dispatches are (cumulative): positions
             # computed (rows x prefill_chunk) and the prompt tokens among
             # them, rows dispatched and the rows that held a prefilling
@@ -4166,10 +4310,10 @@ def build_jax_serving_engine(
 ) -> JaxServingEngine:
     """CLI/SDK entry: model + engine from a ModelDeploymentCard."""
     from dynamo_tpu.engine_jax.weights import config_from_card, load_params
-    from dynamo_tpu.models.llama import param_shardings
     from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
 
     model_config = config_from_card(card)
+    param_shardings = module_for(model_config).param_shardings
 
     mesh = None
     mesh_cfg = MeshConfig(

@@ -22,14 +22,68 @@ import jax.numpy as jnp
 import numpy as np
 
 from dynamo_tpu.llm.model_card import ModelDeploymentCard
-from dynamo_tpu.models.llama import LlamaConfig, init_params
+from dynamo_tpu.models import module_for
+from dynamo_tpu.models.kimi_linear import KimiLinearConfig
+from dynamo_tpu.models.llama import LlamaConfig
 
 logger = logging.getLogger(__name__)
 
 
-def config_from_card(card: ModelDeploymentCard, dtype: Any = jnp.bfloat16) -> LlamaConfig:
-    """Derive a LlamaConfig from the card's HF config.json contents."""
+def kimi_linear_config(mc: Dict[str, Any], dtype: Any = jnp.bfloat16) -> KimiLinearConfig:
+    """``model_type: kimi_linear``. The KDA group is read from the published
+    nested ``linear_attn_config`` where the card has it, else from its flat
+    spelling (``linear_attn_num_heads``, ``linear_attn_head_dim``,
+    ``short_conv_kernel_size``, ``kda_layers``, ``full_attn_layers``: a
+    harness that writes only scalar and list keys). ``num_experts`` is the
+    count held here; ``num_experts_published``, where given, the router's."""
+    group = mc.get("linear_attn_config") or {}
+
+    def kda(nested: str, flat: str):
+        return group[nested] if nested in group else mc[flat]
+
+    experts = int(mc["num_experts"])
+    return KimiLinearConfig(
+        vocab_size=int(mc["vocab_size"]),
+        hidden_size=int(mc["hidden_size"]),
+        intermediate_size=int(mc["intermediate_size"]),
+        num_layers=int(mc["num_hidden_layers"]),
+        num_heads=int(mc["num_attention_heads"]),
+        kv_lora_rank=int(mc["kv_lora_rank"]),
+        qk_nope_head_dim=int(mc["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(mc["qk_rope_head_dim"]),
+        v_head_dim=int(mc["v_head_dim"]),
+        kda_heads=int(kda("num_heads", "linear_attn_num_heads")),
+        kda_head_dim=int(kda("head_dim", "linear_attn_head_dim")),
+        conv_kernel=int(kda("short_conv_kernel_size", "short_conv_kernel_size")),
+        kda_layers=tuple(int(i) for i in kda("kda_layers", "kda_layers")),
+        full_attn_layers=tuple(int(i) for i in kda("full_attn_layers", "full_attn_layers")),
+        first_k_dense=int(mc.get("first_k_dense_replace", 1)),
+        moe_intermediate_size=int(mc["moe_intermediate_size"]),
+        num_experts=experts,
+        num_experts_published=int(mc.get("num_experts_published", experts)),
+        num_experts_per_tok=int(mc["num_experts_per_token"]),
+        num_shared_experts=int(mc.get("num_shared_experts", 1)),
+        routed_scaling_factor=float(mc.get("routed_scaling_factor", 1.0)),
+        moe_renormalize=bool(mc.get("moe_renormalize", True)),
+        rms_norm_eps=float(mc.get("rms_norm_eps", 1e-5)),
+        tie_embeddings=bool(mc.get("tie_word_embeddings", False)),
+        dtype=dtype,
+    )
+
+
+def config_from_card(card: ModelDeploymentCard, dtype: Any = jnp.bfloat16):
+    """Derive the model's config from the card's HF config.json contents: a
+    LlamaConfig, or by ``model_type`` another module's (models.module_for)."""
     mc = card.model_config or {}
+    if mc.get("model_type") == "kimi_linear":
+        return kimi_linear_config(mc, dtype)
+    if "num_experts" in mc and "num_local_experts" not in mc:
+        # an expert model of a family this tree has no module for: a
+        # LlamaConfig of it would be a dense impostor under its name
+        raise ValueError(
+            f"model_type {mc.get('model_type')!r} declares num_experts = "
+            f"{mc['num_experts']} and no module here runs it"
+        )
     hidden = int(mc.get("hidden_size", 4096))
     heads = int(mc.get("num_attention_heads", 32))
     return LlamaConfig(
@@ -68,7 +122,7 @@ def _hf_tensors(model_path: str) -> Optional[Dict[str, np.ndarray]]:
 
 
 def load_params(
-    card: ModelDeploymentCard, config: LlamaConfig, seed: int = 0,
+    card: ModelDeploymentCard, config, seed: int = 0,
     shardings=None,
 ):
     """Load llama weights (safetensors or GGUF) into the stacked pytree,
@@ -88,13 +142,17 @@ def load_params(
             gguf_params(read_gguf(card.gguf_path), config), shardings
         )
     tensors = _hf_tensors(card.model_path) if card.model_path else None
+    if tensors is not None and not isinstance(config, LlamaConfig):
+        raise NotImplementedError(
+            f"no checkpoint mapping for {type(config).__name__}: random weights only"
+        )
     if tensors is None:
         logger.info("no safetensors found for %s: random-initializing", card.display_name)
         # always under jit, sharded or not: every process of a deployment
         # (decode workers, prefill workers, a mesh engine and its one-chip
         # comparison) then runs the same program and holds the same values
         init = jax.jit(
-            lambda: init_params(jax.random.PRNGKey(seed), config),
+            lambda: module_for(config).init_params(jax.random.PRNGKey(seed), config),
             out_shardings=shardings,
         )
         return init()

@@ -42,6 +42,14 @@ class MigrationRejected(RuntimeError):
     path — never a torn page set (docs/resilience.md §Live migration)."""
 
 
+class StateNotPortable(MigrationRejected):
+    """The model keeps state per slot beside its pages (a recurrent layer's:
+    ``models.module_for``), and the pages would travel without it: a prefix
+    hit, a host-tier resume, a disaggregated transfer, a migration. Refused
+    by name until the state is snapshotted at block boundaries (ROADMAP M5);
+    a ``MigrationRejected``, so the planes that nack that cleanly nack this."""
+
+
 # The members today's peers and sealed checksums know, in the order they are
 # chained into a block's crc and laid into a frame.
 _NATIVE = ("k", "v")

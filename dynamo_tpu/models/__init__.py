@@ -16,7 +16,25 @@ from dynamo_tpu.models.llama import (
     param_shardings,
 )
 
+
+
+def module_for(model_config):
+    """The model module whose programs run ``model_config``: the ONE place
+    where the engine, the weight loader and the benchmark's reference child
+    pick a module. Each exposes ``init_params``, ``make_kv_cache``,
+    ``param_shardings`` and ``lm_head``; a module whose layers keep state per
+    slot beside the pages (``make_slot_state``) also ``forward_chunk`` and
+    ``decode`` in the form ``engine_jax/engine.py`` calls them with the
+    state."""
+    from dynamo_tpu.models import kimi_linear, llama
+
+    if isinstance(model_config, kimi_linear.KimiLinearConfig):
+        return kimi_linear
+    return llama
+
+
 __all__ = [
+    "module_for",
     "LlamaConfig",
     "LLAMA_PRESETS",
     "init_params",

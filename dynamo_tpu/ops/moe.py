@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -186,3 +186,117 @@ def moe_mlp_reference(params, cfg: MoeConfig, x: jax.Array) -> jax.Array:
     sel = jnp.take_along_axis(all_out, top_idx[:, :, None], axis=1)  # [N, K, E]
     out = (sel * top_p[:, :, None].astype(x.dtype)).sum(axis=1)
     return out.reshape(b, t, e)
+
+
+# -- a dropless expert layer that holds a share of the experts ----------------
+#
+# The layer above drops what overflows a fixed capacity, which no published
+# expert model does. The functions below drop nothing and are told which
+# experts they hold: the router scores ALL experts and picks ``top_k`` of
+# them, and ``dropless_experts`` computes the part of the result that the held
+# experts give (models/kimi_linear.py; the model-configs guide, section 4).
+# What the absent experts would add is left out; on several chips the partial
+# sums add up (tests/test_kimi_linear.py holds the shares to the whole).
+
+def route_sigmoid_topk(
+    x: jax.Array,  # [T, E]
+    router: jax.Array,  # [E, X] float32, X = every expert of the model
+    bias: jax.Array,  # [X] float32: the selection bias
+    top_k: int,
+    scale: float,
+    renormalize: bool = True,
+) -> Tuple[jax.Array, jax.Array]:
+    """Sigmoid scores in float32 at the highest precision (a near-tie decides
+    which expert computes); the ``top_k`` largest of score + bias are chosen,
+    and a chosen expert weighs ``scale * score / sum of the chosen scores``:
+    the bias moves the choice and never the weight. Returns (ids ``[T, k]``
+    int32, weights ``[T, k]`` float32)."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router, precision=jax.lax.Precision.HIGHEST))
+    _, ids = jax.lax.top_k(scores + bias, top_k)
+    chosen = jnp.take_along_axis(scores, ids, axis=-1)
+    if renormalize:
+        chosen = chosen / (chosen.sum(axis=-1, keepdims=True) + 1e-20)
+    return ids.astype(jnp.int32), chosen * scale
+
+
+def _dot(spec: str, x: jax.Array, w: jax.Array) -> jax.Array:
+    """``einsum`` in the weights' precision, summed in float32."""
+    return jnp.einsum(spec, x.astype(w.dtype), w, preferred_element_type=jnp.float32)
+
+
+def rows_per_round(n_tokens: int, top_k: int, num_experts_total: int) -> int:
+    """Rows an expert computes in one round of :func:`dropless_experts`: twice
+    what even routing gives it, in eights, and 16 at least, so that a second
+    round is rare (it reads every held expert's weights again)."""
+    even = n_tokens * top_k / num_experts_total
+    return max(16, 8 * math.ceil(2 * even / 8))
+
+
+def dropless_experts(
+    x: jax.Array,  # [T, E] float32
+    ids: jax.Array,  # [T, k] expert ids over ALL experts
+    weights: jax.Array,  # [T, k] float32
+    w_gate: jax.Array,  # [X, E, F] the held experts, ids first_expert ...
+    w_up: jax.Array,
+    w_down: jax.Array,  # [X, F, E]
+    *,
+    first_expert: int = 0,
+    num_experts_total: Optional[int] = None,
+    token_valid: Optional[jax.Array] = None,  # [T] bool; False = padding
+    dot: Callable[[str, jax.Array, jax.Array], jax.Array] = _dot,
+) -> Tuple[jax.Array, jax.Array]:
+    """``sum over the chosen experts held here of weight * E_e(x)`` for every
+    token, ``[T, E]``, and the counters ``[4]`` int32 (1, pairs computed here,
+    held experts with a row, pairs routed).
+
+    The (token, expert) pairs routed to a held expert are sorted by expert, so
+    that an expert's rows are a run. A round gives every held expert its next
+    ``c`` rows (:func:`rows_per_round`) as one ``[X, c, E]`` block, the three
+    products run batched over the experts, and every pair takes its row back
+    weighted (a gather: a pair's place in the block is its rank in its run).
+    Rounds repeat until the longest run is through, so no pair is dropped
+    whatever the routing; with even routing there is one. ``dot(spec, x, w)``
+    is the product of a block of rows against the experts' weights: the
+    model's own arithmetic (one whose next router reads this layer's output
+    brings a product at float32's precision, models/kimi_linear.py)."""
+    t, k = ids.shape
+    x_held = w_gate.shape[0]
+    n_pairs = t * k
+    c = rows_per_round(t, k, num_experts_total or x_held)
+    local = ids - first_expert
+    held = (local >= 0) & (local < x_held)
+    if token_valid is not None:
+        held = held & token_valid[:, None]
+    expert = jnp.where(held, local, x_held).reshape(n_pairs)  # x_held: not here
+    order = jnp.argsort(expert, stable=True)  # the pairs, an expert's together
+    counts = jnp.zeros((x_held + 1,), jnp.int32).at[expert].add(1)[:x_held]
+    start = jnp.cumsum(counts) - counts
+    rank = jnp.zeros((n_pairs,), jnp.int32).at[order].set(jnp.arange(n_pairs, dtype=jnp.int32))
+    rank = (rank - start[jnp.minimum(expert, x_held - 1)]).reshape(t, k)  # place in its run
+    token_of = (order // k).astype(jnp.int32)
+    slot = jnp.arange(c, dtype=jnp.int32)
+    expert_of_pair = jnp.minimum(local, x_held - 1).clip(0)
+
+    def one_round(carry):
+        r, acc = carry
+        at = r * c + slot  # [c] places of this round in every run
+        live = at[None, :] < counts[:, None]  # [X, c]
+        rows = token_of[jnp.clip(start[:, None] + at[None, :], 0, n_pairs - 1)]
+        xs = jnp.where(live[..., None], x[rows], 0)  # [X, c, E]
+        hidden = jax.nn.silu(dot("xce,xef->xcf", xs, w_gate)) * dot("xce,xef->xcf", xs, w_up)
+        ys = dot("xcf,xfe->xce", hidden, w_down)
+        place = rank - r * c
+        mine = held & (place >= 0) & (place < c)
+        got = ys[expert_of_pair, jnp.clip(place, 0, c - 1)]  # [T, k, E]
+        acc = acc + jnp.sum(jnp.where(mine, weights, 0.0)[..., None] * got, axis=1)
+        return r + 1, acc
+
+    longest = counts.max()
+    _, acc = jax.lax.while_loop(
+        lambda carry: carry[0] * c < longest, one_round,
+        (jnp.int32(0), jnp.zeros((t, x.shape[1]), jnp.float32)))
+    routed = (jnp.sum(token_valid) if token_valid is not None else t) * k
+    stats = jnp.stack([jnp.int32(1), counts.sum(), jnp.sum(counts > 0),
+                       jnp.asarray(routed, jnp.int32)]).astype(jnp.int32)
+    return acc.astype(x.dtype), stats

@@ -1,0 +1,339 @@
+"""Kimi-Linear on the served path, at a tiny size on the CPU in float32
+(hidden 64, 2 KDA heads of 16, 8 experts top-2 holding 4, the five-layer
+pattern KDA+dense, KDA, KDA, MLA, KDA of ``kimi-linear-48b-a3b``).
+
+The program (``models/kimi_linear.py``: chunked prefill through per-slot state
+and latent pages, then decode) is held against the benchmark's plain reference
+(``benchmark/reference_kimi_linear.py``: one sequence, token by token, no
+cache); the expert layer (``ops/moe.py``) against the share rule of the
+model-configs guide; the engine against both, and against the refusals a model
+with per-slot state owes whatever would hand its pages over without it.
+"""
+
+import re
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import reference_kimi_linear as ref
+from dynamo_tpu.engine_jax.engine import EngineConfig, JaxServingEngine
+from dynamo_tpu.engine_jax.weights import config_from_card
+from dynamo_tpu.kv.pages import MigrationRejected, StateNotPortable
+from dynamo_tpu.models import kimi_linear as kl
+from dynamo_tpu.models import llama, module_for
+from dynamo_tpu.ops import moe
+
+from .test_chunk_rows import answer, run_out, step, submit
+
+# ATOL: float32 on the CPU, at the highest matmul precision on both sides. The
+# program and the reference order their sums differently (absorbed against
+# expanded latent attention, experts' rows batched against every token through
+# every expert, a chunk's convolution against the whole sequence's): 2e-4 on
+# logits of magnitude 4 is what test_plain_reference_agrees_with_the_program_
+# at_a_tiny_width allows the dense decoder for the same reason, and a wrong
+# state, page or expert moves a logit by 1e-1 and more.
+ATOL = 2e-4
+
+SHAPE = {
+    "model_type": "kimi_linear", "hidden_size": 64, "intermediate_size": 128,
+    "num_hidden_layers": 5, "num_attention_heads": 4, "kv_lora_rank": 32,
+    "qk_nope_head_dim": 16, "qk_rope_head_dim": 8, "v_head_dim": 16,
+    "linear_attn_num_heads": 2, "linear_attn_head_dim": 16, "short_conv_kernel_size": 4,
+    "kda_layers": [1, 2, 3, 5, 6, 7], "full_attn_layers": [4, 8],
+    "first_k_dense_replace": 1, "moe_intermediate_size": 32, "num_experts": 4,
+    "num_experts_published": 8, "num_experts_per_token": 2, "num_shared_experts": 1,
+    "routed_scaling_factor": 2.446, "moe_renormalize": True, "rms_norm_eps": 1e-5,
+    "vocab_size": 96, "tie_word_embeddings": False,
+}
+ENGINE_CFG = EngineConfig(max_slots=4, kv_block_size=8, max_model_len=96,
+                          prefill_chunk=16, decode_steps=4, top_logprobs=5)
+
+
+def card(shape):
+    return types.SimpleNamespace(model_config=shape, model_path=None, gguf_path=None)
+
+
+def prompt_of(n, salt=0):
+    return [(salt * 31 + 7 * i + 3) % 95 + 1 for i in range(n)]
+
+
+@pytest.fixture(scope="module", autouse=True)
+def highest_precision():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    return config_from_card(card(SHAPE), jnp.float32)
+
+
+@pytest.fixture(scope="module")
+def params(cfg):
+    return kl.init_params(jax.random.PRNGKey(3), cfg)
+
+
+@pytest.fixture(scope="module")
+def engine(cfg, params):
+    eng = JaxServingEngine(cfg, params, ENGINE_CFG)
+    yield eng
+    eng.close()
+
+
+def test_the_five_layers_are_the_published_pattern(cfg):
+    assert kl.layer_kinds(cfg) == ("kda", "kda", "kda", "mla", "kda")
+    assert [kl.is_expert_layer(cfg, i) for i in range(5)] == [False, True, True, True, True]
+    assert module_for(cfg) is kl and module_for(llama.LLAMA_PRESETS["tiny"]) is llama
+
+
+@pytest.mark.parametrize("chunks", [(16, 16, 5), (7, 16, 14), (16, 9)],
+                         ids=["full_chunks", "a_short_first_chunk", "two_chunks"])
+def test_chunked_prefill_then_decode_agrees_with_the_plain_reference(cfg, params, chunks):
+    """(a) A prompt fed in chunks whose boundaries lie inside it, each starting
+    from the slot's KDA state and the latent pages the last one left, then
+    three decode steps off the same state, against the reference's one pass
+    over the whole sequence. A second row of the chunk is padding, and the
+    other slots stay as they were."""
+    n_prompt, n_decode = sum(chunks), 3
+    tokens = np.asarray(prompt_of(n_prompt + n_decode, salt=len(chunks)), np.int32)
+    want = np.asarray(ref.logits(params, SHAPE, jnp.asarray(tokens), jnp.arange(len(tokens))))
+    slots, c, bs, mb, slot = 4, 16, 8, 8, 2
+    cache = kl.make_kv_cache(cfg, 32, bs)
+    state = jax.tree.map(lambda a: a + 7.0, kl.make_slot_state(cfg, slots))  # stale, every slot
+    tables = np.zeros((2, mb), np.int32)
+    tables[0] = np.arange(1, 9)
+    got, at = [], 0
+    for n in chunks:
+        toks, pos = np.zeros((2, c), np.int32), np.full((2, c), -1, np.int32)
+        toks[0, :n], pos[0, :n] = tokens[at:at + n], np.arange(at, at + n)
+        h, cache, state, sums = kl.forward_chunk(
+            params, cfg, jnp.asarray(toks), jnp.asarray(pos), cache, jnp.asarray(tables),
+            state, jnp.asarray([slot, slots], jnp.int32))
+        got.append(kl.lm_head(params, cfg, h[0, :n]))
+        assert int(sums[4]) == (at == 0)  # the first chunk resets the slot, once
+        at += n
+    np.testing.assert_allclose(np.concatenate(got), want[:n_prompt], atol=ATOL)
+    assert float(state["s"][0][0].min()) == 7.0  # another slot's state is untouched
+
+    lanes_tables = np.zeros((slots, mb), np.int32)
+    lanes_tables[slot] = tables[0]
+    toks, pos = np.zeros((slots,), np.int32), np.full((slots,), -1, np.int32)
+    toks[slot], pos[slot] = tokens[n_prompt], n_prompt
+
+    def forced(logits, p, carry, k):  # teacher forcing: the sequence's own next token
+        return jnp.where(p >= 0, jnp.asarray(tokens)[jnp.clip(p + 1, 0, len(tokens) - 1)], 0), carry, logits
+
+    out = kl.decode(params, cfg, jnp.asarray(toks), jnp.asarray(pos), cache,
+                    jnp.asarray(lanes_tables), state, n_decode, 95, forced, None)
+    np.testing.assert_allclose(np.asarray(out[3])[:, slot], want[n_prompt:], atol=ATOL)
+    assert float(out[5]["s"][0][0].min()) == 7.0 and int(out[1][slot]) == n_prompt + n_decode
+
+
+def expert_weights(key, experts=8, e=16, f=8):
+    k = jax.random.split(key, 6)
+    return {"x": jax.random.normal(k[0], (37, e)),
+            "router": jax.random.normal(k[1], (e, experts)),
+            "router_bias": 0.3 * jax.random.normal(k[2], (experts,)),
+            "w_gate": jax.random.normal(k[3], (experts, e, f)) / 4,
+            "w_up": jax.random.normal(k[4], (experts, e, f)) / 4,
+            "w_down": jax.random.normal(k[5], (experts, f, e)) / 3}
+
+
+def test_the_expert_shares_add_up_to_the_uncut_reference_layer():
+    """(b) Experts 0-3 and 4-7 as the two shares of a 2-chip deployment: their
+    partial sums, with the shared expert counted once, are the reference's
+    uncut layer. The model-configs guide's one test of the cut."""
+    w = expert_weights(jax.random.PRNGKey(0))
+    shape = {"num_experts_per_token": 2, "routed_scaling_factor": 2.446}
+    shared = {"ws_gate": w["w_gate"][0], "ws_up": w["w_up"][0], "ws_down": w["w_down"][0]}
+    whole = ref.expert_layer({**w, **shared}, shape, w["x"])
+    ids, weights = moe.route_sigmoid_topk(w["x"], w["router"], w["router_bias"], 2, 2.446)
+    parts = [moe.dropless_experts(
+        w["x"], ids, weights, w["w_gate"][lo:lo + 4], w["w_up"][lo:lo + 4], w["w_down"][lo:lo + 4],
+        first_expert=lo, num_experts_total=8) for lo in (0, 4)]
+    once = ref.swiglu(w["x"], shared["ws_gate"], shared["ws_up"], shared["ws_down"])
+    np.testing.assert_allclose(parts[0][0] + parts[1][0] + once, whole, atol=1e-5)
+    # each share counted its own pairs, and together every pair routed
+    assert int(parts[0][1][1]) + int(parts[1][1][1]) == int(parts[0][1][3]) == 37 * 2
+    # ... and the reference given one share is that share
+    half = ref.expert_layer({**w, **shared, **{k: w[k][4:] for k in ("w_gate", "w_up", "w_down")}},
+                            shape, w["x"], first_expert=4)
+    np.testing.assert_allclose(parts[1][0] + once, half, atol=1e-5)
+
+
+def test_no_token_is_dropped_when_every_token_goes_to_one_expert():
+    """(c) 37 tokens all routed to expert 0 and expert 1: more rows than one
+    round gives an expert (24 here), so the layer takes a second round, and
+    every token still gets its full output."""
+    w = expert_weights(jax.random.PRNGKey(1))
+    x = w["x"]
+    ids = jnp.zeros((37, 2), jnp.int32).at[:, 1].set(1)
+    weights = jnp.full((37, 2), 0.5)
+    assert moe.rows_per_round(37, 2, 8) < 37
+    got, sums = moe.dropless_experts(x, ids, weights, w["w_gate"], w["w_up"], w["w_down"])
+    want = sum(0.5 * ref.swiglu(x, w["w_gate"][e], w["w_up"][e], w["w_down"][e]) for e in (0, 1))
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    assert sums.tolist() == [1, 74, 2, 74]
+    # a padding token computes nothing and counts nowhere
+    valid = jnp.arange(37) < 30
+    got, sums = moe.dropless_experts(x, ids, weights, w["w_gate"], w["w_up"], w["w_down"],
+                                     token_valid=valid)
+    np.testing.assert_allclose(got[:30], want[:30], atol=1e-5)
+    assert float(jnp.abs(got[30:]).max()) == 0.0 and sums.tolist() == [1, 60, 2, 60]
+
+
+def test_experts_are_chosen_by_score_plus_bias_and_weighed_by_score():
+    """(d) A bias large enough to change the choice leaves the chosen experts'
+    weights what their scores alone make them."""
+    x = jnp.eye(3, 4)
+    router = jnp.asarray([[2.0, 1.0, 0.0, -1.0], [0.0, 0.1, 0.2, 0.3], [1.0, 1.0, 1.0, 1.0], [0.0] * 4])
+    bias = jnp.asarray([0.0, 0.0, 0.0, 5.0])  # expert 3 is always chosen, whatever it scores
+    ids, weights = moe.route_sigmoid_topk(x, router, bias, 2, 2.0)
+    scores = jax.nn.sigmoid(x @ router)
+    assert (np.sort(np.asarray(ids), axis=-1)[:, 1] == 3).all()
+    assert np.sort(np.asarray(ids), axis=-1)[0, 0] == 0  # the best of the unbiased rest
+    chosen = np.take_along_axis(np.asarray(scores), np.asarray(ids), axis=-1)
+    np.testing.assert_allclose(weights, 2.0 * chosen / chosen.sum(-1, keepdims=True), rtol=1e-6)
+    # without the bias expert 3 is the last choice of token 0
+    assert 3 not in np.asarray(moe.route_sigmoid_topk(x, router, 0 * bias, 2, 2.0)[0])[0]
+    np.testing.assert_allclose(
+        ref.route({"router": router, "router_bias": bias},
+                  {"num_experts_per_token": 2, "routed_scaling_factor": 2.0}, x)[np.arange(3)[:, None], ids],
+        weights, rtol=1e-6)
+
+
+@pytest.mark.parametrize("spelling", ["nested", "flat"])
+def test_config_from_card_reads_either_spelling_of_the_kda_group(cfg, spelling):
+    """(f) The published nested ``linear_attn_config`` and the flat keys the
+    benchmark's harness can write give one configuration."""
+    flat = ("linear_attn_num_heads", "linear_attn_head_dim", "short_conv_kernel_size",
+            "kda_layers", "full_attn_layers")
+    shape = dict(SHAPE)
+    if spelling == "nested":
+        shape = {k: v for k, v in SHAPE.items() if k not in flat}
+        shape["linear_attn_config"] = {
+            "num_heads": 2, "head_dim": 16, "short_conv_kernel_size": 4,
+            "kda_layers": SHAPE["kda_layers"], "full_attn_layers": SHAPE["full_attn_layers"]}
+    assert config_from_card(card(shape), jnp.float32) == cfg
+    assert cfg.num_experts == 4 and cfg.num_experts_published == 8
+    assert ref.sizes(shape) == ref.sizes(SHAPE)
+
+
+def test_a_card_that_says_num_experts_is_no_dense_impostor():
+    """(f) ``num_experts`` under a ``model_type`` no module runs is refused;
+    the Mixtral spelling still loads the llama module's expert option."""
+    with pytest.raises(ValueError, match="num_experts"):
+        config_from_card(card({"model_type": "some_moe", "num_experts": 64, "hidden_size": 64}))
+    mixtral = config_from_card(card({"model_type": "mixtral", "num_local_experts": 8, "hidden_size": 64}))
+    assert isinstance(mixtral, llama.LlamaConfig) and mixtral.num_experts == 8
+    assert isinstance(config_from_card(card({"model_type": "qwen2"})), llama.LlamaConfig)
+
+
+def served(engine, prompt, max_tokens, **sampling):
+    seq = submit(engine, prompt, max_tokens, **sampling)
+    run_out(engine)
+    return answer(seq)
+
+
+def test_the_engine_serves_the_reference_greedy_tokens_and_logprobs(engine, params):
+    """(g) Through ``JaxServingEngine``: admission, three chunk dispatches,
+    pipelined decode dispatches of 4 steps, sampling and log-probabilities."""
+    prompt = prompt_of(37)
+    toks, lps, finish = served(engine, prompt, 10, logprobs=5)
+    seq = jnp.asarray(prompt + toks[:-1], jnp.int32)
+    want = np.asarray(ref.logits(params, SHAPE, seq, jnp.arange(len(prompt) - 1, len(seq))))
+    assert toks == want.argmax(-1).tolist() and len(toks) == 10 and finish == "length"
+    logp = want - np.log(np.exp(want).sum(-1, keepdims=True))
+    np.testing.assert_allclose(lps, logp[np.arange(10), toks], atol=ATOL)
+    snap = engine.metrics_snapshot()
+    assert snap["moe_layer_calls"] > 0 and snap["slot_state_resets"] >= 1
+    # every routed pair is counted, and about half of them are held here
+    assert 0 < snap["moe_held_rows"] < snap["moe_routed_pairs"]
+    assert snap["moe_experts_hit"] <= 4 * snap["moe_layer_calls"]
+    # both programs read every table's full width, and the counters say so
+    assert snap["chunk_history_tiles_read"] == snap["chunk_history_tiles_full"] > 0
+    assert snap["decode_history_tiles_read"] == snap["decode_history_tiles_full"] > 0
+    tiers = list(snap["attention_tiers"].values())
+    assert tiers and all(t == {"tier": "dense", "interpret": False} for t in tiers)
+
+
+def test_a_reused_slot_gives_what_the_request_gives_alone(engine, cfg, params):
+    """(e) Four requests fill every slot and leave their state behind; a fifth
+    admitted into a used slot, beside another that still decodes, answers as
+    it does alone on a new engine: the slot was zeroed on admission."""
+    fresh = JaxServingEngine(cfg, params, ENGINE_CFG)
+    alone = served(fresh, prompt_of(21, salt=9), 8)[0]
+    fresh.close()
+    before = engine.metrics_snapshot()["slot_state_resets"]
+    for salt in range(4):
+        submit(engine, prompt_of(30 + salt, salt=salt), 6)
+    run_out(engine)
+    long_one = submit(engine, prompt_of(25, salt=5), 24)
+    for _ in range(4):
+        step(engine)
+    assert long_one.slot is not None
+    late = submit(engine, prompt_of(21, salt=9), 8)
+    run_out(engine)
+    assert answer(late)[0] == alone
+    assert engine.metrics_snapshot()["slot_state_resets"] == before + 6
+
+
+def test_a_repeated_prompt_takes_no_prefix_hit(engine):
+    """(h) The pages of a prompt served before are in the prefix cache; the
+    state that goes with them is not, so the hit is declined, the prompt
+    prefills from position 0, and the answer is the first one's."""
+    prompt = prompt_of(40, salt=3)
+    first = served(engine, prompt, 6)[0]
+    declined, resets = engine.prefix_hits_declined, engine.model_counters["slot_state_resets"]
+    seq = submit(engine, prompt, 6)
+    step(engine)
+    assert seq.alloc.cached_tokens == 0 and seq.alloc.declined_tokens == 32
+    assert seq.prefix_declined == 32  # what the request's prefill span carries
+    run_out(engine)
+    assert answer(seq)[0] == first
+    assert engine.prefix_hits_declined == declined + 1
+    assert engine.model_counters["slot_state_resets"] == resets + 1
+    assert engine.metrics_snapshot()["prefix_hits_declined"] == declined + 1
+
+
+def test_what_would_hand_pages_over_without_the_state_is_refused_by_name(engine, cfg, params):
+    """(h) Migration, disaggregated prefill, page transfer and the host tier
+    each raise ``StateNotPortable`` (a ``MigrationRejected``) with the reason."""
+    assert issubclass(StateNotPortable, MigrationRejected)
+    for refused in (engine.export_migratable,
+                    lambda: engine.stage_migration({"token_ids": [1, 2, 3]}, {}),
+                    lambda: engine.set_remote_prefill_policy(object()),
+                    lambda: engine.extract_blocks([0]),
+                    lambda: engine.seed_external_prefix([1] * 8, {})):
+        with pytest.raises(StateNotPortable, match="state per slot"):
+            refused()
+    with pytest.raises(StateNotPortable, match="the host tier"):
+        JaxServingEngine(cfg, params, EngineConfig(
+            max_slots=2, kv_block_size=8, max_model_len=64, host_cache_blocks=4))
+    with pytest.raises(ValueError, match="one device"):
+        JaxServingEngine(cfg, params, ENGINE_CFG, mesh=object())
+
+
+def test_the_step_programs_carry_the_three_scopes(engine):
+    """The device trace finds the mechanisms by name: ``kda``, ``mla`` and
+    ``moe`` are scopes of both step programs."""
+    def sd(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype)
+
+    s, c, mb = ENGINE_CFG.max_slots, ENGINE_CFG.prefill_chunk, ENGINE_CFG.max_blocks_per_seq
+    pool = (jax.tree.map(sd, engine.params), jax.tree.map(sd, engine.cache),
+            jax.tree.map(sd, engine.slot_state), sd(engine._dummy_counts))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+    wd = (i32(),) if engine._watchdog else ()
+    chunk = engine._build_chunk_fn(False, False, False).lower(
+        *pool, i32(s, c), i32(s, c), i32(s, mb), i32(s), i32(s), i32(), i32(2, s), f32(4, s), *wd)
+    decode = engine._build_decode_fn(False, False, False).lower(
+        *pool, i32(s), i32(s), i32(s, mb), i32(), i32(2, s), f32(4, s), *wd)
+    for program in (chunk, decode):
+        # "jit(chunk)/kda/dot_general"; inside the decode loop's body "kda/dot_general"
+        names = set(re.findall(r'loc\("(?:[^"]*/)?(kda|mla|moe)/', program.as_text(debug_info=True)))
+        assert names == {"kda", "mla", "moe"}, names

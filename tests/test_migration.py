@@ -443,34 +443,39 @@ class TestEngineStageAdopt:
 
         run(go())
 
-    def test_unfreeze_resumes_locally_byte_equal(self, tiny, run):
+    def test_unfreeze_resumes_locally_byte_equal(self, tiny):
         """An undrain mid-migration un-freezes the stream: it re-enters the
-        decode batch where it stopped and finishes byte-equal locally."""
+        decode batch where it stopped and finishes byte-equal locally.
 
-        async def go():
-            control = _engine(tiny)
-            prompt = list(range(13, 33))
-            golden = await _collect(control, prompt, 12)
-            control.close()
+        Driven one host step at a time on the test's own thread, as
+        tests/test_chunk_rows.py drives it (no engine thread): since a chunk
+        and a decode dispatch share a host step, a 12-token stream on the
+        engine's thread can end before a test on another posts its freeze."""
+        from .test_chunk_rows import answer, run_out, step, submit
 
-            eng = _engine(tiny)
-            ctx = Context(_payload(prompt, 12))
-            gen = eng.generate(ctx)
-            got = []
-            async for item in gen:
-                got.extend((item.data or {}).get("token_ids", []))
-                if len(got) >= 4:
-                    break
-            cps = _call(eng, eng.export_migratable)
-            assert len(cps) == 1
-            assert _call(eng, eng.unfreeze_migrations) == 1
-            rest = []
-            async for item in gen:
-                rest.extend((item.data or {}).get("token_ids", []))
-            assert got + rest == golden
-            eng.close()
+        prompt = list(range(13, 33))
+        control = _engine(tiny)
+        whole = submit(control, prompt, 12, temperature=0.0)
+        run_out(control)
+        golden = answer(whole)[0]
+        control.close()
+        assert len(golden) == 12
 
-        run(go())
+        eng = _engine(tiny)
+        seq = submit(eng, prompt, 12, temperature=0.0)
+        got = []
+        while len(got) < 4:
+            step(eng)
+            got += answer(seq)[0]
+        cps = eng.export_migratable()  # commits what is in flight, then freezes
+        got += answer(seq)[0]
+        assert len(cps) == 1 and len(got) < 12
+        step(eng)  # frozen: it holds its pages and decodes nothing
+        assert answer(seq)[0] == []
+        assert eng.unfreeze_migrations() == 1
+        run_out(eng)
+        assert got + answer(seq)[0] == golden
+        eng.close()
 
     def test_cut_for_resume_emits_directives(self, tiny, run):
         async def go():
