@@ -53,12 +53,13 @@ KVCache = Dict[str, jax.Array]  # {"latent": [L_mla, N, bs, rank + rope]} float3
 SlotState = Dict[str, Tuple[jax.Array, ...]]  # {"s": per KDA layer, "conv": ...}
 
 # sums the step programs return, in this order (engine: /debug/engine)
-COUNTERS = ("moe_layer_calls", "moe_held_rows", "moe_experts_hit",
-            "moe_routed_pairs", "slot_state_resets")
+COUNTERS = ("moe_layer_calls", "moe_held_rows", "moe_experts_hit", "moe_routed_pairs",
+            "moe_rows_computed", "moe_expert_reads", "slot_state_resets")
 # rows of a chunk computed at once: the rows are independent, and a chunk of
 # more is taken in groups, which bounds what the program holds beside its
 # arguments (64 rows at once: 3.7 GB of temporaries next to 9.4 GB)
 ROWS_AT_ONCE = 16
+MOE_COUNTERS = len(COUNTERS) - 1  # what ops/moe.py:dropless_experts counts
 
 
 # -- products of float32 activations against bfloat16 weights ------------------
@@ -103,18 +104,28 @@ def parts_of(x: jax.Array, passes: int = PASSES):
     return parts
 
 
-def wdot(spec: str, x: jax.Array, w: jax.Array) -> jax.Array:
-    """``einsum(spec, x, w)`` in float32 for a float32 ``x``. ``w`` in
-    bfloat16 is the served case (above); any other ``w`` (the float32 weights
-    of a CPU test) is multiplied at the highest precision as it is."""
-    if w.dtype != jnp.bfloat16:
-        return jnp.einsum(spec, x, w, precision=HIGHEST, preferred_element_type=jnp.float32)
-    parts, precision = parts_of(x.astype(jnp.float32)), None
+def operand_parts(x: jax.Array, dtype) -> list:
+    """What of a float32 ``x`` is multiplied against a weight of ``dtype``:
+    its bfloat16 parts for a bfloat16 weight (the served case, above), itself
+    for any other (the float32 weights of a CPU test). The weight is
+    multiplied in the parts' dtype, which on the CPU is float32: its dot has no
+    bfloat16 x bfloat16 -> float32 for every shape, and float32 copies give the
+    same parts and the same sums (exact)."""
+    if dtype != jnp.bfloat16:
+        return [x]
+    parts = parts_of(x)
     if jax.default_backend() == "cpu":
-        # the CPU's dot has no bfloat16 x bfloat16 -> float32 for every shape:
-        # the same parts and the same sums, from float32 copies (exact)
-        parts, w = [part.astype(jnp.float32) for part in parts], w.astype(jnp.float32)
-        precision = HIGHEST
+        parts = [part.astype(jnp.float32) for part in parts]
+    return parts
+
+
+def wdot(spec: str, x: jax.Array, w: jax.Array) -> jax.Array:
+    """``einsum(spec, x, w)`` in float32 for a float32 ``x``: the sum of its
+    :func:`operand_parts`' products, at the highest precision where they are
+    float32."""
+    parts = operand_parts(x.astype(jnp.float32), w.dtype)
+    w = w.astype(parts[0].dtype)
+    precision = HIGHEST if w.dtype == jnp.float32 else None
     if x.size <= STACK_UP_TO:
         ins, out = spec.split("->")
         both = jnp.einsum(f"Z{ins}->Z{out}", jnp.stack(parts), w, precision=precision,
@@ -425,10 +436,10 @@ def _swiglu(x, w_gate, w_up, w_down):
 
 
 def feed_forward(lp: Params, c: KimiLinearConfig, layer: int, x: jax.Array, valid: jax.Array):
-    """(output ``[B, T, E]``, counters ``[4]`` int32: calls, held rows, held
-    experts hit, pairs routed). The dense first layers count nothing."""
+    """(output ``[B, T, E]``, the expert layer's counters: ``COUNTERS`` but the
+    last). The dense first layers count nothing."""
     if not is_expert_layer(c, layer):
-        return _swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"]), jnp.zeros((4,), jnp.int32)
+        return _swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"]), jnp.zeros((MOE_COUNTERS,), jnp.int32)
     with jax.named_scope("moe"):
         b, t, e = x.shape
         flat = x.reshape(b * t, e)
@@ -438,7 +449,7 @@ def feed_forward(lp: Params, c: KimiLinearConfig, layer: int, x: jax.Array, vali
         y, stats = moe.dropless_experts(
             flat, ids, weights, lp["w_gate"], lp["w_up"], lp["w_down"],
             first_expert=c.first_expert, num_experts_total=c.num_experts_published,
-            token_valid=valid.reshape(-1), dot=wdot)
+            token_valid=valid.reshape(-1), parts_of=operand_parts)
         y = y + _swiglu(flat, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
         return y.reshape(b, t, e), stats
 
@@ -482,7 +493,7 @@ def forward_chunk(
 
     Returns (hidden ``[R, C, E]`` after the final norm, the pool with the
     rows' latents written, the slot state with the rows' slots advanced, the
-    counters ``[5]``). A row whose first position is 0 starts from a zeroed
+    counters ``[len(COUNTERS)]``). A row whose first position is 0 starts from a zeroed
     state: a slot is reset by the first chunk of the request admitted to it.
     More than ``ROWS_AT_ONCE`` rows are taken in groups of that many, one
     after another (a row touches its own slot and pages only)."""
@@ -518,7 +529,7 @@ def _chunk_rows(params, config, tokens, positions, kv_cache, block_tables, state
     back = jnp.where(lanes < slots, lanes, slots)
     pool = kv_cache["latent"]
     s_out, conv_out = list(state["s"]), list(state["conv"])
-    counters = jnp.zeros((4,), jnp.int32)
+    counters = jnp.zeros((MOE_COUNTERS,), jnp.int32)
     # key p of a gathered table is position p: a query sees keys up to its own
     key_pos = jnp.arange(block_tables.shape[1] * pool.shape[2])
     mask = (key_pos[None, None, :] <= positions[:, :, None]) & valid[:, :, None]
@@ -566,7 +577,7 @@ def decode(
     latents after the loop in one scatter a layer. ``sample(logits [S, V],
     positions, carry, k) -> (next tokens [S], carry, outputs)`` is the
     engine's. Returns (tokens, positions, carry, the stacked outputs, pool,
-    state, counters ``[5]``)."""
+    state, counters ``[len(COUNTERS)]``)."""
     c = config
     kinds = layer_kinds(c)
     pool = kv_cache["latent"]
@@ -609,7 +620,8 @@ def decode(
 
     (toks, pos, carry, s_all, conv_all, _, counters), (out, fresh, at) = jax.lax.scan(
         step,
-        (tokens, positions, carry, state["s"], state["conv"], history, jnp.zeros((4,), jnp.int32)),
+        (tokens, positions, carry, state["s"], state["conv"], history,
+         jnp.zeros((MOE_COUNTERS,), jnp.int32)),
         jnp.arange(steps))
     for j, lat in enumerate(fresh):  # [steps, S, D], written at `at` [steps, S]
         pool = _write_latent(pool, j, jnp.moveaxis(lat, 0, 1), at.T, block_tables)

@@ -19,12 +19,15 @@ recipe: annotate the expert axis (parallel/mesh.py logical rule
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from dynamo_tpu.ops.pallas.grouped_product import grouped_product, make_schedule
 
 
 @dataclass(frozen=True)
@@ -220,19 +223,22 @@ def route_sigmoid_topk(
     return ids.astype(jnp.int32), chosen * scale
 
 
-def _dot(spec: str, x: jax.Array, w: jax.Array) -> jax.Array:
-    """``einsum`` in the weights' precision, summed in float32."""
-    return jnp.einsum(spec, x.astype(w.dtype), w, preferred_element_type=jnp.float32)
+def _parts(x: jax.Array, dtype) -> List[jax.Array]:
+    """``x`` as the arrays, in the weights' ``dtype``, whose products against
+    a weight add up to ``x``'s: one rounding, for a caller that brings none."""
+    return [x.astype(dtype)]
 
 
-def rows_per_round(n_tokens: int, top_k: int, num_experts_total: int) -> int:
-    """Rows an expert computes in one round of :func:`dropless_experts`: twice
-    what even routing gives it, in eights, and 16 at least, so that a second
-    round is rare (it reads every held expert's weights again)."""
+def rows_per_tile(n_tokens: int, top_k: int, num_experts_total: int) -> int:
+    """Rows of activations in one tile of :func:`dropless_experts`' products:
+    what even routing gives an expert, in sixteens (a bfloat16 tile's rows)
+    and 64 at most. A tile is computed whole once an expert with a row in it,
+    so a tile much longer than a run computes mostly other experts' rows."""
     even = n_tokens * top_k / num_experts_total
-    return max(16, 8 * math.ceil(2 * even / 8))
+    return min(64, 16 * max(1, math.ceil(even / 16)))
 
 
+@functools.partial(jax.jit, static_argnames=("first_expert", "num_experts_total", "parts_of"))
 def dropless_experts(
     x: jax.Array,  # [T, E] float32
     ids: jax.Array,  # [T, k] expert ids over ALL experts
@@ -244,26 +250,31 @@ def dropless_experts(
     first_expert: int = 0,
     num_experts_total: Optional[int] = None,
     token_valid: Optional[jax.Array] = None,  # [T] bool; False = padding
-    dot: Callable[[str, jax.Array, jax.Array], jax.Array] = _dot,
+    parts_of: Callable[[jax.Array, Any], List[jax.Array]] = _parts,
 ) -> Tuple[jax.Array, jax.Array]:
     """``sum over the chosen experts held here of weight * E_e(x)`` for every
-    token, ``[T, E]``, and the counters ``[4]`` int32 (1, pairs computed here,
-    held experts with a row, pairs routed).
+    token, ``[T, E]``, and the counters ``[6]`` int32 (1, pairs computed here,
+    held experts with a row, pairs routed, rows the products ran over, expert
+    reads).
 
     The (token, expert) pairs routed to a held expert are sorted by expert, so
-    that an expert's rows are a run. A round gives every held expert its next
-    ``c`` rows (:func:`rows_per_round`) as one ``[X, c, E]`` block, the three
-    products run batched over the experts, and every pair takes its row back
-    weighted (a gather: a pair's place in the block is its rank in its run).
-    Rounds repeat until the longest run is through, so no pair is dropped
-    whatever the routing; with even routing there is one. ``dot(spec, x, w)``
-    is the product of a block of rows against the experts' weights: the
-    model's own arithmetic (one whose next router reads this layer's output
-    brings a product at float32's precision, models/kimi_linear.py)."""
+    that an expert's rows are a run, and each of the three products is ONE
+    grouped product over the sorted rows (ops/pallas/grouped_product.py): a
+    row meets its own expert's matrix only, a tile of ``rows_per_tile`` rows
+    past the last held pair is never computed, and an expert without a row is
+    never read. No pair is dropped whatever the routing: the rows are all
+    ``T * k`` pairs at most. ``parts_of(a, dtype)`` is the caller's arithmetic:
+    the arrays whose products against a weight of ``dtype``, taken in the
+    arrays' own dtype and summed in float32, are the product of the float32
+    ``a`` (a model whose next router reads this layer's output brings three
+    bfloat16 parts, models/kimi_linear.py). The last two counters are the
+    products' schedule: its visits x the rows of a tile (a tile that spans
+    several runs is computed once a run), and the runs it holds (the tiles of
+    a run follow each other, so a hit expert's matrix is read once)."""
     t, k = ids.shape
     x_held = w_gate.shape[0]
     n_pairs = t * k
-    c = rows_per_round(t, k, num_experts_total or x_held)
+    r = rows_per_tile(t, k, num_experts_total or x_held)
     local = ids - first_expert
     held = (local >= 0) & (local < x_held)
     if token_valid is not None:
@@ -271,32 +282,26 @@ def dropless_experts(
     expert = jnp.where(held, local, x_held).reshape(n_pairs)  # x_held: not here
     order = jnp.argsort(expert, stable=True)  # the pairs, an expert's together
     counts = jnp.zeros((x_held + 1,), jnp.int32).at[expert].add(1)[:x_held]
-    start = jnp.cumsum(counts) - counts
-    rank = jnp.zeros((n_pairs,), jnp.int32).at[order].set(jnp.arange(n_pairs, dtype=jnp.int32))
-    rank = (rank - start[jnp.minimum(expert, x_held - 1)]).reshape(t, k)  # place in its run
-    token_of = (order // k).astype(jnp.int32)
-    slot = jnp.arange(c, dtype=jnp.int32)
-    expert_of_pair = jnp.minimum(local, x_held - 1).clip(0)
+    place = jnp.zeros((n_pairs,), jnp.int32).at[order].set(jnp.arange(n_pairs, dtype=jnp.int32))
+    rows = -(-n_pairs // r) * r  # whole tiles
+    token_of = jnp.pad((order // k).astype(jnp.int32), (0, rows - n_pairs))
+    schedule = make_schedule(counts, rows, r)
 
-    def one_round(carry):
-        r, acc = carry
-        at = r * c + slot  # [c] places of this round in every run
-        live = at[None, :] < counts[:, None]  # [X, c]
-        rows = token_of[jnp.clip(start[:, None] + at[None, :], 0, n_pairs - 1)]
-        xs = jnp.where(live[..., None], x[rows], 0)  # [X, c, E]
-        hidden = jax.nn.silu(dot("xce,xef->xcf", xs, w_gate)) * dot("xce,xef->xcf", xs, w_up)
-        ys = dot("xcf,xfe->xce", hidden, w_down)
-        place = rank - r * c
-        mine = held & (place >= 0) & (place < c)
-        got = ys[expert_of_pair, jnp.clip(place, 0, c - 1)]  # [T, k, E]
-        acc = acc + jnp.sum(jnp.where(mine, weights, 0.0)[..., None] * got, axis=1)
-        return r + 1, acc
+    def product(a, w):
+        """Sorted rows ``a`` ``[rows, K]`` float32, each against its expert's
+        ``[K, N]``: ``[rows, N]`` float32 (past the held pairs: anything)."""
+        parts = jnp.stack(parts_of(a, w.dtype))
+        return grouped_product(parts, w.astype(parts.dtype), schedule, rows_per_tile=r,
+                               interpret=jax.default_backend() == "cpu")
 
-    longest = counts.max()
-    _, acc = jax.lax.while_loop(
-        lambda carry: carry[0] * c < longest, one_round,
-        (jnp.int32(0), jnp.zeros((t, x.shape[1]), jnp.float32)))
+    xs = x[token_of]
+    hidden = jax.nn.silu(product(xs, w_gate)) * product(xs, w_up)
+    ys = product(hidden, w_down)
+    got = ys[place.reshape(t, k)]  # [T, k, E]: each pair takes its row back
+    y = jnp.sum(jnp.where(held[..., None], weights[..., None] * got, 0.0), axis=1)
+
     routed = (jnp.sum(token_valid) if token_valid is not None else t) * k
-    stats = jnp.stack([jnp.int32(1), counts.sum(), jnp.sum(counts > 0),
-                       jnp.asarray(routed, jnp.int32)]).astype(jnp.int32)
-    return acc.astype(x.dtype), stats
+    hit = jnp.sum(counts > 0)
+    stats = jnp.stack([jnp.int32(1), counts.sum(), hit, jnp.asarray(routed, jnp.int32),
+                       schedule[3] * r, hit]).astype(jnp.int32)
+    return y.astype(x.dtype), stats

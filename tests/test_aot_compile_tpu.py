@@ -510,3 +510,49 @@ def test_taking_blocks_out_never_copies_the_kv_pool(request, one_chip, program, 
         ) == []
         temps.append(memory.temp_size_in_bytes)
     assert quantized or temps[0] == temps[1]
+
+
+# -- the expert layer of kimi-linear-48b-a3b -----------------------------------
+
+@pytest.mark.parametrize("tokens", [64, 2048], ids=["a_decode_step", "a_chunk_group"])
+def test_the_expert_layer_compiles_at_the_cells_shapes_and_copies_no_expert(
+        monkeypatch, one_chip, tokens):
+    """``ops/moe.py:dropless_experts`` as ``batch.kimi-linear-48b-a3b`` calls
+    it (128 experts held of 256, 8 a token, E 2,304, F 1,024, bf16 weights,
+    the model's three bfloat16 parts, padding tokens marked by a ``[tokens]``
+    mask whatever their number): three grouped-product kernels, and
+    nothing that writes a buffer shaped like the experts' weights, like one
+    expert's matrix or like a block of one. What the layer should stream, it
+    does not copy."""
+    from dynamo_tpu.models import kimi_linear as kl
+    from dynamo_tpu.ops import moe
+
+    # the layer asks for the backend to pick the interpreter; the described
+    # chip is not the default backend, so the test says "tpu" in its place
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    held, total, k, e, f = 128, 256, 8, 2304, 1024
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(x, ids, weights, valid, w_gate, w_up, w_down):
+        return moe.dropless_experts(
+            x, ids, weights, w_gate, w_up, w_down, num_experts_total=total,
+            token_valid=valid, parts_of=kl.operand_parts)
+
+    compiled = jax.jit(layer).lower(
+        sd((tokens, e), jnp.float32), sd((tokens, k), jnp.int32), sd((tokens, k), jnp.float32),
+        sd((tokens,), jnp.bool_), sd((held, e, f), jnp.bfloat16), sd((held, e, f), jnp.bfloat16),
+        sd((held, f, e), jnp.bfloat16)).compile()
+    hlo = compiled.as_text()
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", hlo)) == 3
+    # a bf16 array whose rows are an expert matrix's (E or F of them) and whose
+    # columns are whole lanes: the weights, one expert's matrix, a block of it.
+    # The layer's own bf16 buffers are [rows of pairs x parts, E or F].
+    written = [line.strip()[:120] for line in re.sub(r"/\*.*?\*/", "", hlo).splitlines()
+               if (m := _INSTRUCTION.match(line)) and m.group(3) != "parameter"
+               and re.search(rf"bf16\[(?:\d+,)*(?:{e}|{f}),(\d+)\]", m.group(2))]
+    assert written == [], written
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"expert layer, {tokens} tokens: temp_size_in_bytes {temp}")
+    assert temp < (1 << 20 if tokens == 64 else 1_200_000_000)

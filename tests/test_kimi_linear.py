@@ -113,7 +113,7 @@ def test_chunked_prefill_then_decode_agrees_with_the_plain_reference(cfg, params
             params, cfg, jnp.asarray(toks), jnp.asarray(pos), cache, jnp.asarray(tables),
             state, jnp.asarray([slot, slots], jnp.int32))
         got.append(kl.lm_head(params, cfg, h[0, :n]))
-        assert int(sums[4]) == (at == 0)  # the first chunk resets the slot, once
+        assert int(sums[-1]) == (at == 0)  # the first chunk resets the slot, once
         at += n
     np.testing.assert_allclose(np.concatenate(got), want[:n_prompt], atol=ATOL)
     assert float(state["s"][0][0].min()) == 7.0  # another slot's state is untouched
@@ -165,24 +165,87 @@ def test_the_expert_shares_add_up_to_the_uncut_reference_layer():
 
 
 def test_no_token_is_dropped_when_every_token_goes_to_one_expert():
-    """(c) 37 tokens all routed to expert 0 and expert 1: more rows than one
-    round gives an expert (24 here), so the layer takes a second round, and
-    every token still gets its full output."""
+    """(c) 37 tokens all routed to expert 0 and expert 1: runs of 37 rows, more
+    than the 16 of a tile, so a run spans tiles, and every token still gets its
+    full output. The products ran over the five tiles that hold the 74 rows
+    (the third holds the end of one run and the start of the next, and is
+    computed once for each: six visits) and read two experts, once each."""
     w = expert_weights(jax.random.PRNGKey(1))
     x = w["x"]
     ids = jnp.zeros((37, 2), jnp.int32).at[:, 1].set(1)
     weights = jnp.full((37, 2), 0.5)
-    assert moe.rows_per_round(37, 2, 8) < 37
+    assert moe.rows_per_tile(37, 2, 8) == 16 < 37
     got, sums = moe.dropless_experts(x, ids, weights, w["w_gate"], w["w_up"], w["w_down"])
     want = sum(0.5 * ref.swiglu(x, w["w_gate"][e], w["w_up"][e], w["w_down"][e]) for e in (0, 1))
     np.testing.assert_allclose(got, want, atol=1e-5)
-    assert sums.tolist() == [1, 74, 2, 74]
+    assert sums.tolist() == [1, 74, 2, 74, 6 * 16, 2]
     # a padding token computes nothing and counts nowhere
     valid = jnp.arange(37) < 30
     got, sums = moe.dropless_experts(x, ids, weights, w["w_gate"], w["w_up"], w["w_down"],
                                      token_valid=valid)
     np.testing.assert_allclose(got[:30], want[:30], atol=1e-5)
-    assert float(jnp.abs(got[30:]).max()) == 0.0 and sums.tolist() == [1, 60, 2, 60]
+    assert float(jnp.abs(got[30:]).max()) == 0.0 and sums.tolist() == [1, 60, 2, 60, 5 * 16, 2]
+
+
+def test_ragged_runs_in_one_call_are_the_reference_layer():
+    """Held experts 2-5 of 8 in one call: expert 2 without a row, expert 3
+    with one, expert 4 with 21 (more than a tile's 16), expert 5 with 9; ids of
+    experts held elsewhere (0, 1, 6, 7) and padding tokens mixed in. Against
+    the reference's layer given the same share; the counters from the runs."""
+    w = expert_weights(jax.random.PRNGKey(2))
+    x, lo = w["x"], 2
+    first = np.asarray([3] + [4] * 21 + [5] * 9 + [0, 1, 6, 7, 0, 7], np.int32)  # 37 tokens
+    second = np.asarray([0] * 31 + [4, 4, 5, 5, 3, 3], np.int32)  # the last six: padding
+    ids = jnp.stack([jnp.asarray(first), jnp.asarray(second)], axis=1)
+    weights = jax.random.uniform(jax.random.PRNGKey(5), (37, 2), jnp.float32, 0.1, 1.0)
+    valid = jnp.arange(37) < 31
+    held = {k: w[k][lo:lo + 4] for k in ("w_gate", "w_up", "w_down")}
+    got, sums = moe.dropless_experts(x, ids, weights, held["w_gate"], held["w_up"], held["w_down"],
+                                     first_expert=lo, num_experts_total=8, token_valid=valid)
+    want = jnp.zeros_like(x)
+    for e in (3, 4, 5):
+        mine = jnp.where((ids == e) & valid[:, None], weights, 0.0).sum(axis=1)
+        want = want + mine[:, None] * ref.swiglu(x, w["w_gate"][e], w["w_up"][e], w["w_down"][e])
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    assert float(jnp.abs(got[31:]).max()) == 0.0
+    # 31 held pairs in runs of 0, 1, 21, 9: rows 0-30 lie in two tiles of 16;
+    # the first is visited by experts 3 and 4, the second by 4 and 5
+    assert sums.tolist() == [1, 31, 3, 62, 4 * 16, 3]
+    # the reference's own layer (its router, its share) under a selection bias
+    # that sends every token to expert 4 and none to expert 2
+    bias = w["router_bias"].at[4].set(100.0).at[2].set(-100.0)
+    shape = {"num_experts_per_token": 2, "routed_scaling_factor": 2.446}
+    ids, weights = moe.route_sigmoid_topk(x, w["router"], bias, 2, 2.446)
+    got, sums = moe.dropless_experts(x, ids, weights, held["w_gate"], held["w_up"], held["w_down"],
+                                     first_expert=lo, num_experts_total=8, token_valid=valid)
+    zero = jnp.zeros_like(w["w_gate"][0])
+    whole = ref.expert_layer(
+        {**held, "router": w["router"], "router_bias": bias, "ws_gate": zero, "ws_up": zero,
+         "ws_down": zero.T}, shape, x, first_expert=lo)
+    np.testing.assert_allclose(got[:31], whole[:31], atol=1e-5)
+    assert int((ids == 4).sum()) == 37 and int((ids == 2).sum()) == 0 and int(sums[1]) >= 31
+
+
+def test_the_three_part_arithmetic_goes_through_the_grouped_product():
+    """bf16 weights and float32 rows in three bfloat16 parts, as the model
+    hands them over (``operand_parts``), against the same layer at float32's
+    highest precision: 24 bits of the activation reach every product."""
+    w = expert_weights(jax.random.PRNGKey(4), e=32, f=16)
+    w = {k: v.astype(jnp.bfloat16) if k.startswith("w_") else v for k, v in w.items()}
+    x = w["x"]
+    ids, weights = moe.route_sigmoid_topk(x, w["router"], w["router_bias"], 2, 2.446)
+    got, _ = moe.dropless_experts(x, ids, weights, w["w_gate"], w["w_up"], w["w_down"],
+                                  parts_of=kl.operand_parts)
+    one, _ = moe.dropless_experts(x, ids, weights, w["w_gate"], w["w_up"], w["w_down"])
+    f32 = {k: w[k].astype(jnp.float32) for k in ("w_gate", "w_up", "w_down")}
+    want = jnp.zeros_like(x)
+    for e in range(8):
+        mine = jnp.where(ids == e, weights, 0.0).sum(axis=1)
+        want = want + mine[:, None] * ref.swiglu(x, f32["w_gate"][e], f32["w_up"][e], f32["w_down"][e])
+    scale = float(jnp.abs(want).max())
+    assert float(jnp.abs(got - want).max()) <= 1e-6 * scale
+    # one bfloat16 part, the layer's own default, is what three are held against
+    assert float(jnp.abs(one - want).max()) > 1e-3 * scale
 
 
 def test_experts_are_chosen_by_score_plus_bias_and_weighed_by_score():
@@ -253,6 +316,9 @@ def test_the_engine_serves_the_reference_greedy_tokens_and_logprobs(engine, para
     # every routed pair is counted, and about half of them are held here
     assert 0 < snap["moe_held_rows"] < snap["moe_routed_pairs"]
     assert snap["moe_experts_hit"] <= 4 * snap["moe_layer_calls"]
+    # the products ran over whole tiles of 16 rows, and read a hit expert once
+    assert snap["moe_rows_computed"] % 16 == 0 and snap["moe_rows_computed"] >= snap["moe_held_rows"]
+    assert snap["moe_expert_reads"] == snap["moe_experts_hit"]
     # both programs read every table's full width, and the counters say so
     assert snap["chunk_history_tiles_read"] == snap["chunk_history_tiles_full"] > 0
     assert snap["decode_history_tiles_read"] == snap["decode_history_tiles_full"] > 0

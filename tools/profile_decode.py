@@ -2,7 +2,9 @@
 
 ``python tools/profile_decode.py history`` times the decode program ALONE at
 the served shapes by history profile, the live form beside the full-width one
-(:func:`history_profiles`); without the word, the round-5 ablation below.
+(:func:`history_profiles`); ``... experts`` times the expert layer ALONE at
+``batch.kimi-linear-48b-a3b``'s two shapes by routing and by grouped product
+(:func:`expert_profiles`); without a word, the round-5 ablation below.
 
 Method notes:
 - every measurement chains computations via data dependencies and fences
@@ -402,5 +404,127 @@ def history_profiles():
     engine.close()
 
 
+# -- the expert layer alone, by routing and by grouped product -----------------
+
+def draw_routing(t: int, skew: float, rng, total: int = 256, k: int = 8):
+    """``[t, k]`` expert ids: the ``k`` largest of an expert's popularity
+    (``skew`` x a standard normal) + Gumbel noise. ``skew`` 0 is even routing
+    (64 tokens hit 86.5 % of 128 held experts); 1.5 hits 54 %, which is what
+    ``/debug/engine`` reads of the cell's random weights (47-60 %, PERF.md)."""
+    scores = skew * rng.standard_normal(total) + rng.gumbel(size=(t, total))
+    return np.argsort(-scores, axis=1)[:, :k].astype(np.int32)
+
+
+def megablox_product(parts, w, schedule, *, rows_per_tile, interpret=False):
+    """Candidate (a) as JAX ships it: the parts of a row interleaved in its
+    run, ``megablox.gmm`` over ``[P * M, K]``, the parts summed after."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from dynamo_tpu.ops.pallas.grouped_product import columns_per_block
+
+    p, m, k = parts.shape
+    sizes = jnp.diff(schedule[0]) * p
+    out = gmm(jnp.moveaxis(parts, 0, 1).reshape(m * p, k), w, sizes, jnp.float32,
+              (rows_per_tile * p, k, columns_per_block(k, w.shape[2], 2)), interpret=interpret)
+    return out.reshape(m, p, -1).sum(axis=1)
+
+
+def ragged_dot_product(parts, w, schedule, *, rows_per_tile, interpret=False):
+    """Candidate (b): ``lax.ragged_dot`` a part."""
+    sizes = jnp.diff(schedule[0])
+    return sum(jax.lax.ragged_dot(part, w, sizes, preferred_element_type=jnp.float32)
+               for part in parts)
+
+
+def tile_loop_product(parts, w, schedule, *, rows_per_tile, interpret=False):
+    """Candidate (c): a loop over the (row tile, expert) visits of the kernel's
+    own schedule, each slicing its expert's matrix."""
+    offsets, groups, tiles, visits = schedule
+    p, m, k = parts.shape
+    tm = rows_per_tile
+
+    def visit(i, out):
+        g, at = groups[i], tiles[i] * tm
+        rows = jax.lax.dynamic_slice_in_dim(parts, at, tm, axis=1).reshape(p * tm, k)
+        got = jnp.dot(rows, w[g], preferred_element_type=jnp.float32).reshape(p, tm, -1).sum(axis=0)
+        row = at + jnp.arange(tm)
+        mine = (row >= offsets[g]) & (row < offsets[g + 1])
+        old = jax.lax.dynamic_slice_in_dim(out, at, tm)
+        return jax.lax.dynamic_update_slice_in_dim(out, jnp.where(mine[:, None], got, old), at, 0)
+
+    return jax.lax.fori_loop(0, visits, visit, jnp.zeros((m, w.shape[2]), jnp.float32))
+
+
+def expert_profiles():
+    """``ops/moe.py:dropless_experts`` of ``kimi-linear-48b-a3b`` (128 experts
+    held of 256, 8 a token, E 2,304, F 1,024, bf16 weights, float32 rows in
+    three bfloat16 parts) at the cell's two shapes: a decode step's 64 tokens,
+    and a chunk group's 2,048 of which half are padding. Each is timed under
+    routing drawn even, drawn as the cell's, and all to ONE expert (a run of
+    many tiles: whether the kernel reads an expert once a run or once a tile),
+    PROF_ITERS (default 8) layers chained in one dispatch. PROF_PRODUCTS names
+    the grouped products to compare (``kernel`` is the tree's; ``megablox``,
+    ``ragged_dot``, ``tile_loop``), PROF_TILES the rows a tile to try beside
+    the layer's own (``rows_per_tile``: 0)."""
+    from dynamo_tpu.engine_jax.compile_cache import enable_compile_cache
+    from dynamo_tpu.models import kimi_linear as kl
+    from dynamo_tpu.ops import moe
+
+    enable_compile_cache()
+    n_iter = int(os.environ.get("PROF_ITERS", "8"))
+    products = {"kernel": moe.grouped_product, "megablox": megablox_product,
+                "ragged_dot": ragged_dot_product, "tile_loop": tile_loop_product}
+    chosen = os.environ.get("PROF_PRODUCTS", "kernel").split(",")
+    tiles = [int(r) for r in os.environ.get("PROF_TILES", "0").split(",")]
+    held, total, k, e, f = 128, 256, 8, 2304, 1024
+    key = jax.random.split(jax.random.PRNGKey(0), 4)
+
+    def dense(key, shape, fan_in):
+        return (jax.random.normal(key, shape, jnp.float32) / np.sqrt(fan_in)).astype(jnp.bfloat16)
+
+    w = (dense(key[0], (held, e, f), e), dense(key[1], (held, e, f), e), dense(key[2], (held, f, e), f))
+    own = moe.rows_per_tile
+
+    def timed(t, ids, valid):
+        x = jax.random.normal(key[3], (t, e), jnp.float32)
+        weights = jnp.full((t, k), 2.446 / k, jnp.float32)
+
+        @jax.jit
+        def chain(x, ids, weights, valid, w_gate, w_up, w_down):
+            def layer(x, _):
+                y, stats = moe.dropless_experts(
+                    x, ids, weights, w_gate, w_up, w_down, num_experts_total=total,
+                    token_valid=valid, parts_of=kl.operand_parts)
+                return x + 1e-3 * y, stats
+            return jax.lax.scan(layer, x, None, length=n_iter)
+
+        args = (x, jnp.asarray(ids), weights, jnp.asarray(valid), *w)
+        chain(*args)[0].block_until_ready()
+        times = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            out, stats = chain(*args)
+            out.block_until_ready()
+            times.append(time.perf_counter() - t0)
+        return float(np.median(times)) * 1e3 / n_iter, np.asarray(stats[0]).tolist()
+
+    rng = np.random.default_rng(0)
+    shapes = {"decode 64": (64, np.ones(64, bool)),
+              "chunk 2048": (2048, np.tile(np.arange(128) < 64, 16))}
+    for shape, (t, valid) in shapes.items():
+        routings = {"even": draw_routing(t, 0.0, rng), "as the cell": draw_routing(t, 1.5, rng),
+                    "one expert": np.tile(np.arange(k, dtype=np.int32) * held, (t, 1))}
+        for name in chosen:
+            moe.grouped_product = products[name]
+            for r in tiles:
+                moe.rows_per_tile = (lambda *a, r=r: r) if r else own
+                for routing, ids in routings.items():
+                    ms, stats = timed(t, ids, valid)
+                    print(f"{shape:10s} {name:10s} tile {r or own(t, k, total):3d} {routing:11s} "
+                          f"{ms:7.3f} ms a layer; held rows {stats[1]}, experts hit {stats[2]}, "
+                          f"rows computed {stats[4]}, expert reads {stats[5]}", flush=True)
+    moe.rows_per_tile, moe.grouped_product = own, products["kernel"]
+
+
 if __name__ == "__main__":
-    history_profiles() if sys.argv[1:2] == ["history"] else main()
+    {"history": history_profiles, "experts": expert_profiles}.get(" ".join(sys.argv[1:2]), main)()
