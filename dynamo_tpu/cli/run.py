@@ -52,7 +52,7 @@ from ..llm.preprocessor import (
     OpenAIPreprocessor,
 )
 from ..llm.protocols.openai import ChatCompletionRequest
-from ..runtime import Context, Pipeline
+from ..runtime import Context, Pipeline, profiling
 from ..runtime.logging_util import init as init_logging
 
 logger = logging.getLogger(__name__)
@@ -296,6 +296,9 @@ def build_engine(out_spec: str, flags: argparse.Namespace):
         if flags.extra_engine_args:
             with open(flags.extra_engine_args) as f:
                 extra = json.load(f)
+        # start-up by phase (`setup_phase_s` of /debug/engine): `devices`
+        # runs from `amain` to the line that names the device
+        setup = profiling.setup_clock()
         from ..engine_jax.compile_cache import enable_compile_cache
 
         cache_dir = enable_compile_cache()
@@ -323,6 +326,7 @@ def build_engine(out_spec: str, flags: argparse.Namespace):
         )
         # compile the step functions off the request path
         logger.info("warmup %s", json.dumps(core.warmup()))
+        setup.switch(profiling.S_HTTP)  # ends where the port answers
         if getattr(flags, "wire", "openai") == "token":
             # token wire: the CORE engine serves the endpoint directly
             # (PreprocessedRequest dicts in, LLMEngineOutput dicts out);
@@ -644,6 +648,9 @@ def init_multihost(flags) -> None:
 
 
 async def amain(argv: list[str]) -> None:
+    setup = profiling.setup_clock()
+    setup.credit(profiling.S_BEFORE_MAIN, profiling.process_age_us() or 0.0)
+    setup.switch(profiling.S_DEVICES)
     init_logging()
     in_spec, out_spec, rest = parse_io(argv)
     flags = build_parser().parse_args(rest)

@@ -24,9 +24,10 @@ _COMPILE_LOCK = threading.Lock()
 _COMPILES: dict[str, int] = {}
 
 
-def record_compile(kind: str = "step", detail: str = "") -> None:
+def record_compile(kind: str = "step", detail: str = "") -> str:
     """Count one jitted-program build (called where engines create a new
-    compiled variant — cache misses in their per-shape fn tables).
+    compiled variant — cache misses in their per-shape fn tables) and
+    return its key, ``kind`` and ``detail`` in one string.
     ``detail`` carries the triggering variant key / abstract shapes; it
     lands on the profiling timeline (docs/observability.md §Profiling) as
     a ``jit_compile`` event when that plane is armed — a recompile storm
@@ -35,11 +36,11 @@ def record_compile(kind: str = "step", detail: str = "") -> None:
         _COMPILES[kind] = _COMPILES.get(kind, 0) + 1
     # lazy + constructor-free: processes that never armed DYN_TPU_PROFILE
     # never even import the profiling module from here
+    key = f"{kind} {detail}".strip()
     prof = sys.modules.get("dynamo_tpu.runtime.profiling")
     if prof is not None:
-        prof.note_event(
-            "jit_compile", detail=f"{kind} {detail}".strip(), phase=kind
-        )
+        prof.note_event("jit_compile", detail=key, phase=kind)
+    return key
 
 
 def compile_count() -> int:

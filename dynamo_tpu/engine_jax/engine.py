@@ -94,6 +94,12 @@ from dynamo_tpu.kv.pages import KvDtypeMismatch, MigrationRejected
 from dynamo_tpu.runtime import faults as faults_mod
 from dynamo_tpu.runtime import integrity as integrity_mod
 from dynamo_tpu.runtime import profiling as profiling_mod
+from dynamo_tpu.runtime.profiling import (
+    P_ADMIT, P_ALLOC, P_CHUNK_BUILD, P_CHUNK_DISPATCH, P_CHUNK_EMIT,
+    P_CHUNK_FETCH, P_COMPILE, P_DECODE_BUILD, P_DECODE_DISPATCH,
+    P_DECODE_EMIT, P_DECODE_FETCH, P_DRAIN, P_POSTED, P_PREPARE, P_SEAL_CRC,
+    P_SEAL_READ, P_SPILLS, P_SWEEP, P_WAIT,
+)
 from dynamo_tpu.runtime import qos as qos_mod
 from dynamo_tpu.runtime import straggler as straggler_mod
 from dynamo_tpu.runtime import telemetry, tracing
@@ -455,14 +461,14 @@ class _ChunkInflight:
     of its sampled tokens, and per live row the lane, its sequence and the
     prompt tokens it fed. It lives inside one host step (`_prefill_step`)."""
 
-    __slots__ = ("fetch", "rows", "sealing", "t_step", "t_disp")
+    __slots__ = ("fetch", "rows", "sealing", "t_step", "prof")
 
-    def __init__(self, fetch, rows, sealing, t_step, t_disp):
+    def __init__(self, fetch, rows, sealing, t_step, prof):
         self.fetch = fetch  # (sampled [R],) or (sampled, lp, top_ids, top_lps)
         self.rows = rows  # List[Tuple[int, _Seq, List[int]]]: lane, seq, fed
         self.sealing = sealing  # Optional[_SealPages]: the blocks it fills
-        self.t_step = t_step
-        self.t_disp = t_disp  # 0.0 = this dispatch is not profiled
+        self.t_step = t_step  # for the straggler detector alone
+        self.prof = prof  # the timeline samples this dispatch
 
 
 def chunk_row_ladder(max_slots: int) -> List[int]:
@@ -803,10 +809,16 @@ class JaxServingEngine(AsyncEngine):
         self._timeline = (
             profiling_mod.timeline() if self._profile is not None else None
         )
-        # allocator microseconds (alloc/grow/evict/seal-checksum) accrued
-        # since the last dispatch record — admission allocs between
-        # dispatches charge the NEXT dispatch's record
-        self._prof_alloc_us = 0.0
+        # the engine thread's ONE clock (runtime/profiling.py:PhaseClock):
+        # every phase of a host step is a span on the profiler's clock while
+        # a session runs, and a self-time counter in /debug/engine always
+        self._clock = profiling_mod.PhaseClock(
+            profiling_mod.ENGINE_PHASES, "engine.", jax.profiler.TraceAnnotation,
+            jax.profiler.StepTraceAnnotation, stall_s=0.5,
+        )
+        # enqueue -> slot, summed over the requests admitted (cumulative)
+        self.queue_wait_us_sum = 0.0
+        self.queue_wait_count = 0
 
         # fail-slow defense (runtime/straggler.py, docs/resilience.md
         # §Fail-slow): per-dispatch wall-us-per-token EWMA feeding the
@@ -1266,7 +1278,7 @@ class JaxServingEngine(AsyncEngine):
         key = (want_lp, want_pen, want_sample)
         fn = self._decode_fns.get(key)
         if fn is None:
-            record_compile("decode", detail=(
+            self._clock.compile_key = record_compile("decode", detail=(
                 f"lp={want_lp} pen={want_pen} sample={want_sample} "
                 f"[S={self.config.max_slots},k={self.config.decode_steps}]"
             ))
@@ -1287,7 +1299,7 @@ class JaxServingEngine(AsyncEngine):
         key = (want_lp, want_pen, want_sample, want_history, rows)
         fn = self._chunk_fns.get(key)
         if fn is None:
-            record_compile("chunk", detail=(
+            self._clock.compile_key = record_compile("chunk", detail=(
                 f"lp={want_lp} pen={want_pen} sample={want_sample} "
                 f"history={want_history} [R={rows},"
                 f"C={self.config.prefill_chunk}]"
@@ -1420,7 +1432,7 @@ class JaxServingEngine(AsyncEngine):
         key = (want_lp, want_pen, want_sample)
         fn = self._verify_fns.get(key)
         if fn is None:
-            record_compile("verify", detail=(
+            self._clock.compile_key = record_compile("verify", detail=(
                 f"lp={want_lp} pen={want_pen} sample={want_sample} "
                 f"[S={self.config.max_slots},k1={self._spec_k + 1}]"
             ))
@@ -1702,6 +1714,16 @@ class JaxServingEngine(AsyncEngine):
         cfg = self.config
         S, C, MB = cfg.max_slots, cfg.prefill_chunk, cfg.max_blocks_per_seq
         timings: Dict[str, float] = {}
+        # start-up's clock (main thread): `compile` is this method's wall,
+        # out of which the time under `tracing_turn` goes to `lower`
+        setup = profiling_mod.setup_clock(jax.profiler.TraceAnnotation)
+        setup.switch(profiling_mod.S_COMPILE)
+
+        def done():
+            setup.switch(None)
+            self._clock.compile_key = None  # built here, not on the served path
+            return timings
+
         sample_set = (False,) if variants == "greedy" else (False, True)
         # (rows, want_sample, want_history) of every chunk program to compile
         chunk_set = [
@@ -1770,8 +1792,9 @@ class JaxServingEngine(AsyncEngine):
                 timings[f"decode(sample={want_sample})"] = round(
                     time.perf_counter() - t0, 2
                 )
+            setup.switch(profiling_mod.S_SEALING)
             warm_sealing()
-            return timings
+            return done()
 
         from concurrent.futures import ThreadPoolExecutor
 
@@ -1834,6 +1857,7 @@ class JaxServingEngine(AsyncEngine):
         # so that each compile starts when its trace ends instead of all of
         # them after all the traces
         tracing_turn = threading.Lock()
+        lowering_s = [0.0]
         jobs.sort(key=lambda job: not job[3][3])  # key = (kind, lp, pen, sample, ...)
 
         def compile_one(job):
@@ -1842,7 +1866,9 @@ class JaxServingEngine(AsyncEngine):
                 return key, fn
             t0 = time.perf_counter()
             with tracing_turn:
+                t1 = time.perf_counter()
                 lowered = fn.lower(*args)
+                lowering_s[0] += time.perf_counter() - t1
             compiled = lowered.compile()
             timings[name] = round(time.perf_counter() - t0, 2)
             return key, compiled
@@ -1859,7 +1885,11 @@ class JaxServingEngine(AsyncEngine):
                 else:
                     self._decode_fns[key[1:]] = compiled
             sealing.result()
-        return timings
+        setup.credit(profiling_mod.S_LOWER, lowering_s[0] * 1e6,
+                     out_of=profiling_mod.S_COMPILE)
+        # the take programs' own seconds, beside the compiles and not after them
+        setup.credit(profiling_mod.S_SEALING, timings.get("take_blocks", 0.0) * 1e6)
+        return done()
 
     # -- AsyncEngine interface ----------------------------------------------
 
@@ -1932,86 +1962,11 @@ class JaxServingEngine(AsyncEngine):
             self._thread.join(timeout=5)
 
     def _step_loop(self) -> None:
+        clock = self._clock
+        clock.start()
         try:
-            while True:
-                with self._cond:
-                    while (
-                        not self._shutdown
-                        and not self._pending
-                        and not self._posted
-                        and not any(self._slots)
-                        and self._inflight is None
-                        and not self._pending_spills
-                        and self._counts is None  # idle pass frees it first
-                    ):
-                        if self._awaiting or self._staged_migrations:
-                            # wake periodically to sweep remote-prefill
-                            # timeouts and unclaimed staged migrations
-                            self._cond.wait(timeout=1.0)
-                            break
-                        # parking idle: record it, or the last busy beat
-                        # would age into a false stall (health.py reads
-                        # busy-at-last-beat, and an idle park beats no more)
-                        self.heartbeat.beat(busy=False)
-                        self._cond.wait()
-                    if self._shutdown:
-                        # drain posted callbacks before exiting: callers of
-                        # post() (transfer-plane _engine_call) await futures
-                        # these resolve — dropping them would hang the
-                        # awaiting task forever on a close() race
-                        self._run_posted()
-                        return
-                # liveness beat BEFORE the work: if the dispatch below (or a
-                # posted callback / spill harvest) wedges, the recorded busy
-                # flag plus a growing beat age is exactly the stall
-                # signature the health monitor detects. Every wake source of
-                # the idle-wait predicate above counts as busy — a wedge in
-                # a posted callback on an otherwise-idle engine must not
-                # masquerade as an idle park.
-                self.heartbeat.beat(busy=bool(
-                    self._pending
-                    or self._posted
-                    or self._inflight is not None
-                    or any(s is not None for s in self._slots)
-                    or self._awaiting
-                    or self._pending_spills
-                ))
-                self._run_posted()
-                self._sweep_remote_timeouts()
-                self._sweep_staged()
-                idle = (
-                    not self._pending and not any(self._slots)
-                    and self._inflight is None
-                )
-                # idle = nothing to stall: drain spills fully so revisits
-                # after an idle gap see their prefixes in the host tier,
-                # and drop the [S, V] penalty-count buffer (16 MB at a
-                # 128k vocab) a final dispatch with penalized lanes left
-                # allocated — no later dispatch would ever release it
-                self._harvest_spills(force=idle)
-                if idle:
-                    self._release_counts()
-                    if self._perf is not None:
-                        # exclude the idle gap from throughput timing
-                        self._perf.note_idle()
-                    if self._fair is not None:
-                        # bound fair-queue memory across tenant churn; an
-                        # idle engine has no backlog to be fair about
-                        self._fair.forget_absent(
-                            [s.tenant for s in self._awaiting.values()]
-                        )
-                self._coalesce_admission_wave()
-                self._admit()
-                self._dispatch_step()
-                if (
-                    not any(self._slots) and self._inflight is None
-                    and self._pending and self._awaiting
-                ):
-                    # every pending request is parked (capacity or shared
-                    # in-flight prefix) behind remote prefills: poll gently
-                    # instead of spinning the GIL against the transfer plane
-                    with self._cond:
-                        self._cond.wait(timeout=0.005)
+            while not self._host_step(clock):
+                pass
         except Exception:
             logger.exception("engine step loop crashed")
             # fail every in-flight request rather than hanging clients
@@ -2019,6 +1974,98 @@ class JaxServingEngine(AsyncEngine):
                 if seq is not None:
                     seq.emit(Annotated.from_error("engine internal error"))
                     seq.emit(_FINISHED)
+
+    def _host_step(self, clock) -> bool:
+        """One iteration of the engine thread: one tree of phases under one
+        ``engine.step`` annotation. True once the engine has shut down."""
+        with clock.step(self._step_counter):
+            with self._cond:
+                while (
+                    not self._shutdown
+                    and not self._pending
+                    and not self._posted
+                    and not any(self._slots)
+                    and self._inflight is None
+                    and not self._pending_spills
+                    and self._counts is None  # idle pass frees it first
+                ):
+                    if self._awaiting or self._staged_migrations:
+                        # wake periodically to sweep remote-prefill
+                        # timeouts and unclaimed staged migrations
+                        with clock(P_WAIT):
+                            self._cond.wait(timeout=1.0)
+                        break
+                    # parking idle: record it, or the last busy beat
+                    # would age into a false stall (health.py reads
+                    # busy-at-last-beat, and an idle park beats no more)
+                    self.heartbeat.beat(busy=False)
+                    with clock(P_WAIT):
+                        self._cond.wait()
+                if self._shutdown:
+                    # drain posted callbacks before exiting: callers of
+                    # post() (transfer-plane _engine_call) await futures
+                    # these resolve — dropping them would hang the
+                    # awaiting task forever on a close() race
+                    self._run_posted()
+                    return True
+            # liveness beat BEFORE the work: if the dispatch below (or a
+            # posted callback / spill harvest) wedges, the recorded busy
+            # flag plus a growing beat age is exactly the stall
+            # signature the health monitor detects. Every wake source of
+            # the idle-wait predicate above counts as busy — a wedge in
+            # a posted callback on an otherwise-idle engine must not
+            # masquerade as an idle park.
+            self.heartbeat.beat(busy=bool(
+                self._pending
+                or self._posted
+                or self._inflight is not None
+                or any(s is not None for s in self._slots)
+                or self._awaiting
+                or self._pending_spills
+            ))
+            clock.active = any(self._slots)
+            with clock(P_POSTED):
+                self._run_posted()
+            with clock(P_SWEEP):
+                self._sweep_remote_timeouts()
+                self._sweep_staged()
+            idle = (
+                not self._pending and not any(self._slots)
+                and self._inflight is None
+            )
+            # idle = nothing to stall: drain spills fully so revisits
+            # after an idle gap see their prefixes in the host tier,
+            # and drop the [S, V] penalty-count buffer (16 MB at a
+            # 128k vocab) a final dispatch with penalized lanes left
+            # allocated — no later dispatch would ever release it
+            with clock(P_SPILLS):
+                self._harvest_spills(force=idle)
+            if idle:
+                self._release_counts()
+                if self._perf is not None:
+                    # exclude the idle gap from throughput timing
+                    self._perf.note_idle()
+                if self._fair is not None:
+                    # bound fair-queue memory across tenant churn; an
+                    # idle engine has no backlog to be fair about
+                    self._fair.forget_absent(
+                        [s.tenant for s in self._awaiting.values()]
+                    )
+            with clock(P_ADMIT):
+                self._coalesce_admission_wave()
+                self._admit()
+            clock.active = any(self._slots)
+            self._dispatch_step()
+            if (
+                not any(self._slots) and self._inflight is None
+                and self._pending and self._awaiting
+            ):
+                # every pending request is parked (capacity or shared
+                # in-flight prefix) behind remote prefills: poll gently
+                # instead of spinning the GIL against the transfer plane
+                with self._cond, clock(P_WAIT):
+                    self._cond.wait(timeout=0.005)
+        return False
 
     def post(self, fn) -> None:
         """Schedule a host function to run on the engine thread (thread-safe).
@@ -2211,16 +2258,14 @@ class JaxServingEngine(AsyncEngine):
                 # KV + first token already landed, just start decoding
                 seq.slot = free[0]
                 self._slots[seq.slot] = seq
-                if seq.admit_t is None:
-                    seq.admit_t = time.perf_counter()
+                self._note_admitted(seq)
                 continue
             if seq.alloc is not None:
                 # remote prefill failed/timed out: run the prefill locally on
                 # the allocation we already hold
                 seq.slot = free[0]
                 self._slots[seq.slot] = seq
-                if seq.admit_t is None:
-                    seq.admit_t = time.perf_counter()
+                self._note_admitted(seq)
                 seq.prefill_pos = min(seq.alloc.cached_tokens, len(seq.prompt) - 1)
                 continue
             if seq.wait_hash is not None:
@@ -2239,7 +2284,7 @@ class JaxServingEngine(AsyncEngine):
                 # keeps admitting other tenants past it
                 deferred.append(seq)
                 continue
-            alloc = self._alloc_seq_timed(seq)
+            alloc = self._alloc_seq(seq)
             if isinstance(alloc, InflightPrefix):
                 # another lane is prefilling this prompt's prefix right now:
                 # park until it seals (then these become ordinary prefix
@@ -2252,7 +2297,7 @@ class JaxServingEngine(AsyncEngine):
             if alloc is None and (self._inflight is not None or self._zombie_allocs):
                 # blocks may be parked behind the in-flight speculative chunk
                 self._drain_inflight()
-                alloc = self._alloc_seq_timed(seq)
+                alloc = self._alloc_seq(seq)
                 if isinstance(alloc, InflightPrefix):
                     seq.joined_inflight = True
                     seq.wait_hash = alloc.seq_hash
@@ -2267,7 +2312,7 @@ class JaxServingEngine(AsyncEngine):
                 if victim is not seq:
                     self._drain_inflight()
                     self._preempt(victim)
-                    alloc = self._alloc_seq_timed(seq)
+                    alloc = self._alloc_seq(seq)
                     if isinstance(alloc, InflightPrefix):
                         seq.joined_inflight = True
                         seq.wait_hash = alloc.seq_hash
@@ -2350,52 +2395,38 @@ class JaxServingEngine(AsyncEngine):
 
             seq.slot = free[0]
             self._slots[seq.slot] = seq
-            if seq.admit_t is None:
-                seq.admit_t = time.perf_counter()
+            self._note_admitted(seq)
             # the last prompt token is never cached (allocator guarantees it),
             # so every admitted sequence computes at least one position
             seq.prefill_pos = seq.alloc.cached_tokens
 
-    # -- performance attribution (runtime/profiling.py) ----------------------
+    def _note_admitted(self, seq: "_Seq") -> None:
+        """A request takes a slot: its wait in the queue ends here, once."""
+        if seq.admit_t is None:
+            seq.admit_t = time.perf_counter()
+            self.queue_wait_us_sum += (seq.admit_t - seq.enqueue_t) * 1e6
+            self.queue_wait_count += 1
 
-    def _alloc_seq_timed(self, seq: "_Seq"):
-        """allocate_sequence with the allocator time accrued into the next
-        dispatch record (profiling armed) — the bare call otherwise."""
-        tl = self._timeline
-        t = time.perf_counter() if tl is not None else 0.0
+    def _alloc_seq(self, seq: "_Seq"):
         # a slot model takes no prefix hit: the pages would come without the
         # slot's state, so it prefills from position 0 (`_refuse_for_state`)
-        alloc = self.allocator.allocate_sequence(
-            seq.prompt, tenant=seq.tenant, level=seq.level,
-            reuse=not self._slot_model,
-        )
-        if tl is not None:
-            self._prof_alloc_us += (time.perf_counter() - t) * 1e6
+        with self._clock(P_ALLOC):
+            alloc = self.allocator.allocate_sequence(
+                seq.prompt, tenant=seq.tenant, level=seq.level,
+                reuse=not self._slot_model,
+            )
         # None (no room) and an InflightPrefix (wait for it) decline nothing
         if getattr(alloc, "declined_tokens", 0):
             self.prefix_hits_declined += 1
             seq.prefix_declined = alloc.declined_tokens
         return alloc
 
-    def _seal_timed(self, alloc, toks) -> None:
-        """note_tokens_computed (block seal + integrity checksum) with the
-        time accrued to the allocator share of the dispatch record."""
-        tl = self._timeline
-        if tl is None:
-            self.allocator.note_tokens_computed(alloc, toks)
-            return
-        t = time.perf_counter()
-        self.allocator.note_tokens_computed(alloc, toks)
-        self._prof_alloc_us += (time.perf_counter() - t) * 1e6
-
-    def _note_dispatch(
-        self, tl, phase: str, t_step: float, t_disp: float, t_fetch: float,
-        t_end: float, batch: int, tokens: int,
-    ) -> None:
-        """One sampled dispatch into the timeline: host build / device /
-        host emit split, the accrued allocator share, queue depths, and the
-        PR5 request/trace ids riding the batch."""
-        alloc_us, self._prof_alloc_us = self._prof_alloc_us, 0.0
+    def _note_dispatch(self, phase: str, batch: int, tokens: int) -> None:
+        """One sampled dispatch into the timeline (DYN_TPU_PROFILE), from the
+        phase clock's counters of this host step: build / device as the host
+        observed it / emit, the allocator's share, queue depths, and the PR5
+        request/trace ids riding the batch."""
+        clock, kind = self._clock, 0 if phase == "chunk" else 1
         reqs: List[str] = []
         traces: List[str] = []
         for s in self._slots:
@@ -2409,17 +2440,16 @@ class JaxServingEngine(AsyncEngine):
         # epoch-align the perf_counter anchors so captures from different
         # workers merge onto one Perfetto timeline
         now_wall = time.time()  # dynlint: allow-wall-clock(cross-process trace alignment)
-        now_perf = time.perf_counter()
-        tl.note_dispatch(
+        self._timeline.note_dispatch(
             phase,
-            ts=now_wall - (now_perf - t_step),
+            ts=now_wall - (time.perf_counter() - clock.t_step),
             step=self._step_counter,
             batch=batch,
             tokens=tokens,
-            host_us=(t_disp - t_step) * 1e6,
-            device_us=(t_fetch - t_disp) * 1e6,
-            post_us=(t_end - t_fetch) * 1e6,
-            alloc_us=alloc_us,
+            host_us=clock.step_us(P_DECODE_BUILD if kind else P_CHUNK_BUILD),
+            device_us=clock.device_us[kind],
+            post_us=clock.step_us(P_DECODE_EMIT if kind else P_CHUNK_EMIT),
+            alloc_us=clock.step_us(P_ALLOC) + clock.step_us(P_SEAL_CRC),
             queue=len(self._pending) + len(self._awaiting),
             reqs=reqs,
             traces=traces,
@@ -2501,11 +2531,7 @@ class JaxServingEngine(AsyncEngine):
         most-starved tenant's lanes first, and the consumed tokens are
         charged to the prefill debt that keeps the following steps
         pure-decode."""
-        t_step = (
-            time.perf_counter()
-            if self._timeline is not None or self._straggler is not None
-            else 0.0
-        )
+        t_step = time.perf_counter() if self._straggler is not None else 0.0
         if self._rides:
             # a riding lane's row starts from its last token, host-side
             self._drain_inflight()
@@ -2518,10 +2544,13 @@ class JaxServingEngine(AsyncEngine):
         chunk = self._chunk_dispatch(paced, t_step)
         decode = (
             None if self._rides and chunk is not None
-            else self._decode_dispatch(profile=False)
+            else self._decode_dispatch()
         )
         if chunk is not None:
+            self._clock.steps[0] += 1
             self._chunk_finish(chunk)
+        elif decode is not None:
+            self._clock.steps[1] += 1
         prev = decode[0] if decode is not None else None
         if prev is not None:
             self._process_chunk(prev, defer_free=True)
@@ -2535,9 +2564,39 @@ class JaxServingEngine(AsyncEngine):
         / chunk) dispatches. Where `_rides`, the lanes that decode are rows
         too, one token each. Returns the dispatch for `_chunk_finish`, or
         None when no lane takes a prompt token (all budgeted out)."""
+        with self._clock(P_CHUNK_BUILD):
+            built = self._chunk_build(paced)
+        if built is None:
+            return None
+        fn, args, want_pen, fed, filled = built
+        tl, clock = self._timeline, self._clock
+        with clock(P_CHUNK_DISPATCH if clock.compile_key is None else P_COMPILE):
+            *fetch, counts_out = self._take_state(fn(*args))
+            clock.dispatched(0)
+        # copy_to_host_async right after dispatch: started here, the
+        # device→host copy overlaps the chunk's own compute instead of
+        # starting cold at get time (the saving is not measured on the
+        # current machine)
+        for arr in fetch:
+            arr.copy_to_host_async()
+        sealing = self._take_sealing(filled)
+        # the counts go on now, not when the result is fetched: the decode
+        # program of this host step takes them next
+        if want_pen:
+            self._counts = counts_out
+        else:
+            self._dummy_counts = counts_out
+            self._release_counts()
+        return _ChunkInflight(
+            tuple(fetch), fed, sealing, t_step, tl is not None and tl.should_sample()
+        )
+
+    def _chunk_build(self, paced: bool):
+        """The host's half of `_chunk_dispatch`: the program, its arguments
+        (host arrays put on the device) and what `_chunk_finish` needs; None
+        when no lane takes a prompt token."""
         cfg = self.config
         S, C, MB = cfg.max_slots, cfg.prefill_chunk, cfg.max_blocks_per_seq
-        tl = self._timeline
         pre = [
             i for i in range(S)
             if self._slots[i] is not None
@@ -2667,37 +2726,29 @@ class JaxServingEngine(AsyncEngine):
             self._put(ipack_np), self._put(fpack_np),
         ) + self._wd_args()
         self._slow_fault()
-        prof = tl is not None and tl.should_sample()
-        t_disp = time.perf_counter() if prof else 0.0
-        *fetch, counts_out = self._take_state(self._chunk(
-            want_lp, want_pen, want_sample, want_history, rows
-        )(*args))
-        # copy_to_host_async right after dispatch: started here, the
-        # device→host copy overlaps the chunk's own compute instead of
-        # starting cold at get time (the saving is not measured on the
-        # current machine)
-        for arr in fetch:
-            arr.copy_to_host_async()
-        sealing = self._take_sealing(filled)
-        # the counts go on now, not when the result is fetched: the decode
-        # program of this host step takes them next
-        if want_pen:
-            self._counts = counts_out
-        else:
-            self._dummy_counts = counts_out
-            self._release_counts()
-        return _ChunkInflight(tuple(fetch), fed, sealing, t_step, t_disp)
+        fn = self._chunk(want_lp, want_pen, want_sample, want_history, rows)
+        return fn, args, want_pen, fed, filled
 
     def _chunk_finish(self, chunk: _ChunkInflight) -> None:
         """Fetch a chunk dispatch's sampled tokens and hand each row's back
         to its lane: seal what it fed, and where the prompt is through, emit
         the first token. A lane that finishes here was inert in every decode
         dispatch still in flight, so its blocks are free at once."""
-        tl = self._timeline
-        # dynlint: allow-host-sync(leader sync: one fetch per chunk dispatch,
-        # overlapped by copy_to_host_async at dispatch)
-        fetched = jax.device_get(chunk.fetch)
-        t_fetch = time.perf_counter() if chunk.t_disp else 0.0
+        clock = self._clock
+        with clock(P_CHUNK_FETCH):
+            # dynlint: allow-host-sync(leader sync: one fetch per chunk dispatch,
+            # overlapped by copy_to_host_async at dispatch)
+            fetched = jax.device_get(chunk.fetch)
+            clock.fetched(0)
+        with clock(P_CHUNK_EMIT):
+            self._chunk_emit(chunk, fetched)
+        n_tokens = sum(len(toks) for _, _, toks in chunk.rows)
+        if chunk.prof:
+            self._note_dispatch("chunk", len(chunk.rows), n_tokens)
+        if self._straggler is not None:
+            self._straggler_tick("chunk", chunk.t_step, n_tokens)
+
+    def _chunk_emit(self, chunk: _ChunkInflight, fetched) -> None:
         if self._slot_model:
             *fetched, sums = fetched
             self._add_model_counters(sums)
@@ -2707,7 +2758,7 @@ class JaxServingEngine(AsyncEngine):
         for r, (lane, seq, toks) in enumerate(chunk.rows):
             if seq.slot != lane:
                 continue  # left its lane since the dispatch
-            self._seal_timed(seq.alloc, toks)
+            self.allocator.note_tokens_computed(seq.alloc, toks)
             tok = int(sampled_np[r])
             lpinfo = (
                 (float(lp_np[r]), tids_np[r], tlps_np[r])
@@ -2736,27 +2787,18 @@ class JaxServingEngine(AsyncEngine):
             seq.first_token_t = time.perf_counter()
             self._emit_token(seq, tok, lpinfo=lpinfo)
         self._sealing = None
-        n_tokens = sum(len(toks) for _, _, toks in chunk.rows)
-        if chunk.t_disp:
-            self._note_dispatch(
-                tl, "chunk", chunk.t_step, chunk.t_disp, t_fetch,
-                time.perf_counter(), batch=len(chunk.rows), tokens=n_tokens,
-            )
-        elif tl is not None:
-            # unsampled dispatch: drop the accrued allocator share so it
-            # can't pile up across the sampling stride and misattribute
-            self._prof_alloc_us = 0.0
-        if self._straggler is not None:
-            self._straggler_tick("chunk", chunk.t_step, n_tokens)
 
     def _prepare_lanes(self) -> None:
         """Before a host step dispatches anything: end the cancelled lanes
         and grow the decode lanes' allocations. Either can free blocks (a
         cancellation's, a preemption victim's), so whatever is in flight is
         drained first wherever that happens."""
+        with self._clock(P_PREPARE):
+            self._end_stopped_and_grow()
+
+    def _end_stopped_and_grow(self) -> None:
         cfg = self.config
         k = cfg.decode_steps
-        tl = self._timeline
         stopped = [s for s in self._slots if s is not None and s.ctx.context.is_stopped]
         if stopped:
             self._drain_inflight()
@@ -2768,7 +2810,6 @@ class JaxServingEngine(AsyncEngine):
         # and the next (speculative) chunk another k past that. Prefilling
         # lanes hold their whole prompt's blocks from admission and are no
         # row of the decode program: they neither grow nor dispatch there.
-        t_grow = time.perf_counter() if tl is not None else 0.0
         while True:
             ok = True
             for seq in [s for s in self._slots if s is not None]:
@@ -2795,10 +2836,6 @@ class JaxServingEngine(AsyncEngine):
                     break
             if ok:
                 break
-        if tl is not None:
-            # grow/evict/preempt work: the allocator share of this
-            # dispatch's host overhead
-            self._prof_alloc_us += (time.perf_counter() - t_grow) * 1e6
 
     def _live_lanes(self) -> List[Optional["_Seq"]]:
         """By slot, the sequences the decode program advances: a prefilling
@@ -2817,39 +2854,61 @@ class JaxServingEngine(AsyncEngine):
         writes, so their allocations are parked in ``_zombie_allocs`` and
         freed only once the in-flight chunk has been fetched."""
         tl = self._timeline
-        t_step = (
-            time.perf_counter()
-            if tl is not None or self._straggler is not None else 0.0
-        )
+        t_step = time.perf_counter() if self._straggler is not None else 0.0
         self._prepare_lanes()
-        decode = self._decode_dispatch(profile=True)
+        decode = self._decode_dispatch()
         if decode is None:
             return
-        prev, n_active, t_disp, t_fetch = decode
+        self._clock.steps[1] += 1
+        prev, n_active = decode
         if prev is not None:
             self._process_chunk(prev, defer_free=True)
         k = self.config.decode_steps
-        if t_disp:
-            self._note_dispatch(
-                tl, "decode", t_step, t_disp, t_fetch, time.perf_counter(),
-                batch=n_active, tokens=n_active * k,
-            )
-        elif tl is not None:
-            self._prof_alloc_us = 0.0
+        if tl is not None and tl.should_sample():
+            self._note_dispatch("decode", n_active, n_active * k)
         if self._straggler is not None:
             self._straggler_tick("decode", t_step, n_active * k)
 
-    def _decode_dispatch(
-        self, profile: bool
-    ) -> Optional[Tuple[Optional[_Inflight], int, float, float]]:
+    def _decode_dispatch(self) -> Optional[Tuple[Optional[_Inflight], int]]:
         """Dispatch the decode program over the live lanes (after
         `_prepare_lanes`) and return (the dispatch it displaced, still to be
-        processed; live lanes; the profiled dispatch's two clock readings,
-        0.0 when it is not sampled). None when nothing was dispatched: no
-        lane decodes, or none needs more than what is in flight."""
+        processed; live lanes). None when nothing was dispatched: no lane
+        decodes, or none needs more than what is in flight."""
+        clock = self._clock
+        with clock(P_DECODE_BUILD):
+            built = self._decode_build()
+        if built is None:
+            return None
+        fn, args, want_lp, want_pen, live, filled, n_active = built
+        with clock(P_DECODE_DISPATCH if clock.compile_key is None else P_COMPILE):
+            *done, counts_out = self._take_state(fn(*args))
+            clock.dispatched(1)
+        sums = done.pop() if self._slot_model else None
+        out, *lp_out, toks2, pos2 = done
+        lps, tids, tlps = lp_out if want_lp else (None, None, None)
+        if want_pen:
+            self._counts = counts_out
+        else:
+            self._dummy_counts = counts_out
+            self._release_counts()
+        prev, self._inflight = (
+            self._inflight,
+            _Inflight(out, lps, tids, tlps, toks2, pos2, live,
+                      self._take_sealing(filled), sums),
+        )
+        # start the host copies now: by the time this chunk is processed (one
+        # pipelined dispatch later) the fetch has ridden the previous chunk's
+        # compute window and the blocking get is ~free (vs ~100 ms cold)
+        for arr in (out, lps, tids, tlps):
+            if arr is not None:
+                arr.copy_to_host_async()
+        return prev, n_active
+
+    def _decode_build(self):
+        """The host's half of `_decode_dispatch`: what is in flight drained
+        where the live set changed, then the program and its arguments."""
         cfg = self.config
         S, k = cfg.max_slots, cfg.decode_steps
-        tl = self._timeline
         live = self._live_lanes()
         if self._inflight is not None and any(
             a is not b for a, b in zip(self._inflight.lanes, live)
@@ -2965,39 +3024,8 @@ class JaxServingEngine(AsyncEngine):
             self._m_fpack.get(fpack_np),
         ) + self._wd_args()
         self._slow_fault()
-        prof = profile and tl is not None and tl.should_sample()
-        t_disp = time.perf_counter() if prof else 0.0
-        t_fetch = 0.0
-        *done, counts_out = self._take_state(
-            self._decode(want_lp, want_pen, want_sample)(*args)
-        )
-        sums = done.pop() if self._slot_model else None
-        out, *lp_out, toks2, pos2 = done
-        lps, tids, tlps = lp_out if want_lp else (None, None, None)
-        if prof:
-            # the profiling contract: block-until-ready device time for the
-            # SAMPLED dispatch (serializes this one dispatch of the
-            # pipeline; sample_every bounds the tax)
-            # dynlint: allow-host-sync(sampled profiling dispatch: device-time measurement)
-            jax.block_until_ready(out)
-            t_fetch = time.perf_counter()
-        if want_pen:
-            self._counts = counts_out
-        else:
-            self._dummy_counts = counts_out
-            self._release_counts()
-        prev, self._inflight = (
-            self._inflight,
-            _Inflight(out, lps, tids, tlps, toks2, pos2, live,
-                      self._take_sealing(filled), sums),
-        )
-        # start the host copies now: by the time this chunk is processed (one
-        # pipelined dispatch later) the fetch has ridden the previous chunk's
-        # compute window and the blocking get is ~free (vs ~100 ms cold)
-        for arr in (out, lps, tids, tlps):
-            if arr is not None:
-                arr.copy_to_host_async()
-        return prev, n_active, t_disp, t_fetch
+        fn = self._decode(want_lp, want_pen, want_sample)
+        return fn, args, want_lp, want_pen, live, filled, n_active
 
     def _emit_token_run(
         self,
@@ -3051,7 +3079,7 @@ class JaxServingEngine(AsyncEngine):
         # last token plus every emitted token bar the final one (in the
         # verify dispatch, matched drafts ARE the emitted prefix)
         fed0 = seq.generated[-1] if seq.generated else seq.prompt[-1]
-        self._seal_timed(seq.alloc, [fed0] + toks[:-1])
+        self.allocator.note_tokens_computed(seq.alloc, [fed0] + toks[:-1])
 
         log_probs = top_logprobs = None
         if lp_rows is not None and seq.logprobs is not None:
@@ -3092,33 +3120,33 @@ class JaxServingEngine(AsyncEngine):
                 sum(1 for s in chunk.lanes if s is not None),
                 self.config.max_slots,
             )
-        if chunk.lps is not None:
+        clock = self._clock
+        # the read of a pipeline being emptied (`_drain_inflight`) is the wait
+        # the host chose: `engine.drain`; what it emits is an emit as any other
+        with clock(P_DECODE_FETCH if defer_free else P_DRAIN):
             # dynlint: allow-host-sync(leader sync: pipelined fetch — the copy
             # rode the NEXT chunk's compute window, ~free by the time we get)
-            out, lps, tids, tlps = jax.device_get(
-                (chunk.out, chunk.lps, chunk.top_ids, chunk.top_lps)
+            out, lps, tids, tlps, sums = jax.device_get(
+                (chunk.out, chunk.lps, chunk.top_ids, chunk.top_lps, chunk.sums)
             )
-        else:
-            # dynlint: allow-host-sync(leader sync: pipelined fetch, see above)
-            out = jax.device_get(chunk.out)
-            lps = tids = tlps = None
+            clock.fetched(1)
         out = np.asarray(out)  # [S, k_steps]
-        if chunk.sums is not None:
-            # dynlint: allow-host-sync(pipelined fetch: the dispatch is done, `out` came with it)
-            self._add_model_counters(jax.device_get(chunk.sums))
-        self._sealing = chunk.sealing
-        for i, seq in enumerate(chunk.lanes):
-            if seq is None or seq.slot != i:
-                # not live in this dispatch (empty, or prefilling then: its
-                # row is garbage, not tokens), or finished in an earlier chunk
-                continue
-            self._emit_token_run(
-                seq,
-                [int(t) for t in out[i]],
-                (lps[i], tids[i], tlps[i]) if lps is not None else None,
-                defer_free=defer_free,
-            )
-        self._sealing = None
+        with clock(P_DECODE_EMIT):
+            if sums is not None:
+                self._add_model_counters(sums)
+            self._sealing = chunk.sealing
+            for i, seq in enumerate(chunk.lanes):
+                if seq is None or seq.slot != i:
+                    # not live in this dispatch (empty, or prefilling then: its
+                    # row is garbage, not tokens), or finished in an earlier chunk
+                    continue
+                self._emit_token_run(
+                    seq,
+                    [int(t) for t in out[i]],
+                    (lps[i], tids[i], tlps[i]) if lps is not None else None,
+                    defer_free=defer_free,
+                )
+            self._sealing = None
         if self._perf is not None:
             self._perf.note_decode(
                 self.total_generated_tokens - tokens_before,
@@ -3144,11 +3172,8 @@ class JaxServingEngine(AsyncEngine):
         workloads keep the non-speculative fast path."""
         cfg = self.config
         S = cfg.max_slots
-        tl = self._timeline
-        t_step = (
-            time.perf_counter()
-            if tl is not None or self._straggler is not None else 0.0
-        )
+        tl, clock = self._timeline, self._clock
+        t_step = time.perf_counter() if self._straggler is not None else 0.0
         # host needs every lane's true last token and the drafters need the
         # emitted suffix up to date before proposing
         self._drain_inflight()
@@ -3243,29 +3268,20 @@ class JaxServingEngine(AsyncEngine):
             self._m_ipack.get(ipack_np), self._m_fpack.get(fpack_np),
         ) + self._wd_args()
         self._slow_fault()
-        prof = tl is not None and tl.should_sample()
-        t_disp = time.perf_counter() if prof else 0.0
-        if want_lp:
-            tgt, lps, tids, tlps, self.cache, counts_out = self._verify(
-                True, want_pen, want_sample
-            )(*args)
-            for arr in (tgt, lps, tids, tlps):
-                arr.copy_to_host_async()
+        fn = self._verify(want_lp, want_pen, want_sample)
+        # a verify dispatch is a decode dispatch to the clock: its phases
+        with clock(P_DECODE_DISPATCH if clock.compile_key is None else P_COMPILE):
+            *fetch, self.cache, counts_out = fn(*args)
+            clock.dispatched(1)
+        with clock(P_DECODE_FETCH):
             # dynlint: allow-host-sync(leader sync: one fetch per verify
             # dispatch — acceptance decides the next dispatch's inputs, so
             # this path is deliberately not pipelined)
-            tgt_np, lp_np, tids_np, tlps_np = jax.device_get(
-                (tgt, lps, tids, tlps)
-            )
-        else:
-            tgt, self.cache, counts_out = self._verify(
-                False, want_pen, want_sample
-            )(*args)
-            tgt.copy_to_host_async()
-            # dynlint: allow-host-sync(leader sync: one fetch per verify dispatch)
-            tgt_np = np.asarray(jax.device_get(tgt))
-            lp_np = tids_np = tlps_np = None
-        t_fetch = time.perf_counter() if prof else 0.0
+            tgt_np, *lp_nps = jax.device_get(fetch)
+            clock.fetched(1)
+        tgt_np = np.asarray(tgt_np)
+        lp_np, tids_np, tlps_np = lp_nps if want_lp else (None, None, None)
+        clock.steps[1] += 1
         if want_pen:
             self._counts = counts_out
         else:
@@ -3330,16 +3346,9 @@ class JaxServingEngine(AsyncEngine):
                 self.total_generated_tokens - tokens_before, 1
             )
             self._perf.note_spec(drafted_total, accepted_total)
-        if prof:
-            self._note_dispatch(
-                tl, "verify", t_step, t_disp, t_fetch, time.perf_counter(),
-                batch=sum(1 for s in self._slots if s is not None),
-                tokens=accepted_total + sum(
-                    1 for s in self._slots if s is not None
-                ),
-            )
-        elif tl is not None:
-            self._prof_alloc_us = 0.0
+        if tl is not None and tl.should_sample():
+            n_live = sum(1 for s in self._slots if s is not None)
+            self._note_dispatch("verify", n_live, accepted_total + n_live)
         if self._straggler is not None:
             self._straggler_tick(
                 "verify", t_step,
@@ -3613,11 +3622,12 @@ class JaxServingEngine(AsyncEngine):
         the dispatch being processed had these blocks' pages taken as it
         was dispatched (_take_sealing), their bytes are on the host already."""
         ahead = self._sealing
-        if ahead is not None and all(b in ahead.where for b in block_ids):
-            return kv_pages.checksums(kv_pages.select(
-                ahead.host(), [ahead.where[b] for b in block_ids]
-            ))
-        return kv_pages.checksums(kv_pages.to_host(kv_pages.take(self.cache, block_ids)))
+        with self._clock(P_SEAL_CRC):
+            if ahead is not None and all(b in ahead.where for b in block_ids):
+                return kv_pages.checksums(kv_pages.select(
+                    ahead.host(), [ahead.where[b] for b in block_ids]
+                ))
+            return kv_pages.checksums(kv_pages.to_host(kv_pages.take(self.cache, block_ids)))
 
     def _take_sealing(self, filled: List[int]) -> Optional[_SealPages]:
         """Enqueue, behind the program just dispatched, the read of the blocks
@@ -3628,9 +3638,10 @@ class JaxServingEngine(AsyncEngine):
         if not self._seal_checksums or not 0 < n <= max(self._sealing_sizes, default=0):
             return None
         size = next(b for b in self._sealing_sizes if b >= n)
-        pages = kv_pages.take(self.cache, filled + filled[-1:] * (size - n))
-        for a in pages.values():
-            a.copy_to_host_async()
+        with self._clock(P_SEAL_READ):
+            pages = kv_pages.take(self.cache, filled + filled[-1:] * (size - n))
+            for a in pages.values():
+                a.copy_to_host_async()
         return _SealPages(pages, {b: j for j, b in enumerate(filled)})
 
     def _blocks_filled(self, alloc: SequenceAllocation, start: int, n: int) -> List[int]:
@@ -4177,6 +4188,17 @@ class JaxServingEngine(AsyncEngine):
             "num_requests_waiting": len(self._pending) + len(self._awaiting),
             "gpu_cache_usage_perc": self.allocator.usage(),
             "gpu_prefix_cache_hit_rate": self.allocator.hit_tokens / probe,
+            # the same two since boot, as they are: read by difference
+            "prefix_hit_tokens": self.allocator.hit_tokens,
+            "prefix_probe_tokens": self.allocator.probe_tokens,
+            # the engine thread's time by phase (self time), the part of it in
+            # which nothing was in flight while a slot was active, host steps
+            # by what they dispatched, the longest stall, all cumulative
+            # (runtime/profiling.py:PhaseClock), and start-up's seconds by phase
+            **self._clock.snapshot(),
+            "queue_wait_us_sum": int(self.queue_wait_us_sum),
+            "queue_wait_count": self.queue_wait_count,
+            "setup_phase_s": profiling_mod.setup_phase_s(),
             # shared in-flight prefill registry (reserved.rs parity):
             # deferrals onto a concurrent identical prefix + tokens saved
             "inflight_prefill_waits": self.allocator.inflight_waits,
@@ -4314,6 +4336,8 @@ def build_jax_serving_engine(
 
     model_config = config_from_card(card)
     param_shardings = module_for(model_config).param_shardings
+    setup = profiling_mod.setup_clock(jax.profiler.TraceAnnotation)
+    setup.switch(profiling_mod.S_WEIGHTS)
 
     mesh = None
     mesh_cfg = MeshConfig(
@@ -4341,6 +4365,7 @@ def build_jax_serving_engine(
             ),
         )
 
+    setup.switch(profiling_mod.S_ENGINE)  # the pool and the state: until `warmup`
     engine_config = EngineConfig(
         max_slots=max_batch_size,
         kv_block_size=kv_block_size,

@@ -168,6 +168,7 @@ class HttpService:
             self.port = sock.getsockname()[1]
             break
         logger.info("HTTP service listening on %s:%d", self.host, self.port)
+        profiling.setup_done()  # a start-up that was timed ends here
         if self._fcpu is not None and self._lag_sampler is None:
             # event-loop lag: the direct saturation signal of a frontend
             # process (docs/observability.md §Profiling); one sampler per

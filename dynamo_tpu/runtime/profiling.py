@@ -14,12 +14,20 @@ missing layer (docs/observability.md §Profiling):
   no event-loop lag sampler is ever constructed (tests monkeypatch the
   constructors to prove it), and the engine step loop pays one attribute
   check per dispatch.
+- **PhaseClock** — the ONE clock of the engine thread (and, as a second
+  instance, of the main thread's start-up): entering a phase opens a
+  profiler annotation (a span on the host plane of the device trace, on
+  the profiler's clock) and charges the time since the last transition to
+  the phase that was innermost, so a phase's counter is its SELF time.
+  Always on: no knob; "tracing off" is "no profiler session". Counters
+  reach ``/debug/engine`` (``host_phase_us``, ``host_starved_us``, …).
 - **StepTimeline** — a process-global, thread-safe ring of per-dispatch
-  records fed by the engine step loop: phase (prefill ``chunk`` /
-  ``decode`` / ``verify``), batch shape, *block-until-ready device time*
-  vs *host-side dispatch overhead* (split again into pre-dispatch build
-  and post-fetch emit work), allocator time (alloc/grow/evict/
-  seal-checksum ride one accumulator), per-step queue depths, and the
+  records fed by the engine step loop from the phase clock: phase
+  (prefill ``chunk`` / ``decode`` / ``verify``), batch shape, *device
+  time as the host observed it* (dispatch → its blocking read returned,
+  never forced) vs *host-side dispatch overhead* (split again into
+  pre-dispatch build and post-fetch emit work), allocator time
+  (``alloc`` + ``seal_crc`` of the host step), per-step queue depths, and the
   request/trace ids (PR5) riding the batch — plus ``jit_compile`` events
   with the triggering variant/shape detail. A decode-roofline decay like
   BENCH_r05's 0.31→0.17 becomes readable as "device idle between
@@ -33,18 +41,18 @@ missing layer (docs/observability.md §Profiling):
   phase, one per event loop, slice args carrying the PR5 ids), served by
   ``GET /debug/profile`` and ``llmctl profile capture --trace``.
 
-Sampling: timing a dispatch costs a handful of ``perf_counter`` calls
-plus one ``block_until_ready`` on the dispatch outputs (which, in
-pipelined decode, serializes that one dispatch). ``sample_every`` bounds
-the tax — only every Nth dispatch is timed; untimed dispatches still
-count into ``dispatches_total`` so ``device_idle_frac`` stays honest
-about coverage.
+Sampling: a sampled dispatch costs one record built from the phase
+clock's counters; nothing waits for the device that the served path does
+not wait for anyway (the forced ``block_until_ready`` of the sampled
+decode dispatch went with PR 39). ``sample_every`` bounds the ring's
+churn; untimed dispatches still count into ``dispatches_total``.
 """
 
 from __future__ import annotations
 
 import json
 import logging
+import os
 import threading
 import time
 from collections import deque
@@ -80,10 +88,8 @@ class ProfilePolicy:
     ``enabled``       DYN_TPU_PROFILE (default OFF — 1 arms the plane;
                       0/unset is the zero-overhead gate: nothing is ever
                       constructed).
-    ``sample_every``  time every Nth engine dispatch (clamped to
-                      [1, 1_000_000]; 1 = every dispatch — exact but the
-                      block-until-ready serializes pipelined decode, so
-                      production captures want the default 8).
+    ``sample_every``  record every Nth engine dispatch (clamped to
+                      [1, 1_000_000]; 1 = every dispatch).
     ``ring_size``     dispatch/event records retained (clamped to
                       [256, 262_144]).
     ``lag_ms``        event-loop lag sampler interval in ms (clamped to
@@ -153,15 +159,15 @@ class StepTimeline:
     ``batch``      active lanes in the dispatch
     ``tokens``     tokens this dispatch advances (prefill feed or
                    batch × decode_steps)
-    ``host_us``    host-side build time up to the jit call (alloc time
-                   included; the "dispatch overhead" half of the split)
-    ``device_us``  jit call → outputs ready (block-until-ready; the
-                   device half)
-    ``post_us``    host-side fetch/emit work after the outputs were
-                   ready (still dispatch overhead, but attributable to
-                   token processing, not building)
-    ``alloc_us``   allocator share of host_us (alloc/grow/evict/
-                   seal-checksum accumulated since the last record)
+    ``host_us``    the host step's ``*_build`` self time (the "dispatch
+                   overhead" half of the split)
+    ``device_us``  jit call → its blocking read returned, as the host
+                   step observed it (never forced: in pipelined decode
+                   it is the dispatch before, read in this step, and an
+                   upper bound of the device's own time)
+    ``post_us``    the host step's ``*_emit`` self time (still dispatch
+                   overhead, but attributable to token processing)
+    ``alloc_us``   the host step's ``alloc`` + ``seal_crc`` self time
     ``queue``      pending + awaiting-remote-prefill depth at dispatch
     ``reqs``       up to 8 request ids riding the batch (PR5 link)
     ``traces``     their trace ids when tracing is on (PR5 link)
@@ -366,6 +372,207 @@ class StepTimeline:
 
 
 # ---------------------------------------------------------------------------
+# the phase clock: the host's time by name
+# ---------------------------------------------------------------------------
+
+# phases of a host step, by index (engine_jax/engine.py names them P_*); a
+# span is "engine." + the name with its underscore a dot
+ENGINE_PHASES = (
+    "step", "wait", "posted", "sweep", "spills", "admit", "alloc", "prepare",
+    "chunk_build", "chunk_dispatch", "chunk_fetch", "chunk_emit",
+    "decode_build", "decode_dispatch", "decode_fetch", "decode_emit",
+    "drain", "seal_read", "seal_crc", "compile",
+)
+(P_STEP, P_WAIT, P_POSTED, P_SWEEP, P_SPILLS, P_ADMIT, P_ALLOC, P_PREPARE,
+ P_CHUNK_BUILD, P_CHUNK_DISPATCH, P_CHUNK_FETCH, P_CHUNK_EMIT,
+ P_DECODE_BUILD, P_DECODE_DISPATCH, P_DECODE_FETCH, P_DECODE_EMIT,
+ P_DRAIN, P_SEAL_READ, P_SEAL_CRC, P_COMPILE) = range(len(ENGINE_PHASES))
+# phases of a start-up, main thread (S_*); spans "setup.<name>"
+SETUP_PHASES = (
+    "before_main", "devices", "weights", "engine", "lower", "compile",
+    "sealing", "http",
+)
+(S_BEFORE_MAIN, S_DEVICES, S_WEIGHTS, S_ENGINE, S_LOWER, S_COMPILE,
+ S_SEALING, S_HTTP) = range(len(SETUP_PHASES))
+
+
+class PhaseClock:
+    """One thread's time, by phase. ``with clock(i):`` enters phase ``i`` of
+    ``names``; every transition reads the clock once and charges the time
+    since the last one to the phase that was innermost, so ``us[i]`` is
+    phase ``i``'s SELF time and the counters sum to the time since
+    ``start()``. With ``annotate`` (``jax.profiler.TraceAnnotation``, handed
+    over by whoever has jax: this module stays free of it) a phase is also a
+    span on the profiler's clock, recorded only while a session runs.
+
+    ``in_flight`` counts dispatches whose blocking read has not returned
+    (``dispatched`` / ``fetched``); while it is 0 and ``active`` (the engine
+    holds a request in a slot) the time is ALSO charged to ``starved_us``: a
+    lower bound of the device's idle time, by what the host was doing. A
+    stretch over ``stall_s`` between two transitions, outside ``wait``, is a
+    stall: one log line and ``stall``. Not thread-safe: its thread alone
+    writes; ``snapshot`` may be read from another (a torn read costs one
+    stretch in one snapshot, nothing cumulative)."""
+
+    __slots__ = (
+        "names", "spans", "us", "starved_us", "annotate", "step_annotate",
+        "in_flight", "active", "steps", "stall", "compile_key", "device_us",
+        "step_num", "t_step", "_clock", "_t0", "_mark", "_cur", "_stack",
+        "_open", "_next", "_base", "_issued", "_wait", "_compile", "_stall_us",
+    )
+
+    def __init__(self, names, prefix, annotate=None, step_annotate=None,
+                 stall_s=float("inf"), clock=time.perf_counter):
+        self.names = tuple(names)
+        self.spans = tuple(prefix + n.replace("_", ".") for n in self.names)
+        self.us = [0.0] * len(self.names)
+        self.starved_us = [0.0] * len(self.names)
+        self.annotate, self.step_annotate = annotate, step_annotate
+        self.in_flight, self.active = 0, False
+        self.steps = [0, 0]  # host steps that dispatched a chunk; a decode alone
+        self.stall = {"count": 0, "longest_ms": 0.0, "phase": None, "step": None}
+        self.compile_key = None  # set where a step program is built, served or not
+        self.device_us = [0.0, 0.0]  # last dispatch -> read returned: chunk, decode
+        self.step_num = 0
+        self._clock = clock
+        self._t0 = self._mark = self.t_step = clock()
+        self._cur, self._stack, self._open, self._next = 0, [], [], 0
+        self._base = list(self.us)
+        self._issued = (deque(), deque())
+        self._wait = self.names.index("wait") if "wait" in self.names else -1
+        self._compile = self.names.index("compile") if "compile" in self.names else -1
+        self._stall_us = stall_s * 1e6
+
+    def start(self) -> None:
+        """The thread whose time this is starts (again) now: what passed
+        since the last transition is nobody's."""
+        now = self._clock()
+        self._t0 += now - self._mark
+        self._mark = now
+
+    def _charge(self) -> float:
+        now = self._clock()
+        d, cur = (now - self._mark) * 1e6, self._cur
+        self._mark = now
+        self.us[cur] += d
+        if cur != self._wait:
+            if self.active and not self.in_flight:
+                self.starved_us[cur] += d
+            if d > self._stall_us:
+                st = self.stall
+                st["count"] += 1
+                if d / 1e3 > st["longest_ms"]:
+                    st.update(longest_ms=round(d / 1e3, 1),
+                              phase=self.names[cur], step=self.step_num)
+                logger.warning("host stall: %.0f ms in %s at step %d",
+                               d / 1e3, self.spans[cur], self.step_num)
+        return now
+
+    def __call__(self, i: int) -> "PhaseClock":
+        self._next = i
+        return self
+
+    def step(self, n: int) -> "PhaseClock":
+        """The root of one host step's tree (phase 0, a step annotation)."""
+        self._next, self.step_num = 0, n
+        return self
+
+    def __enter__(self) -> "PhaseClock":
+        now, i, span = self._charge(), self._next, None
+        self._stack.append(self._cur)
+        self._cur = i
+        if i == 0:
+            self.t_step, self._base = now, list(self.us)
+            if self.step_annotate is not None:
+                span = self.step_annotate(self.spans[0], step_num=self.step_num)
+        elif i == self._compile and self.compile_key is not None:
+            key, self.compile_key = self.compile_key, None
+            logger.warning("a step program compiles on the served path at "
+                           "step %d: %s", self.step_num, key)
+            if self.annotate is not None:
+                span = self.annotate(self.spans[i] + ":" + key)
+        elif self.annotate is not None:
+            span = self.annotate(self.spans[i])
+        if span is not None:
+            span.__enter__()
+        self._open.append(span)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._charge()
+        self._cur = self._stack.pop()
+        span = self._open.pop()
+        if span is not None:
+            span.__exit__(None, None, None)
+        return False
+
+    def switch(self, i: Optional[int]) -> None:
+        """Phases in a row, none inside another (a start-up): leave the one
+        that is open and enter ``i``; None ends the row."""
+        if self._stack:
+            self.__exit__()
+        if i is not None:
+            self(i).__enter__()
+
+    def credit(self, i: int, us: float, out_of: Optional[int] = None) -> None:
+        """Time measured elsewhere (another thread's, the process's before
+        this clock) goes to phase ``i``, out of phase ``out_of``'s if given."""
+        self.us[i] += us
+        if out_of is not None:
+            self.us[out_of] -= us
+
+    def dispatched(self, kind: int) -> None:
+        """A step program was called (inside its dispatch phase): 0 a chunk,
+        1 a decode or verify dispatch."""
+        issued = self._mark  # where its dispatch phase began
+        self._charge()  # the call itself ran with one fewer in flight
+        self.in_flight += 1
+        self._issued[kind].append(issued)
+
+    def fetched(self, kind: int) -> None:
+        """The blocking read of the oldest such dispatch has returned."""
+        now = self._charge()
+        if self._issued[kind]:
+            self.in_flight -= 1
+            self.device_us[kind] = (now - self._issued[kind].popleft()) * 1e6
+
+    def step_us(self, i: int) -> float:
+        """Phase ``i``'s self time since the host step began."""
+        return self.us[i] - self._base[i]
+
+    def snapshot(self) -> Dict[str, Any]:
+        """Cumulative counters for ``/debug/engine``, the stretch in progress
+        included: read by difference between two snapshots."""
+        now, cur = self._clock(), self._cur
+        d = max((now - self._mark) * 1e6, 0.0)
+        us, starved = list(self.us), list(self.starved_us)
+        us[cur] += d
+        if cur != self._wait and self.active and not self.in_flight:
+            starved[cur] += d
+        return {
+            "uptime_us": round((now - self._t0) * 1e6),
+            "host_phase_us": {n: round(v) for n, v in zip(self.names, us)},
+            "host_starved_us": {n: round(v) for n, v in zip(self.names, starved)},
+            "host_steps": {"prefill": self.steps[0], "decode": self.steps[1]},
+            "host_stall": dict(self.stall),
+        }
+
+
+def process_age_us() -> Optional[float]:
+    """Microseconds since the kernel started this process (interpreter
+    start-up and imports, when asked at the top of ``main``): field 22 of
+    ``/proc/self/stat`` against the boot clock. None where there is no such
+    file."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        return (time.clock_gettime(time.CLOCK_BOOTTIME)
+                - ticks / os.sysconf("SC_CLK_TCK")) * 1e6
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+
+
+# ---------------------------------------------------------------------------
 # the frontend-side hot-path accounting
 # ---------------------------------------------------------------------------
 
@@ -469,6 +676,7 @@ class EventLoopLagSampler:
 # ---------------------------------------------------------------------------
 
 _TIMELINE: Optional[StepTimeline] = None
+_SETUP: Optional[PhaseClock] = None
 _FRONTEND: Optional[FrontendCpu] = None
 _LAG: Optional[EventLoopLagSampler] = None
 _LOCK = threading.Lock()
@@ -484,6 +692,36 @@ def timeline() -> StepTimeline:
             if _TIMELINE is None:
                 _TIMELINE = StepTimeline()
     return _TIMELINE
+
+
+def setup_clock(annotate=None) -> PhaseClock:
+    """The process's start-up clock (main thread; ``cli/run.py``, the
+    engine's ``warmup``), constructed on first use. Whoever has jax hands
+    over ``jax.profiler.TraceAnnotation`` for its spans."""
+    global _SETUP
+    if _SETUP is None:
+        with _LOCK:
+            if _SETUP is None:
+                _SETUP = PhaseClock(SETUP_PHASES, "setup.")
+    if annotate is not None:
+        _SETUP.annotate = annotate
+    return _SETUP
+
+
+def setup_done() -> None:
+    """Start-up ends where the port answers. Constructor-free: a process
+    that timed no start-up has nothing to end."""
+    if _SETUP is not None:
+        _SETUP.switch(None)
+
+
+def setup_phase_s() -> Dict[str, float]:
+    """``setup_phase_s`` of ``/debug/engine``: seconds by start-up phase
+    (constructor-free: empty where nothing timed a start-up)."""
+    c = _SETUP
+    if c is None:
+        return {}
+    return {n: round(v / 1e6, 3) for n, v in zip(c.names, c.us)}
 
 
 def maybe_timeline() -> Optional[StepTimeline]:
@@ -598,8 +836,9 @@ def render_frontend_prometheus(prefix: str = "dynamo_frontend") -> str:
 def reset_for_tests() -> None:
     """Drop the process-global state (conftest autouse reset: one test's
     records/lag samples must not bleed into another's assertions)."""
-    global _TIMELINE, _FRONTEND, _LAG
+    global _TIMELINE, _FRONTEND, _LAG, _SETUP
     with _LOCK:
+        _SETUP = None
         if _LAG is not None:
             _LAG._starts = 0  # force past the refcount: tests must not leak
             if _LAG._task is not None:

@@ -378,11 +378,11 @@ def test_a_finished_lanes_blocks_wait_for_the_dispatch_in_flight_when_a_chunk_go
     seen = {}
     dispatch = eng._decode_dispatch
 
-    def watched(profile):
+    def watched():
         # the chunk has been dispatched by now, the decode dispatch not yet drained
         seen["parked"] = [set(z.block_ids) for z in eng._zombie_allocs]
         seen["chunks"] = sum(eng.chunk_dispatches_by_rows.values())
-        return dispatch(profile)
+        return dispatch()
 
     eng._decode_dispatch = watched
     chunks = sum(eng.chunk_dispatches_by_rows.values())

@@ -310,11 +310,15 @@ class TestEngineProfiling:
         # PR5 link: the batch's request id and trace id ride the record
         assert any(ctx.id in r.get("reqs", []) for r in recs)
         assert any(span.trace_id in r.get("traces", []) for r in recs)
-        # the split must cover the gap between adjacent sampled dispatches
-        # (±10% on a quiet box; allow slack for CI noise — the bench
-        # profiling section reports the tight number)
+        # the split, as the phase clock feeds it (PR 39): ``host_us`` and
+        # ``post_us`` are self time INSIDE one host step, so between adjacent
+        # sampled dispatches they never add up to more than the gap;
+        # ``device_us`` is dispatch -> its blocking read returned as the host
+        # observed it, never forced: in pipelined decode the read of dispatch
+        # N returns in host step N + 1, so it spans up to two steps and is an
+        # upper bound of the device's own time
         recs.sort(key=lambda r: r["ts"])
-        span_s = busy = 0.0
+        span_s = host = device = 0.0
         for a, b in zip(recs, recs[1:]):
             if b["step"] - a["step"] != 1:
                 continue
@@ -322,10 +326,13 @@ class TestEngineProfiling:
             if gap <= 0:
                 continue
             span_s += gap
-            busy += (a["host_us"] + a["device_us"] + a["post_us"]) / 1e6
+            host += (a["host_us"] + a["post_us"]) / 1e6
+            device += a["device_us"] / 1e6
         assert span_s > 0
-        cov = busy / span_s
-        assert 0.7 <= cov <= 1.05, f"device/host split covers {cov:.2f}"
+        assert 0.0 < host / span_s <= 1.0, f"host split covers {host / span_s:.2f}"
+        # (the first decode step reads nothing back: no dispatch before it)
+        assert all(r["device_us"] > 0 for r in recs[1:])
+        assert device / span_s <= 2.05, f"observed device time {device / span_s:.2f}"
         # events carry the compile detail (variant key + shapes)
         assert any(
             e["kind"] == "jit_compile" and "S=4" in e["detail"]
