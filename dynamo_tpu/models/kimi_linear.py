@@ -16,7 +16,8 @@ Two kinds of state live side by side:
   which is how a slot is reset when a request is admitted to it. Nothing
   outside this module indexes it, and ``pages.take`` / ``put`` never see it.
 
-Every mechanism is plain ``jax.numpy`` through XLA. The weights are bfloat16
+Every mechanism but two is plain ``jax.numpy`` through XLA (the expert layer's
+products and a chunk's KDA recurrence are kernels, below). The weights are bfloat16
 and the activations float32 from the embedding to the head, with every product
 against a weight taken at float32's precision (:func:`wdot` says why: a
 router's choice of 8 among 256 scores is a discontinuity, and bfloat16's
@@ -26,7 +27,10 @@ would serve bfloat16 and be a quarter faster a step; ROADMAP B7). The
 latent pages are float32 with them: a latent rounded to bfloat16 on its way
 into the pool was enough to swap an expert in three probes of fourteen on the
 chip (PERF.md, the model's section). The chunk form of the KDA
-recurrence is the recurrence itself, scanned over the chunk's tokens. The MLA
+recurrence is the recurrence itself, token by token, in ONE kernel a layer
+that holds a (row, head) pair's state on the chip from the row's first token
+to its last valid one (``ops/pallas/kda_scan.py``); a decode step, one token,
+is the same step in ``jax.numpy``: the shape picks the path. The MLA
 layer attends in the absorbed form: the query's no-position part goes through
 ``W_kvb``'s key half into the latent space, scores and the weighted sum are
 taken against the cached latent, and ``W_kvb``'s value half comes after; the
@@ -47,6 +51,7 @@ import jax.numpy as jnp
 
 from dynamo_tpu.models.llama import embed_lookup, rms_norm
 from dynamo_tpu.ops import moe
+from dynamo_tpu.ops.pallas.kda_scan import kda_scan
 
 Params = Dict[str, Any]
 KVCache = Dict[str, jax.Array]  # {"latent": [L_mla, N, bs, rank + rope]} float32
@@ -54,12 +59,15 @@ SlotState = Dict[str, Tuple[jax.Array, ...]]  # {"s": per KDA layer, "conv": ...
 
 # sums the step programs return, in this order (engine: /debug/engine)
 COUNTERS = ("moe_layer_calls", "moe_held_rows", "moe_experts_hit", "moe_routed_pairs",
-            "moe_rows_computed", "moe_expert_reads", "slot_state_resets")
+            "moe_rows_computed", "moe_expert_reads",
+            # what the chunks' KDA kernel advanced: valid tokens, and rows with one (each
+            # a read and a write of a row's state); summed over the KDA layers
+            "kda_chunk_tokens", "kda_state_passes", "slot_state_resets")
 # rows of a chunk computed at once: the rows are independent, and a chunk of
 # more is taken in groups, which bounds what the program holds beside its
 # arguments (64 rows at once: 3.7 GB of temporaries next to 9.4 GB)
 ROWS_AT_ONCE = 16
-MOE_COUNTERS = len(COUNTERS) - 1  # what ops/moe.py:dropless_experts counts
+MOE_COUNTERS = COUNTERS.index("kda_chunk_tokens")  # the first: what ops/moe.py:dropless_experts counts
 
 
 # -- products of float32 activations against bfloat16 weights ------------------
@@ -381,21 +389,18 @@ def _kda_output(lp: Params, c: KimiLinearConfig, o: jax.Array, gate: jax.Array):
 def kda_mixer(lp: Params, c: KimiLinearConfig, x: jax.Array, valid: jax.Array,
               s: jax.Array, conv_tail: jax.Array):
     """The KDA mixer over ``[B, T, E]`` normed inputs from the rows' state:
-    (output ``[B, T, E]``, state after the last valid token, new tails)."""
+    (output ``[B, T, E]``, state after the last valid token, new tails). One
+    token (a decode step) is :func:`_kda_step`; more (a chunk) are one call of
+    the kernel that keeps the state on the chip (outputs past a row's valid
+    tokens: zeros)."""
     q, k, v, log_decay, beta, gate, new_tail = _kda_inputs(lp, c, x, conv_tail, valid)
-
-    def token(s, xs):
-        q, k, v, log_decay, beta, ok = xs
-        new, o = _kda_step(s, q, k, v, log_decay, beta)
-        return jnp.where(ok[:, None, None, None], new, s), o
-
-    per_token = tuple(jnp.moveaxis(a, 1, 0) for a in (q, k, v, log_decay, beta, valid))
     if x.shape[1] == 1:
-        s, o = token(s, tuple(a[0] for a in per_token))
-        o = o[None]
+        new, o = _kda_step(s, q[:, 0], k[:, 0], v[:, 0], log_decay[:, 0], beta[:, 0])
+        s, o = jnp.where(valid[:, 0, None, None, None], new, s), o[:, None]
     else:
-        s, o = jax.lax.scan(token, s, per_token)
-    return _kda_output(lp, c, jnp.moveaxis(o, 0, 1), gate), s, new_tail
+        o, s = kda_scan(q, k, v, log_decay, beta, s, valid.sum(axis=1),
+                        interpret=jax.default_backend() == "cpu")
+    return _kda_output(lp, c, o, gate), s, new_tail
 
 
 # -- MLA ----------------------------------------------------------------------
@@ -436,8 +441,8 @@ def _swiglu(x, w_gate, w_up, w_down):
 
 
 def feed_forward(lp: Params, c: KimiLinearConfig, layer: int, x: jax.Array, valid: jax.Array):
-    """(output ``[B, T, E]``, the expert layer's counters: ``COUNTERS`` but the
-    last). The dense first layers count nothing."""
+    """(output ``[B, T, E]``, the expert layer's counters: the first
+    ``MOE_COUNTERS`` of ``COUNTERS``). The dense first layers count nothing."""
     if not is_expert_layer(c, layer):
         return _swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"]), jnp.zeros((MOE_COUNTERS,), jnp.int32)
     with jax.named_scope("moe"):
@@ -558,9 +563,11 @@ def _chunk_rows(params, config, tokens, positions, kv_cache, block_tables, state
         h = h + y
         counters = counters + stats
     h = rms_norm(h, params["final_norm"], c.rms_norm_eps)
-    resets = jnp.sum(fresh & (lanes < slots)).astype(jnp.int32)
+    resets = jnp.sum(fresh & (lanes < slots))
+    advanced = valid.sum(axis=1)  # a KDA layer's kernel advances each row by its valid tokens
+    own = jnp.stack([i_kda * advanced.sum(), i_kda * jnp.sum(advanced > 0), resets]).astype(jnp.int32)
     return (h, {"latent": pool}, {"s": tuple(s_out), "conv": tuple(conv_out)},
-            jnp.concatenate([counters, resets[None]]))
+            jnp.concatenate([counters, own]))
 
 
 def decode(
@@ -626,4 +633,4 @@ def decode(
     for j, lat in enumerate(fresh):  # [steps, S, D], written at `at` [steps, S]
         pool = _write_latent(pool, j, jnp.moveaxis(lat, 0, 1), at.T, block_tables)
     return (toks, pos, carry, out, {"latent": pool}, {"s": s_all, "conv": conv_all},
-            jnp.concatenate([counters, jnp.zeros((1,), jnp.int32)]))
+            jnp.concatenate([counters, jnp.zeros((len(COUNTERS) - MOE_COUNTERS,), jnp.int32)]))

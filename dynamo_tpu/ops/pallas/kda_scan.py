@@ -1,0 +1,149 @@
+"""A chunk of the KDA delta rule with the state held on the chip.
+
+``kda_scan(q, k, v, log_decay, beta, s0, n_valid)`` advances every (row, head)
+pair's ``[d, d]`` float32 state over the first ``n_valid[row]`` tokens of a
+``[B, T, H, d]`` chunk by ``models/kimi_linear.py:_kda_step``'s recurrence, in
+that function's operations and order, all float32 on the vector unit (no
+product on the MXU, which would round to bfloat16):
+
+    S = S * exp(log_decay)[:, None]           decay, a factor a key channel
+    u = v - k^T S
+    S = S + (beta k)[:, None] * u[None, :]    the rank-one update
+    o = S^T q
+
+A ``lax.scan`` over the tokens carries the rows' whole state through HBM once a
+token; here a grid step holds its pairs' state in the chip's fast memory, reads
+it once and writes it once after the row's last valid token. Valid tokens are a
+prefix of a row; positions past them are never computed and their outputs are
+zeros, and a row without a valid token gets its state back bit for bit.
+
+**The layout is the kernel.** The state lies key channel by sublane and value
+channel by lane, so both sums over the key channels are plain vector adds. What
+a token costs beside them is the spread of its decay, k and q, vectors along the
+key channels, along the lanes: one permute a register of 8 key channels on the
+cross-lane unit, and a head alone needs 48 a token, which take longer than its
+arithmetic (PERF.md, PR 40). So a grid step takes ``HEADS`` heads of a row
+together: a register's 128 lanes are ``HEADS`` heads x a slice of ``d / HEADS``
+value channels, the state of the group is ``HEADS`` such parts (one a slice),
+and ONE permute spreads a token's coefficient of all ``HEADS`` heads for all the
+parts (its source holds, head by head, ``d / HEADS`` tokens a head along the
+lanes). The parts are independent, which is also what keeps the vector unit
+busy while a part's sums finish. Bringing q, k, v, beta and the state into that
+layout and the outputs and the state back, and the exponential, are XLA's,
+around the kernel.
+
+On the TPU the kernel tiles heads of 128 channels and chunks of whole tiles of
+128 tokens; any other shape there is a ``ValueError`` that says so (interpreted
+on the CPU, as the tests run it, every shape goes: a chunk is padded to whole
+source registers).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128
+HEADS = 4  # heads of a row a grid step takes together, where the heads divide by it
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def kda_scan(
+    q: jax.Array,  # [B, T, H, d] float32, as is k, v and log_decay
+    k: jax.Array,
+    v: jax.Array,
+    log_decay: jax.Array,
+    beta: jax.Array,  # [B, T, H]
+    s0: jax.Array,  # [B, H, d, d] float32: the rows' state before the chunk
+    n_valid: jax.Array,  # [B] int32: a row's valid tokens, a prefix of it
+    *,
+    interpret: bool = False,
+) -> Tuple[jax.Array, jax.Array]:
+    """(outputs ``[B, T, H, d]``, zeros past a row's valid tokens; the state
+    after each row's last valid token ``[B, H, d, d]``)."""
+    b, t_in, h, d = q.shape
+    p = max(n for n in (HEADS, 2, 1) if h % n == 0 and d % n == 0)  # heads a step, parts of a state
+    seg = d // p  # value channels of a part, and tokens of a head in a source register
+    if not interpret and (d != LANES or t_in % LANES):
+        raise ValueError(
+            f"kda_scan on the TPU takes heads of {LANES} channels and chunks of whole tiles "
+            f"of {LANES} tokens, not a head of {d} and a chunk of {t_in}")
+    t = -(-t_in // seg) * seg
+    tile = t if interpret else LANES
+    groups = h // p
+
+    def kernel(n, q, k, decay, v, beta, s0, o, s):
+        at = pl.program_id(2)
+
+        @pl.when(at == 0)
+        def _():
+            s[...] = s0[...]
+
+        o[...] = jnp.zeros_like(o)
+        head = jax.lax.broadcasted_iota(jnp.int32, (d, d), 1) // seg * seg
+
+        def token(i, carry):
+            row = pl.ds(i, 1)
+            lane = head + i % seg  # where token i of each head lies in its source register
+            decay_i, k_i, q_i = (jnp.take_along_axis(ref[i // seg], lane, axis=1)
+                                 for ref in (decay, k, q))  # [d, d]: key channel x (head, any)
+            bk = beta[row, :] * k_i
+            for part in range(p):
+                state = s[part] * decay_i
+                ks = jnp.sum(k_i * state, axis=0, keepdims=True)  # k^T S: [1, d]
+                state = state + bk * (v[part, row, :] - ks)
+                o[part, row, :] = jnp.sum(q_i * state, axis=0, keepdims=True)
+                s[part] = state
+            return carry
+
+        tokens = jnp.clip(n[pl.program_id(0)] - at * tile, 0, tile)
+        # two tokens a trip: the second's permutes run under the first's arithmetic
+        jax.lax.fori_loop(0, tokens // 2, lambda i, c: token(2 * i + 1, token(2 * i, c)), 0)
+        jax.lax.fori_loop(tokens // 2 * 2, tokens, token, 0)
+
+    def by_lane(a):
+        """``[B, T, H, d]`` as ``[B, groups, T / seg, d, (head of the group, token)]``."""
+        a = a.reshape(b, t // seg, seg, groups, p, d)
+        return jnp.transpose(a, (0, 3, 1, 5, 4, 2)).reshape(b, groups, t // seg, d, d)
+
+    def by_row(a):
+        """``[B, T, H, d]`` as ``[B, groups, part, T, (head of the group, channel of the part)]``."""
+        a = a.reshape(b, t, groups, p, p, seg)
+        return jnp.transpose(a, (0, 2, 4, 1, 3, 5)).reshape(b, groups, p, t, d)
+
+    def parts_of(s):
+        """``[B, H, d, d]`` as ``[B, groups, part, d, (head, channel)]``, and back."""
+        s = s.reshape(b, groups, p, d, p, seg)
+        return jnp.transpose(s, (0, 1, 4, 3, 2, 5)).reshape(b, groups, p, d, d)
+
+    pad = ((0, 0), (0, t - t_in)) + ((0, 0),) * 2
+    q, k, v, decay = (jnp.pad(a, pad) for a in (q, k, v, jnp.exp(log_decay)))
+    beta = jnp.repeat(jnp.pad(beta, pad[:3]).reshape(b, t, groups, p), seg, axis=-1)  # [B, T, groups, d]
+
+    source = pl.BlockSpec((None, None, tile // seg, d, d), lambda i, j, a, n: (i, j, a, 0, 0))
+    rows = pl.BlockSpec((None, None, p, tile, d), lambda i, j, a, n: (i, j, 0, a, 0))
+    state = pl.BlockSpec((None, None, p, d, d), lambda i, j, a, n: (i, j, 0, 0, 0))
+    o, s = pl.pallas_call(
+        kernel,
+        out_shape=(jax.ShapeDtypeStruct((b, groups, p, t, d), jnp.float32),
+                   jax.ShapeDtypeStruct((b, groups, p, d, d), jnp.float32)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            in_specs=[source, source, source, rows,
+                      pl.BlockSpec((None, None, tile, d), lambda i, j, a, n: (i, j, a, 0)), state],
+            out_specs=(rows, state),
+            grid=(b, groups, t // tile),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="kda_scan",
+    )(n_valid.astype(jnp.int32), by_lane(q), by_lane(k), by_lane(decay), by_row(v),
+      jnp.transpose(beta, (0, 2, 1, 3)), parts_of(s0))
+    o = jnp.transpose(o.reshape(b, groups, p, t, p, seg), (0, 3, 1, 4, 2, 5)).reshape(b, t, h, d)
+    return o[:, :t_in], parts_of(s).reshape(b, h, d, d)

@@ -556,3 +556,35 @@ def test_the_expert_layer_compiles_at_the_cells_shapes_and_copies_no_expert(
     temp = compiled.memory_analysis().temp_size_in_bytes
     print(f"expert layer, {tokens} tokens: temp_size_in_bytes {temp}")
     assert temp < (1 << 20 if tokens == 64 else 1_200_000_000)
+
+
+# -- a chunk's KDA recurrence of kimi-linear-48b-a3b ----------------------------
+
+@pytest.mark.parametrize("rows", [8, 16])
+def test_a_chunks_kda_mixer_compiles_with_the_state_on_the_chip(monkeypatch, one_chip, rows):
+    """``models/kimi_linear.py:kda_mixer`` of ONE layer as a chunk group of
+    ``batch.kimi-linear-48b-a3b`` calls it (``rows`` x 128 tokens, 32 heads of
+    128, hidden 2,304, bf16 weights): ``ops/pallas/kda_scan.py`` is in the
+    compiled program (its tiling and its fast memory are what interpret mode
+    cannot see), and no loop carries the rows' ``f32[rows,32,128,128]`` state,
+    once a token through HBM, as the scan the kernel replaced did."""
+    from dynamo_tpu.models import kimi_linear as kl
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # as the expert layer's test
+    c = kl.KimiLinearConfig(num_layers=1, kda_layers=(1,), first_k_dense=1, vocab_size=256)
+    t, h, d = 128, c.kda_heads, c.kda_head_dim
+    lp = jax.eval_shape(lambda: kl.init_params(jax.random.PRNGKey(0), c))["layers"][0]
+
+    def sd(a, dtype=None):
+        shape = a.shape if hasattr(a, "shape") else a
+        return jax.ShapeDtypeStruct(shape, dtype or a.dtype, sharding=one_chip)
+
+    compiled = jax.jit(lambda lp, x, valid, s, tail: kl.kda_mixer(lp, c, x, valid, s, tail)).lower(
+        jax.tree.map(sd, lp), sd((rows, t, c.hidden_size), jnp.float32), sd((rows, t), jnp.bool_),
+        sd((rows, h, d, d), jnp.float32), sd((rows, c.conv_kernel - 1, 3 * c.kda_dim), jnp.float32),
+    ).compile()
+    hlo = re.sub(r"/\*.*?\*/", "", compiled.as_text())
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", hlo)) == 1
+    carried = [line.strip()[:160] for line in hlo.splitlines()
+               if re.search(r"\bwhile\(", line) and f"f32[{rows},{h},{d},{d}]" in line]
+    assert carried == [], carried

@@ -25,6 +25,7 @@ from dynamo_tpu.kv.pages import MigrationRejected, StateNotPortable
 from dynamo_tpu.models import kimi_linear as kl
 from dynamo_tpu.models import llama, module_for
 from dynamo_tpu.ops import moe
+from dynamo_tpu.ops.pallas.kda_scan import kda_scan
 
 from .test_chunk_rows import answer, run_out, step, submit
 
@@ -295,6 +296,84 @@ def test_a_card_that_says_num_experts_is_no_dense_impostor():
     assert isinstance(config_from_card(card({"model_type": "qwen2"})), llama.LlamaConfig)
 
 
+def scanned(q, k, v, log_decay, beta, s0, n_valid):
+    """What the kernel replaces: ``lax.scan`` of ``_kda_step`` over the tokens,
+    a row's state kept where its valid tokens end."""
+    def token(s, xs):
+        q, k, v, log_decay, beta, i = xs
+        new, o = kl._kda_step(s, q, k, v, log_decay, beta)
+        ok = i < n_valid
+        return jnp.where(ok[:, None, None, None], new, s), jnp.where(ok[:, None, None], o, 0.0)
+
+    per_token = tuple(jnp.moveaxis(a, 1, 0) for a in (q, k, v, log_decay, beta))
+    s, o = jax.lax.scan(token, s0, (*per_token, jnp.arange(q.shape[1])))
+    return jnp.moveaxis(o, 0, 1), s
+
+
+def assert_float32_equal(got, want):
+    """Equal to float32 rounding: ``rtol`` 1e-6 of an element, and of the
+    largest one where a sum cancelled to something small."""
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6 * float(jnp.abs(want).max()))
+
+
+def recurrence_inputs(rows, t=128, h=4, d=128, decay=(-1.6, -0.001), seed=0):
+    """Inputs as ``_kda_inputs`` makes them: unit keys, queries of length
+    ``d ** -0.5``, beta in (0, 1), log-decay in ``decay``."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q, k, v = (jax.random.normal(ks[i], (rows, t, h, d), jnp.float32) for i in range(3))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * d ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    log_decay = jax.random.uniform(ks[3], (rows, t, h, d), jnp.float32, *decay)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (rows, t, h), jnp.float32))
+    carried = jax.random.normal(ks[5], (rows, h, d, d), jnp.float32)
+    return (q, k, v, log_decay, beta), carried
+
+
+@pytest.mark.parametrize("rows", [8, 16])
+@pytest.mark.parametrize("start", ["zero", "carried"])
+def test_the_chunk_kernel_is_the_scanned_step(rows, start):
+    """Rows with 0, 1, 22 and 128 valid tokens of 128 in one call, at the
+    published head size: state and outputs are the scan's to float32 rounding,
+    outputs past a row's valid tokens are zeros, and a row without a valid
+    token gets its state back bit for bit."""
+    xs, carried = recurrence_inputs(rows, seed=rows)
+    s0 = carried if start == "carried" else jnp.zeros_like(carried)
+    n = jnp.asarray(([0, 1, 22, 128] * 4)[:rows], jnp.int32)
+    o, s = kda_scan(*xs, s0, n, interpret=True)
+    want_o, want_s = scanned(*xs, s0, n)
+    assert_float32_equal(o, want_o)
+    assert_float32_equal(s, want_s)
+    idle = np.asarray(n) == 0
+    assert np.array_equal(np.asarray(s)[idle], np.asarray(s0)[idle])
+    past = np.arange(128)[None, :] >= np.asarray(n)[:, None]
+    assert float(jnp.abs(jnp.where(past[:, :, None, None], o, 0.0)).max()) == 0.0
+
+
+def test_the_chunk_kernel_takes_a_chunk_that_is_no_whole_source_register():
+    """12 tokens of 2 heads of 16 channels (two heads a step, 8 tokens a source
+    register: the chunk is padded to 16), as a tiny engine's chunk may be."""
+    xs, carried = recurrence_inputs(3, t=12, h=2, d=16, seed=2)
+    n = jnp.asarray([12, 5, 0], jnp.int32)
+    o, s = kda_scan(*xs, carried, n, interpret=True)
+    want_o, want_s = scanned(*xs, carried, n)
+    assert o.shape == want_o.shape
+    assert_float32_equal(o, want_o)
+    assert_float32_equal(s, want_s)
+
+
+def test_the_chunk_kernel_stands_a_decay_of_minus_twenty_a_token():
+    """Log-decay down to -20 a token over all 128 tokens, whose sum over a block
+    no factored form holds in float32: the kernel decays token by token as
+    the step does, so nothing overflows and it is the scan still."""
+    xs, carried = recurrence_inputs(4, decay=(-20.0, -15.0), seed=5)
+    n = jnp.full((4,), 128, jnp.int32)
+    o, s = kda_scan(*xs, carried, n, interpret=True)
+    want_o, want_s = scanned(*xs, carried, n)
+    assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(s).all())
+    assert_float32_equal(o, want_o)
+    assert_float32_equal(s, want_s)
+
+
 def served(engine, prompt, max_tokens, **sampling):
     seq = submit(engine, prompt, max_tokens, **sampling)
     run_out(engine)
@@ -324,6 +403,18 @@ def test_the_engine_serves_the_reference_greedy_tokens_and_logprobs(engine, para
     assert snap["decode_history_tiles_read"] == snap["decode_history_tiles_full"] > 0
     tiers = list(snap["attention_tiers"].values())
     assert tiers and all(t == {"tier": "dense", "interpret": False} for t in tiers)
+
+
+def test_a_served_prompt_passes_its_state_once_a_chunk(engine):
+    """Through the engine (``prefill_chunk`` 16): a prompt of 40 tokens is three
+    chunk dispatches, and each of the four KDA layers reads and writes its
+    slot's state once a dispatch: 40 tokens advanced to 3 passes a layer."""
+    before = engine.metrics_snapshot()
+    served(engine, prompt_of(40, salt=11), 4)
+    after = engine.metrics_snapshot()
+    tokens = after["kda_chunk_tokens"] - before["kda_chunk_tokens"]
+    passes = after["kda_state_passes"] - before["kda_state_passes"]
+    assert (tokens, passes) == (4 * 40, 4 * 3)
 
 
 def test_a_reused_slot_gives_what_the_request_gives_alone(engine, cfg, params):

@@ -4,7 +4,9 @@
 the served shapes by history profile, the live form beside the full-width one
 (:func:`history_profiles`); ``... experts`` times the expert layer ALONE at
 ``batch.kimi-linear-48b-a3b``'s two shapes by routing and by grouped product
-(:func:`expert_profiles`); without a word, the round-5 ablation below.
+(:func:`expert_profiles`); ``... kda`` times a KDA layer's recurrence ALONE
+over a chunk, the scan over the tokens beside the kernel that keeps the state
+on the chip (:func:`kda_profiles`); without a word, the round-5 ablation below.
 
 Method notes:
 - every measurement chains computations via data dependencies and fences
@@ -526,5 +528,87 @@ def expert_profiles():
     moe.rows_per_tile, moe.grouped_product = own, products["kernel"]
 
 
+def chunk_valid_counts(rows: int, used: int, rng, chunk: int = 128) -> np.ndarray:
+    """Valid tokens of each row of a chunk dispatch in ``batch``'s traffic:
+    ``used`` rows hold one chunk (any of them, drawn) of a prompt of the
+    cell's lengths (lognormal, median 256, sigma 0.7, clipped 32-1,024), the
+    rest are the rung's padding."""
+    counts = np.zeros(rows, np.int32)
+    for row in range(used):
+        n = int(np.clip(np.exp(rng.normal(np.log(256), 0.7)), 32, 1024))
+        at = int(rng.integers(0, -(-n // chunk)))
+        counts[row] = min(chunk, n - at * chunk)
+    return counts
+
+
+def kda_profiles():
+    """The delta-rule recurrence of ONE KDA layer of ``kimi-linear-48b-a3b``
+    (32 heads of 128, float32) over a chunk of 128 tokens, from a carried
+    state: ``lax.scan`` of ``_kda_step`` over the tokens (what the chunk
+    program ran before PR 40: the rows' whole state through HBM once a token)
+    beside ``ops/pallas/kda_scan.py`` (once a chunk). At 8 and 16 rows with the
+    valid counts the cell's traffic gives a rung (5 and 12 rows hold a chunk of
+    a prompt), at 8 full rows and at 8 rows of padding (what the layout around
+    the kernel and a load and a store of the state cost); PROF_ITERS (default 8) layers chained in
+    one dispatch, each from the state the last one left. Also the largest
+    difference between the two, state and outputs, on this device. PROF_HEADS
+    (default 32) cuts the heads for a rehearsal on the CPU (interpreted)."""
+    from dynamo_tpu.engine_jax.compile_cache import enable_compile_cache
+    from dynamo_tpu.models import kimi_linear as kl
+    from dynamo_tpu.ops.pallas.kda_scan import kda_scan
+
+    enable_compile_cache()
+    n_iter = int(os.environ.get("PROF_ITERS", "8"))
+    t, h, d = 128, int(os.environ.get("PROF_HEADS", "32")), 128
+    kernel = functools.partial(kda_scan, interpret=jax.default_backend() == "cpu")
+
+    def scanned(q, k, v, log_decay, beta, s, n):
+        def token(s, xs):
+            q, k, v, log_decay, beta, i = xs
+            new, o = kl._kda_step(s, q, k, v, log_decay, beta)
+            return jnp.where((i < n)[:, None, None, None], new, s), o
+
+        per_token = tuple(jnp.moveaxis(a, 1, 0) for a in (q, k, v, log_decay, beta))
+        s, o = jax.lax.scan(token, s, (*per_token, jnp.arange(t)))
+        return jnp.where((jnp.arange(t) < n[:, None])[:, :, None, None], jnp.moveaxis(o, 0, 1), 0.0), s
+
+    def timed(fn, xs, s0, n):
+        @jax.jit
+        def chain(xs, s, n):
+            def layer(s, _):
+                o, s = fn(*xs, s, n)
+                return s, o[:, :, 0, 0]
+            return jax.lax.scan(layer, s, None, length=n_iter)
+
+        chain(xs, s0, n)[0].block_until_ready()
+        times = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            chain(xs, s0, n)[0].block_until_ready()
+            times.append(time.perf_counter() - t0)
+        return float(np.median(times)) * 1e3 / n_iter
+
+    rng = np.random.default_rng(0)
+    for rows, used in ((8, 5), (16, 12), (8, 8), (8, 0)):
+        key = jax.random.split(jax.random.PRNGKey(rows), 6)
+        q, k, v = (jax.random.normal(key[i], (rows, t, h, d), jnp.float32) for i in range(3))
+        q = q * jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True)) * d ** -0.5
+        k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True))
+        log_decay = jax.random.uniform(key[3], (rows, t, h, d), jnp.float32, -1.6, -0.1)
+        beta = jax.nn.sigmoid(jax.random.normal(key[4], (rows, t, h), jnp.float32))
+        s0 = jax.random.normal(key[5], (rows, h, d, d), jnp.float32)
+        xs = (q, k, v, log_decay, beta)
+        n = jnp.asarray(np.full(rows, t, np.int32) if used == rows
+                        else chunk_valid_counts(rows, used, rng))
+        (o_scan, s_scan), (o_kernel, s_kernel) = jax.jit(scanned)(*xs, s0, n), kernel(*xs, s0, n)
+        ms_scan, ms_kernel = timed(scanned, xs, s0, n), timed(kernel, xs, s0, n)
+        print(f"kda {rows:2d} rows, valid {np.asarray(n).tolist()} ({int(n.sum())} tokens): "
+              f"scan {ms_scan:7.3f} ms a layer, kernel {ms_kernel:7.3f}; largest difference "
+              f"state {float(jnp.abs(s_kernel - s_scan).max()):.3g} of {float(jnp.abs(s_scan).max()):.3g}, "
+              f"outputs {float(jnp.abs(o_kernel - o_scan).max()):.3g} of "
+              f"{float(jnp.abs(o_scan).max()):.3g}", flush=True)
+
+
 if __name__ == "__main__":
-    {"history": history_profiles, "experts": expert_profiles}.get(" ".join(sys.argv[1:2]), main)()
+    {"history": history_profiles, "experts": expert_profiles, "kda": kda_profiles}.get(
+        " ".join(sys.argv[1:2]), main)()
