@@ -74,8 +74,6 @@ from dynamo_tpu.llm.protocols.common import (
 from dynamo_tpu.models import module_for
 from dynamo_tpu.models.llama import (
     LlamaConfig,
-    chunk_history_tiles,
-    decode_history_tiles,
     dequantize_kv,
     flush_window,
     forward,
@@ -2699,13 +2697,12 @@ class JaxServingEngine(AsyncEngine):
         )
         if want_history and self._pp == 1 and self._sp == 1:
             bs = cfg.kv_block_size
-            full = history_tiles_full(bs, MB)
-            # a slot model's chunk attends every row's whole table, whatever
-            # it holds (models/kimi_linear.py: mla_attend)
-            self.chunk_history_tiles_read += full if self._slot_model else int(
-                chunk_history_tiles(positions, bs, MB)
+            # what the chunk program reads of the tables is its module's to
+            # say: the tiles up to the longest history, or every table whole
+            self.chunk_history_tiles_read += int(
+                self.model.chunk_history_tiles(positions, bs, MB)
             )
-            self.chunk_history_tiles_full += full
+            self.chunk_history_tiles_full += history_tiles_full(bs, MB)
         if want_pen:
             self._sync_counts(list(self._slots))
         counts_in = self._counts if want_pen else self._dummy_counts
@@ -2986,9 +2983,10 @@ class JaxServingEngine(AsyncEngine):
             bs, MB = cfg.kv_block_size, cfg.max_blocks_per_seq
             full = S * history_tiles_full(bs, MB)
             self.decode_history_tiles_full += full
-            # a mesh engine and a slot model gather every table's full width
-            self.decode_history_tiles_read += full if self._rides or self._slot_model else int(
-                decode_history_tiles(
+            # a mesh engine gathers every table's full width; one device what
+            # its module says (the live pairs, or every table whole)
+            self.decode_history_tiles_read += full if self._rides else int(
+                self.model.decode_history_tiles(
                     np.where(self._positions < 0, -1, self._positions + ahead),
                     bs, MB,
                 )

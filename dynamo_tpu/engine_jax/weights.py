@@ -23,19 +23,20 @@ import numpy as np
 
 from dynamo_tpu.llm.model_card import ModelDeploymentCard
 from dynamo_tpu.models import module_for
-from dynamo_tpu.models.kimi_linear import KimiLinearConfig
 from dynamo_tpu.models.llama import LlamaConfig
 
 logger = logging.getLogger(__name__)
 
 
-def kimi_linear_config(mc: Dict[str, Any], dtype: Any = jnp.bfloat16) -> KimiLinearConfig:
+def kimi_linear_config(mc: Dict[str, Any], dtype: Any = jnp.bfloat16):
     """``model_type: kimi_linear``. The KDA group is read from the published
     nested ``linear_attn_config`` where the card has it, else from its flat
     spelling (``linear_attn_num_heads``, ``linear_attn_head_dim``,
     ``short_conv_kernel_size``, ``kda_layers``, ``full_attn_layers``: a
     harness that writes only scalar and list keys). ``num_experts`` is the
     count held here; ``num_experts_published``, where given, the router's."""
+    from dynamo_tpu.models.kimi_linear import KimiLinearConfig
+
     group = mc.get("linear_attn_config") or {}
 
     def kda(nested: str, flat: str):
@@ -71,12 +72,47 @@ def kimi_linear_config(mc: Dict[str, Any], dtype: Any = jnp.bfloat16) -> KimiLin
     )
 
 
+def jamba_config(mc: Dict[str, Any], dtype: Any = jnp.bfloat16):
+    """``model_type: jamba``: Mamba layers beside attention layers, every
+    feed-forward dense. A card with experts is refused by name: no module
+    here runs an expert Jamba."""
+    from dynamo_tpu.models.jamba import JambaConfig
+
+    if int(mc.get("num_experts", 1)) > 1:
+        raise ValueError(
+            f"model_type 'jamba' with num_experts = {mc['num_experts']}: "
+            "models/jamba.py runs the dense feed-forward only (num_experts 1)"
+        )
+    hidden, heads = int(mc["hidden_size"]), int(mc["num_attention_heads"])
+    return JambaConfig(
+        vocab_size=int(mc["vocab_size"]),
+        hidden_size=hidden,
+        intermediate_size=int(mc["intermediate_size"]),
+        num_layers=int(mc["num_hidden_layers"]),
+        num_heads=heads,
+        num_kv_heads=int(mc.get("num_key_value_heads", heads)),
+        head_dim=int(mc.get("head_dim") or hidden // heads),
+        attn_layer_period=int(mc["attn_layer_period"]),
+        attn_layer_offset=int(mc["attn_layer_offset"]),
+        mamba_expand=int(mc.get("mamba_expand", 2)),
+        mamba_d_state=int(mc.get("mamba_d_state", 16)),
+        mamba_dt_rank=int(mc["mamba_dt_rank"]),
+        mamba_d_conv=int(mc.get("mamba_d_conv", 4)),
+        rms_norm_eps=float(mc.get("rms_norm_eps", 1e-6)),
+        tie_embeddings=bool(mc.get("tie_word_embeddings", True)),
+        dtype=dtype,
+    )
+
+
 def config_from_card(card: ModelDeploymentCard, dtype: Any = jnp.bfloat16):
     """Derive the model's config from the card's HF config.json contents: a
-    LlamaConfig, or by ``model_type`` another module's (models.module_for)."""
+    LlamaConfig, or by ``model_type`` another module's (models.module_for),
+    imported in its branch: serving one model loads no other's module."""
     mc = card.model_config or {}
     if mc.get("model_type") == "kimi_linear":
         return kimi_linear_config(mc, dtype)
+    if mc.get("model_type") == "jamba":
+        return jamba_config(mc, dtype)
     if "num_experts" in mc and "num_local_experts" not in mc:
         # an expert model of a family this tree has no module for: a
         # LlamaConfig of it would be a dense impostor under its name

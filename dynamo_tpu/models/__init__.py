@@ -7,6 +7,8 @@ serves Llama-70B-class models through vLLM; here the model IS the framework's,
 SURVEY.md §6 north star).
 """
 
+import sys
+
 from dynamo_tpu.models.llama import (
     LlamaConfig,
     LLAMA_PRESETS,
@@ -22,15 +24,22 @@ def module_for(model_config):
     """The model module whose programs run ``model_config``: the ONE place
     where the engine, the weight loader and the benchmark's reference child
     pick a module. Each exposes ``init_params``, ``make_kv_cache``,
-    ``param_shardings`` and ``lm_head``; a module whose layers keep state per
-    slot beside the pages (``make_slot_state``) also ``forward_chunk`` and
-    ``decode`` in the form ``engine_jax/engine.py`` calls them with the
-    state."""
-    from dynamo_tpu.models import kimi_linear, llama
+    ``param_shardings``, ``lm_head``, and ``chunk_history_tiles`` /
+    ``decode_history_tiles`` (what its step programs read of a block table,
+    for the host's count). A module whose layers keep state per slot beside
+    the pages also has ``make_slot_state``, ``forward_chunk`` and ``decode``
+    in the form ``engine_jax/engine.py`` calls them with the state, and
+    ``COUNTERS`` (docs/kv_cache_manager.md, "State per slot").
 
-    if isinstance(model_config, kimi_linear.KimiLinearConfig):
-        return kimi_linear
-    return llama
+    A config that is no ``LlamaConfig`` was made by its own module's class
+    (``engine_jax/weights.py:config_from_card`` imports that module in its
+    branch), so the module is the one already loaded: serving one model
+    imports no other's module."""
+    from dynamo_tpu.models import llama
+
+    if isinstance(model_config, llama.LlamaConfig):
+        return llama
+    return sys.modules[type(model_config).__module__]
 
 
 __all__ = [

@@ -1,0 +1,528 @@
+"""Jamba decoder: Mamba-1 mixers (a diagonal state-space recurrence, with
+Jamba's three inner RMS norms) beside a few attention layers without any
+positional encoding, a dense gated feed-forward after every mixer.
+
+Nearly every layer is a Mamba layer (26 of 28 in Jamba2-3B), so the layers are
+kept by RUN: ``params["mamba"]`` is a tuple of runs of consecutive Mamba
+layers, each a tree stacked on a leading layer axis and traced once under
+``lax.scan``; ``params["attn"]`` is a tuple of the attention layers between
+them (:func:`segments`). No conditional on the layer kind sits inside a layer
+loop, and the depth is not unrolled. Two kinds of state live side by side:
+
+- the attention layers' pages: the Llama layout, ``{"k", "v"}``
+  ``[L_attn, N, bs, KVH, D]``, written, gathered and attended by
+  ``models/llama.py``'s and ``ops/attention.py``'s own functions with the
+  rotation left out (the chunk's history a tile at a time, a decode
+  dispatch's through ``with_live_history``). Paged, and it travels through
+  ``kv/pages.py`` like any member.
+- the Mamba layers' state, PER SLOT (:class:`SlotState`, owned here), a run
+  at a time: float32 ``s`` ``[n, S, N, D]`` and the convolution's last
+  ``K - 1`` inputs ``[n, S, (K - 1) * D]``. ``D`` is the minor axis of both
+  (``[D, N]`` as published would pad 16 to 128 lanes in HBM), and
+  ``a_log`` is held ``[N, D]`` with it. The chunk and decode programs read the
+  state and hand it back; a chunk row whose first position is 0 starts from
+  zeros, which is how a slot is reset when a request is admitted to it; padding
+  rows, padding positions and lanes that do not decode leave it untouched.
+  Nothing outside this module indexes it, and ``pages.take`` / ``put`` never
+  see it.
+
+The weights are bfloat16 and the activations float32 from the embedding to
+the head (:func:`_dot`). Nothing here routes, and bfloat16 activations were the
+first build; on the chip they read ``logprob_rms`` 0.077 against the float32
+reference where the dense decoders read 0.017 (random weights of this
+architecture carry a rounding 4.5 x as far: PERF.md 6, PR 41), and by part: the
+stream and the products' outputs rounded to bfloat16 are over half of it (free
+to keep in float32), then the inputs of the in-projection, of the
+out-projection and of the two small projections that make the step size, B and
+C. Those four take their activation in TWO bfloat16 parts (16 bits of it; the
+parts stacked into one product, so a decode step reads the weight once); the
+attention layers' four, the feed-forwards and the head take one part, the
+MXU's own rounding, which is all that is left of bfloat16's noise (0.025).
+Float32 inside the recurrence, as the published kernels compute it: the
+convolution over the float32 tail, the three inner norms, the step size after
+its softplus, ``exp(step x A)``, the state, the sum over N. The recurrence
+advances ``[rows, N, D]`` token by token (:func:`_scan_tokens`); a chunk's is a
+``lax.scan`` over its tokens and a decode step's is one trip of the same
+body. ``[rows, T, N, D]`` never exists.
+"""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from typing import Any, Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from dynamo_tpu.models.llama import (  # noqa: F401  (the two tile counts are this module's too)
+    _chunk_self_partial, _live_window_attention, _merge_partials, _pool_pages,
+    chunk_history_partial, chunk_history_tiles, decode_history_tiles, embed_lookup, flush_window,
+    history_tile, history_tiles_full, rms_norm, with_live_history,
+)
+
+Params = Dict[str, Any]
+KVCache = Dict[str, jax.Array]  # {"k", "v"}: [L_attn, N, bs, KVH, D]
+SlotState = Dict[str, Tuple[jax.Array, ...]]  # {"s": per run [n, S, N, D], "conv": [n, S, (K-1)*D]}
+
+# sums the step programs return, in this order (engine: /debug/engine):
+# Mamba layers run (a chunk dispatch or a decode step each count their 26);
+# valid tokens the chunks' recurrences advanced, and the times a chunk row's
+# state went through the token loop (one a token under the scan: the ratio is
+# what a kernel that keeps a row's state on the chip would raise), both summed
+# over the Mamba layers; rows that started a request
+COUNTERS = ("ssm_layer_calls", "ssm_chunk_tokens", "ssm_state_passes", "slot_state_resets")
+# tokens a trip of a chunk's token loop advances (`lax.scan`'s `unroll`). Every
+# operation of every trip is an event of a device trace, and at one token a trip
+# the 26 loops of a chunk dispatch made 1.1 million events in a traced 4 s (the
+# benchmark's export then takes 144 s of the 135-145 it is given: ROADMAP B0). At
+# 16 the compiler's program runs a third of the operations a layer (384 against
+# 1,152) for 7 % more time at 8 and 16 rows (1.147 / 1.795 ms a layer against
+# 1.076 / 1.691 alone on the chip; 2 is the fastest, 0.960 / 1.438, and saves
+# few events; 128 wins at 8 rows and loses at 16: PERF.md 6, PR 41).
+SCAN_UNROLL = 16
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+@dataclass(frozen=True)
+class JambaConfig:
+    vocab_size: int = 65536
+    hidden_size: int = 2560
+    intermediate_size: int = 8192
+    num_layers: int = 28
+    num_heads: int = 20
+    num_kv_heads: int = 1
+    head_dim: int = 128
+    # layer i (0-based) is attention where i % period == offset
+    attn_layer_period: int = 14
+    attn_layer_offset: int = 7
+    mamba_expand: int = 2
+    mamba_d_state: int = 16
+    mamba_dt_rank: int = 160
+    mamba_d_conv: int = 4
+    rms_norm_eps: float = 1e-6
+    tie_embeddings: bool = True
+    dtype: Any = jnp.bfloat16
+
+    @property
+    def q_dim(self) -> int:
+        return self.num_heads * self.head_dim
+
+    @property
+    def kv_dim(self) -> int:
+        return self.num_kv_heads * self.head_dim
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
+
+
+def layer_kinds(c: JambaConfig) -> Tuple[str, ...]:
+    """``"attn"`` or ``"mamba"`` for each layer, as the published code reads
+    ``attn_layer_period`` and ``attn_layer_offset``."""
+    return tuple("attn" if i % c.attn_layer_period == c.attn_layer_offset else "mamba"
+                 for i in range(c.num_layers))
+
+
+def segments(c: JambaConfig) -> Tuple[Tuple[str, int], ...]:
+    """The layers in order as (kind, count): a run of consecutive Mamba layers
+    is one segment, an attention layer one of its own."""
+    out = []
+    for kind in layer_kinds(c):
+        if kind == "mamba" and out and out[-1][0] == "mamba":
+            out[-1][1] += 1
+        else:
+            out.append([kind, 1])
+    return tuple((kind, n) for kind, n in out)
+
+
+def _runs(c: JambaConfig) -> Tuple[int, ...]:
+    return tuple(n for kind, n in segments(c) if kind == "mamba")
+
+
+# -- parameters ---------------------------------------------------------------
+
+def init_params(rng: jax.Array, config: JambaConfig) -> Params:
+    """Random init with fan-in scaling. ``a_log`` = log(1..N) a channel and
+    ``d_skip`` = 1 as the published code initialises them; ``b_dt`` the inverse
+    softplus of U(0.001, 0.1), Mamba's own initialiser, so that the step size
+    is a trained model's; the convolution's bias small seeded values, so that a
+    test can tell it is added."""
+    c = config
+    e, f, d = c.hidden_size, c.intermediate_size, c.d_inner
+    n, r, kk = c.mamba_d_state, c.mamba_dt_rank, c.mamba_d_conv
+
+    def dense(key, shape, fan_in, dtype=None):
+        w = jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)
+        return w.astype(dtype or c.dtype)
+
+    def mlp(key):
+        k = jax.random.split(key, 3)
+        return {"mlp_norm": jnp.ones((e,), jnp.float32), "w_gate": dense(k[0], (e, f), e),
+                "w_up": dense(k[1], (e, f), e), "w_down": dense(k[2], (f, e), f)}
+
+    def mamba(key):
+        k = jax.random.split(key, 8)
+        dt = jax.random.uniform(k[5], (d,), jnp.float32, 0.001, 0.1)
+        return {
+            "mixer_norm": jnp.ones((e,), jnp.float32),
+            "w_in": dense(k[0], (e, 2 * d), e),
+            "conv_w": dense(k[1], (kk, d), kk, jnp.float32),
+            "conv_b": 0.1 * jax.random.normal(k[2], (d,), jnp.float32),
+            "w_x": dense(k[3], (d, r + 2 * n), d),
+            "dt_norm": jnp.ones((r,), jnp.float32),
+            "b_norm": jnp.ones((n,), jnp.float32),
+            "c_norm": jnp.ones((n,), jnp.float32),
+            "w_dt": dense(k[4], (r, d), r),
+            "b_dt": dt + jnp.log(-jnp.expm1(-dt)),
+            "a_log": jnp.broadcast_to(jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32))[:, None], (n, d)),
+            "d_skip": jnp.ones((d,), jnp.float32),
+            "w_out": dense(k[6], (d, e), d),
+            **mlp(k[7]),
+        }
+
+    def attn(key):
+        k = jax.random.split(key, 5)
+        return {
+            "mixer_norm": jnp.ones((e,), jnp.float32),
+            "wq": dense(k[0], (e, c.q_dim), e), "wk": dense(k[1], (e, c.kv_dim), e),
+            "wv": dense(k[2], (e, c.kv_dim), e), "wo": dense(k[3], (c.q_dim, e), c.q_dim),
+            **mlp(k[4]),
+        }
+
+    runs, layers, first = [], [], 0
+    for kind, count in segments(c):
+        keys = jnp.stack([jax.random.fold_in(rng, first + i) for i in range(count)])
+        if kind == "mamba":
+            runs.append(jax.vmap(mamba)(keys))
+        else:
+            layers.append(attn(keys[0]))
+        first += count
+    params = {
+        "embed": dense(jax.random.fold_in(rng, 1000), (c.vocab_size, e), e),
+        "final_norm": jnp.ones((e,), jnp.float32),
+        "mamba": tuple(runs),
+        "attn": tuple(layers),
+    }
+    if not c.tie_embeddings:
+        params["lm_head"] = dense(jax.random.fold_in(rng, 1001), (e, c.vocab_size), e)
+    return params
+
+
+def param_shardings(config: JambaConfig, mesh):
+    raise NotImplementedError(
+        "jamba runs on one device: a slot's state over the chips of a host has no "
+        "sharding rule yet"
+    )
+
+
+# -- the two kinds of state ---------------------------------------------------
+
+def make_kv_cache(
+    config: JambaConfig, num_blocks: int, block_size: int, dtype: Any = None,
+    quantized: bool = False,
+) -> KVCache:
+    """The attention layers' page pool, in the Llama layout."""
+    if quantized:
+        raise ValueError("jamba has no int8 page layout")
+    c = config
+    shape = (layer_kinds(c).count("attn"), num_blocks, block_size, c.num_kv_heads, c.head_dim)
+    return {"k": jnp.zeros(shape, dtype or c.dtype), "v": jnp.zeros(shape, dtype or c.dtype)}
+
+
+def make_slot_state(config: JambaConfig, slots: int) -> SlotState:
+    """The Mamba layers' state of every slot, zeroed, a run at a time: float32
+    ``[n, S, N, D]`` and the convolution's ``K - 1`` last inputs, oldest first,
+    side by side along the minor axis ``[n, S, (K - 1) * D]``."""
+    c = config
+    return {
+        "s": tuple(jnp.zeros((n, slots, c.mamba_d_state, c.d_inner), jnp.float32)
+                   for n in _runs(c)),
+        "conv": tuple(jnp.zeros((n, slots, (c.mamba_d_conv - 1) * c.d_inner), jnp.float32)
+                      for n in _runs(c)),
+    }
+
+
+# -- the Mamba mixer ----------------------------------------------------------
+
+# bfloat16 parts of an activation that the in-projection, the out-projection,
+# x_proj and dt_proj multiply (what is left of it after the first part, rounded
+# again): 16 bits of the float32 activation. With every product so the chip
+# reads 0.0006 against the reference, with none 0.051 (PERF.md 6, PR 41).
+PARTS = 2
+
+
+def _dot(x: jax.Array, w: jax.Array, parts: int = 1) -> jax.Array:
+    """``x @ w`` with a float32 result, for a float32 ``x``. Against a
+    bfloat16 weight (the served case) ``x`` goes to the MXU as ``parts``
+    bfloat16 arrays whose sum is ``x`` to ``8 * parts`` bits, stacked into ONE
+    product so that the weight is read once, their products added in float32.
+    The rounding is ``reduce_precision``, which the compiler keeps (a float32
+    -> bfloat16 -> float32 pair of converts it may drop: models/kimi_linear.py
+    found it so). Any other weight (the float32 weights of a CPU test) is
+    multiplied as it is, at float32's own precision."""
+    x = x.astype(jnp.float32)
+    if w.dtype != jnp.bfloat16:
+        return jnp.dot(x, w.astype(jnp.float32), precision=HIGHEST)
+    if parts == 1:
+        return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
+    split, rest = [], x
+    for _ in range(parts):
+        part = jax.lax.reduce_precision(rest, exponent_bits=8, mantissa_bits=7)
+        split.append(part.astype(w.dtype))
+        rest = rest - part
+    return jnp.dot(jnp.stack(split), w, preferred_element_type=jnp.float32).sum(axis=0)
+
+
+def lm_head(params: Params, config: JambaConfig, h: jax.Array) -> jax.Array:
+    """Final hidden states to float32 logits (the head is the embedding
+    table where it is tied)."""
+    return _dot(h, params["embed"].T if config.tie_embeddings else params["lm_head"])
+
+
+def mlp(lp: Params, c: JambaConfig, h: jax.Array) -> jax.Array:
+    """``h + MLP(RMSNorm(h))``, the dense gated feed-forward."""
+    x = rms_norm(h, lp["mlp_norm"], c.rms_norm_eps)
+    return h + _dot(jax.nn.silu(_dot(x, lp["w_gate"])) * _dot(x, lp["w_up"]), lp["w_down"])
+
+
+def _scan_tokens(lp: Params, s: jax.Array, delta: jax.Array, x: jax.Array, b: jax.Array,
+                 c: jax.Array, valid: jax.Array):
+    """The selective scan over ``[B, T]`` tokens from the rows' state ``s``
+    ``[B, N, D]``, all float32 and elementwise: ``s = exp(delta A) * s +
+    (delta x) B``, ``y = s C`` (summed over N). A token that is not valid
+    leaves the state as it is. Returns (``y`` ``[B, T, D]``, the state after
+    the last valid token). One token is one trip of the same body."""
+    a = -jnp.exp(lp["a_log"])  # [N, D]
+
+    def token(s, xs):
+        d_t, x_t, b_t, c_t, v_t = xs  # [B, D], [B, D], [B, N], [B, N], [B]
+        new = jnp.exp(d_t[:, None, :] * a) * s + (d_t * x_t)[:, None, :] * b_t[:, :, None]
+        return jnp.where(v_t[:, None, None], new, s), jnp.sum(new * c_t[:, :, None], axis=1)
+
+    if delta.shape[1] == 1:
+        s, y = token(s, (delta[:, 0], x[:, 0], b[:, 0], c[:, 0], valid[:, 0]))
+        return y[:, None], s
+    s, y = jax.lax.scan(token, s, tuple(jnp.moveaxis(a_, 1, 0) for a_ in (delta, x, b, c, valid)),
+                        unroll=SCAN_UNROLL)
+    return jnp.moveaxis(y, 0, 1), s
+
+
+def mamba_mixer(lp: Params, c: JambaConfig, u: jax.Array, valid: jax.Array,
+                s: jax.Array, tail: jax.Array):
+    """The Mamba mixer over ``[B, T, E]`` normed inputs whose valid tokens are
+    a prefix of each row, from the rows' state ``s`` ``[B, N, D]`` and the
+    convolution's tail ``[B, (K - 1) * D]``: (output ``[B, T, E]``, the state
+    after the last valid token, the new tail: the row's last ``K - 1`` valid
+    inputs)."""
+    bsz, t, _ = u.shape
+    d, n, r, kk = c.d_inner, c.mamba_d_state, c.mamba_dt_rank, c.mamba_d_conv
+    xz = _dot(u, lp["w_in"], PARTS)
+    x, gate = xz[..., :d], xz[..., d:]
+    # causal depthwise convolution: tap K-1 is the token itself, tap 0 the oldest input
+    seq = jnp.concatenate([tail.reshape(bsz, kk - 1, d), x], axis=1)  # [B, K-1+T, D]
+    x = jax.nn.silu(sum(seq[:, j:j + t] * lp["conv_w"][j] for j in range(kk)) + lp["conv_b"])
+    tail_at = valid.sum(axis=1)[:, None] + jnp.arange(kk - 1)[None, :]  # the K-1 inputs before position n
+    new_tail = jnp.take_along_axis(seq, tail_at[:, :, None], axis=1).reshape(bsz, -1)
+
+    dbc = _dot(x, lp["w_x"], PARTS)
+    dt = rms_norm(dbc[..., :r], lp["dt_norm"], c.rms_norm_eps)
+    b = rms_norm(dbc[..., r:r + n], lp["b_norm"], c.rms_norm_eps)
+    cc = rms_norm(dbc[..., r + n:], lp["c_norm"], c.rms_norm_eps)
+    delta = jax.nn.softplus(_dot(dt, lp["w_dt"], PARTS) + lp["b_dt"])  # [B, T, D]
+    y, s = _scan_tokens(lp, s, delta, x, b, cc, valid)
+    y = (y + lp["d_skip"] * x) * jax.nn.silu(gate)
+    return _dot(y, lp["w_out"], PARTS), s, new_tail
+
+
+# -- the attention mixer ------------------------------------------------------
+
+def _project_qkv(lp: Params, c: JambaConfig, x: jax.Array):
+    """q, k, v of normed inputs ``[B, T, E]``, split into heads and in the
+    pages' dtype (attention's own arithmetic is ``models/llama.py``'s): no
+    bias, no rotation, no q/k norm."""
+    b, t, _ = x.shape
+    return (_dot(x, lp["wq"]).astype(c.dtype).reshape(b, t, c.num_heads, c.head_dim),
+            _dot(x, lp["wk"]).astype(c.dtype).reshape(b, t, c.num_kv_heads, c.head_dim),
+            _dot(x, lp["wv"]).astype(c.dtype).reshape(b, t, c.num_kv_heads, c.head_dim))
+
+
+# -- the step programs --------------------------------------------------------
+
+def forward_chunk(
+    params: Params, config: JambaConfig, tokens: jax.Array, positions: jax.Array,
+    kv_cache: KVCache, block_tables: jax.Array, state: SlotState, lanes: jax.Array,
+):
+    """A ``[R, C]`` block of prompt tokens, one row per prefilling lane
+    (``lanes`` ``[R]``: the row's slot; ``max_slots`` and above = a padding
+    row), valid tokens (position >= 0) a prefix of each row.
+
+    Returns (hidden ``[R, C, E]`` after the final norm, the pool with the
+    rows' K and V written, the slot state with the rows' slots advanced, the
+    counters ``[len(COUNTERS)]``). A row whose first position is 0 starts from
+    a zeroed state: a slot is reset by the first chunk of the request admitted
+    to it. A run of Mamba layers is one ``lax.scan`` whose carry holds the
+    run's state whole: a layer gathers its rows' slots by ONE flat index and
+    scatters them back in place, so a dispatch costs what its rows touch. The
+    attention layers read the pool as ``models/llama.py:forward_chunk`` does
+    (history a tile at a time, the chunk's own keys in hand) and their fresh K
+    and V are written after the layers by one scatter a pool array."""
+    from dynamo_tpu.ops.attention import write_kv_to_pool
+
+    c = config
+    b, t = positions.shape
+    valid = positions >= 0
+    fresh = positions[:, 0] == 0
+    slots = state["s"][0].shape[1]
+    lane = jnp.clip(lanes, 0, slots - 1)
+    real = lanes < slots
+
+    scale = c.head_dim ** -0.5
+    num_blocks, block_size = kv_cache["k"].shape[1:3]
+    table_blocks = block_tables.shape[1]
+    pages = _pool_pages(kv_cache)
+    tile_blocks = history_tile(block_size, table_blocks) // block_size
+    history_len = jnp.clip(positions[:, 0], 0, table_blocks * block_size)
+    n_tiles = chunk_history_tiles(positions, block_size, table_blocks)
+    tables = jnp.pad(block_tables, (
+        (0, 0), (0, history_tiles_full(block_size, table_blocks) * tile_blocks - table_blocks)))
+
+    def mamba_layer(carry, xs):
+        h, s_all, conv_all = carry  # [n * S, N, D], [n * S, (K-1) * D]: the run's state, flat
+        lp, layer = xs
+        with jax.named_scope("mamba"):
+            at = layer * slots + lane
+            s0 = jnp.where(fresh[:, None, None], 0.0, s_all[at])
+            tail0 = jnp.where(fresh[:, None], 0.0, conv_all[at])
+            y, s1, tail1 = mamba_mixer(
+                lp, c, rms_norm(h, lp["mixer_norm"], c.rms_norm_eps), valid, s0, tail0)
+            # a padding row writes nowhere: its index lies past the run's state
+            back = jnp.where(real, at, s_all.shape[0])
+            s_all = s_all.at[back].set(s1, mode="drop")
+            conv_all = conv_all.at[back].set(tail1, mode="drop")
+        with jax.named_scope("mlp"):
+            h = mlp(lp, c, h + y)
+        return (h, s_all, conv_all), None
+
+    h = embed_lookup(params, tokens, c.dtype).astype(jnp.float32)
+    s_out, conv_out, fresh_k, fresh_v = [], [], [], []
+    for kind, count in segments(c):
+        if kind == "mamba":
+            i = len(s_out)
+            s_run, conv_run = state["s"][i], state["conv"][i]
+            (h, s_run, conv_run), _ = jax.lax.scan(
+                mamba_layer,
+                (h, s_run.reshape(-1, *s_run.shape[2:]), conv_run.reshape(-1, conv_run.shape[2])),
+                (params["mamba"][i], jnp.arange(count)))
+            s_out.append(s_run.reshape(state["s"][i].shape))
+            conv_out.append(conv_run.reshape(state["conv"][i].shape))
+            continue
+        j = len(fresh_k)
+        lp = params["attn"][j]
+        with jax.named_scope("attn"):
+            q, k, v = _project_qkv(lp, c, rms_norm(h, lp["mixer_norm"], c.rms_norm_eps))
+            hist = chunk_history_partial(
+                c, q, pages, j * num_blocks + tables, history_len, n_tiles, positions, scale,
+                tile_blocks, block_size, c.dtype)
+            num, _, den = _merge_partials(hist, _chunk_self_partial(c, q, k, v, positions, scale))
+            attn = jnp.where(
+                (den > 0.0).transpose(0, 2, 1)[..., None],
+                num / jnp.maximum(den, 1e-30).transpose(0, 2, 1)[..., None], 0.0)
+            h = h + _dot(attn.reshape(b, t, c.q_dim), lp["wo"])
+            fresh_k.append(k)
+            fresh_v.append(v)
+        with jax.named_scope("mlp"):
+            h = mlp(lp, c, h)
+    cache = {"k": write_kv_to_pool(kv_cache["k"], jnp.stack(fresh_k), positions, block_tables),
+             "v": write_kv_to_pool(kv_cache["v"], jnp.stack(fresh_v), positions, block_tables)}
+    h = rms_norm(h, params["final_norm"], c.rms_norm_eps)
+    n_mamba = sum(_runs(c))
+    advanced = n_mamba * jnp.sum(valid & real[:, None])
+    counters = jnp.stack([jnp.int32(n_mamba), advanced, advanced, jnp.sum(fresh & real)])
+    return h, cache, {"s": tuple(s_out), "conv": tuple(conv_out)}, counters.astype(jnp.int32)
+
+
+def decode(
+    params: Params, config: JambaConfig, tokens: jax.Array, positions: jax.Array,
+    kv_cache: KVCache, block_tables: jax.Array, state: SlotState, steps: int, max_pos: int,
+    sample, carry,
+):
+    """``steps`` tokens of every slot (``tokens``, ``positions`` ``[S]``;
+    position < 0 = the slot does not decode, and its state stays as it is; a
+    lane that passes ``max_pos`` stops there).
+
+    A step reads and writes every slot's state once: a run of Mamba layers is
+    a ``lax.scan`` with the run's state as its ``xs`` and ``ys``, and the
+    ``steps`` (a handful) are unrolled, so that one step's ``ys`` are the next
+    step's ``xs`` and no buffer is copied. (Under a ``lax.scan`` over the steps
+    the chip's compiler copies the state whole onto the loop's carry every
+    step, in either form of the layer loop: PERF.md 6, PR 41.) The attention
+    layers are the dense tier of the Llama decode program: the pool is
+    read-only inside the dispatch, its live (lane, tile) pairs gathered once
+    (``with_live_history``), a step's K and V go to a window buffer and the
+    pool takes the window after the steps in one scatter a pool array
+    (``flush_window``). ``sample(logits [S, V], positions, carry, k) -> (next
+    tokens [S], carry, outputs)`` is the engine's. Returns (tokens, positions,
+    carry, the stacked outputs, pool, state, counters ``[len(COUNTERS)]``)."""
+    c = config
+    segs = segments(c)
+    base = positions
+    n_slots = tokens.shape[0]
+    window = jnp.zeros((n_slots, steps, c.num_kv_heads, c.head_dim), c.dtype)
+    n_attn = layer_kinds(c).count("attn")
+
+    def run(history):
+        live = history[1]
+
+        def step(loop, k):
+            toks, pos, carry, s_all, conv_all, wk, wv = loop
+            valid = (pos >= 0)[:, None]
+
+            def mamba_layer(h, xs):
+                lp, s, tail = xs
+                with jax.named_scope("mamba"):
+                    y, s, tail = mamba_mixer(
+                        lp, c, rms_norm(h, lp["mixer_norm"], c.rms_norm_eps), valid, s, tail)
+                with jax.named_scope("mlp"):
+                    h = mlp(lp, c, h + y)
+                return h, (s, tail)
+
+            in_window = (jnp.arange(steps)[None, :] <= k) & (base[:, None] >= 0)  # [S, W]
+            s_all, conv_all, wk, wv = list(s_all), list(conv_all), list(wk), list(wv)
+            h = embed_lookup(params, toks, c.dtype).astype(jnp.float32)[:, None]  # [S, 1, E]
+            i = j = 0
+            for kind, _ in segs:
+                if kind == "mamba":
+                    h, (s_all[i], conv_all[i]) = jax.lax.scan(
+                        mamba_layer, h, (params["mamba"][i], s_all[i], conv_all[i]))
+                    i += 1
+                    continue
+                lp = params["attn"][j]
+                with jax.named_scope("attn"):
+                    q, kk, vv = _project_qkv(lp, c, rms_norm(h, lp["mixer_norm"], c.rms_norm_eps))
+                    wk[j] = jax.lax.dynamic_update_slice(wk[j], kk, (0, k, 0, 0))
+                    wv[j] = jax.lax.dynamic_update_slice(wv[j], vv, (0, k, 0, 0))
+                    attn = _live_window_attention(
+                        c, q, live, live.k[j], live.v[j], wk[j], wv[j], in_window, None)
+                    h = h + _dot(attn.reshape(n_slots, 1, c.q_dim), lp["wo"])
+                with jax.named_scope("mlp"):
+                    h = mlp(lp, c, h)
+                j += 1
+            h = rms_norm(h, params["final_norm"], c.rms_norm_eps)
+            nxt, carry, out = sample(lm_head(params, c, h)[:, 0], pos, carry, k)
+            new_pos = jnp.where((pos >= 0) & (pos < max_pos), pos + 1, -1)
+            return (nxt, new_pos, carry, tuple(s_all), tuple(conv_all), tuple(wk), tuple(wv)), out
+
+        loop = (tokens, positions, carry, state["s"], state["conv"],
+                (window,) * n_attn, (window,) * n_attn)
+        outs = []
+        for k in range(steps):
+            loop, out = step(loop, jnp.int32(k))
+            outs.append(out)
+        return loop, jax.tree.map(lambda *a: jnp.stack(a), *outs)
+
+    (toks, pos, carry, s_all, conv_all, wk, wv), out = with_live_history(
+        kv_cache, block_tables, base, run, out_dtype=c.dtype)
+    cache = flush_window(kv_cache, block_tables, base, jnp.stack(wk), jnp.stack(wv), max_pos)
+    counters = jnp.zeros((len(COUNTERS),), jnp.int32).at[0].set(steps * sum(_runs(c)))
+    return toks, pos, carry, out, cache, {"s": s_all, "conv": conv_all}, counters

@@ -49,7 +49,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu.models.llama import embed_lookup, rms_norm
+from dynamo_tpu.models.llama import embed_lookup, history_tiles_full, rms_norm
 from dynamo_tpu.ops import moe
 from dynamo_tpu.ops.pallas.kda_scan import kda_scan
 
@@ -337,6 +337,18 @@ def make_slot_state(config: KimiLinearConfig, slots: int) -> SlotState:
         "conv": tuple(jnp.zeros((slots, c.conv_kernel - 1, 3 * c.kda_dim), jnp.float32)
                       for _ in range(n_kda)),
     }
+
+
+def chunk_history_tiles(positions, block_size: int, table_blocks: int) -> int:
+    """Tiles of a block table a chunk dispatch reads, for the host's count
+    (``models/llama.py`` has the form): :func:`mla_attend` scores every row's
+    whole table, whatever it holds."""
+    return history_tiles_full(block_size, table_blocks)
+
+
+def decode_history_tiles(base, block_size: int, table_blocks: int) -> int:
+    """(lane, tile) slots a decode dispatch gathers: every lane's whole table."""
+    return base.shape[0] * history_tiles_full(block_size, table_blocks)
 
 
 # -- KDA ----------------------------------------------------------------------

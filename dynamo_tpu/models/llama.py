@@ -1073,6 +1073,59 @@ def _merge_partials(p, q):
     return num, m, a_p * l_p + a_q * l_q
 
 
+def chunk_history_partial(
+    c: LlamaConfig,
+    q: jax.Array,  # [B, T, H, D] one layer's chunk queries
+    pages: KVCache,  # the pool as `_pool_pages` views
+    rows: jax.Array,  # [B, tiles * tile_blocks] the layer's rows of those views
+    history_len: jax.Array,  # [B] positions a lane has in the pool
+    n_tiles,  # trips: `chunk_history_tiles`
+    positions: jax.Array,  # [B, T]; < 0 = padding
+    scale: float,
+    tile_blocks: int,
+    block_size: int,
+    dtype: Any,  # what an int8 pool's pages dequantize into
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Flash partial of one layer's chunk queries against the history in the
+    pool, a tile of :func:`history_tile` positions a trip and ``n_tiles``
+    trips, each tile's partial folded into a running one by the flash merge.
+    No trip leaves the empty partial, which the merge with the chunk's own
+    turns into that alone. The layer loop of :func:`forward_chunk` calls it,
+    and any module whose attention layers read the same page layout."""
+    from dynamo_tpu.ops.attention import gather_pages
+
+    b, t = positions.shape
+    quantized = kv_cache_quantized(pages)
+
+    def tile(i, acc):
+        """Fold tile ``i``'s partial into ``acc``: its pages hold
+        positions ``i * tile`` onwards."""
+        cols = jax.lax.dynamic_slice_in_dim(
+            rows, i * tile_blocks, tile_blocks, axis=1
+        )
+        gk = gather_pages(pages["k"], cols)
+        gv = gather_pages(pages["v"], cols)
+        if quantized:
+            # dequant on the GATHERED lanes only (O(context), never
+            # O(pool)); gather_pages is trailing-dim agnostic so the
+            # [L * N, bs] scale tables gather like [B, S] vectors
+            gks = gather_pages(pages["k_scale"], cols)
+            gvs = gather_pages(pages["v_scale"], cols)
+            gk = dequantize_kv(gk, gks, dtype)
+            gv = dequantize_kv(gv, gvs, dtype)
+        start = i * tile_blocks * block_size
+        return _merge_partials(acc, _history_partial(
+            c, q, gk, gv, history_len - start, positions, scale
+        ))
+
+    empty = (
+        jnp.zeros((b, t, c.num_heads, c.head_dim), jnp.float32),
+        jnp.full((b, c.num_heads, t), -1e30, jnp.float32),
+        jnp.zeros((b, c.num_heads, t), jnp.float32),
+    )
+    return jax.lax.fori_loop(0, n_tiles, tile, empty)
+
+
 def forward_chunk(
     params: Params,
     config: LlamaConfig,
@@ -1109,7 +1162,7 @@ def forward_chunk(
     ``with_history=False`` compiles out the pool gather + history partial
     entirely — the caller guarantees every lane starts at position 0 (a
     fresh admission wave's first chunk, THE TTFT-critical dispatch)."""
-    from dynamo_tpu.ops.attention import gather_pages, write_kv_to_pool
+    from dynamo_tpu.ops.attention import write_kv_to_pool
 
     c = config
     scale = c.head_dim ** -0.5
@@ -1135,35 +1188,11 @@ def forward_chunk(
         q, k, v = project_qkv(lp, c, hidden, positions)
         part = _chunk_self_partial(c, q, k, v, positions, scale)
         if with_history:
-            rows = layer * num_blocks + tables
-
-            def tile(i, acc):
-                """Fold tile ``i``'s partial into ``acc``: its pages hold
-                positions ``i * tile`` onwards."""
-                cols = jax.lax.dynamic_slice_in_dim(
-                    rows, i * tile_blocks, tile_blocks, axis=1
-                )
-                gk = gather_pages(pages["k"], cols)
-                gv = gather_pages(pages["v"], cols)
-                if quantized:
-                    # dequant on the GATHERED lanes only (O(context), never
-                    # O(pool)); gather_pages is trailing-dim agnostic so the
-                    # [L * N, bs] scale tables gather like [B, S] vectors
-                    gks = gather_pages(pages["k_scale"], cols)
-                    gvs = gather_pages(pages["v_scale"], cols)
-                    gk = dequantize_kv(gk, gks, hidden.dtype)
-                    gv = dequantize_kv(gv, gvs, hidden.dtype)
-                start = i * tile_blocks * block_size
-                return _merge_partials(acc, _history_partial(
-                    c, q, gk, gv, history_len - start, positions, scale
-                ))
-
-            empty = (
-                jnp.zeros((b, t, c.num_heads, c.head_dim), jnp.float32),
-                jnp.full((b, c.num_heads, t), -1e30, jnp.float32),
-                jnp.zeros((b, c.num_heads, t), jnp.float32),
+            hist = chunk_history_partial(
+                c, q, pages, layer * num_blocks + tables, history_len,
+                n_tiles, positions, scale, tile_blocks, block_size,
+                hidden.dtype,
             )
-            hist = jax.lax.fori_loop(0, n_tiles, tile, empty)
             part = _merge_partials(hist, part)
         num, _, den = part
         attn = jnp.where(

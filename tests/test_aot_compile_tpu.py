@@ -588,3 +588,53 @@ def test_a_chunks_kda_mixer_compiles_with_the_state_on_the_chip(monkeypatch, one
     carried = [line.strip()[:160] for line in hlo.splitlines()
                if re.search(r"\bwhile\(", line) and f"f32[{rows},{h},{d},{d}]" in line]
     assert carried == [], carried
+
+
+# -- the step programs of jamba2-3b ---------------------------------------------
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_jambas_step_programs_never_copy_the_slots_state(one_chip, program):
+    """``models/jamba.py`` at ``batch.jamba2-3b``'s served shapes (64 slots,
+    block 16, 12,288 blocks, 2,048 positions; a chunk at the 8-row rung): the
+    compiled program holds no copy of a run's ``f32[n,64,16,5120]`` state or of
+    its convolution tails. A decode step must read and write the 647 MB once:
+    under a ``lax.scan`` over the steps the compiler copied the state whole onto
+    the loop's carry every step, which is why the steps are unrolled and a run's
+    layers scan the state as ``xs`` / ``ys`` (PERF.md 6, PR 41). A chunk's rows
+    gather and scatter their slots by one flat index on the layer loop's carry."""
+    from dynamo_tpu.models import jamba
+
+    c = jamba.JambaConfig()
+    slots, rows, mb, chunk = 64, 8, 128, 128
+
+    def sd(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = jax.tree.map(sd, jax.eval_shape(lambda: jamba.init_params(jax.random.PRNGKey(0), c)))
+    cache = jax.tree.map(sd, jax.eval_shape(lambda: jamba.make_kv_cache(c, 12288, 16)))
+    state = jax.tree.map(sd, jax.eval_shape(lambda: jamba.make_slot_state(c, slots)))
+    if program == "decode":
+        def greedy(logits, pos, carry, k):
+            return jnp.argmax(logits, -1).astype(jnp.int32), carry, jnp.argmax(logits, -1)
+
+        compiled = jax.jit(
+            lambda p, kv, st, toks, pos, tables: jamba.decode(
+                p, c, toks, pos, kv, tables, st, 4, 2047, greedy, 0),
+            donate_argnums=(1, 2),
+        ).lower(params, cache, state, i32(slots), i32(slots), i32(slots, mb)).compile()
+    else:
+        compiled = jax.jit(
+            lambda p, kv, st, toks, pos, tables, lanes: jamba.forward_chunk(
+                p, c, toks, pos, kv, tables, st, lanes),
+            donate_argnums=(1, 2),
+        ).lower(params, cache, state, i32(rows, chunk), i32(rows, chunk), i32(rows, mb),
+                i32(rows)).compile()
+    hlo = re.sub(r"/\*.*?\*/", "", compiled.as_text())
+    copies = re.findall(r"= f32\[\d+,64,(?:16,5120|15360)\]\{[^}]*\} copy\(", hlo)
+    copies += re.findall(r"= bf16\[2,12288,16,1,128\]\{[^}]*\} copy\(", hlo)  # nor the pool
+    assert copies == [], copies
+    # both donated: the pool and the state come back in the buffers they came in
+    assert compiled.memory_analysis().alias_size_in_bytes >= 647_495_680 + 201_326_592

@@ -1,0 +1,403 @@
+"""Jamba on the served path, at a tiny size on the CPU (hidden 64, six layers
+in the pattern Mamba, attention, Mamba, Mamba, attention, Mamba; a state of
+8 x 128 a layer, dt rank 8, a convolution of 4, four query heads over ONE
+key/value head of 16).
+
+The program (``models/jamba.py``: chunked prefill through per-slot state and
+K/V pages, then decode) is held against the benchmark's plain reference
+(``benchmark/reference_jamba.py``: one sequence, token by token, no cache); the
+engine against both, and against the refusals a model with per-slot state owes
+whatever would hand its pages over without it.
+"""
+
+import json
+import os
+import re
+import subprocess
+import sys
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import reference_jamba as ref
+from dynamo_tpu.engine_jax.engine import EngineConfig, JaxServingEngine
+from dynamo_tpu.engine_jax.weights import config_from_card
+from dynamo_tpu.kv.pages import MigrationRejected, StateNotPortable
+from dynamo_tpu.models import jamba, llama, module_for
+
+from .test_chunk_rows import answer, run_out, step, submit
+
+# ATOL, the float32 build: float32 on the CPU at the highest matmul precision
+# on both sides, so the program and the reference differ by the order of their
+# sums alone (a chunk's convolution against the whole sequence's, flash partials
+# against one softmax): 2e-4 on logits of magnitude 4 is what the dense decoder
+# and Kimi-Linear are allowed for the same reason (measured here: 5e-6). A wrong
+# state, tail or page moves a logit by 1e-1 and more, and the recurrence taken
+# in bfloat16 by 1e-2 (a test below holds that it fails this tolerance).
+ATOL = 2e-4
+# ATOL_BF16, the served build (bfloat16 weights, float32 activations, the
+# Mamba mixers' four projections in two bfloat16 parts, attention, the
+# feed-forwards and the head in one: the MXU's rounding of their inputs, 8 bits
+# of mantissa, six layers deep) against the float32 reference over the same
+# weights: measured 0.04 on logits of magnitude 3.8, held at twice that (the
+# first build, bfloat16 activations throughout, read 0.13 and would fail it).
+# The benchmark's comparison (logprob_rms) is the tight one for this build.
+ATOL_BF16 = 0.08
+
+SHAPE = {
+    "model_type": "jamba", "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 6,
+    "num_attention_heads": 4, "num_key_value_heads": 1, "attn_layer_period": 3,
+    "attn_layer_offset": 1, "expert_layer_period": 2, "expert_layer_offset": 1, "num_experts": 1,
+    "num_experts_per_tok": 1, "mamba_expand": 2, "mamba_d_state": 8, "mamba_dt_rank": 8,
+    "mamba_d_conv": 4, "mamba_conv_bias": True, "mamba_proj_bias": False, "rms_norm_eps": 1e-6,
+    "vocab_size": 96, "tie_word_embeddings": True,
+}
+N_MAMBA = 4
+ENGINE_CFG = EngineConfig(max_slots=4, kv_block_size=8, max_model_len=96,
+                          prefill_chunk=16, decode_steps=4, top_logprobs=5)
+PUBLISHED = "benchmark/configs/jamba2-3b.json"
+
+
+def card(shape):
+    return types.SimpleNamespace(model_config=shape, model_path=None, gguf_path=None)
+
+
+def prompt_of(n, salt=0):
+    return [(salt * 31 + 7 * i + 3) % 95 + 1 for i in range(n)]
+
+
+@pytest.fixture(scope="module", autouse=True)
+def highest_precision():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    return config_from_card(card(SHAPE), jnp.float32)
+
+
+@pytest.fixture(scope="module")
+def params(cfg):
+    return jamba.init_params(jax.random.PRNGKey(3), cfg)
+
+
+@pytest.fixture(scope="module")
+def engine(cfg, params):
+    eng = JaxServingEngine(cfg, params, ENGINE_CFG)
+    yield eng
+    eng.close()
+
+
+def test_the_layer_kinds_are_the_published_pattern(cfg):
+    """Attention at 7 and 21 of 28 (``i % 14 == 7``), the other 26 Mamba, in
+    runs of 7, 13 and 6; the tiny shape keeps both kinds and a run of two."""
+    with open(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), PUBLISHED)) as f:
+        published = config_from_card(card(json.load(f)))
+    kinds = jamba.layer_kinds(published)
+    assert [i for i, k in enumerate(kinds) if k == "attn"] == [7, 21] and len(kinds) == 28
+    assert jamba.segments(published) == (
+        ("mamba", 7), ("attn", 1), ("mamba", 13), ("attn", 1), ("mamba", 6))
+    assert (published.d_inner, published.head_dim, published.num_kv_heads) == (5120, 128, 1)
+    assert ref.sizes(SHAPE)["kinds"] == jamba.layer_kinds(cfg) == (
+        "mamba", "attn", "mamba", "mamba", "attn", "mamba")
+    assert module_for(cfg) is jamba and module_for(llama.LLAMA_PRESETS["tiny"]) is llama
+
+
+def prefill_then_decode(cfg, params, chunks, n_decode=3, between=None):
+    """Logits ``[sum(chunks) + n_decode, V]`` of a prompt fed in ``chunks``
+    into slot 2 of 4 (the chunk's second row is padding) and decoded from the
+    state and pages they left; (logits, state, cache, the chunks' counters)."""
+    n_prompt = sum(chunks)
+    tokens = np.asarray(prompt_of(n_prompt + n_decode, salt=len(chunks)), np.int32)
+    slots, c, bs, mb, slot = 4, 16, 8, 8, 2
+    cache = jamba.make_kv_cache(cfg, 32, bs)
+    state = jax.tree.map(lambda a: a + 7.0, jamba.make_slot_state(cfg, slots))  # stale, every slot
+    tables = np.zeros((2, mb), np.int32)
+    tables[0] = np.arange(1, 9)
+    got, sums, at = [], [], 0
+    for n in chunks:
+        toks, pos = np.zeros((2, c), np.int32), np.full((2, c), -1, np.int32)
+        toks[0, :n], pos[0, :n] = tokens[at:at + n], np.arange(at, at + n)
+        h, cache, state, counted = jamba.forward_chunk(
+            params, cfg, jnp.asarray(toks), jnp.asarray(pos), cache, jnp.asarray(tables),
+            state, jnp.asarray([slot, slots], jnp.int32))
+        got.append(np.asarray(jamba.lm_head(params, cfg, h[0, :n]), np.float32))
+        sums.append(np.asarray(counted))
+        at += n
+        if between is not None:
+            state = between(state)
+    lanes_tables = np.zeros((slots, mb), np.int32)
+    lanes_tables[slot] = tables[0]
+    toks, pos = np.zeros((slots,), np.int32), np.full((slots,), -1, np.int32)
+    toks[slot], pos[slot] = tokens[n_prompt], n_prompt
+
+    def forced(logits, p, carry, k):  # teacher forcing: the sequence's own next token
+        return jnp.where(p >= 0, jnp.asarray(tokens)[jnp.clip(p + 1, 0, len(tokens) - 1)], 0), carry, logits
+
+    out = jamba.decode(params, cfg, jnp.asarray(toks), jnp.asarray(pos), cache,
+                       jnp.asarray(lanes_tables), state, n_decode, 95, forced, None)
+    assert int(out[1][slot]) == n_prompt + n_decode and int(out[6][0]) == n_decode * N_MAMBA
+    got.append(np.asarray(out[3], np.float32)[:, slot])
+    return tokens, np.concatenate(got), out[5], out[4], sums
+
+
+@pytest.mark.parametrize("dtype, atol", [(jnp.float32, ATOL), (jnp.bfloat16, ATOL_BF16)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("chunks", [(16,), (16, 16, 5), (7, 16, 14)],
+                         ids=["one_chunk", "a_prompt_that_ends_mid_chunk", "a_short_first_chunk"])
+def test_chunked_prefill_then_decode_agrees_with_the_plain_reference(chunks, dtype, atol):
+    """A prompt fed in chunks whose boundaries lie inside it, each starting
+    from the slot's Mamba state and the K/V pages the last one left, then three
+    decode steps off the same state, against the reference's one pass over the
+    whole sequence. The other slots' state and the other pages stay as they
+    were, and the first chunk alone resets the slot."""
+    cfg = config_from_card(card(SHAPE), dtype)
+    params = jamba.init_params(jax.random.PRNGKey(3), cfg)
+    tokens, got, state, cache, sums = prefill_then_decode(cfg, params, chunks)
+    want = np.asarray(ref.logits(params, SHAPE, jnp.asarray(tokens), jnp.arange(len(tokens))))
+    np.testing.assert_allclose(got, want, atol=atol)
+    for leaf in jax.tree.leaves(state):  # slots 0, 1 and 3 of every layer: untouched
+        assert float(leaf[:, (0, 1, 3)].min()) == float(leaf[:, (0, 1, 3)].max()) == 7.0
+    # pages outside the lane's table (block 0: where a padding row's table points; 9 on)
+    assert not np.asarray(cache["k"][:, 0]).any() and not np.asarray(cache["v"][:, 9:]).any()
+    assert np.asarray(cache["k"][:, 1]).any()
+    assert [int(s[3]) for s in sums] == [1] + [0] * (len(chunks) - 1)
+    assert [int(s[1]) for s in sums] == [N_MAMBA * n for n in chunks]
+
+
+def test_a_recurrence_taken_in_bfloat16_fails_the_float32_tolerance(cfg, params, monkeypatch):
+    """What ATOL is there to catch: the same float32 program with the scan's
+    inputs and state rounded to bfloat16 a token is off by more than ATOL."""
+    scan = jamba._scan_tokens
+
+    def rounded(lp, s, delta, x, b, c, valid):
+        low = [a.astype(jnp.bfloat16).astype(jnp.float32) for a in (s, delta, x, b, c)]
+        y, s = scan(lp, *low, valid)
+        return y, s.astype(jnp.bfloat16).astype(jnp.float32)
+
+    monkeypatch.setattr(jamba, "_scan_tokens", rounded)
+    tokens, got, *_ = prefill_then_decode(cfg, params, (16, 9))
+    want = np.asarray(ref.logits(params, SHAPE, jnp.asarray(tokens), jnp.arange(len(tokens))))
+    assert np.abs(got - want).max() > 5 * ATOL
+
+
+def test_the_convolutions_tail_carries_across_a_chunk_boundary(cfg, params):
+    """After a chunk of 7 tokens a layer's tail holds its inputs 4, 5 and 6
+    (oldest first), and a second chunk that starts from a zeroed tail is
+    wrong by far more than ATOL."""
+    tokens, got, *_ = prefill_then_decode(cfg, params, (7, 9))
+    want = np.asarray(ref.logits(params, SHAPE, jnp.asarray(tokens), jnp.arange(len(tokens))))
+    np.testing.assert_allclose(got, want, atol=ATOL)
+
+    seen = {}
+
+    def zeroed(state):
+        seen.setdefault("tail", np.asarray(state["conv"][0][0, 2]))
+        return {"s": state["s"], "conv": jax.tree.map(jnp.zeros_like, state["conv"])}
+
+    _, cut, *_ = prefill_then_decode(cfg, params, (7, 9), between=zeroed)
+    assert np.abs(cut[7:] - want[7:]).max() > 100 * ATOL
+    np.testing.assert_allclose(cut[:7], want[:7], atol=ATOL)
+    # layer 0's inputs: the x half of the in-projection of the normed embedding
+    lp = jax.tree.map(lambda a: a[0], params["mamba"][0])
+    u = llama.rms_norm(params["embed"][jnp.asarray(tokens[:7])], lp["mixer_norm"], cfg.rms_norm_eps)
+    x = np.asarray(u @ lp["w_in"])[:, :cfg.d_inner]
+    np.testing.assert_allclose(seen["tail"].reshape(3, cfg.d_inner), x[4:7], atol=1e-5)
+
+
+def test_a_decode_step_is_one_trip_of_the_chunks_token_loop(cfg, params):
+    """The step form and the chunk form of the recurrence are one body: eleven
+    tokens one at a time give the scan's state and outputs to float32 rounding
+    (the compiler fuses a multiply and an add inside the loop and not outside
+    it, so not bit for bit)."""
+    lp = jax.tree.map(lambda a: a[0], params["mamba"][0])
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    rows, t, n, d = 3, 11, cfg.mamba_d_state, cfg.d_inner
+    delta = jax.nn.softplus(jax.random.normal(ks[0], (rows, t, d)))
+    x, b, c = (jax.random.normal(k, shape) for k, shape in zip(
+        ks[1:4], [(rows, t, d), (rows, t, n), (rows, t, n)]))
+    valid = jnp.arange(t)[None, :] < jnp.asarray([11, 6, 0])[:, None]
+    s0 = jax.random.normal(ks[4], (rows, n, d))
+    y, s = jamba._scan_tokens(lp, s0, delta, x, b, c, valid)
+    s1, ys = s0, []
+    for i in range(t):
+        y1, s1 = jamba._scan_tokens(lp, s1, *(a[:, i:i + 1] for a in (delta, x, b, c, valid)))
+        ys.append(y1)
+    np.testing.assert_allclose(np.asarray(s), np.asarray(s1), rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(jnp.concatenate(ys, axis=1)), rtol=1e-5, atol=1e-5)
+    assert np.array_equal(np.asarray(s[2]), np.asarray(s0[2]))  # a row of padding keeps its state
+
+
+@pytest.mark.parametrize("shape, what", [
+    (SHAPE, "accepted"),
+    (dict(SHAPE, num_experts=16, num_experts_per_tok=2), "refused"),
+    ({"model_type": "qwen2", "hidden_size": 64}, "llama"),
+    ({"model_type": "some_moe", "num_experts": 64, "hidden_size": 64}, "impostor"),
+], ids=["num_experts_1", "an_expert_jamba", "a_qwen_card", "another_expert_card"])
+def test_config_from_card_picks_the_module_by_model_type(shape, what):
+    """``num_experts: 1`` under ``model_type: jamba`` is the dense model and is
+    read; an expert Jamba is refused by name; the other cards go where they
+    went (the Kimi card: tests/test_kimi_linear.py)."""
+    if what == "accepted":
+        c = config_from_card(card(shape), jnp.float32)
+        assert isinstance(c, jamba.JambaConfig) and module_for(c) is jamba
+        assert (c.head_dim, c.d_inner, c.mamba_dt_rank, c.tie_embeddings) == (16, 128, 8, True)
+    elif what == "refused":
+        with pytest.raises(ValueError, match="model_type 'jamba' with num_experts = 16"):
+            config_from_card(card(shape))
+    elif what == "llama":
+        assert isinstance(config_from_card(card(shape)), llama.LlamaConfig)
+    else:
+        with pytest.raises(ValueError, match="no module here runs it"):
+            config_from_card(card(shape))
+
+
+def test_serving_a_qwen_card_imports_no_other_models_module():
+    """A third module costs a Qwen start-up nothing: ``config_from_card`` and
+    ``module_for`` import a module in its own branch alone."""
+    code = (
+        "import sys, types\n"
+        "from dynamo_tpu.engine_jax.weights import config_from_card\n"
+        "from dynamo_tpu.models import module_for\n"
+        "import dynamo_tpu.engine_jax.engine\n"
+        "c = config_from_card(types.SimpleNamespace(model_config={'model_type': 'qwen2'}))\n"
+        "assert module_for(c).__name__ == 'dynamo_tpu.models.llama'\n"
+        "print([m for m in ('jamba', 'kimi_linear') if 'dynamo_tpu.models.' + m in sys.modules])\n"
+    )
+    done = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True, timeout=110,
+                          env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert done.returncode == 0 and done.stdout.strip().endswith("[]"), done.stdout + done.stderr[-800:]
+
+
+def served(engine, prompt, max_tokens, **sampling):
+    seq = submit(engine, prompt, max_tokens, **sampling)
+    run_out(engine)
+    return answer(seq)
+
+
+def test_the_engine_serves_the_reference_greedy_tokens_and_logprobs(engine, params):
+    """Through ``JaxServingEngine``: admission, three chunk dispatches,
+    pipelined decode dispatches of 4 steps, sampling and log-probabilities,
+    the seal-time checksums over a slot model's K and V members."""
+    prompt = prompt_of(37)
+    toks, lps, finish = served(engine, prompt, 10, logprobs=5)
+    seq = jnp.asarray(prompt + toks[:-1], jnp.int32)
+    want = np.asarray(ref.logits(params, SHAPE, seq, jnp.arange(len(prompt) - 1, len(seq))))
+    assert toks == want.argmax(-1).tolist() and len(toks) == 10 and finish == "length"
+    logp = want - np.log(np.exp(want).sum(-1, keepdims=True))
+    np.testing.assert_allclose(lps, logp[np.arange(10), toks], atol=ATOL)
+    snap = engine.metrics_snapshot()
+    assert snap["ssm_layer_calls"] > 0 and snap["slot_state_resets"] >= 1
+    assert set(jamba.COUNTERS) <= set(snap) and not any(k.startswith(("moe_", "kda_")) for k in snap)
+    # the module says what its programs read of the tables: the live part, not all
+    assert 0 < snap["chunk_history_tiles_read"] <= snap["chunk_history_tiles_full"]
+    assert 0 < snap["decode_history_tiles_read"] <= snap["decode_history_tiles_full"]
+    assert set(engine.cache) == {"k", "v"} and engine.cache["k"].shape[0] == 2
+    tiers = list(snap["attention_tiers"].values())
+    assert tiers and all(t == {"tier": "dense", "interpret": False} for t in tiers)
+
+
+def test_the_counters_count_what_a_served_prompt_did(engine):
+    """A prompt of 40 tokens and 4 answered: every one of the four Mamba layers
+    advances the 40 prompt tokens in the chunk program, a pass of the row's
+    state a token under the token scan; three chunk dispatches and the decode
+    steps each run the four layers."""
+    before = engine.metrics_snapshot()
+    served(engine, prompt_of(40, salt=11), 4)
+    after = engine.metrics_snapshot()
+    rise = {k: after[k] - before[k] for k in jamba.COUNTERS}
+    assert rise["ssm_chunk_tokens"] == rise["ssm_state_passes"] == N_MAMBA * 40
+    assert rise["slot_state_resets"] == 1
+    # 3 chunk dispatches + the decode dispatches' 4 steps each (3 more tokens: 1 or 2 dispatches)
+    assert rise["ssm_layer_calls"] in (N_MAMBA * (3 + 4), N_MAMBA * (3 + 8))
+
+
+def test_a_reused_slot_gives_what_the_request_gives_alone(engine, cfg, params):
+    """Four requests fill every slot and leave their state behind; a fifth
+    admitted into a used slot, beside another that still decodes, answers as
+    it does alone on a new engine: the slot was zeroed on admission."""
+    fresh = JaxServingEngine(cfg, params, ENGINE_CFG)
+    alone = served(fresh, prompt_of(21, salt=9), 8)[0]
+    fresh.close()
+    before = engine.metrics_snapshot()["slot_state_resets"]
+    for salt in range(4):
+        submit(engine, prompt_of(30 + salt, salt=salt), 6)
+    run_out(engine)
+    long_one = submit(engine, prompt_of(25, salt=5), 24)
+    for _ in range(4):
+        step(engine)
+    assert long_one.slot is not None
+    late = submit(engine, prompt_of(21, salt=9), 8)
+    run_out(engine)
+    assert answer(late)[0] == alone
+    assert engine.metrics_snapshot()["slot_state_resets"] == before + 6
+
+
+def test_a_repeated_prompt_takes_no_prefix_hit(engine):
+    """The pages of a prompt served before are in the prefix cache; the state
+    that goes with them is not, so the hit is declined, the prompt prefills
+    from position 0, and the answer is the first one's."""
+    prompt = prompt_of(40, salt=3)
+    first = served(engine, prompt, 6)[0]
+    declined, resets = engine.prefix_hits_declined, engine.model_counters["slot_state_resets"]
+    seq = submit(engine, prompt, 6)
+    step(engine)
+    assert seq.alloc.cached_tokens == 0 and seq.alloc.declined_tokens == 32
+    run_out(engine)
+    assert answer(seq)[0] == first
+    assert engine.prefix_hits_declined == declined + 1
+    assert engine.model_counters["slot_state_resets"] == resets + 1
+
+
+@pytest.mark.parametrize("what", [
+    "export_migratable", "stage_migration", "set_remote_prefill_policy", "extract_blocks",
+    "seed_external_prefix", "the host tier", "a mesh"])
+def test_what_would_hand_pages_over_without_the_state_is_refused_by_name(engine, cfg, params, what):
+    """Migration, disaggregated prefill, page transfer and the host tier each
+    raise ``StateNotPortable`` (a ``MigrationRejected``) with the reason; a
+    mesh is refused at construction."""
+    assert issubclass(StateNotPortable, MigrationRejected)
+    calls = {
+        "export_migratable": engine.export_migratable,
+        "stage_migration": lambda: engine.stage_migration({"token_ids": [1, 2, 3]}, {}),
+        "set_remote_prefill_policy": lambda: engine.set_remote_prefill_policy(object()),
+        "extract_blocks": lambda: engine.extract_blocks([0]),
+        "seed_external_prefix": lambda: engine.seed_external_prefix([1] * 8, {}),
+    }
+    if what in calls:
+        with pytest.raises(StateNotPortable, match="JambaConfig keeps state per slot"):
+            calls[what]()
+    elif what == "the host tier":
+        with pytest.raises(StateNotPortable, match="the host tier"):
+            JaxServingEngine(cfg, params, EngineConfig(
+                max_slots=2, kv_block_size=8, max_model_len=64, host_cache_blocks=4))
+    else:
+        with pytest.raises(ValueError, match="one device"):
+            JaxServingEngine(cfg, params, ENGINE_CFG, mesh=object())
+        with pytest.raises(NotImplementedError, match="one device"):
+            jamba.param_shardings(cfg, object())
+
+
+def test_the_step_programs_carry_the_three_scopes(engine):
+    """The device trace finds the mechanisms by name: ``mamba``, ``attn`` and
+    ``mlp`` are scopes of both step programs."""
+    def sd(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype)
+
+    s, c, mb = ENGINE_CFG.max_slots, ENGINE_CFG.prefill_chunk, ENGINE_CFG.max_blocks_per_seq
+    pool = (jax.tree.map(sd, engine.params), jax.tree.map(sd, engine.cache),
+            jax.tree.map(sd, engine.slot_state), sd(engine._dummy_counts))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+    wd = (i32(),) if engine._watchdog else ()
+    chunk = engine._build_chunk_fn(False, False, False).lower(
+        *pool, i32(s, c), i32(s, c), i32(s, mb), i32(s), i32(s), i32(), i32(2, s), f32(4, s), *wd)
+    decode = engine._build_decode_fn(False, False, False).lower(
+        *pool, i32(s), i32(s), i32(s, mb), i32(), i32(2, s), f32(4, s), *wd)
+    for program in (chunk, decode):
+        names = set(re.findall(r'loc\("(?:[^"]*/)?(mamba|attn|mlp)/', program.as_text(debug_info=True)))
+        assert names == {"mamba", "attn", "mlp"}, names

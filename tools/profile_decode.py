@@ -6,7 +6,9 @@ the served shapes by history profile, the live form beside the full-width one
 ``batch.kimi-linear-48b-a3b``'s two shapes by routing and by grouped product
 (:func:`expert_profiles`); ``... kda`` times a KDA layer's recurrence ALONE
 over a chunk, the scan over the tokens beside the kernel that keeps the state
-on the chip (:func:`kda_profiles`); without a word, the round-5 ablation below.
+on the chip (:func:`kda_profiles`); ``... mamba`` times a Mamba layer's
+selective scan ALONE over a chunk and over a decode step beside the whole mixer
+(:func:`mamba_profiles`); without a word, the round-5 ablation below.
 
 Method notes:
 - every measurement chains computations via data dependencies and fences
@@ -609,6 +611,68 @@ def kda_profiles():
               f"{float(jnp.abs(o_scan).max()):.3g}", flush=True)
 
 
+def mamba_profiles():
+    """The selective scan of ONE Mamba layer of ``jamba2-3b`` (a float32 state
+    of ``[16, 5120]`` a row) ALONE over a chunk of 128 tokens, from a carried
+    state: ``models/jamba.py:_scan_tokens``, a ``lax.scan`` over the tokens
+    whose carry is the rows' state, beside the whole mixer of the layer (the
+    four projections, the convolution, the norms and the scan): the scan's
+    share of a Mamba layer of ``jit_chunk``. At the rungs of 64 slots (8, 16 and
+    64 rows), full rows (a token costs the state's pass whatever is valid).
+    Then a decode step's pass: 64 lanes, one token, the same body.
+    PROF_ITERS (default 8) layers chained in one dispatch, each from the state
+    the last one left. PROF_UNROLLS (e.g. ``2,16``; through ``chiprun -- env``)
+    times the scan again at those tokens a trip (``jamba.SCAN_UNROLL``).
+    PROF_DINNER (default 5120) cuts the width for a rehearsal on the CPU."""
+    from dynamo_tpu.engine_jax.compile_cache import enable_compile_cache
+    from dynamo_tpu.models import jamba
+
+    enable_compile_cache()
+    n_iter = int(os.environ.get("PROF_ITERS", "8"))
+    d = int(os.environ.get("PROF_DINNER", "5120"))
+    c = jamba.JambaConfig(hidden_size=d // 2, num_layers=1, attn_layer_offset=1, vocab_size=256)
+    lp = jax.tree.map(lambda a: a[0], jamba.init_params(jax.random.PRNGKey(0), c)["mamba"][0])
+    n = c.mamba_d_state
+
+    def timed(fn, *args):
+        @jax.jit
+        def chain(s, *args):
+            def layer(s, _):
+                y, s = fn(s, *args)
+                return s, y[:, :, 0]
+            return jax.lax.scan(layer, s, None, length=n_iter)
+
+        chain(*args)[0].block_until_ready()
+        times = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            chain(*args)[0].block_until_ready()
+            times.append(time.perf_counter() - t0)
+        return float(np.median(times)) * 1e3 / n_iter
+
+    for rows, t in ((8, 128), (16, 128), (64, 128), (64, 1)):
+        key = jax.random.split(jax.random.PRNGKey(rows), 6)
+        delta = jax.nn.softplus(jax.random.normal(key[0], (rows, t, d), jnp.float32) - 3.0)
+        x = jax.random.normal(key[1], (rows, t, d), jnp.float32)
+        b, cc = (jax.random.normal(key[i], (rows, t, n), jnp.float32) for i in (2, 3))
+        s0 = jax.random.normal(key[4], (rows, n, d), jnp.float32)
+        u = jax.random.normal(key[5], (rows, t, c.hidden_size), jnp.float32).astype(c.dtype)
+        tail = jnp.zeros((rows, (c.mamba_d_conv - 1) * d), jnp.float32)
+        valid = jnp.ones((rows, t), bool)
+        ms_scan = timed(lambda s, *a: jamba._scan_tokens(lp, s, *a), s0, delta, x, b, cc, valid)
+        for unroll in [int(u) for u in os.environ.get("PROF_UNROLLS", "").split(",") if u and t > 1]:
+            was, jamba.SCAN_UNROLL = jamba.SCAN_UNROLL, unroll
+            ms = timed(lambda s, *a: jamba._scan_tokens(lp, s, *a), s0, delta, x, b, cc, valid)
+            jamba.SCAN_UNROLL = was
+            print(f"mamba {rows:2d} rows x {t:3d} tokens: scan at unroll {unroll:3d} {ms:7.3f} ms a layer", flush=True)
+        ms_mixer = timed(lambda s, u, valid, tail: jamba.mamba_mixer(lp, c, u, valid, s, tail)[:2],
+                         s0, u, valid, tail)
+        state_gb = 2 * rows * t * n * d * 4 / 1e9  # read and written once a token
+        print(f"mamba {rows:2d} rows x {t:3d} tokens: scan {ms_scan:7.3f} ms a layer "
+              f"({state_gb / ms_scan * 1e3:6.1f} GB/s of state a token pass), "
+              f"whole mixer {ms_mixer:7.3f} ms a layer", flush=True)
+
+
 if __name__ == "__main__":
-    {"history": history_profiles, "experts": expert_profiles, "kda": kda_profiles}.get(
-        " ".join(sys.argv[1:2]), main)()
+    {"history": history_profiles, "experts": expert_profiles, "kda": kda_profiles,
+     "mamba": mamba_profiles}.get(" ".join(sys.argv[1:2]), main)()
