@@ -41,9 +41,12 @@ MXU's own rounding, which is all that is left of bfloat16's noise (0.025).
 Float32 inside the recurrence, as the published kernels compute it: the
 convolution over the float32 tail, the three inner norms, the step size after
 its softplus, ``exp(step x A)``, the state, the sum over N. The recurrence
-advances ``[rows, N, D]`` token by token (:func:`_scan_tokens`); a chunk's is a
-``lax.scan`` over its tokens and a decode step's is one trip of the same
-body. ``[rows, T, N, D]`` never exists.
+advances ``[rows, N, D]`` token by token (:func:`_scan_tokens`): a chunk's runs
+in ``ops/pallas/selective_scan.py``, one kernel that holds a row's state on the
+chip from the row's first token to its last valid one (HBM sees it once in and
+once out), and a decode step's, one token, is one elementwise pass over the
+lanes' state in place. The token count the code sees picks the path.
+``[rows, T, N, D]`` never exists.
 """
 
 from __future__ import annotations
@@ -60,6 +63,7 @@ from dynamo_tpu.models.llama import (  # noqa: F401  (the two tile counts are th
     chunk_history_partial, chunk_history_tiles, decode_history_tiles, embed_lookup, flush_window,
     history_tile, history_tiles_full, rms_norm, with_live_history,
 )
+from dynamo_tpu.ops.pallas.selective_scan import selective_scan
 
 Params = Dict[str, Any]
 KVCache = Dict[str, jax.Array]  # {"k", "v"}: [L_attn, N, bs, KVH, D]
@@ -68,19 +72,11 @@ SlotState = Dict[str, Tuple[jax.Array, ...]]  # {"s": per run [n, S, N, D], "con
 # sums the step programs return, in this order (engine: /debug/engine):
 # Mamba layers run (a chunk dispatch or a decode step each count their 26);
 # valid tokens the chunks' recurrences advanced, and the times a chunk row's
-# state went through the token loop (one a token under the scan: the ratio is
-# what a kernel that keeps a row's state on the chip would raise), both summed
-# over the Mamba layers; rows that started a request
+# state went from HBM to the chip and back (one a real row that holds a valid
+# token: the kernel keeps it there over the row's tokens, where the scan it
+# replaced made a pass a token), both summed over the Mamba layers; rows that
+# started a request
 COUNTERS = ("ssm_layer_calls", "ssm_chunk_tokens", "ssm_state_passes", "slot_state_resets")
-# tokens a trip of a chunk's token loop advances (`lax.scan`'s `unroll`). Every
-# operation of every trip is an event of a device trace, and at one token a trip
-# the 26 loops of a chunk dispatch made 1.1 million events in a traced 4 s (the
-# benchmark's export then takes 144 s of the 135-145 it is given: ROADMAP B0). At
-# 16 the compiler's program runs a third of the operations a layer (384 against
-# 1,152) for 7 % more time at 8 and 16 rows (1.147 / 1.795 ms a layer against
-# 1.076 / 1.691 alone on the chip; 2 is the fastest, 0.960 / 1.438, and saves
-# few events; 128 wins at 8 rows and loses at 16: PERF.md 6, PR 41).
-SCAN_UNROLL = 16
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -292,20 +288,18 @@ def _scan_tokens(lp: Params, s: jax.Array, delta: jax.Array, x: jax.Array, b: ja
     ``[B, N, D]``, all float32 and elementwise: ``s = exp(delta A) * s +
     (delta x) B``, ``y = s C`` (summed over N). A token that is not valid
     leaves the state as it is. Returns (``y`` ``[B, T, D]``, the state after
-    the last valid token). One token is one trip of the same body."""
+    the last valid token). One token (a decode step) is one elementwise pass
+    over the rows' state; more (a chunk) are one call of the kernel that keeps
+    a row's state on the chip over its valid tokens, a prefix of the row
+    (``y`` past them: zeros)."""
     a = -jnp.exp(lp["a_log"])  # [N, D]
-
-    def token(s, xs):
-        d_t, x_t, b_t, c_t, v_t = xs  # [B, D], [B, D], [B, N], [B, N], [B]
-        new = jnp.exp(d_t[:, None, :] * a) * s + (d_t * x_t)[:, None, :] * b_t[:, :, None]
-        return jnp.where(v_t[:, None, None], new, s), jnp.sum(new * c_t[:, :, None], axis=1)
-
-    if delta.shape[1] == 1:
-        s, y = token(s, (delta[:, 0], x[:, 0], b[:, 0], c[:, 0], valid[:, 0]))
-        return y[:, None], s
-    s, y = jax.lax.scan(token, s, tuple(jnp.moveaxis(a_, 1, 0) for a_ in (delta, x, b, c, valid)),
-                        unroll=SCAN_UNROLL)
-    return jnp.moveaxis(y, 0, 1), s
+    if delta.shape[1] > 1:
+        return selective_scan(delta, x, b, c, a, s, valid.sum(axis=1),
+                              interpret=jax.default_backend() == "cpu")
+    d_t, x_t, b_t, c_t, v_t = delta[:, 0], x[:, 0], b[:, 0], c[:, 0], valid[:, 0]
+    new = jnp.exp(d_t[:, None, :] * a) * s + (d_t * x_t)[:, None, :] * b_t[:, :, None]
+    y = jnp.sum(new * c_t[:, :, None], axis=1)
+    return y[:, None], jnp.where(v_t[:, None, None], new, s)
 
 
 def mamba_mixer(lp: Params, c: JambaConfig, u: jax.Array, valid: jax.Array,
@@ -437,8 +431,9 @@ def forward_chunk(
              "v": write_kv_to_pool(kv_cache["v"], jnp.stack(fresh_v), positions, block_tables)}
     h = rms_norm(h, params["final_norm"], c.rms_norm_eps)
     n_mamba = sum(_runs(c))
-    advanced = n_mamba * jnp.sum(valid & real[:, None])
-    counters = jnp.stack([jnp.int32(n_mamba), advanced, advanced, jnp.sum(fresh & real)])
+    counters = jnp.stack([
+        jnp.int32(n_mamba), n_mamba * jnp.sum(valid & real[:, None]),
+        n_mamba * jnp.sum(valid[:, 0] & real), jnp.sum(fresh & real)])
     return h, cache, {"s": tuple(s_out), "conv": tuple(conv_out)}, counters.astype(jnp.int32)
 
 

@@ -13,6 +13,7 @@ owns them.
 """
 
 import dataclasses
+import functools
 import math
 import re
 
@@ -592,20 +593,16 @@ def test_a_chunks_kda_mixer_compiles_with_the_state_on_the_chip(monkeypatch, one
 
 # -- the step programs of jamba2-3b ---------------------------------------------
 
-@pytest.mark.parametrize("program", ["decode", "chunk"])
-def test_jambas_step_programs_never_copy_the_slots_state(one_chip, program):
-    """``models/jamba.py`` at ``batch.jamba2-3b``'s served shapes (64 slots,
-    block 16, 12,288 blocks, 2,048 positions; a chunk at the 8-row rung): the
-    compiled program holds no copy of a run's ``f32[n,64,16,5120]`` state or of
-    its convolution tails. A decode step must read and write the 647 MB once:
-    under a ``lax.scan`` over the steps the compiler copied the state whole onto
-    the loop's carry every step, which is why the steps are unrolled and a run's
-    layers scan the state as ``xs`` / ``ys`` (PERF.md 6, PR 41). A chunk's rows
-    gather and scatter their slots by one flat index on the layer loop's carry."""
+@functools.lru_cache(maxsize=None)
+def _compile_jamba(program, one_chip, rows=8):
+    """``models/jamba.py``'s decode or chunk program at ``batch.jamba2-3b``'s
+    served shapes (64 slots, block 16, 12,288 blocks, 2,048 positions; a chunk of
+    ``rows`` x 128 tokens), the pool and the state donated, for the described
+    chip (compiled once a program and rung: two tests read the 8-row chunk)."""
     from dynamo_tpu.models import jamba
 
     c = jamba.JambaConfig()
-    slots, rows, mb, chunk = 64, 8, 128, 128
+    slots, mb, chunk = 64, 128, 128
 
     def sd(a):
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
@@ -620,21 +617,52 @@ def test_jambas_step_programs_never_copy_the_slots_state(one_chip, program):
         def greedy(logits, pos, carry, k):
             return jnp.argmax(logits, -1).astype(jnp.int32), carry, jnp.argmax(logits, -1)
 
-        compiled = jax.jit(
+        return jax.jit(
             lambda p, kv, st, toks, pos, tables: jamba.decode(
                 p, c, toks, pos, kv, tables, st, 4, 2047, greedy, 0),
             donate_argnums=(1, 2),
         ).lower(params, cache, state, i32(slots), i32(slots), i32(slots, mb)).compile()
-    else:
-        compiled = jax.jit(
-            lambda p, kv, st, toks, pos, tables, lanes: jamba.forward_chunk(
-                p, c, toks, pos, kv, tables, st, lanes),
-            donate_argnums=(1, 2),
-        ).lower(params, cache, state, i32(rows, chunk), i32(rows, chunk), i32(rows, mb),
-                i32(rows)).compile()
+    return jax.jit(
+        lambda p, kv, st, toks, pos, tables, lanes: jamba.forward_chunk(
+            p, c, toks, pos, kv, tables, st, lanes),
+        donate_argnums=(1, 2),
+    ).lower(params, cache, state, i32(rows, chunk), i32(rows, chunk), i32(rows, mb),
+            i32(rows)).compile()
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_jambas_step_programs_never_copy_the_slots_state(monkeypatch, one_chip, program):
+    """``models/jamba.py`` at ``batch.jamba2-3b``'s served shapes (a chunk at
+    the 8-row rung): the compiled program holds no copy of a run's
+    ``f32[n,64,16,5120]`` state or of its convolution tails. A decode step must
+    read and write the 647 MB once: under a ``lax.scan`` over the steps the
+    compiler copied the state whole onto the loop's carry every step, which is
+    why the steps are unrolled and a run's layers scan the state as ``xs`` /
+    ``ys`` (PERF.md 6, PR 41). A chunk's rows gather and scatter their slots by
+    one flat index on the layer loop's carry, and the view the chunk's kernel
+    wants is made of the rows' state, never of the slots'."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernel compiled, not interpreted
+    compiled = _compile_jamba(program, one_chip)
     hlo = re.sub(r"/\*.*?\*/", "", compiled.as_text())
     copies = re.findall(r"= f32\[\d+,64,(?:16,5120|15360)\]\{[^}]*\} copy\(", hlo)
     copies += re.findall(r"= bf16\[2,12288,16,1,128\]\{[^}]*\} copy\(", hlo)  # nor the pool
     assert copies == [], copies
     # both donated: the pool and the state come back in the buffers they came in
     assert compiled.memory_analysis().alias_size_in_bytes >= 647_495_680 + 201_326_592
+
+
+@pytest.mark.parametrize("rows", [8, 64])
+def test_jambas_chunk_program_holds_a_rows_state_on_the_chip(monkeypatch, one_chip, rows):
+    """The chunk program at the 8- and 64-row rungs of 64 slots:
+    ``ops/pallas/selective_scan.py`` is in the compiled program, once a run of
+    Mamba layers (its tiling, its SMEM blocks and its fast memory are what
+    interpret mode cannot see), and no loop carries the rows'
+    ``f32[rows,16,5120]`` state once a token through HBM, as the scan the kernel
+    replaced did."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hlo = re.sub(r"/\*.*?\*/", "", _compile_jamba("chunk", one_chip, rows).as_text())
+    kernels = re.findall(r"custom-call\([^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
+    assert len(kernels) == 3 and "selective_scan" in hlo, len(kernels)
+    carried = [line.strip()[:160] for line in hlo.splitlines()
+               if re.search(r"\bwhile\(", line) and f"f32[{rows},16,5120]" in line]
+    assert carried == [], carried

@@ -27,6 +27,7 @@ from dynamo_tpu.engine_jax.engine import EngineConfig, JaxServingEngine
 from dynamo_tpu.engine_jax.weights import config_from_card
 from dynamo_tpu.kv.pages import MigrationRejected, StateNotPortable
 from dynamo_tpu.models import jamba, llama, module_for
+from dynamo_tpu.ops.pallas.selective_scan import selective_scan
 
 from .test_chunk_rows import answer, run_out, step, submit
 
@@ -209,27 +210,98 @@ def test_the_convolutions_tail_carries_across_a_chunk_boundary(cfg, params):
     np.testing.assert_allclose(seen["tail"].reshape(3, cfg.d_inner), x[4:7], atol=1e-5)
 
 
-def test_a_decode_step_is_one_trip_of_the_chunks_token_loop(cfg, params):
-    """The step form and the chunk form of the recurrence are one body: eleven
-    tokens one at a time give the scan's state and outputs to float32 rounding
-    (the compiler fuses a multiply and an add inside the loop and not outside
-    it, so not bit for bit)."""
-    lp = jax.tree.map(lambda a: a[0], params["mamba"][0])
-    ks = jax.random.split(jax.random.PRNGKey(0), 5)
-    rows, t, n, d = 3, 11, cfg.mamba_d_state, cfg.d_inner
-    delta = jax.nn.softplus(jax.random.normal(ks[0], (rows, t, d)))
+def recurrence_inputs(cfg, rows, t, seed=0, step=0.0):
+    """Inputs as ``mamba_mixer`` makes them (the step size after its softplus,
+    ``step`` added before it) and a carried state."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    n, d = cfg.mamba_d_state, cfg.d_inner
+    delta = jax.nn.softplus(jax.random.normal(ks[0], (rows, t, d)) + step)
     x, b, c = (jax.random.normal(k, shape) for k, shape in zip(
         ks[1:4], [(rows, t, d), (rows, t, n), (rows, t, n)]))
-    valid = jnp.arange(t)[None, :] < jnp.asarray([11, 6, 0])[:, None]
-    s0 = jax.random.normal(ks[4], (rows, n, d))
-    y, s = jamba._scan_tokens(lp, s0, delta, x, b, c, valid)
-    s1, ys = s0, []
-    for i in range(t):
-        y1, s1 = jamba._scan_tokens(lp, s1, *(a[:, i:i + 1] for a in (delta, x, b, c, valid)))
-        ys.append(y1)
-    np.testing.assert_allclose(np.asarray(s), np.asarray(s1), rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(jnp.concatenate(ys, axis=1)), rtol=1e-5, atol=1e-5)
-    assert np.array_equal(np.asarray(s[2]), np.asarray(s0[2]))  # a row of padding keeps its state
+    return (delta, x, b, c), jax.random.normal(ks[4], (rows, n, d))
+
+
+def one_token_at_a_time(lp, s0, delta, x, b, c, valid):
+    """What the kernel replaces: ``_scan_tokens``'s one-token form (a decode
+    step's) over the tokens in turn, outputs past a row's valid tokens zeroed."""
+    def token(s, xs):
+        y, s = jamba._scan_tokens(lp, s, *(a[:, None] for a in xs))
+        return s, jnp.where(xs[4][:, None], y[:, 0], 0.0)
+
+    s, y = jax.lax.scan(token, s0, tuple(jnp.moveaxis(a, 1, 0) for a in (delta, x, b, c, valid)))
+    return jnp.moveaxis(y, 0, 1), s
+
+
+def assert_float32_equal(s, y, want_s, want_y):
+    """The tolerance the step and the chunk form have been held to since PR 41."""
+    np.testing.assert_allclose(np.asarray(s), np.asarray(want_s), rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want_y), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("t", [11, 40])
+@pytest.mark.parametrize("start", ["zero", "carried"])
+def test_a_decode_step_is_one_trip_of_the_chunks_token_loop(cfg, params, t, start):
+    """The step form and the chunk form of the recurrence are one body: ``t``
+    tokens taken one at a time give the kernel's state and outputs, for rows
+    that are full, partly valid (one token; a third of the row) and empty. To
+    float32 rounding and not bit for bit, either of them: a channel sees the
+    same products and sums in the same order, but the CPU's compiler fuses a
+    multiply and an add in one form and not in the other, and the sum over N is
+    the kernel's own (n = 0 first) where the step's is the compiler's. (On the
+    chip: ``tools/profile_decode.py mamba`` prints the largest difference.)"""
+    lp = jax.tree.map(lambda a: a[0], params["mamba"][0])
+    xs, carried = recurrence_inputs(cfg, 4, t, seed=t)
+    s0 = carried if start == "carried" else jnp.zeros_like(carried)
+    n_valid = np.asarray([t, t // 3, 1, 0])
+    valid = jnp.arange(t)[None, :] < jnp.asarray(n_valid)[:, None]
+    y, s = jamba._scan_tokens(lp, s0, *xs, valid)
+    want_y, want_s = one_token_at_a_time(lp, s0, *xs, valid)
+    assert_float32_equal(s, y, want_s, want_y)
+    assert np.array_equal(np.asarray(s[3]), np.asarray(s0[3]))  # a row of padding keeps its state
+
+
+def test_an_empty_rows_state_comes_back_bit_for_bit_and_its_outputs_are_zeros(cfg, params):
+    """A padding row of a rung: no valid token, whatever its inputs hold (here
+    infinities and NaNs, which a computed-and-thrown-away token would spread)."""
+    a = -jnp.exp(params["mamba"][0]["a_log"][0])
+    (delta, x, b, c), s0 = recurrence_inputs(cfg, 3, 16, seed=1)
+    x = x.at[1].set(jnp.nan)
+    delta = delta.at[1].set(jnp.inf)
+    y, s = selective_scan(delta, x, b, c, a, s0, jnp.asarray([16, 0, 5]), interpret=True)
+    assert np.array_equal(np.asarray(s[1]), np.asarray(s0[1]))
+    assert not np.asarray(y[1]).any() and not np.asarray(y[2, 5:]).any()
+    assert np.isfinite(np.asarray(y)).all() and np.isfinite(np.asarray(s)).all()
+    assert np.asarray(y[2, :5]).all() and np.asarray(y[0]).all()
+
+
+def test_the_chunk_kernel_takes_a_chunk_that_is_no_multiple_of_its_tile(cfg, params):
+    """300 tokens are two tiles of 128 and 44 of a third (the chunk is padded to
+    384 around the kernel): the state rides from a tile to the next on the
+    chip, and a row that ends inside the second tile stops there."""
+    from dynamo_tpu.ops.pallas import selective_scan as kernel
+
+    lp = jax.tree.map(lambda a: a[0], params["mamba"][0])
+    t = 2 * kernel.TILE + 44
+    xs, s0 = recurrence_inputs(cfg, 3, t, seed=2, step=-2.0)
+    valid = jnp.arange(t)[None, :] < jnp.asarray([t, kernel.TILE + 9, 0])[:, None]
+    y, s = jamba._scan_tokens(lp, s0, *xs, valid)
+    want_y, want_s = one_token_at_a_time(lp, s0, *xs, valid)
+    assert y.shape == want_y.shape
+    assert_float32_equal(s, y, want_s, want_y)
+
+
+def test_the_chunk_kernel_stands_a_step_whose_decay_underflows(cfg, params):
+    """A step size near 30: ``exp(delta A)`` is 0 in float32 for every state
+    row but the first few, so a token forgets what came before it. Nothing
+    overflows, nothing is NaN, and it is the step still."""
+    lp = jax.tree.map(lambda a: a[0], params["mamba"][0])
+    xs, s0 = recurrence_inputs(cfg, 2, 24, seed=3, step=30.0)
+    assert float(jnp.exp(-xs[0].min() * cfg.mamba_d_state)) == 0.0
+    valid = jnp.arange(24)[None, :] < jnp.asarray([24, 13])[:, None]
+    y, s = jamba._scan_tokens(lp, s0, *xs, valid)
+    want_y, want_s = one_token_at_a_time(lp, s0, *xs, valid)
+    assert np.isfinite(np.asarray(y)).all() and np.isfinite(np.asarray(s)).all()
+    assert_float32_equal(s, y, want_s, want_y)
 
 
 @pytest.mark.parametrize("shape, what", [
@@ -303,14 +375,15 @@ def test_the_engine_serves_the_reference_greedy_tokens_and_logprobs(engine, para
 
 def test_the_counters_count_what_a_served_prompt_did(engine):
     """A prompt of 40 tokens and 4 answered: every one of the four Mamba layers
-    advances the 40 prompt tokens in the chunk program, a pass of the row's
-    state a token under the token scan; three chunk dispatches and the decode
-    steps each run the four layers."""
+    advances the 40 prompt tokens in the chunk program, and the row's state goes
+    to the chip and back once a layer and dispatch (the kernel holds it there
+    over the dispatch's tokens); three chunk dispatches and the decode steps
+    each run the four layers."""
     before = engine.metrics_snapshot()
     served(engine, prompt_of(40, salt=11), 4)
     after = engine.metrics_snapshot()
     rise = {k: after[k] - before[k] for k in jamba.COUNTERS}
-    assert rise["ssm_chunk_tokens"] == rise["ssm_state_passes"] == N_MAMBA * 40
+    assert (rise["ssm_chunk_tokens"], rise["ssm_state_passes"]) == (N_MAMBA * 40, N_MAMBA * 3)
     assert rise["slot_state_resets"] == 1
     # 3 chunk dispatches + the decode dispatches' 4 steps each (3 more tokens: 1 or 2 dispatches)
     assert rise["ssm_layer_calls"] in (N_MAMBA * (3 + 4), N_MAMBA * (3 + 8))

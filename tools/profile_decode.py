@@ -27,6 +27,7 @@ import dataclasses
 import faulthandler
 import functools
 import os
+import shutil
 import sys
 import time
 
@@ -614,16 +615,26 @@ def kda_profiles():
 def mamba_profiles():
     """The selective scan of ONE Mamba layer of ``jamba2-3b`` (a float32 state
     of ``[16, 5120]`` a row) ALONE over a chunk of 128 tokens, from a carried
-    state: ``models/jamba.py:_scan_tokens``, a ``lax.scan`` over the tokens
-    whose carry is the rows' state, beside the whole mixer of the layer (the
-    four projections, the convolution, the norms and the scan): the scan's
-    share of a Mamba layer of ``jit_chunk``. At the rungs of 64 slots (8, 16 and
-    64 rows), full rows (a token costs the state's pass whatever is valid).
-    Then a decode step's pass: 64 lanes, one token, the same body.
+    state: ``ops/pallas/selective_scan.py`` with the layout XLA makes around it
+    (what ``models/jamba.py:_scan_tokens`` calls for a chunk: the state on the
+    chip over a row's valid tokens), beside the step it replaced scanned over
+    the tokens (``_scan_tokens``'s one-token form under ``lax.scan``: the rows'
+    state through HBM once a token) and the whole mixer of the layer (the four
+    projections, the convolution, the norms and the kernel). At the rungs of 64
+    slots (8, 16 and 64 rows): full rows, the valid counts the cell's traffic
+    gives a rung (5 of 8 and 12 of 16 rows hold a chunk of a prompt; all 64 in
+    the pre-roll's admission wave) and 8 rows of padding (the layout and a load
+    and a store of the state). The kernel's own event comes from a device
+    trace of one chain (the chain's ``lax.scan`` and the slice it stacks add to
+    the wall time). The kernel's share of the vector unit's bound:
+    ``bytes_and_flops_jamba.SCAN_OPS_PER_ELEMENT`` operations an element of a
+    valid token's ``[16, 5120]`` at 4 x 1,024 lanes a cycle of 1.5 GHz. Also
+    the largest difference between kernel and scanned step, state and outputs,
+    on this device. Then a decode step's pass: 64 lanes, one token.
     PROF_ITERS (default 8) layers chained in one dispatch, each from the state
-    the last one left. PROF_UNROLLS (e.g. ``2,16``; through ``chiprun -- env``)
-    times the scan again at those tokens a trip (``jamba.SCAN_UNROLL``).
-    PROF_DINNER (default 5120) cuts the width for a rehearsal on the CPU."""
+    the last one left. PROF_DINNER (default 5120) cuts the width for a
+    rehearsal on the CPU (interpreted)."""
+    from benchmark.bytes_and_flops_jamba import SCAN_OPS_PER_ELEMENT
     from dynamo_tpu.engine_jax.compile_cache import enable_compile_cache
     from dynamo_tpu.models import jamba
 
@@ -633,15 +644,27 @@ def mamba_profiles():
     c = jamba.JambaConfig(hidden_size=d // 2, num_layers=1, attn_layer_offset=1, vocab_size=256)
     lp = jax.tree.map(lambda a: a[0], jamba.init_params(jax.random.PRNGKey(0), c)["mamba"][0])
     n = c.mamba_d_state
+    vector_ops_per_s = 4 * 1024 * 1.5e9  # four vector slots an instruction over 8 x 128 lanes
 
-    def timed(fn, *args):
+    def scanned(s, delta, x, b, cc, valid):
+        def token(s, xs):
+            y, s = jamba._scan_tokens(lp, s, *(a[:, None] for a in xs))
+            return s, jnp.where(xs[4][:, None], y[:, 0], 0.0)
+
+        s, y = jax.lax.scan(token, s, tuple(jnp.moveaxis(a, 1, 0) for a in (delta, x, b, cc, valid)))
+        return jnp.moveaxis(y, 0, 1), s
+
+    def chained(fn):
         @jax.jit
         def chain(s, *args):
             def layer(s, _):
                 y, s = fn(s, *args)
                 return s, y[:, :, 0]
             return jax.lax.scan(layer, s, None, length=n_iter)
+        return chain
 
+    def timed(fn, *args):
+        chain = chained(fn)
         chain(*args)[0].block_until_ready()
         times = []
         for _ in range(5):
@@ -650,7 +673,25 @@ def mamba_profiles():
             times.append(time.perf_counter() - t0)
         return float(np.median(times)) * 1e3 / n_iter
 
-    for rows, t in ((8, 128), (16, 128), (64, 128), (64, 1)):
+    def kernel_event_ms(*args):
+        """The mean ``selective_scan`` event of one traced chain of the kernel:
+        the kernel's own time, without what the chain or XLA puts around it."""
+        from benchmark.trace_reduce import find_xplane, read_xplane
+
+        trace_dir = os.path.join("chiprun_out", "profile_decode", "mamba")
+        shutil.rmtree(trace_dir, ignore_errors=True)
+        chain = chained(lambda s, *a: jamba._scan_tokens(lp, s, *a))
+        chain(*args)[0].block_until_ready()
+        jax.profiler.start_trace(trace_dir)
+        chain(*args)[0].block_until_ready()
+        jax.profiler.stop_trace()
+        ops = read_xplane(find_xplane(trace_dir))["devices"].get("0", {"ops": []})["ops"]
+        events = [dur for name, _, dur in ops if "selective_scan" in name]
+        return float(np.mean(events)) / 1e6 if events else float("nan")
+
+    rng = np.random.default_rng(0)
+    for rows, t, used in ((8, 128, 5), (16, 128, 12), (8, 128, 8), (8, 128, 0), (16, 128, 16),
+                          (64, 128, 64), (64, 128, None), (64, 1, 64)):
         key = jax.random.split(jax.random.PRNGKey(rows), 6)
         delta = jax.nn.softplus(jax.random.normal(key[0], (rows, t, d), jnp.float32) - 3.0)
         x = jax.random.normal(key[1], (rows, t, d), jnp.float32)
@@ -658,19 +699,27 @@ def mamba_profiles():
         s0 = jax.random.normal(key[4], (rows, n, d), jnp.float32)
         u = jax.random.normal(key[5], (rows, t, c.hidden_size), jnp.float32).astype(c.dtype)
         tail = jnp.zeros((rows, (c.mamba_d_conv - 1) * d), jnp.float32)
-        valid = jnp.ones((rows, t), bool)
-        ms_scan = timed(lambda s, *a: jamba._scan_tokens(lp, s, *a), s0, delta, x, b, cc, valid)
-        for unroll in [int(u) for u in os.environ.get("PROF_UNROLLS", "").split(",") if u and t > 1]:
-            was, jamba.SCAN_UNROLL = jamba.SCAN_UNROLL, unroll
-            ms = timed(lambda s, *a: jamba._scan_tokens(lp, s, *a), s0, delta, x, b, cc, valid)
-            jamba.SCAN_UNROLL = was
-            print(f"mamba {rows:2d} rows x {t:3d} tokens: scan at unroll {unroll:3d} {ms:7.3f} ms a layer", flush=True)
+        counts = (np.full(rows, t, np.int32) if used == rows
+                  else chunk_valid_counts(rows, rows if used is None else used, rng))
+        valid = jnp.arange(t)[None, :] < jnp.asarray(counts)[:, None]
+        xs = (delta, x, b, cc, valid)
+        ms = timed(lambda s, *a: jamba._scan_tokens(lp, s, *a), s0, *xs)
         ms_mixer = timed(lambda s, u, valid, tail: jamba.mamba_mixer(lp, c, u, valid, s, tail)[:2],
                          s0, u, valid, tail)
-        state_gb = 2 * rows * t * n * d * 4 / 1e9  # read and written once a token
-        print(f"mamba {rows:2d} rows x {t:3d} tokens: scan {ms_scan:7.3f} ms a layer "
-              f"({state_gb / ms_scan * 1e3:6.1f} GB/s of state a token pass), "
-              f"whole mixer {ms_mixer:7.3f} ms a layer", flush=True)
+        if t == 1:
+            state_gb = 2 * rows * n * d * 4 / 1e9  # read and written once
+            print(f"mamba {rows:2d} rows x   1 token: the step's pass {ms:7.3f} ms a layer "
+                  f"({state_gb / ms * 1e3:6.1f} GB/s of state), whole mixer {ms_mixer:7.3f}", flush=True)
+            continue
+        bound = int(counts.sum()) * n * d * SCAN_OPS_PER_ELEMENT / vector_ops_per_s * 1e3
+        (y_k, s_k), (y_s, s_s) = jax.jit(lambda *a: jamba._scan_tokens(lp, *a))(s0, *xs), jax.jit(scanned)(s0, *xs)
+        event = kernel_event_ms(s0, *xs)
+        print(f"mamba {rows:2d} rows, valid {counts.tolist() if rows <= 16 else '...'} ({int(counts.sum())} tokens): "
+              f"kernel {ms:7.3f} ms a layer in the chain, its own event {event:7.3f} "
+              f"({bound / event:5.1%} of the vector unit's bound, {bound:.3f} ms), "
+              f"scanned step {timed(scanned, s0, *xs):7.3f}, whole mixer {ms_mixer:7.3f}; largest difference "
+              f"state {float(jnp.abs(s_k - s_s).max()):.3g} of {float(jnp.abs(s_s).max()):.3g}, "
+              f"outputs {float(jnp.abs(y_k - y_s).max()):.3g} of {float(jnp.abs(y_s).max()):.3g}", flush=True)
 
 
 if __name__ == "__main__":
