@@ -104,6 +104,42 @@ def jamba_config(mc: Dict[str, Any], dtype: Any = jnp.bfloat16):
     )
 
 
+def lfm2_config(mc: Dict[str, Any], dtype: Any = jnp.bfloat16):
+    """``model_type: lfm2_moe``: gated short convolutions beside rotary
+    attention layers, dense feed-forwards first and experts after. The
+    rotation's base is read from the published ``rope_parameters`` group where
+    the card has it, else from a flat ``rope_theta`` (a harness that writes
+    only scalar and list keys)."""
+    from dynamo_tpu.models.lfm2 import Lfm2Config
+
+    if mc.get("conv_bias", False):
+        raise ValueError("model_type 'lfm2_moe' with conv_bias: models/lfm2.py has no bias anywhere")
+    hidden, heads = int(mc["hidden_size"]), int(mc["num_attention_heads"])
+    rope = mc.get("rope_parameters") or {}
+    return Lfm2Config(
+        vocab_size=int(mc["vocab_size"]),
+        hidden_size=hidden,
+        intermediate_size=int(mc["intermediate_size"]),
+        num_layers=int(mc["num_hidden_layers"]),
+        num_heads=heads,
+        num_kv_heads=int(mc.get("num_key_value_heads", heads)),
+        head_dim=int(mc.get("head_dim") or hidden // heads),
+        layer_types=tuple(str(kind) for kind in mc["layer_types"]),
+        num_dense_layers=int(mc.get("num_dense_layers", 2)),
+        conv_kernel=int(mc.get("conv_L_cache", 3)),
+        moe_intermediate_size=int(mc["moe_intermediate_size"]),
+        num_experts=int(mc["num_experts"]),
+        num_experts_per_tok=int(mc["num_experts_per_tok"]),
+        norm_topk_prob=bool(mc.get("norm_topk_prob", True)),
+        use_expert_bias=bool(mc.get("use_expert_bias", True)),
+        routed_scaling_factor=float(mc.get("routed_scaling_factor", 1.0)),
+        norm_eps=float(mc.get("norm_eps", 1e-5)),
+        rope_theta=float(rope["rope_theta"] if "rope_theta" in rope else mc.get("rope_theta", 1000000.0)),
+        tie_embeddings=bool(mc.get("tie_word_embeddings", True)),
+        dtype=dtype,
+    )
+
+
 def config_from_card(card: ModelDeploymentCard, dtype: Any = jnp.bfloat16):
     """Derive the model's config from the card's HF config.json contents: a
     LlamaConfig, or by ``model_type`` another module's (models.module_for),
@@ -113,6 +149,8 @@ def config_from_card(card: ModelDeploymentCard, dtype: Any = jnp.bfloat16):
         return kimi_linear_config(mc, dtype)
     if mc.get("model_type") == "jamba":
         return jamba_config(mc, dtype)
+    if mc.get("model_type") == "lfm2_moe":
+        return lfm2_config(mc, dtype)
     if "num_experts" in mc and "num_local_experts" not in mc:
         # an expert model of a family this tree has no module for: a
         # LlamaConfig of it would be a dense impostor under its name

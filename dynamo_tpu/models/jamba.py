@@ -27,9 +27,10 @@ loop, and the depth is not unrolled. Two kinds of state live side by side:
   see it.
 
 The weights are bfloat16 and the activations float32 from the embedding to
-the head (:func:`_dot`). Nothing here routes, and bfloat16 activations were the
-first build; on the chip they read ``logprob_rms`` 0.077 against the float32
-reference where the dense decoders read 0.017 (random weights of this
+the head (``ops/parts.py:dot_parts``, here ``_dot``). Nothing here routes, and
+bfloat16 activations were the first build; on the chip they read
+``logprob_rms`` 0.077 against the float32 reference where the dense decoders
+read 0.017 (random weights of this
 architecture carry a rounding 4.5 x as far: PERF.md 6, PR 41), and by part: the
 stream and the products' outputs rounded to bfloat16 are over half of it (free
 to keep in float32), then the inputs of the in-projection, of the
@@ -64,6 +65,7 @@ from dynamo_tpu.models.llama import (  # noqa: F401  (the two tile counts are th
     history_tile, history_tiles_full, rms_norm, with_live_history,
 )
 from dynamo_tpu.ops.pallas.selective_scan import selective_scan
+from dynamo_tpu.ops.parts import dot_parts as _dot
 
 Params = Dict[str, Any]
 KVCache = Dict[str, jax.Array]  # {"k", "v"}: [L_attn, N, bs, KVH, D]
@@ -77,7 +79,6 @@ SlotState = Dict[str, Tuple[jax.Array, ...]]  # {"s": per run [n, S, N, D], "con
 # replaced made a pass a token), both summed over the Mamba layers; rows that
 # started a request
 COUNTERS = ("ssm_layer_calls", "ssm_chunk_tokens", "ssm_state_passes", "slot_state_resets")
-HIGHEST = jax.lax.Precision.HIGHEST
 
 
 @dataclass(frozen=True)
@@ -246,28 +247,6 @@ def make_slot_state(config: JambaConfig, slots: int) -> SlotState:
 # again): 16 bits of the float32 activation. With every product so the chip
 # reads 0.0006 against the reference, with none 0.051 (PERF.md 6, PR 41).
 PARTS = 2
-
-
-def _dot(x: jax.Array, w: jax.Array, parts: int = 1) -> jax.Array:
-    """``x @ w`` with a float32 result, for a float32 ``x``. Against a
-    bfloat16 weight (the served case) ``x`` goes to the MXU as ``parts``
-    bfloat16 arrays whose sum is ``x`` to ``8 * parts`` bits, stacked into ONE
-    product so that the weight is read once, their products added in float32.
-    The rounding is ``reduce_precision``, which the compiler keeps (a float32
-    -> bfloat16 -> float32 pair of converts it may drop: models/kimi_linear.py
-    found it so). Any other weight (the float32 weights of a CPU test) is
-    multiplied as it is, at float32's own precision."""
-    x = x.astype(jnp.float32)
-    if w.dtype != jnp.bfloat16:
-        return jnp.dot(x, w.astype(jnp.float32), precision=HIGHEST)
-    if parts == 1:
-        return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
-    split, rest = [], x
-    for _ in range(parts):
-        part = jax.lax.reduce_precision(rest, exponent_bits=8, mantissa_bits=7)
-        split.append(part.astype(w.dtype))
-        rest = rest - part
-    return jnp.dot(jnp.stack(split), w, preferred_element_type=jnp.float32).sum(axis=0)
 
 
 def lm_head(params: Params, config: JambaConfig, h: jax.Array) -> jax.Array:

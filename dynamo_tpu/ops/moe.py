@@ -208,18 +208,20 @@ def route_sigmoid_topk(
     top_k: int,
     scale: float,
     renormalize: bool = True,
+    renormalize_eps: float = 1e-20,
 ) -> Tuple[jax.Array, jax.Array]:
     """Sigmoid scores in float32 at the highest precision (a near-tie decides
     which expert computes); the ``top_k`` largest of score + bias are chosen,
-    and a chosen expert weighs ``scale * score / sum of the chosen scores``:
-    the bias moves the choice and never the weight. Returns (ids ``[T, k]``
-    int32, weights ``[T, k]`` float32)."""
+    and a chosen expert weighs ``scale * score / (sum of the chosen scores +
+    renormalize_eps)``: the bias moves the choice and never the weight, and the
+    epsilon is the published code's of the model's family (1e-20 Kimi's, 1e-6
+    LFM2's). Returns (ids ``[T, k]`` int32, weights ``[T, k]`` float32)."""
     scores = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), router, precision=jax.lax.Precision.HIGHEST))
     _, ids = jax.lax.top_k(scores + bias, top_k)
     chosen = jnp.take_along_axis(scores, ids, axis=-1)
     if renormalize:
-        chosen = chosen / (chosen.sum(axis=-1, keepdims=True) + 1e-20)
+        chosen = chosen / (chosen.sum(axis=-1, keepdims=True) + renormalize_eps)
     return ids.astype(jnp.int32), chosen * scale
 
 

@@ -666,3 +666,79 @@ def test_jambas_chunk_program_holds_a_rows_state_on_the_chip(monkeypatch, one_ch
     carried = [line.strip()[:160] for line in hlo.splitlines()
                if re.search(r"\bwhile\(", line) and f"f32[{rows},16,5120]" in line]
     assert carried == [], carried
+
+
+# -- the step programs of lfm2-24b-a2b ------------------------------------------
+
+@functools.lru_cache(maxsize=None)
+def _compile_lfm2(program, one_chip, rows=8):
+    """``models/lfm2.py``'s decode or chunk program at ``batch.lfm2-24b-a2b``'s
+    served shapes (10 of the 40 layers, 64 slots, block 16, 12,288 blocks,
+    2,048 positions; a chunk of ``rows`` x 128 tokens), the pool and the state
+    donated, for the described chip."""
+    from dynamo_tpu.models import lfm2
+
+    kinds = (["conv", "conv"] + ["full_attention", "conv", "conv", "conv"] * 2)
+    c = lfm2.Lfm2Config(num_layers=10, layer_types=tuple(kinds))
+    slots, mb, chunk = 64, 128, 128
+
+    def sd(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = jax.tree.map(sd, jax.eval_shape(lambda: lfm2.init_params(jax.random.PRNGKey(0), c)))
+    cache = jax.tree.map(sd, jax.eval_shape(lambda: lfm2.make_kv_cache(c, 12288, 16)))
+    state = jax.tree.map(sd, jax.eval_shape(lambda: lfm2.make_slot_state(c, slots)))
+    if program == "decode":
+        def greedy(logits, pos, carry, k):
+            return jnp.argmax(logits, -1).astype(jnp.int32), carry, jnp.argmax(logits, -1)
+
+        return jax.jit(
+            lambda p, kv, st, toks, pos, tables: lfm2.decode(
+                p, c, toks, pos, kv, tables, st, 4, 2047, greedy, 0),
+            donate_argnums=(1, 2),
+        ).lower(params, cache, state, i32(slots), i32(slots), i32(slots, mb)).compile()
+    return jax.jit(
+        lambda p, kv, st, toks, pos, tables, lanes: lfm2.forward_chunk(
+            p, c, toks, pos, kv, tables, st, lanes),
+        donate_argnums=(1, 2),
+    ).lower(params, cache, state, i32(rows, chunk), i32(rows, chunk), i32(rows, mb),
+            i32(rows)).compile()
+
+
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize("program, rows", [("decode", 64), ("chunk", 8), ("chunk", 64)],
+                         ids=["decode", "chunk_8_rows", "chunk_64_rows"])
+def test_lfm2s_step_programs_copy_neither_the_pool_nor_the_state_nor_an_expert(
+        monkeypatch, one_chip, program, rows):
+    """``models/lfm2.py`` at ``batch.lfm2-24b-a2b``'s served shapes, for the
+    chip's compiler: the programs fit beside 10.5 GB of weights (a pool whose
+    minor axis is a head's 64 does not: padded to 128 lanes, 3 GB of copies a
+    dispatch, and the decode program is refused for 244 MB: PERF.md 6, PR 43);
+    no instruction copies the float32 pool ``[2, 12288, 16, 4, 128]`` (two KV
+    heads a row) or a view of it, none copies an expert layer's matrices
+    (handed to the kernel as they lie), the grouped product is in the program
+    three times an expert layer (and step, and history width), and both the
+    pool and the tails are donated. A chunk reads the tails and the pool
+    inside its loop over groups of 8 rows and writes them after it: no copy
+    of a layer's ``f32[64, 4096]`` tails. A decode dispatch writes each conv
+    layer's tails out ONCE, from the chip's fast memory (one ``copy`` a
+    layer, not one a step)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernel compiled, not interpreted
+    compiled = _compile_lfm2(program, one_chip, rows)
+    hlo = re.sub(r"/\*.*?\*/", "", compiled.as_text())
+    big = re.findall(
+        r"= (?:f32|bf16)\[(?:2,12288,16,4,128|24576,16,4,128|64,2048,1536|64,1536,2048)\]\{[^}]*\} copy\(", hlo)
+    assert big == [], big
+    tails = re.findall(r"= f32\[64,4096\]\{[^}]*\} copy\(", hlo)
+    assert len(tails) <= (8 if program == "decode" else 0), len(tails)
+    kernels = len(re.findall(r"custom_call_target=\"tpu_custom_call\"", hlo))
+    widths = len(llama.history_widths(64 * 8))  # a decode dispatch holds its steps once a history width
+    assert kernels == (3 * 8 * 4 * widths if program == "decode" else 3 * 8) and "grouped_product" in hlo
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * 805_306_368 + 8_388_608
+    # beside the arguments: the dense history of a full-width decode dispatch (1.07 GB) and its
+    # steps; a chunk's groups hold what 8 rows need, whatever the rung
+    assert memory.temp_size_in_bytes < (2_600_000_000 if program == "decode" else 400_000_000)

@@ -3,7 +3,8 @@
 ``python tools/profile_decode.py history`` times the decode program ALONE at
 the served shapes by history profile, the live form beside the full-width one
 (:func:`history_profiles`); ``... experts`` times the expert layer ALONE at
-``batch.kimi-linear-48b-a3b``'s two shapes by routing and by grouped product
+``batch.kimi-linear-48b-a3b``'s and ``batch.lfm2-24b-a2b``'s two shapes each
+by routing and by grouped product, beside its own roofline
 (:func:`expert_profiles`); ``... kda`` times a KDA layer's recurrence ALONE
 over a chunk, the scan over the tokens beside the kernel that keeps the state
 on the chip (:func:`kda_profiles`); ``... mamba`` times a Mamba layer's
@@ -460,75 +461,106 @@ def tile_loop_product(parts, w, schedule, *, rows_per_tile, interpret=False):
     return jax.lax.fori_loop(0, visits, visit, jnp.zeros((m, w.shape[2]), jnp.float32))
 
 
+def expert_layers() -> dict:
+    """The expert layers ``expert_profiles`` times, by configuration: experts
+    held and scored, experts a token, E, F, the model's ``parts_of`` (and how
+    many bfloat16 parts it makes), a chosen expert's weight, and a chunk
+    group's tokens with the valid ones of each row of 128."""
+    from dynamo_tpu.models import kimi_linear as kl
+    from dynamo_tpu.models import lfm2
+
+    return {
+        "kimi-linear-48b-a3b": dict(held=128, total=256, k=8, e=2304, f=1024, parts_of=kl.operand_parts,
+                                    parts=kl.PASSES, weight=2.446 / 8, chunk=2048, valid_a_row=64),
+        "lfm2-24b-a2b": dict(held=64, total=64, k=4, e=2048, f=1536, parts_of=lfm2._expert_parts,
+                             parts=lfm2.PARTS, weight=1.0 / 4, chunk=lfm2.ROWS_AT_ONCE * 128, valid_a_row=128),
+    }
+
+
 def expert_profiles():
-    """``ops/moe.py:dropless_experts`` of ``kimi-linear-48b-a3b`` (128 experts
-    held of 256, 8 a token, E 2,304, F 1,024, bf16 weights, float32 rows in
-    three bfloat16 parts) at the cell's two shapes: a decode step's 64 tokens,
-    and a chunk group's 2,048 of which half are padding. Each is timed under
-    routing drawn even, drawn as the cell's, and all to ONE expert (a run of
+    """``ops/moe.py:dropless_experts`` as a configuration calls it (PROF_MODELS,
+    default both: ``kimi-linear-48b-a3b``, 128 experts held of 256, 8 a token,
+    E 2,304, F 1,024, three bfloat16 parts; ``lfm2-24b-a2b``, all 64 held, 4 a
+    token, E 2,048, F 1,536, two parts; bf16 weights, float32 rows) at the
+    cell's two shapes: a decode step's 64 tokens, and a chunk group's (Kimi's
+    2,048 of which half are padding, LFM2's 1,024). Each is timed under routing
+    drawn even, drawn as Kimi's cell's, and all to ONE expert (a run of
     many tiles: whether the kernel reads an expert once a run or once a tile),
-    PROF_ITERS (default 8) layers chained in one dispatch. PROF_PRODUCTS names
-    the grouped products to compare (``kernel`` is the tree's; ``megablox``,
+    PROF_ITERS (default 8) layers chained in one dispatch, and printed beside
+    the layer's own roofline: the matrices of the experts it reads over the
+    chip's bytes a second, or the products of the rows it computes (every part)
+    over its bf16 peak, whichever is longer. PROF_PRODUCTS names the grouped
+    products to compare (``kernel`` is the tree's; ``megablox``,
     ``ragged_dot``, ``tile_loop``), PROF_TILES the rows a tile to try beside
     the layer's own (``rows_per_tile``: 0)."""
+    from benchmark import bytes_and_flops
     from dynamo_tpu.engine_jax.compile_cache import enable_compile_cache
-    from dynamo_tpu.models import kimi_linear as kl
     from dynamo_tpu.ops import moe
 
     enable_compile_cache()
+    peaks = bytes_and_flops.load_peaks(jax.devices()[0].device_kind)
     n_iter = int(os.environ.get("PROF_ITERS", "8"))
     products = {"kernel": moe.grouped_product, "megablox": megablox_product,
                 "ragged_dot": ragged_dot_product, "tile_loop": tile_loop_product}
     chosen = os.environ.get("PROF_PRODUCTS", "kernel").split(",")
     tiles = [int(r) for r in os.environ.get("PROF_TILES", "0").split(",")]
-    held, total, k, e, f = 128, 256, 8, 2304, 1024
-    key = jax.random.split(jax.random.PRNGKey(0), 4)
+    layers = expert_layers()
+    own = moe.rows_per_tile
 
     def dense(key, shape, fan_in):
         return (jax.random.normal(key, shape, jnp.float32) / np.sqrt(fan_in)).astype(jnp.bfloat16)
 
-    w = (dense(key[0], (held, e, f), e), dense(key[1], (held, e, f), e), dense(key[2], (held, f, e), f))
-    own = moe.rows_per_tile
+    for model in os.environ.get("PROF_MODELS", ",".join(layers)).split(","):
+        z = layers[model]
+        held, total, k, e, f = z["held"], z["total"], z["k"], z["e"], z["f"]
+        key = jax.random.split(jax.random.PRNGKey(0), 4)
+        w = (dense(key[0], (held, e, f), e), dense(key[1], (held, e, f), e), dense(key[2], (held, f, e), f))
 
-    def timed(t, ids, valid):
-        x = jax.random.normal(key[3], (t, e), jnp.float32)
-        weights = jnp.full((t, k), 2.446 / k, jnp.float32)
+        def timed(t, ids, valid):
+            x = jax.random.normal(key[3], (t, e), jnp.float32)
+            weights = jnp.full((t, k), z["weight"], jnp.float32)
 
-        @jax.jit
-        def chain(x, ids, weights, valid, w_gate, w_up, w_down):
-            def layer(x, _):
-                y, stats = moe.dropless_experts(
-                    x, ids, weights, w_gate, w_up, w_down, num_experts_total=total,
-                    token_valid=valid, parts_of=kl.operand_parts)
-                return x + 1e-3 * y, stats
-            return jax.lax.scan(layer, x, None, length=n_iter)
+            @jax.jit
+            def chain(x, ids, weights, valid, w_gate, w_up, w_down):
+                def layer(x, _):
+                    y, stats = moe.dropless_experts(
+                        x, ids, weights, w_gate, w_up, w_down, num_experts_total=total,
+                        token_valid=valid, parts_of=z["parts_of"])
+                    return x + 1e-3 * y, stats
+                return jax.lax.scan(layer, x, None, length=n_iter)
 
-        args = (x, jnp.asarray(ids), weights, jnp.asarray(valid), *w)
-        chain(*args)[0].block_until_ready()
-        times = []
-        for _ in range(5):
-            t0 = time.perf_counter()
-            out, stats = chain(*args)
-            out.block_until_ready()
-            times.append(time.perf_counter() - t0)
-        return float(np.median(times)) * 1e3 / n_iter, np.asarray(stats[0]).tolist()
+            args = (x, jnp.asarray(ids), weights, jnp.asarray(valid), *w)
+            chain(*args)[0].block_until_ready()
+            times = []
+            for _ in range(5):
+                t0 = time.perf_counter()
+                out, stats = chain(*args)
+                out.block_until_ready()
+                times.append(time.perf_counter() - t0)
+            return float(np.median(times)) * 1e3 / n_iter, np.asarray(stats[0]).tolist()
 
-    rng = np.random.default_rng(0)
-    shapes = {"decode 64": (64, np.ones(64, bool)),
-              "chunk 2048": (2048, np.tile(np.arange(128) < 64, 16))}
-    for shape, (t, valid) in shapes.items():
-        routings = {"even": draw_routing(t, 0.0, rng), "as the cell": draw_routing(t, 1.5, rng),
-                    "one expert": np.tile(np.arange(k, dtype=np.int32) * held, (t, 1))}
-        for name in chosen:
-            moe.grouped_product = products[name]
-            for r in tiles:
-                moe.rows_per_tile = (lambda *a, r=r: r) if r else own
-                for routing, ids in routings.items():
-                    ms, stats = timed(t, ids, valid)
-                    print(f"{shape:10s} {name:10s} tile {r or own(t, k, total):3d} {routing:11s} "
-                          f"{ms:7.3f} ms a layer; held rows {stats[1]}, experts hit {stats[2]}, "
-                          f"rows computed {stats[4]}, expert reads {stats[5]}", flush=True)
-    moe.rows_per_tile, moe.grouped_product = own, products["kernel"]
+        rng = np.random.default_rng(0)
+        shapes = {"decode 64": (64, np.ones(64, bool)),
+                  f"chunk {z['chunk']}": (z["chunk"], np.tile(np.arange(128) < z["valid_a_row"], z["chunk"] // 128))}
+        for shape, (t, valid) in shapes.items():
+            routings = {"even": draw_routing(t, 0.0, rng, total, k),
+                        "as the cell": draw_routing(t, 1.5, rng, total, k),
+                        "one expert": np.tile(np.arange(k, dtype=np.int32) * held, (t, 1))}
+            for name in chosen:
+                moe.grouped_product = products[name]
+                for r in tiles:
+                    moe.rows_per_tile = (lambda *a, r=r: r) if r else own
+                    for routing, ids in routings.items():
+                        ms, stats = timed(t, ids, valid)
+                        read_ms = stats[5] * 3 * e * f * 2 / peaks["hbm_bytes_per_s"] * 1e3
+                        mxu_ms = stats[4] * z["parts"] * 3 * 2 * e * f / peaks["bf16_flops_per_s"] * 1e3
+                        print(f"{model:20s} {shape:10s} {name:10s} tile {r or own(t, k, total):3d} {routing:11s} "
+                              f"{ms:7.3f} ms a layer; held rows {stats[1]}, experts hit {stats[2]}, "
+                              f"rows computed {stats[4]}, expert reads {stats[5]}; its roofline "
+                              f"{max(read_ms, mxu_ms):6.3f} ms ({'bytes' if read_ms >= mxu_ms else 'products'}): "
+                              f"{max(read_ms, mxu_ms) / ms:5.1%}", flush=True)
+        moe.rows_per_tile, moe.grouped_product = own, products["kernel"]
+        del w
 
 
 def chunk_valid_counts(rows: int, used: int, rng, chunk: int = 128) -> np.ndarray:
