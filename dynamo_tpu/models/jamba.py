@@ -42,12 +42,12 @@ MXU's own rounding, which is all that is left of bfloat16's noise (0.025).
 Float32 inside the recurrence, as the published kernels compute it: the
 convolution over the float32 tail, the three inner norms, the step size after
 its softplus, ``exp(step x A)``, the state, the sum over N. The recurrence
-advances ``[rows, N, D]`` token by token (:func:`_scan_tokens`): a chunk's runs
-in ``ops/pallas/selective_scan.py``, one kernel that holds a row's state on the
+advances ``[rows, N, D]`` token by token (:func:`_scan_tokens`), in one of the two
+kernels of ``ops/pallas/selective_scan.py``: a chunk's holds a row's state on the
 chip from the row's first token to its last valid one (HBM sees it once in and
-once out), and a decode step's, one token, is one elementwise pass over the
-lanes' state in place. The token count the code sees picks the path.
-``[rows, T, N, D]`` never exists.
+once out); a decode step's, one token of every slot, updates a layer of the run's
+state in place, read once and written once. The token count the code sees picks
+the kernel, the token's arithmetic is one. ``[rows, T, N, D]`` never exists.
 """
 
 from __future__ import annotations
@@ -64,7 +64,7 @@ from dynamo_tpu.models.llama import (  # noqa: F401  (the two tile counts are th
     chunk_history_partial, chunk_history_tiles, decode_history_tiles, embed_lookup, flush_window,
     history_tile, history_tiles_full, rms_norm, with_live_history,
 )
-from dynamo_tpu.ops.pallas.selective_scan import selective_scan
+from dynamo_tpu.ops.pallas.selective_scan import selective_scan, selective_step
 from dynamo_tpu.ops.parts import dot_parts as _dot
 
 Params = Dict[str, Any]
@@ -264,39 +264,39 @@ def mlp(lp: Params, c: JambaConfig, h: jax.Array) -> jax.Array:
 def _scan_tokens(lp: Params, s: jax.Array, delta: jax.Array, x: jax.Array, b: jax.Array,
                  c: jax.Array, valid: jax.Array):
     """The selective scan over ``[B, T]`` tokens from the rows' state ``s``
-    ``[B, N, D]``, all float32 and elementwise: ``s = exp(delta A) * s +
-    (delta x) B``, ``y = s C`` (summed over N). A token that is not valid
-    leaves the state as it is. Returns (``y`` ``[B, T, D]``, the state after
-    the last valid token). One token (a decode step) is one elementwise pass
-    over the rows' state; more (a chunk) are one call of the kernel that keeps
-    a row's state on the chip over its valid tokens, a prefix of the row
-    (``y`` past them: zeros)."""
+    ``[B, N, D]``, all float32: ``s = exp(delta A) * s + (delta x) B``, ``y =
+    s C`` (summed over N). A token that is not valid leaves the state as it is.
+    Returns (``y`` ``[B, T, D]``, zeros where not valid; the state after the
+    last valid token). More tokens than one (a chunk): the kernel that keeps a
+    row's state on the chip over its valid tokens, a prefix of the row. One (a
+    decode step): the step kernel, which updates in place, so ``s`` may be (a
+    run's state ``[n, B, N, D]``, the layer to advance) and comes back so."""
     a = -jnp.exp(lp["a_log"])  # [N, D]
     if delta.shape[1] > 1:
         return selective_scan(delta, x, b, c, a, s, valid.sum(axis=1),
                               interpret=jax.default_backend() == "cpu")
-    d_t, x_t, b_t, c_t, v_t = delta[:, 0], x[:, 0], b[:, 0], c[:, 0], valid[:, 0]
-    new = jnp.exp(d_t[:, None, :] * a) * s + (d_t * x_t)[:, None, :] * b_t[:, :, None]
-    y = jnp.sum(new * c_t[:, :, None], axis=1)
-    return y[:, None], jnp.where(v_t[:, None, None], new, s)
+    run, layer = s if isinstance(s, tuple) else (s[None], 0)  # rows' own state: a run of one layer
+    y, run = selective_step(delta[:, 0], x[:, 0], b[:, 0], c[:, 0], a, run, layer, valid[:, 0],
+                            interpret=jax.default_backend() == "cpu")
+    return y[:, None], (run, layer) if isinstance(s, tuple) else run[0]
 
 
 def mamba_mixer(lp: Params, c: JambaConfig, u: jax.Array, valid: jax.Array,
                 s: jax.Array, tail: jax.Array):
     """The Mamba mixer over ``[B, T, E]`` normed inputs whose valid tokens are
-    a prefix of each row, from the rows' state ``s`` ``[B, N, D]`` and the
-    convolution's tail ``[B, (K - 1) * D]``: (output ``[B, T, E]``, the state
-    after the last valid token, the new tail: the row's last ``K - 1`` valid
-    inputs)."""
-    bsz, t, _ = u.shape
-    d, n, r, kk = c.d_inner, c.mamba_d_state, c.mamba_dt_rank, c.mamba_d_conv
+    a prefix of each row, from the rows' state ``s`` ``[B, N, D]`` (or a layer
+    of a run's, as :func:`_scan_tokens` takes it) and the convolution's tail
+    ``[B, (K - 1) * D]``: (output ``[B, T, E]``, the state after the last valid
+    token, the new tail: the row's last ``K - 1`` valid inputs).
+    """
+    d, n, r = c.d_inner, c.mamba_d_state, c.mamba_dt_rank
     xz = _dot(u, lp["w_in"], PARTS)
     x, gate = xz[..., :d], xz[..., d:]
-    # causal depthwise convolution: tap K-1 is the token itself, tap 0 the oldest input
-    seq = jnp.concatenate([tail.reshape(bsz, kk - 1, d), x], axis=1)  # [B, K-1+T, D]
-    x = jax.nn.silu(sum(seq[:, j:j + t] * lp["conv_w"][j] for j in range(kk)) + lp["conv_b"])
-    tail_at = valid.sum(axis=1)[:, None] + jnp.arange(kk - 1)[None, :]  # the K-1 inputs before position n
-    new_tail = jnp.take_along_axis(seq, tail_at[:, :, None], axis=1).reshape(bsz, -1)
+    # the causal depthwise convolution over the tail and the tokens, and the new tail: a chunk's
+    # form or a decode step's, by the token count (:func:`_convolve`, which stands under the chunk
+    # program: the lines from here down to there are callers of the chunk's kernel, and a kernel's
+    # key in the compile cache holds its callers' line numbers, so they are kept where they were)
+    x, new_tail = _convolve(lp, c, x, valid, tail)
 
     dbc = _dot(x, lp["w_x"], PARTS)
     dt = rms_norm(dbc[..., :r], lp["dt_norm"], c.rms_norm_eps)
@@ -416,6 +416,30 @@ def forward_chunk(
     return h, cache, {"s": tuple(s_out), "conv": tuple(conv_out)}, counters.astype(jnp.int32)
 
 
+def _convolve(lp: Params, c: JambaConfig, x: jax.Array, valid: jax.Array, tail: jax.Array):
+    """The mixer's causal depthwise convolution of ``x`` ``[B, T, D]`` behind the
+    rows' tail ``[B, (K - 1) * D]`` (tap K - 1 is the token itself, tap 0 the
+    oldest input), its bias and its silu: (the mixer's ``x`` ``[B, T, D]``, the
+    new tail: the row's last ``K - 1`` valid inputs). One token (a decode step):
+    the taps are the tail's ``K - 1`` slices of ``D`` lanes and the token, and the
+    new tail is the old one moved up by a token where the lane decodes, so the
+    tail stays ``[B, (K - 1) * D]`` from the slot state and back. (As ``[B, K - 1,
+    D]``, a chunk's form, its 3 rows lie on 4 sublanes: at one token the chip's
+    compiler relaid it there and back around a gather of 192 rows, 52 us a layer
+    and step of 445, as much as the state's pass: PERF.md 6, PR 44.) The same
+    products, summed in the same order, in both forms."""
+    bsz, t, d = x.shape
+    kk = c.mamba_d_conv
+    if t == 1:
+        taps = [tail[:, j * d:(j + 1) * d] for j in range(kk - 1)] + [x[:, 0]]
+        out = jax.nn.silu(sum(taps[j] * lp["conv_w"][j] for j in range(kk)) + lp["conv_b"])
+        return out[:, None], jnp.where(valid, jnp.concatenate(taps[1:], axis=1), tail)
+    seq = jnp.concatenate([tail.reshape(bsz, kk - 1, d), x], axis=1)  # [B, K-1+T, D]
+    out = jax.nn.silu(sum(seq[:, j:j + t] * lp["conv_w"][j] for j in range(kk)) + lp["conv_b"])
+    tail_at = valid.sum(axis=1)[:, None] + jnp.arange(kk - 1)[None, :]  # the K-1 inputs before position n
+    return out, jnp.take_along_axis(seq, tail_at[:, :, None], axis=1).reshape(bsz, -1)
+
+
 def decode(
     params: Params, config: JambaConfig, tokens: jax.Array, positions: jax.Array,
     kv_cache: KVCache, block_tables: jax.Array, state: SlotState, steps: int, max_pos: int,
@@ -426,11 +450,14 @@ def decode(
     lane that passes ``max_pos`` stops there).
 
     A step reads and writes every slot's state once: a run of Mamba layers is
-    a ``lax.scan`` with the run's state as its ``xs`` and ``ys``, and the
-    ``steps`` (a handful) are unrolled, so that one step's ``ys`` are the next
-    step's ``xs`` and no buffer is copied. (Under a ``lax.scan`` over the steps
-    the chip's compiler copies the state whole onto the loop's carry every
-    step, in either form of the layer loop: PERF.md 6, PR 41.) The attention
+    a ``lax.scan`` that CARRIES the run's state, and a layer's step kernel
+    updates its layer of it in place (the state as the loop's ``xs`` and ``ys``
+    reads a layer twice: one fusion for the new state, one for the sum over N;
+    PERF.md 6, PR 44); the convolution's tails are its ``xs`` and ``ys``. The
+    ``steps`` (a handful) are unrolled, so that one step leaves the state where
+    the next takes it and no buffer is copied. (Under a ``lax.scan`` over the
+    steps the chip's compiler copies the state whole onto the loop's carry
+    every step, in either form of the layer loop: PERF.md 6, PR 41.) The attention
     layers are the dense tier of the Llama decode program: the pool is
     read-only inside the dispatch, its live (lane, tile) pairs gathered once
     (``with_live_history``), a step's K and V go to a window buffer and the
@@ -445,6 +472,18 @@ def decode(
     window = jnp.zeros((n_slots, steps, c.num_kv_heads, c.head_dim), c.dtype)
     n_attn = layer_kinds(c).count("attn")
 
+    def mamba_layer(carry, xs):
+        # one function for every step and width, the lanes that decode in its carry: a run of
+        # layers is traced and lowered once a run, and not once a run, step and width
+        h, s_run, valid = carry
+        lp, tail, layer = xs
+        with jax.named_scope("mamba"):
+            y, (s_run, _), tail = mamba_mixer(
+                lp, c, rms_norm(h, lp["mixer_norm"], c.rms_norm_eps), valid, (s_run, layer), tail)
+        with jax.named_scope("mlp"):
+            h = mlp(lp, c, h + y)
+        return (h, s_run, valid), tail
+
     def run(history):
         live = history[1]
 
@@ -452,23 +491,15 @@ def decode(
             toks, pos, carry, s_all, conv_all, wk, wv = loop
             valid = (pos >= 0)[:, None]
 
-            def mamba_layer(h, xs):
-                lp, s, tail = xs
-                with jax.named_scope("mamba"):
-                    y, s, tail = mamba_mixer(
-                        lp, c, rms_norm(h, lp["mixer_norm"], c.rms_norm_eps), valid, s, tail)
-                with jax.named_scope("mlp"):
-                    h = mlp(lp, c, h + y)
-                return h, (s, tail)
-
             in_window = (jnp.arange(steps)[None, :] <= k) & (base[:, None] >= 0)  # [S, W]
             s_all, conv_all, wk, wv = list(s_all), list(conv_all), list(wk), list(wv)
             h = embed_lookup(params, toks, c.dtype).astype(jnp.float32)[:, None]  # [S, 1, E]
             i = j = 0
             for kind, _ in segs:
                 if kind == "mamba":
-                    h, (s_all[i], conv_all[i]) = jax.lax.scan(
-                        mamba_layer, h, (params["mamba"][i], s_all[i], conv_all[i]))
+                    (h, s_all[i], _), conv_all[i] = jax.lax.scan(
+                        mamba_layer, (h, s_all[i], valid),
+                        (params["mamba"][i], conv_all[i], jnp.arange(s_all[i].shape[0])))
                     i += 1
                     continue
                 lp = params["attn"][j]
@@ -487,16 +518,23 @@ def decode(
             new_pos = jnp.where((pos >= 0) & (pos < max_pos), pos + 1, -1)
             return (nxt, new_pos, carry, tuple(s_all), tuple(conv_all), tuple(wk), tuple(wv)), out
 
-        loop = (tokens, positions, carry, state["s"], state["conv"],
-                (window,) * n_attn, (window,) * n_attn)
-        outs = []
-        for k in range(steps):
-            loop, out = step(loop, jnp.int32(k))
-            outs.append(out)
-        return loop, jax.tree.map(lambda *a: jnp.stack(a), *outs)
+        def unrolled(loop, _):
+            outs = []
+            for k in range(steps):
+                loop, out = step(loop, jnp.int32(k))
+                outs.append(out)
+            return loop, jax.tree.map(lambda *a: jnp.stack(a), *outs)
 
+        return unrolled
+
+    # the steps update the state in place, so they take it as ``carried`` (with_live_history), and
+    # with it room for what a step's ``sample`` gives, stacked over the steps
+    out = jax.eval_shape(lambda: sample(
+        jnp.zeros((n_slots, c.vocab_size), jnp.float32), positions, carry, jnp.int32(0))[2])
+    loop = (tokens, positions, carry, state["s"], state["conv"], (window,) * n_attn, (window,) * n_attn)
     (toks, pos, carry, s_all, conv_all, wk, wv), out = with_live_history(
-        kv_cache, block_tables, base, run, out_dtype=c.dtype)
+        kv_cache, block_tables, base, run, out_dtype=c.dtype,
+        carried=(loop, jax.tree.map(lambda a: jnp.zeros((steps, *a.shape), a.dtype), out)))
     cache = flush_window(kv_cache, block_tables, base, jnp.stack(wk), jnp.stack(wv), max_pos)
     counters = jnp.zeros((len(COUNTERS),), jnp.int32).at[0].set(steps * sum(_runs(c)))
     return toks, pos, carry, out, cache, {"s": s_all, "conv": conv_all}, counters

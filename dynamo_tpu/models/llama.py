@@ -920,7 +920,7 @@ def with_live_history(
     block_tables: jax.Array,  # [B, MB]
     base: jax.Array,  # [B] history = positions < base; -1 = padding lane
     steps,  # history -> the dispatch's steps over it (any pytree)
-    out_dtype: Any = None,
+    out_dtype: Any = None, carried=None,  # or steps(history)(*carried) -> carried': the last lines
 ):
     """``steps(("live", LiveHistory))`` over the (lane, tile) pairs of the
     pool that hold history: gathered once, at the width
@@ -986,7 +986,26 @@ def with_live_history(
     return jax.lax.switch(
         sum((read > n).astype(jnp.int32) for n in widths[:-1]),
         [partial(at_width, n) for n in widths],
-    )
+    ) if carried is None else _each_width_in_turn(read, widths, at_width, carried)
+
+
+def _each_width_in_turn(read, widths, at_width, carried):
+    """``with_live_history`` for steps that UPDATE what they are handed
+    (``carried``, a tuple that holds per-slot state; ``steps(history)`` gives
+    the function that takes it and gives it back advanced): one conditional a
+    width in turn, a width that is not the dispatch's handing ``carried`` on
+    as it is. The chip's compiler orders a conditional's branches, the first
+    before the second, to let them share buffers; so in every branch but the
+    last an operand of the conditional is live past the branch, and an update
+    of it in place is made on a copy: of a run's whole state, a layer and step
+    (PERF.md 6, PR 44). Here every width's steps are the last branch of their
+    own conditional. (Written under the function and not in it: a Pallas
+    kernel's cache key holds its callers' lines and columns, and two models'
+    kernels are called from the lines above.)"""
+    for n in widths:  # ``read`` is one of them
+        carried = jax.lax.cond(
+            read == n, lambda *c, n=n: at_width(n)(*c), lambda *c: c, *carried)
+    return carried
 
 
 def _live_window_attention(

@@ -168,3 +168,113 @@ def selective_scan(
       _by_sublane(jnp.pad(x, pad), lanes), by_token(b), by_token(c),
       _by_sublane(a, lanes), _by_sublane(s0, lanes))
     return _by_row(y)[:, :t_in], _by_row(s)
+
+
+ROWS = 16  # slots of a channel block a grid step of the step kernel holds
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def selective_step(
+    delta: jax.Array,  # [S, D] float32: every slot's one token, the step size after its softplus
+    x: jax.Array,  # [S, D] float32
+    b: jax.Array,  # [S, N] float32
+    c: jax.Array,  # [S, N] float32
+    a: jax.Array,  # [N, D] float32: -exp(a_log)
+    s: jax.Array,  # [L, S, N, D] float32: the state of every slot, a run of layers whole
+    layer: jax.Array,  # int32: the layer of the run this call advances
+    valid: jax.Array,  # [S] bool: the slots that take the token
+    *,
+    interpret: bool = False,
+) -> Tuple[jax.Array, jax.Array]:
+    """A decode step of the same recurrence: ONE token of every slot, the
+    chunk kernel's token body (the same products and sums in the same order, so
+    a slot's state after a step is, bit for bit, what the chunk kernel leaves
+    after a chunk of that one token), a slot's state read from HBM once and
+    written once. (``y`` ``[S, D]``, zeros where a slot is not valid; ``s``
+    with layer ``layer`` advanced, a slot that is not valid bit for bit as it
+    was.)
+
+    The run's whole state is the operand, in :func:`_by_sublane`'s order (a
+    bitcast), ALIASED to the output, and the layer's index rides in the block
+    index map: the kernel updates layer ``layer`` in place and touches no
+    other, so a loop over the layers carries the run's state and nothing is
+    sliced out of it or stacked back around the call (either would be a pass
+    of its own). A grid step takes ``ROWS`` slots of ``GROUPS * LANES``
+    channels, 1 MB of state in and 1 MB out: under it the step's overhead
+    shows, over it nothing is gained and the first load and the last store,
+    which nothing hides, grow. The slots' tokens lie on the sublanes as a
+    chunk's tokens do, so slot ``r`` of a block is the same strided load.
+    Slots past a multiple of ``ROWS`` are a part block of the state; the token
+    arrays are padded to it."""
+    slots, d = delta.shape
+    n = a.shape[0]
+    if n % SUBLANES:
+        raise ValueError(f"selective_step takes state rows in eights, not {n}")
+    if not interpret and d % (GROUPS * LANES):
+        raise ValueError(
+            f"selective_step on the TPU takes channels in blocks of {GROUPS * LANES} "
+            f"({GROUPS} sublanes x {LANES} lanes), not a width of {d}")
+    lanes = LANES if d % LANES == 0 else d
+    groups = GROUPS if d % (GROUPS * lanes) == 0 else d // lanes
+    blocks = -(-slots // ROWS)
+
+    def row(r):
+        return r >> 3, pl.ds(r & (SUBLANES - 1), groups, stride=SUBLANES), slice(None)
+
+    def kernel(layer, valid, delta, x, b, c, a, s0, y, s, a_rows):
+        first = pl.program_id(1) * ROWS
+        for j in range(n):
+            a_rows[j] = a[row(j)]
+
+        def slot(r, _):
+            live = valid[first + r] != 0
+
+            @pl.when(live)
+            def _():
+                d_r = delta[row(r)]
+                dx = d_r * x[row(r)]
+                out = None
+                for j in range(n):
+                    s_j = jnp.exp(d_r * a_rows[j]) * s0[(r, *row(j))] + dx * b[r * n + j]
+                    term = s_j * c[r * n + j]
+                    out = term if out is None else out + term
+                    s[(r, *row(j))] = s_j
+                y[row(r)] = out
+
+            @pl.when(jnp.logical_not(live))
+            def _():
+                s[r] = s0[r]
+                y[row(r)] = jnp.zeros((groups, lanes), jnp.float32)
+
+        jax.lax.fori_loop(0, ROWS, slot, None)
+
+    pad = ((0, blocks * ROWS - slots), (0, 0))
+    by_slot = lambda v: jnp.pad(v, pad).reshape(blocks, 1, ROWS * n)  # noqa: E731
+    width, whole = groups * SUBLANES, d // lanes * SUBLANES  # sublanes of a block, of a slot's channels
+
+    tokens = pl.BlockSpec((ROWS // SUBLANES, width, lanes), lambda j, i, layer, valid: (i, j, 0))
+    scalars = pl.BlockSpec((None, None, ROWS * n), lambda j, i, layer, valid: (i, 0, 0),
+                           memory_space=pltpu.SMEM)
+    state = pl.BlockSpec((None, ROWS, n // SUBLANES, width, lanes),
+                         lambda j, i, layer, valid: (layer[0], i, 0, j, 0))
+    y, s = pl.pallas_call(
+        kernel,
+        out_shape=(jax.ShapeDtypeStruct((blocks * ROWS // SUBLANES, whole, lanes), jnp.float32),
+                   jax.ShapeDtypeStruct((*s.shape[:2], n // SUBLANES, whole, lanes), jnp.float32)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            in_specs=[tokens, tokens, scalars, scalars,
+                      pl.BlockSpec((n // SUBLANES, width, lanes), lambda j, i, layer, valid: (0, j, 0)),
+                      state],
+            out_specs=(tokens, state),
+            grid=(d // (groups * lanes), blocks),  # a channel block's slots in turn: A's rows stay
+            scratch_shapes=[pltpu.VMEM((n, groups, lanes), jnp.float32)],
+        ),
+        input_output_aliases={7: 1},  # the state, after the two prefetched scalars and five arrays
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name="selective_step",
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), jnp.pad(valid.astype(jnp.int32), pad[0]),
+      _by_sublane(jnp.pad(delta, pad), lanes), _by_sublane(jnp.pad(x, pad), lanes),
+      by_slot(b), by_slot(c), _by_sublane(a, lanes), _by_sublane(s, lanes))
+    return _by_row(y)[:slots], _by_row(s)

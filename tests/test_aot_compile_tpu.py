@@ -651,6 +651,44 @@ def test_jambas_step_programs_never_copy_the_slots_state(monkeypatch, one_chip, 
     assert compiled.memory_analysis().alias_size_in_bytes >= 647_495_680 + 201_326_592
 
 
+def test_jambas_decode_step_passes_over_a_layers_state_once(monkeypatch, one_chip):
+    """The decode program at ``batch.jamba2-3b``'s served shapes: in every
+    layer-loop body the run's ``f32[n,64,16,5120]`` state (or its view in the
+    tiled order, ``f32[n,64,2,320,128]``) is an operand of exactly ONE
+    operation, the step kernel of ``ops/pallas/selective_scan.py``, which is in
+    the program once a run of layers, step and history width; nothing else in
+    the program reads or writes a run's state, and no ``reduce`` over a
+    layer's ``f32[64,16,5120]`` is left. (With the state as the layer loop's
+    ``xs`` and ``ys`` and the step in ``jax.numpy`` a body held two readers, a
+    ``dynamic-update-slice`` fusion for the new state and a ``reduce`` fusion
+    for the sum over N, each with its own ``exp``: PERF.md 6, PR 44. With the
+    steps in ONE conditional's branches, every loop of the first branch copied
+    the run's state before and after its kernel: the no-copy test above.)"""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hlo = re.sub(r"/\*.*?\*/", "", _compile_jamba("decode", one_chip).as_text())
+    views = {"parameter", "get-tuple-element", "bitcast", "tuple", "while", "conditional", "call"}
+    state = r"f32\[\d+,64,(?:16,5120|2,320,128)\]"
+    bodies, elsewhere = 0, []
+    for computation in hlo.split("\n\n"):
+        lines = [re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?[\]})]) ([a-z][\w\-]*)\((.*)", line)
+                 for line in computation.splitlines()[1:]]
+        lines = [m.groups() for m in lines if m]
+        held = {name for name, shape, _, _ in lines if re.search(state, shape)}
+        readers = [(name, op) for name, _, op, rest in lines if op not in views
+                   and held & set(re.findall(r"%([\w.\-]+)", rest.split("), ")[0]))]
+        if "tpu_custom_call" in computation:
+            bodies += 1
+            assert [op for _, op in readers] == ["custom-call"], readers
+        else:
+            elsewhere += readers
+    widths = len(llama.history_widths(64 * 8))
+    assert bodies == 3 * 4 * widths and elsewhere == [], (bodies, elsewhere)
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]* custom-call\([^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
+    assert len(kernels) == bodies and all(name.startswith("selective_step") for name in kernels), kernels
+    assert not [line for line in hlo.splitlines()
+                if re.search(r"\breduce\(", line) and "f32[64,16,5120]" in line]
+
+
 @pytest.mark.parametrize("rows", [8, 64])
 def test_jambas_chunk_program_holds_a_rows_state_on_the_chip(monkeypatch, one_chip, rows):
     """The chunk program at the 8- and 64-row rungs of 64 slots:

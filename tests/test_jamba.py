@@ -27,7 +27,7 @@ from dynamo_tpu.engine_jax.engine import EngineConfig, JaxServingEngine
 from dynamo_tpu.engine_jax.weights import config_from_card
 from dynamo_tpu.kv.pages import MigrationRejected, StateNotPortable
 from dynamo_tpu.models import jamba, llama, module_for
-from dynamo_tpu.ops.pallas.selective_scan import selective_scan
+from dynamo_tpu.ops.pallas.selective_scan import ROWS, selective_scan, selective_step
 
 from .test_chunk_rows import answer, run_out, step, submit
 
@@ -175,10 +175,12 @@ def test_a_recurrence_taken_in_bfloat16_fails_the_float32_tolerance(cfg, params,
     inputs and state rounded to bfloat16 a token is off by more than ATOL."""
     scan = jamba._scan_tokens
 
+    def low(a):  # a decode step's state comes with its layer's index, which stays
+        return a.astype(jnp.bfloat16).astype(jnp.float32) if a.dtype == jnp.float32 else a
+
     def rounded(lp, s, delta, x, b, c, valid):
-        low = [a.astype(jnp.bfloat16).astype(jnp.float32) for a in (s, delta, x, b, c)]
-        y, s = scan(lp, *low, valid)
-        return y, s.astype(jnp.bfloat16).astype(jnp.float32)
+        y, s = scan(lp, *jax.tree.map(low, (s, delta, x, b, c)), valid)
+        return y, jax.tree.map(low, s)
 
     monkeypatch.setattr(jamba, "_scan_tokens", rounded)
     tokens, got, *_ = prefill_then_decode(cfg, params, (16, 9))
@@ -208,6 +210,24 @@ def test_the_convolutions_tail_carries_across_a_chunk_boundary(cfg, params):
     u = llama.rms_norm(params["embed"][jnp.asarray(tokens[:7])], lp["mixer_norm"], cfg.rms_norm_eps)
     x = np.asarray(u @ lp["w_in"])[:, :cfg.d_inner]
     np.testing.assert_allclose(seen["tail"].reshape(3, cfg.d_inner), x[4:7], atol=1e-5)
+
+
+def test_a_decode_steps_convolution_is_the_chunks_on_a_row_of_one_token(cfg, params):
+    """``_convolve``'s one-token form (the tail flat, its taps slices of it)
+    against its chunk form on rows whose first token alone is valid, or none:
+    the mixer's ``x`` of that token and the new tail, bit for bit; a lane that
+    does not decode keeps its tail."""
+    lp = jax.tree.map(lambda a: a[0], params["mamba"][0])
+    ks = jax.random.split(jax.random.PRNGKey(7), 2)
+    d, taps = cfg.d_inner, cfg.mamba_d_conv - 1
+    x, tail = jax.random.normal(ks[0], (5, 2, d)), jax.random.normal(ks[1], (5, taps * d))
+    valid = jnp.asarray([[True, False]] * 3 + [[False, False]] * 2)
+    want_x, want_tail = jamba._convolve(lp, cfg, x, valid, tail)
+    got_x, got_tail = jamba._convolve(lp, cfg, x[:, :1], valid[:, :1], tail)
+    assert np.array_equal(np.asarray(got_x[:, 0]), np.asarray(want_x[:, 0]))
+    assert np.array_equal(np.asarray(got_tail), np.asarray(want_tail))
+    assert np.array_equal(np.asarray(got_tail[3:]), np.asarray(tail[3:]))
+    assert np.array_equal(np.asarray(got_tail[:3, -d:]), np.asarray(x[:3, 0]))
 
 
 def recurrence_inputs(cfg, rows, t, seed=0, step=0.0):
@@ -242,13 +262,17 @@ def assert_float32_equal(s, y, want_s, want_y):
 @pytest.mark.parametrize("start", ["zero", "carried"])
 def test_a_decode_step_is_one_trip_of_the_chunks_token_loop(cfg, params, t, start):
     """The step form and the chunk form of the recurrence are one body: ``t``
-    tokens taken one at a time give the kernel's state and outputs, for rows
-    that are full, partly valid (one token; a third of the row) and empty. To
-    float32 rounding and not bit for bit, either of them: a channel sees the
-    same products and sums in the same order, but the CPU's compiler fuses a
-    multiply and an add in one form and not in the other, and the sum over N is
-    the kernel's own (n = 0 first) where the step's is the compiler's. (On the
-    chip: ``tools/profile_decode.py mamba`` prints the largest difference.)"""
+    tokens taken one at a time (the step kernel, a token a call) give the chunk
+    kernel's state and outputs, for rows that are full, partly valid (one
+    token; a third of the row) and empty. Both kernels take the same products
+    and sums in the same order, the sum over N from n = 0 in both, and on the
+    chip they agree bit for bit (``tools/profile_decode.py mamba`` prints the
+    largest difference). Interpreted on the CPU they agree to float32 rounding
+    over tokens in a row: its compiler fuses ONE of the two products of
+    ``exp(delta A) s + (delta x) B`` with the add, and not the same one in both
+    kernels (the chunk's state is a loop's carry, the step's a load), so the sum
+    is rounded once more in one of them. Where both products are exact it
+    cannot matter: the tests of one step below hold bit for bit."""
     lp = jax.tree.map(lambda a: a[0], params["mamba"][0])
     xs, carried = recurrence_inputs(cfg, 4, t, seed=t)
     s0 = carried if start == "carried" else jnp.zeros_like(carried)
@@ -302,6 +326,81 @@ def test_the_chunk_kernel_stands_a_step_whose_decay_underflows(cfg, params):
     want_y, want_s = one_token_at_a_time(lp, s0, *xs, valid)
     assert np.isfinite(np.asarray(y)).all() and np.isfinite(np.asarray(s)).all()
     assert_float32_equal(s, y, want_s, want_y)
+
+
+def one_step_inputs(cfg, slots, layers, seed, step=0.0):
+    """One token of every slot as ``mamba_mixer`` makes it, and a run's carried
+    state. The state, B and C are signed powers of two, so both products of
+    ``exp(delta A) s + (delta x) B`` and each ``s C`` are exact in float32:
+    whichever multiply the CPU's compiler fuses with an add, every sum is
+    rounded once, and two kernels that take the same sums in the same order
+    agree bit for bit (in another order, n = 0 last, they would not)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+    n, d = cfg.mamba_d_state, cfg.d_inner
+
+    def power_of_two(k, sign, shape):
+        return jnp.exp2(jnp.round(2.0 * jax.random.normal(k, shape))) * jnp.where(
+            jax.random.bernoulli(sign, 0.5, shape), 1.0, -1.0)
+
+    delta = jax.nn.softplus(jax.random.normal(ks[0], (slots, d)) + step)
+    x = jax.random.normal(ks[1], (slots, d))
+    b, c = power_of_two(ks[2], ks[3], (slots, n)), power_of_two(ks[4], ks[5], (slots, n))
+    return (delta, x, b, c), power_of_two(ks[6], ks[7], (layers, slots, n, d))
+
+
+def chunk_of_one_token(a, s0, delta, x, b, c, valid):
+    """The chunk kernel on a chunk of that one token a row."""
+    y, s = selective_scan(delta[:, None], x[:, None], b[:, None], c[:, None], a, s0,
+                          valid.astype(jnp.int32), interpret=True)
+    return y[:, 0], s
+
+
+@pytest.mark.parametrize("slots, decoding", [
+    (ROWS, "all"), (ROWS, "some"), (ROWS, "none"), (4, "some"), (ROWS + 4, "some"), (2 * ROWS, "all"),
+], ids=["a_full_block", "lanes_that_do_not_decode", "no_lane_decodes", "fewer_slots_than_a_block",
+        "slots_that_are_no_multiple_of_the_block", "two_blocks"])
+def test_the_step_kernel_is_the_chunk_kernel_on_a_chunk_of_one_token(cfg, params, slots, decoding):
+    """``selective_step`` on the middle layer of a run of three against
+    ``selective_scan`` on a one-token chunk from that layer's state: the state
+    and the outputs bit for bit; a lane that does not decode (``pos < 0``) gets
+    its state back bit for bit, whatever its inputs hold (NaNs here), and zeros
+    for its output; the run's other layers are not touched. Slot counts under,
+    at and over the kernel's row block, and one that is no multiple of it (the
+    state's last block is a part block, the token arrays are padded)."""
+    a = -jnp.exp(params["mamba"][0]["a_log"][0])
+    (delta, x, b, c), run = one_step_inputs(cfg, slots, 3, seed=slots)
+    valid = {"all": jnp.ones((slots,), bool), "none": jnp.zeros((slots,), bool),
+             "some": jnp.arange(slots) % 3 != 1}[decoding]
+    x = jnp.where(valid[:, None], x, jnp.nan)
+    before = np.asarray(run)
+    y, after = selective_step(delta, x, b, c, a, run, jnp.int32(1), valid, interpret=True)
+    want_y, want_s = chunk_of_one_token(a, jnp.asarray(before[1]), delta, x, b, c, valid)
+    y, after, live = np.asarray(y), np.asarray(after), np.asarray(valid)
+    assert np.array_equal(after[1], np.asarray(want_s)) and np.array_equal(y, np.asarray(want_y))
+    assert np.array_equal(after[1][~live], before[1][~live]) and not y[~live].any()
+    assert np.array_equal(after[[0, 2]], before[[0, 2]])
+    assert np.isfinite(after).all() and np.isfinite(y).all()
+    if live.any():
+        assert (after[1][live] != before[1][live]).any() and y[live].all()
+
+
+def test_the_step_kernel_stands_a_step_whose_decay_underflows(cfg, params):
+    """The twin of the chunk kernel's test: a step size near 30, so that
+    ``exp(delta A)`` is 0 in float32 for every state row but the first few and
+    a token forgets what came before it. Nothing overflows, nothing is NaN,
+    and it is the chunk kernel's token still, bit for bit."""
+    a = -jnp.exp(params["mamba"][0]["a_log"][0])
+    (delta, x, b, c), run = one_step_inputs(cfg, ROWS, 1, seed=5, step=30.0)
+    assert float(jnp.exp(-delta.min() * cfg.mamba_d_state)) == 0.0
+    valid = jnp.arange(ROWS) != 2
+    y, after = selective_step(delta, x, b, c, a, run, jnp.int32(0), valid, interpret=True)
+    want_y, want_s = chunk_of_one_token(a, run[0], delta, x, b, c, valid)
+    assert np.isfinite(np.asarray(y)).all() and np.isfinite(np.asarray(after)).all()
+    assert np.array_equal(np.asarray(after[0]), np.asarray(want_s))
+    assert np.array_equal(np.asarray(y), np.asarray(want_y))
+    # the last state row's decay is gone: the new state there is (delta x) B alone
+    forgot = np.asarray((delta * x) * b[:, -1:])
+    assert np.array_equal(np.asarray(after[0, :, -1])[np.asarray(valid)], forgot[np.asarray(valid)])
 
 
 @pytest.mark.parametrize("shape, what", [

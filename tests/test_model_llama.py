@@ -596,6 +596,36 @@ def test_what_the_decode_program_does_not_gather_it_does_not_read(tiny_model, de
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
+@pytest.mark.parametrize("case", ["ragged", "every_lane_empty", "a_pair_more_than_a_width"])
+def test_steps_that_take_what_they_update_give_what_the_switch_gives(tiny_model, decode_pool, case):
+    """``with_live_history(..., carried=)``, one conditional a width with the
+    other widths handing ``carried`` on (the form for steps that update per-slot
+    state in place: ``models/jamba.py``), against the one ``lax.switch``: the
+    same logits and window buffers, bit for bit, at either width."""
+    base = jnp.asarray(LIVE_CASES[case][0], jnp.int32)
+    tables, pools = decode_pool
+    tokens = jax.random.randint(jax.random.PRNGKey(13), (D_LANES, WINDOW), 0, CFG.vocab_size)
+    want = _decode_steps(tiny_model, pools["float"], tables, base, tokens, live=True)
+
+    def steps(history):
+        def from_window(wk, wv, _):
+            pos, logits = base, []
+            for k in range(WINDOW):
+                lg, wk, wv = forward_window(
+                    tiny_model, CFG, tokens[:, k], pos, history, base, wk, wv, jnp.int32(k))
+                logits.append(lg)
+                pos = jnp.where(pos >= 0, pos + 1, -1)
+            return wk, wv, jnp.stack(logits, 1)
+        return from_window
+
+    window = jnp.zeros((CFG.num_layers, D_LANES, WINDOW, CFG.num_kv_heads, CFG.head_dim), jnp.float32)
+    wk, wv, logits = jax.jit(lambda: with_live_history(
+        pools["float"], tables, base, steps, out_dtype=jnp.float32,
+        carried=(window, window, jnp.zeros(want[0].shape, jnp.float32))))()
+    for g, w in zip((logits, wk, wv), want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 @pytest.mark.parametrize("case", list(LIVE_CASES))
 def test_the_hosts_count_of_decode_history_is_the_programs_own(decode_pool, case):
     """``decode_history_tiles`` on a numpy array (the engine's counter) is

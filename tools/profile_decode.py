@@ -662,7 +662,13 @@ def mamba_profiles():
     ``bytes_and_flops_jamba.SCAN_OPS_PER_ELEMENT`` operations an element of a
     valid token's ``[16, 5120]`` at 4 x 1,024 lanes a cycle of 1.5 GHz. Also
     the largest difference between kernel and scanned step, state and outputs,
-    on this device. Then a decode step's pass: 64 lanes, one token.
+    on this device. Then a decode step's pass alone, 64 lanes of one token: the
+    step kernel over a run of PROF_ITERS layers' state carried by a loop, a
+    layer a trip, as the decode program's layer loop calls it (ms a layer in
+    the chain and by its own traced event, GB/s of a layer's state there and
+    back, the largest difference from the chunk kernel on a chunk of that one
+    token: 0 on the chip), at each row block of PROF_ROWS (default: the
+    kernel's own).
     PROF_ITERS (default 8) layers chained in one dispatch, each from the state
     the last one left. PROF_DINNER (default 5120) cuts the width for a
     rehearsal on the CPU (interpreted)."""
@@ -695,8 +701,8 @@ def mamba_profiles():
             return jax.lax.scan(layer, s, None, length=n_iter)
         return chain
 
-    def timed(fn, *args):
-        chain = chained(fn)
+    def chain_ms(chain, *args):
+        """ms a layer of a chain of ``n_iter``: the median of five calls."""
         chain(*args)[0].block_until_ready()
         times = []
         for _ in range(5):
@@ -705,21 +711,58 @@ def mamba_profiles():
             times.append(time.perf_counter() - t0)
         return float(np.median(times)) * 1e3 / n_iter
 
-    def kernel_event_ms(*args):
-        """The mean ``selective_scan`` event of one traced chain of the kernel:
-        the kernel's own time, without what the chain or XLA puts around it."""
+    def timed(fn, *args):
+        return chain_ms(chained(fn), *args)
+
+    def kernel_event_ms(chain, *args, name="selective_scan"):
+        """The mean event of that name in one traced call of ``chain``: the
+        kernel's own time, without what the chain or XLA puts around it."""
         from benchmark.trace_reduce import find_xplane, read_xplane
 
         trace_dir = os.path.join("chiprun_out", "profile_decode", "mamba")
         shutil.rmtree(trace_dir, ignore_errors=True)
-        chain = chained(lambda s, *a: jamba._scan_tokens(lp, s, *a))
         chain(*args)[0].block_until_ready()
         jax.profiler.start_trace(trace_dir)
         chain(*args)[0].block_until_ready()
         jax.profiler.stop_trace()
         ops = read_xplane(find_xplane(trace_dir))["devices"].get("0", {"ops": []})["ops"]
-        events = [dur for name, _, dur in ops if "selective_scan" in name]
+        events = [dur for op, _, dur in ops if name in op]
         return float(np.mean(events)) / 1e6 if events else float("nan")
+
+    def step_profile(rows, s0, delta, x, b, cc, valid, ms_mixer):
+        """A decode step's pass alone: the run's state carried, a layer a trip."""
+        from dynamo_tpu.ops.pallas import selective_scan as kernels
+
+        # under jit as the step is, so that A is made the same way on both sides
+        y_c, s_c = jax.jit(lambda s: kernels.selective_scan(
+            delta, x, b, cc, -jnp.exp(lp["a_log"]), s, valid.sum(axis=1).astype(jnp.int32),
+            interpret=jax.default_backend() == "cpu"))(s0)
+        own = kernels.ROWS
+        state_gb = 2 * rows * n * d * 4 / 1e9  # a layer's, read once and written once
+        for block in [int(r) for r in os.environ.get("PROF_ROWS", str(kernels.ROWS)).split(",")]:
+            kernels.ROWS = block
+            jax.clear_caches()
+
+            @jax.jit
+            def chain(run):
+                def layer(run, i):
+                    y, (run, _) = jamba._scan_tokens(lp, (run, i), delta, x, b, cc, valid)
+                    return run, y[:, 0, 0]
+                return jax.lax.scan(layer, run, jnp.arange(n_iter))
+
+            run = jnp.broadcast_to(s0, (n_iter, *s0.shape)) + 0.0
+            ms = chain_ms(chain, run)
+            event = kernel_event_ms(chain, run, name="selective_step")
+            y_k, (s_k, _) = jax.jit(lambda s: jamba._scan_tokens(lp, (s, 1), delta, x, b, cc, valid))(run)
+            print(f"mamba {rows:2d} rows x   1 token, {block:2d} rows a grid step: the step's pass {ms:7.3f} ms a layer "
+                  f"in the chain ({state_gb / ms * 1e3:6.1f} GB/s of state there and back), its own event "
+                  f"{event:7.3f} ({state_gb / event * 1e3:6.1f} GB/s), whole mixer {ms_mixer:7.3f}; largest "
+                  f"difference from the chunk kernel on the one-token chunk: state "
+                  f"{float(jnp.abs(s_k[1] - s_c).max()):.3g} of {float(jnp.abs(s_c).max()):.3g}, outputs "
+                  f"{float(jnp.abs(y_k - y_c).max()):.3g} of {float(jnp.abs(y_c).max()):.3g}; the other layers "
+                  f"{float(jnp.abs(jnp.concatenate([s_k[:1], s_k[2:]]) - s0).max()):.3g}", flush=True)
+        kernels.ROWS = own
+        jax.clear_caches()
 
     rng = np.random.default_rng(0)
     for rows, t, used in ((8, 128, 5), (16, 128, 12), (8, 128, 8), (8, 128, 0), (16, 128, 16),
@@ -735,17 +778,15 @@ def mamba_profiles():
                   else chunk_valid_counts(rows, rows if used is None else used, rng))
         valid = jnp.arange(t)[None, :] < jnp.asarray(counts)[:, None]
         xs = (delta, x, b, cc, valid)
-        ms = timed(lambda s, *a: jamba._scan_tokens(lp, s, *a), s0, *xs)
         ms_mixer = timed(lambda s, u, valid, tail: jamba.mamba_mixer(lp, c, u, valid, s, tail)[:2],
                          s0, u, valid, tail)
         if t == 1:
-            state_gb = 2 * rows * n * d * 4 / 1e9  # read and written once
-            print(f"mamba {rows:2d} rows x   1 token: the step's pass {ms:7.3f} ms a layer "
-                  f"({state_gb / ms * 1e3:6.1f} GB/s of state), whole mixer {ms_mixer:7.3f}", flush=True)
+            step_profile(rows, s0, *xs, ms_mixer)
             continue
+        ms = timed(lambda s, *a: jamba._scan_tokens(lp, s, *a), s0, *xs)
         bound = int(counts.sum()) * n * d * SCAN_OPS_PER_ELEMENT / vector_ops_per_s * 1e3
         (y_k, s_k), (y_s, s_s) = jax.jit(lambda *a: jamba._scan_tokens(lp, *a))(s0, *xs), jax.jit(scanned)(s0, *xs)
-        event = kernel_event_ms(s0, *xs)
+        event = kernel_event_ms(chained(lambda s, *a: jamba._scan_tokens(lp, s, *a)), s0, *xs)
         print(f"mamba {rows:2d} rows, valid {counts.tolist() if rows <= 16 else '...'} ({int(counts.sum())} tokens): "
               f"kernel {ms:7.3f} ms a layer in the chain, its own event {event:7.3f} "
               f"({bound / event:5.1%} of the vector unit's bound, {bound:.3f} ms), "
