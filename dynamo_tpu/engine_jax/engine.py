@@ -6,16 +6,21 @@ Architecture (TPU-first, cf. SURVEY.md §7 stage 4):
   from first token to finish. All decode steps run ONE jitted function with
   static shapes — no recompilation, ever.
 - **Chunked, batched prefill**: a host step with a prefilling lane runs ONE
-  compiled `[rows, prefill_chunk]` function that holds one row per lane that
-  prefills and nothing else: `rows` is the smallest rung of a short ladder
-  (`chunk_row_ladder`: `max_slots` and at most two rungs under it) that holds
-  them, so the dispatch costs what its prefilling lanes need. The lanes that
-  decode are never rows of it: they advance through the decode program in the
-  same host step, and both programs are dispatched before either result is
+  compiled `[rows, prefill_chunk]` function that holds the rows of the lanes
+  that prefill and nothing else: `rows` is a rung of a short ladder
+  (`chunk_row_ladder`: `max_slots` and at most two rungs under it), so the
+  dispatch costs what its prefilling lanes need. A lane takes as many rows
+  as its prompt needs while a rung under `max_slots` holds them
+  (`chunk_rows_of`: a prompt prefills in one host step, a later piece
+  attending the earlier rows' fresh keys inside the program), where the
+  model's module allows it; otherwise one row a lane. The lanes that decode
+  are never rows of it: they advance through the decode program in the same
+  host step, and both programs are dispatched before either result is
   fetched. (On a mesh the decode lanes still ride the chunk dispatch at the
-  full width, one token each: `_rides`, which says why.) Prompts longer than
-  a chunk just take several steps (long-context prefill is chunked by
-  construction; no shape depends on prompt length).
+  full width, one token each, and a lane has one row: `_rides`, which says
+  why.) What a dispatch does not hold of a prompt goes on in the next step
+  (long-context prefill is chunked by construction; no shape depends on
+  prompt length).
 - **Paged KV**: allocator (allocator.py) maps sequences onto a page pool in
   HBM with content-addressed prefix reuse; the model writes-then-attends
   through block tables (models/llama.py), making prefix hits free.
@@ -457,13 +462,14 @@ class _SealPages:
 class _ChunkInflight:
     """A chunk dispatch whose result has not been fetched: the device handles
     of its sampled tokens, and per live row the lane, its sequence and the
-    prompt tokens it fed. It lives inside one host step (`_prefill_step`)."""
+    prompt tokens it fed (a lane that took several rows appears in each, in
+    order). It lives inside one host step (`_prefill_step`)."""
 
     __slots__ = ("fetch", "rows", "sealing", "t_step", "prof")
 
     def __init__(self, fetch, rows, sealing, t_step, prof):
         self.fetch = fetch  # (sampled [R],) or (sampled, lp, top_ids, top_lps)
-        self.rows = rows  # List[Tuple[int, _Seq, List[int]]]: lane, seq, fed
+        self.rows = rows  # List[Tuple[int, _Seq, List[int]]]: lane, seq, fed; by row
         self.sealing = sealing  # Optional[_SealPages]: the blocks it fills
         self.t_step = t_step  # for the straggler detector alone
         self.prof = prof  # the timeline samples this dispatch
@@ -475,8 +481,38 @@ def chunk_row_ladder(max_slots: int) -> List[int]:
     an eighth and a quarter of the slots. Under load a request prefills for a
     few of the host steps it lives, so 1-4 of 32 lanes prefill in most chunk
     dispatches and up to a quarter in nearly all the rest (PERF.md 6, PR 32);
-    each rung is one more program to compile and warm."""
+    each rung is one more program to compile and warm. A row is a lane that
+    prefills or, where a lane may take several (`chunk_rows_of`), one piece
+    of a lane's prompt: the second rung is then what a prompt of eight chunks
+    prefills in at once."""
     return sorted({r for r in (max_slots // 8, max_slots // 4) if r >= 1} | {max_slots})
+
+
+def chunk_rows_of(need: List[int], waited: List[float], rungs: List[int]) -> List[int]:
+    """The rows each prefilling lane takes of a chunk dispatch, where a lane
+    may fill several with successive pieces of its prompt (the model module's
+    ``LANE_TAKES_ROWS``). ``need[i]`` is the rows lane i's remaining prompt
+    asks for, ``waited[i]`` when its request arrived, ``rungs`` the engine's
+    ladder; the dispatch is the smallest rung that holds the rows taken.
+
+    That rung is the smallest that holds ``max(n, min(W, r2))``: the n lanes,
+    and of the W rows they ask for as many as the largest rung under
+    ``max_slots`` holds (r2), so the full width is never taken for the sake
+    of pieces. Every lane gets its first row (none is starved); the rows left
+    go to further pieces, the lane that has waited longest first, and what
+    does not fit goes on in the next step. At the full width a lane has one
+    row: its program is the one without lanes (a mesh engine's, to the
+    character), and row pairs there would be ``[max_slots, max_slots]``."""
+    n, full = len(need), rungs[-1]
+    under = max((r for r in rungs if r < full), default=0)
+    rows = next(r for r in rungs if r >= max(n, min(sum(need), under)))
+    takes = [1] * n
+    if rows < full:
+        spare = rows - n
+        for i in sorted(range(n), key=lambda i: waited[i]):
+            takes[i] += min(need[i] - 1, spare)
+            spare -= takes[i] - 1
+    return takes
 
 
 class JaxServingEngine(AsyncEngine):
@@ -884,6 +920,13 @@ class JaxServingEngine(AsyncEngine):
         self.chunk_rows_dispatched = 0
         self.chunk_rows_live = 0
         self.chunk_dispatches_by_rows: Dict[int, int] = {}
+        # distinct lanes the chunk dispatches fed, summed (chunk_rows_live
+        # over it: the rows a lane took of a dispatch); the same for the lanes
+        # that prefill alone, and the prompts whose last token went through
+        # (prompt_dispatches over it: the chunk dispatches a prompt took)
+        self.chunk_lanes_fed = 0
+        self.prompt_dispatches = 0
+        self.prompts_prefilled = 0
 
         # (with_logprobs, with_penalties, with_sampling) variants, compiled
         # lazily per need; the chunk's key also holds (with_history, rows)
@@ -989,6 +1032,17 @@ class JaxServingEngine(AsyncEngine):
         self._chunk_rungs: List[int] = (
             [S] if self._rides or self._multihost or self._pp > 1 or self._sp > 1
             else chunk_row_ladder(S)
+        )
+        # a lane may fill several rows of a chunk dispatch under the full
+        # width with successive pieces of its prompt (`chunk_rows_of`) where
+        # the model's module says its chunk program lets a row attend the
+        # earlier rows of its lane. A module that keeps state per slot beside
+        # the pages does not (the state would pass from row to row inside its
+        # kernels), and the engines of one rung keep one row a lane: on a
+        # mesh until `_rides` goes.
+        self._lane_rows = (
+            len(self._chunk_rungs) > 1
+            and getattr(self.model, "LANE_TAKES_ROWS", False)
         )
         # the block counts _take_sealing reads ahead, ascending. The largest
         # is what a decode dispatch or a chunk dispatch of a rung under
@@ -1303,12 +1357,16 @@ class JaxServingEngine(AsyncEngine):
                 f"C={self.config.prefill_chunk}]"
             ))
             fn = self._chunk_fns[key] = self._build_chunk_fn(
-                want_lp, want_pen, want_sample, want_history
+                want_lp, want_pen, want_sample, want_history,
+                # under the full width a lane may fill several rows
+                # (`_chunk_build`): the program is told the rows' lanes
+                with_lanes=self._lane_rows and rows < self.config.max_slots,
             )
         return fn
 
     def _build_chunk_fn(self, with_lp: bool = False, with_pen: bool = False,
-                        with_sample: bool = True, with_history: bool = True):
+                        with_sample: bool = True, with_history: bool = True,
+                        with_lanes: bool = False):
         cfg = self.model_config
         n_top = self.config.top_logprobs
         wd = self._watchdog
@@ -1378,9 +1436,11 @@ class JaxServingEngine(AsyncEngine):
 
         def chunk(params, cache, counts, tokens, positions, tables, sample_at,
                   lanes, step_ctr, ipack, fpack, wdf=None):
-            # tokens/positions: [R, C] (−1 positions = padding), one row per
-            # prefilling lane, packed to the front; sample_at: [R] index of
-            # the token whose logits to sample, −1 → output unused; lanes:
+            # tokens/positions: [R, C] (−1 positions = padding), a row per
+            # prefilling lane or (``with_lanes``) per piece of a lane's
+            # prompt, a lane's pieces in consecutive rows in order, packed to
+            # the front; sample_at: [R] index of the token whose logits to
+            # sample (a lane's last row alone), −1 → output unused; lanes:
             # [R] the slot of each row (max_slots = a padding row), which is
             # its row of the [S, V] penalty counts. R is the inputs' own.
             # The LM head runs on the gathered [R, E] sample positions only —
@@ -1409,6 +1469,7 @@ class JaxServingEngine(AsyncEngine):
                 h, cache = forward_chunk(
                     params, cfg, tokens, positions, cache, tables,
                     hidden_only=True, with_history=with_history,
+                    lanes=lanes if with_lanes else None,
                 )
             fetch, counts = sample_rows(
                 params, h, counts, sample_at, lanes, inputs, wdf
@@ -2516,7 +2577,8 @@ class JaxServingEngine(AsyncEngine):
 
     def _prefill_step(self, paced: bool = False) -> None:
         """One host step in which some lane prefills. A chunk dispatch holds
-        one row per lane that prefills; a lane that decodes is never a row
+        the rows of the lanes that prefill, one a lane or as many as its
+        prompt needs (`chunk_rows_of`); a lane that decodes is never a row
         of it and advances through the decode program in the same step. The
         chunk goes first (a first token is what a caller waits for), and
         both programs are dispatched before either result is fetched, so
@@ -2555,13 +2617,17 @@ class JaxServingEngine(AsyncEngine):
 
     def _chunk_dispatch(self, paced: bool, t_step: float) -> Optional[_ChunkInflight]:
         """Build and dispatch one [rows, prefill_chunk] program over the lanes
-        that prefill, packed to the front; ``rows`` is the smallest rung of
-        ``_chunk_rungs`` that holds them, the rest padding (positions -1, as
-        an empty lane has). Prefilling lanes consume up to a chunk of
-        prompt each; a whole admission wave prefills in ceil(longest_suffix
-        / chunk) dispatches. Where `_rides`, the lanes that decode are rows
-        too, one token each. Returns the dispatch for `_chunk_finish`, or
-        None when no lane takes a prompt token (all budgeted out)."""
+        that prefill, packed to the front; ``rows`` is a rung of
+        ``_chunk_rungs``, the rows no lane fills padding (positions -1, as
+        an empty lane has). A prefilling lane consumes up to a chunk of
+        prompt a row, and where `_lane_rows` as many rows as its prompt needs
+        and the rung holds (`chunk_rows_of`: a lane's pieces in consecutive
+        rows, in order); at the full width, under pacing and on every other
+        engine one row, so a whole admission wave prefills in
+        ceil(longest_suffix / chunk) dispatches. Where `_rides`, the lanes
+        that decode are rows too, one token each. Returns the dispatch for
+        `_chunk_finish`, or None when no lane takes a prompt token (all
+        budgeted out)."""
         with self._clock(P_CHUNK_BUILD):
             built = self._chunk_build(paced)
         if built is None:
@@ -2613,21 +2679,37 @@ class JaxServingEngine(AsyncEngine):
                 for i in pre
             ]
             allow = dict(zip(pre, qos_mod.split_prefill_budget(rem, C, C)))
-        take: List[Tuple[int, int]] = []  # (lane, prompt tokens it feeds)
+        # a row: (lane, its first position, prompt tokens it feeds)
+        take: List[Tuple[int, int, int]] = []
         for i in sorted(pre):
             seq = self._slots[i]
             n = min(C, len(seq.prompt) - seq.prefill_pos)
             if allow is not None:
                 n = min(n, allow.get(i, 0))
             if n > 0:  # else budgeted out of this step; advances next one
-                take.append((i, n))
+                take.append((i, seq.prefill_pos, n))
         if not take:
             return None
-        n_prefill = sum(n for _, n in take)
+        n_lanes = len(take)
+        if self._lane_rows and allow is None:
+            # a lane takes the rows its prompt needs, as far as a rung under
+            # the full width holds them (`chunk_rows_of`): its pieces in
+            # consecutive rows, in order, each full but the last
+            fed_lanes = [self._slots[i] for i, _, _ in take]
+            takes = chunk_rows_of(
+                [-(-(len(s.prompt) - s.prefill_pos) // C) for s in fed_lanes],
+                [s.enqueue_t for s in fed_lanes], self._chunk_rungs,
+            )
+            take = [
+                (i, at, min(C, len(s.prompt) - at))
+                for (i, start, _), s, k in zip(take, fed_lanes, takes)
+                for at in range(start, start + k * C, C)
+            ]
+        n_prefill = sum(n for _, _, n in take)
         if self._rides:
-            # (lane, 0): a decode lane rides along, one token forward
+            # (lane, its place, 0): a decode lane rides along, one token forward
             take = sorted(take + [
-                (i, 0) for i, s in enumerate(self._slots)
+                (i, s.total_len - 1, 0) for i, s in enumerate(self._slots)
                 if s is not None and s.prefill_pos is None
             ])
 
@@ -2642,7 +2724,7 @@ class JaxServingEngine(AsyncEngine):
         fpack_np[1] = 1.0
         fed: List[Tuple[int, _Seq, List[int]]] = []  # lane, seq, its tokens
         filled: List[int] = []  # blocks this dispatch fills: they seal at its finish
-        for r, (i, n) in enumerate(take):
+        for r, (i, start, n) in enumerate(take):
             seq = self._slots[i]
             lanes[r] = i
             tables[r, : len(seq.alloc.block_ids)] = seq.alloc.block_ids
@@ -2651,13 +2733,12 @@ class JaxServingEngine(AsyncEngine):
                 seq.temperature, seq.top_p, seq.freq_pen, seq.pres_pen
             )
             if n == 0:  # a riding decode lane: its last token, at its place
-                start, n = seq.total_len - 1, 1
+                n = 1
                 chunk_toks = [seq.generated[-1] if seq.generated else seq.prompt[-1]]
                 sample_at[r] = 0
             else:
-                start = seq.prefill_pos
                 chunk_toks = seq.prompt[start : start + n]
-                if start + n == len(seq.prompt):
+                if start + n == len(seq.prompt):  # a lane's last row alone
                     sample_at[r] = n - 1
             filled += self._blocks_filled(seq.alloc, start, n)
             tokens[r, :n] = chunk_toks
@@ -2675,9 +2756,11 @@ class JaxServingEngine(AsyncEngine):
         if paced and has_decode:
             self._prefill_debt += n_prefill
         self.chunk_positions_dispatched += rows * C
-        self.chunk_tokens_fed += n_prefill + sum(1 for _, n in take if n == 0)
+        self.chunk_tokens_fed += n_prefill + sum(1 for _, _, n in take if n == 0)
         self.chunk_rows_dispatched += rows
         self.chunk_rows_live += len(take)
+        self.chunk_lanes_fed += len({i for i, _, _ in take})
+        self.prompt_dispatches += n_lanes
         self.chunk_dispatches_by_rows[rows] = (
             self.chunk_dispatches_by_rows.get(rows, 0) + 1
         )
@@ -2700,7 +2783,9 @@ class JaxServingEngine(AsyncEngine):
             # what the chunk program reads of the tables is its module's to
             # say: the tiles up to the longest history, or every table whole
             self.chunk_history_tiles_read += int(
-                self.model.chunk_history_tiles(positions, bs, MB)
+                self.model.chunk_history_tiles(
+                    positions, bs, MB, *((lanes,) if self._lane_rows else ())
+                )
             )
             self.chunk_history_tiles_full += history_tiles_full(bs, MB)
         if want_pen:
@@ -2781,6 +2866,7 @@ class JaxServingEngine(AsyncEngine):
                 self._watchdog_trip(seq)
                 continue
             seq.prefill_pos = None
+            self.prompts_prefilled += 1
             seq.first_token_t = time.perf_counter()
             self._emit_token(seq, tok, lpinfo=lpinfo)
         self._sealing = None
@@ -4232,6 +4318,11 @@ class JaxServingEngine(AsyncEngine):
             "chunk_tokens_fed": self.chunk_tokens_fed,
             "chunk_rows_dispatched": self.chunk_rows_dispatched,
             "chunk_rows_live": self.chunk_rows_live,
+            # rows a lane took of a dispatch = chunk_rows_live over the first;
+            # chunk dispatches a prompt took = the second over the third
+            "chunk_lanes_fed": self.chunk_lanes_fed,
+            "prompt_dispatches": self.prompt_dispatches,
+            "prompts_prefilled": self.prompts_prefilled,
             "chunk_dispatches_by_rows": {
                 # keyed as JSON sends it. .copy(): one atomic C-level op
                 # (the engine thread adds rungs without holding _cond)

@@ -26,7 +26,11 @@ def module_for(model_config):
     pick a module. Each exposes ``init_params``, ``make_kv_cache``,
     ``param_shardings``, ``lm_head``, and ``chunk_history_tiles`` /
     ``decode_history_tiles`` (what its step programs read of a block table,
-    for the host's count). A module whose layers keep state per slot beside
+    for the host's count), and beside them says whether a lane may fill
+    several rows of one chunk dispatch (``LANE_TAKES_ROWS = True``: its chunk
+    program takes the rows' lanes and lets a row attend the fresh keys of the
+    earlier rows of its lane; a module that says nothing keeps one row a
+    lane). A module whose layers keep state per slot beside
     the pages also has ``make_slot_state``, ``forward_chunk`` and ``decode``
     in the form ``engine_jax/engine.py`` calls them with the state, and
     ``COUNTERS`` (docs/kv_cache_manager.md, "State per slot").

@@ -843,15 +843,38 @@ def history_tiles_full(block_size: int, table_blocks: int) -> int:
     return -(-table_blocks * block_size // history_tile(block_size, table_blocks))
 
 
-def chunk_history_tiles(positions, block_size: int, table_blocks: int):
+# A lane may fill several rows of one chunk dispatch with successive pieces of
+# its prompt (the engine's `_chunk_build` says when): attention through pages
+# is all that carries a sequence's past here, and the fresh keys of a lane's
+# earlier rows are in the program's hands (`chunk_sibling_partial`). A module
+# whose layers hand state from token to token beside the pages does not say
+# this, and keeps one row a lane (docs/kv_cache_manager.md).
+LANE_TAKES_ROWS = True
+
+
+def lane_first_positions(positions, lanes):
+    """[B]: the lowest first position among the rows of each row's lane, which
+    is where the lane's pool history ends in a dispatch that holds several
+    pieces of its prompt (a row alone in its lane: its own first position; a
+    padding row, < 0, its own). In arithmetic and without a ``where``: for a
+    traced array and a numpy one alike, as :func:`chunk_history_tiles` is."""
+    first = positions[:, 0]
+    sibling = (lanes[:, None] == lanes[None, :]) & (first[None, :] >= 0)
+    return (sibling * first[None, :] + ~sibling * first[:, None]).min(axis=1)
+
+
+def chunk_history_tiles(positions, block_size: int, table_blocks: int, lanes=None):
     """Trips of :func:`forward_chunk`'s history loop for ``positions`` [B, C]:
     the tiles that hold the longest history of the dispatch. A lane's history
     is what lies below its first query (``positions[:, 0]``; a padding lane,
-    < 0, has none), and no lane's reaches past its block table. Written for a
-    traced array (the program's own trip count) and for a numpy one (the
-    host's count of what the program will read) alike."""
+    < 0, has none; with ``lanes`` [B] given, below the first query of the
+    lane's FIRST row: :func:`lane_first_positions`), and no lane's reaches
+    past its block table. Written for a traced array (the program's own trip
+    count) and for a numpy one (the host's count of what the program will
+    read) alike."""
     tile = history_tile(block_size, table_blocks)
-    longest = positions[:, 0].max().clip(0, table_blocks * block_size)
+    first = positions[:, 0] if lanes is None else lane_first_positions(positions, lanes)
+    longest = first.max().clip(0, table_blocks * block_size)
     return (longest + tile - 1) // tile
 
 
@@ -1145,6 +1168,75 @@ def chunk_history_partial(
     return jax.lax.fori_loop(0, n_tiles, tile, empty)
 
 
+def chunk_sibling_partial(
+    c: LlamaConfig,
+    q: jax.Array,  # [B, T, H, D] one layer's chunk queries (rope applied)
+    k: jax.Array,  # [B, T, KVH, D] the chunk's fresh keys (rope applied)
+    v: jax.Array,
+    positions: jax.Array,  # [B, T]; < 0 = padding
+    lanes: jax.Array,  # [B] the lane of each row
+    n_back,  # trips: `sibling_rows_back`
+    scale: float,
+    acc: Tuple[jax.Array, jax.Array, jax.Array],
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``acc`` with the flash partial of each row's queries against the fresh
+    keys of the EARLIER rows of its lane folded in: a lane that fills several
+    rows of a dispatch with successive pieces of its prompt attends, from a
+    later piece, keys that are in hand and not yet in the pool. Trip ``i``
+    meets every row with the row ``i + 1`` above it, where that is a row of
+    the same lane, as the row's own partial meets it with itself (causal by
+    position, so an earlier piece is attended whole); ``n_back`` trips reach
+    the farthest pair of the dispatch, and none leaves ``acc`` as it was.
+    Scores are ``[B, T, T]`` a trip, never ``[B, B]`` row pairs at once."""
+    b, t, h, d = q.shape
+    kvh = k.shape[2]
+    qg = q.reshape(b, t, kvh, h // kvh, d)
+    rows = jnp.arange(b)
+    # rows r - back .. r - back + B - 1 of a doubled array: row r meets r - back
+    k2, v2 = jnp.concatenate([k, k]), jnp.concatenate([v, v])
+    pos2, lanes2 = jnp.concatenate([positions, positions]), jnp.concatenate([lanes, lanes])
+
+    def above(x2, back):
+        return jax.lax.dynamic_slice_in_dim(x2, b - back, b, axis=0)
+
+    def trip(i, acc):
+        back = i + 1
+        kv_pos = above(pos2, back)  # [B, T]
+        met = (above(lanes2, back) == lanes) & (rows >= back)  # [B]
+        mask = (
+            met[:, None, None] & (kv_pos >= 0)[:, None, :]
+            & (kv_pos[:, None, :] <= positions[:, :, None])
+        )[:, None, None, :, :]
+        scores = jnp.einsum(
+            "btngd,bsnd->bngts", qg, above(k2, back),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [B, KVH, G, T, T]
+        scores = jnp.where(mask, scores, -jnp.inf)
+        m = jnp.maximum(scores.max(axis=-1), -1e30)
+        p = jnp.exp(scores - m[..., None])
+        num = jnp.einsum(
+            "bngts,bsnd->btngd", p, above(v2, back).astype(jnp.float32)
+        )
+        return _merge_partials(acc, (
+            num.reshape(b, t, h, d),
+            m.reshape(b, h, t),
+            p.sum(axis=-1).reshape(b, h, t),
+        ))
+
+    return jax.lax.fori_loop(0, n_back, trip, acc)
+
+
+def sibling_rows_back(positions, lanes):
+    """Trips of :func:`chunk_sibling_partial` for a dispatch: the farthest
+    that a row lies below an earlier row of its lane (0: every lane has one
+    row; the engine lays a lane's pieces in consecutive rows, so the rows a
+    lane took less one)."""
+    real = positions[:, 0] >= 0
+    rows = jnp.arange(lanes.shape[0])
+    pair = (lanes[:, None] == lanes[None, :]) & real[:, None] & real[None, :]
+    return jnp.where(pair, rows[:, None] - rows[None, :], 0).max()
+
+
 def forward_chunk(
     params: Params,
     config: LlamaConfig,
@@ -1155,6 +1247,7 @@ def forward_chunk(
     *,
     hidden_only: bool = False,
     with_history: bool = True,
+    lanes: Optional[jax.Array] = None,  # [B] the rows' lanes: a lane may take several
 ) -> Tuple[jax.Array, KVCache]:
     """Prefill-chunk forward with the history/fresh attention split — the
     same contract as :func:`forward`, restructured for the TPU scheduler.
@@ -1180,7 +1273,16 @@ def forward_chunk(
 
     ``with_history=False`` compiles out the pool gather + history partial
     entirely — the caller guarantees every lane starts at position 0 (a
-    fresh admission wave's first chunk, THE TTFT-critical dispatch)."""
+    fresh admission wave's first chunk, THE TTFT-critical dispatch).
+
+    ``lanes`` given, a lane may fill SEVERAL rows with successive pieces of
+    its prompt, an earlier piece in an earlier row: a row's pool history then
+    ends where the lane's first row of the dispatch starts
+    (:func:`lane_first_positions`), and one more partial a layer attends the
+    fresh keys of the lane's earlier rows (:func:`chunk_sibling_partial`).
+    The pool is still written once, after the loop, by position and table:
+    two rows of a lane write different positions of the same pages. ``None``
+    is the program as it was, one row a lane, to the character."""
     from dynamo_tpu.ops.attention import write_kv_to_pool
 
     c = config
@@ -1193,8 +1295,13 @@ def forward_chunk(
     pages = _pool_pages(kv_cache)
     tile_blocks = history_tile(block_size, table_blocks) // block_size
     # what a lane has in the pool: below its first query, within its table
-    history_len = jnp.clip(positions[:, 0], 0, table_blocks * block_size)  # [B]
-    n_tiles = chunk_history_tiles(positions, block_size, table_blocks)
+    # (with lanes: below the first query of the lane's FIRST row; the rows
+    # between are siblings, their keys in hand)
+    first = positions[:, 0] if lanes is None else lane_first_positions(positions, lanes)
+    history_len = jnp.clip(first, 0, table_blocks * block_size)  # [B]
+    n_tiles = chunk_history_tiles(positions, block_size, table_blocks, lanes)
+    if lanes is not None:
+        n_back = sibling_rows_back(positions, lanes)
     # whole tiles: the columns added point at page 0 and lie past every history
     max_tiles = history_tiles_full(block_size, table_blocks)
     tables = jnp.pad(
@@ -1213,6 +1320,10 @@ def forward_chunk(
                 hidden.dtype,
             )
             part = _merge_partials(hist, part)
+        if lanes is not None:
+            part = chunk_sibling_partial(
+                c, q, k, v, positions, lanes, n_back, scale, part
+            )
         num, _, den = part
         attn = jnp.where(
             (den > 0.0).transpose(0, 2, 1)[..., None],

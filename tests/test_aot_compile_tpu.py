@@ -158,6 +158,15 @@ def _chunk(params, cache, cfg, tokens, positions, tables, *, with_history):
     )
 
 
+def _chunk_lanes(params, cache, cfg, tokens, positions, tables, lanes):
+    """The chunk program of a rung under ``max_slots`` on one device: the
+    rows' lanes given, so that a lane may fill several rows of it."""
+    return llama.forward_chunk(
+        params, cfg, tokens, positions, cache, tables,
+        hidden_only=True, with_history=True, lanes=lanes,
+    )
+
+
 def _flush(params, cache, cfg, tokens, positions, tables):
     w = (cfg.num_layers, LANES, WINDOW, cfg.num_kv_heads, cfg.head_dim)
     wk = wv = jnp.zeros(w, cfg.dtype)
@@ -207,8 +216,9 @@ POOL_PROGRAMS = {
 }
 
 
-def _compile_pool_program(program, cfg, num_blocks, quantized, mesh, one_chip, rows=LANES):
-    fn, kw = POOL_PROGRAMS[program]
+def _compile_pool_program(program, cfg, num_blocks, quantized, mesh, one_chip, rows=LANES,
+                          lower_only=False):
+    fn, kw = (_chunk_lanes, {}) if program == "chunk_lanes" else POOL_PROGRAMS[program]
     params = jax.eval_shape(lambda: llama.init_params(jax.random.PRNGKey(0), cfg))
     cache = jax.eval_shape(
         lambda: llama.make_kv_cache(cfg, num_blocks, BS, quantized=quantized)
@@ -233,14 +243,14 @@ def _compile_pool_program(program, cfg, num_blocks, quantized, mesh, one_chip, r
     ints = [
         jax.ShapeDtypeStruct(shape, jnp.int32, sharding=rep)
         for shape in ((rows, CHUNK), (rows, CHUNK), (rows, TABLE))
+        + (((rows,),) if fn is _chunk_lanes else ())
     ]
     jitted = jax.jit(
         lambda params, cache, *a: fn(params, cache, cfg, *a, **kw),
         donate_argnums=(1,),
     )
-    return jitted.lower(
-        shaped(params, param_sh), shaped(cache, cache_sh), *ints
-    ).compile()
+    lowered = jitted.lower(shaped(params, param_sh), shaped(cache, cache_sh), *ints)
+    return lowered if lower_only else lowered.compile()
 
 
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(")
@@ -420,6 +430,48 @@ def test_the_smallest_rung_of_the_chunk_program_holds_an_eighth_of_the_temporari
     temp = compiled.memory_analysis().temp_size_in_bytes
     print(f"chunk program at {rows} rows: temp_size_in_bytes {temp}")
     assert temp < CHUNK_TEMP_LIMIT * rows // LANES * 2
+
+
+# sha256 of the chunk program's lowered text with no lanes given, at 8 rows,
+# Qwen2.5-1.5B's widths and POOL_LAYERS layers, as the tree before a lane could
+# take several rows lowered it (PR 44's): the program a mesh engine, the
+# full-width rung and `verify` run is that one to the character, and their
+# compile-cache entries stand
+CHUNK_TEXT_SHA256 = "d9212223c0442bf8ee7eca11f9147286d140813a239ba00f743a447cd0b8710c"
+
+
+@pytest.mark.parametrize("rows", [4, 8])
+def test_the_chunk_program_with_lanes_given_compiles_in_place_at_the_small_rungs(one_chip, rows):
+    """What ``batch.qwen2.5-1.5b`` dispatches at its rungs of 4 and 8 rows since
+    a lane may fill several of them (``forward_chunk(..., lanes=)``), at full
+    depth and the served pool, for the described v5e: it compiles, the pool is
+    never copied, and the sibling partial's scores stay a row pair at a time:
+    the temporaries stay under those of the program without lanes at 8 rows
+    plus 64 MB (one masked product over all row pairs is 50 MB of float32
+    scores a layer at 8 rows, and more than one of them live)."""
+    import hashlib
+
+    cfg = llama.LLAMA_PRESETS["qwen2.5-1.5b"]
+    compiled = _compile_pool_program("chunk_lanes", cfg, 6144, False, None, one_chip, rows=rows)
+    pool = jax.eval_shape(lambda: llama.make_kv_cache(cfg, 6144, BS))
+    pages = pool["k"].size
+    assert _pool_sized_instructions(compiled.as_text(), {pages, pages // cfg.num_layers}) == []
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in pool.values())
+    memory = compiled.memory_analysis()
+    assert pool_bytes <= memory.alias_size_in_bytes < pool_bytes * 1.001
+    plain = _compile_pool_program("chunk", cfg, 6144, False, None, one_chip, rows=8)
+    plain_temp = plain.memory_analysis().temp_size_in_bytes
+    print(f"chunk program with lanes at {rows} rows: temp_size_in_bytes "
+          f"{memory.temp_size_in_bytes}; without, at 8 rows: {plain_temp}")
+    assert memory.temp_size_in_bytes < plain_temp + 64 * 2 ** 20
+    # no scores over all row pairs: [rows, ..., 128, rows * 128]
+    assert re.findall(rf"f32\[[\d,]*,{rows * CHUNK}\]", compiled.as_text()) == []
+    # and with no lanes given, the program's text is what it was
+    short = dataclasses.replace(cfg, num_layers=POOL_LAYERS)
+    text = _compile_pool_program(
+        "chunk", short, POOL_BLOCKS[1], False, None, one_chip, rows=8, lower_only=True
+    ).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == CHUNK_TEXT_SHA256
 
 
 # Temporary memory of the decode program at Qwen2.5-1.5B's serving shapes (32

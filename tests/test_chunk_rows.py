@@ -1,5 +1,7 @@
-"""A chunk dispatch holds one row per lane that prefills; a lane that decodes is
-never a row of it (engine_jax/engine.py ``_prefill_step``).
+"""A chunk dispatch holds the rows of the lanes that prefill, a lane as many as
+its prompt needs while a rung under the full width holds them
+(engine_jax/engine.py ``chunk_rows_of``); a lane that decodes is never a row of
+it (``_prefill_step``).
 
 The engine is driven one host step at a time on the test's own thread
 (``_admit`` + ``_dispatch_step``, what ``_step_loop`` runs; no engine thread is
@@ -23,6 +25,7 @@ from dynamo_tpu.engine_jax.engine import (
     JaxServingEngine,
     _Seq,
     chunk_row_ladder,
+    chunk_rows_of,
 )
 from dynamo_tpu.llm.protocols.common import (
     PreprocessedRequest,
@@ -172,6 +175,44 @@ def test_an_engine_on_a_mesh_has_the_one_rung_ladder(params, axes):
         eng.close()
 
 
+# -- the rule: which rung, and whose pieces fill it ---------------------------------
+
+
+@pytest.mark.parametrize("need, waited, rungs, want", [
+    # one lane: the rows its prompt needs, as far as the second rung holds them
+    ([1], [0], [4, 8, 32], (4, [1])),
+    ([3], [0], [4, 8, 32], (4, [3])),
+    ([5], [0], [4, 8, 32], (8, [5])),
+    ([8], [0], [4, 8, 32], (8, [8])),
+    ([12], [0], [4, 8, 32], (8, [8])),  # the rest goes on in the next step
+    # every lane its first row, the rows left to the one that waited longest
+    ([3, 2], [5, 1], [4, 8, 32], (8, [3, 2])),
+    ([3, 3], [5, 1], [4, 8, 32], (8, [3, 3])),
+    ([8, 8, 2], [3, 1, 2], [4, 8, 32], (8, [1, 6, 1])),
+    ([2, 8, 2], [3, 9, 2], [4, 8, 32], (8, [2, 4, 2])),
+    ([1, 1, 1, 1], [0, 1, 2, 3], [4, 8, 32], (4, [1, 1, 1, 1])),
+    ([2, 1, 1, 1], [9, 1, 2, 3], [4, 8, 32], (8, [2, 1, 1, 1])),
+    ([3] * 8, list(range(8)), [4, 8, 32], (8, [1] * 8)),
+    # more lanes than the second rung: the full width, and one row a lane there
+    ([3] * 9, list(range(9)), [4, 8, 32], (32, [1] * 9)),
+    ([8] * 32, list(range(32)), [4, 8, 32], (32, [1] * 32)),
+    # the pieces never raise the rung to the full width
+    ([8, 8], [0, 1], [1, 2, 8], (2, [1, 1])),
+    ([5], [0], [1, 2, 8], (2, [2])),
+    ([5], [0], [1, 4], (1, [1])),
+    # one rung (a mesh engine's ladder, were it asked): one row a lane
+    ([5, 2], [0, 1], [16], (16, [1, 1])),
+], ids=lambda v: "-".join(map(str, v)) if isinstance(v, list) else None)
+def test_the_rung_holds_the_lanes_and_as_many_pieces_as_the_second_rung_takes(need, waited, rungs, want):
+    takes = chunk_rows_of(need, waited, rungs)
+    rows = next(r for r in rungs if r >= sum(takes))  # as `_chunk_build` picks it
+    assert (rows, takes) == want
+    # the rung of the rule: the lanes, and of their pieces what the second rung holds
+    under = max((r for r in rungs if r < rungs[-1]), default=0)
+    assert rows == next(r for r in rungs if r >= max(len(need), min(sum(need), under)))
+    assert all(1 <= t <= n for t, n in zip(takes, need))
+
+
 # -- token for token -------------------------------------------------------------
 
 # (arrives at host step, prompt tokens, answer tokens, sampling): prompts of one
@@ -259,12 +300,109 @@ def test_on_a_mesh_the_decode_lanes_ride_the_chunk_and_answer_as_alone(params, s
         # and its decode program keeps every table's full width
         assert eng.decode_history_tiles_read == eng.decode_history_tiles_full > 0
         assert set(eng.chunk_dispatches_by_rows) == {ENGINE_CFG.max_slots}
-        # more rows than chunks of prompt: the riders
+        # more rows than chunks of prompt: the riders, each lane one row
         assert eng.chunk_rows_live > sum(-(-n // CHUNK) for _, n, _, _ in schedule)
+        assert not eng._lane_rows and eng.chunk_rows_live == eng.chunk_lanes_fed
         assert eng.allocator.active_blocks == 0 and not eng._zombie_allocs
     finally:
         eng.close()
         one.close()
+
+
+# ladder [2, 4, 16]: a lane fills up to four rows of a dispatch
+WIDE_CFG = dataclasses.replace(ENGINE_CFG, max_slots=16)
+# a prompt of seven chunks beside lanes that decode and a short prompt: more
+# rows than the second rung holds, so its pieces go on in the next step
+SPILLS = [(0, 9, 30, {}), (2, 100, 8, {}), (2, 12, 10, {}), (3, 60, 6, {})]
+
+
+@pytest.fixture(scope="module")
+def wide(params):
+    eng = JaxServingEngine(CFG, params, WIDE_CFG)
+    yield eng
+    eng.close()
+
+
+@pytest.mark.parametrize("salt, schedule", [
+    (100, MIXED),
+    (110, with_sampling(MIXED, {1, 3, 4}, logprobs=0)),
+    (120, with_sampling(MIXED, {0, 2}, frequency_penalty=1.5, presence_penalty=0.5)),
+    (130, WAVE),
+    (140, SPILLS),
+], ids=["plain", "logprobs", "a_penalised_lane", "five_lanes_at_once",
+        "a_prompt_longer_than_the_second_rung_holds"])
+def test_every_request_answers_as_alone_where_a_lane_fills_several_rows(wide, alone, salt, schedule):
+    """The same traffic on a ladder whose second rung holds four rows: most
+    prompts prefill in one dispatch, a later piece attending the earlier
+    rows' fresh keys in the program, and every answer is the one the request
+    gets alone, prefilled a chunk a step."""
+    assert not busy(wide)
+    before = wide.metrics_snapshot()
+    got = serve_schedule(wide, schedule, salt=salt)
+    for i, (at, n, m, sampling) in enumerate(schedule):
+        toks, lps, finish = got[i]
+        want_toks, want_lps, _ = alone(prompt_of(n, salt + i), m, **sampling)
+        assert (toks, finish) == (want_toks, "length"), i
+        if "logprobs" in sampling:
+            np.testing.assert_allclose(lps, want_lps, atol=2e-5)
+    rise = {k: v - before[k] for k, v in wide.metrics_snapshot().items()
+            if k.startswith(("chunk_", "prompt")) and isinstance(v, int)}
+    # a row for every chunk of every prompt, whichever dispatch held it
+    assert rise["chunk_rows_live"] == sum(-(-n // CHUNK) for _, n, _, _ in schedule)
+    assert rise["prompts_prefilled"] == len(schedule)
+    assert rise["chunk_lanes_fed"] == rise["prompt_dispatches"]
+    # lanes took several rows: fewer dispatches a prompt than chunks a prompt
+    assert rise["chunk_rows_live"] > rise["chunk_lanes_fed"]
+    if schedule is SPILLS:
+        # the 100 tokens: three rows beside the 12's one, three beside the
+        # 60's first, the last beside the 60's other three
+        assert rise["prompt_dispatches"] == 1 + 3 + 1 + 2
+        assert rise["chunk_lanes_fed"] == 7 and rise["chunk_rows_live"] == 13
+    assert wide.allocator.active_blocks == 0 and not wide._zombie_allocs
+
+
+def test_a_request_cancelled_between_its_lanes_two_dispatches_moves_no_other(wide, alone):
+    """Request 1's first three rows went through one dispatch; it is cancelled
+    before the step that would take the next three."""
+    def cancel(t, seqs):
+        if t == 3:
+            assert seqs[1].prefill_pos == 3 * CHUNK
+            seqs[1].ctx.context.stop_generating()
+
+    got = serve_schedule(wide, SPILLS, on_step=cancel, salt=150)
+    for i, (at, n, m, sampling) in enumerate(SPILLS):
+        if i == 1:
+            assert got[i] == ([], [], "cancelled")
+        else:
+            assert got[i][0] == alone(prompt_of(n, 150 + i), m)[0], i
+    assert wide.allocator.active_blocks == 0 and not wide._zombie_allocs
+
+
+@pytest.mark.parametrize("which", ["a_module_that_says_nothing", "rides"])
+def test_an_engine_whose_module_or_mesh_does_not_allow_it_gives_a_lane_one_row(
+        params, alone, monkeypatch, which):
+    """Whether a lane's rows may share a dispatch is the module's to say
+    (``LANE_TAKES_ROWS``; a module that keeps state per slot says nothing),
+    and an engine whose decode lanes ride the chunk keeps one row a lane."""
+    from dynamo_tpu.models import llama
+
+    if which == "rides":
+        eng = mesh_engine(params, tp=2)
+    else:
+        monkeypatch.delattr(llama, "LANE_TAKES_ROWS")
+        eng = JaxServingEngine(CFG, params, WIDE_CFG)
+    try:
+        assert not eng._lane_rows
+        got = serve_schedule(eng, SPILLS, salt=160)
+        for i, (at, n, m, _) in enumerate(SPILLS):
+            if which != "rides":  # a sharded sum rounds as a sharded sum
+                assert got[i][0] == alone(prompt_of(n, 160 + i), m)[0], i
+            assert len(got[i][0]) == m
+        assert eng.chunk_rows_live == eng.chunk_lanes_fed
+        assert eng.prompt_dispatches == sum(-(-n // CHUNK) for _, n, _, _ in SPILLS)
+        assert eng.prompts_prefilled == len(SPILLS)
+    finally:
+        eng.close()
 
 
 def test_a_request_cancelled_mid_prefill_frees_its_lane_and_moves_no_other(eng, alone):
@@ -310,18 +448,18 @@ def test_a_decode_dispatch_in_flight_emits_nothing_for_a_lane_that_prefilled_the
     step(eng)  # A prefills
     step(eng)  # A decodes: one dispatch in flight
     assert eng._inflight is not None and eng._inflight.lanes[a.slot] is a
-    b = submit(eng, prompt_of(2 * CHUNK, 51), 6)
-    step(eng)  # B's first chunk, beside A's decode dispatch
-    assert b.prefill_pos == CHUNK
+    b = submit(eng, prompt_of(3 * CHUNK, 51), 6)
+    step(eng)  # B's first two chunks (the rung of 2), beside A's decode dispatch
+    assert b.prefill_pos == 2 * CHUNK
     before = eng._inflight
     assert before.lanes[b.slot] is None and before.lanes[a.slot] is a
     step(eng)  # B's last chunk; `before` is processed after it
     assert b.prefill_pos is None and eng._inflight is not before
     toks, _, _ = answer(b)
-    assert toks == alone(prompt_of(2 * CHUNK, 51), 6)[0][:1]  # the first token, no garbage run
+    assert toks == alone(prompt_of(3 * CHUNK, 51), 6)[0][:1]  # the first token, no garbage run
     assert eng._inflight.lanes[b.slot] is None  # still inert in the newest dispatch
     run_out(eng)
-    assert answer(b)[0] == alone(prompt_of(2 * CHUNK, 51), 6)[0][1:]
+    assert answer(b)[0] == alone(prompt_of(3 * CHUNK, 51), 6)[0][1:]
     assert answer(a)[0] == alone(prompt_of(9, 50), 40)[0]
 
 
@@ -406,20 +544,24 @@ def test_the_counters_say_how_full_the_chunk_dispatches_are(params):
         snap = eng.metrics_snapshot()
         assert [snap[k] for k in (
             "chunk_positions_dispatched", "chunk_tokens_fed", "chunk_rows_dispatched",
-            "chunk_rows_live", "chunk_dispatches_by_rows")] == [0, 0, 0, 0, {}]
+            "chunk_rows_live", "chunk_dispatches_by_rows", "chunk_lanes_fed",
+            "prompt_dispatches", "prompts_prefilled")] == [0, 0, 0, 0, {}, 0, 0, 0]
         a = submit(eng, prompt_of(9, 0), 30)
         step(eng)  # one row of 16 positions, 9 tokens
-        submit(eng, prompt_of(40, 1), 2)  # 16 + 16 + 8, one row each, beside A
+        submit(eng, prompt_of(40, 1), 2)  # 16 + 16 in two rows of one dispatch, then 8, beside A
         for _ in range(3):
             step(eng)
         submit(eng, prompt_of(5, 2), 2)
-        submit(eng, prompt_of(20, 3), 2)  # two rows, then one
+        submit(eng, prompt_of(20, 3), 2)  # two lanes fill the rung of 2, then the 4 tokens left
         run_out(eng)
         snap = eng.metrics_snapshot()
-        assert snap["chunk_dispatches_by_rows"] == {"1": 5, "2": 1}
+        assert snap["chunk_dispatches_by_rows"] == {"1": 3, "2": 2}
         assert snap["chunk_rows_dispatched"] == 7 and snap["chunk_rows_live"] == 7
         assert snap["chunk_positions_dispatched"] == 7 * CHUNK
         assert snap["chunk_tokens_fed"] == 9 + 40 + 5 + 20
+        # seven rows over the six lanes the five dispatches fed: one lane took two
+        assert snap["chunk_lanes_fed"] == snap["prompt_dispatches"] == 6
+        assert snap["prompts_prefilled"] == 4  # 1 + 2 + 1 + 2 dispatches
         assert len(answer(a)[0]) == 30
     finally:
         eng.close()

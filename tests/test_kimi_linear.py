@@ -400,6 +400,8 @@ def test_the_engine_serves_the_reference_greedy_tokens_and_logprobs(engine, para
     assert snap["moe_expert_reads"] == snap["moe_experts_hit"]
     # both programs read every table's full width, and the counters say so
     assert snap["chunk_history_tiles_read"] == snap["chunk_history_tiles_full"] > 0
+    # state per slot beside the pages: a lane has ONE row of a chunk dispatch
+    assert not engine._lane_rows and snap["chunk_rows_live"] == snap["chunk_lanes_fed"] > 0
     assert snap["decode_history_tiles_read"] == snap["decode_history_tiles_full"] > 0
     tiers = list(snap["attention_tiers"].values())
     assert tiers and all(t == {"tier": "dense", "interpret": False} for t in tiers)

@@ -434,6 +434,8 @@ def test_the_engine_serves_the_reference_greedy_tokens_and_logprobs(engine, para
     assert not any(k.startswith(("ssm_", "kda_")) for k in snap)
     # the module says what its programs read of the tables: the live part, not all
     assert 0 < snap["chunk_history_tiles_read"] <= snap["chunk_history_tiles_full"]
+    # state per slot beside the pages: a lane has ONE row of a chunk dispatch
+    assert not engine._lane_rows and snap["chunk_rows_live"] == snap["chunk_lanes_fed"] > 0
     assert 0 < snap["decode_history_tiles_read"] <= snap["decode_history_tiles_full"]
     assert set(engine.cache) == {"k", "v"} and engine.cache["k"].shape == (2, engine.num_blocks, 8, 2, 128)
     tiers = list(snap["attention_tiers"].values())

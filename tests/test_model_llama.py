@@ -676,3 +676,110 @@ def test_taking_pages_is_the_block_axis_index(quantized):
     assert set(taken) == set(pool)
     for name, a in pool.items():
         np.testing.assert_array_equal(np.asarray(taken[name]), np.asarray(a[:, ids]))
+
+
+# -- a lane that fills several rows of one chunk dispatch -------------------------
+#
+# With the rows' lanes given, a lane may bring successive pieces of its prompt
+# as rows of ONE dispatch: a later piece attends the pool below the lane's
+# FIRST row, the fresh keys of the lane's earlier rows (chunk_sibling_partial)
+# and its own. Held against the same pieces dispatched one at a time, one row a
+# lane, the program as it was.
+
+R_ROWS, R_CHUNK, R_SLOTS = 8, 128, 8
+# (lane, first position, tokens) of each row; lane R_SLOTS = a padding row.
+# Lane 2 is a prompt of 300 tokens in three rows, a padding row between them;
+# lane 5 has 40 tokens in the pool already and brings two pieces, one in the
+# first row and one five rows below it; lane 1 has one row.
+R_LAYOUT = [
+    (5, 40, 128), (2, 0, 128), (2, 128, 128), (R_SLOTS, -1, 0),
+    (2, 256, 44), (5, 168, 100), (1, 7, 60), (R_SLOTS, -1, 0),
+]
+
+_forward_chunk_rows = jax.jit(
+    lambda p, tk, ps, c, bt, lanes, cfg: forward_chunk(p, cfg, tk, ps, c, bt, lanes=lanes),
+    static_argnames="cfg",
+)
+_forward_chunk_of = jax.jit(
+    lambda p, tk, ps, c, bt, cfg: forward_chunk(p, cfg, tk, ps, c, bt), static_argnames="cfg",
+)
+
+
+def _rows_dispatch(layout, tokens_of, tables_of):
+    tokens = np.zeros((R_ROWS, R_CHUNK), np.int32)
+    positions = np.full((R_ROWS, R_CHUNK), -1, np.int32)
+    tables = np.zeros((R_ROWS, T_MB), np.int32)
+    lanes = np.full((R_ROWS,), R_SLOTS, np.int32)
+    for r, (lane, start, n) in enumerate(layout):
+        if n:
+            lanes[r] = lane
+            tokens[r, :n] = tokens_of[lane][start:start + n]
+            positions[r, :n] = np.arange(start, start + n)
+            tables[r] = tables_of[lane]
+    return tokens, positions, tables, lanes
+
+
+@pytest.mark.parametrize("dtype, tol", [(jnp.float32, 1e-5), (jnp.bfloat16, 6e-2)],
+                         ids=["float32", "bfloat16"])
+def test_a_lanes_rows_of_one_dispatch_give_what_its_pieces_give_one_dispatch_each(dtype, tol):
+    cfg = dataclasses.replace(CFG, dtype=dtype)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    n_blocks = R_SLOTS * T_MB + 3
+    order = np.random.default_rng(5).permutation(n_blocks)[: R_SLOTS * T_MB]
+    tables_of = order.reshape(R_SLOTS, T_MB).astype(np.int32)
+    tokens_of = np.asarray(
+        jax.random.randint(jax.random.PRNGKey(17), (R_SLOTS, 512), 0, cfg.vocab_size))
+    before = make_kv_cache(cfg, n_blocks, T_BLOCK, dtype=dtype)
+    # lane 5's 40 and lane 1's 7 tokens of history, through the program itself
+    history = [(5, 0, 40), (1, 0, 7)] + [(R_SLOTS, -1, 0)] * 6
+    tk, ps, bt, _ = _rows_dispatch(history, tokens_of, tables_of)
+    _, before = _forward_chunk_of(params, tk, ps, before, bt, cfg)
+
+    # the host's count of the history tiles is the program's: lane 5's 40
+    # positions, not the 168 its second row starts at
+    tk, ps, bt, lanes = _rows_dispatch(R_LAYOUT, tokens_of, tables_of)
+    assert chunk_history_tiles(ps, T_BLOCK, T_MB) == 1
+    assert chunk_history_tiles(ps, T_BLOCK, T_MB, lanes) == 1
+    deep = [(5, 300, 128), (5, 428, 10)] + [(R_SLOTS, -1, 0)] * 6
+    deep_ps, deep_lanes = _rows_dispatch(deep, tokens_of, tables_of)[1::2]
+    assert chunk_history_tiles(deep_ps, T_BLOCK, T_MB) == 2
+    assert chunk_history_tiles(deep_ps, T_BLOCK, T_MB, deep_lanes) == 2
+    deep_ps[0, 0] = 255  # the lane's first row decides, whichever row it is
+    assert chunk_history_tiles(deep_ps, T_BLOCK, T_MB, deep_lanes) == 1
+    assert int(jax.jit(chunk_history_tiles, static_argnums=(1, 2))(
+        jnp.asarray(deep_ps), T_BLOCK, T_MB, jnp.asarray(deep_lanes))) == 1
+
+    logits, got = _forward_chunk_rows(params, tk, ps, before, bt, lanes, cfg)
+
+    # one row a lane: a dispatch for each further piece of a lane
+    want, want_logits = before, {}
+    left = [(r, row) for r, row in enumerate(R_LAYOUT) if row[2]]
+    while left:
+        taken, now, later = set(), [], []
+        for r, row in left:
+            (later if row[0] in taken else now).append((r, row))
+            taken.add(row[0])
+        layout = [(R_SLOTS, -1, 0)] * R_ROWS
+        for r, row in now:
+            layout[r] = row
+        one = _rows_dispatch(layout, tokens_of, tables_of)
+        out, want = _forward_chunk_of(params, *one[:2], want, one[2], cfg)
+        for r, (_, _, n) in now:
+            want_logits[r] = np.asarray(out[r, n - 1], np.float32)
+        left = later
+    assert len(want_logits) == 6
+    for r, (lane, start, n) in enumerate(R_LAYOUT):
+        if n:  # the last token of every row, a lane's last row among them
+            np.testing.assert_allclose(
+                np.asarray(logits[r, n - 1], np.float32), want_logits[r], rtol=tol, atol=tol)
+    for name in ("k", "v"):
+        np.testing.assert_allclose(
+            np.asarray(got[name], np.float32), np.asarray(want[name], np.float32),
+            rtol=tol, atol=tol)
+    # and no row but those the rows own was written
+    untouched = np.ones(n_blocks, bool)
+    for lane, start, n in R_LAYOUT + history:
+        if n:
+            untouched[tables_of[lane, : -(-(start + n) // T_BLOCK)]] = False
+    np.testing.assert_array_equal(
+        np.asarray(got["k"], np.float32)[:, untouched], 0.0)
