@@ -1,4 +1,4 @@
-"""Cross-host device-path KV transfer (VERDICT r3 missing item 4).
+"""Cross-host device-path KV transfer.
 
 The host-staged TCP plane (disagg/transfer.py) works everywhere but pays
 device→host→TCP→host→device. On platforms whose PJRT backend implements the

@@ -117,8 +117,8 @@ logger = logging.getLogger(__name__)
 class _EnginePerf:
     """Live decode-perf accounting (engine thread only, EMA-smoothed).
 
-    The BENCH files compute tokens/s and roofline fractions *offline*; this
-    makes the same signals live gauges on the metrics stream
+    The benchmark reduces tokens/s and roofline shares from a run *after* it
+    (``benchmark/run.py``); this keeps their host-clock estimates as live gauges on the metrics stream
     (``ForwardPassMetrics.decode_tokens_per_s`` etc.) so the telemetry
     plane — and eventually the SLA planner — can see a decode regression as
     it happens. Built only when telemetry sampling is enabled
@@ -202,7 +202,7 @@ class EngineConfig:
     remote_prefill_timeout: float = 60.0
     # host-RAM KV tier: evicted device blocks spill here and re-enter HBM on
     # a prefix hit (0 = disabled). Sized in blocks; reference credits the
-    # equivalent pinned-host tier with +40% TTFT on multi-turn (BASELINE.md).
+    # equivalent pinned-host tier with +40% TTFT on multi-turn (SURVEY.md).
     host_cache_blocks: int = 0
     # alternatives computed per step for OpenAI logprobs; matches OpenAI's
     # documented top_logprobs bound so a validated request is never silently
@@ -1768,8 +1768,8 @@ class JaxServingEngine(AsyncEngine):
         Mesh engines keep the executing warmup: AOT avals would need the
         exact input shardings, and on a multi-process mesh the warmup
         executions themselves must run in leader/follower lockstep.
-        Returns per-variant compile seconds (recorded by the bench —
-        VERDICT r4 item 9)."""
+        Returns per-variant compile seconds (``setup_phase_s`` has the
+        start-up's phases by name)."""
         cfg = self.config
         S, C, MB = cfg.max_slots, cfg.prefill_chunk, cfg.max_blocks_per_seq
         timings: Dict[str, float] = {}
@@ -4287,7 +4287,7 @@ class JaxServingEngine(AsyncEngine):
             # deferrals onto a concurrent identical prefix + tokens saved
             "inflight_prefill_waits": self.allocator.inflight_waits,
             "shared_prefill_tokens": self.allocator.shared_prefill_tokens,
-            # live perf accounting (telemetry plane): the BENCH roofline
+            # live perf accounting (telemetry plane): a roofline share's
             # inputs as gauges; zeros with sampling off (DYN_TPU_SLO=0)
             "jit_recompiles": compile_count(),
             "kv_peak_occupancy_perc": round(self.allocator.peak_occupancy(), 4),

@@ -310,132 +310,3 @@ def make_indexer(block_size: int, salt: Optional[bytes] = None):
         return NativeKvIndexer(lib, block_size, salt)
     return KvIndexer(block_size, salt)
 
-
-class KvIndexerSharded:
-    """Indexer sharded by WORKER across independent sub-indexers.
-
-    Each worker's events land on one shard (hash of the worker id), so
-    event application parallelizes across shard locks instead of
-    serializing on one tree; queries probe every shard and merge (each
-    worker's score lives wholly in its shard, so the merge is a dict
-    union). Reference: KvIndexerSharded (kv_router/indexer.rs:677).
-    """
-
-    def __init__(self, block_size: int, num_shards: int = 4,
-                 salt: Optional[bytes] = None, native: bool = True):
-        if num_shards < 1:
-            raise ValueError("num_shards must be >= 1")
-        self.block_size = block_size
-        self.salt = salt
-        make = make_indexer if native else (
-            lambda bs, s: KvIndexer(bs, s)
-        )
-        self._shards = [make(block_size, salt) for _ in range(num_shards)]
-
-    def _shard(self, worker_id: str):
-        return self._shards[hash(worker_id) % len(self._shards)]
-
-    def apply_event(self, event: RouterEvent) -> None:
-        self._shard(event.worker_id).apply_event(event)
-
-    def apply_events(self, events: Iterable[RouterEvent]) -> None:
-        for e in events:
-            self.apply_event(e)
-
-    def remove_worker(self, worker: str) -> None:
-        self._shard(worker).remove_worker(worker)
-
-    def find_matches(self, sequence_hashes: Sequence[int]) -> OverlapScores:
-        merged: OverlapScores = {}
-        for shard in self._shards:
-            merged.update(shard.find_matches(sequence_hashes))
-        return merged
-
-    def find_matches_for_request(self, token_ids: Sequence[int]) -> OverlapScores:
-        hashes = compute_block_hashes_for_seq(token_ids, self.block_size, self.salt)
-        return self.find_matches(hashes)
-
-    @property
-    def event_count(self) -> int:
-        return sum(s.event_count for s in self._shards)
-
-
-class KvIndexerFrequency:
-    """Indexer that additionally tracks per-block probe frequency with
-    expiration — hot prefixes can be identified (e.g. for host-tier
-    pinning or router telemetry) and stale counters age out instead of
-    growing unboundedly. Reference: the frequency-tracking indexer variant
-    with expiration (kv_router/indexer.rs).
-
-    ``now`` is injectable for tests; frequency entries not probed within
-    ``ttl`` seconds are dropped lazily on access and in bulk by
-    :meth:`expire`.
-    """
-
-    def __init__(self, block_size: int, salt: Optional[bytes] = None,
-                 ttl: float = 300.0, clock=None):
-        import time as _time
-
-        self.block_size = block_size
-        self.salt = salt
-        self.ttl = ttl
-        self._clock = clock or _time.monotonic
-        self._inner = make_indexer(block_size, salt)
-        self._freq: Dict[int, List[float]] = {}  # hash → [count, last_seen]
-        self._lock = threading.Lock()
-
-    def apply_event(self, event: RouterEvent) -> None:
-        # counters deliberately survive RemovedBlocks: one worker evicting a
-        # block says nothing about the others still holding it, and erasing
-        # the count would reset hot-prefix signal exactly under eviction
-        # pressure; the ttl bounds growth instead
-        self._inner.apply_event(event)
-
-    def apply_events(self, events: Iterable[RouterEvent]) -> None:
-        for e in events:
-            self.apply_event(e)
-
-    def remove_worker(self, worker: str) -> None:
-        self._inner.remove_worker(worker)
-
-    def find_matches(self, sequence_hashes: Sequence[int]) -> OverlapScores:
-        scores = self._inner.find_matches(sequence_hashes)
-        if scores:
-            matched = max(scores.values())
-            now = self._clock()
-            with self._lock:
-                for h in sequence_hashes[:matched]:
-                    ent = self._freq.get(h)
-                    if ent is None or now - ent[1] > self.ttl:
-                        self._freq[h] = [1, now]
-                    else:
-                        ent[0] += 1
-                        ent[1] = now
-        return scores
-
-    def find_matches_for_request(self, token_ids: Sequence[int]) -> OverlapScores:
-        hashes = compute_block_hashes_for_seq(token_ids, self.block_size, self.salt)
-        return self.find_matches(hashes)
-
-    def frequency(self, block_hash: int) -> int:
-        with self._lock:
-            ent = self._freq.get(block_hash)
-            if ent is None:
-                return 0
-            if self._clock() - ent[1] > self.ttl:
-                del self._freq[block_hash]
-                return 0
-            return int(ent[0])
-
-    def expire(self) -> int:
-        """Drop every counter past its ttl; returns how many were dropped."""
-        now = self._clock()
-        with self._lock:
-            stale = [h for h, e in self._freq.items() if now - e[1] > self.ttl]
-            for h in stale:
-                del self._freq[h]
-        return len(stale)
-
-    @property
-    def event_count(self) -> int:
-        return self._inner.event_count

@@ -177,8 +177,8 @@ class ForwardPassMetrics:
     # Rendered by components/metrics.py as per-phase quantile gauges; the
     # cluster telemetry aggregator diffs the raw `buckets` vectors.
     phase_latency: Optional[dict] = None
-    # live engine perf accounting (engine_jax/engine.py, PR6): the roofline
-    # fractions the BENCH files compute offline, as live gauges. Zeros from
+    # live engine perf accounting (engine_jax/engine.py, PR6): a roofline
+    # share's inputs as live gauges, from host clocks. Zeros from
     # engines without perf sampling (DYN_TPU_SLO=0) or non-JAX engines.
     decode_tokens_per_s: float = 0.0
     step_time_ms: float = 0.0

@@ -284,7 +284,7 @@ class ModelWatcher:
             # Traffic flows through the FIRST entry's endpoint path — if this
             # entry points somewhere else, its worker will never see requests
             # for this model name; surface that instead of silently dropping
-            # it (ADVICE r2: endpoint-path divergence was invisible).
+            # it (endpoint-path divergence was invisible once).
             known = self._endpoint_paths.get(parsed)
             if known is not None and endpoint_path != known:
                 logger.warning(

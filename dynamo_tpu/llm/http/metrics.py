@@ -196,7 +196,7 @@ class Histogram:
 
     def snapshot(self) -> dict[tuple, tuple[list[int], int, float]]:
         """{label_values: (cumulative_bucket_counts, total, sum)} — the raw
-        state quantile estimators (tracing.phase_summary, bench.py) read."""
+        state quantile estimators (tracing.phase_summary) read."""
         with self._lock:
             return {
                 key: (list(counts), self._totals[key], self._sums[key])

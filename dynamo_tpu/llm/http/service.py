@@ -692,8 +692,8 @@ class _SseTemplate:
 
     Every streamed chat/completions chunk in a request differs ONLY in the
     token text: id/object/created/model repeat verbatim. json.dumps of the
-    nested dict is the measured frontend hot spot (VERDICT r4 item 6 —
-    24.5 µs/token at saturation, one frontend per ~7 chips); splicing the
+    nested dict was the frontend's hot spot on another machine (a record of
+    2026-07; not measured on the current one); splicing the
     escaped token into a pre-encoded prefix/suffix removes the per-token
     tree walk. Any chunk that doesn't match the plain content-delta shape
     (logprobs, finish frames, tool calls, n>1) falls back to json.dumps —

@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
+from functools import partial
 from typing import Any, Dict, Tuple
 
 import jax
@@ -52,6 +53,7 @@ import jax.numpy as jnp
 from dynamo_tpu.models.llama import embed_lookup, history_tiles_full, rms_norm
 from dynamo_tpu.ops import moe
 from dynamo_tpu.ops.pallas.kda_scan import kda_scan
+from dynamo_tpu.ops.parts import HIGHEST, operand_parts
 
 Params = Dict[str, Any]
 KVCache = Dict[str, jax.Array]  # {"latent": [L_mla, N, bs, rank + rope]} float32
@@ -92,46 +94,17 @@ MOE_COUNTERS = COUNTERS.index("kda_chunk_tokens")  # the first: what ops/moe.py:
 # into one product; a large one (a chunk of prompt, where the products are the
 # work) takes one product a part, so that only one part's output is held.
 
-HIGHEST = jax.lax.Precision.HIGHEST
 PASSES = 3
+_expert_parts = partial(operand_parts, parts=PASSES)  # ops/moe.py:dropless_experts' ``parts_of``
 # activations of at most this many elements are stacked into one product
 STACK_UP_TO = 1 << 24
-
-
-def parts_of(x: jax.Array, passes: int = PASSES):
-    """``x`` (float32) as ``passes`` bfloat16 arrays whose sum is ``x`` to
-    ``8 * passes`` bits. The rounding is ``reduce_precision``, which the
-    compiler keeps: a float32 -> bfloat16 -> float32 pair of converts it may
-    drop for the sake of "excess precision" (the TPU's does), and what is left
-    of ``x`` after a part is then zero and the product one part's."""
-    parts, rest = [], x
-    for _ in range(passes):
-        part = jax.lax.reduce_precision(rest, exponent_bits=8, mantissa_bits=7)
-        parts.append(part.astype(jnp.bfloat16))
-        rest = rest - part
-    return parts
-
-
-def operand_parts(x: jax.Array, dtype) -> list:
-    """What of a float32 ``x`` is multiplied against a weight of ``dtype``:
-    its bfloat16 parts for a bfloat16 weight (the served case, above), itself
-    for any other (the float32 weights of a CPU test). The weight is
-    multiplied in the parts' dtype, which on the CPU is float32: its dot has no
-    bfloat16 x bfloat16 -> float32 for every shape, and float32 copies give the
-    same parts and the same sums (exact)."""
-    if dtype != jnp.bfloat16:
-        return [x]
-    parts = parts_of(x)
-    if jax.default_backend() == "cpu":
-        parts = [part.astype(jnp.float32) for part in parts]
-    return parts
 
 
 def wdot(spec: str, x: jax.Array, w: jax.Array) -> jax.Array:
     """``einsum(spec, x, w)`` in float32 for a float32 ``x``: the sum of its
     :func:`operand_parts`' products, at the highest precision where they are
     float32."""
-    parts = operand_parts(x.astype(jnp.float32), w.dtype)
+    parts = operand_parts(x.astype(jnp.float32), w.dtype, PASSES)
     w = w.astype(parts[0].dtype)
     precision = HIGHEST if w.dtype == jnp.float32 else None
     if x.size <= STACK_UP_TO:
@@ -466,7 +439,7 @@ def feed_forward(lp: Params, c: KimiLinearConfig, layer: int, x: jax.Array, vali
         y, stats = moe.dropless_experts(
             flat, ids, weights, lp["w_gate"], lp["w_up"], lp["w_down"],
             first_expert=c.first_expert, num_experts_total=c.num_experts_published,
-            token_valid=valid.reshape(-1), parts_of=operand_parts)
+            token_valid=valid.reshape(-1), parts_of=_expert_parts)
         y = y + _swiglu(flat, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
         return y.reshape(b, t, e), stats
 

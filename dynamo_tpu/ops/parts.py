@@ -7,8 +7,8 @@ carries from layer to layer. A model module that keeps its activations
 float32 chooses, product by product, how many bfloat16 parts of the
 activation it multiplies (what is left of it after the parts before, rounded
 again: ``8 * parts`` bits), from readings against its float32 reference
-(``models/jamba.py``, ``models/lfm2.py``; ``models/kimi_linear.py`` has its
-own form, ``wdot``). The weight IS bfloat16, so it needs no parts.
+(``models/jamba.py``, ``models/lfm2.py``; ``models/kimi_linear.py``'s ``wdot`` is
+an einsum over :func:`operand_parts`). The weight IS bfloat16, so it needs no parts.
 """
 
 from __future__ import annotations

@@ -5,8 +5,8 @@ request was slow; nothing in the system could say *where* inside a
 dispatch or an event-loop tick the time went — which is why the two
 standing perf walls (the Pallas decode kernel losing to dense jnp, and
 one frontend process capping at ~50k tok/s) have been guess-and-measure
-loops since BENCH_r05. This module is the shared vocabulary of that
-missing layer (docs/observability.md §Profiling):
+loops since a record of another machine (2026-07-31). This module is the
+shared vocabulary of that layer (docs/observability.md §Profiling):
 
 - **ProfilePolicy** — the ``DYN_TPU_PROFILE*`` knob bundle (PR3 clamping
   contract). ``DYN_TPU_PROFILE`` defaults OFF and is THE zero-overhead
@@ -29,8 +29,8 @@ missing layer (docs/observability.md §Profiling):
   pre-dispatch build and post-fetch emit work), allocator time
   (``alloc`` + ``seal_crc`` of the host step), per-step queue depths, and the
   request/trace ids (PR5) riding the batch — plus ``jit_compile`` events
-  with the triggering variant/shape detail. A decode-roofline decay like
-  BENCH_r05's 0.31→0.17 becomes readable as "device idle between
+  with the triggering variant/shape detail. A decode roofline share that
+  falls with concurrency becomes readable as "device idle between
   dispatches" vs "recompile storm" vs "allocator stall".
 - **FrontendCpu / EventLoopLagSampler** — the frontend hot path's
   equivalents: per-token CPU split across detokenize / serialize /

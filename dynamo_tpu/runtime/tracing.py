@@ -399,7 +399,7 @@ def render_phase_metrics() -> str:
 def phase_summary() -> Dict[str, dict]:
     """Compact per-phase stats {count, sum_s, p50_ms, p95_ms, p99_ms,
     buckets} — published on the worker metrics stream
-    (``attach_kv_publishing``) and recorded by ``bench.py``. Quantiles are
+    (``attach_kv_publishing``). Quantiles are
     bucket-interpolated (the usual Prometheus histogram_quantile estimate).
     ``buckets`` is the raw cumulative bucket-count vector (aligned with
     :data:`PHASE_BUCKETS` + Inf): the cluster telemetry aggregator

@@ -591,7 +591,7 @@ def test_the_expert_layer_compiles_at_the_cells_shapes_and_copies_no_expert(
     def layer(x, ids, weights, valid, w_gate, w_up, w_down):
         return moe.dropless_experts(
             x, ids, weights, w_gate, w_up, w_down, num_experts_total=total,
-            token_valid=valid, parts_of=kl.operand_parts)
+            token_valid=valid, parts_of=kl._expert_parts)
 
     compiled = jax.jit(layer).lower(
         sd((tokens, e), jnp.float32), sd((tokens, k), jnp.int32), sd((tokens, k), jnp.float32),

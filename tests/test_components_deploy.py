@@ -1,4 +1,4 @@
-"""Artifact store + deploy CLI + generic pool + indexer variants."""
+"""Artifact store + deploy CLI + generic pool."""
 
 import asyncio
 import io
@@ -9,18 +9,6 @@ import threading
 import pytest
 
 from dynamo_tpu.components.artifact_store import ArtifactStore, build_app, serve
-from dynamo_tpu.kv_router.indexer import (
-    KvIndexer,
-    KvIndexerFrequency,
-    KvIndexerSharded,
-)
-from dynamo_tpu.kv_router.protocols import (
-    KvCacheEvent,
-    RemovedBlocks,
-    RouterEvent,
-    StoredBlock,
-    StoredBlocks,
-)
 from dynamo_tpu.runtime.pool import Pool
 
 
@@ -86,58 +74,6 @@ def test_artifact_store_http_and_deploy_cli(tmp_path, run, capsys):
     run(go())
     out = capsys.readouterr().out
     assert "pushed demo_bundle" in out
-
-
-def _stored(worker, hashes, parent=None):
-    return RouterEvent(
-        worker_id=worker,
-        event=KvCacheEvent(
-            event_id=1,
-            data=StoredBlocks(
-                parent_hash=parent,
-                blocks=[StoredBlock(block_hash=h, tokens_hash=h) for h in hashes],
-            ),
-        ),
-    )
-
-
-def test_sharded_indexer_matches_single():
-    plain = KvIndexer(block_size=4)
-    sharded = KvIndexerSharded(block_size=4, num_shards=3, native=False)
-    for idx in (plain, sharded):
-        idx.apply_event(_stored("w1", [10, 11, 12]))
-        idx.apply_event(_stored("w2", [10, 11]))
-        idx.apply_event(_stored("w3", [99]))
-    assert sharded.find_matches([10, 11, 12]) == plain.find_matches([10, 11, 12])
-    sharded.remove_worker("w1")
-    plain.remove_worker("w1")
-    assert sharded.find_matches([10, 11, 12]) == plain.find_matches([10, 11, 12])
-    assert sharded.event_count == plain.event_count
-
-
-def test_frequency_indexer_counts_and_expires():
-    now = [0.0]
-    idx = KvIndexerFrequency(block_size=4, ttl=10.0, clock=lambda: now[0])
-    idx.apply_event(_stored("w1", [5, 6]))
-    idx.find_matches([5, 6])
-    idx.find_matches([5, 6])
-    assert idx.frequency(5) == 2 and idx.frequency(6) == 2
-    now[0] = 5.0
-    idx.find_matches([5])
-    assert idx.frequency(5) == 3
-    now[0] = 16.0  # 6 last seen at t=0 → expired; 5 at t=5 → expired too
-    assert idx.frequency(6) == 0
-    assert idx.expire() >= 0
-    assert idx.frequency(5) == 0
-    # one worker's removal does NOT erase the counter (others may still
-    # hold the block); only the ttl ages it out
-    now[0] = 20.0
-    idx.find_matches([5])
-    idx.apply_event(RouterEvent(
-        worker_id="w1",
-        event=KvCacheEvent(event_id=2, data=RemovedBlocks(block_hashes=[5])),
-    ))
-    assert idx.frequency(5) == 1
 
 
 def test_pool_raii_and_sharing():

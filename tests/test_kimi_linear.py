@@ -229,14 +229,14 @@ def test_ragged_runs_in_one_call_are_the_reference_layer():
 
 def test_the_three_part_arithmetic_goes_through_the_grouped_product():
     """bf16 weights and float32 rows in three bfloat16 parts, as the model
-    hands them over (``operand_parts``), against the same layer at float32's
+    hands them over (``ops/parts.py:operand_parts``), against the same layer at float32's
     highest precision: 24 bits of the activation reach every product."""
     w = expert_weights(jax.random.PRNGKey(4), e=32, f=16)
     w = {k: v.astype(jnp.bfloat16) if k.startswith("w_") else v for k, v in w.items()}
     x = w["x"]
     ids, weights = moe.route_sigmoid_topk(x, w["router"], w["router_bias"], 2, 2.446)
     got, _ = moe.dropless_experts(x, ids, weights, w["w_gate"], w["w_up"], w["w_down"],
-                                  parts_of=kl.operand_parts)
+                                  parts_of=kl._expert_parts)
     one, _ = moe.dropless_experts(x, ids, weights, w["w_gate"], w["w_up"], w["w_down"])
     f32 = {k: w[k].astype(jnp.float32) for k in ("w_gate", "w_up", "w_down")}
     want = jnp.zeros_like(x)

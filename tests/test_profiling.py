@@ -24,13 +24,11 @@ Coverage:
 - gauges worker → aggregator → cluster (promtext-parsed: max-not-sum for
   p95s/idle, summed recompiles) + mock_worker drill flags;
 - ``llmctl profile capture`` e2e over a real statestore + RPC plane
-  (--json summary and --trace Chrome-trace file);
-- bench summary/--check units (the CI perf gate).
+  (--json summary and --trace Chrome-trace file).
 """
 
 import asyncio
 import dataclasses
-import importlib.util
 import json
 
 import pytest
@@ -865,85 +863,3 @@ class TestProfileCapture:
         state = run(go())
         assert state["enabled"] is True
         assert state["records"][0]["phase"] == "chunk"
-
-
-# -- bench summary + --check gate ----------------------------------------------
-
-
-@pytest.fixture(scope="module")
-def bench_mod():
-    spec = importlib.util.spec_from_file_location(
-        "bench_for_tests",
-        str(__import__("pathlib").Path(__file__).parent.parent / "bench.py"),
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-class TestBenchGate:
-    def test_build_summary_extracts_tracked_metrics(self, bench_mod):
-        out = {
-            "value": 123.4, "roofline_fraction": 0.41, "model": "m",
-            "frontend": {"frontend_tok_s": 50_000.0,
-                         "frontend_cpu_us_per_token": 19.8},
-            "profiling": {"overhead_ratio": 1.01,
-                          "split_wall_coverage": 0.96},
-            "isl_sweep": {"whatever": "ignored"},
-        }
-        s = bench_mod.build_bench_summary(out)
-        m = s["metrics"]
-        assert m["tok_s_per_chip"]["value"] == 123.4
-        assert m["frontend_cpu_us_per_token"]["better"] == "lower"
-        assert m["profiling_split_coverage"]["value"] == 0.96
-        assert "itl_p95_ms" not in m  # absent sections stay absent
-
-    def test_check_directions_and_tolerance(self, bench_mod):
-        base = {"metrics": {
-            "tok_s_per_chip": {"value": 100.0, "better": "higher"},
-            "ttft_p95_ms": {"value": 200.0, "better": "lower"},
-            "only_in_base": {"value": 5.0, "better": "higher"},
-        }}
-        ok = {"metrics": {
-            "tok_s_per_chip": {"value": 90.0, "better": "higher"},
-            "ttft_p95_ms": {"value": 225.0, "better": "lower"},
-        }}
-        assert bench_mod.check_bench_summary(base, ok) == []
-        bad = {"metrics": {
-            "tok_s_per_chip": {"value": 80.0, "better": "higher"},
-            "ttft_p95_ms": {"value": 250.0, "better": "lower"},
-        }}
-        regs = bench_mod.check_bench_summary(base, bad)
-        assert {r[0] for r in regs} == {"tok_s_per_chip", "ttft_p95_ms"}
-        # custom tolerance widens the gate
-        assert bench_mod.check_bench_summary(base, bad, tolerance=0.30) == []
-
-    def test_run_check_exit_codes(self, bench_mod, tmp_path, capsys):
-        base = tmp_path / "base.json"
-        cur = tmp_path / "cur.json"
-        # a FULL bench JSON works as a baseline (summarized on the fly)
-        base.write_text(json.dumps({"value": 100.0}))
-        cur.write_text(json.dumps({"value": 99.0}))
-        rc = bench_mod.run_check(
-            ["--check", str(base), "--summary", str(cur)]
-        )
-        assert rc == 0
-        cur.write_text(json.dumps({"value": 50.0}))
-        rc = bench_mod.run_check(
-            ["--check", str(base), "--summary", str(cur)]
-        )
-        assert rc == 2
-        out = capsys.readouterr().out
-        assert "REGRESSION" in out
-        rc = bench_mod.run_check(
-            ["--check", str(tmp_path / "missing.json"),
-             "--summary", str(cur)]
-        )
-        assert rc == 1
-        # malformed invocations exit 1 (usage), never a traceback — the
-        # CI contract is exit 2 = regression, exit 1 = can't judge
-        assert bench_mod.run_check(["--check"]) == 1
-        assert bench_mod.run_check(
-            ["--check", str(base), "--summary", str(cur),
-             "--tolerance", "lots"]
-        ) == 1

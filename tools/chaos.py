@@ -9,7 +9,7 @@ seeded fault schedule and judges it with the cluster invariant suite
     python tools/chaos.py replay runs/x/schedule.json    # bit-faithful rerun
     python tools/chaos.py shrink runs/x/schedule.json    # 1-minimal repro
 
-Exit contract (the bench.py --check pattern): 0 = every invariant held,
+Exit contract: 0 = every invariant held,
 2 = an invariant violation (artifacts written to --out), 1 = the run
 itself could not execute. ``run --seed N`` twice emits byte-identical
 schedule JSON; ``replay`` of a violating schedule reproduces it; ``shrink``

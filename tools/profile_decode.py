@@ -470,7 +470,7 @@ def expert_layers() -> dict:
     from dynamo_tpu.models import lfm2
 
     return {
-        "kimi-linear-48b-a3b": dict(held=128, total=256, k=8, e=2304, f=1024, parts_of=kl.operand_parts,
+        "kimi-linear-48b-a3b": dict(held=128, total=256, k=8, e=2304, f=1024, parts_of=kl._expert_parts,
                                     parts=kl.PASSES, weight=2.446 / 8, chunk=2048, valid_a_row=64),
         "lfm2-24b-a2b": dict(held=64, total=64, k=4, e=2048, f=1536, parts_of=lfm2._expert_parts,
                              parts=lfm2.PARTS, weight=1.0 / 4, chunk=lfm2.ROWS_AT_ONCE * 128, valid_a_row=128),

@@ -8,8 +8,8 @@ clock), :class:`~dynamo_tpu.runtime.qos.FairQueue`,
 accounting + class-tiered eviction) — against a fluid model of one
 worker's step loop in *virtual time*. No JAX, no wall clock, no jitter:
 the same scenario produces byte-identical latencies every run, which is
-what the tier-1 noisy-neighbor chaos gate (tests/test_qos.py) and the
-``bench.py qos`` section need.
+what the tier-1 noisy-neighbor chaos gate (tests/test_qos.py) needs. Its
+ratios are a simulator's, never a device metric.
 
 The engine model mirrors the aggregated engine's physics: every loop
 iteration is ONE dispatch; a dispatch that carries prefill work costs
@@ -17,8 +17,8 @@ iteration is ONE dispatch; a dispatch that carries prefill work costs
 compute scales with the tokens fed), every decode lane advances exactly
 one token per dispatch, and a decode lane's inter-token latency IS the
 gap between consecutive dispatches — exactly the head-of-line mechanism
-a 4096-token prefill uses to spike everyone's ITL (BENCH_r05
-``isl_sweep``: ~4 s TTFT at ISL 4096).
+a 4096-token prefill uses to spike everyone's ITL (a record of another
+machine, 2026-07-31: ~4 s TTFT at ISL 4096).
 
 Scenario (:func:`run_noisy_neighbor`): a *victim* tenant streams steady
 short-prompt requests while an *abuser* tenant offers long-prompt
@@ -337,7 +337,7 @@ def run_noisy_neighbor(
 
 
 def run_scenario(cfg: Optional[SimConfig] = None) -> dict:
-    """All three legs, as the bench section / CLI reports them."""
+    """All three legs, as the CLI reports them."""
     alone = run_noisy_neighbor(with_abuser=False, qos_on=True, cfg=cfg)
     qos = run_noisy_neighbor(with_abuser=True, qos_on=True, cfg=cfg)
     ctrl = run_noisy_neighbor(with_abuser=True, qos_on=False, cfg=cfg)

@@ -1,8 +1,9 @@
 """Million-user traffic simulator: the planner's acceptance harness.
 
 Generates a deterministic synthetic workload — a diurnal curve, flash-crowd
-bursts, and the heavy-tail ISL mix measured in BENCH_r05's ``isl_sweep`` —
-and drives it through a fluid-queue model of a mock-worker fleet
+bursts, and a heavy-tail ISL mix (``ISL_MIX``, from a record of another
+machine, 2026-07-31) — and drives it through a fluid-queue model of a
+mock-worker fleet
 (``frontend`` / ``prefill`` / ``decode`` pools of
 :class:`~dynamo_tpu.components.mock_worker.MockWorkerStats`). Each tick the
 fleet publishes exactly what real workers publish on the ``kv_metrics``
@@ -12,8 +13,7 @@ they cannot tell from a real one — TPU-less and byte-deterministic.
 Two execution modes, same model:
 
 - **virtual time** (:class:`VirtualClock`): hours of simulated traffic in
-  milliseconds of wall clock; the ``bench.py`` ``planner_sim`` section and
-  the scenario unit tests run this way.
+  milliseconds of wall clock; the scenario unit tests run this way.
 - **wall clock** over a real statestore/bus: the tier-1 chaos acceptance
   test (``tests/test_planner.py``) publishes each tick onto a real bus with
   env-scaled SLO windows — the full components-on-a-bus loop in ~seconds.
@@ -40,7 +40,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 from dynamo_tpu.components.mock_worker import MockWorkerStats
 
 # (isl, probability, zero-queue prefill cost ms) — the heavy-tail prompt mix
-# measured by BENCH_r05 isl_sweep (llama3.2-1b int8: TTFT p50 at each ISL)
+# as a record of another machine had it (2026-07-31; llama3.2-1b int8: TTFT
+# p50 at each ISL): the simulator's input, not a number of this chip
 ISL_MIX: Tuple[Tuple[int, float, float], ...] = (
     (128, 0.55, 151.0),
     (1024, 0.25, 642.0),
