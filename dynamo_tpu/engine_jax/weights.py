@@ -140,6 +140,56 @@ def lfm2_config(mc: Dict[str, Any], dtype: Any = jnp.bfloat16):
     )
 
 
+def qwen3_next_config(mc: Dict[str, Any], dtype: Any = jnp.bfloat16):
+    """``model_type: qwen3_next``: Gated DeltaNet layers beside gated attention
+    layers (``layer_types`` where the card has the list, else every
+    ``full_attention_interval``-th layer attends), an expert layer after every
+    mixer. ``num_experts`` is what is HELD here of the ``num_experts_published``
+    the router scores (a card without the second holds them all). What
+    ``models/qwen3_next.py`` does not run is refused by name."""
+    from dynamo_tpu.models.qwen3_next import Qwen3NextConfig, layer_kinds
+
+    if mc.get("mlp_only_layers"):
+        raise ValueError(
+            f"model_type 'qwen3_next' with mlp_only_layers = {mc['mlp_only_layers']}: "
+            "models/qwen3_next.py runs an expert layer after every mixer")
+    if int(mc.get("decoder_sparse_step", 1)) != 1:
+        raise ValueError(
+            f"model_type 'qwen3_next' with decoder_sparse_step = {mc['decoder_sparse_step']}: "
+            "models/qwen3_next.py runs an expert layer after every mixer (1)")
+    if mc.get("rope_scaling"):
+        raise ValueError(
+            f"model_type 'qwen3_next' with rope_scaling = {mc['rope_scaling']}: "
+            "models/qwen3_next.py rotates by rope_theta alone")
+    layers, experts = int(mc["num_hidden_layers"]), int(mc["num_experts"])
+    kinds = mc.get("layer_types") or layer_kinds(layers, int(mc.get("full_attention_interval", 4)))
+    return Qwen3NextConfig(
+        vocab_size=int(mc["vocab_size"]),
+        hidden_size=int(mc["hidden_size"]),
+        num_layers=layers,
+        layer_types=tuple(str(kind) for kind in kinds),
+        num_heads=int(mc["num_attention_heads"]),
+        num_kv_heads=int(mc["num_key_value_heads"]),
+        head_dim=int(mc["head_dim"]),
+        partial_rotary_factor=float(mc.get("partial_rotary_factor", 0.25)),
+        rope_theta=float(mc.get("rope_theta", 10000000.0)),
+        linear_num_key_heads=int(mc["linear_num_key_heads"]),
+        linear_num_value_heads=int(mc["linear_num_value_heads"]),
+        linear_key_head_dim=int(mc["linear_key_head_dim"]),
+        linear_value_head_dim=int(mc["linear_value_head_dim"]),
+        linear_conv_kernel_dim=int(mc.get("linear_conv_kernel_dim", 4)),
+        moe_intermediate_size=int(mc["moe_intermediate_size"]),
+        shared_expert_intermediate_size=int(mc["shared_expert_intermediate_size"]),
+        num_experts=experts,
+        num_experts_published=int(mc.get("num_experts_published", experts)),
+        num_experts_per_tok=int(mc["num_experts_per_tok"]),
+        norm_topk_prob=bool(mc.get("norm_topk_prob", True)),
+        rms_norm_eps=float(mc.get("rms_norm_eps", 1e-6)),
+        tie_embeddings=bool(mc.get("tie_word_embeddings", False)),
+        dtype=dtype,
+    )
+
+
 def config_from_card(card: ModelDeploymentCard, dtype: Any = jnp.bfloat16):
     """Derive the model's config from the card's HF config.json contents: a
     LlamaConfig, or by ``model_type`` another module's (models.module_for),
@@ -151,6 +201,8 @@ def config_from_card(card: ModelDeploymentCard, dtype: Any = jnp.bfloat16):
         return jamba_config(mc, dtype)
     if mc.get("model_type") == "lfm2_moe":
         return lfm2_config(mc, dtype)
+    if mc.get("model_type") == "qwen3_next":
+        return qwen3_next_config(mc, dtype)
     if "num_experts" in mc and "num_local_experts" not in mc:
         # an expert model of a family this tree has no module for: a
         # LlamaConfig of it would be a dense impostor under its name

@@ -52,7 +52,7 @@ import jax.numpy as jnp
 
 from dynamo_tpu.models.llama import embed_lookup, history_tiles_full, rms_norm
 from dynamo_tpu.ops import moe
-from dynamo_tpu.ops.pallas.kda_scan import kda_scan
+from dynamo_tpu.ops.pallas.kda_scan import kda_scan, kda_step as _kda_step
 from dynamo_tpu.ops.parts import HIGHEST, operand_parts
 
 Params = Dict[str, Any]
@@ -354,16 +354,6 @@ def _kda_inputs(lp: Params, c: KimiLinearConfig, x: jax.Array, conv_tail: jax.Ar
     return q, k, v, log_decay, beta, gate, new_tail
 
 
-def _kda_step(s, q, k, v, log_decay, beta):
-    """One token of the delta rule on ``s`` ``[B, H, d_k, d_v]``, all float32
-    and elementwise (the MXU would round a float32 product to bfloat16):
-    ``S = (I - beta k k^T) Diag(alpha) S + beta k v^T``, ``o = S^T q``."""
-    s = s * jnp.exp(log_decay)[..., None]
-    ks = jnp.sum(k[..., None] * s, axis=-2)  # k^T S: [B, H, d_v]
-    s = s + (beta[..., None] * k)[..., None] * (v - ks)[..., None, :]
-    return s, jnp.sum(q[..., None] * s, axis=-2)
-
-
 def _kda_output(lp: Params, c: KimiLinearConfig, o: jax.Array, gate: jax.Array):
     """``W_o [RMSNorm_head(o) * gate]``; ``o`` float32 ``[B, T, H, d_v]``."""
     b, t = o.shape[:2]
@@ -375,9 +365,9 @@ def kda_mixer(lp: Params, c: KimiLinearConfig, x: jax.Array, valid: jax.Array,
               s: jax.Array, conv_tail: jax.Array):
     """The KDA mixer over ``[B, T, E]`` normed inputs from the rows' state:
     (output ``[B, T, E]``, state after the last valid token, new tails). One
-    token (a decode step) is :func:`_kda_step`; more (a chunk) are one call of
-    the kernel that keeps the state on the chip (outputs past a row's valid
-    tokens: zeros)."""
+    token (a decode step) is ``ops/pallas/kda_scan.py:kda_step`` (shared with
+    ``models/qwen3_next.py``); more (a chunk) are one call of the kernel that
+    keeps the state on the chip (outputs past a row's valid tokens: zeros)."""
     q, k, v, log_decay, beta, gate, new_tail = _kda_inputs(lp, c, x, conv_tail, valid)
     if x.shape[1] == 1:
         new, o = _kda_step(s, q[:, 0], k[:, 0], v[:, 0], log_decay[:, 0], beta[:, 0])

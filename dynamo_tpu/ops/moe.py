@@ -225,6 +225,26 @@ def route_sigmoid_topk(
     return ids.astype(jnp.int32), chosen * scale
 
 
+def route_softmax_topk(
+    x: jax.Array,  # [T, E]
+    router: jax.Array,  # [E, X] float32, X = every expert of the model
+    top_k: int,
+    renormalize: bool = True,
+) -> Tuple[jax.Array, jax.Array]:
+    """Softmax over ALL ``X`` logits in float32 at the highest precision (a
+    near-tie decides which expert computes), THEN the ``top_k`` largest (of
+    equal scores the lower id, ``lax.top_k``'s order); a chosen expert weighs
+    its probability, divided by the sum of the chosen ones where
+    ``renormalize`` (Qwen3-Next's ``norm_topk_prob``). No selection bias, no
+    scale. Returns (ids ``[T, k]`` int32, weights ``[T, k]`` float32)."""
+    probs = jax.nn.softmax(jnp.dot(
+        x.astype(jnp.float32), router, precision=jax.lax.Precision.HIGHEST), axis=-1)
+    chosen, ids = jax.lax.top_k(probs, top_k)
+    if renormalize:
+        chosen = chosen / chosen.sum(axis=-1, keepdims=True)
+    return ids.astype(jnp.int32), chosen
+
+
 def _parts(x: jax.Array, dtype) -> List[jax.Array]:
     """``x`` as the arrays, in the weights' ``dtype``, whose products against
     a weight add up to ``x``'s: one rounding, for a caller that brings none."""

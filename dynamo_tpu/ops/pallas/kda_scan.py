@@ -2,8 +2,9 @@
 
 ``kda_scan(q, k, v, log_decay, beta, s0, n_valid)`` advances every (row, head)
 pair's ``[d, d]`` float32 state over the first ``n_valid[row]`` tokens of a
-``[B, T, H, d]`` chunk by ``models/kimi_linear.py:_kda_step``'s recurrence, in
-that function's operations and order, all float32 on the vector unit (no
+``[B, T, H, d]`` chunk by :func:`kda_step`'s recurrence (this file's last
+function: a decode step's one token, in ``jax.numpy``), in that function's
+operations and order, all float32 on the vector unit (no
 product on the MXU, which would round to bfloat16):
 
     S = S * exp(log_decay)[:, None]           decay, a factor a key channel
@@ -147,3 +148,18 @@ def kda_scan(
       jnp.transpose(beta, (0, 2, 1, 3)), parts_of(s0))
     o = jnp.transpose(o.reshape(b, groups, p, t, p, seg), (0, 3, 1, 4, 2, 5)).reshape(b, t, h, d)
     return o[:, :t_in], parts_of(s).reshape(b, h, d, d)
+
+
+def kda_step(s, q, k, v, log_decay, beta):
+    """One token of the delta rule on ``s`` ``[B, H, d_k, d_v]``, all float32
+    and elementwise (the MXU would round a float32 product to bfloat16):
+    ``S = (I - beta k k^T) Diag(alpha) S + beta k v^T``, ``o = S^T q``: what
+    :func:`kda_scan` does a token, for the one token of a decode step.
+    ``log_decay`` ``[B, H, d_k]`` is a factor a key channel (Kimi-Linear's
+    KDA) or ``[B, H, 1]``, one a head (Qwen3-Next's Gated DeltaNet). Written
+    under the kernel and not over it: a Mosaic payload holds its callers'
+    lines."""
+    s = s * jnp.exp(log_decay)[..., None]
+    ks = jnp.sum(k[..., None] * s, axis=-2)  # k^T S: [B, H, d_v]
+    s = s + (beta[..., None] * k)[..., None] * (v - ks)[..., None, :]
+    return s, jnp.sum(q[..., None] * s, axis=-2)

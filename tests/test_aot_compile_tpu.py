@@ -832,3 +832,86 @@ def test_lfm2s_step_programs_copy_neither_the_pool_nor_the_state_nor_an_expert(
     # beside the arguments: the dense history of a full-width decode dispatch (1.07 GB) and its
     # steps; a chunk's groups hold what 8 rows need, whatever the rung
     assert memory.temp_size_in_bytes < (2_600_000_000 if program == "decode" else 400_000_000)
+
+
+# -- the step programs of qwen3-next-80b-a3b -----------------------------------
+
+@functools.lru_cache(maxsize=None)
+def _compile_qwen3_next(program, one_chip, rows=8):
+    """``models/qwen3_next.py``'s decode or chunk program at
+    ``batch.qwen3-next-80b-a3b``'s served shapes (8 of the 48 layers, 128 of
+    512 experts and 37,984 vocabulary rows held, 64 slots, block 16, 12,288
+    blocks, 2,048 positions; a chunk of ``rows`` x 128 tokens), the pool and
+    the state donated, for the described chip."""
+    from dynamo_tpu.models import qwen3_next as qn
+
+    c = qn.Qwen3NextConfig(vocab_size=37984, num_layers=8, layer_types=qn.layer_kinds(8, 4),
+                           num_experts=128)
+    slots, mb, chunk = 64, 128, 128
+
+    def sd(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = jax.tree.map(sd, jax.eval_shape(lambda: qn.init_params(jax.random.PRNGKey(0), c)))
+    cache = jax.tree.map(sd, jax.eval_shape(lambda: qn.make_kv_cache(c, 12288, 16)))
+    state = jax.tree.map(sd, jax.eval_shape(lambda: qn.make_slot_state(c, slots)))
+    if program == "decode":
+        def greedy(logits, pos, carry, k):
+            return jnp.argmax(logits, -1).astype(jnp.int32), carry, jnp.argmax(logits, -1)
+
+        return jax.jit(
+            lambda p, kv, st, toks, pos, tables: qn.decode(
+                p, c, toks, pos, kv, tables, st, 4, 2047, greedy, 0),
+            donate_argnums=(1, 2),
+        ).lower(params, cache, state, i32(slots), i32(slots), i32(slots, mb)).compile()
+    return jax.jit(
+        lambda p, kv, st, toks, pos, tables, lanes: qn.forward_chunk(
+            p, c, toks, pos, kv, tables, st, lanes),
+        donate_argnums=(1, 2),
+    ).lower(params, cache, state, i32(rows, chunk), i32(rows, chunk), i32(rows, mb),
+            i32(rows)).compile()
+
+
+@pytest.mark.timeout(400)
+@pytest.mark.parametrize("program, rows", [("decode", 64), ("chunk", 8), ("chunk", 64)],
+                         ids=["decode", "chunk_8_rows", "chunk_64_rows"])
+def test_qwen3_nexts_step_programs_copy_neither_the_pool_nor_the_state_nor_an_expert(
+        monkeypatch, one_chip, program, rows):
+    """``models/qwen3_next.py`` at ``batch.qwen3-next-80b-a3b``'s served shapes,
+    for the chip's compiler: the programs fit beside 7.33 GB of weights, 0.84
+    GB of DeltaNet state and 1.61 GB of float32 pages; no instruction copies
+    the pool ``[2, 12288, 16, 2, 256]`` (a head of two registers' lanes, for
+    the first time through ``models/llama.py``'s page functions) or a view of
+    it, none copies an expert layer's matrices (handed to the kernel as they
+    lie) and none a DeltaNet layer's whole ``[64, 32, 128, 128]`` state (a
+    decode step updates it where it lies, a chunk reads it inside its loop
+    over groups of 8 rows and scatters the rows' new states after it); the grouped product
+    is in the program three times an expert layer (and step, and history
+    width), ``kda_scan`` once a DeltaNet layer of a chunk and never in a decode
+    step; the pool and the state are donated."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels compiled, not interpreted
+    compiled = _compile_qwen3_next(program, one_chip, rows)
+    hlo = re.sub(r"/\*.*?\*/", "", compiled.as_text())
+    big = re.findall(
+        r"= (?:f32|bf16)\[(?:2,12288,16,2,256|24576,16,2,256|128,2048,512|128,512,2048)\]\{[^}]*\} copy\(", hlo)
+    assert big == [], big
+    # 64 ROWS' new states have the 64 slots' shape: the kernel leaves a row's state value channel
+    # by key channel, and ONE relayout a DeltaNet layer brings the rows' from the loop over the
+    # groups into the state's layout for the scatter (its operand the loop's result, never the state)
+    states = re.findall(r"= f32\[64,32,128,128\]\{[^}]*\} copy\((%[\w.]+)\)", hlo)
+    assert len(states) == (6 if (program, rows) == ("chunk", 64) else 0), states
+    assert not any(re.search(rf"{re.escape(src)} = [^\n]*parameter\(", hlo) for src in states)
+    kernels = len(re.findall(r"custom_call_target=\"tpu_custom_call\"", hlo))
+    widths = len(llama.history_widths(64 * 8))  # a decode dispatch holds its steps once a history width
+    scans = len(re.findall(r"%kda_scan[.\d]* = .*custom_call_target=\"tpu_custom_call\"", hlo))
+    assert "grouped_product" in hlo and scans == (6 if program == "chunk" else 0), scans
+    assert kernels == (3 * 8 * 4 * widths if program == "decode" else 3 * 8 + 6), kernels
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * 805_306_368 + 843_055_104
+    # beside the arguments: the dense history of a full-width decode dispatch (1.07 GB) and its
+    # steps; a chunk's groups hold what 8 rows need, and at 64 rows the rows' new states (0.8 GB)
+    limit = {("decode", 64): 2_600_000_000, ("chunk", 8): 1_200_000_000, ("chunk", 64): 2_200_000_000}
+    assert memory.temp_size_in_bytes < limit[program, rows], memory.temp_size_in_bytes

@@ -217,3 +217,56 @@ def test_llama_moe_int8_family_quantizes():
     assert not np.isnan(np.asarray(logits)).any()
     # same argmax as the unquantized model on a tiny model
     assert (np.asarray(logits[0, -1]).argmax() == np.asarray(ref[0, -1]).argmax())
+
+
+# -- the softmax router (ops/moe.py:route_softmax_topk; models/qwen3_next.py) ---
+
+@pytest.mark.parametrize("case", ["renormalised", "not_renormalised", "ties", "float32_at_the_highest_precision"])
+def test_route_softmax_topk(case):
+    """Softmax over ALL the logits, then the ``top_k`` largest: a chosen expert
+    weighs its probability over the sum of the chosen ones (``renormalize``) or
+    its probability itself; of equal probabilities the lower id wins, whatever
+    the order they come in; and the logits are a float32 product at the highest
+    precision whatever precision is in force (on the MXU a default-precision
+    float32 product rounds its operands to bfloat16: a near-tie then decides
+    by the rounding)."""
+    import numpy as np
+
+    from dynamo_tpu.ops.moe import route_softmax_topk
+
+    x = jax.random.normal(jax.random.PRNGKey(0), (7, 32))
+    router = jax.random.normal(jax.random.PRNGKey(1), (32, 16))
+    probs = np.asarray(jax.nn.softmax(jnp.dot(x, router, precision=jax.lax.Precision.HIGHEST), axis=-1), np.float64)
+    order = np.argsort(-probs, axis=-1, kind="stable")[:, :4]
+    if case in ("renormalised", "not_renormalised"):
+        ids, weights = route_softmax_topk(x, router, 4, case == "renormalised")
+        chosen = np.take_along_axis(probs, order, axis=-1)
+        want = chosen / chosen.sum(-1, keepdims=True) if case == "renormalised" else chosen
+        np.testing.assert_array_equal(np.asarray(ids), order)
+        np.testing.assert_allclose(np.asarray(weights), want, rtol=1e-6)
+        assert ids.dtype == jnp.int32 and weights.dtype == jnp.float32
+        if case == "renormalised":
+            np.testing.assert_allclose(np.asarray(weights).sum(-1), 1.0, rtol=1e-6)
+            # ... which IS the softmax over the chosen logits alone
+            logits = np.take_along_axis(np.asarray(jnp.dot(x, router, precision=jax.lax.Precision.HIGHEST)), order, -1)
+            np.testing.assert_allclose(np.asarray(weights), np.asarray(jax.nn.softmax(logits, axis=-1)), rtol=1e-5)
+        else:
+            assert float(np.asarray(weights).sum(-1).max()) < 1.0
+    elif case == "ties":
+        # experts 3, 9 and 12 score alike and highest, 5 next: top-2 takes the two lowest ids of the tie
+        tied = jnp.zeros((32, 16)).at[0, jnp.asarray([3, 9, 12])].set(2.0).at[0, 5].set(1.0)
+        one = jnp.zeros((1, 32)).at[0, 0].set(1.0)
+        ids, weights = route_softmax_topk(one, tied, 2, True)
+        assert np.asarray(ids).tolist() == [[3, 9]] and np.asarray(weights).tolist() == [[0.5, 0.5]]
+        ids, _ = route_softmax_topk(one, tied, 4, True)
+        assert np.asarray(ids).tolist() == [[3, 9, 12, 5]]
+    else:
+        # activations that bfloat16 cannot hold: a rounded product would move the probabilities by 1e-3
+        fine = x * (1.0 + 2.0 ** -10)
+        with jax.default_matmul_precision("bfloat16"):
+            _, low = route_softmax_topk(fine, router, 4, False)
+        with jax.default_matmul_precision("highest"):
+            _, high = route_softmax_topk(fine, router, 4, False)
+        np.testing.assert_array_equal(np.asarray(low), np.asarray(high))
+        _, from_bf16 = route_softmax_topk(fine.astype(jnp.bfloat16), router, 4, False)  # widened, not re-rounded
+        assert from_bf16.dtype == jnp.float32

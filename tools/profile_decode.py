@@ -468,20 +468,25 @@ def expert_layers() -> dict:
     group's tokens with the valid ones of each row of 128."""
     from dynamo_tpu.models import kimi_linear as kl
     from dynamo_tpu.models import lfm2
+    from dynamo_tpu.models import qwen3_next as qn
 
     return {
         "kimi-linear-48b-a3b": dict(held=128, total=256, k=8, e=2304, f=1024, parts_of=kl._expert_parts,
                                     parts=kl.PASSES, weight=2.446 / 8, chunk=2048, valid_a_row=64),
         "lfm2-24b-a2b": dict(held=64, total=64, k=4, e=2048, f=1536, parts_of=lfm2._expert_parts,
                              parts=lfm2.PARTS, weight=1.0 / 4, chunk=lfm2.ROWS_AT_ONCE * 128, valid_a_row=128),
+        "qwen3-next-80b-a3b": dict(held=128, total=512, k=10, e=2048, f=512, parts_of=qn._expert_parts,
+                                   parts=qn.PARTS, weight=1.0 / 10, chunk=qn.ROWS_AT_ONCE * 128, valid_a_row=128),
     }
 
 
 def expert_profiles():
     """``ops/moe.py:dropless_experts`` as a configuration calls it (PROF_MODELS,
-    default both: ``kimi-linear-48b-a3b``, 128 experts held of 256, 8 a token,
+    default all: ``kimi-linear-48b-a3b``, 128 experts held of 256, 8 a token,
     E 2,304, F 1,024, three bfloat16 parts; ``lfm2-24b-a2b``, all 64 held, 4 a
-    token, E 2,048, F 1,536, two parts; bf16 weights, float32 rows) at the
+    token, E 2,048, F 1,536; ``qwen3-next-80b-a3b``, 128 held of 512, 10 a
+    token, E 2,048, F 512: 1.25 rows an expert in a decode step, the thinnest;
+    bf16 weights, float32 rows) at the
     cell's two shapes: a decode step's 64 tokens, and a chunk group's (Kimi's
     2,048 of which half are padding, LFM2's 1,024). Each is timed under routing
     drawn even, drawn as Kimi's cell's, and all to ONE expert (a run of
@@ -586,8 +591,17 @@ def kda_profiles():
     a prompt), at 8 full rows and at 8 rows of padding (what the layout around
     the kernel and a load and a store of the state cost); PROF_ITERS (default 8) layers chained in
     one dispatch, each from the state the last one left. Also the largest
-    difference between the two, state and outputs, on this device. PROF_HEADS
-    (default 32) cuts the heads for a rehearsal on the CPU (interpreted)."""
+    difference between the two, state and outputs, on this device. The last
+    line is ``qwen3-next-80b-a3b``'s Gated DeltaNet shape on the same kernel (8
+    rows at the cell's counts, 32 value heads, a head's ONE decay spread over
+    its 128 key channels), and every line ends with what the kernel must move
+    (the rows' state there and back, q, k, v, the decay and the outputs) and its
+    share of the chip's bytes a second. PROF_HEADS (default 32) cuts the heads
+    for a rehearsal on the CPU (interpreted)."""
+    from benchmark import bytes_and_flops
+
+    peak = (bytes_and_flops.load_peaks(jax.devices()[0].device_kind)["hbm_bytes_per_s"]
+            if jax.default_backend() == "tpu" else float("nan"))
     from dynamo_tpu.engine_jax.compile_cache import enable_compile_cache
     from dynamo_tpu.models import kimi_linear as kl
     from dynamo_tpu.ops.pallas.kda_scan import kda_scan
@@ -624,12 +638,13 @@ def kda_profiles():
         return float(np.median(times)) * 1e3 / n_iter
 
     rng = np.random.default_rng(0)
-    for rows, used in ((8, 5), (16, 12), (8, 8), (8, 0)):
+    for rows, used, spread in ((8, 5, False), (16, 12, False), (8, 8, False), (8, 0, False), (8, 5, True)):
         key = jax.random.split(jax.random.PRNGKey(rows), 6)
         q, k, v = (jax.random.normal(key[i], (rows, t, h, d), jnp.float32) for i in range(3))
         q = q * jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True)) * d ** -0.5
         k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True))
-        log_decay = jax.random.uniform(key[3], (rows, t, h, d), jnp.float32, -1.6, -0.1)
+        log_decay = jax.random.uniform(key[3], (rows, t, h, 1 if spread else d), jnp.float32, -1.6, -0.1)
+        log_decay = jnp.broadcast_to(log_decay, (rows, t, h, d))  # Gated DeltaNet: a head's one decay
         beta = jax.nn.sigmoid(jax.random.normal(key[4], (rows, t, h), jnp.float32))
         s0 = jax.random.normal(key[5], (rows, h, d, d), jnp.float32)
         xs = (q, k, v, log_decay, beta)
@@ -637,11 +652,13 @@ def kda_profiles():
                         else chunk_valid_counts(rows, used, rng))
         (o_scan, s_scan), (o_kernel, s_kernel) = jax.jit(scanned)(*xs, s0, n), kernel(*xs, s0, n)
         ms_scan, ms_kernel = timed(scanned, xs, s0, n), timed(kernel, xs, s0, n)
-        print(f"kda {rows:2d} rows, valid {np.asarray(n).tolist()} ({int(n.sum())} tokens): "
+        moved = 4 * (2 * rows * h * d * d + int(n.sum()) * h * (5 * d + 1))  # state there and back; q, k, v, decay, o, beta
+        print(f"{'gdn' if spread else 'kda'} {rows:2d} rows, valid {np.asarray(n).tolist()} ({int(n.sum())} tokens): "
               f"scan {ms_scan:7.3f} ms a layer, kernel {ms_kernel:7.3f}; largest difference "
               f"state {float(jnp.abs(s_kernel - s_scan).max()):.3g} of {float(jnp.abs(s_scan).max()):.3g}, "
               f"outputs {float(jnp.abs(o_kernel - o_scan).max()):.3g} of "
-              f"{float(jnp.abs(o_scan).max()):.3g}", flush=True)
+              f"{float(jnp.abs(o_scan).max()):.3g}; the kernel must move {moved / 1e6:.1f} MB: "
+              f"{moved / peak / ms_kernel * 1e3:5.1%} of the chip's bytes a second", flush=True)
 
 
 def mamba_profiles():
