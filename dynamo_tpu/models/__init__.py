@@ -33,7 +33,16 @@ def module_for(model_config):
     lane). A module whose layers keep state per slot beside
     the pages also has ``make_slot_state``, ``forward_chunk`` and ``decode``
     in the form ``engine_jax/engine.py`` calls them with the state, and
-    ``COUNTERS`` (docs/kv_cache_manager.md, "State per slot").
+    ``COUNTERS`` (docs/kv_cache_manager.md, "State per slot"). Such a module
+    may set ``LANE_TAKES_ROWS`` once its chunk program, under the full width,
+    (1) starts a row whose lane is that of the row above it from what that
+    row leaves and not from the slot's stored state, (2) lets a lane's LAST
+    row alone write the slot's state back, (3) ends a row's pool history
+    where its lane's first row of the dispatch starts and attends the rows
+    between as fresh keys, and (4) is, at ``rows == slots``, the program it
+    was (``models/lfm2.py`` does; ``jamba``, ``qwen3_next`` and
+    ``kimi_linear`` say nothing until their kernels walk a lane's rows in
+    order).
 
     A config that is no ``LlamaConfig`` was made by its own module's class
     (``engine_jax/weights.py:config_from_card`` imports that module in its

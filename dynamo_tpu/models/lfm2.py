@@ -70,8 +70,9 @@ import jax.numpy as jnp
 
 from dynamo_tpu.models.llama import (  # noqa: F401  (the two tile counts are this module's too)
     _chunk_self_partial, _live_window_attention, _merge_partials, _pool_pages, apply_rope,
-    chunk_history_partial, chunk_history_tiles, decode_history_tiles, embed_lookup, flush_window,
-    history_tile, history_tiles_full, rms_norm, with_live_history,
+    chunk_history_partial, chunk_history_tiles, chunk_rows_above_partial, decode_history_tiles,
+    embed_lookup, flush_window, history_tile, history_tiles_full, lane_first_positions, rms_norm,
+    sibling_rows_back, with_live_history,
 )
 from dynamo_tpu.ops import moe
 from dynamo_tpu.ops.parts import dot_parts, operand_parts
@@ -84,10 +85,20 @@ SlotState = Dict[str, Tuple[jax.Array, ...]]  # {"conv": per conv layer [S, (K -
 # of ops/moe.py:dropless_experts, under the names models/kimi_linear.py gives
 # them (a call is one expert layer over a decode step's lanes or over a group
 # of a chunk's rows); convolution layers run (a group of a chunk's rows or a
-# decode step each count their layers); rows that started a request
+# decode step each count their layers); rows that started a request; real rows
+# of a chunk dispatch that took the convolutions' tails from the row above them
+# (the rows of a dispatch less the lanes it fed)
 COUNTERS = ("moe_layer_calls", "moe_held_rows", "moe_experts_hit", "moe_routed_pairs",
-            "moe_rows_computed", "moe_expert_reads", "conv_layer_calls", "slot_state_resets")
+            "moe_rows_computed", "moe_expert_reads", "conv_layer_calls", "slot_state_resets",
+            "conv_tail_handovers")
 MOE_COUNTERS = COUNTERS.index("conv_layer_calls")  # the first: what dropless_experts counts
+# A lane may fill several rows of one chunk dispatch with successive pieces of
+# its prompt (engine_jax/engine.py:chunk_rows_of; docs/kv_cache_manager.md,
+# "State per slot", says what a module with state per slot owes for it): under
+# the full width a row whose lane is that of the row above it starts each
+# convolution from that row's last inputs and attends its lane's earlier rows'
+# fresh keys (`_Layout`), and a lane's last row alone leaves the slot its tail
+LANE_TAKES_ROWS = True
 # rows of a chunk computed at once: the rows are independent, and a chunk of
 # more is taken in groups. 8 rows of 128 positions route 4,096 pairs, 64 rows
 # an expert, which fills the grouped product's longest tile; more at once only
@@ -253,17 +264,30 @@ def lm_head(params: Params, config: Lfm2Config, h: jax.Array) -> jax.Array:
     return dot_parts(h, params["embed"].T if config.tie_embeddings else params["lm_head"])
 
 
-def conv_mixer(lp: Params, c: Lfm2Config, u: jax.Array, valid: jax.Array, tail: jax.Array):
+def conv_mixer(lp: Params, c: Lfm2Config, u: jax.Array, valid: jax.Array, tail: jax.Array,
+               above=None):
     """The gated short convolution over ``[B, T, E]`` normed inputs whose
     valid tokens are a prefix of each row, from the convolution's tail
     ``[B, (K - 1) * E]``: ``[B, C, x] = u W_in``; ``g = B * x``; a causal
     depthwise convolution of ``g`` (no bias, no activation); ``y = C * conv``;
     out ``y W_out``. Returns (output ``[B, T, E]``, the new tail: the row's
-    last ``K - 1`` valid values of ``g``)."""
+    last ``K - 1`` valid values of ``g``).
+
+    ``above`` = (``takes`` ``[B]``, a tail ``[(K - 1) * E]``): a row that
+    ``takes`` goes on where the row above it ends, a FULL row of the same
+    sequence, so it starts from that row's last ``K - 1`` values of ``g``
+    (row 0 from the tail given) and not from ``tail``. ``g`` is the row's own
+    inputs' alone, so the rows are still computed all at once."""
     bsz, t, e = u.shape
     kk = c.conv_kernel
     bcx = dot_parts(u, lp["w_in"], PARTS)
     g = bcx[..., :e] * bcx[..., 2 * e:]
+    if above is not None:
+        takes, first = above
+        if t < kk - 1:
+            raise ValueError(f"a row of {t} tokens holds no tail of {kk - 1}")
+        ends = jnp.concatenate([first[None], g[:-1, t - (kk - 1):].reshape(bsz - 1, (kk - 1) * e)])
+        tail = jnp.where(takes[:, None], ends, tail)
     # tap K-1 is the token itself, tap 0 the oldest input
     seq = jnp.concatenate([tail.reshape(bsz, kk - 1, e), g], axis=1)  # [B, K-1+T, E]
     y = bcx[..., e:2 * e] * sum(seq[:, j:j + t] * lp["conv_w"][j] for j in range(kk))
@@ -348,13 +372,36 @@ def feed_forward(lp: Params, c: Lfm2Config, layer: int, h: jax.Array, valid: jax
 
 # -- the step programs --------------------------------------------------------
 
+class _Layout(NamedTuple):
+    """A chunk dispatch in which a lane may fill several rows (under the full
+    width), as each group of its rows is told it."""
+
+    positions: jax.Array  # [N, C] of every row of the dispatch
+    lanes: jax.Array  # [N]
+    takes: jax.Array  # [N] a real row whose lane is that of the real row above it
+    starts: jax.Array  # [N] where a row's pool history ends: `lane_first_positions`
+    n_back: jax.Array  # the sibling loop's trips: `sibling_rows_back`
+
+
+class _Left(NamedTuple):
+    """What the groups of such a dispatch so far leave the next one: all that
+    the loop over the groups carries from group to group."""
+
+    at: jax.Array  # the dispatch's row that is the next group's first
+    tails: jax.Array  # [L_conv, (K - 1) * E] the tails the row above that one left
+    k: jax.Array  # [L_attn, N, C, KVH, D] the dispatch's fresh keys so far, zeros from `at` on
+    v: jax.Array
+
+
 def forward_chunk(
     params: Params, config: Lfm2Config, tokens: jax.Array, positions: jax.Array,
     kv_cache: KVCache, block_tables: jax.Array, state: SlotState, lanes: jax.Array,
 ):
-    """A ``[R, C]`` block of prompt tokens, one row per prefilling lane
-    (``lanes`` ``[R]``: the row's slot; ``max_slots`` and above = a padding
-    row), valid tokens (position >= 0) a prefix of each row.
+    """A ``[R, C]`` block of prompt tokens (``lanes`` ``[R]``: the row's slot;
+    ``max_slots`` and above = a padding row), valid tokens (position >= 0) a
+    prefix of each row. Under the full width (``R`` < the state's slots) a lane
+    may fill several CONSECUTIVE rows with successive pieces of its prompt, in
+    order, each full but the last; at it, one row a lane.
 
     Returns (hidden ``[R, C, E]`` after the final norm, the pool with the
     rows' K and V written, the slot state with the rows' slots advanced, the
@@ -363,7 +410,18 @@ def forward_chunk(
     to it. More than ``ROWS_AT_ONCE`` rows are taken in groups of that many,
     one after another; the pool and the state are only read inside the loop
     (a row touches its own slot and pages only), and what the rows made is
-    written after it: one scatter a pool array and one a conv layer."""
+    written after it: one scatter a pool array and one a conv layer.
+
+    A row whose lane is that of the row above it (both real) goes on where
+    that row ends, inside the program (``_Layout``; the loop over the groups
+    carries what a later group needs of the earlier ones, ``_Left``, and no
+    more): its convolutions start from that row's last inputs and not from
+    the slot's tail (:func:`conv_mixer`), its pool history ends where its
+    lane's FIRST row of the dispatch starts, and one more partial attends the
+    fresh keys of its lane's rows above it (``chunk_rows_above_partial``).
+    Only a lane's LAST row leaves the slot its tail. Where every lane has one
+    row nothing is taken from a row above and the sibling loop makes no trip;
+    at the full width none of it is in the program."""
     from dynamo_tpu.ops.attention import write_kv_to_pool
 
     c = config
@@ -371,9 +429,23 @@ def forward_chunk(
     slots = state["conv"][0].shape[0]
     pages = _pool_pages(kv_cache)
     num_blocks = kv_cache["k"].shape[1]
-    group = partial(_chunk_rows, params, c, pages, num_blocks, state["conv"])
+    layout = left = None
+    if rows < slots:  # at the full width a lane has one row, and the program is what it was
+        live = (lanes < slots) & (positions[:, 0] >= 0)
+        layout = _Layout(
+            positions=positions, lanes=lanes,
+            takes=jnp.concatenate([
+                jnp.zeros((1,), bool), (lanes[1:] == lanes[:-1]) & live[1:] & live[:-1]]),
+            starts=lane_first_positions(positions, lanes),
+            n_back=sibling_rows_back(positions, lanes))
+        none_yet = jnp.zeros((c.layer_types.count("full_attention"), *tokens.shape,
+                              *_rows_of_heads(c)[1:]), kv_cache["k"].dtype)
+        left = _Left(
+            at=jnp.int32(0), k=none_yet, v=none_yet,
+            tails=jnp.zeros((len(state["conv"]), state["conv"][0].shape[1]), jnp.float32))
+    group = partial(_chunk_rows, params, c, pages, num_blocks, state["conv"], layout)
     if rows <= ROWS_AT_ONCE:
-        h, k, v, tails, counters = group(tokens, positions, block_tables, lanes)
+        h, k, v, tails, counters = group(left, tokens, positions, block_tables, lanes)
     else:
         if rows % ROWS_AT_ONCE:
             raise ValueError(f"{rows} rows are no whole number of groups of {ROWS_AT_ONCE}")
@@ -381,36 +453,48 @@ def forward_chunk(
         def grouped(a):
             return a.reshape(rows // ROWS_AT_ONCE, ROWS_AT_ONCE, *a.shape[1:])
 
-        def step(sums, xs):
-            *made, more = group(*xs)
-            return sums + more, made
+        def step(carry, xs):
+            sums, left = carry
+            h, k, v, tails, more = group(left, *xs)
+            if left is None:
+                return (sums + more, None), (h, tails, k, v)
+            # the dispatch's K and V so far and the last row's tails go on to the next group
+            return (sums + more, _Left(left.at + ROWS_AT_ONCE, tails[:, -1], k, v)), (h, tails)
 
-        counters, (h, k, v, tails) = jax.lax.scan(
-            step, jnp.zeros((len(COUNTERS),), jnp.int32),
+        (counters, left), (h, tails, *kv) = jax.lax.scan(
+            step, (jnp.zeros((len(COUNTERS),), jnp.int32), left),
             (grouped(tokens), grouped(positions), grouped(block_tables), grouped(lanes)))
         h = h.reshape(rows, *h.shape[2:])
         # [G, L, R, ...] -> [L, G * R, ...]
-        k, v, tails = (jnp.moveaxis(a, 0, 1).reshape(a.shape[1], rows, *a.shape[3:])
-                       for a in (k, v, tails))
+        tails, *kv = (jnp.moveaxis(a, 0, 1).reshape(a.shape[1], rows, *a.shape[3:])
+                      for a in (tails, *kv))
+        k, v = kv or (left.k, left.v)
     cache = {"k": write_kv_to_pool(kv_cache["k"], k, positions, block_tables),
              "v": write_kv_to_pool(kv_cache["v"], v, positions, block_tables)}
     # a padding row writes nowhere: its slot index lies past the state
     back = jnp.where(lanes < slots, lanes, slots)
+    if layout is not None:
+        # nor does a row that the row under it goes on from: one write a slot, its lane's last row's
+        back = jnp.where(jnp.concatenate([layout.takes[1:], jnp.zeros((1,), bool)]), slots, back)
     conv = tuple(was.at[back].set(tail, mode="drop") for was, tail in zip(state["conv"], tails))
     return h, cache, {"conv": conv}, counters
 
 
-def _chunk_rows(params, c, pages, num_blocks, conv, tokens, positions, block_tables, lanes):
+def _chunk_rows(params, c, pages, num_blocks, conv, layout, left, tokens, positions, block_tables,
+                lanes):
     """The layers over the rows given, all at once, the pool (its
     ``_pool_pages`` views) and the slots' tails ``conv`` read and not written:
     (hidden after the final norm, the attention layers' fresh K and V
     ``[L_attn, R, C, KVH, D]``, the conv layers' new tails ``[L_conv, R, (K -
-    1) * E]``, the counters)."""
+    1) * E]``, the counters). With a ``layout`` the rows are ``left.at``
+    onwards of a dispatch in which a lane may fill several, and the K and V
+    returned are the DISPATCH's so far, ``[L_attn, N, C, KVH, D]``."""
     valid = positions >= 0
     fresh = positions[:, 0] == 0
     slots = conv[0].shape[0]
     lane = jnp.clip(lanes, 0, slots - 1)
     real = lanes < slots
+    n = tokens.shape[0]
 
     dtype = pages["k"].dtype
     r = _rows_of_heads(c)
@@ -418,10 +502,14 @@ def _chunk_rows(params, c, pages, num_blocks, conv, tokens, positions, block_tab
     block_size = pages["k"].shape[1]
     table_blocks = block_tables.shape[1]
     tile_blocks = history_tile(block_size, table_blocks) // block_size
-    history_len = jnp.clip(positions[:, 0], 0, table_blocks * block_size)
-    n_tiles = chunk_history_tiles(positions, block_size, table_blocks)
+    # positions whose first says where each row's pool history ends: the row's own, or
+    # with rows above those of its lane's first row (the rows between: their keys in hand)
+    ends = positions if layout is None else jax.lax.dynamic_slice_in_dim(layout.starts, left.at, n)[:, None]
+    history_len = jnp.clip(ends[:, 0], 0, table_blocks * block_size)
+    n_tiles = chunk_history_tiles(ends, block_size, table_blocks)
     tables = jnp.pad(block_tables, (
         (0, 0), (0, history_tiles_full(block_size, table_blocks) * tile_blocks - table_blocks)))
+    takes = None if layout is None else jax.lax.dynamic_slice_in_dim(layout.takes, left.at, n)
 
     h = embed_lookup(params, tokens, c.dtype).astype(jnp.float32)
     tails, fresh_k, fresh_v = [], [], []
@@ -432,7 +520,9 @@ def _chunk_rows(params, c, pages, num_blocks, conv, tokens, positions, block_tab
         if kind == "conv":
             with jax.named_scope("conv"):
                 tail = jnp.where(fresh[:, None], 0.0, conv[len(tails)][lane])
-                y, tail = conv_mixer(lp, c, u, valid, tail)
+                y, tail = conv_mixer(
+                    lp, c, u, valid, tail,
+                    None if layout is None else (takes, left.tails[len(tails)]))
                 tails.append(tail)
         else:
             with jax.named_scope("attn"):
@@ -442,8 +532,15 @@ def _chunk_rows(params, c, pages, num_blocks, conv, tokens, positions, block_tab
                     hist = chunk_history_partial(
                         r, q, pages, j * num_blocks + tables, history_len, n_tiles, positions,
                         scale, tile_blocks, block_size, dtype)
-                    num, _, den = _merge_partials(
+                    part = _merge_partials(
                         hist, _chunk_self_partial(r, q, k, v, positions, scale))
+                    if layout is not None:
+                        k, v = (jax.lax.dynamic_update_slice_in_dim(all_rows[j], mine, left.at, 0)
+                                for all_rows, mine in ((left.k, k), (left.v, v)))
+                        part = chunk_rows_above_partial(
+                            r, q, k, v, layout.positions, layout.lanes, left.at, layout.n_back,
+                            scale, part)
+                    num, _, den = part
                 y = _attended(lp, c, jnp.where(
                     (den > 0.0).transpose(0, 2, 1)[..., None],
                     num / jnp.maximum(den, 1e-30).transpose(0, 2, 1)[..., None], 0.0))
@@ -452,8 +549,9 @@ def _chunk_rows(params, c, pages, num_blocks, conv, tokens, positions, block_tab
         h, more = feed_forward(lp, c, i, h + y, valid)
         stats = stats + more
     h = rms_norm(h, params["final_norm"], c.norm_eps)
-    counters = jnp.concatenate([
-        stats, jnp.stack([jnp.int32(len(tails)), jnp.sum(fresh & real)]).astype(jnp.int32)])
+    counters = jnp.concatenate([stats, jnp.stack([
+        jnp.int32(len(tails)), jnp.sum(fresh & real),
+        jnp.int32(0) if layout is None else jnp.sum(takes)]).astype(jnp.int32)])
     return h, jnp.stack(fresh_k), jnp.stack(fresh_v), jnp.stack(tails), counters
 
 
@@ -529,5 +627,5 @@ def decode(
     (toks, pos, carry, conv, wk, wv, stats), out = with_live_history(
         kv_cache, block_tables, base, run, out_dtype=dtype)
     cache = flush_window(kv_cache, block_tables, base, jnp.stack(wk), jnp.stack(wv), max_pos)
-    counters = jnp.concatenate([stats, jnp.asarray([steps * n_conv, 0], jnp.int32)])
+    counters = jnp.concatenate([stats, jnp.asarray([steps * n_conv, 0, 0], jnp.int32)])
     return toks, pos, carry, out, cache, {"conv": conv}, counters

@@ -1237,6 +1237,70 @@ def sibling_rows_back(positions, lanes):
     return jnp.where(pair, rows[:, None] - rows[None, :], 0).max()
 
 
+def chunk_rows_above_partial(
+    c: LlamaConfig,
+    q: jax.Array,  # [B, T, H, D] the queries of rows `at` .. `at + B - 1` of the dispatch
+    k: jax.Array,  # [N, T, KVH, D] the dispatch's fresh keys; rows past `at + B` are not read
+    v: jax.Array,
+    positions: jax.Array,  # [N, T] of every row of the dispatch; < 0 = padding
+    lanes: jax.Array,  # [N] the lane of each row of the dispatch
+    at,  # the dispatch's row that is the first of the B
+    n_back,  # trips: `sibling_rows_back` of the dispatch
+    scale: float,
+    acc: Tuple[jax.Array, jax.Array, jax.Array],
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """:func:`chunk_sibling_partial` for a GROUP of a dispatch's rows, for a
+    chunk program that takes its rows some at a time: ``acc`` with the flash
+    partial of each of the B rows' queries against the fresh keys of the
+    EARLIER rows of its lane folded in, whether those lie in the group or
+    above it (a lane's rows may straddle two groups). Trip ``i`` meets row
+    ``at + r`` with row ``at + r - (i + 1)`` of the dispatch, where there is
+    one and it is a row of the same lane; the same ``[B, T, T]`` scores a
+    trip, and with ``at = 0`` and ``N = B`` the same pairs."""
+    b, t, h, d = q.shape
+    kvh = k.shape[2]
+    qg = q.reshape(b, t, kvh, h // kvh, d)
+    rows = at + jnp.arange(b)
+    q_positions = jax.lax.dynamic_slice_in_dim(positions, at, b, axis=0)
+    q_lanes = jax.lax.dynamic_slice_in_dim(lanes, at, b, axis=0)
+
+    # B rows in front for the rows that would lie above row 0 (`rows >= back`
+    # says they meet nothing): row j of the dispatch is row B + j of these
+    k2, v2, pos2, lanes2 = (
+        jnp.concatenate([jnp.zeros((b, *x.shape[1:]), x.dtype), x])
+        for x in (k, v, positions, lanes)
+    )
+
+    def above(x2, back):
+        return jax.lax.dynamic_slice_in_dim(x2, at + b - back, b, axis=0)
+
+    def trip(i, acc):
+        back = i + 1
+        kv_pos = above(pos2, back)  # [B, T]
+        met = (above(lanes2, back) == q_lanes) & (rows >= back)  # [B]
+        mask = (
+            met[:, None, None] & (kv_pos >= 0)[:, None, :]
+            & (kv_pos[:, None, :] <= q_positions[:, :, None])
+        )[:, None, None, :, :]
+        scores = jnp.einsum(
+            "btngd,bsnd->bngts", qg, above(k2, back),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [B, KVH, G, T, T]
+        scores = jnp.where(mask, scores, -jnp.inf)
+        m = jnp.maximum(scores.max(axis=-1), -1e30)
+        p = jnp.exp(scores - m[..., None])
+        num = jnp.einsum(
+            "bngts,bsnd->btngd", p, above(v2, back).astype(jnp.float32)
+        )
+        return _merge_partials(acc, (
+            num.reshape(b, t, h, d),
+            m.reshape(b, h, t),
+            p.sum(axis=-1).reshape(b, h, t),
+        ))
+
+    return jax.lax.fori_loop(0, n_back, trip, acc)
+
+
 def forward_chunk(
     params: Params,
     config: LlamaConfig,

@@ -799,8 +799,8 @@ def _compile_lfm2(program, one_chip, rows=8):
 
 
 @pytest.mark.timeout(300)
-@pytest.mark.parametrize("program, rows", [("decode", 64), ("chunk", 8), ("chunk", 64)],
-                         ids=["decode", "chunk_8_rows", "chunk_64_rows"])
+@pytest.mark.parametrize("program, rows", [("decode", 64), ("chunk", 8), ("chunk", 64), ("chunk", 16)],
+                         ids=["decode", "chunk_8_rows", "chunk_64_rows", "chunk_16_rows"])
 def test_lfm2s_step_programs_copy_neither_the_pool_nor_the_state_nor_an_expert(
         monkeypatch, one_chip, program, rows):
     """``models/lfm2.py`` at ``batch.lfm2-24b-a2b``'s served shapes, for the
@@ -813,7 +813,9 @@ def test_lfm2s_step_programs_copy_neither_the_pool_nor_the_state_nor_an_expert(
     three times an expert layer (and step, and history width), and both the
     pool and the tails are donated. A chunk reads the tails and the pool
     inside its loop over groups of 8 rows and writes them after it: no copy
-    of a layer's ``f32[64, 4096]`` tails. A decode dispatch writes each conv
+    of a layer's ``f32[64, 4096]`` tails, under the full width too, where a
+    row may go on from the row above it and the loop over two groups carries
+    the dispatch's fresh K and V (PR 50). A decode dispatch writes each conv
     layer's tails out ONCE, from the chip's fast memory (one ``copy`` a
     layer, not one a step)."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernel compiled, not interpreted

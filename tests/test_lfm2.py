@@ -12,6 +12,7 @@ against the refusals a model with per-slot state owes whatever would hand its
 pages over without it.
 """
 
+import dataclasses
 import json
 import os
 import re
@@ -31,7 +32,7 @@ from dynamo_tpu.kv.pages import MigrationRejected, StateNotPortable
 from dynamo_tpu.models import lfm2, llama, module_for
 from dynamo_tpu.ops import moe
 
-from .test_chunk_rows import answer, run_out, step, submit
+from .test_chunk_rows import answer, busy, run_out, step, submit
 
 # ATOL, the float32 build: float32 on the CPU at the highest matmul precision
 # on both sides, so the program and the reference differ by the order of their
@@ -140,80 +141,144 @@ def test_the_layer_kinds_are_the_published_pattern_and_the_first_feed_forwards_d
     assert module_for(c) is lfm2 and module_for(llama.LLAMA_PRESETS["tiny"]) is llama
 
 
-def prefill_then_decode(cfg, params, chunks, n_decode=3, between=None):
-    """Logits ``[sum(chunks) + n_decode, V]`` of a prompt fed in ``chunks``
-    into slot 2 of 4 (the chunk's second row is padding) and decoded from the
-    tails and pages they left; (tokens, logits, state, cache, the chunks'
-    counters)."""
-    n_prompt = sum(chunks)
-    tokens = np.asarray(prompt_of(n_prompt + n_decode, salt=len(chunks)), np.int32)
-    slots, c, bs, mb, slot = 4, 16, 8, 8, 2
-    cache = lfm2.make_kv_cache(cfg, 32, bs)
+def dispatch_rows(cfg, params, dispatches, rows=2, slots=4, mb=8, n_decode=3, between=None, salt=None):
+    """Chunk dispatches of ``rows`` rows over ``slots`` slots, then ``n_decode``
+    teacher-forced decode steps of every slot fed, off the tails and pages the
+    dispatches left. A dispatch is a list of its rows in order, ``(slot, n)``
+    = the slot's next ``n`` prompt tokens (a lane's rows of one dispatch are
+    its successive pieces) or ``None`` = a padding row; the rows left are
+    padding. The k-th slot fed has blocks ``1 + k * mb`` onwards; every slot's
+    tails start stale; its tokens are ``prompt_of(., salt or the slot)``.
+    Returns ({slot: (its tokens, logits ``[prompt + n_decode, V]``)}, state,
+    cache, the dispatches' counters)."""
+    c, bs = 16, 8
+    fed = list(dict.fromkeys(row[0] for d in dispatches for row in d if row))
+    length = {slot: sum(row[1] for d in dispatches for row in d if row and row[0] == slot) for slot in fed}
+    toks_of = {slot: np.asarray(prompt_of(length[slot] + n_decode, salt=salt or slot), np.int32) for slot in fed}
+    table = {slot: 1 + k * mb + np.arange(mb, dtype=np.int32) for k, slot in enumerate(fed)}
+    cache = lfm2.make_kv_cache(cfg, 1 + len(fed) * mb, bs)
     state = jax.tree.map(lambda a: a + 7.0, lfm2.make_slot_state(cfg, slots))  # stale, every slot
-    tables = np.zeros((2, mb), np.int32)
-    tables[0] = np.arange(1, 9)
-    got, sums, at = [], [], 0
-    for n in chunks:
-        toks, pos = np.zeros((2, c), np.int32), np.full((2, c), -1, np.int32)
-        toks[0, :n], pos[0, :n] = tokens[at:at + n], np.arange(at, at + n)
+    at, got, sums = dict.fromkeys(fed, 0), {slot: [] for slot in fed}, []
+    for d in dispatches:
+        toks, pos = np.zeros((rows, c), np.int32), np.full((rows, c), -1, np.int32)
+        tables, lanes = np.zeros((rows, mb), np.int32), np.full((rows,), slots, np.int32)
+        for r, row in enumerate(d):
+            if row is None:
+                continue
+            slot, n = row
+            toks[r, :n], pos[r, :n] = toks_of[slot][at[slot]:at[slot] + n], np.arange(at[slot], at[slot] + n)
+            tables[r], lanes[r] = table[slot], slot
+            at[slot] += n
         h, cache, state, counted = lfm2.forward_chunk(
             params, cfg, jnp.asarray(toks), jnp.asarray(pos), cache, jnp.asarray(tables),
-            state, jnp.asarray([slot, slots], jnp.int32))
-        got.append(np.asarray(lfm2.lm_head(params, cfg, h[0, :n]), np.float32))
-        sums.append(np.asarray(counted))
-        at += n
+            state, jnp.asarray(lanes))
+        for r, row in enumerate(d):
+            if row is not None:
+                got[row[0]].append(np.asarray(lfm2.lm_head(params, cfg, h[r, :row[1]]), np.float32))
+        sums.append(dict(zip(lfm2.COUNTERS, np.asarray(counted).tolist())))
         if between is not None:
             state = between(state)
+    if not n_decode:
+        return {slot: (toks_of[slot], np.concatenate(got[slot])) for slot in fed}, state, cache, sums
     lanes_tables = np.zeros((slots, mb), np.int32)
-    lanes_tables[slot] = tables[0]
     toks, pos = np.zeros((slots,), np.int32), np.full((slots,), -1, np.int32)
-    toks[slot], pos[slot] = tokens[n_prompt], n_prompt
+    forcing = np.zeros((slots, max(length.values()) + n_decode), np.int32)
+    for slot in fed:
+        lanes_tables[slot], toks[slot], pos[slot] = table[slot], toks_of[slot][length[slot]], length[slot]
+        forcing[slot, :len(toks_of[slot])] = toks_of[slot]
 
-    def forced(logits, p, carry, k):  # teacher forcing: the sequence's own next token
-        return jnp.where(p >= 0, jnp.asarray(tokens)[jnp.clip(p + 1, 0, len(tokens) - 1)], 0), carry, logits
+    def forced(logits, p, carry, k):  # teacher forcing: each sequence's own next token
+        nxt = jnp.asarray(forcing)[jnp.arange(slots), jnp.clip(p + 1, 0, forcing.shape[1] - 1)]
+        return jnp.where(p >= 0, nxt, 0), carry, logits
 
     out = lfm2.decode(params, cfg, jnp.asarray(toks), jnp.asarray(pos), cache,
-                      jnp.asarray(lanes_tables), state, n_decode, 95, forced, None)
+                      jnp.asarray(lanes_tables), state, n_decode, 8 * mb - 1, forced, None)
     counted = dict(zip(lfm2.COUNTERS, np.asarray(out[6]).tolist()))
-    assert int(out[1][slot]) == n_prompt + n_decode
-    assert counted["conv_layer_calls"] == n_decode * N_CONV and counted["slot_state_resets"] == 0
-    # one lane decodes: 2 pairs a layer and step, each its own expert
+    assert [int(out[1][slot]) for slot in fed] == [length[slot] + n_decode for slot in fed]
+    assert counted["conv_layer_calls"] == n_decode * N_CONV
+    assert counted["slot_state_resets"] == counted["conv_tail_handovers"] == 0
+    # the lanes that decode route 2 pairs a layer and step each, every expert held
     assert counted["moe_layer_calls"] == n_decode * N_EXPERT_LAYERS
-    assert counted["moe_held_rows"] == counted["moe_routed_pairs"] == counted["moe_experts_hit"] == 2 * n_decode * N_EXPERT_LAYERS
-    got.append(np.asarray(out[3], np.float32)[:, slot])
-    return tokens, np.concatenate(got), out[5], out[4], sums
+    assert counted["moe_held_rows"] == counted["moe_routed_pairs"] == 2 * len(fed) * n_decode * N_EXPERT_LAYERS
+    assert counted["moe_experts_hit"] <= counted["moe_held_rows"]  # one lane: each pair its own expert
+    assert len(fed) > 1 or counted["moe_experts_hit"] == counted["moe_held_rows"]
+    decoded = np.asarray(out[3], np.float32)
+    return ({slot: (toks_of[slot], np.concatenate(got[slot] + [decoded[:, slot]])) for slot in fed},
+            out[5], out[4], sums)
+
+
+def prefill_then_decode(cfg, params, chunks, n_decode=3, between=None):
+    """A prompt fed a chunk a dispatch into slot 2 of 4 (the dispatch's second
+    row is padding) and decoded: (tokens, logits ``[sum(chunks) + n_decode,
+    V]``, state, cache, the chunks' counters)."""
+    served, state, cache, sums = dispatch_rows(
+        cfg, params, [[(2, n)] for n in chunks], n_decode=n_decode, between=between, salt=len(chunks))
+    return (*served[2], state, cache, sums)
 
 
 def reference_of(params, tokens):
     return np.asarray(ref.logits(params, SHAPE, jnp.asarray(tokens), jnp.arange(len(tokens))))
 
 
+def a_chunk_a_dispatch(*chunks):
+    return dict(dispatches=[[(2, n)] for n in chunks], salt=len(chunks))
+
+
+# (how `dispatch_rows` is called: a dispatch is its rows, (slot, tokens) each)
+LAYOUTS = {
+    "one_chunk": a_chunk_a_dispatch(16),
+    "a_prompt_that_ends_mid_chunk": a_chunk_a_dispatch(16, 16, 5),
+    "a_short_first_chunk": a_chunk_a_dispatch(7, 16, 14),
+    # a lane's successive pieces in consecutive rows of ONE dispatch, beside another lane's one
+    "two_pieces_in_one_dispatch": dict(rows=8, slots=10, dispatches=[[(2, 16), (2, 5), (5, 12)]]),
+    "three_pieces_in_one_dispatch": dict(rows=8, slots=10, dispatches=[[(1, 9), (2, 16), (2, 16), (2, 7)]]),
+    "eight_pieces_that_fill_a_group": dict(rows=8, slots=10, mb=20, dispatches=[[(2, 16)] * 7 + [(2, 10)]]),
+    # two groups of 8 rows: lane 2's eight pieces are rows 4-11, lane 1's three end before the boundary
+    "eight_pieces_astride_two_groups": dict(rows=16, slots=20, mb=20, dispatches=[
+        [(0, 11), (1, 16), (1, 16), (1, 3)] + [(2, 16)] * 7 + [(2, 9)] + [(7, 16), (7, 2)]]),
+    # pieces behind 32 positions of the lane's own pool history, lane 3's rows 6-9 astride the groups
+    "pieces_behind_pool_history": dict(rows=16, slots=20, mb=20, dispatches=[
+        [(3, 16), (3, 16), (5, 16)],
+        [(0, 7), (1, 16), (1, 2), (2, 16), (2, 16), (2, 1), (3, 16), (3, 16), (3, 16), (3, 6), (5, 4)]]),
+}
+
+
 @pytest.mark.parametrize("dtype, atol", [(jnp.float32, ATOL), (jnp.bfloat16, ATOL_BF16)],
                          ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("chunks", [(16,), (16, 16, 5), (7, 16, 14)],
-                         ids=["one_chunk", "a_prompt_that_ends_mid_chunk", "a_short_first_chunk"])
-def test_chunked_prefill_then_decode_agrees_with_the_plain_reference(chunks, dtype, atol):
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_chunked_prefill_then_decode_agrees_with_the_plain_reference(layout, dtype, atol):
     """A prompt fed in chunks whose boundaries lie inside it, each starting
     from the slot's tails and the K/V pages the last one left (keys rotated at
     their own positions, past a chunk's boundary too), then three decode steps
     off the same state, against the reference's one pass over the whole
-    sequence. The other slots' tails and the other pages stay as they were, and
-    the first chunk alone resets the slot."""
+    sequence. The chunks a dispatch each, or several in consecutive rows of
+    ONE dispatch (at 8 rows, and at 16 with a lane astride the two groups): a
+    later row starts from what the row above it leaves and attends its fresh
+    keys, and the lane's last row leaves the slot its tail. The other slots'
+    tails and the other pages stay as they were, and a sequence's first chunk
+    alone resets its slot."""
     cfg = config_from_card(card(SHAPE), dtype)
     params = seeded_params(cfg)
-    tokens, got, state, cache, sums = prefill_then_decode(cfg, params, chunks)
-    np.testing.assert_allclose(got, reference_of(params, tokens), atol=atol)
-    for leaf in jax.tree.leaves(state):  # slots 0, 1 and 3 of every layer: untouched
-        assert float(leaf[(0, 1, 3), :].min()) == float(leaf[(0, 1, 3), :].max()) == 7.0
-    # pages outside the lane's table (block 0: where a padding row's table points; 9 on)
-    assert not np.asarray(cache["k"][:, 0]).any() and not np.asarray(cache["v"][:, 9:]).any()
-    assert np.asarray(cache["k"][:, 1]).any()
-    counted = [dict(zip(lfm2.COUNTERS, s.tolist())) for s in sums]
-    assert [s["slot_state_resets"] for s in counted] == [1] + [0] * (len(chunks) - 1)
-    assert [s["conv_layer_calls"] for s in counted] == [N_CONV] * len(chunks)
-    # every valid token routes 2 pairs in each of the 4 expert layers, all 8 experts held
-    assert [s["moe_held_rows"] for s in counted] == [2 * N_EXPERT_LAYERS * n for n in chunks]
-    assert [s["moe_layer_calls"] for s in counted] == [N_EXPERT_LAYERS] * len(chunks)
+    how = {"rows": 2, "slots": 4, "mb": 8, **LAYOUTS[layout]}
+    served, state, cache, sums = dispatch_rows(cfg, params, **how)
+    for slot, (tokens, got) in served.items():
+        np.testing.assert_allclose(got, reference_of(params, tokens), atol=atol, err_msg=f"slot {slot}")
+    idle = tuple(i for i in range(how["slots"]) if i not in served)
+    for leaf in jax.tree.leaves(state):  # the slots no row fed, of every layer: untouched
+        assert float(leaf[idle, :].min()) == float(leaf[idle, :].max()) == 7.0
+    # pages outside the lanes' tables (block 0: where a padding row's table points)
+    assert not np.asarray(cache["k"][:, 0]).any() and not np.asarray(cache["v"][:, 0]).any()
+    assert np.asarray(cache["k"][:, 1]).any() and cache["k"].shape[1] == 1 + len(served) * how["mb"]
+    groups, begun = -(-how["rows"] // lfm2.ROWS_AT_ONCE), set()
+    for d, counted in zip(how["dispatches"], sums):
+        assert counted["slot_state_resets"] == len({slot for slot, _ in d} - begun)
+        begun |= {slot for slot, _ in d}
+        # the rows that went on from the row above them: the dispatch's rows less its lanes
+        assert counted["conv_tail_handovers"] == len(d) - len({slot for slot, _ in d})
+        assert counted["conv_layer_calls"] == groups * N_CONV
+        # every valid token routes 2 pairs in each of the 4 expert layers, all 8 experts held
+        assert counted["moe_held_rows"] == 2 * N_EXPERT_LAYERS * sum(n for _, n in d)
+        assert counted["moe_layer_calls"] == groups * N_EXPERT_LAYERS
 
 
 @pytest.mark.parametrize("what", ["the_routers_input", "the_rotation", "the_head_norms"])
@@ -241,32 +306,72 @@ def test_a_coarser_or_wrong_program_fails_the_float32_tolerance(cfg, params, mon
     assert np.abs(got - reference_of(params, tokens)).max() > (3 if what == "the_routers_input" else 100) * ATOL
 
 
-@pytest.mark.parametrize("where", ["a_chunk_boundary", "prefill_to_decode"])
-def test_the_convolutions_tail_carries_across(cfg, params, where):
+@pytest.mark.parametrize("where", ["a_chunk_boundary", "prefill_to_decode", "a_row_of_the_same_dispatch"])
+def test_the_convolutions_tail_carries_across(cfg, params, where, monkeypatch):
     """After a chunk of 7 tokens a conv layer's tail holds its gated inputs 5
     and 6 (oldest first); a second chunk, or a decode step, that starts from a
-    zeroed tail is wrong by far more than ATOL, and only from there on."""
-    chunks = (7, 9) if where == "a_chunk_boundary" else (7,)
-    tokens, got, *_ = prefill_then_decode(cfg, params, chunks)
-    want = reference_of(params, tokens)
-    np.testing.assert_allclose(got, want, atol=ATOL)
+    zeroed tail is wrong by far more than ATOL, and only from there on. A
+    prompt's second piece in the row under its first (16 tokens, then 9) starts
+    from the first row's gated inputs 14 and 15: from the slot's stored tail,
+    as a row alone in its lane does, it is as wrong, and the tail the slot
+    is left is the second row's."""
+    if where == "a_row_of_the_same_dispatch":
+        how = dict(dispatches=[[(2, 16), (2, 9)]], salt=2)
+        (tokens, got), = dispatch_rows(cfg, params, **how)[0].values()
+        want = reference_of(params, tokens)
+        np.testing.assert_allclose(got, want, atol=ATOL)
+        mixer = lfm2.conv_mixer
+        monkeypatch.setattr(lfm2, "conv_mixer", lambda *a: mixer(*a[:5]))  # nothing from the row above
+        served, state, *_ = dispatch_rows(cfg, params, **how)
+        cut, first, last = served[2][1], 16, 25
+        monkeypatch.undo()
+        tail = np.asarray(dispatch_rows(cfg, params, n_decode=0, **how)[1]["conv"][0][2])
+    else:
+        chunks = (7, 9) if where == "a_chunk_boundary" else (7,)
+        tokens, got, *_ = prefill_then_decode(cfg, params, chunks)
+        want = reference_of(params, tokens)
+        np.testing.assert_allclose(got, want, atol=ATOL)
 
-    seen = {}
+        seen = {}
 
-    def zeroed(state):
-        if "tail" in seen and where == "a_chunk_boundary":
-            return state  # only after the first chunk
-        seen.setdefault("tail", np.asarray(state["conv"][0][2]))
-        return jax.tree.map(jnp.zeros_like, state)
+        def zeroed(state):
+            if "tail" in seen and where == "a_chunk_boundary":
+                return state  # only after the first chunk
+            seen.setdefault("tail", np.asarray(state["conv"][0][2]))
+            return jax.tree.map(jnp.zeros_like, state)
 
-    _, cut, *_ = prefill_then_decode(cfg, params, chunks, between=zeroed)
-    assert np.abs(cut[7:] - want[7:]).max() > 100 * ATOL
-    np.testing.assert_allclose(cut[:7], want[:7], atol=ATOL)
+        _, cut, *_ = prefill_then_decode(cfg, params, chunks, between=zeroed)
+        tail, first, last = seen["tail"], 7, 7
+    assert np.abs(cut[first:] - want[first:]).max() > 100 * ATOL
+    np.testing.assert_allclose(cut[:first], want[:first], atol=ATOL)
     # layer 0's gated inputs: B * x of the in-projection of the normed embedding
     lp, e = params["layers"][0], cfg.hidden_size
-    u = llama.rms_norm(params["embed"][jnp.asarray(tokens[:7])], lp["operator_norm"], cfg.norm_eps)
+    u = llama.rms_norm(params["embed"][jnp.asarray(tokens[:last])], lp["operator_norm"], cfg.norm_eps)
     bcx = np.asarray(u @ lp["w_in"])
-    np.testing.assert_allclose(seen["tail"].reshape(2, e), (bcx[:, :e] * bcx[:, 2 * e:])[5:7], atol=1e-5)
+    np.testing.assert_allclose(tail.reshape(2, e), (bcx[:, :e] * bcx[:, 2 * e:])[last - 2:last], atol=1e-5)
+
+
+def test_a_lanes_rows_write_the_slots_tail_once_and_a_padding_row_between_lanes_changes_nothing(cfg, params):
+    """Three pieces of a prompt in rows 0-2 beside another lane's one: the
+    slot is left the tail of the lane's LAST row, what three dispatches of a
+    row each leave it (the rows above it write nowhere: three writes of one
+    slot in one scatter would leave any of them), and a padding row between
+    the two lanes moves nothing of either."""
+    rows = [(2, 16), (2, 16), (2, 5), (5, 9)]
+    one = dispatch_rows(cfg, params, [rows], rows=8, slots=10, n_decode=0)
+    apart = dispatch_rows(cfg, params, [rows[:3] + [None] + rows[3:]], rows=8, slots=10, n_decode=0)
+    piecewise = dispatch_rows(cfg, params, [[(2, 16)], [(2, 16)], [(2, 5), (5, 9)]], rows=8, slots=10, n_decode=0)
+    assert one[3][0]["conv_tail_handovers"] == apart[3][0]["conv_tail_handovers"] == 2
+    assert [s["conv_tail_handovers"] for s in piecewise[3]] == [0, 0, 0]
+    for other in (apart, piecewise):
+        # the order of an expert's sorted rows differs with the rows beside them: float32 rounding
+        for slot in (2, 5):
+            np.testing.assert_allclose(one[0][slot][1], other[0][slot][1], atol=1e-4)
+        for mine, theirs in zip(one[1]["conv"], other[1]["conv"]):
+            np.testing.assert_allclose(np.asarray(mine), np.asarray(theirs), atol=1e-5)
+            assert float(mine[0].min()) == float(mine[9].max()) == 7.0
+        for name in ("k", "v"):
+            np.testing.assert_allclose(np.asarray(one[2][name]), np.asarray(other[2][name]), atol=1e-5)
 
 
 @pytest.mark.parametrize("where", ["a_chunk_row", "a_decode_lane"])
@@ -430,12 +535,13 @@ def test_the_engine_serves_the_reference_greedy_tokens_and_logprobs(engine, para
     np.testing.assert_allclose(lps, logp[np.arange(10), toks], atol=ATOL)
     snap = engine.metrics_snapshot()
     assert snap["moe_layer_calls"] > 0 and snap["conv_layer_calls"] > 0 and snap["slot_state_resets"] >= 1
-    assert set(lfm2.COUNTERS) <= set(snap) and len(lfm2.COUNTERS) == 8
+    assert set(lfm2.COUNTERS) <= set(snap) and len(lfm2.COUNTERS) == 9
     assert not any(k.startswith(("ssm_", "kda_")) for k in snap)
     # the module says what its programs read of the tables: the live part, not all
     assert 0 < snap["chunk_history_tiles_read"] <= snap["chunk_history_tiles_full"]
-    # state per slot beside the pages: a lane has ONE row of a chunk dispatch
-    assert not engine._lane_rows and snap["chunk_rows_live"] == snap["chunk_lanes_fed"] > 0
+    # the module says a lane may fill several rows; this ladder, [1, 4], has no rung that holds them
+    assert lfm2.LANE_TAKES_ROWS and engine._lane_rows
+    assert snap["chunk_rows_live"] == snap["chunk_lanes_fed"] > 0 == snap["conv_tail_handovers"]
     assert 0 < snap["decode_history_tiles_read"] <= snap["decode_history_tiles_full"]
     assert set(engine.cache) == {"k", "v"} and engine.cache["k"].shape == (2, engine.num_blocks, 8, 2, 128)
     tiers = list(snap["attention_tiers"].values())
@@ -500,6 +606,52 @@ def test_a_repeated_prompt_takes_no_prefix_hit(engine):
     assert engine.model_counters["slot_state_resets"] == resets + 1
 
 
+# ladder [8, 16, 64]: a lane fills up to sixteen rows of a dispatch, in one group of 8 or two
+WIDE_CFG = EngineConfig(max_slots=64, kv_block_size=8, max_model_len=192, prefill_chunk=16,
+                        decode_steps=4)
+# (the step a request is submitted on, prompt tokens, answered): a prompt of 7 chunks beside lanes that
+# decode, prompts of 1 to 9 chunks at once (more rows than the second rung holds: the pieces left go on
+# in the next step), a late long one behind decoding lanes
+MIXED = [(0, 9, 24), (2, 100, 8), (2, 12, 10), (3, 60, 6), (3, 140, 5), (3, 37, 9), (3, 90, 5),
+         (4, 128, 6), (4, 16, 7), (9, 75, 5)]
+
+
+def test_every_request_answers_as_alone_where_a_lane_fills_several_rows(cfg, params):
+    """Mixed traffic on a ladder whose rungs under the full width hold 8 and 16
+    rows: most prompts prefill in one dispatch, a later piece starting from
+    the tails the row above it leaves and attending its fresh keys inside the
+    program, and every answer is the one the request gets alone on an engine
+    of four slots (ladder [1, 4]), prefilled a chunk a step."""
+    wide = JaxServingEngine(cfg, params, WIDE_CFG)
+    one = JaxServingEngine(cfg, params, dataclasses.replace(WIDE_CFG, max_slots=4))
+    try:
+        seqs, t = {}, 0
+        while busy(wide) or len(seqs) < len(MIXED):
+            for i, (at, n, m) in enumerate(MIXED):
+                if at == t:
+                    seqs[i] = submit(wide, prompt_of(n, salt=40 + i), m)
+            step(wide)
+            t += 1
+            assert t < 400
+        for i, (at, n, m) in enumerate(MIXED):
+            toks, _, finish = answer(seqs[i])
+            assert (toks, finish) == (served(one, prompt_of(n, salt=40 + i), m)[0], "length"), i
+        assert one.metrics_snapshot()["chunk_rows_live"] == one.metrics_snapshot()["chunk_lanes_fed"]
+        snap = wide.metrics_snapshot()
+        # a row for every chunk of every prompt, whichever dispatch held it, and fewer dispatches a prompt
+        assert snap["chunk_rows_live"] == sum(-(-n // 16) for _, n, _ in MIXED)
+        assert snap["prompts_prefilled"] == len(MIXED) < snap["prompt_dispatches"] < snap["chunk_rows_live"]
+        assert snap["chunk_rows_live"] > snap["chunk_lanes_fed"] == snap["prompt_dispatches"]
+        # every row but a lane's first of a dispatch went on from the row above it
+        assert snap["conv_tail_handovers"] == snap["chunk_rows_live"] - snap["chunk_lanes_fed"]
+        assert snap["slot_state_resets"] == len(MIXED)
+        assert {8, 16} <= {int(r) for r in snap["chunk_dispatches_by_rows"]}
+        assert wide.allocator.active_blocks == 0 and not wide._zombie_allocs
+    finally:
+        wide.close()
+        one.close()
+
+
 @pytest.mark.parametrize("what", [
     "export_migratable", "stage_migration", "set_remote_prefill_policy", "extract_blocks",
     "seed_external_prefix", "the host tier", "a mesh"])
@@ -529,23 +681,31 @@ def test_what_would_hand_pages_over_without_the_state_is_refused_by_name(engine,
             lfm2.param_shardings(cfg, object())
 
 
-def test_the_step_programs_carry_the_four_scopes(engine):
-    """The device trace finds the mechanisms by name: ``conv``, ``attn``,
-    ``moe`` and ``mlp`` are scopes of both step programs, and the expert
-    layer's three grouped products sit under ``moe``."""
+def lowered_step_programs(engine, rows=None):
+    """(the chunk program at ``rows`` rows, the decode program) of the engine's
+    module, lowered from shapes as the engine calls them."""
     def sd(a):
         return jax.ShapeDtypeStruct(a.shape, a.dtype)
 
     s, c, mb = ENGINE_CFG.max_slots, ENGINE_CFG.prefill_chunk, ENGINE_CFG.max_blocks_per_seq
+    r = s if rows is None else rows
     pool = (jax.tree.map(sd, engine.params), jax.tree.map(sd, engine.cache),
             jax.tree.map(sd, engine.slot_state), sd(engine._dummy_counts))
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
     f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
     wd = (i32(),) if engine._watchdog else ()
     chunk = engine._build_chunk_fn(False, False, False).lower(
-        *pool, i32(s, c), i32(s, c), i32(s, mb), i32(s), i32(s), i32(), i32(2, s), f32(4, s), *wd)
+        *pool, i32(r, c), i32(r, c), i32(r, mb), i32(r), i32(r), i32(), i32(2, r), f32(4, r), *wd)
     decode = engine._build_decode_fn(False, False, False).lower(
         *pool, i32(s), i32(s), i32(s, mb), i32(), i32(2, s), f32(4, s), *wd)
+    return chunk, decode
+
+
+def test_the_step_programs_carry_the_four_scopes(engine):
+    """The device trace finds the mechanisms by name: ``conv``, ``attn``,
+    ``moe`` and ``mlp`` are scopes of both step programs, and the expert
+    layer's three grouped products sit under ``moe``."""
+    chunk, decode = lowered_step_programs(engine)
     for program in (chunk, decode):
         text = program.as_text(debug_info=True)
         names = set(re.findall(r'loc\("(?:[^"]*/)?(conv|attn|moe|mlp)/', text))
@@ -553,3 +713,31 @@ def test_the_step_programs_carry_the_four_scopes(engine):
     # an operation's name in the compiled program (what a trace's events carry) is its whole path
     compiled = chunk.compile().as_text()
     assert re.search(r'op_name="[^"]*/moe/jit\(dropless_experts\)/[^"]*grouped_product', compiled)
+
+
+# sha256 of the full-width chunk program's lowered text (4 rows of 16 at SHAPE, float32, as the engine
+# calls it). Compared with the text the tree before a lane could take several rows lowers (PR 48's), at
+# this shape, at 16 and 64 slots and at the cell's: one constant and one broadcast more, the ninth
+# counter's zero, and nothing else.
+FULL_WIDTH_CHUNK_SHA256 = "bfd3714991d518f4073edfc9d985c6e5fea0b6cbe6b2d72bd651d3fbfe36ddb4"
+
+
+def test_the_full_width_chunk_program_holds_nothing_of_the_hand_over(engine, monkeypatch):
+    """At ``rows == slots`` a lane has one row by the engine's rule, and the
+    admission wave's program is what it was: none of the functions that hand
+    a row what lies above it is traced there (each raises here), the text is
+    the same with them gone and its hash stands. The rung under it calls
+    them."""
+    import hashlib
+
+    text = lowered_step_programs(engine)[0].as_text()
+
+    def unreachable(*a, **kw):
+        raise AssertionError("the hand-over, in a program that has one row a lane")
+
+    for name in ("_Layout", "_Left", "lane_first_positions", "sibling_rows_back", "chunk_rows_above_partial"):
+        monkeypatch.setattr(lfm2, name, unreachable)
+    assert lowered_step_programs(engine)[0].as_text() == text
+    assert hashlib.sha256(text.encode()).hexdigest() == FULL_WIDTH_CHUNK_SHA256
+    with pytest.raises(AssertionError, match="the hand-over"):
+        lowered_step_programs(engine, rows=1)
