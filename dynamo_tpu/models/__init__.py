@@ -40,9 +40,14 @@ def module_for(model_config):
     row alone write the slot's state back, (3) ends a row's pool history
     where its lane's first row of the dispatch starts and attends the rows
     between as fresh keys, and (4) is, at ``rows == slots``, the program it
-    was (``models/lfm2.py`` does; ``jamba``, ``qwen3_next`` and
-    ``kimi_linear`` say nothing until their kernels walk a lane's rows in
-    order).
+    was. ``models/lfm2.py`` does: its state is a convolution's tail, which a
+    row's own inputs make. ``models/jamba.py`` does: its recurrence is a
+    KERNEL, which for (1) walks a lane's rows in order and keeps the state on
+    the chip from the lane's first row to its last
+    (``ops/pallas/selective_scan.py:selective_scan(..., continues)``), so that
+    the lane's FIRST row holds the state after its last and writes it, the
+    last row the tail. ``qwen3_next`` and ``kimi_linear`` say nothing until
+    ``kda_scan`` does the same.
 
     A config that is no ``LlamaConfig`` was made by its own module's class
     (``engine_jax/weights.py:config_from_card`` imports that module in its

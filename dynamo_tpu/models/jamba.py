@@ -45,7 +45,9 @@ its softplus, ``exp(step x A)``, the state, the sum over N. The recurrence
 advances ``[rows, N, D]`` token by token (:func:`_scan_tokens`), in one of the two
 kernels of ``ops/pallas/selective_scan.py``: a chunk's holds a row's state on the
 chip from the row's first token to its last valid one (HBM sees it once in and
-once out); a decode step's, one token of every slot, updates a layer of the run's
+once out), and over the rows that go on from it where a lane fills several rows
+of a dispatch with successive pieces of its prompt (``LANE_TAKES_ROWS``;
+:func:`forward_chunk`); a decode step's, one token of every slot, updates a layer of the run's
 state in place, read once and written once. The token count the code sees picks
 the kernel, the token's arithmetic is one. ``[rows, T, N, D]`` never exists.
 """
@@ -61,8 +63,9 @@ import jax.numpy as jnp
 
 from dynamo_tpu.models.llama import (  # noqa: F401  (the two tile counts are this module's too)
     _chunk_self_partial, _live_window_attention, _merge_partials, _pool_pages,
-    chunk_history_partial, chunk_history_tiles, decode_history_tiles, embed_lookup, flush_window,
-    history_tile, history_tiles_full, rms_norm, with_live_history,
+    chunk_history_partial, chunk_history_tiles, chunk_sibling_partial, decode_history_tiles,
+    embed_lookup, flush_window, history_tile, history_tiles_full, lane_first_positions, rms_norm,
+    sibling_rows_back, with_live_history,
 )
 from dynamo_tpu.ops.pallas.selective_scan import selective_scan, selective_step
 from dynamo_tpu.ops.parts import dot_parts as _dot
@@ -74,11 +77,23 @@ SlotState = Dict[str, Tuple[jax.Array, ...]]  # {"s": per run [n, S, N, D], "con
 # sums the step programs return, in this order (engine: /debug/engine):
 # Mamba layers run (a chunk dispatch or a decode step each count their 26);
 # valid tokens the chunks' recurrences advanced, and the times a chunk row's
-# state went from HBM to the chip and back (one a real row that holds a valid
-# token: the kernel keeps it there over the row's tokens, where the scan it
+# state went from HBM to the chip and back (one a LANE of a dispatch: a real
+# row that holds a valid token and continues no row above it; the kernel keeps
+# the state there over the tokens of the lane's rows, where the scan it
 # replaced made a pass a token), both summed over the Mamba layers; rows that
-# started a request
-COUNTERS = ("ssm_layer_calls", "ssm_chunk_tokens", "ssm_state_passes", "slot_state_resets")
+# started a request; real rows of a chunk dispatch that took their state and
+# the convolution's tail from the row above them (the rows of a dispatch less
+# the lanes it fed)
+COUNTERS = ("ssm_layer_calls", "ssm_chunk_tokens", "ssm_state_passes", "slot_state_resets",
+            "ssm_state_handovers")
+# A lane may fill several rows of one chunk dispatch with successive pieces of
+# its prompt (engine_jax/engine.py:chunk_rows_of; docs/kv_cache_manager.md,
+# "State per slot", says what a module with state per slot owes for it): under
+# the full width a row whose lane is that of the row above it starts the
+# recurrence from the state that row ends with, inside the kernel, and the
+# convolution from that row's last inputs, and attends its lane's earlier rows'
+# fresh keys; one row of a lane alone leaves the slot each part of its state
+LANE_TAKES_ROWS = True
 
 
 @dataclass(frozen=True)
@@ -262,18 +277,21 @@ def mlp(lp: Params, c: JambaConfig, h: jax.Array) -> jax.Array:
 
 
 def _scan_tokens(lp: Params, s: jax.Array, delta: jax.Array, x: jax.Array, b: jax.Array,
-                 c: jax.Array, valid: jax.Array):
+                 c: jax.Array, valid: jax.Array, above=None):
     """The selective scan over ``[B, T]`` tokens from the rows' state ``s``
     ``[B, N, D]``, all float32: ``s = exp(delta A) * s + (delta x) B``, ``y =
     s C`` (summed over N). A token that is not valid leaves the state as it is.
     Returns (``y`` ``[B, T, D]``, zeros where not valid; the state after the
     last valid token). More tokens than one (a chunk): the kernel that keeps a
-    row's state on the chip over its valid tokens, a prefix of the row. One (a
-    decode step): the step kernel, which updates in place, so ``s`` may be (a
-    run's state ``[n, B, N, D]``, the layer to advance) and comes back so."""
+    row's state on the chip over its valid tokens, a prefix of the row; a row
+    that goes on from the row ``above`` it (``[B]`` bool) starts from that
+    row's last state on the chip and not from ``s``, and the state comes back
+    at the FIRST of such rows, after the last of them. One (a decode step): the
+    step kernel, which updates in place, so ``s`` may be (a run's state ``[n,
+    B, N, D]``, the layer to advance) and comes back so."""
     a = -jnp.exp(lp["a_log"])  # [N, D]
     if delta.shape[1] > 1:
-        return selective_scan(delta, x, b, c, a, s, valid.sum(axis=1),
+        return selective_scan(delta, x, b, c, a, s, valid.sum(axis=1), above,
                               interpret=jax.default_backend() == "cpu")
     run, layer = s if isinstance(s, tuple) else (s[None], 0)  # rows' own state: a run of one layer
     y, run = selective_step(delta[:, 0], x[:, 0], b[:, 0], c[:, 0], a, run, layer, valid[:, 0],
@@ -282,28 +300,29 @@ def _scan_tokens(lp: Params, s: jax.Array, delta: jax.Array, x: jax.Array, b: ja
 
 
 def mamba_mixer(lp: Params, c: JambaConfig, u: jax.Array, valid: jax.Array,
-                s: jax.Array, tail: jax.Array):
+                s: jax.Array, tail: jax.Array, above=None):
     """The Mamba mixer over ``[B, T, E]`` normed inputs whose valid tokens are
     a prefix of each row, from the rows' state ``s`` ``[B, N, D]`` (or a layer
     of a run's, as :func:`_scan_tokens` takes it) and the convolution's tail
     ``[B, (K - 1) * D]``: (output ``[B, T, E]``, the state after the last valid
-    token, the new tail: the row's last ``K - 1`` valid inputs).
+    token, the new tail: the row's last ``K - 1`` valid inputs). ``above``
+    ``[B]`` bool: a row that goes on where the row above it ends, a FULL row of
+    the same sequence, takes its state and its tail from that row and not from
+    ``s`` and ``tail``, and the state of the sequence comes back at its first row.
     """
     d, n, r = c.d_inner, c.mamba_d_state, c.mamba_dt_rank
     xz = _dot(u, lp["w_in"], PARTS)
     x, gate = xz[..., :d], xz[..., d:]
     # the causal depthwise convolution over the tail and the tokens, and the new tail: a chunk's
-    # form or a decode step's, by the token count (:func:`_convolve`, which stands under the chunk
-    # program: the lines from here down to there are callers of the chunk's kernel, and a kernel's
-    # key in the compile cache holds its callers' line numbers, so they are kept where they were)
-    x, new_tail = _convolve(lp, c, x, valid, tail)
+    # form or a decode step's, by the token count (:func:`_convolve`, under the chunk program)
+    x, new_tail = _convolve(lp, c, x, valid, tail, above)
 
     dbc = _dot(x, lp["w_x"], PARTS)
     dt = rms_norm(dbc[..., :r], lp["dt_norm"], c.rms_norm_eps)
     b = rms_norm(dbc[..., r:r + n], lp["b_norm"], c.rms_norm_eps)
     cc = rms_norm(dbc[..., r + n:], lp["c_norm"], c.rms_norm_eps)
     delta = jax.nn.softplus(_dot(dt, lp["w_dt"], PARTS) + lp["b_dt"])  # [B, T, D]
-    y, s = _scan_tokens(lp, s, delta, x, b, cc, valid)
+    y, s = _scan_tokens(lp, s, delta, x, b, cc, valid, above)
     y = (y + lp["d_skip"] * x) * jax.nn.silu(gate)
     return _dot(y, lp["w_out"], PARTS), s, new_tail
 
@@ -326,9 +345,11 @@ def forward_chunk(
     params: Params, config: JambaConfig, tokens: jax.Array, positions: jax.Array,
     kv_cache: KVCache, block_tables: jax.Array, state: SlotState, lanes: jax.Array,
 ):
-    """A ``[R, C]`` block of prompt tokens, one row per prefilling lane
-    (``lanes`` ``[R]``: the row's slot; ``max_slots`` and above = a padding
-    row), valid tokens (position >= 0) a prefix of each row.
+    """A ``[R, C]`` block of prompt tokens (``lanes`` ``[R]``: the row's slot;
+    ``max_slots`` and above = a padding row), valid tokens (position >= 0) a
+    prefix of each row. Under the full width (``R`` < the state's slots) a lane
+    may fill several CONSECUTIVE rows with successive pieces of its prompt, in
+    order, each full but the last; at it, one row a lane.
 
     Returns (hidden ``[R, C, E]`` after the final norm, the pool with the
     rows' K and V written, the slot state with the rows' slots advanced, the
@@ -339,7 +360,21 @@ def forward_chunk(
     scatters them back in place, so a dispatch costs what its rows touch. The
     attention layers read the pool as ``models/llama.py:forward_chunk`` does
     (history a tile at a time, the chunk's own keys in hand) and their fresh K
-    and V are written after the layers by one scatter a pool array."""
+    and V are written after the layers by one scatter a pool array.
+
+    A row whose lane is that of the row above it (both real: ``above``) goes on
+    where that row ends, inside the program. In a Mamba layer the recurrence
+    starts from the state that row ends with, which the kernel keeps on the
+    chip from the lane's first row to its last, and the convolution from that
+    row's last inputs (:func:`mamba_mixer`); one write a slot: the lane's FIRST
+    row holds the state after its last (``selective_scan`` leaves it there) and
+    scatters it, the lane's LAST row scatters the tail, and the lane's other
+    rows send their index past the run's state, as padding rows do. In an
+    attention layer the row's pool history ends where its lane's first row of
+    the dispatch starts and one more partial attends the fresh keys of its
+    lane's rows above it (``chunk_sibling_partial``). Where every lane has one
+    row nothing is taken from a row above and the sibling loop makes no trip;
+    at the full width none of it is in the program."""
     from dynamo_tpu.ops.attention import write_kv_to_pool
 
     c = config
@@ -355,8 +390,20 @@ def forward_chunk(
     table_blocks = block_tables.shape[1]
     pages = _pool_pages(kv_cache)
     tile_blocks = history_tile(block_size, table_blocks) // block_size
-    history_len = jnp.clip(positions[:, 0], 0, table_blocks * block_size)
-    n_tiles = chunk_history_tiles(positions, block_size, table_blocks)
+    above = under = None
+    starts, of_lanes = positions[:, 0], ()
+    if b < slots:  # at the full width a lane has one row, and the program holds nothing of this
+        live = real & (starts >= 0)
+        above = jnp.concatenate([jnp.zeros((1,), bool), (lanes[1:] == lanes[:-1]) & live[1:] & live[:-1]])
+        under = jnp.concatenate([above[1:], jnp.zeros((1,), bool)])  # the row under it goes on from it
+        starts, of_lanes = lane_first_positions(positions, lanes), (lanes,)
+        n_back = sibling_rows_back(positions, lanes)
+        # the flash partial of no keys: what the sibling loop starts from (below)
+        no_keys = (jnp.zeros((b, t, c.num_heads, c.head_dim), jnp.float32),
+                   jnp.full((b, c.num_heads, t), -1e30, jnp.float32),
+                   jnp.zeros((b, c.num_heads, t), jnp.float32))
+    history_len = jnp.clip(starts, 0, table_blocks * block_size)
+    n_tiles = chunk_history_tiles(positions, block_size, table_blocks, *of_lanes)
     tables = jnp.pad(block_tables, (
         (0, 0), (0, history_tiles_full(block_size, table_blocks) * tile_blocks - table_blocks)))
 
@@ -368,10 +415,14 @@ def forward_chunk(
             s0 = jnp.where(fresh[:, None, None], 0.0, s_all[at])
             tail0 = jnp.where(fresh[:, None], 0.0, conv_all[at])
             y, s1, tail1 = mamba_mixer(
-                lp, c, rms_norm(h, lp["mixer_norm"], c.rms_norm_eps), valid, s0, tail0)
+                lp, c, rms_norm(h, lp["mixer_norm"], c.rms_norm_eps), valid, s0, tail0, above)
             # a padding row writes nowhere: its index lies past the run's state
-            back = jnp.where(real, at, s_all.shape[0])
-            s_all = s_all.at[back].set(s1, mode="drop")
+            back = s_back = jnp.where(real, at, s_all.shape[0])
+            if above is not None:
+                # nor do a lane's rows but ONE: the first holds the state after the last, the last the tail
+                s_back = jnp.where(above, s_all.shape[0], back)
+                back = jnp.where(under, s_all.shape[0], back)
+            s_all = s_all.at[s_back].set(s1, mode="drop")
             conv_all = conv_all.at[back].set(tail1, mode="drop")
         with jax.named_scope("mlp"):
             h = mlp(lp, c, h + y)
@@ -397,7 +448,14 @@ def forward_chunk(
             hist = chunk_history_partial(
                 c, q, pages, j * num_blocks + tables, history_len, n_tiles, positions, scale,
                 tile_blocks, block_size, c.dtype)
-            num, _, den = _merge_partials(hist, _chunk_self_partial(c, q, k, v, positions, scale))
+            part = _merge_partials(hist, _chunk_self_partial(c, q, k, v, positions, scale))
+            if above is not None:
+                # the rows above, folded from the partial of no keys (merging with it is exact): the
+                # loop's carry is kept apart from ``part``, so what stands above compiles as it does
+                # without the loop and rows alone in their lanes keep their bits
+                part = _merge_partials(part, chunk_sibling_partial(
+                    c, q, k, v, positions, lanes, n_back, scale, no_keys))
+            num, _, den = part
             attn = jnp.where(
                 (den > 0.0).transpose(0, 2, 1)[..., None],
                 num / jnp.maximum(den, 1e-30).transpose(0, 2, 1)[..., None], 0.0)
@@ -410,17 +468,25 @@ def forward_chunk(
              "v": write_kv_to_pool(kv_cache["v"], jnp.stack(fresh_v), positions, block_tables)}
     h = rms_norm(h, params["final_norm"], c.rms_norm_eps)
     n_mamba = sum(_runs(c))
+    begins = valid[:, 0] & real  # a lane's first row of the dispatch: where its state goes in and out
+    if above is not None:
+        begins &= ~above
     counters = jnp.stack([
-        jnp.int32(n_mamba), n_mamba * jnp.sum(valid & real[:, None]),
-        n_mamba * jnp.sum(valid[:, 0] & real), jnp.sum(fresh & real)])
+        jnp.int32(n_mamba), n_mamba * jnp.sum(valid & real[:, None]), n_mamba * jnp.sum(begins),
+        jnp.sum(fresh & real), jnp.int32(0) if above is None else jnp.sum(above)])
     return h, cache, {"s": tuple(s_out), "conv": tuple(conv_out)}, counters.astype(jnp.int32)
 
 
-def _convolve(lp: Params, c: JambaConfig, x: jax.Array, valid: jax.Array, tail: jax.Array):
+def _convolve(lp: Params, c: JambaConfig, x: jax.Array, valid: jax.Array, tail: jax.Array,
+              above=None):
     """The mixer's causal depthwise convolution of ``x`` ``[B, T, D]`` behind the
     rows' tail ``[B, (K - 1) * D]`` (tap K - 1 is the token itself, tap 0 the
     oldest input), its bias and its silu: (the mixer's ``x`` ``[B, T, D]``, the
-    new tail: the row's last ``K - 1`` valid inputs). One token (a decode step):
+    new tail: the row's last ``K - 1`` valid inputs). A chunk's row that goes on
+    from the row ``above`` it (``[B]`` bool), a FULL row of the same sequence,
+    starts behind that row's last ``K - 1`` inputs and not behind ``tail``: they
+    are the in-projection of that row's own tokens, so the rows are still
+    convolved all at once. One token (a decode step):
     the taps are the tail's ``K - 1`` slices of ``D`` lanes and the token, and the
     new tail is the old one moved up by a token where the lane decodes, so the
     tail stays ``[B, (K - 1) * D]`` from the slot state and back. (As ``[B, K - 1,
@@ -434,6 +500,11 @@ def _convolve(lp: Params, c: JambaConfig, x: jax.Array, valid: jax.Array, tail: 
         taps = [tail[:, j * d:(j + 1) * d] for j in range(kk - 1)] + [x[:, 0]]
         out = jax.nn.silu(sum(taps[j] * lp["conv_w"][j] for j in range(kk)) + lp["conv_b"])
         return out[:, None], jnp.where(valid, jnp.concatenate(taps[1:], axis=1), tail)
+    if above is not None:
+        if t < kk - 1:
+            raise ValueError(f"a row of {t} tokens holds no tail of {kk - 1}")
+        ends = x[:, t - (kk - 1):].reshape(bsz, -1)  # what each row, if full, leaves the row under it
+        tail = jnp.where(above[:, None], jnp.concatenate([tail[:1], ends[:-1]]), tail)
     seq = jnp.concatenate([tail.reshape(bsz, kk - 1, d), x], axis=1)  # [B, K-1+T, D]
     out = jax.nn.silu(sum(seq[:, j:j + t] * lp["conv_w"][j] for j in range(kk)) + lp["conv_b"])
     tail_at = valid.sum(axis=1)[:, None] + jnp.arange(kk - 1)[None, :]  # the K-1 inputs before position n

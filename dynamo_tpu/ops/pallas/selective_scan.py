@@ -1,7 +1,7 @@
 """A chunk of Mamba's selective scan with the state held on the chip.
 
-``selective_scan(delta, x, b, c, a, s0, n_valid)`` advances every row's
-``[N, D]`` float32 state over the first ``n_valid[row]`` tokens of a
+``selective_scan(delta, x, b, c, a, s0, n_valid, continues)`` advances every
+row's ``[N, D]`` float32 state over the first ``n_valid[row]`` tokens of a
 ``[B, T, D]`` chunk by ``models/jamba.py:_scan_tokens``'s recurrence, in its
 operations, all float32 on the vector unit (nothing goes to the MXU, which
 would round to bfloat16):
@@ -16,6 +16,20 @@ Valid tokens are a prefix of a row; positions past them are never computed and
 their outputs are zeros, and a row without a valid token gets its state back
 bit for bit. ``exp(delta A)`` is made a token at a time: ``[B, T, N, D]`` never
 exists.
+
+**A sequence may fill several consecutive rows**, an earlier piece of it in
+an earlier row (a lane's rows of one chunk dispatch:
+``models/jamba.py:forward_chunk``). A row that ``continues`` the row above it
+starts from the state that row ends with, and not from ``s0[row]``: the grid
+walks the rows IN ORDER for each block of channels, and the state's block is
+indexed by the sequence's FIRST row (a prefetched scalar beside ``n_valid``),
+so the block stays on the chip from that row's first token to the last valid
+token of the sequence's last row. ``s0`` is loaded where a row starts a
+sequence, the state goes to HBM once a SEQUENCE, at its first row, and the rows
+that continue have no entry of their own in the result. A row that continues
+nothing (a padding row between two sequences too) takes nothing and hands
+nothing on; where no row continues, every row is a sequence and the results are
+what a call a row gives, bit for bit: the token's body knows nothing of rows.
 
 **The layout is the kernel.** The only vectors along N are ``B_t`` and ``C_t``
 (16 numbers a token) and the only reduction is the sum over N. So a grid step
@@ -46,7 +60,7 @@ one a grid step.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -84,11 +98,15 @@ def selective_scan(
     a: jax.Array,  # [N, D] float32: -exp(a_log)
     s0: jax.Array,  # [B, N, D] float32: the rows' state before the chunk
     n_valid: jax.Array,  # [B] int32: a row's valid tokens, a prefix of it
+    continues: Optional[jax.Array] = None,  # [B] bool: the row goes on where the row above it ends
     *,
     interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
-    """(``y`` ``[B, T, D]``, zeros past a row's valid tokens; the state after
-    each row's last valid token ``[B, N, D]``)."""
+    """(``y`` ``[B, T, D]``, zeros past a row's valid tokens; the state
+    ``[B, N, D]`` after the last valid token of each SEQUENCE, at the
+    sequence's first row: a row that ``continues`` the row above it, a full
+    row, belongs to that row's sequence, and its own entry of the state is
+    never written. ``None``: no row continues, a row a sequence.)"""
     rows, t_in, d = delta.shape
     n = a.shape[0]
     if n % SUBLANES:
@@ -108,10 +126,11 @@ def selective_scan(
         operations a load.)"""
         return r >> 3, pl.ds(r & (SUBLANES - 1), groups, stride=SUBLANES), slice(None)
 
-    def kernel(n_valid, delta, x, b, c, a, s0, y, s, a_rows):
-        at = pl.program_id(2)
+    def kernel(n_valid, first, delta, x, b, c, a, s0, y, s, a_rows):
+        r, at = pl.program_id(1), pl.program_id(2)
 
-        @pl.when(at == 0)
+        # a row that continues finds its sequence's state where the row above left it: in ``s``
+        @pl.when((at == 0) & (first[r] == r))
         def _():
             s[...] = s0[...]
 
@@ -131,7 +150,7 @@ def selective_scan(
             y[row(i)] = out
             return tuple(new)
 
-        tokens = jnp.clip(n_valid[pl.program_id(0)] - at * tile, 0, tile)
+        tokens = jnp.clip(n_valid[r] - at * tile, 0, tile)
         state = tuple(s[row(j)] for j in range(n))
         # two tokens a trip: the loop's copies of the carried registers are paid once for both
         state = jax.lax.fori_loop(0, tokens // 2, lambda i, st: token(2 * i + 1, token(2 * i, st)), state)
@@ -143,28 +162,35 @@ def selective_scan(
     by_token = lambda v: jnp.pad(v, pad).reshape(rows, t // tile, tile * n)  # noqa: E731
     width, whole = groups * SUBLANES, d // lanes * SUBLANES  # sublanes of a block, of a row's channels
 
-    chunk = pl.BlockSpec((None, tile // SUBLANES, width, lanes), lambda i, j, k, n_valid: (i, k, j, 0))
-    scalars = pl.BlockSpec((None, None, tile * n), lambda i, j, k, n_valid: (i, k, 0),
+    # a sequence's first row: the row itself, or where it continues, that of the row above it
+    first = jnp.arange(rows, dtype=jnp.int32)
+    if continues is not None:
+        first = jax.lax.cummax(jnp.where(continues, 0, first))
+
+    # the grid: (channel block j, row i, token tile k), the rows of a channel block in order
+    chunk = pl.BlockSpec((None, tile // SUBLANES, width, lanes), lambda j, i, k, n_valid, first: (i, k, j, 0))
+    scalars = pl.BlockSpec((None, None, tile * n), lambda j, i, k, n_valid, first: (i, k, 0),
                            memory_space=pltpu.SMEM)
-    state = pl.BlockSpec((None, n // SUBLANES, width, lanes), lambda i, j, k, n_valid: (i, 0, j, 0))
+    # one block a sequence: it stays where it is while the rows that follow continue it
+    state = pl.BlockSpec((None, n // SUBLANES, width, lanes), lambda j, i, k, n_valid, first: (first[i], 0, j, 0))
     y, s = pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct((rows, t // SUBLANES, whole, lanes), jnp.float32),
                    jax.ShapeDtypeStruct((rows, n // SUBLANES, whole, lanes), jnp.float32)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             in_specs=[chunk, chunk, scalars, scalars,
-                      pl.BlockSpec((n // SUBLANES, width, lanes), lambda i, j, k, n_valid: (0, j, 0)),
+                      pl.BlockSpec((n // SUBLANES, width, lanes), lambda j, i, k, n_valid, first: (0, j, 0)),
                       state],
             out_specs=(chunk, state),
-            grid=(rows, d // (groups * lanes), t // tile),
+            grid=(d // (groups * lanes), rows, t // tile),
             scratch_shapes=[pltpu.VMEM((n, groups, lanes), jnp.float32)],
         ),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
         name="selective_scan",
-    )(n_valid.astype(jnp.int32), _by_sublane(jnp.pad(delta, pad), lanes),
+    )(n_valid.astype(jnp.int32), first, _by_sublane(jnp.pad(delta, pad), lanes),
       _by_sublane(jnp.pad(x, pad), lanes), by_token(b), by_token(c),
       _by_sublane(a, lanes), _by_sublane(s0, lanes))
     return _by_row(y)[:, :t_in], _by_row(s)

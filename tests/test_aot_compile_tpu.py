@@ -682,10 +682,13 @@ def _compile_jamba(program, one_chip, rows=8):
             i32(rows)).compile()
 
 
-@pytest.mark.parametrize("program", ["decode", "chunk"])
-def test_jambas_step_programs_never_copy_the_slots_state(monkeypatch, one_chip, program):
+@pytest.mark.parametrize("program, rows", [("decode", 8), ("chunk", 8), ("chunk", 16)],
+                         ids=["decode", "chunk", "chunk_of_16_rows"])
+def test_jambas_step_programs_never_copy_the_slots_state(monkeypatch, one_chip, program, rows):
     """``models/jamba.py`` at ``batch.jamba2-3b``'s served shapes (a chunk at
-    the 8-row rung): the compiled program holds no copy of a run's
+    the 8- and 16-row rungs, where a lane may fill several rows and the program
+    is traced with the hand-over from row to row: ``LANE_TAKES_ROWS``): the
+    compiled program holds no copy of a run's
     ``f32[n,64,16,5120]`` state or of its convolution tails. A decode step must
     read and write the 647 MB once: under a ``lax.scan`` over the steps the
     compiler copied the state whole onto the loop's carry every step, which is
@@ -694,7 +697,7 @@ def test_jambas_step_programs_never_copy_the_slots_state(monkeypatch, one_chip, 
     one flat index on the layer loop's carry, and the view the chunk's kernel
     wants is made of the rows' state, never of the slots'."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernel compiled, not interpreted
-    compiled = _compile_jamba(program, one_chip)
+    compiled = _compile_jamba(program, one_chip, rows)
     hlo = re.sub(r"/\*.*?\*/", "", compiled.as_text())
     copies = re.findall(r"= f32\[\d+,64,(?:16,5120|15360)\]\{[^}]*\} copy\(", hlo)
     copies += re.findall(r"= bf16\[2,12288,16,1,128\]\{[^}]*\} copy\(", hlo)  # nor the pool
@@ -741,12 +744,14 @@ def test_jambas_decode_step_passes_over_a_layers_state_once(monkeypatch, one_chi
                 if re.search(r"\breduce\(", line) and "f32[64,16,5120]" in line]
 
 
-@pytest.mark.parametrize("rows", [8, 64])
+@pytest.mark.parametrize("rows", [8, 16, 64])
 def test_jambas_chunk_program_holds_a_rows_state_on_the_chip(monkeypatch, one_chip, rows):
-    """The chunk program at the 8- and 64-row rungs of 64 slots:
+    """The chunk program at the 8-, 16- and 64-row rungs of 64 slots (under the
+    full width as it is traced under ``LANE_TAKES_ROWS``: the kernel walks a
+    lane's rows in order, its state block indexed by a prefetched scalar):
     ``ops/pallas/selective_scan.py`` is in the compiled program, once a run of
-    Mamba layers (its tiling, its SMEM blocks and its fast memory are what
-    interpret mode cannot see), and no loop carries the rows'
+    Mamba layers (its grid, its index maps, its SMEM blocks and its fast memory
+    are what interpret mode cannot see), and no loop carries the rows'
     ``f32[rows,16,5120]`` state once a token through HBM, as the scan the kernel
     replaced did."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")

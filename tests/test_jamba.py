@@ -76,6 +76,25 @@ def highest_precision():
         yield
 
 
+@pytest.fixture(autouse=True)
+def room_for_compiled_programs():
+    """Every compiled program is a few memory mappings of the process, and a
+    process may hold 65,530 (``vm.max_map_count``): past it the CPU's compiler
+    dies with the worker. Alone in a process this file peaks at 34,093 and
+    ``tests/test_jamba_lane_rows.py`` at 31,532, but a worker brings what its
+    files before left (``tests/test_lfm2.py``: 36,218). So JAX's caches go once
+    the process holds 30,000, and the programs a later test shares compile
+    again."""
+    yield
+    try:
+        with open("/proc/self/maps") as f:
+            held = sum(1 for _ in f)
+    except OSError:
+        return
+    if held > 30_000:
+        jax.clear_caches()
+
+
 @pytest.fixture(scope="module")
 def cfg():
     return config_from_card(card(SHAPE), jnp.float32)
@@ -108,42 +127,92 @@ def test_the_layer_kinds_are_the_published_pattern(cfg):
     assert module_for(cfg) is jamba and module_for(llama.LLAMA_PRESETS["tiny"]) is llama
 
 
-def prefill_then_decode(cfg, params, chunks, n_decode=3, between=None):
-    """Logits ``[sum(chunks) + n_decode, V]`` of a prompt fed in ``chunks``
-    into slot 2 of 4 (the chunk's second row is padding) and decoded from the
-    state and pages they left; (logits, state, cache, the chunks' counters)."""
-    n_prompt = sum(chunks)
-    tokens = np.asarray(prompt_of(n_prompt + n_decode, salt=len(chunks)), np.int32)
-    slots, c, bs, mb, slot = 4, 16, 8, 8, 2
-    cache = jamba.make_kv_cache(cfg, 32, bs)
+SPARE_BLOCKS = 8
+
+
+def dispatch_rows(cfg, params, dispatches, rows=8, slots=10, mb=8, n_decode=3, between=None, salt=None):
+    """Chunk dispatches of ``rows`` rows over ``slots`` slots, then ``n_decode``
+    teacher-forced decode steps of every slot fed, off the state and pages the
+    dispatches left. A dispatch is a list of its rows in order, ``(slot, n)``
+    = the slot's next ``n`` prompt tokens (a lane's rows of one dispatch are
+    its successive pieces) or ``None`` = a padding row; the rows left are
+    padding. The k-th slot fed has blocks ``1 + k * mb`` onwards, and the pool
+    holds ``SPARE_BLOCKS`` more that no table names: no page but those a fed
+    slot's tokens reach may be written, block 0 (where a padding row's table
+    points) and the spare ones included, which is held here for every caller.
+    Every slot's state starts stale (``between`` may change it after a
+    dispatch); its tokens are ``prompt_of(., salt or the slot)``. Returns
+    ({slot: (its tokens, hidden states of its prompt, logits ``[prompt +
+    n_decode, V]``)}, state, cache, the dispatches' counters)."""
+    c, bs = 16, 8
+    fed = list(dict.fromkeys(row[0] for d in dispatches for row in d if row))
+    length = {slot: sum(row[1] for d in dispatches for row in d if row and row[0] == slot) for slot in fed}
+    toks_of = {slot: np.asarray(prompt_of(length[slot] + n_decode, salt=salt or slot), np.int32) for slot in fed}
+    table = {slot: 1 + k * mb + np.arange(mb, dtype=np.int32) for k, slot in enumerate(fed)}
+    cache = jamba.make_kv_cache(cfg, 1 + len(fed) * mb + SPARE_BLOCKS, bs)
     state = jax.tree.map(lambda a: a + 7.0, jamba.make_slot_state(cfg, slots))  # stale, every slot
-    tables = np.zeros((2, mb), np.int32)
-    tables[0] = np.arange(1, 9)
-    got, sums, at = [], [], 0
-    for n in chunks:
-        toks, pos = np.zeros((2, c), np.int32), np.full((2, c), -1, np.int32)
-        toks[0, :n], pos[0, :n] = tokens[at:at + n], np.arange(at, at + n)
-        h, cache, state, counted = jamba.forward_chunk(
-            params, cfg, jnp.asarray(toks), jnp.asarray(pos), cache, jnp.asarray(tables),
-            state, jnp.asarray([slot, slots], jnp.int32))
-        got.append(np.asarray(jamba.lm_head(params, cfg, h[0, :n]), np.float32))
-        sums.append(np.asarray(counted))
-        at += n
+    at, hidden, sums = dict.fromkeys(fed, 0), {slot: [] for slot in fed}, []
+    chunk = jax.jit(lambda params, *a: jamba.forward_chunk(params, cfg, *a))  # as the engine runs it
+    for d in dispatches:
+        toks, pos = np.zeros((rows, c), np.int32), np.full((rows, c), -1, np.int32)
+        tables, lanes = np.zeros((rows, mb), np.int32), np.full((rows,), slots, np.int32)
+        for r, row in enumerate(d):
+            if row is None:
+                continue
+            slot, n = row
+            toks[r, :n], pos[r, :n] = toks_of[slot][at[slot]:at[slot] + n], np.arange(at[slot], at[slot] + n)
+            tables[r], lanes[r] = table[slot], slot
+            at[slot] += n
+        h, cache, state, counted = chunk(
+            params, jnp.asarray(toks), jnp.asarray(pos), cache, jnp.asarray(tables),
+            state, jnp.asarray(lanes))
+        for r, row in enumerate(d):
+            if row is not None:
+                hidden[row[0]].append(np.asarray(h[r, :row[1]], np.float32))
+        sums.append(dict(zip(jamba.COUNTERS, np.asarray(counted).tolist())))
         if between is not None:
             state = between(state)
-    lanes_tables = np.zeros((slots, mb), np.int32)
-    lanes_tables[slot] = tables[0]
-    toks, pos = np.zeros((slots,), np.int32), np.full((slots,), -1, np.int32)
-    toks[slot], pos[slot] = tokens[n_prompt], n_prompt
+    hidden = {slot: np.concatenate(hidden[slot]) for slot in fed}
+    logits = {slot: [np.asarray(jamba.lm_head(params, cfg, jnp.asarray(hidden[slot])), np.float32)] for slot in fed}
+    if n_decode:
+        lanes_tables = np.zeros((slots, mb), np.int32)
+        toks, pos = np.zeros((slots,), np.int32), np.full((slots,), -1, np.int32)
+        forcing = np.zeros((slots, max(length.values()) + n_decode), np.int32)
+        for slot in fed:
+            lanes_tables[slot], toks[slot], pos[slot] = table[slot], toks_of[slot][length[slot]], length[slot]
+            forcing[slot, :len(toks_of[slot])] = toks_of[slot]
 
-    def forced(logits, p, carry, k):  # teacher forcing: the sequence's own next token
-        return jnp.where(p >= 0, jnp.asarray(tokens)[jnp.clip(p + 1, 0, len(tokens) - 1)], 0), carry, logits
+        def forced(logits, p, carry, k):  # teacher forcing: each sequence's own next token
+            nxt = jnp.asarray(forcing)[jnp.arange(slots), jnp.clip(p + 1, 0, forcing.shape[1] - 1)]
+            return jnp.where(p >= 0, nxt, 0), carry, logits
 
-    out = jamba.decode(params, cfg, jnp.asarray(toks), jnp.asarray(pos), cache,
-                       jnp.asarray(lanes_tables), state, n_decode, 95, forced, None)
-    assert int(out[1][slot]) == n_prompt + n_decode and int(out[6][0]) == n_decode * N_MAMBA
-    got.append(np.asarray(out[3], np.float32)[:, slot])
-    return tokens, np.concatenate(got), out[5], out[4], sums
+        out = jamba.decode(params, cfg, jnp.asarray(toks), jnp.asarray(pos), cache,
+                           jnp.asarray(lanes_tables), state, n_decode, 8 * mb - 1, forced, None)
+        assert [int(out[1][slot]) for slot in fed] == [length[slot] + n_decode for slot in fed]
+        assert np.asarray(out[6]).tolist() == [n_decode * N_MAMBA, 0, 0, 0, 0]
+        for slot in fed:
+            logits[slot].append(np.asarray(out[3], np.float32)[:, slot])
+        state, cache = out[5], out[4]
+    reached = np.zeros((cache["k"].shape[1],), bool)
+    for slot in fed:
+        reached[table[slot][:-(-(length[slot] + n_decode) // bs)]] = True
+    for name in ("k", "v"):
+        pool = np.asarray(cache[name], np.float32)
+        assert not pool[:, ~reached].any(), f"{name}: a page outside what the fed slots' tokens reach was written"
+        assert all(pool[:, block].any() for block in np.flatnonzero(reached)), name
+    return ({slot: (toks_of[slot], hidden[slot], np.concatenate(logits[slot])) for slot in fed},
+            state, cache, sums)
+
+
+def prefill_then_decode(cfg, params, chunks, n_decode=3, between=None):
+    """A prompt fed a chunk a dispatch into slot 2 of 4 (the dispatch's second
+    row is padding) and decoded: (tokens, logits ``[sum(chunks) + n_decode,
+    V]``, state, cache, the chunks' counters)."""
+    served, state, cache, sums = dispatch_rows(
+        cfg, params, [[(2, n)] for n in chunks], rows=2, slots=4, n_decode=n_decode, between=between,
+        salt=len(chunks))
+    tokens, _, logits = served[2]
+    return tokens, logits, state, cache, sums
 
 
 @pytest.mark.parametrize("dtype, atol", [(jnp.float32, ATOL), (jnp.bfloat16, ATOL_BF16)],
@@ -163,11 +232,12 @@ def test_chunked_prefill_then_decode_agrees_with_the_plain_reference(chunks, dty
     np.testing.assert_allclose(got, want, atol=atol)
     for leaf in jax.tree.leaves(state):  # slots 0, 1 and 3 of every layer: untouched
         assert float(leaf[:, (0, 1, 3)].min()) == float(leaf[:, (0, 1, 3)].max()) == 7.0
-    # pages outside the lane's table (block 0: where a padding row's table points; 9 on)
-    assert not np.asarray(cache["k"][:, 0]).any() and not np.asarray(cache["v"][:, 9:]).any()
-    assert np.asarray(cache["k"][:, 1]).any()
-    assert [int(s[3]) for s in sums] == [1] + [0] * (len(chunks) - 1)
-    assert [int(s[1]) for s in sums] == [N_MAMBA * n for n in chunks]
+    # pages outside the lane's table (block 0: where a padding row's table points; 9 on, the spare ones)
+    assert cache["k"].shape[1] == 9 + SPARE_BLOCKS
+    for pool in (np.asarray(cache["k"]), np.asarray(cache["v"])):
+        assert not pool[:, 0].any() and not pool[:, 9:].any() and pool[:, 1].any()
+    assert [s["slot_state_resets"] for s in sums] == [1] + [0] * (len(chunks) - 1)
+    assert [s["ssm_chunk_tokens"] for s in sums] == [N_MAMBA * n for n in chunks]
 
 
 def test_a_recurrence_taken_in_bfloat16_fails_the_float32_tolerance(cfg, params, monkeypatch):
@@ -178,8 +248,8 @@ def test_a_recurrence_taken_in_bfloat16_fails_the_float32_tolerance(cfg, params,
     def low(a):  # a decode step's state comes with its layer's index, which stays
         return a.astype(jnp.bfloat16).astype(jnp.float32) if a.dtype == jnp.float32 else a
 
-    def rounded(lp, s, delta, x, b, c, valid):
-        y, s = scan(lp, *jax.tree.map(low, (s, delta, x, b, c)), valid)
+    def rounded(lp, s, delta, x, b, c, valid, above=None):
+        y, s = scan(lp, *jax.tree.map(low, (s, delta, x, b, c)), valid, above)
         return y, jax.tree.map(low, s)
 
     monkeypatch.setattr(jamba, "_scan_tokens", rounded)
@@ -466,8 +536,9 @@ def test_the_engine_serves_the_reference_greedy_tokens_and_logprobs(engine, para
     assert set(jamba.COUNTERS) <= set(snap) and not any(k.startswith(("moe_", "kda_")) for k in snap)
     # the module says what its programs read of the tables: the live part, not all
     assert 0 < snap["chunk_history_tiles_read"] <= snap["chunk_history_tiles_full"]
-    # state per slot beside the pages: a lane has ONE row of a chunk dispatch
-    assert not engine._lane_rows and snap["chunk_rows_live"] == snap["chunk_lanes_fed"] > 0
+    # the module says a lane may fill several rows; this ladder, [1, 4], has no rung that holds them
+    assert jamba.LANE_TAKES_ROWS and engine._lane_rows and len(jamba.COUNTERS) == 5
+    assert snap["chunk_rows_live"] == snap["chunk_lanes_fed"] > 0 == snap["ssm_state_handovers"]
     assert 0 < snap["decode_history_tiles_read"] <= snap["decode_history_tiles_full"]
     assert set(engine.cache) == {"k", "v"} and engine.cache["k"].shape[0] == 2
     tiers = list(snap["attention_tiers"].values())
@@ -486,6 +557,9 @@ def test_the_counters_count_what_a_served_prompt_did(engine):
     rise = {k: after[k] - before[k] for k in jamba.COUNTERS}
     assert (rise["ssm_chunk_tokens"], rise["ssm_state_passes"]) == (N_MAMBA * 40, N_MAMBA * 3)
     assert rise["slot_state_resets"] == 1
+    # a row a lane on this ladder: the rows that took their state from the row above, none
+    rows, lanes = (after[k] - before[k] for k in ("chunk_rows_live", "chunk_lanes_fed"))
+    assert rise["ssm_state_handovers"] == rows - lanes == 0 and rows == 3
     # 3 chunk dispatches + the decode dispatches' 4 steps each (3 more tokens: 1 or 2 dispatches)
     assert rise["ssm_layer_calls"] in (N_MAMBA * (3 + 4), N_MAMBA * (3 + 8))
 
@@ -527,6 +601,26 @@ def test_a_repeated_prompt_takes_no_prefix_hit(engine):
     assert engine.model_counters["slot_state_resets"] == resets + 1
 
 
+def lowered_step_programs(engine, rows=None):
+    """(the chunk program at ``rows`` rows, the decode program) of the engine's
+    module, lowered from shapes as the engine calls them."""
+    def sd(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype)
+
+    s, c, mb = ENGINE_CFG.max_slots, ENGINE_CFG.prefill_chunk, ENGINE_CFG.max_blocks_per_seq
+    r = s if rows is None else rows
+    pool = (jax.tree.map(sd, engine.params), jax.tree.map(sd, engine.cache),
+            jax.tree.map(sd, engine.slot_state), sd(engine._dummy_counts))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+    wd = (i32(),) if engine._watchdog else ()
+    chunk = engine._build_chunk_fn(False, False, False).lower(
+        *pool, i32(r, c), i32(r, c), i32(r, mb), i32(r), i32(r), i32(), i32(2, r), f32(4, r), *wd)
+    decode = engine._build_decode_fn(False, False, False).lower(
+        *pool, i32(s), i32(s), i32(s, mb), i32(), i32(2, s), f32(4, s), *wd)
+    return chunk, decode
+
+
 @pytest.mark.parametrize("what", [
     "export_migratable", "stage_migration", "set_remote_prefill_policy", "extract_blocks",
     "seed_external_prefix", "the host tier", "a mesh"])
@@ -559,19 +653,6 @@ def test_what_would_hand_pages_over_without_the_state_is_refused_by_name(engine,
 def test_the_step_programs_carry_the_three_scopes(engine):
     """The device trace finds the mechanisms by name: ``mamba``, ``attn`` and
     ``mlp`` are scopes of both step programs."""
-    def sd(a):
-        return jax.ShapeDtypeStruct(a.shape, a.dtype)
-
-    s, c, mb = ENGINE_CFG.max_slots, ENGINE_CFG.prefill_chunk, ENGINE_CFG.max_blocks_per_seq
-    pool = (jax.tree.map(sd, engine.params), jax.tree.map(sd, engine.cache),
-            jax.tree.map(sd, engine.slot_state), sd(engine._dummy_counts))
-    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
-    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
-    wd = (i32(),) if engine._watchdog else ()
-    chunk = engine._build_chunk_fn(False, False, False).lower(
-        *pool, i32(s, c), i32(s, c), i32(s, mb), i32(s), i32(s), i32(), i32(2, s), f32(4, s), *wd)
-    decode = engine._build_decode_fn(False, False, False).lower(
-        *pool, i32(s), i32(s), i32(s, mb), i32(), i32(2, s), f32(4, s), *wd)
-    for program in (chunk, decode):
+    for program in lowered_step_programs(engine):
         names = set(re.findall(r'loc\("(?:[^"]*/)?(mamba|attn|mlp)/', program.as_text(debug_info=True)))
         assert names == {"mamba", "attn", "mlp"}, names

@@ -568,6 +568,22 @@ def expert_profiles():
         del w
 
 
+def median_ms(fn, *args) -> float:
+    """ms a call of ``fn(*args)``: the median of five, after one that compiles."""
+    jax.block_until_ready(fn(*args))
+    times = []
+    for _ in range(5):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        times.append(time.perf_counter() - t0)
+    return float(np.median(times)) * 1e3
+
+
+def largest(a, b) -> float:
+    """The largest difference between two arrays, or two trees of them."""
+    return max(float(jnp.abs(x - y).max()) for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
+
+
 def chunk_valid_counts(rows: int, used: int, rng, chunk: int = 128) -> np.ndarray:
     """Valid tokens of each row of a chunk dispatch in ``batch``'s traffic:
     ``used`` rows hold one chunk (any of them, drawn) of a prompt of the
@@ -688,7 +704,18 @@ def mamba_profiles():
     kernel's own).
     PROF_ITERS (default 8) layers chained in one dispatch, each from the state
     the last one left. PROF_DINNER (default 5120) cuts the width for a
-    rehearsal on the CPU (interpreted)."""
+    rehearsal on the CPU (interpreted).
+
+    Then a lane's rows handed over (PR 51), full rows of 128 tokens: at 8 rows
+    one row a lane, lanes of two, 5 + 3 and one lane of eight, at 16 rows one
+    row a lane, 8 + 8 and one lane of sixteen; the kernel in the chain and by
+    its own event, the whole mixer, and the largest difference from the same
+    kernel called a row at a time, each call from the state the call before it
+    left (0: the hand-over is those calls). Last the WHOLE chunk program of
+    ``jamba2-3b`` at those layouts, beside the same rows in successive
+    dispatches, a piece of every lane a dispatch: the largest difference of the
+    first run of Mamba layers' state (0: nothing stands in front of it) and of
+    all of it (what two orders of attention's sums leave)."""
     from benchmark.bytes_and_flops_jamba import SCAN_OPS_PER_ELEMENT
     from dynamo_tpu.engine_jax.compile_cache import enable_compile_cache
     from dynamo_tpu.models import jamba
@@ -720,13 +747,7 @@ def mamba_profiles():
 
     def chain_ms(chain, *args):
         """ms a layer of a chain of ``n_iter``: the median of five calls."""
-        chain(*args)[0].block_until_ready()
-        times = []
-        for _ in range(5):
-            t0 = time.perf_counter()
-            chain(*args)[0].block_until_ready()
-            times.append(time.perf_counter() - t0)
-        return float(np.median(times)) * 1e3 / n_iter
+        return median_ms(chain, *args) / n_iter
 
     def timed(fn, *args):
         return chain_ms(chained(fn), *args)
@@ -781,16 +802,21 @@ def mamba_profiles():
         kernels.ROWS = own
         jax.clear_caches()
 
-    rng = np.random.default_rng(0)
-    for rows, t, used in ((8, 128, 5), (16, 128, 12), (8, 128, 8), (8, 128, 0), (16, 128, 16),
-                          (64, 128, 64), (64, 128, None), (64, 1, 64)):
+    def inputs(rows, t):
+        """What the kernel takes of a chunk (``delta``, ``x``, ``b``, ``c``, a carried state)
+        and what the mixer takes (its normed inputs, a tail)."""
         key = jax.random.split(jax.random.PRNGKey(rows), 6)
         delta = jax.nn.softplus(jax.random.normal(key[0], (rows, t, d), jnp.float32) - 3.0)
         x = jax.random.normal(key[1], (rows, t, d), jnp.float32)
         b, cc = (jax.random.normal(key[i], (rows, t, n), jnp.float32) for i in (2, 3))
         s0 = jax.random.normal(key[4], (rows, n, d), jnp.float32)
         u = jax.random.normal(key[5], (rows, t, c.hidden_size), jnp.float32).astype(c.dtype)
-        tail = jnp.zeros((rows, (c.mamba_d_conv - 1) * d), jnp.float32)
+        return (delta, x, b, cc), s0, u, jnp.zeros((rows, (c.mamba_d_conv - 1) * d), jnp.float32)
+
+    rng = np.random.default_rng(0)
+    for rows, t, used in ((8, 128, 5), (16, 128, 12), (8, 128, 8), (8, 128, 0), (16, 128, 16),
+                          (64, 128, 64), (64, 128, None), (64, 1, 64)):
+        (delta, x, b, cc), s0, u, tail = inputs(rows, t)
         counts = (np.full(rows, t, np.int32) if used == rows
                   else chunk_valid_counts(rows, rows if used is None else used, rng))
         valid = jnp.arange(t)[None, :] < jnp.asarray(counts)[:, None]
@@ -810,6 +836,83 @@ def mamba_profiles():
               f"scanned step {timed(scanned, s0, *xs):7.3f}, whole mixer {ms_mixer:7.3f}; largest difference "
               f"state {float(jnp.abs(s_k - s_s).max()):.3g} of {float(jnp.abs(s_s).max()):.3g}, "
               f"outputs {float(jnp.abs(y_k - y_s).max()):.3g} of {float(jnp.abs(y_s).max()):.3g}", flush=True)
+
+    # -- a lane's rows handed over: full rows, the lanes as the engine lays them --------------
+    for rows, named in LANE_LAYOUTS.items():
+        made, s0, u, tail = inputs(rows, 128)
+        valid = jnp.ones((rows, 128), bool)
+        xs = (*made, valid)
+        a_row = jax.jit(lambda s, *a: jamba._scan_tokens(lp, s, *a))
+        for name, sizes in named.items():
+            above = jnp.asarray([k > 0 for m in sizes for k in range(m)])
+            scan = lambda s, *a: jamba._scan_tokens(lp, s, *a, above)  # noqa: E731
+            ms, event = timed(scan, s0, *xs), kernel_event_ms(chained(scan), s0, *xs)
+            ms_mixer = timed(lambda s, u, valid, tail: jamba.mamba_mixer(lp, c, u, valid, s, tail, above)[:2],
+                             s0, u, valid, tail)
+            y_k, s_k = jax.jit(scan)(s0, *xs)
+            off_y = off_s = 0.0
+            for first, m in zip(np.cumsum([0] + sizes[:-1]), sizes):  # a call a row, from the state the last left
+                state = s0[first:first + 1]
+                for r in range(first, first + m):
+                    y_r, state = a_row(state, *(v[r:r + 1] for v in xs))
+                    off_y = max(off_y, largest(y_r[0], y_k[r]))
+                off_s = max(off_s, largest(state[0], s_k[first]))  # the lane's state: at its first row
+            print(f"mamba {rows:2d} full rows, {name}: kernel {ms:7.3f} ms a layer in the chain, its own event "
+                  f"{event:7.3f}, whole mixer {ms_mixer:7.3f}; largest difference from a call a row: state "
+                  f"{off_s:.3g}, outputs {off_y:.3g}", flush=True)
+    chunk_program_profile(jamba, d)
+
+
+# (rows of a chunk dispatch: {a layout's name: the rows each lane fills, in order})
+LANE_LAYOUTS = {
+    8: {"one row a lane": [1] * 8, "lanes of two": [2] * 4, "5 + 3": [5, 3], "one lane of eight": [8]},
+    16: {"one row a lane": [1] * 16, "8 + 8": [8, 8], "one lane of sixteen": [16]},
+}
+
+
+def chunk_program_profile(jamba, d_inner):
+    """``jamba2-3b``'s whole chunk program ALONE at the cell's shapes (64 slots,
+    12,288 blocks of 16, tables of 2,048 positions, random bf16 weights) at the
+    layouts of ``LANE_LAYOUTS``, every row full: ms a dispatch (the median of
+    five), beside the same rows a piece of every lane a dispatch."""
+    c = jamba.JambaConfig(hidden_size=d_inner // 2)
+    slots, mb, t = 64, 128, 128
+    params = jax.jit(lambda: jamba.init_params(jax.random.PRNGKey(0), c))()
+    cache = jamba.make_kv_cache(c, 12288, 16)
+    state = jax.tree.map(lambda a: 0.1 * jax.random.normal(jax.random.PRNGKey(1), a.shape, a.dtype),
+                         jamba.make_slot_state(c, slots))
+
+    mine = jax.jit(lambda p, kv, st, toks, pos, tables, lanes: jamba.forward_chunk(
+        p, c, toks, pos, kv, tables, st, lanes))
+
+    def dispatch(rows, pieces):
+        """The arrays of a dispatch of ``rows`` rows: ``pieces`` = (lane, which piece of its prompt)."""
+        toks = np.zeros((rows, t), np.int32)
+        pos = np.full((rows, t), -1, np.int32)
+        tables, lanes = np.zeros((rows, mb), np.int32), np.full((rows,), slots, np.int32)
+        for r, (lane, k) in enumerate(pieces):
+            toks[r] = (np.arange(t) * 7 + lane * 131 + k * 17) % (c.vocab_size - 1) + 1
+            pos[r] = k * t + np.arange(t)
+            tables[r], lanes[r] = 1 + lane * mb + np.arange(mb), lane
+        return tuple(jnp.asarray(a) for a in (toks, pos, tables, lanes))
+
+    for rows, named in LANE_LAYOUTS.items():
+        for name, sizes in named.items():
+            once = dispatch(rows, [(lane, k) for lane, m in enumerate(sizes) for k in range(m)])
+            ms = median_ms(mine, params, cache, state, *once)
+            _, _, st, sums = mine(params, cache, state, *once)
+            line = (f"chunk program {rows:2d} full rows, {name}: {ms:8.3f} ms a dispatch; handovers "
+                    f"{int(sums[4])}, state passes {int(sums[2])}")
+            if max(sizes) > 1:  # the same rows, a piece of every lane a dispatch
+                kv_p, st_p, total = cache, state, 0.0
+                for k in range(max(sizes)):
+                    step = dispatch(rows, [(lane, k) for lane, m in enumerate(sizes) if k < m])
+                    total += median_ms(mine, params, kv_p, st_p, *step)
+                    _, kv_p, st_p, _ = mine(params, kv_p, st_p, *step)
+                line += (f"; in {max(sizes)} successive dispatches {total:8.3f} ms; largest difference of the "
+                         f"state they leave: the first run of Mamba layers {largest(st['s'][0], st_p['s'][0]):.3g}, "
+                         f"all {largest(st, st_p):.3g} of {largest(st_p, jax.tree.map(jnp.zeros_like, st_p)):.3g}")
+            print(line, flush=True)
 
 
 if __name__ == "__main__":
