@@ -203,3 +203,48 @@ class NgramDrafter:
             if out:
                 return list(out)
         return None
+
+
+class DeviceDrafter:
+    """The draft of a model that predicts further than one token itself (a
+    multi-token-prediction module: ``models/openpangu.py``): ONE token, made on
+    the device inside the dispatch that produced the stream's last token and
+    handed over with it (:meth:`offer`). The same surface as
+    :class:`NgramDrafter`, so the engine's verify step asks either alike.
+
+    It holds no index and no state of the model: only the length of the
+    logical stream (prompt + generated, append-only as the n-gram drafter's)
+    and the one guess with the stream length it was made for. A guess for
+    another length (the lane emitted fewer tokens than its dispatch computed,
+    or more since) is never proposed."""
+
+    __slots__ = ("k", "_len", "_guess", "_at", "drafted", "accepted")
+
+    def __init__(self, prompt: Sequence[int], k: int):
+        self.k = k
+        self._len = len(prompt)
+        self._guess: Optional[int] = None
+        self._at = -1  # the stream length ``_guess`` continues
+        self.drafted = 0
+        self.accepted = 0
+
+    def __len__(self) -> int:
+        return self._len
+
+    accept_rate = NgramDrafter.accept_rate
+    dormant = NgramDrafter.dormant
+    note_result = NgramDrafter.note_result
+
+    def extend(self, tokens: Sequence[int]) -> None:
+        self._len += len(tokens)
+
+    def offer(self, token: int, at: int) -> None:
+        """The device's guess for the token that continues a stream of ``at``
+        tokens."""
+        self._guess, self._at = int(token), at
+
+    def would_draft(self) -> bool:
+        return not self.dormant and self._guess is not None and self._at == self._len
+
+    def draft(self) -> Optional[List[int]]:
+        return [self._guess] if self.would_draft() else None

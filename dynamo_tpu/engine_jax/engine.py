@@ -59,6 +59,7 @@ from dynamo_tpu.engine_jax.allocator import (
 )
 from dynamo_tpu.engine_jax.drafter import (
     MAX_SPEC_K,
+    DeviceDrafter,
     NgramDrafter,
     env_kv_dtype,
     env_spec_k,
@@ -415,11 +416,13 @@ class _Inflight:
     """
 
     __slots__ = ("out", "lps", "top_ids", "top_lps", "tokens", "positions",
-                 "lanes", "sealing", "sums")
+                 "lanes", "sealing", "sums", "drafts")
 
     def __init__(self, out, lps, top_ids, top_lps, tokens, positions, lanes,
-                 sealing=None, sums=None):
-        self.sums = sums  # device, a slot model's counters of this dispatch
+                 sealing=None, sums=None, drafts=None):
+        self.sums = sums  # device, the counters of a module's own program
+        # device [S], a drafting module's guess at the token after the last
+        self.drafts = drafts
         self.out = out  # [S, k_steps] device
         self.lps = lps  # [S, k_steps] device, chosen-token logprobs
         self.top_ids = top_ids  # [S, k_steps, P] device
@@ -529,14 +532,23 @@ class JaxServingEngine(AsyncEngine):
     ):
         self.model_config = model_config
         self.config = engine_config
-        # the model module, picked in one place (models.module_for). A module
-        # with ``make_slot_state`` keeps state per SLOT beside the pages (a
-        # recurrent layer's): the chunk and decode programs carry it, and
-        # whatever hands pages over without it is refused by name
-        # (`_refuse_for_state`)
+        # the model module, picked in one place (models.module_for). Two
+        # facts about it, each stated by what the module has:
+        # - ``COUNTERS``: it brings its OWN step programs (``forward_chunk``
+        #   / ``decode`` in the form called here, with the slots' state in
+        #   and out and the sums its layers count), where `models/llama.py`'s
+        #   are imported by name. That picks the call form, and nothing else.
+        # - ``make_slot_state``: it keeps state per SLOT beside the pages (a
+        #   recurrent layer's), which the programs carry: whatever hands
+        #   pages over without it is refused by name (`_refuse_for_state`:
+        #   no prefix hit, no draft, no host tier, no transfer). A module
+        #   with its own programs and NO such state (`models/openpangu.py`:
+        #   latent pages and nothing else) is handed ``None`` for the state
+        #   and is refused none of them.
         self.model = module_for(model_config)
+        self._own_programs = hasattr(self.model, "COUNTERS")
         self._slot_model = hasattr(self.model, "make_slot_state")
-        if self._slot_model and (
+        if self._own_programs and (
             mesh is not None or engine_config.quantize
             or (engine_config.kv_dtype or env_kv_dtype()) == "int8"
         ):
@@ -600,6 +612,11 @@ class JaxServingEngine(AsyncEngine):
         # a drafted token that is rejected would have to be taken out of
         # the slot's recurrent state again: such a model never drafts
         self._spec_k = 0 if self._slot_model else max(0, min(int(sk), MAX_SPEC_K))
+        # a module with a prediction module of its own (``draft_chunk``)
+        # drafts on the device, inside the dispatch that made the token
+        # before (engine_jax/drafter.py:DeviceDrafter); every other model's
+        # drafts come from the host's n-gram index
+        self._device_drafts = self._spec_k > 0 and hasattr(self.model, "draft_chunk")
         self._spec_ngram = (
             engine_config.spec_ngram if engine_config.spec_ngram is not None
             else env_spec_ngram()
@@ -695,6 +712,8 @@ class JaxServingEngine(AsyncEngine):
             self.cache = self.model.make_kv_cache(
                 model_config, self.num_blocks, engine_config.kv_block_size,
                 dtype=cache_dtype, quantized=self._kv_quantized,
+                # the prediction module's own pages, only where it runs
+                **({"drafting": True} if self._device_drafts else {}),
             )
         # the slots' state, one value the model module owns: the step
         # programs take it and hand it back, nothing here looks inside
@@ -702,8 +721,8 @@ class JaxServingEngine(AsyncEngine):
             self.model.make_slot_state(model_config, engine_config.max_slots)
             if self._slot_model else None
         )
-        # sums the slot model's programs return (its module's COUNTERS),
-        # added up by the host as their dispatches are fetched
+        # sums a module's own programs return (its COUNTERS), added up by
+        # the host as their dispatches are fetched
         self.model_counters: Dict[str, int] = {
             name: 0 for name in getattr(self.model, "COUNTERS", ())
         }
@@ -943,7 +962,7 @@ class JaxServingEngine(AsyncEngine):
 
         mc, ec = model_config, engine_config
         dtype_size = jnp.dtype(cache_dtype or mc.dtype).itemsize
-        if self._slot_model:
+        if self._own_programs:
             # its module gathers the paged members to a dense buffer once a
             # dispatch: the jnp tier, and no kernel
             self._decode_dense = True
@@ -1186,23 +1205,26 @@ class JaxServingEngine(AsyncEngine):
                         tlps.transpose(1, 0, 2))
             return (out.T,)
 
-        if self._slot_model:
+        if self._own_programs:
+            drafting = {"draft": True} if self._device_drafts else {}
+
             def decode(params, cache, state, counts, tokens, positions,
                        tables, step_ctr, ipack, fpack, wdf=None):
-                # a model whose layers keep state per slot beside the pages
-                # (models.module_for): its module scans the steps itself, over
-                # the slots' state, and hands back the sums its layers count
+                # a module with its own programs (models.module_for): it scans
+                # the steps itself, over the slots' state where it keeps one
+                # (None otherwise), and hands back the sums its layers count
+                # and, where it drafts, its guess at the token after the last
                 sample_step = sampler(step_ctr, ipack, fpack, wdf)
 
                 def sample(sel, pos, counts, k):
                     nxt, _, counts, out = sample_step(sel, pos, counts, k)
                     return nxt, counts, out
 
-                toks, pos, counts, out, cache, state, sums = self.model.decode(
+                toks, pos, counts, out, cache, state, sums, *drafts = self.model.decode(
                     params, cfg, tokens, positions, cache, tables, state,
-                    k_steps, max_pos, sample, counts,
+                    k_steps, max_pos, sample, counts, **drafting,
                 )
-                return (*slot_major(out), toks, pos, sums, cache, state, counts)
+                return (*slot_major(out), toks, pos, sums, *drafts, cache, state, counts)
 
             # the trace names a program after its function: jit_decode
             return jax.jit(decode, donate_argnums=(1, 2, 3))
@@ -1345,7 +1367,7 @@ class JaxServingEngine(AsyncEngine):
         """The chunk variant at ``rows`` rows (a rung of ``_chunk_rungs``;
         default ``max_slots``). One jitted function serves every row count:
         the key keeps the programs apart that ``warmup`` compiled ahead."""
-        if self._pp > 1 or self._sp > 1 or self._slot_model:
+        if self._pp > 1 or self._sp > 1 or self._own_programs:
             want_history = True  # these forwards have no history-free variant
         rows = self.config.max_slots if rows is None else rows
         key = (want_lp, want_pen, want_sample, want_history, rows)
@@ -1415,13 +1437,49 @@ class JaxServingEngine(AsyncEngine):
                 return (nxt, *token_logprobs(sel, nxt, n_top)), counts
             return (nxt,), counts
 
-        if self._slot_model:
+        if self._device_drafts:
+            def chunk(params, cache, state, counts, tokens, positions,
+                      tables, sample_at, lanes, following, step_ctr, ipack,
+                      fpack, wdf=None):
+                # a module that drafts (``draft_chunk``): its prediction
+                # module runs over the rows' positions behind the sampling,
+                # each with the token that follows it (``following`` [R, C]:
+                # the prompt's own, from the host; at ``sample_at`` the token
+                # just sampled), writes its own pages, and the host gets its
+                # first choice for the token AFTER the sampled one
+                inputs = sampling_inputs(step_ctr, ipack, fpack)
+                x, cache, state, sums = self.model.forward_chunk(
+                    params, cfg, tokens, positions, cache, tables, state, lanes,
+                    raw=True,
+                )
+                fetch, counts = sample_rows(
+                    params, self.model.final_norm(params, cfg, x), counts,
+                    sample_at, lanes, inputs, wdf,
+                )
+                cols = jnp.arange(tokens.shape[1])
+                following = jnp.where(
+                    cols[None, :] == sample_at[:, None], fetch[0][:, None],
+                    following,
+                )
+                hd, cache, more = self.model.draft_chunk(
+                    params, cfg, x, following, positions, cache, tables,
+                )
+                hs = hd[jnp.arange(hd.shape[0]), jnp.clip(sample_at, 0)]
+                drafts = jnp.argmax(
+                    self.model.lm_head(params, cfg, hs), axis=-1
+                ).astype(jnp.int32)
+                return (*fetch, sums + more, drafts, cache, state, counts)
+
+            return jax.jit(chunk, donate_argnums=(1, 2, 3))
+
+        if self._own_programs:
             def chunk(params, cache, state, counts, tokens, positions,
                       tables, sample_at, lanes, step_ctr, ipack, fpack,
                       wdf=None):
-                # a model whose layers keep state per slot beside the pages
-                # (models.module_for): a row starts from its slot's state and
-                # leaves it behind; the sums its layers count go to the host
+                # a module with its own programs (models.module_for): a row
+                # starts from its slot's state and leaves it behind (None
+                # where the module keeps none); the sums its layers count go
+                # to the host
                 inputs = sampling_inputs(step_ctr, ipack, fpack)
                 h, cache, state, sums = self.model.forward_chunk(
                     params, cfg, tokens, positions, cache, tables, state, lanes,
@@ -1528,11 +1586,25 @@ class JaxServingEngine(AsyncEngine):
             # dispatches overwrite before any mask lets it be attended
             # (history masks are position-based: pool reads stop below each
             # lane's current position).
-            h, cache = forward_chunk(
-                params, cfg, tokens, positions, cache, tables,
-                hidden_only=True, with_history=True,
-            )
-            logits_all = lm_head(params, cfg, h)  # [S, K1, V] f32
+            sums = x = None
+            if self._own_programs:
+                # the module's own program over the [S, K1] fed positions (a
+                # module that drafts keeps no state per slot: None). Where it
+                # drafts, the raw output goes on to its prediction module
+                lanes = jnp.arange(tokens.shape[0], dtype=jnp.int32)
+                h, cache, _, sums = self.model.forward_chunk(
+                    params, cfg, tokens, positions, cache, tables, None, lanes,
+                    **({"raw": True} if self._device_drafts else {}),
+                )
+                if self._device_drafts:
+                    x, h = h, self.model.final_norm(params, cfg, h)
+                logits_all = self.model.lm_head(params, cfg, h)
+            else:
+                h, cache = forward_chunk(
+                    params, cfg, tokens, positions, cache, tables,
+                    hidden_only=True, with_history=True,
+                )
+                logits_all = lm_head(params, cfg, h)  # [S, K1, V] f32
             if wd:
                 logits_all = jnp.where(
                     wdf > 0, jnp.full_like(logits_all, jnp.nan), logits_all
@@ -1547,17 +1619,25 @@ class JaxServingEngine(AsyncEngine):
                 with_pen=with_pen, with_sample=with_sample, with_lp=with_lp,
                 n_top=n_top,
             )
-            if with_lp:
-                tgt, lp, tids, tlps, counts = outs
-                if wd:
-                    tgt = jnp.where(
-                        bad[:, None], jnp.int32(WATCHDOG_TOKEN), tgt
-                    )
-                return tgt, lp, tids, tlps, cache, counts
-            tgt, counts = outs
+            *fetch, counts = outs  # (tgt,) or (tgt, lp, tids, tlps)
             if wd:
-                tgt = jnp.where(bad[:, None], jnp.int32(WATCHDOG_TOKEN), tgt)
-            return tgt, cache, counts
+                fetch[0] = jnp.where(
+                    bad[:, None], jnp.int32(WATCHDOG_TOKEN), fetch[0]
+                )
+            if self._device_drafts:
+                # position j's target is the token that follows it: the
+                # prediction module's choice after each, [S, K1] (the host
+                # keeps the one behind the last accepted token)
+                hd, cache, more = self.model.draft_chunk(
+                    params, cfg, x, fetch[0], positions, cache, tables,
+                )
+                drafts = jnp.argmax(
+                    self.model.lm_head(params, cfg, hd), axis=-1
+                ).astype(jnp.int32)
+                return (*fetch, sums + more, drafts, cache, counts)
+            if sums is not None:
+                return (*fetch, sums, cache, counts)
+            return (*fetch, cache, counts)
 
         return jax.jit(verify, donate_argnums=(1, 2))
 
@@ -1565,16 +1645,18 @@ class JaxServingEngine(AsyncEngine):
 
     def _state_args(self, params) -> tuple:
         """The leading arguments of a step program: the weights, the pool,
-        and for a slot model the slots' state behind it."""
-        if self._slot_model:
+        and for a module's own programs the slots' state behind it (None
+        where the module keeps none)."""
+        if self._own_programs:
             return (params, self.cache, self.slot_state)
         return (params, self.cache)
 
     def _take_state(self, result: tuple) -> tuple:
-        """Take the pool (and a slot model's state) a step program handed
-        back, in front of the penalty counts at the end of ``result``; what
-        is left is what the host fetches, and the counts."""
-        if self._slot_model:
+        """Take the pool (and the slots' state, of a module's own programs)
+        a step program handed back, in front of the penalty counts at the
+        end of ``result``; what is left is what the host fetches, and the
+        counts."""
+        if self._own_programs:
             *rest, self.cache, self.slot_state, counts = result
         else:
             *rest, self.cache, counts = result
@@ -1788,8 +1870,8 @@ class JaxServingEngine(AsyncEngine):
         chunk_set = [
             (S, want_sample, want_history)
             for want_sample in sample_set
-            # a slot model's chunk program has one form (`_chunk`)
-            for want_history in ((True,) if self._slot_model else (False, True))
+            # a module's own chunk program has one form (`_chunk`)
+            for want_history in ((True,) if self._own_programs else (False, True))
         ] + [(rows, False, True) for rows in self._chunk_rungs if rows < S]
 
         def chunk_name(rows, want_sample, want_history):
@@ -1865,10 +1947,10 @@ class JaxServingEngine(AsyncEngine):
             lambda a: sd(a.shape, a.dtype), self.params_decode
         )
         cache_sd = jax.tree.map(lambda a: sd(a.shape, a.dtype), self.cache)
-        # the pool, and behind it a slot model's state
+        # the pool, and behind it the slots' state of a module's own programs
         pool_sd = (cache_sd,) + ((jax.tree.map(
             lambda a: sd(a.shape, a.dtype), self.slot_state
-        ),) if self._slot_model else ())
+        ),) if self._own_programs else ())
         counts_sd = jax.tree.map(
             lambda a: sd(a.shape, a.dtype), self._dummy_counts
         )
@@ -1888,7 +1970,10 @@ class JaxServingEngine(AsyncEngine):
                 self._chunk(False, False, want_sample, want_history, rows),
                 (p_sd, *pool_sd, counts_sd, sd((rows, C), jnp.int32),
                  sd((rows, C), jnp.int32), sd((rows, MB), jnp.int32), rvec,
-                 rvec, ctr, sd((2, rows), jnp.int32),
+                 rvec,
+                 # a module that drafts: the token that follows each position
+                 *((sd((rows, C), jnp.int32),) if self._device_drafts else ()),
+                 ctr, sd((2, rows), jnp.int32),
                  sd((4, rows), jnp.float32)) + wd_tail,
                 ("chunk", False, False, want_sample, want_history, rows),
             ))
@@ -1983,8 +2068,10 @@ class JaxServingEngine(AsyncEngine):
             # step loop never allocates drafter state. Multihost never
             # dispatches verify (followers only replay chunk/decode
             # opcodes), so it must not pay the index either.
-            seq.drafter = NgramDrafter(
-                seq.prompt, self._spec_k, self._spec_ngram
+            seq.drafter = (
+                DeviceDrafter(seq.prompt, self._spec_k)
+                if self._device_drafts
+                else NgramDrafter(seq.prompt, self._spec_k, self._spec_ngram)
             )
         with self._cond:
             self._pending.append(seq)
@@ -2722,6 +2809,9 @@ class JaxServingEngine(AsyncEngine):
         ipack_np = np.zeros((2, rows), np.int32)  # seeds, topk
         fpack_np = np.zeros((4, rows), np.float32)  # temp, topp, freqp, presp
         fpack_np[1] = 1.0
+        # for a module that drafts: the token that follows each position (the
+        # program puts the sampled one behind a prompt's last)
+        following = np.zeros((rows, C), np.int32) if self._device_drafts else None
         fed: List[Tuple[int, _Seq, List[int]]] = []  # lane, seq, its tokens
         filled: List[int] = []  # blocks this dispatch fills: they seal at its finish
         for r, (i, start, n) in enumerate(take):
@@ -2743,6 +2833,9 @@ class JaxServingEngine(AsyncEngine):
             filled += self._blocks_filled(seq.alloc, start, n)
             tokens[r, :n] = chunk_toks
             positions[r, :n] = np.arange(start, start + n)
+            if following is not None:
+                after = seq.prompt[start + 1 : start + n + 1]
+                following[r, : len(after)] = after
             fed.append((i, seq, chunk_toks))
         has_decode = any(
             s is not None and s.prefill_pos is None for s in self._slots
@@ -2804,7 +2897,9 @@ class JaxServingEngine(AsyncEngine):
         args = self._state_args(self.params) + (
             counts_in, self._put(tokens),
             self._put(positions), self._put(tables), self._put(sample_at),
-            self._put(lanes), self._put(np.int32(self._step_counter)),
+            self._put(lanes),
+            *((self._put(following),) if following is not None else ()),
+            self._put(np.int32(self._step_counter)),
             self._put(ipack_np), self._put(fpack_np),
         ) + self._wd_args()
         self._slow_fault()
@@ -2831,7 +2926,10 @@ class JaxServingEngine(AsyncEngine):
             self._straggler_tick("chunk", chunk.t_step, n_tokens)
 
     def _chunk_emit(self, chunk: _ChunkInflight, fetched) -> None:
-        if self._slot_model:
+        drafts = None
+        if self._device_drafts:
+            *fetched, drafts = fetched
+        if self._own_programs:
             *fetched, sums = fetched
             self._add_model_counters(sums)
         sampled_np = fetched[0]
@@ -2869,6 +2967,9 @@ class JaxServingEngine(AsyncEngine):
             self.prompts_prefilled += 1
             seq.first_token_t = time.perf_counter()
             self._emit_token(seq, tok, lpinfo=lpinfo)
+            if drafts is not None and seq.drafter is not None:
+                # the prediction module's choice for the token after ``tok``
+                seq.drafter.offer(int(drafts[r]), seq.total_len)
         self._sealing = None
 
     def _prepare_lanes(self) -> None:
@@ -2966,7 +3067,8 @@ class JaxServingEngine(AsyncEngine):
         with clock(P_DECODE_DISPATCH if clock.compile_key is None else P_COMPILE):
             *done, counts_out = self._take_state(fn(*args))
             clock.dispatched(1)
-        sums = done.pop() if self._slot_model else None
+        drafts = done.pop() if self._device_drafts else None
+        sums = done.pop() if self._own_programs else None
         out, *lp_out, toks2, pos2 = done
         lps, tids, tlps = lp_out if want_lp else (None, None, None)
         if want_pen:
@@ -2977,7 +3079,7 @@ class JaxServingEngine(AsyncEngine):
         prev, self._inflight = (
             self._inflight,
             _Inflight(out, lps, tids, tlps, toks2, pos2, live,
-                      self._take_sealing(filled), sums),
+                      self._take_sealing(filled), sums, drafts),
         )
         # start the host copies now: by the time this chunk is processed (one
         # pipelined dispatch later) the fetch has ridden the previous chunk's
@@ -3210,8 +3312,9 @@ class JaxServingEngine(AsyncEngine):
         with clock(P_DECODE_FETCH if defer_free else P_DRAIN):
             # dynlint: allow-host-sync(leader sync: pipelined fetch — the copy
             # rode the NEXT chunk's compute window, ~free by the time we get)
-            out, lps, tids, tlps, sums = jax.device_get(
-                (chunk.out, chunk.lps, chunk.top_ids, chunk.top_lps, chunk.sums)
+            out, lps, tids, tlps, sums, drafts = jax.device_get(
+                (chunk.out, chunk.lps, chunk.top_ids, chunk.top_lps, chunk.sums,
+                 chunk.drafts)
             )
             clock.fetched(1)
         out = np.asarray(out)  # [S, k_steps]
@@ -3224,12 +3327,17 @@ class JaxServingEngine(AsyncEngine):
                     # not live in this dispatch (empty, or prefilling then: its
                     # row is garbage, not tokens), or finished in an earlier chunk
                     continue
+                start = seq.total_len
                 self._emit_token_run(
                     seq,
                     [int(t) for t in out[i]],
                     (lps[i], tids[i], tlps[i]) if lps is not None else None,
                     defer_free=defer_free,
                 )
+                if drafts is not None and seq.drafter is not None:
+                    # the guess follows the dispatch's LAST token: of use
+                    # only to a lane that emitted them all (`DeviceDrafter`)
+                    seq.drafter.offer(int(drafts[i]), start + out.shape[1])
             self._sealing = None
         if self._perf is not None:
             self._perf.note_decode(
@@ -3363,6 +3471,10 @@ class JaxServingEngine(AsyncEngine):
             # this path is deliberately not pipelined)
             tgt_np, *lp_nps = jax.device_get(fetch)
             clock.fetched(1)
+        # a module's own program: its sums and, where it drafts, its guesses
+        next_np = lp_nps.pop() if self._device_drafts else None
+        if self._own_programs:
+            self._add_model_counters(lp_nps.pop())
         tgt_np = np.asarray(tgt_np)
         lp_np, tids_np, tlps_np = lp_nps if want_lp else (None, None, None)
         clock.steps[1] += 1
@@ -3399,12 +3511,17 @@ class JaxServingEngine(AsyncEngine):
             penalized = seq.penalized
             # emitted run: matched drafts + the bonus target, then the same
             # cut rules as _process_chunk (shared _emit_token_run tail)
+            start = seq.total_len
             n_emitted = self._emit_token_run(
                 seq,
                 [int(t) for t in row[: a + 1]],
                 (lp_np[i], tids_np[i], tlps_np[i])
                 if lp_np is not None else None,
             )
+            if next_np is not None and seq.drafter is not None:
+                # the prediction module's choice behind the last accepted
+                # token (position a's target): the next dispatch's draft
+                seq.drafter.offer(int(next_np[i, a]), start + a + 1)
             if want_pen and penalized:
                 # the scan added EVERY active position's target into this
                 # lane's count row (sequential exactness up to the first

@@ -190,6 +190,59 @@ def qwen3_next_config(mc: Dict[str, Any], dtype: Any = jnp.bfloat16):
     )
 
 
+def openpangu_config(mc: Dict[str, Any], dtype: Any = jnp.bfloat16):
+    """``model_type: pangu_ultra_moe``: latent attention in every layer with a
+    compressed query and a rotated key part, the sandwich norm, dense
+    feed-forwards in the first ``first_k_dense_replace`` layers and
+    sigmoid-routed experts beside one shared expert after them, one
+    multi-token-prediction module. ``n_routed_experts`` is what is HELD here of
+    the ``n_routed_experts_published`` the router scores (a card without the
+    second holds them all). What ``models/openpangu.py`` does not run is
+    refused by name."""
+    from dynamo_tpu.models.openpangu import OpenPanguConfig
+
+    def refuse(key: str, why: str):
+        raise ValueError(f"model_type 'pangu_ultra_moe' with {key} = {mc[key]!r}: models/openpangu.py {why}")
+
+    if mc.get("rope_scaling"):
+        refuse("rope_scaling", "rotates by rope_theta alone, with no softmax-scale correction")
+    if mc.get("attention_bias"):
+        refuse("attention_bias", "has no bias in its attention projections")
+    if not mc.get("sandwich_norm", True):
+        refuse("sandwich_norm", "runs four norms a layer (the sandwich)")
+    if int(mc.get("n_shared_experts", 1)) != 1:
+        refuse("n_shared_experts", "runs one shared expert beside the routed ones")
+    if int(mc.get("num_nextn_predict_layers", 1)) > 1:
+        refuse("num_nextn_predict_layers", "holds one multi-token-prediction module at most")
+    heads = int(mc["num_attention_heads"])
+    if int(mc.get("num_key_value_heads", heads)) != heads:
+        refuse("num_key_value_heads", "expands every head's keys from the one latent (= num_attention_heads)")
+    experts = int(mc["n_routed_experts"])
+    return OpenPanguConfig(
+        vocab_size=int(mc["vocab_size"]),
+        hidden_size=int(mc["hidden_size"]),
+        intermediate_size=int(mc["intermediate_size"]),
+        num_layers=int(mc["num_hidden_layers"]),
+        num_heads=heads,
+        q_lora_rank=int(mc["q_lora_rank"]),
+        kv_lora_rank=int(mc["kv_lora_rank"]),
+        qk_nope_head_dim=int(mc["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(mc["qk_rope_head_dim"]),
+        v_head_dim=int(mc["v_head_dim"]),
+        rope_theta=float(mc.get("rope_theta", 25600000.0)),
+        first_k_dense=int(mc.get("first_k_dense_replace", 3)),
+        moe_intermediate_size=int(mc["moe_intermediate_size"]),
+        num_experts=experts,
+        num_experts_published=int(mc.get("n_routed_experts_published", experts)),
+        num_experts_per_tok=int(mc["num_experts_per_tok"]),
+        routed_scaling_factor=float(mc.get("routed_scaling_factor", 2.5)),
+        moe_renormalize=bool(mc.get("norm_topk_prob", True)),
+        num_mtp_layers=int(mc.get("num_nextn_predict_layers", 1)),
+        rms_norm_eps=float(mc.get("rms_norm_eps", 1e-5)),
+        dtype=dtype,
+    )
+
+
 def config_from_card(card: ModelDeploymentCard, dtype: Any = jnp.bfloat16):
     """Derive the model's config from the card's HF config.json contents: a
     LlamaConfig, or by ``model_type`` another module's (models.module_for),
@@ -203,6 +256,8 @@ def config_from_card(card: ModelDeploymentCard, dtype: Any = jnp.bfloat16):
         return lfm2_config(mc, dtype)
     if mc.get("model_type") == "qwen3_next":
         return qwen3_next_config(mc, dtype)
+    if mc.get("model_type") == "pangu_ultra_moe":
+        return openpangu_config(mc, dtype)
     if "num_experts" in mc and "num_local_experts" not in mc:
         # an expert model of a family this tree has no module for: a
         # LlamaConfig of it would be a dense impostor under its name

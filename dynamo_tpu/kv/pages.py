@@ -54,6 +54,9 @@ class StateNotPortable(MigrationRejected):
 # chained into a block's crc and laid into a frame.
 _NATIVE = ("k", "v")
 _INT8 = ("k", "v", "k_scale", "v_scale")
+# a latent-attention pool (ops/latent.py): ONE member, a token's normed latent
+# and shared key part in a row
+_LATENT = ("latent",)
 
 
 def members(pages: Mapping[str, Any]) -> List[str]:
@@ -246,7 +249,7 @@ def verify(pages: Mapping[str, Any], crcs: Optional[Sequence[Optional[int]]],
 
 def _wire_members(pages: Mapping[str, Any]) -> Tuple[str, ...]:
     names = tuple(members(pages))
-    if names not in (_NATIVE, _INT8):
+    if names not in (_NATIVE, _INT8, _LATENT):
         raise KvDtypeMismatch("no wire form for pages of " + "/".join(names))
     return names
 
@@ -258,8 +261,8 @@ def arrays(pages: Mapping[str, Any]) -> List[Any]:
 
 def from_arrays(pulled: Sequence[Any]) -> Pages:
     """Inverse of :func:`arrays`, for a list the device plane pulled: two
-    arrays are a native set, four an int8 one."""
-    names = {len(_NATIVE): _NATIVE, len(_INT8): _INT8}.get(len(pulled))
+    arrays are a native set, four an int8 one, one a latent one."""
+    names = {len(_NATIVE): _NATIVE, len(_INT8): _INT8, len(_LATENT): _LATENT}.get(len(pulled))
     if names is None:
         raise KvDtypeMismatch(f"no page set is {len(pulled)} arrays")
     return dict(zip(names, pulled))
@@ -276,11 +279,20 @@ def pack(pages: Mapping[str, Any], crcs: Optional[Sequence[int]] = None
     checksums, docs/resilience.md §Silent corruption) is the same kind of
     optional extension: frames without it — pre-integrity peers,
     DYN_TPU_KV_INTEGRITY=0 senders — still parse everywhere; receivers
-    simply cannot verify them."""
+    simply cannot verify them. A latent set (one member) is its one segment
+    under ``members: ["latent"]`` and WITHOUT ``k_bytes``: a peer that knows
+    only k and v fails on the missing key and never injects."""
     names = _wire_members(pages)
     # bfloat16 is no standard numpy dtype everywhere: raw bytes and a dtype
     # string (ml_dtypes gives numpy bfloat16 in this stack)
     raw = [np.asarray(pages[m]).tobytes() for m in names]
+    if names == _LATENT:
+        latent = pages["latent"]
+        header = {"members": list(names), "dtype": latent.dtype.name,
+                  "shape": list(latent.shape)}
+        if crcs is not None:
+            header["crcs"] = [int(c) for c in crcs]
+        return header, raw[0]
     k = pages["k"]
     header = {
         "dtype": k.dtype.name, "shape": list(k.shape), "k_bytes": len(raw[0]),
@@ -298,7 +310,8 @@ def pack(pages: Mapping[str, Any], crcs: Optional[Sequence[int]] = None
 
 def unpack(header: Mapping[str, Any], body: bytes) -> Pages:
     """Inverse of :func:`pack`: a native set for a frame without
-    ``kv_dtype`` (a pre-int8 peer's included), an int8 set otherwise."""
+    ``kv_dtype`` (a pre-int8 peer's included), an int8 set otherwise, a
+    latent set for a frame that names its one member."""
     import ml_dtypes  # noqa: F401  (registers bfloat16 with numpy)
 
     def segment(at, size, dtype, shape):
@@ -306,6 +319,8 @@ def unpack(header: Mapping[str, Any], body: bytes) -> Pages:
             body[at : at + size], dtype=np.dtype(dtype)
         ).reshape(shape)
 
+    if tuple(header.get("members") or ()) == _LATENT:
+        return {"latent": segment(0, len(body), header["dtype"], header["shape"])}
     n, dt, shape = header["k_bytes"], header["dtype"], header["shape"]
     pages = {"k": segment(0, n, dt, shape), "v": segment(n, n, dt, shape)}
     if header.get("kv_dtype") == "int8":

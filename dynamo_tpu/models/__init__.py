@@ -30,10 +30,16 @@ def module_for(model_config):
     several rows of one chunk dispatch (``LANE_TAKES_ROWS = True``: its chunk
     program takes the rows' lanes and lets a row attend the fresh keys of the
     earlier rows of its lane; a module that says nothing keeps one row a
-    lane). A module whose layers keep state per slot beside
-    the pages also has ``make_slot_state``, ``forward_chunk`` and ``decode``
-    in the form ``engine_jax/engine.py`` calls them with the state, and
-    ``COUNTERS`` (docs/kv_cache_manager.md, "State per slot"). Such a module
+    lane). A module that brings its OWN step programs has ``COUNTERS`` and
+    ``forward_chunk`` / ``decode`` in the form ``engine_jax/engine.py`` calls
+    them, with the slots' state in and out; a module whose layers keep state
+    per slot beside the pages ALSO has ``make_slot_state``, and only such a
+    module is refused whatever hands pages over without that state
+    (docs/kv_cache_manager.md, "State per slot": the two facts and the table).
+    ``models/openpangu.py`` has the first and not the second (latent pages and
+    nothing else: ``state`` is ``None``), and a prediction module of its own,
+    which ``draft_chunk`` and ``decode(..., draft=True)`` run where the engine
+    drafts. A module with state
     may set ``LANE_TAKES_ROWS`` once its chunk program, under the full width,
     (1) starts a row whose lane is that of the row above it from what that
     row leaves and not from the slot's stored state, (2) lets a lane's LAST

@@ -19,7 +19,7 @@ Two kinds of state live side by side:
 Every mechanism but two is plain ``jax.numpy`` through XLA (the expert layer's
 products and a chunk's KDA recurrence are kernels, below). The weights are bfloat16
 and the activations float32 from the embedding to the head, with every product
-against a weight taken at float32's precision (:func:`wdot` says why: a
+against a weight taken at float32's precision (``ops/latent.py:wdot`` says why: a
 router's choice of 8 among 256 scores is a discontinuity, and bfloat16's
 noise on its input swaps experts in several token-layers of a hundred). That
 arithmetic is this module's alone: no shared op takes it up (a deployment
@@ -52,8 +52,12 @@ import jax.numpy as jnp
 
 from dynamo_tpu.models.llama import embed_lookup, history_tiles_full, rms_norm
 from dynamo_tpu.ops import moe
+from dynamo_tpu.ops.latent import (
+    PASSES, attend_absorbed, cached_latent, gather_latent as _gather_latent, mm as _mm,
+    write_latent as _write_latent,
+)
 from dynamo_tpu.ops.pallas.kda_scan import kda_scan, kda_step as _kda_step
-from dynamo_tpu.ops.parts import HIGHEST, operand_parts
+from dynamo_tpu.ops.parts import operand_parts
 
 Params = Dict[str, Any]
 KVCache = Dict[str, jax.Array]  # {"latent": [L_mla, N, bs, rank + rope]} float32
@@ -72,54 +76,10 @@ ROWS_AT_ONCE = 16
 MOE_COUNTERS = COUNTERS.index("kda_chunk_tokens")  # the first: what ops/moe.py:dropless_experts counts
 
 
-# -- products of float32 activations against bfloat16 weights ------------------
-#
-# The MXU multiplies bfloat16. A float32 activation handed to it is rounded to
-# 8 bits of mantissa first (a relative error of up to 2^-9), and that is the
-# noise a bfloat16 program carries from layer to layer. A dense model's answer
-# moves with it smoothly. An expert model's does not: the router keeps the
-# ``top_k`` largest of several hundred scores, the ninth lies a few percent of
-# their spread below the eighth, and noise of a few tenths of a percent on the
-# router's input swaps the two in several token-layers of a hundred: another
-# expert computes, and the token's hidden state moves by a tenth (PERF.md: the
-# model's section). So this model keeps its activations in float32 on the whole
-# path to its routers: the activation is split into ``PASSES`` bfloat16 parts
-# (what is left of it after the parts before, rounded again), every part is
-# multiplied exactly as bfloat16 against the weight (which IS bfloat16, so it
-# needs no parts) and the products are added in float32. Three parts carry 24
-# bits: float32's own.
-#
-# The weights are read once where it matters: a small activation (a decode
-# step, which streams the weights and computes little) has its parts stacked
-# into one product; a large one (a chunk of prompt, where the products are the
-# work) takes one product a part, so that only one part's output is held.
-
-PASSES = 3
+# The arithmetic (float32 activations in ``PASSES`` bfloat16 parts against
+# bfloat16 weights: ``wdot``, ``_mm``) is ``ops/latent.py``'s, shared with
+# ``models/openpangu.py``; it says why.
 _expert_parts = partial(operand_parts, parts=PASSES)  # ops/moe.py:dropless_experts' ``parts_of``
-# activations of at most this many elements are stacked into one product
-STACK_UP_TO = 1 << 24
-
-
-def wdot(spec: str, x: jax.Array, w: jax.Array) -> jax.Array:
-    """``einsum(spec, x, w)`` in float32 for a float32 ``x``: the sum of its
-    :func:`operand_parts`' products, at the highest precision where they are
-    float32."""
-    parts = operand_parts(x.astype(jnp.float32), w.dtype, PASSES)
-    w = w.astype(parts[0].dtype)
-    precision = HIGHEST if w.dtype == jnp.float32 else None
-    if x.size <= STACK_UP_TO:
-        ins, out = spec.split("->")
-        both = jnp.einsum(f"Z{ins}->Z{out}", jnp.stack(parts), w, precision=precision,
-                          preferred_element_type=jnp.float32)
-        return both.sum(axis=0)
-    # the smallest part first: the sum loses least
-    return sum(jnp.einsum(spec, part, w, precision=precision, preferred_element_type=jnp.float32)
-               for part in reversed(parts))
-
-
-def _mm(x: jax.Array, w: jax.Array) -> jax.Array:
-    """``x @ w`` in float32: the activation against a weight matrix."""
-    return wdot("...e,ef->...f", x, w)
 
 
 def lm_head(params: Params, config: "KimiLinearConfig", h: jax.Array) -> jax.Array:
@@ -382,31 +342,19 @@ def kda_mixer(lp: Params, c: KimiLinearConfig, x: jax.Array, valid: jax.Array,
 
 def mla_latent(lp: Params, c: KimiLinearConfig, x: jax.Array) -> jax.Array:
     """What the cache holds of a token: ``[RMSNorm(c) ; k^r]``."""
-    kv = _mm(x, lp["w_kva"])
-    lat = rms_norm(kv[..., :c.kv_lora_rank], lp["kv_norm"], c.rms_norm_eps)
-    return jnp.concatenate([lat, kv[..., c.kv_lora_rank:]], axis=-1)
+    return cached_latent(x, lp["w_kva"], lp["kv_norm"], c.kv_lora_rank, c.rms_norm_eps)
 
 
 def mla_attend(lp: Params, c: KimiLinearConfig, x: jax.Array, latent: jax.Array,
                mask: jax.Array) -> jax.Array:
-    """Absorbed latent attention: queries of ``x`` ``[B, T, E]`` against the
-    cached ``latent`` ``[B, P, rank + rope]`` under ``mask`` ``[B, T, P]``. No
-    rotation anywhere (``mla_use_nope``): position comes from the KDA layers."""
+    """Absorbed latent attention (``ops/latent.py``): queries of ``x`` ``[B, T,
+    E]`` against the cached ``latent`` ``[B, P, rank + rope]`` under ``mask``
+    ``[B, T, P]``. No rotation anywhere (``mla_use_nope``): position comes from
+    the KDA layers."""
     b, t, _ = x.shape
-    h, r, dn, dv = c.num_heads, c.kv_lora_rank, c.qk_nope_head_dim, c.v_head_dim
-    q = _mm(x, lp["wq"]).reshape(b, t, h, c.qk_head_dim)
-    w_kvb = lp["w_kvb"].reshape(r, h, dn + dv)
-    # the query's no-position part, taken into the latent space by W_kvb's key half
-    q_lat = wdot("bthd,rhd->bthr", q[..., :dn], w_kvb[..., :dn])
-    q_all = jnp.concatenate([q_lat, q[..., dn:]], axis=-1)  # [B, T, H, rank + rope]
-    scores = wdot("bthc,bpc->bhtp", q_all, latent) * c.qk_head_dim ** -0.5
-    scores = jnp.where(mask[:, None], scores, -jnp.inf)
-    top = jnp.maximum(scores.max(axis=-1, keepdims=True), -1e30)
-    p = jnp.exp(scores - top)
-    p = p / jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-30)
-    out_lat = wdot("bhtp,bpr->bthr", p, latent[..., :r])
-    out = wdot("bthr,rhd->bthd", out_lat, w_kvb[..., dn:])
-    return _mm(out.reshape(b, t, h * dv), lp["wo"])
+    q = _mm(x, lp["wq"]).reshape(b, t, c.num_heads, c.qk_head_dim)
+    return attend_absorbed(q, lp["w_kvb"], lp["wo"], latent, mask, c.kv_lora_rank,
+                           c.qk_nope_head_dim, c.v_head_dim, c.qk_head_dim ** -0.5)
 
 
 # -- feed-forward -------------------------------------------------------------
@@ -435,33 +383,6 @@ def feed_forward(lp: Params, c: KimiLinearConfig, layer: int, x: jax.Array, vali
 
 
 # -- the step programs --------------------------------------------------------
-
-def _page_rows(positions, block_tables, num_blocks: int, block_size: int, layer: int, layers: int):
-    """Row of each position in the ``[L * N * bs, ...]`` view of the pool for
-    MLA layer ``layer``; padding gets the row past the pool (dropped)."""
-    from dynamo_tpu.ops.attention import _page_rows as rows_of
-
-    rows = rows_of(positions, block_tables, num_blocks, block_size)
-    per_layer = num_blocks * block_size
-    return jnp.where(rows < per_layer, layer * per_layer + rows, layers * per_layer)
-
-
-def _write_latent(pool: jax.Array, layer: int, new: jax.Array, positions, block_tables):
-    """Scatter ``new`` ``[B, T, D]`` into MLA layer ``layer`` of the pool under
-    ONE flat row index (ops/attention.py ``write_kv_to_pool`` says why)."""
-    l, n, bs, d = pool.shape
-    rows = _page_rows(positions, block_tables, n, bs, layer, l).reshape(-1)
-    flat = pool.reshape(l * n * bs, d).at[rows].set(
-        new.reshape(-1, d).astype(pool.dtype), mode="drop")
-    return flat.reshape(pool.shape)
-
-
-def _gather_latent(pool: jax.Array, layer: int, block_tables) -> jax.Array:
-    """A lane's pages of MLA layer ``layer`` as ``[B, MB * bs, D]``."""
-    l, n, bs, d = pool.shape
-    pages = pool.reshape(l * n, bs, d)[layer * n + block_tables]
-    return pages.reshape(block_tables.shape[0], -1, d)
-
 
 def forward_chunk(
     params: Params, config: KimiLinearConfig, tokens: jax.Array, positions: jax.Array,

@@ -922,3 +922,119 @@ def test_qwen3_nexts_step_programs_copy_neither_the_pool_nor_the_state_nor_an_ex
     # steps; a chunk's groups hold what 8 rows need, and at 64 rows the rows' new states (0.8 GB)
     limit = {("decode", 64): 2_600_000_000, ("chunk", 8): 1_200_000_000, ("chunk", 64): 2_200_000_000}
     assert memory.temp_size_in_bytes < limit[program, rows], memory.temp_size_in_bytes
+
+
+@functools.lru_cache(maxsize=None)
+def _compile_openpangu(program, one_chip, rows=8, draft=False):
+    """``models/openpangu.py``'s decode or chunk program at
+    ``batch.openpangu-ultra-moe-718b``'s served shapes (1 dense + 4 expert
+    layers of the 61, 8 of 256 experts and 38,400 vocabulary rows held, the
+    prediction module, 64 slots, block 16, 12,288 blocks, 2,048 positions; a
+    chunk of ``rows`` x 128 tokens), the pool donated, for the described chip.
+    ``draft``: the ``spec_k`` > 0 variants, the module run and its sixth layer
+    of pages allocated."""
+    from dynamo_tpu.models import openpangu as op
+
+    c = op.OpenPanguConfig(vocab_size=38400, num_layers=5, first_k_dense=1, num_experts=8)
+    slots, mb, chunk = 64, 128, 128
+
+    def sd(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = jax.tree.map(sd, jax.eval_shape(lambda: op.init_params(jax.random.PRNGKey(0), c)))
+    cache = jax.tree.map(sd, jax.eval_shape(lambda: op.make_kv_cache(c, 12288, 16, drafting=draft)))
+    if program == "decode":
+        def greedy(logits, pos, carry, k):
+            return jnp.argmax(logits, -1).astype(jnp.int32), carry, jnp.argmax(logits, -1)
+
+        return jax.jit(
+            lambda p, kv, toks, pos, tables: op.decode(
+                p, c, toks, pos, kv, tables, None, 4, 2047, greedy, 0, draft=draft),
+            donate_argnums=(1,),
+        ).lower(params, cache, i32(slots), i32(slots), i32(slots, mb)).compile()
+
+    def chunk_fn(p, kv, toks, pos, tables, lanes, following):
+        x, kv, _, sums = op.forward_chunk(p, c, toks, pos, kv, tables, None, lanes, raw=draft)
+        if not draft:
+            return x, kv, sums
+        hd, kv, more = op.draft_chunk(p, c, x, following, pos, kv, tables)
+        return op.final_norm(p, c, x), hd, kv, sums + more
+
+    return jax.jit(chunk_fn, donate_argnums=(1,)).lower(
+        params, cache, i32(rows, chunk), i32(rows, chunk), i32(rows, mb), i32(rows),
+        i32(rows, chunk)).compile()
+
+
+@pytest.mark.timeout(400)
+@pytest.mark.parametrize("program, rows, draft", [
+    ("decode", 64, False), ("chunk", 8, False), ("chunk", 64, False), ("decode", 64, True), ("chunk", 8, True)],
+    ids=["decode", "chunk_8_rows", "chunk_64_rows", "decode_drafting", "chunk_8_rows_drafting"])
+def test_openpangus_step_programs_neither_pad_nor_copy_the_latent_pool(monkeypatch, one_chip, program, rows, draft):
+    """``models/openpangu.py`` at ``batch.openpangu-ultra-moe-718b``'s served
+    shapes, for the chip's compiler: the programs fit beside 8.89 GB of weights
+    and 2.52 GB of float32 latent pages (3.02 with the prediction module's
+    layer); NO instruction copies the pool ``[5, 12288, 16, 640]`` or a view of
+    it, and the pool is not padded: its row is five whole registers of 128
+    lanes (a 576-wide row, 4.5 registers, is padded to 640 by the compiler AND
+    copied whole twice a chunk dispatch, once into a transposed layout: 2.3 GB
+    a copy; Kimi-Linear's one layer pays 0.113 s of 4 s for it: ROADMAP M9b);
+    none copies an expert layer's matrices; the grouped product is in the
+    program three times an expert layer; the pool is donated and written in
+    place."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels compiled, not interpreted
+    compiled = _compile_openpangu(program, one_chip, rows, draft)
+    hlo = re.sub(r"/\*.*?\*/", "", compiled.as_text())
+    layers = 6 if draft else 5
+    views = rf"{layers},12288,16,640|{layers * 12288},16,640|{layers * 12288 * 16},640"
+    big = re.findall(
+        rf"= (?:f32|bf16)\[(?:{views}|8,7680,2048|8,2048,7680)\]\{{[^}}]*\}} copy\(", hlo)
+    assert big == [], big
+    # not padded: every pool-shaped value lies in the plain tiled layout of its own shape, and the
+    # arguments (weights + pool) are their elements' bytes
+    layouts = set(re.findall(rf"= f32\[(?:{views})\](\{{[^}}]*\}})", hlo))
+    assert layouts and all("T(8,128)" in x for x in layouts), layouts
+    memory = compiled.memory_analysis()
+    pool_bytes = layers * 12288 * 16 * 640 * 4
+    assert memory.alias_size_in_bytes >= pool_bytes
+    kernels = len(re.findall(r"custom_call_target=\"tpu_custom_call\"", hlo))
+    expert_layers = 5 if draft else 4
+    assert "grouped_product" in hlo
+    assert kernels == (3 * expert_layers if program == "chunk" else 3 * expert_layers), kernels
+    # beside the arguments: a decode dispatch's dense histories (0.34 GB a layer) and its steps; a
+    # chunk's groups hold what 512 positions need (128 heads' scores over 2,048 keys: 0.54 GB)
+    limit = {("decode", False): 2_300_000_000, ("decode", True): 2_700_000_000,
+             ("chunk", False): 1_700_000_000, ("chunk", True): 2_000_000_000}
+    assert memory.temp_size_in_bytes < limit[program, draft], memory.temp_size_in_bytes
+    weights = 8_890_675_200 if draft else 8_890_675_200 - 2 * 741_235_200
+    assert memory.temp_size_in_bytes + pool_bytes + weights < 15_750_000_000
+
+
+def test_a_576_wide_latent_row_is_padded_and_copied_by_the_chips_compiler(monkeypatch, one_chip):
+    """The guard's own control: the same chunk program over a pool whose row is
+    the 576 values held and no more (``LANES`` = 64) compiles with copies of
+    the WHOLE pool in it, which is why the module pads the row itself."""
+    from dynamo_tpu.models import openpangu as op
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(op, "LANES", 64)
+    c = op.OpenPanguConfig(vocab_size=38400, num_layers=5, first_k_dense=1, num_experts=8)
+    assert c.latent_width == 576
+
+    def sd(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = jax.tree.map(sd, jax.eval_shape(lambda: op.init_params(jax.random.PRNGKey(0), c)))
+    cache = jax.tree.map(sd, jax.eval_shape(lambda: op.make_kv_cache(c, 12288, 16)))
+    compiled = jax.jit(
+        lambda p, kv, toks, pos, tables, lanes: op.forward_chunk(p, c, toks, pos, kv, tables, None, lanes),
+        donate_argnums=(1,),
+    ).lower(params, cache, i32(8, 128), i32(8, 128), i32(8, 128), i32(8)).compile()
+    hlo = re.sub(r"/\*.*?\*/", "", compiled.as_text())
+    copies = re.findall(r"= f32\[(?:5,12288,16,576|61440,16,576|983040,576)\]\{[^}]*\} copy\(", hlo)
+    assert copies, "the compiler no longer copies a 576-wide pool: the padding may go (ROADMAP M9b)"

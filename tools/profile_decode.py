@@ -9,7 +9,10 @@ by routing and by grouped product, beside its own roofline
 over a chunk, the scan over the tokens beside the kernel that keeps the state
 on the chip (:func:`kda_profiles`); ``... mamba`` times a Mamba layer's
 selective scan ALONE over a chunk and over a decode step beside the whole mixer
-(:func:`mamba_profiles`); without a word, the round-5 ablation below.
+(:func:`mamba_profiles`); ``... mla`` times ONE layer's absorbed latent
+attention ALONE at ``batch.openpangu-ultra-moe-718b``'s two shapes, beside its
+bytes and operations (:func:`mla_profiles`); without a word, the round-5
+ablation below.
 
 Method notes:
 - every measurement chains computations via data dependencies and fences
@@ -915,6 +918,78 @@ def chunk_program_profile(jamba, d_inner):
             print(line, flush=True)
 
 
+def mla_profiles():
+    """ONE layer's absorbed latent attention of ``openpangu-ultra-moe-718b``
+    (``ops/latent.py:attend_absorbed`` as ``models/openpangu.py`` calls it: 128
+    heads, a latent of 512 + 64 in a row of 640, float32, ``W_kvb`` and ``W_o``
+    bf16) ALONE, over a dense history of 2,048 positions a lane: at a decode
+    step's 64 lanes x 1 token and at a chunk's 8 x 128 rows (two groups of 512
+    positions, as the chunk program takes them: PROF_ROWS, default 4 rows a
+    group), every lane's table attended whole under the mask as the programs do
+    today, at a context of PROF_CONTEXT positions (default 320, the traffic's
+    mean). PROF_ITERS (default 8) layers chained in one dispatch. Beside each
+    time: the bytes the call must read (the live part of the history, twice:
+    scores and values, ``W_kvb`` and ``W_o``) and what it does read (the whole
+    table), the operations it needs (the live keys) and does (all 2,048), and
+    their shares of the chip's peaks. PROF_HEADS (default 128) cuts the heads
+    for a rehearsal on the CPU."""
+    from benchmark import bytes_and_flops
+
+    on_chip = jax.default_backend() == "tpu"
+    peaks = bytes_and_flops.load_peaks(jax.devices()[0].device_kind) if on_chip else {}
+    from dynamo_tpu.engine_jax.compile_cache import enable_compile_cache
+    from dynamo_tpu.ops.latent import attend_absorbed
+
+    enable_compile_cache()
+    n_iter = int(os.environ.get("PROF_ITERS", "8"))
+    h = int(os.environ.get("PROF_HEADS", "128"))
+    context = int(os.environ.get("PROF_CONTEXT", "320"))
+    group = int(os.environ.get("PROF_ROWS", "4"))
+    r, dn, dr, dv, w, e, table = 512, 128, 64, 128, 640, 7680, 2048
+    key = jax.random.split(jax.random.PRNGKey(0), 5)
+    w_kvb = (jax.random.normal(key[0], (r, h * (dn + dv)), jnp.float32) / r ** 0.5).astype(jnp.bfloat16)
+    wo = (jax.random.normal(key[1], (h * dv, e), jnp.float32) / (h * dv) ** 0.5).astype(jnp.bfloat16)
+
+    for name, b, t in (("a decode step, 64 lanes x 1 token", 64, 1),
+                       (f"a chunk group, {group} rows x 128 tokens", group, 128)):
+        q = jax.random.normal(key[2], (b, t, h, dn + dr), jnp.float32)
+        latent = jax.random.normal(key[3], (b, table, w), jnp.float32).at[..., r + dr:].set(0.0)
+        first = context - t + jnp.arange(t)  # the queries stand at the context's last t positions
+        mask = jnp.broadcast_to(jnp.arange(table)[None, None, :] <= first[None, :, None], (b, t, table))
+
+        @jax.jit
+        def chain(q, latent, mask, w_kvb, wo):
+            def layer(q, _):
+                y = attend_absorbed(q, w_kvb, wo, latent, mask, r, dn, dv, (dn + dr) ** -0.5)
+                return q + 1e-3 * y[..., None, :dn + dr], y[:, :, 0]
+            return jax.lax.scan(layer, q, None, length=n_iter)
+
+        args = (q, latent, mask, w_kvb, wo)
+        chain(*args)[0].block_until_ready()
+        times = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            chain(*args)[0].block_until_ready()
+            times.append(time.perf_counter() - t0)
+        ms = float(np.median(times)) * 1e3 / n_iter
+        weights = 2 * (w_kvb.size + wo.size)
+        need_bytes = 2 * b * context * (r + dr) * 4 + weights
+        read_bytes = 2 * b * table * w * 4 + weights
+        per_key = 2 * h * (2 * r + dr)  # a score over rank + rope, a value over rank, a head
+        through = 2 * b * t * (h * dn * r + h * r * dv + h * dv * e)  # the two halves of W_kvb, W_o
+        need_ops, done_ops = b * t * per_key * context + through, b * t * per_key * table + through
+        line = (f"mla {name}, context {context} of {table}: {ms:8.3f} ms a layer; must read "
+                f"{need_bytes / 1e6:7.1f} MB and reads {read_bytes / 1e6:7.1f}; needs {need_ops / 1e9:7.2f} "
+                f"GFLOP and does {done_ops / 1e9:7.2f}")
+        if on_chip:
+            line += (f"; of the chip's bytes a second {need_bytes / peaks['hbm_bytes_per_s'] / ms * 1e3:5.1%} "
+                     f"needed, {read_bytes / peaks['hbm_bytes_per_s'] / ms * 1e3:5.1%} read; of its bf16 "
+                     f"operations a second {need_ops / peaks['bf16_flops_per_s'] / ms * 1e3:5.1%} needed, "
+                     f"{done_ops / peaks['bf16_flops_per_s'] / ms * 1e3:5.1%} done (float32 at the highest "
+                     f"precision is six bfloat16 passes a product: a sixth of this peak is its ceiling)")
+        print(line, flush=True)
+
+
 if __name__ == "__main__":
     {"history": history_profiles, "experts": expert_profiles, "kda": kda_profiles,
-     "mamba": mamba_profiles}.get(" ".join(sys.argv[1:2]), main)()
+     "mamba": mamba_profiles, "mla": mla_profiles}.get(" ".join(sys.argv[1:2]), main)()
