@@ -11,6 +11,10 @@ the pool's one member ``latent`` and nothing else, ``[L, N, bs, W]`` float32
 values, in a row of ``W`` = 640 = five registers of 128 lanes; a 576-wide row
 is 4.5 registers, and the chip's compiler then pads the pool AND copies it
 whole in front of every dispatch's gather: ``tests/test_aot_compile_tpu.py``).
+A chunk's rows (``forward_chunk``, ``draft_chunk``, so ``verify`` too) read
+their block tables a tile of positions a trip and only as far as the group's
+last position (``ops/latent.py:attend_absorbed_tiled``); a decode dispatch
+gathers every lane's whole table once and attends it under a mask.
 So it has no ``make_slot_state``, the engine hands its programs ``state =
 None`` and takes None back, and everything that hands pages over (a prefix hit,
 ``verify``, preemption, the host tier, a transfer) is open to it as to
@@ -50,10 +54,12 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu.models.llama import apply_rope, embed_lookup, history_tiles_full, rms_norm
+from dynamo_tpu.models.llama import (
+    apply_rope, embed_lookup, history_tile, history_tiles_full, rms_norm,
+)
 from dynamo_tpu.ops import moe
 from dynamo_tpu.ops.latent import (
-    PASSES, attend_absorbed, cached_latent, gather_latent, mm, write_latent,
+    PASSES, attend_absorbed, attend_absorbed_tiled, cached_latent, gather_latent, mm, write_latent,
 )
 from dynamo_tpu.ops.parts import operand_parts
 
@@ -64,13 +70,14 @@ KVCache = Dict[str, jax.Array]  # {"latent": [L (+ 1 where the engine drafts), N
 COUNTERS = ("moe_layer_calls", "moe_held_rows", "moe_experts_hit", "moe_routed_pairs",
             "moe_rows_computed", "moe_expert_reads",
             # latent attention, summed over the layers' calls: the cached positions a call's
-            # rows attended (every row's whole table) and, of those, the ones that held history
+            # rows attended (a decode lane its whole table, a chunk's row the tiles its group
+            # reads) and, of those, the ones that held history
             "mla_layer_calls", "mla_history_positions_read", "mla_history_positions_live",
             "mtp_layer_calls")
 MOE_COUNTERS = COUNTERS.index("mla_layer_calls")  # the first: what ops/moe.py:dropless_experts counts
 # positions of a chunk computed at once: the rows are independent, and more are taken in groups,
-# which bounds what the program holds beside its arguments (128 heads' scores against a whole
-# table are 1 MB of float32 a position)
+# which bounds what the program holds beside its arguments (128 heads' scores against a tile of
+# 256 keys are 128 KB of float32 a position)
 TOKENS_AT_ONCE = 512
 LANES = 128  # of a register: the pool's rows are whole registers wide
 
@@ -212,11 +219,18 @@ def make_kv_cache(
         dtype or jnp.float32)}
 
 
-def chunk_history_tiles(positions, block_size: int, table_blocks: int) -> int:
-    """Tiles of a block table a chunk dispatch reads, for the host's count
-    (``models/llama.py`` has the form): ``ops/latent.py:attend_absorbed`` scores
-    every row's whole table, whatever it holds."""
-    return history_tiles_full(block_size, table_blocks)
+def chunk_history_tiles(positions, block_size: int, table_blocks: int):
+    """Trips of the chunk programs' loop over a block table (``ops/latent.py:
+    attend_absorbed_tiled``) for rows at ``positions`` ``[B, C]``: the tiles of
+    ``models/llama.py:history_tile`` positions up to the one that holds the
+    rows' last position (the rows' fresh latents are in the pool before the
+    loop, so it reads them there), none for padding rows alone, and no more
+    than cover a table. A group of rows makes its own trips; over a whole
+    dispatch this is its longest row's, which is the host's count. Written for
+    a traced array and a numpy one alike, as ``models/llama.py``'s."""
+    tile = history_tile(block_size, table_blocks)
+    reach = (positions.max() + 1).clip(0, table_blocks * block_size)
+    return (reach + tile - 1) // tile
 
 
 def decode_history_tiles(base, block_size: int, table_blocks: int) -> int:
@@ -264,12 +278,19 @@ def feed_forward(lp: Params, c: OpenPanguConfig, x: jax.Array, valid: jax.Array)
         return y.reshape(b, t, e), stats
 
 
-def _layer(lp: Params, c: OpenPanguConfig, x: jax.Array, positions: jax.Array, mask: jax.Array,
-           width: int, keys_of):
+def _absorbed(lp: Params, c: OpenPanguConfig):
+    """What ``ops/latent.py``'s two forms of absorbed attention take of a layer
+    around the keys: (``W_kvb``, ``W_o``) in front, (rank, no-position width,
+    value width, the scores' scale) behind."""
+    return ((lp["w_kvb"], lp["wo"]),
+            (c.kv_lora_rank, c.qk_nope_head_dim, c.v_head_dim, c.qk_head_dim ** -0.5))
+
+
+def _layer(lp: Params, c: OpenPanguConfig, x: jax.Array, positions: jax.Array, width: int, attend):
     """One decoder layer over ``x`` ``[B, T, E]`` at ``positions`` ``[B, T]``
-    (< 0: padding). ``keys_of(latent [B, T, W]) -> [B, P, W]`` takes the
-    tokens' cache entries where they belong and hands back what the queries
-    attend under ``mask`` ``[B, T, P]``. Returns (x, the expert counters)."""
+    (< 0: padding). ``attend(lp, q [B, T, H, nope + rope], latent [B, T, W])
+    -> [B, T, E]`` takes the tokens' cache entries where they belong and
+    attends what the queries see. Returns (x, the expert counters)."""
     eps = c.rms_norm_eps
     valid = positions >= 0
     a = rms_norm(x, lp["in_norm"], eps)
@@ -281,21 +302,21 @@ def _layer(lp: Params, c: OpenPanguConfig, x: jax.Array, positions: jax.Array, m
         q = mm(c_q, lp["w_qb"]).reshape(b, t, c.num_heads, c.qk_head_dim)
         dn = c.qk_nope_head_dim
         q = jnp.concatenate([q[..., :dn], apply_rope(q[..., dn:], positions, c.rope_theta)], axis=-1)
-        attn = attend_absorbed(q, lp["w_kvb"], lp["wo"], keys_of(latent), mask, c.kv_lora_rank,
-                               dn, c.v_head_dim, c.qk_head_dim ** -0.5)
+        attn = attend(lp, q, latent)
     x = x + rms_norm(attn, lp["post_attn_norm"], eps)
     y, stats = feed_forward(lp, c, rms_norm(x, lp["pre_mlp_norm"], eps), valid)
     return x + rms_norm(y, lp["post_mlp_norm"], eps), stats
 
 
-def _mla_counts(layers: int, positions: jax.Array, table_positions: int) -> jax.Array:
+def _mla_counts(layers: int, positions: jax.Array, attended) -> jax.Array:
     """``mla_layer_calls``, ``..._positions_read``, ``..._positions_live`` of
     ``layers`` calls over rows at ``positions`` ``[B, T]``: a row with a token
-    attends its whole table, of which the positions up to its last held
-    history."""
+    attends ``attended`` cached positions (a decode lane its whole table, a
+    chunk's row the tiles its group reads: trips x tile), of which the
+    positions up to its last held history."""
     last = positions.max(axis=1)
     fed = last >= 0
-    return jnp.stack([jnp.int32(layers), layers * fed.sum() * table_positions,
+    return jnp.stack([jnp.int32(layers), layers * fed.sum() * attended,
                       layers * jnp.sum(jnp.where(fed, last + 1, 0))]).astype(jnp.int32)
 
 
@@ -332,19 +353,31 @@ def _in_groups(rows_fn, pool: jax.Array, arrays: tuple, width: int):
     return h.reshape(rows, *h.shape[2:]), pool, sums
 
 
-def _paged(pool_box: list, layer: int, positions, block_tables):
-    """``keys_of`` of a chunk: the tokens' latents into the pool's ``layer``,
-    and the rows' whole tables back."""
-    def keys_of(latent):
+def _paged(c: OpenPanguConfig, pool_box: list, layer: int, positions, block_tables, n_tiles):
+    """``attend`` of a chunk's rows: the tokens' latents into the pool's
+    ``layer`` first, then the queries against the rows' block tables as the
+    pool holds them, a tile of ``models/llama.py:history_tile`` positions a
+    trip and ``n_tiles`` trips (:func:`chunk_history_tiles` of these rows): a
+    query meets its own row's fresh keys, and what any earlier dispatch or row
+    left in the same pages, where they lie; what lies past the rows' last
+    position is not read."""
+    bs = pool_box[0].shape[2]
+    tile_blocks = history_tile(bs, block_tables.shape[1]) // bs
+
+    def attend(lp, q, latent):
         pool_box[0] = write_latent(pool_box[0], layer, latent, positions, block_tables)
-        return gather_latent(pool_box[0], layer, block_tables)
-    return keys_of
+        ends, dims = _absorbed(lp, c)
+        return attend_absorbed_tiled(q, *ends, pool_box[0], layer, block_tables, positions,
+                                     n_tiles, tile_blocks, *dims)
+    return attend
 
 
-def _chunk_mask(positions, block_tables, block_size: int):
-    # key p of a gathered table is position p: a query sees keys up to its own
-    key_pos = jnp.arange(block_tables.shape[1] * block_size)
-    return (key_pos[None, None, :] <= positions[:, :, None]) & (positions >= 0)[:, :, None]
+def _tiles_read(positions, pool: jax.Array, block_tables):
+    """(trips of a chunk's rows over their tables, the cached positions a row
+    attends in them)."""
+    bs, mb = pool.shape[2], block_tables.shape[1]
+    n_tiles = chunk_history_tiles(positions, bs, mb)
+    return n_tiles, n_tiles * history_tile(bs, mb)
 
 
 def forward_chunk(
@@ -356,7 +389,8 @@ def forward_chunk(
     engine's call form; nothing here is kept by lane), valid tokens (position
     >= 0) a prefix of each row; a row may start at any position (a prefix hit,
     a later chunk, a verify dispatch): what lies before it is read from the
-    pages.
+    pages, as its own tokens' latents are once written, and no page past the
+    last position of the group of rows it is computed with.
 
     Returns (hidden ``[R, C, E]`` after the final norm, or before it where
     ``raw``; the pool with the rows' latents written; ``state`` as it came:
@@ -365,14 +399,14 @@ def forward_chunk(
 
     def rows_fn(pool, tokens, positions, block_tables):
         box = [pool]
-        mask = _chunk_mask(positions, block_tables, pool.shape[2])
+        n_tiles, attended = _tiles_read(positions, pool, block_tables)
         x = embed_lookup(params, tokens, c.dtype).astype(jnp.float32)
         counters = jnp.zeros((MOE_COUNTERS,), jnp.int32)
         for i, lp in enumerate(params["layers"]):
-            x, stats = _layer(lp, c, x, positions, mask, pool.shape[-1],
-                              _paged(box, i, positions, block_tables))
+            x, stats = _layer(lp, c, x, positions, pool.shape[-1],
+                              _paged(c, box, i, positions, block_tables, n_tiles))
             counters = counters + stats
-        own = _mla_counts(c.num_layers, positions, mask.shape[-1])
+        own = _mla_counts(c.num_layers, positions, attended)
         h = x if raw else final_norm(params, c, x)
         return h, box[0], jnp.concatenate([counters, own, jnp.zeros((1,), jnp.int32)])
 
@@ -398,13 +432,13 @@ def draft_chunk(
 
     def rows_fn(pool, hidden, next_tokens, positions, block_tables):
         box = [pool]
-        mask = _chunk_mask(positions, block_tables, pool.shape[2])
+        n_tiles, attended = _tiles_read(positions, pool, block_tables)
         with jax.named_scope("mtp"):
             u = _mtp_input(params, c, hidden, next_tokens)
-            x, stats = _layer(params["mtp"]["layer"], c, u, positions, mask, pool.shape[-1],
-                              _paged(box, layer, positions, block_tables))
+            x, stats = _layer(params["mtp"]["layer"], c, u, positions, pool.shape[-1],
+                              _paged(c, box, layer, positions, block_tables, n_tiles))
             h = rms_norm(x, params["mtp"]["norm"], c.rms_norm_eps)
-        own = _mla_counts(1, positions, mask.shape[-1])
+        own = _mla_counts(1, positions, attended)
         return h, box[0], jnp.concatenate([stats, own, jnp.ones((1,), jnp.int32)])
 
     h, pool, sums = _in_groups(rows_fn, kv_cache["latent"],
@@ -447,23 +481,24 @@ def decode(
         at = jnp.where(pos >= 0, pos, key_pos.shape[0])  # past the buffer: dropped
 
         def buffered(j):
-            def keys_of(latent):
+            def attend(lp, q, latent):
                 lat = latent[:, 0].astype(pool.dtype)  # [S, W]
                 history[j] = history[j].at[lanes, at].set(lat, mode="drop")
                 fresh.append(lat)
-                return history[j]
-            return keys_of
+                ends, dims = _absorbed(lp, c)
+                return attend_absorbed(q, *ends, history[j], mask, *dims)
+            return attend
 
         x = embed_lookup(params, toks, c.dtype).astype(jnp.float32)[:, None]
         for i, lp in enumerate(params["layers"]):
-            x, stats = _layer(lp, c, x, pos2, mask, pool.shape[-1], buffered(i))
+            x, stats = _layer(lp, c, x, pos2, pool.shape[-1], buffered(i))
             counters = counters.at[:MOE_COUNTERS].add(stats)
         nxt, carry, out = sample(lm_head(params, c, final_norm(params, c, x))[:, 0], pos, carry, k)
         own = _mla_counts(n_hist, pos2, key_pos.shape[0])
         if draft:
             with jax.named_scope("mtp"):
                 u = _mtp_input(params, c, x, nxt[:, None])
-                y, stats = _layer(params["mtp"]["layer"], c, u, pos2, mask, pool.shape[-1],
+                y, stats = _layer(params["mtp"]["layer"], c, u, pos2, pool.shape[-1],
                                   buffered(c.num_layers))
                 y = rms_norm(y, params["mtp"]["norm"], c.rms_norm_eps)
                 guess = jnp.argmax(lm_head(params, c, y)[:, 0], axis=-1).astype(jnp.int32)

@@ -11,6 +11,12 @@ absorbed form: the query's no-position part goes through ``W_kvb``'s key half
 into the latent space, scores and the weighted sum are taken against the
 latents as they lie, and ``W_kvb``'s value half comes after; the same
 mathematics as expanding keys and values from the latent at every step.
+Two callers' forms share those two ends: :func:`attend_absorbed` over a
+gathered ``[B, P, W]`` history under a mask (a decode step's dense buffer;
+Kimi's chunk, every row's whole table), and :func:`attend_absorbed_tiled` for
+a chunk, which reads the rows' block tables out of the pool a tile of
+positions a trip and stops at the tile that holds the rows' last position
+(``models/openpangu.py``'s chunk programs).
 
 The arithmetic is the two modules' own (:func:`wdot`): float32 activations
 against bfloat16 weights in ``PASSES`` bfloat16 parts, float32 against float32
@@ -24,7 +30,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu.models.llama import apply_rope, rms_norm
+from dynamo_tpu.models.llama import _merge_partials, apply_rope, rms_norm
 from dynamo_tpu.ops.parts import HIGHEST, operand_parts
 
 # -- products of float32 activations against bfloat16 weights ------------------
@@ -96,28 +102,87 @@ def cached_latent(x: jax.Array, w_kva: jax.Array, kv_norm: jax.Array, rank: int,
     return jnp.concatenate(held, axis=-1)
 
 
+def _into_latent_space(q: jax.Array, w_kvb: jax.Array, rank: int, nope: int, v_dim: int, width: int):
+    """The absorbed form's first end, which no key moves: (the queries ``q``
+    ``[B, T, H, nope + rope]`` as they meet a cached row of ``width`` values,
+    ``[B, T, H, width]``: the no-position part taken into the latent space by
+    ``W_kvb``'s key half, the rotated part as it is, zeros where the row is
+    padded; ``W_kvb`` as ``[rank, H, nope + v_dim]``)."""
+    b, t, h, _ = q.shape
+    w_kvb = w_kvb.reshape(rank, h, nope + v_dim)
+    q_lat = wdot("bthd,rhd->bthr", q[..., :nope], w_kvb[..., :nope])
+    held = [q_lat, q[..., nope:]]
+    if width > rank + q.shape[-1] - nope:  # a padded row: zeros meet its padding
+        held.append(jnp.zeros((b, t, h, width - rank - q.shape[-1] + nope), q.dtype))
+    return jnp.concatenate(held, axis=-1), w_kvb
+
+
+def _out_of_latent_space(out_lat: jax.Array, w_kvb: jax.Array, wo: jax.Array, nope: int) -> jax.Array:
+    """The other end: the weighted sum of latents ``out_lat`` ``[B, T, H,
+    rank]`` through ``W_kvb``'s value half (``w_kvb`` as the first end hands it
+    back) and ``W_o``."""
+    b, t, h, _ = out_lat.shape
+    out = wdot("bthr,rhd->bthd", out_lat, w_kvb[..., nope:])
+    return mm(out.reshape(b, t, -1), wo)
+
+
 def attend_absorbed(q: jax.Array, w_kvb: jax.Array, wo: jax.Array, latent: jax.Array,
                     mask: jax.Array, rank: int, nope: int, v_dim: int, scale: float) -> jax.Array:
     """Absorbed latent attention: the queries ``q`` ``[B, T, H, nope + rope]``
     (the rotated part already rotated) against the cached ``latent`` ``[B, P,
     W]`` under ``mask`` ``[B, T, P]``, through ``W_kvb`` ``[rank, H * (nope +
     v_dim)]`` and ``W_o``; scores times ``scale``."""
-    b, t, h, _ = q.shape
-    w_kvb = w_kvb.reshape(rank, h, nope + v_dim)
-    # the query's no-position part, taken into the latent space by W_kvb's key half
-    q_lat = wdot("bthd,rhd->bthr", q[..., :nope], w_kvb[..., :nope])
-    held = [q_lat, q[..., nope:]]
-    if latent.shape[-1] > rank + q.shape[-1] - nope:  # a padded row: zeros meet its padding
-        held.append(jnp.zeros((b, t, h, latent.shape[-1] - rank - q.shape[-1] + nope), q.dtype))
-    q_all = jnp.concatenate(held, axis=-1)  # [B, T, H, W]
+    q_all, w_kvb = _into_latent_space(q, w_kvb, rank, nope, v_dim, latent.shape[-1])  # [B, T, H, W]
     scores = wdot("bthc,bpc->bhtp", q_all, latent) * scale
     scores = jnp.where(mask[:, None], scores, -jnp.inf)
     top = jnp.maximum(scores.max(axis=-1, keepdims=True), -1e30)
     p = jnp.exp(scores - top)
     p = p / jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-30)
     out_lat = wdot("bhtp,bpr->bthr", p, latent[..., :rank])
-    out = wdot("bthr,rhd->bthd", out_lat, w_kvb[..., nope:])
-    return mm(out.reshape(b, t, h * v_dim), wo)
+    return _out_of_latent_space(out_lat, w_kvb, wo, nope)
+
+
+def attend_absorbed_tiled(q: jax.Array, w_kvb: jax.Array, wo: jax.Array, pool: jax.Array, layer: int,
+                          block_tables: jax.Array, positions: jax.Array, n_tiles, tile_blocks: int,
+                          rank: int, nope: int, v_dim: int, scale: float) -> jax.Array:
+    """:func:`attend_absorbed` for a chunk whose tokens' latents are in the
+    pool already: the queries ``q`` ``[B, T, H, nope + rope]`` at ``positions``
+    ``[B, T]`` (< 0: padding) against MLA layer ``layer`` of ``pool`` ``[L, N,
+    bs, W]`` through the rows' ``block_tables`` ``[B, MB]``, a query seeing the
+    keys up to its own position. The tables are read ``tile_blocks`` pages a
+    trip, ``n_tiles`` trips (the caller's: the tiles up to the rows' last
+    position; a traced scalar): trip ``i`` gathers the rows' pages of tile
+    ``i``, scores them as the full form does, and folds (the weighted sum IN
+    THE LATENT SPACE ``[B, T, H, rank]``, the row max, the denominator) into a
+    running partial by the flash merge (``models/llama.py:
+    chunk_history_partial`` is the form). A tile past the trips is never read,
+    whatever it holds; no trip leaves the empty partial, and zeros. Both ends
+    are :func:`attend_absorbed`'s, done once, outside the loop; every product
+    keeps its precision, and only the order of the float32 sums differs."""
+    b, t, h, _ = q.shape
+    q_all, w_kvb = _into_latent_space(q, w_kvb, rank, nope, v_dim, pool.shape[-1])  # [B, T, H, W]
+    tile = tile_blocks * pool.shape[2]
+    # whole tiles: the columns added point at page 0 and lie past every position
+    tables = jnp.pad(block_tables, ((0, 0), (0, -block_tables.shape[1] % tile_blocks)))
+
+    def trip(i, acc):
+        cols = jax.lax.dynamic_slice_in_dim(tables, i * tile_blocks, tile_blocks, axis=1)
+        latent = gather_latent(pool, layer, cols)  # [B, tile, W]
+        # key p of tile i is position i * tile + p: a query sees keys up to its own (padding, < 0, none)
+        key_pos = i * tile + jnp.arange(tile)
+        mask = key_pos[None, None, :] <= positions[:, :, None]
+        scores = wdot("bthc,bpc->bhtp", q_all, latent) * scale
+        scores = jnp.where(mask[:, None], scores, -jnp.inf)
+        top = jnp.maximum(scores.max(axis=-1), -1e30)
+        p = jnp.exp(scores - top[..., None])
+        part = wdot("bhtp,bpr->bthr", p, latent[..., :rank])
+        return _merge_partials(acc, (part, top, p.sum(axis=-1)))
+
+    empty = (jnp.zeros((b, t, h, rank), jnp.float32), jnp.full((b, h, t), -1e30, jnp.float32),
+             jnp.zeros((b, h, t), jnp.float32))
+    num, _, den = jax.lax.fori_loop(0, n_tiles, trip, empty)
+    out_lat = num / jnp.maximum(den, 1e-30).transpose(0, 2, 1)[..., None]
+    return _out_of_latent_space(out_lat, w_kvb, wo, nope)
 
 
 # -- the pool's rows -------------------------------------------------------------

@@ -983,10 +983,18 @@ def test_openpangus_step_programs_neither_pad_nor_copy_the_latent_pool(monkeypat
     a copy; Kimi-Linear's one layer pays 0.113 s of 4 s for it: ROADMAP M9b);
     none copies an expert layer's matrices; the grouped product is in the
     program three times an expert layer; the pool is donated and written in
-    place."""
+    place. A chunk's rows read their tables out of the pool a tile a trip, the
+    gather INSIDE the loop (``ops/latent.py:attend_absorbed_tiled``), and the
+    guard holds there too; the chunk program holds no float32 value of a
+    group's scores over a whole table ``[4, 128, 128, 2048]``, and the decode
+    program still holds its dense histories ``[64, 2048, 640]``."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels compiled, not interpreted
     compiled = _compile_openpangu(program, one_chip, rows, draft)
     hlo = re.sub(r"/\*.*?\*/", "", compiled.as_text())
+    if program == "chunk":
+        assert "f32[4,128,128,2048]" not in hlo and "f32[4,128,128,256]" in hlo
+    else:
+        assert "f32[64,2048,640]" in hlo
     layers = 6 if draft else 5
     views = rf"{layers},12288,16,640|{layers * 12288},16,640|{layers * 12288 * 16},640"
     big = re.findall(

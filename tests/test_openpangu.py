@@ -169,7 +169,7 @@ def test_chunked_prefill_then_decode_agrees_with_the_plain_reference(cfg, params
         counts = dict(zip(op.COUNTERS, sums))
         assert counts["mla_layer_calls"] == 4 and counts["mtp_layer_calls"] == 1
         assert counts["moe_layer_calls"] == 3  # two expert layers and the module's
-        # one row with tokens, four layers: each attends the whole table, (at + n) of it history
+        # one row with tokens, four layers: each attends ONE tile (this table is one), (at + n) of it history
         assert counts["mla_history_positions_read"] == 4 * MB * BS
         assert counts["mla_history_positions_live"] == 4 * (at + n)
         at += n
@@ -215,6 +215,61 @@ def test_a_lane_that_starts_past_position_zero_is_rotated_at_its_own_positions(c
     # the same tokens at the wrong positions (from 0, over an empty table) are another answer
     fresh, _, _, _ = feed(cfg, params, op.make_kv_cache(cfg, 32, BS), tokens[16:], 0, 13, np.arange(1, 9))
     assert np.abs(np.asarray(fresh) - want[16:]).max() > 100 * ATOL
+
+
+@pytest.mark.parametrize("pieces", [(16, 13), (9, 16), (16, 16, 8)],
+                         ids=["two_rows", "a_short_first_row", "three_rows"])
+def test_a_later_row_of_one_dispatch_attends_the_rows_before_it_through_the_pool(cfg, params, pieces):
+    """Successive pieces of ONE prompt in successive rows of ONE chunk
+    dispatch, every row with the lane's block table: a layer writes all the
+    rows' latents to the pool before any row attends, and a row reads its table
+    out of the pool, so a later row meets the earlier rows' fresh keys there
+    and answers as the reference does over the whole prompt. (What
+    ``LANE_TAKES_ROWS`` would rest on; the module does not set it.)"""
+    n = sum(pieces)
+    tokens = np.asarray(prompt_of(n, salt=len(pieces) + 7), np.int32)
+    want = np.asarray(ref.logits(params, SHAPE, jnp.asarray(tokens), jnp.arange(n)))
+    rows = len(pieces) + 1  # and a padding row
+    toks, pos = np.zeros((rows, C), np.int32), np.full((rows, C), -1, np.int32)
+    at = 0
+    for r, k in enumerate(pieces):
+        toks[r, :k], pos[r, :k] = tokens[at:at + k], np.arange(at, at + k)
+        at += k
+    tables = np.tile(np.arange(1, 9, dtype=np.int32), (rows, 1))
+    x, _, _, sums = op.forward_chunk(
+        params, cfg, jnp.asarray(toks), jnp.asarray(pos), op.make_kv_cache(cfg, 32, BS),
+        jnp.asarray(tables), None, jnp.zeros((rows,), jnp.int32))
+    got = np.concatenate([np.asarray(op.lm_head(params, cfg, x[r, :k])) for r, k in enumerate(pieces)])
+    np.testing.assert_allclose(got, want, atol=ATOL)
+    counts = dict(zip(op.COUNTERS, np.asarray(sums)))
+    assert counts["mla_history_positions_read"] == 3 * len(pieces) * MB * BS
+    assert counts["mla_history_positions_live"] == 3 * sum(np.cumsum(pieces))
+
+
+def test_a_chunk_dispatch_counts_the_tiles_it_reads_and_not_the_tables_width(cfg, params):
+    """A 40-token prompt alone, prefilled by ONE chunk dispatch under a table of
+    512 positions (two tiles of 256): the program's own sums rise by one fed
+    row x the layers x ONE tile (and by the 40 positions that held history),
+    and the host counts one trip of the two that cover a table."""
+    eng = JaxServingEngine(cfg, params, dataclasses.replace(ENGINE_CFG, max_model_len=512, prefill_chunk=64))
+    try:
+        bs, mb = ENGINE_CFG.kv_block_size, eng.config.max_blocks_per_seq
+        tile = llama.history_tile(bs, mb)
+        assert (tile, llama.history_tiles_full(bs, mb)) == (256, 2)
+        before = eng.metrics_snapshot()
+        seq = submit(eng, prompt_of(40, salt=3), 1)
+        run_out(eng)
+        assert len(answer(seq)[0]) == 1
+        after = eng.metrics_snapshot()
+        rise = {k: after[k] - before[k] for k in (
+            "mla_layer_calls", "mla_history_positions_read", "mla_history_positions_live",
+            "chunk_history_tiles_read", "chunk_history_tiles_full", "decode_history_tiles_full")}
+        assert rise == {"mla_layer_calls": cfg.num_layers, "mla_history_positions_read": cfg.num_layers * tile,
+                        "mla_history_positions_live": cfg.num_layers * 40,
+                        "chunk_history_tiles_read": 1, "chunk_history_tiles_full": 2,
+                        "decode_history_tiles_full": 0}
+    finally:
+        eng.close()
 
 
 def test_the_expert_shares_add_up_to_the_uncut_reference_layer(cfg, params):

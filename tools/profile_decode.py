@@ -920,67 +920,69 @@ def chunk_program_profile(jamba, d_inner):
 
 def mla_profiles():
     """ONE layer's absorbed latent attention of ``openpangu-ultra-moe-718b``
-    (``ops/latent.py:attend_absorbed`` as ``models/openpangu.py`` calls it: 128
-    heads, a latent of 512 + 64 in a row of 640, float32, ``W_kvb`` and ``W_o``
-    bf16) ALONE, over a dense history of 2,048 positions a lane: at a decode
-    step's 64 lanes x 1 token and at a chunk's 8 x 128 rows (two groups of 512
-    positions, as the chunk program takes them: PROF_ROWS, default 4 rows a
-    group), every lane's table attended whole under the mask as the programs do
-    today, at a context of PROF_CONTEXT positions (default 320, the traffic's
-    mean). PROF_ITERS (default 8) layers chained in one dispatch. Beside each
-    time: the bytes the call must read (the live part of the history, twice:
-    scores and values, ``W_kvb`` and ``W_o``) and what it does read (the whole
-    table), the operations it needs (the live keys) and does (all 2,048), and
-    their shares of the chip's peaks. PROF_HEADS (default 128) cuts the heads
-    for a rehearsal on the CPU."""
+    (``ops/latent.py`` as ``models/openpangu.py`` calls it: 128 heads, a latent
+    of 512 + 64 in a row of 640, float32, ``W_kvb`` and ``W_o`` bf16) ALONE,
+    PROF_ITERS (default 8) layers chained in one dispatch, at each of
+    PROF_CONTEXT's contexts (positions a row holds, its queries the last of
+    them; default 192,320,640,1024; the traffic's mean is 320):
+
+    - a decode step's 64 lanes x 1 token over a dense history of 2,048
+      positions a lane, attended whole under the mask, as ``decode`` does
+      (``attend_absorbed``; at the first context only: the mask hides it);
+    - a chunk group of PROF_ROWS (default 4) rows x 128 tokens the same way,
+      the form the chunk program had until PR 53, from a history in hand and
+      from its gather out of the pool (which the program paid too);
+    - that full form over a table CUT to the power of two of positions that
+      holds the context (what a ``lax.switch`` over static widths would run);
+    - the tiled form the chunk program runs (``attend_absorbed_tiled``: the
+      gather inside the loop, trips up to the context) at each of PROF_TILES'
+      positions a tile (default 128,256,512).
+
+    Beside each time: the bytes the call must read (the live part of the
+    history, twice: scores and values, ``W_kvb`` and ``W_o``) and what it does
+    read (the positions attended; the tiled form also carries its running
+    numerator through the trips), the operations it needs (the live keys) and
+    does, and their shares of the chip's peaks. PROF_HEADS (default 128) cuts
+    the heads for a rehearsal on the CPU."""
     from benchmark import bytes_and_flops
 
     on_chip = jax.default_backend() == "tpu"
     peaks = bytes_and_flops.load_peaks(jax.devices()[0].device_kind) if on_chip else {}
     from dynamo_tpu.engine_jax.compile_cache import enable_compile_cache
-    from dynamo_tpu.ops.latent import attend_absorbed
+    from dynamo_tpu.ops.latent import attend_absorbed, attend_absorbed_tiled, gather_latent
 
     enable_compile_cache()
     n_iter = int(os.environ.get("PROF_ITERS", "8"))
     h = int(os.environ.get("PROF_HEADS", "128"))
-    context = int(os.environ.get("PROF_CONTEXT", "320"))
+    contexts = [int(x) for x in os.environ.get("PROF_CONTEXT", "192,320,640,1024").split(",")]
+    tiles = [int(x) for x in os.environ.get("PROF_TILES", "128,256,512").split(",")]
     group = int(os.environ.get("PROF_ROWS", "4"))
-    r, dn, dr, dv, w, e, table = 512, 128, 64, 128, 640, 7680, 2048
+    r, dn, dr, dv, w, e, table, bs = 512, 128, 64, 128, 640, 7680, 2048, 16
+    dims = (r, dn, dv, (dn + dr) ** -0.5)
     key = jax.random.split(jax.random.PRNGKey(0), 5)
     w_kvb = (jax.random.normal(key[0], (r, h * (dn + dv)), jnp.float32) / r ** 0.5).astype(jnp.bfloat16)
     wo = (jax.random.normal(key[1], (h * dv, e), jnp.float32) / (h * dv) ** 0.5).astype(jnp.bfloat16)
+    weights = 2 * (w_kvb.size + wo.size)
+    per_key = 2 * h * (2 * r + dr)  # a score over rank + rope, a value over rank, a head
 
-    for name, b, t in (("a decode step, 64 lanes x 1 token", 64, 1),
-                       (f"a chunk group, {group} rows x 128 tokens", group, 128)):
-        q = jax.random.normal(key[2], (b, t, h, dn + dr), jnp.float32)
-        latent = jax.random.normal(key[3], (b, table, w), jnp.float32).at[..., r + dr:].set(0.0)
-        first = context - t + jnp.arange(t)  # the queries stand at the context's last t positions
-        mask = jnp.broadcast_to(jnp.arange(table)[None, None, :] <= first[None, :, None], (b, t, table))
-
+    def ms_a_layer(attend, q, *args) -> float:
+        """``attend(q, *args) -> [B, T, E]`` chained ``n_iter`` times in one dispatch."""
         @jax.jit
-        def chain(q, latent, mask, w_kvb, wo):
+        def chain(q, *args):
             def layer(q, _):
-                y = attend_absorbed(q, w_kvb, wo, latent, mask, r, dn, dv, (dn + dr) ** -0.5)
+                y = attend(q, *args)
                 return q + 1e-3 * y[..., None, :dn + dr], y[:, :, 0]
             return jax.lax.scan(layer, q, None, length=n_iter)
+        return median_ms(lambda *a: chain(*a)[0], q, *args) / n_iter
 
-        args = (q, latent, mask, w_kvb, wo)
-        chain(*args)[0].block_until_ready()
-        times = []
-        for _ in range(5):
-            t0 = time.perf_counter()
-            chain(*args)[0].block_until_ready()
-            times.append(time.perf_counter() - t0)
-        ms = float(np.median(times)) * 1e3 / n_iter
-        weights = 2 * (w_kvb.size + wo.size)
-        need_bytes = 2 * b * context * (r + dr) * 4 + weights
-        read_bytes = 2 * b * table * w * 4 + weights
-        per_key = 2 * h * (2 * r + dr)  # a score over rank + rope, a value over rank, a head
+    def report(name, ms, b, t, context, attended, carried=0):
         through = 2 * b * t * (h * dn * r + h * r * dv + h * dv * e)  # the two halves of W_kvb, W_o
-        need_ops, done_ops = b * t * per_key * context + through, b * t * per_key * table + through
-        line = (f"mla {name}, context {context} of {table}: {ms:8.3f} ms a layer; must read "
-                f"{need_bytes / 1e6:7.1f} MB and reads {read_bytes / 1e6:7.1f}; needs {need_ops / 1e9:7.2f} "
-                f"GFLOP and does {done_ops / 1e9:7.2f}")
+        need_bytes = 2 * b * context * (r + dr) * 4 + weights
+        read_bytes = 2 * b * attended * w * 4 + weights + carried
+        need_ops, done_ops = b * t * per_key * context + through, b * t * per_key * attended + through
+        line = (f"mla {name}, context {context} of {table}: {ms:8.3f} ms a layer; attends {attended:4d} positions "
+                f"a row; must read {need_bytes / 1e6:7.1f} MB and reads {read_bytes / 1e6:7.1f}; needs "
+                f"{need_ops / 1e9:7.2f} GFLOP and does {done_ops / 1e9:7.2f}")
         if on_chip:
             line += (f"; of the chip's bytes a second {need_bytes / peaks['hbm_bytes_per_s'] / ms * 1e3:5.1%} "
                      f"needed, {read_bytes / peaks['hbm_bytes_per_s'] / ms * 1e3:5.1%} read; of its bf16 "
@@ -988,6 +990,50 @@ def mla_profiles():
                      f"{done_ops / peaks['bf16_flops_per_s'] / ms * 1e3:5.1%} done (float32 at the highest "
                      f"precision is six bfloat16 passes a product: a sixth of this peak is its ceiling)")
         print(line, flush=True)
+
+    def queries(b, t, context):
+        """(queries, their positions: the context's last ``t``, the mask over a whole table)."""
+        at = jnp.broadcast_to(context - t + jnp.arange(t), (b, t))
+        mask = jnp.arange(table)[None, None, :] <= at[:, :, None]
+        return jax.random.normal(key[2], (b, t, h, dn + dr), jnp.float32), at, mask
+
+    def full(q, latent, mask, w_kvb, wo):
+        return attend_absorbed(q, w_kvb, wo, latent[:, :mask.shape[-1]], mask, *dims)
+
+    b, t = 64, 1
+    q, _, mask = queries(b, t, contexts[0])
+    latent = jax.random.normal(key[3], (b, table, w), jnp.float32).at[..., r + dr:].set(0.0)
+    report(f"a decode step, {b} lanes x {t} token", ms_a_layer(full, q, latent, mask, w_kvb, wo),
+           b, t, contexts[0], table)
+
+    b, t = group, 128
+    latent = latent[:b]
+    # the group's pages: a pool of one layer, every row its own blocks (block 0 is nobody's)
+    pool = jnp.concatenate([jnp.zeros((bs, w)), latent.reshape(-1, w)]).reshape(1, -1, bs, w)
+    tables = 1 + jnp.arange(b * table // bs, dtype=jnp.int32).reshape(b, -1)
+    name = f"a chunk group, {b} rows x {t} tokens"
+    for context in contexts:
+        q, at, mask = queries(b, t, context)
+        report(f"{name}, the whole table in hand", ms_a_layer(full, q, latent, mask, w_kvb, wo),
+               b, t, context, table)
+        cut = min(table, 1 << (context - 1).bit_length())
+
+        def gathered(q, pool, tables, mask, w_kvb, wo):
+            return full(q, gather_latent(pool, 0, tables), mask, w_kvb, wo)
+
+        for width in sorted({cut, table}, reverse=True):
+            report(f"{name}, a table of {width} gathered", ms_a_layer(
+                gathered, q, pool, tables[:, :width // bs], mask[..., :width], w_kvb, wo), b, t, context, width)
+        for tile in tiles:
+            trips = -(-context // tile)
+
+            def tiled(q, pool, tables, at, n_tiles, w_kvb, wo):  # the trips traced, as the program's are
+                return attend_absorbed_tiled(q, w_kvb, wo, pool, 0, tables, at, n_tiles, tile // bs, *dims)
+
+            # a trip reads the running numerator, the tile's own, and writes the merged one
+            report(f"{name}, {trips} tiles of {tile}",
+                   ms_a_layer(tiled, q, pool, tables, at, jnp.int32(trips), w_kvb, wo),
+                   b, t, context, trips * tile, carried=trips * 3 * b * t * h * r * 4)
 
 
 if __name__ == "__main__":
