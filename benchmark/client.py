@@ -164,12 +164,16 @@ async def run_window(port: int, model: str, plain_words: list, schedule: dict,
     return {"records": sent, "drain_s": drain_s}
 
 
-async def probe(port: int, model: str, prompt: str, max_tokens: int, logprobs: int = None) -> dict:
+async def probe(port: int, model: str, prompt: str, max_tokens: int, logprobs: int = None,
+                timeout_s: float = 120.0) -> dict:
     """One greedy request alone; returns its record (with ``text``, and with
-    ``logprobs`` asked ``top_logprobs``: per token, word -> log-probability)."""
+    ``logprobs`` asked ``top_logprobs``: per token, word -> log-probability).
+    ``timeout_s`` is the whole request's limit: 120 s for a request whose
+    programs the warm-up compiled; the one that asks ``logprobs`` compiles its
+    programs on the served path and is given the server's start-up patience."""
     rec = new_record(-1, len(prompt.split()), max_tokens)
     t0 = time.perf_counter()
-    timeout = aiohttp.ClientTimeout(total=120)
+    timeout = aiohttp.ClientTimeout(total=timeout_s)
     async with aiohttp.ClientSession(timeout=timeout) as session:
         await stream_one(
             session, f"http://127.0.0.1:{port}/v1/completions",

@@ -57,7 +57,8 @@ def main() -> int:
             prompt = traffic.prompt_text(
                 go.plain, REFERENCE_PROMPT_TOKENS, random.Random(args.seed + k))
             probe = asyncio.run(client.probe(
-                go.port, go.model, prompt, REFERENCE_OUTPUT_TOKENS, REFERENCE_TOP_LOGPROBS))
+                go.port, go.model, prompt, REFERENCE_OUTPUT_TOKENS, REFERENCE_TOP_LOGPROBS,
+                timeout_s=go.ready_timeout_s))  # the first compiles the logprobs programs, as a traced run's does
             check(probe["ok"], f"the probe of seed {args.seed + k} failed: {probe.get('error')}")
             answers.append(dict(probe, prompt=prompt))
     finally:

@@ -8,7 +8,10 @@ directory, starts ONE child (``server_child.py``: ``dynamo_tpu.cli.run.amain``
 ``in=http out=jax``), waits for ready, sends two probes, runs pre-roll, window
 and drain over HTTP, reads the counters, sends a third probe, stops the child
 and prints one JSON line. A traced run then holds one more greedy answer against
-the plain reference (``reference_child.py``, a second short-lived child). Without a TPU named by the child the run fails
+the plain reference (``reference_child.py``, a second short-lived child); that
+request compiles its programs on the served path, so it is given the server's
+start-up patience (``serving.ready_timeout_s``) and the ``info`` line says what
+it cost (``reference_probe``). Without a TPU named by the child the run fails
 (``--rehearse`` relaxes that, at a tiny width, and prints no device metric).
 See README.md for the files a cell, a configuration, a generator and a metric
 are made of.
@@ -221,6 +224,7 @@ def numbers_compared(probes, summary, recompiles, device, weights_floor, verdict
         "memory_peak_bytes_at_least": [device["memory_peak_bytes"], weights_floor],
     }
     if verdict is not None:  # the traced run's answer against the plain reference
+        out["reference_probe_answered"] = [0 if "no_answer" in verdict else 1, 1]
         out["worst_gap_at_most"] = [verdict.get("worst_gap"), verdict.get("tolerance")]
         out["argmax_matches_at_least"] = [verdict.get("argmax_matches"), (verdict.get("tokens") or 0) / 2]
         out["logprob_rms_at_most"] = [verdict.get("logprob_rms"), verdict.get("logprob_rms_limit")]
@@ -228,12 +232,49 @@ def numbers_compared(probes, summary, recompiles, device, weights_floor, verdict
     return out
 
 
+def reference_probe_report(rec: dict, seconds: float, timeout_s: float, after: dict, probed: dict) -> dict:
+    """The ``info`` line's ``reference_probe``: what the answer held against the
+    reference cost. ``seconds`` is the request's wall against its ``timeout_s``;
+    ``compile_s`` the rise of ``host_phase_us.compile`` (the engine thread's
+    self time under ``engine.compile``) and ``jit_recompiles`` that counter's
+    rise between ``after`` (the snapshot behind the drain) and ``probed`` (the
+    one behind this request): its ``lp=True`` programs compile inside it. None
+    where a program keeps no such counter, never 0."""
+    def rise(read):
+        try:
+            return read(probed) - read(after)
+        except (KeyError, TypeError):
+            return None
+
+    compile_us = rise(lambda snap: snap["host_phase_us"]["compile"])
+    return {"seconds": seconds, "first_token_s": rec.get("first_s"), "timeout_s": timeout_s,
+            "compile_s": None if compile_us is None else compile_us / 1e6,
+            "jit_recompiles": rise(lambda snap: snap["jit_recompiles"])}
+
+
+def reference_verdict(long_probe: dict, report: dict, against_reference) -> dict:
+    """The verdict on the answer held against the plain reference. A probe that
+    gave no answer is not sent to ``against_reference`` (minutes of chip on an
+    answer of no tokens): its verdict says what happened under ``no_answer``."""
+    if long_probe["ok"]:
+        return against_reference([long_probe])[0]
+    error = long_probe.get("error") or (
+        f"HTTP {long_probe.get('status')}, finish_reason {long_probe.get('finish_reason')!r}, "
+        f"usage {long_probe.get('usage')}")
+    return {"agrees": False, "no_answer": (
+        f"{error} after {report['seconds']:.1f} s of the {report['timeout_s']:g} s it is given "
+        f"(compile_s {report['compile_s']}, jit_recompiles +{report['jit_recompiles']})")}
+
+
 def reference_faults(verdict: dict) -> list:
     """Why the answer held against the plain reference does not pass, as
-    reasons for ``not_correct_because``: the reference's own verdict, and too
-    few of the answer's 480 log-probabilities held (the floor goes into the
-    verdict beside the count, for the ``info`` line)."""
+    reasons for ``not_correct_because``: the probe gave no answer (and nothing
+    was held), or the reference's own verdict, and too few of the answer's 480
+    log-probabilities held (the floor goes into the verdict beside the count,
+    for the ``info`` line)."""
     verdict["logprob_pairs_floor"] = REFERENCE_PAIRS_FLOOR
+    if "no_answer" in verdict:
+        return [f"the probe held against the plain reference gave no answer: {verdict['no_answer']}"]
     faults = []
     if not verdict["agrees"]:
         faults.append(f"the probe's answer disagrees with the plain reference: {verdict}")
@@ -263,6 +304,9 @@ class Launch:
         check(cfg_entry is not None, f"no configuration {self.cell['config']!r} in BENCHMARK.json")
         self.cfg = load_json(ROOT, cfg_entry["file"])
         self.serve = self.cfg["serving"]
+        # how long the server may take to compile: its warm-up before it serves,
+        # and the answer held against the reference, whose programs no warm-up compiles
+        self.ready_timeout_s = self.serve.get("ready_timeout_s", 900)
         self.chips = self.serve["chips"]
         # a cell whose file is here and that BENCHMARK.json does not list is
         # parked (PERF.md 7): it runs by hand, rehearses and sweeps, the driver never asks for it
@@ -332,7 +376,7 @@ class Launch:
             os.path.join(self.scratch, "reference.log"), self.env,
         )
         try:
-            child.proc.wait(timeout=self.serve.get("ready_timeout_s", 900))
+            child.proc.wait(timeout=self.ready_timeout_s)
         except subprocess.TimeoutExpired:
             pass
         finally:
@@ -350,7 +394,7 @@ class Launch:
         check(dev["count"] >= self.chips,
               f"the cell needs {self.chips} chips, JAX found {dev['count']}")
         self.peaks = None if self.rehearse else bytes_and_flops.load_peaks(dev["kind"])
-        serving.wait_http(self.child, self.port, self.serve.get("ready_timeout_s", 900))
+        serving.wait_http(self.child, self.port, self.ready_timeout_s)
         return dev
 
 
@@ -359,7 +403,7 @@ def run(args) -> int:
     bench, cell, cfg, shape, chips = go.bench, go.cell, go.cfg, go.shape, go.chips
     child, port, model, plain, trace_dir = go.child, go.port, go.model, go.plain, go.trace_dir
     schedule = traffic.build_schedule(cell, args.seed, args.seconds)
-    samples, cpu, trace_done, trace_s = [], {}, True, None
+    samples, cpu, trace_done, trace_s, probe_report = [], {}, True, None, None
     try:
         dev = go.wait_ready()
         peaks = go.peaks
@@ -387,9 +431,13 @@ def run(args) -> int:
         if args.trace:
             long_prompt = traffic.prompt_text(
                 plain, REFERENCE_PROMPT_TOKENS, random.Random(args.seed + 2))
+            asked_at = time.perf_counter()
             long_probe = asyncio.run(client.probe(
-                port, model, long_prompt, REFERENCE_OUTPUT_TOKENS, REFERENCE_TOP_LOGPROBS))
+                port, model, long_prompt, REFERENCE_OUTPUT_TOKENS, REFERENCE_TOP_LOGPROBS,
+                timeout_s=go.ready_timeout_s))
             long_probe["prompt"] = long_prompt
+            probe_report = reference_probe_report(
+                long_probe, time.perf_counter() - asked_at, go.ready_timeout_s, after, engine_state(port))
             trace_done = wait_for_xplane(trace_dir, lambda: child.proc.poll() is None)
             trace_s = trace_seconds(trace_dir, time.time())
     finally:
@@ -423,9 +471,7 @@ def run(args) -> int:
 
     verdict = None
     if args.trace:
-        if not long_probe["ok"]:
-            reasons.append(f"the request held against the reference failed: {long_probe.get('error')}")
-        verdict = go.against_reference([long_probe])[0]
+        verdict = reference_verdict(long_probe, probe_report, go.against_reference)
         reasons += reference_faults(verdict)
 
     reduced = None
@@ -455,7 +501,7 @@ def run(args) -> int:
         "warmup_s": (child.log_json("warmup") or [None])[0],
         "compile_cache": dev.get("compile_cache"),
         "jit_recompiles": [before["jit_recompiles"], after["jit_recompiles"]],
-        "against_reference": verdict, **(trace_s or {}),
+        "against_reference": verdict, "reference_probe": probe_report, **(trace_s or {}),
         "memory_peak_bytes_and_weights_floor": [
             device["memory_peak_bytes"], weights_floor_bytes(cfg["memory_account_bytes"], chips)],
         "tiers": sorted({t.get("tier") for t in tiers.values()}),

@@ -40,16 +40,23 @@ def test_the_share_is_the_rise_of_tiles_read_over_the_rise_of_tiles_full(samples
     assert got == want
 
 
-def test_benchmark_json_registers_the_reader_for_the_cells_on_models_llama():
-    """Both cells run ``models/llama.py``'s chunk program, whose loop the
-    counters count; a cell on another model's program has nothing to read."""
+def test_benchmark_json_registers_the_reader_for_every_cell_whose_traced_run_read_it():
+    """The engine counts the tiles for every module (each says what its chunk
+    program reads of the tables: ``chunk_history_tiles``), so the two cells on
+    ``models/llama.py`` were never alone: a traced run of each of the seven on
+    the chip read both counters rise over the window (PR 56; PERF.md 3)."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     entry = next(m for m in bench["per_layer"] if m["name"] == NAME)  # by name: entries are appended
     reader = bench_run.load_readers("layer_metrics")[NAME]
+    cells = entry.pop("workloads")
     assert entry == {"name": NAME, "unit": reader.UNIT, "better": "lower", "source": "program_counter",
-                     "layer": reader.LAYER, "moves": reader.MOVES,
-                     "workloads": ["batch.qwen2.5-1.5b", "batch.qwen2.5-7b-tp4"]}
+                     "layer": reader.LAYER, "moves": reader.MOVES}
+    # a later cell is appended once a traced run of it has read the share
+    assert cells[:7] == ["batch.qwen2.5-1.5b", "batch.qwen2.5-7b-tp4", "batch.kimi-linear-48b-a3b",
+                         "batch.jamba2-3b", "batch.lfm2-24b-a2b", "batch.qwen3-next-80b-a3b",
+                         "batch.openpangu-ultra-moe-718b"]
+    assert set(cells) <= {w["name"] for w in bench["workloads"]} and len(set(cells)) == len(cells)
     assert entry["layer"] in {m["layer"] for m in bench["per_layer"] if m["name"] != NAME}
 
 

@@ -360,7 +360,8 @@ def test_every_number_compared_stands_beside_its_limit_and_comes_last_in_the_lin
     got = bench_run.numbers_compared([{"text": "a b"}] * 3, {"failed": 0}, [8, 8], device, 3_025_680_036.0, verdict)
     assert got == {
         "probe_texts_distinct": [1, 1], "requests_failed": [0, 0], "jit_recompiles_in_window": [0, 0],
-        "memory_peak_bytes_at_least": [5_935_131_648, 3_025_680_036.0], "worst_gap_at_most": [0.03, 0.31],
+        "memory_peak_bytes_at_least": [5_935_131_648, 3_025_680_036.0],
+        "reference_probe_answered": [1, 1], "worst_gap_at_most": [0.03, 0.31],
         "argmax_matches_at_least": [21, 12.0], "logprob_rms_at_most": [0.017, 0.035],
         "logprob_pairs_at_least": [479, 456]}
     # an untraced run holds no answer against the reference
