@@ -16,11 +16,11 @@ Architecture (TPU-first, cf. SURVEY.md §7 stage 4):
   model's module allows it; otherwise one row a lane. The lanes that decode
   are never rows of it: they advance through the decode program in the same
   host step, and both programs are dispatched before either result is
-  fetched. (On a mesh the decode lanes still ride the chunk dispatch at the
-  full width, one token each, and a lane has one row: `_rides`, which says
-  why.) What a dispatch does not hold of a prompt goes on in the next step
-  (long-context prefill is chunked by construction; no shape depends on
-  prompt length).
+  fetched. (On a process-spanning mesh, under pp and under sp the decode
+  lanes still ride the chunk dispatch at the full width, one token each, and
+  a lane has one row: `_rides`, which says why.) What a dispatch does not
+  hold of a prompt goes on in the next step (long-context prefill is chunked
+  by construction; no shape depends on prompt length).
 - **Paged KV**: allocator (allocator.py) maps sequences onto a page pool in
   HBM with content-addressed prefix reuse; the model writes-then-attends
   through block tables (models/llama.py), making prefix hits free.
@@ -504,8 +504,8 @@ def chunk_rows_of(need: List[int], waited: List[float], rungs: List[int]) -> Lis
     of pieces. Every lane gets its first row (none is starved); the rows left
     go to further pieces, the lane that has waited longest first, and what
     does not fit goes on in the next step. At the full width a lane has one
-    row: its program is the one without lanes (a mesh engine's, to the
-    character), and row pairs there would be ``[max_slots, max_slots]``."""
+    row: its program is the one without lanes (that of an engine of one
+    rung, to the character), and row pairs there would be ``[max_slots, max_slots]``."""
     n, full = len(need), rungs[-1]
     under = max((r for r in rungs if r < full), default=0)
     rows = next(r for r in rungs if r >= max(n, min(sum(need), under)))
@@ -1026,39 +1026,29 @@ class JaxServingEngine(AsyncEngine):
         # (_SealPages), where _block_checksums looks first
         self._sealing: Optional[_SealPages] = None
 
-        # row counts of the chunk program (chunk_row_ladder). A process-
-        # spanning mesh broadcasts fixed shapes to its followers, pipeline
-        # stages microbatch the row axis and the sp forward has run at one
-        # width only: those engines pack their rows like any other, into the
-        # one rung every engine has.
+        # Row counts of the chunk program (`chunk_row_ladder`), and who keeps
+        # the host step from before the rows were packed: the engines whose
+        # shapes are fixed by something of their own. A process-spanning
+        # mesh's leader broadcasts one chunk shape and one decode shape to its
+        # followers, a pipeline's stages microbatch the row axis, and the sp
+        # forward has run at one width only. Those have the one rung every
+        # engine has and their decode lanes RIDE the chunk dispatch, one
+        # token each at the full width; the decode program runs only in steps
+        # in which no lane prefills, and sealed blocks are read when they
+        # seal, not ahead (`_sealing_sizes` empty). No chip has run those
+        # three, so their ladder waits for a run between real chips (ROADMAP
+        # D11). Every other engine, a one-process tp or ep mesh among them,
+        # takes the host step that one device takes (PERF.md 6, PR 57).
         S = engine_config.max_slots
-        # On a mesh the host step is the one it was before the rows were
-        # packed: the decode lanes RIDE the chunk dispatch, one token each at
-        # the full width, the decode program runs only in steps in which no
-        # lane prefills, and sealed blocks are read when they seal, not
-        # ahead (`_sealing_sizes` empty). Not for the engine's sake: packed
-        # rows with the decode program beside them read -48 % TTFT and 2.6 x
-        # the tokens/s under tp=4, and the read-ahead alone +8 % tokens/s
-        # (PERF.md 6, PR 32). The benchmark's tracer exports every device
-        # event of four chips inside a time limit that the parent's rate
-        # already nearly fills, and a cell whose traced run fails refuses the
-        # change (PERF.md 7, ROADMAP B0). The same switch keeps their decode
-        # program on every table's full width (`gather_history`), for the
-        # same budget of events: the live form is a fifth faster and some
-        # operations a layer and step longer. When that is repaired: `_rides
-        # = False`, and both go.
-        self._rides = mesh is not None
-        self._chunk_rungs: List[int] = (
-            [S] if self._rides or self._multihost or self._pp > 1 or self._sp > 1
-            else chunk_row_ladder(S)
-        )
+        self._rides = self._multihost or self._pp > 1 or self._sp > 1
+        self._chunk_rungs: List[int] = [S] if self._rides else chunk_row_ladder(S)
         # a lane may fill several rows of a chunk dispatch under the full
         # width with successive pieces of its prompt (`chunk_rows_of`) where
         # the model's module says its chunk program lets a row attend the
         # earlier rows of its lane. A module that keeps state per slot beside
         # the pages does not (the state would pass from row to row inside its
-        # kernels), and the engines of one rung keep one row a lane: on a
-        # mesh until `_rides` goes.
+        # kernels), and the engines of one rung keep one row a lane
+        # (`_rides`).
         self._lane_rows = (
             len(self._chunk_rungs) > 1
             and getattr(self.model, "LANE_TAKES_ROWS", False)
@@ -1308,9 +1298,12 @@ class JaxServingEngine(AsyncEngine):
 
             # the dense tier on one device gathers and attends the history
             # that is live, at a width the program takes from `base`
-            # (models/llama.py with_live_history); a mesh engine keeps every
-            # table's full width (`_rides`, which says until when)
-            if dense and self._rides:
+            # (models/llama.py with_live_history). A mesh engine keeps every
+            # table's full width: with the pool sharded a KV head a shard the
+            # live form's (lane, head) slots cross the shards, and a step read
+            # 10.06 ms where this form reads 7.22 under tp=4, with a second
+            # width to warm up (PERF.md 6, PR 57)
+            if dense and self.mesh is not None:
                 hist_k, hist_v = gather_history(
                     cache, tables, out_dtype=self._compute_dtype
                 )
@@ -1865,6 +1858,8 @@ class JaxServingEngine(AsyncEngine):
             self._clock.compile_key = None  # built here, not on the served path
             return timings
 
+        from concurrent.futures import ThreadPoolExecutor
+
         sample_set = (False,) if variants == "greedy" else (False, True)
         # (rows, want_sample, want_history) of every chunk program to compile
         chunk_set = [
@@ -1880,13 +1875,18 @@ class JaxServingEngine(AsyncEngine):
 
         def warm_sealing():
             # the take program at every block count _take_sealing pads to
-            # (executed: it reads the pool and writes nothing)
+            # (executed: it reads the pool and writes nothing), side by
+            # side: each compiles in a third of a second, too short for the
+            # persistent cache to keep, so every start pays each again
             if not self._seal_checksums or not self._sealing_sizes:
                 return
             t0 = time.perf_counter()
-            for n in self._sealing_sizes:
+            with ThreadPoolExecutor(max_workers=len(self._sealing_sizes)) as takes:
                 # dynlint: allow-host-sync(warmup compile barrier, pre-serving)
-                jax.block_until_ready(kv_pages.take(self.cache, [0] * n))
+                jax.block_until_ready(list(takes.map(
+                    lambda n: kv_pages.take(self.cache, [0] * n),
+                    self._sealing_sizes,
+                )))
             timings["take_blocks"] = round(time.perf_counter() - t0, 2)
 
         if self.mesh is not None:
@@ -1936,8 +1936,6 @@ class JaxServingEngine(AsyncEngine):
             setup.switch(profiling_mod.S_SEALING)
             warm_sealing()
             return done()
-
-        from concurrent.futures import ThreadPoolExecutor
 
         def sd(shape, dtype):
             return jax.ShapeDtypeStruct(shape, dtype)
@@ -3173,7 +3171,7 @@ class JaxServingEngine(AsyncEngine):
             self.decode_history_tiles_full += full
             # a mesh engine gathers every table's full width; one device what
             # its module says (the live pairs, or every table whole)
-            self.decode_history_tiles_read += full if self._rides else int(
+            self.decode_history_tiles_read += full if self.mesh is not None else int(
                 self.model.decode_history_tiles(
                     np.where(self._positions < 0, -1, self._positions + ahead),
                     bs, MB,
@@ -4420,7 +4418,7 @@ class JaxServingEngine(AsyncEngine):
             "chunk_history_tiles_full": self.chunk_history_tiles_full,
             # the same of the decode program: (lane, tile) slots of history
             # read over the slots of every table's full width (a mesh engine
-            # reads them all: `_rides`)
+            # reads them all)
             "decode_history_tiles_read": self.decode_history_tiles_read,
             "decode_history_tiles_full": self.decode_history_tiles_full,
             # a slot model's own sums (its module's COUNTERS; none otherwise)

@@ -159,8 +159,8 @@ def _chunk(params, cache, cfg, tokens, positions, tables, *, with_history):
 
 
 def _chunk_lanes(params, cache, cfg, tokens, positions, tables, lanes):
-    """The chunk program of a rung under ``max_slots`` on one device: the
-    rows' lanes given, so that a lane may fill several rows of it."""
+    """The chunk program of a rung under ``max_slots``: the rows' lanes given,
+    so that a lane may fill several rows of it."""
     return llama.forward_chunk(
         params, cfg, tokens, positions, cache, tables,
         hidden_only=True, with_history=True, lanes=lanes,
@@ -201,7 +201,7 @@ def _decode(params, cache, cfg, tokens, positions, tables, *, live):
             cache, tables, base, steps, out_dtype=cfg.dtype
         )
     else:
-        # a mesh engine (engine._rides): every table's full width
+        # a mesh engine: every table's full width
         hk, hv = llama.gather_history(cache, tables, out_dtype=cfg.dtype)
         toks, wk, wv = steps(("dense", hk, hv))
     return toks, llama.flush_window(cache, tables, base, wk, wv, MAX_POS)
@@ -379,9 +379,8 @@ def test_every_rung_of_the_chunk_row_ladder_compiles_in_place(request, one_chip,
     shard), for the described v5e: it compiles, the donated pool aliases the
     output in full, and nothing pool-sized comes out of any op but views and
     the in-place scatter. The history-bearing program, the only one a rung
-    under ``max_slots`` has. (A mesh engine dispatches the top rung alone
-    while its decode lanes ride the chunk, ``engine._rides``: the rungs under
-    it are held here for the day that is lifted.)"""
+    under ``max_slots`` has. (An engine whose decode lanes ride the chunk,
+    ``engine._rides``, dispatches the top rung alone.)"""
     from dynamo_tpu.engine_jax.engine import chunk_row_ladder
 
     rows = chunk_row_ladder(slots)[rung]
@@ -434,9 +433,9 @@ def test_the_smallest_rung_of_the_chunk_program_holds_an_eighth_of_the_temporari
 
 # sha256 of the chunk program's lowered text with no lanes given, at 8 rows,
 # Qwen2.5-1.5B's widths and POOL_LAYERS layers, as the tree before a lane could
-# take several rows lowered it (PR 44's): the program a mesh engine, the
-# full-width rung and `verify` run is that one to the character, and their
-# compile-cache entries stand
+# take several rows lowered it (PR 44's): the program an engine of one rung
+# (`engine._rides`), the full-width rung and `verify` run is that one to the
+# character, and their compile-cache entries stand
 CHUNK_TEXT_SHA256 = "d9212223c0442bf8ee7eca11f9147286d140813a239ba00f743a447cd0b8710c"
 
 
@@ -472,6 +471,29 @@ def test_the_chunk_program_with_lanes_given_compiles_in_place_at_the_small_rungs
         "chunk", short, POOL_BLOCKS[1], False, None, one_chip, rows=8, lower_only=True
     ).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == CHUNK_TEXT_SHA256
+
+
+@pytest.mark.parametrize("rows", [2, 4])
+def test_the_chunk_program_with_lanes_given_compiles_in_place_under_tp4(tp4_mesh, one_chip, rows):
+    """What ``batch.qwen2.5-7b-tp4`` dispatches at its rungs of 2 and 4 rows of
+    16 slots, a KV head a shard, for the described v5e: with the rows' lanes
+    given it compiles, the donated pool aliases the output in full, nothing
+    pool-sized comes out of any op but views and the in-place scatter, and
+    (at 4 rows) the sibling partial's scores stay a row pair at a time."""
+    cfg = dataclasses.replace(llama.LLAMA_PRESETS["qwen2.5-7b"], num_layers=POOL_LAYERS)
+    shards = tp4_mesh.shape["tp"]
+    num_blocks = POOL_BLOCKS[1]
+    compiled = _compile_pool_program(
+        "chunk_lanes", cfg, num_blocks, False, tp4_mesh, one_chip, rows=rows
+    )
+    pool = jax.eval_shape(lambda: llama.make_kv_cache(cfg, num_blocks, BS))
+    pages = pool["k"].size // shards
+    hlo = compiled.as_text()
+    assert _pool_sized_instructions(hlo, {pages, pages // cfg.num_layers}) == []
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in pool.values()) // shards
+    assert pool_bytes <= compiled.memory_analysis().alias_size_in_bytes < pool_bytes * 1.001
+    if rows * CHUNK != 256:  # two rows are as wide as a tile of the history's scores
+        assert re.findall(rf"f32\[[\d,]*,{rows * CHUNK}\]", hlo) == []
 
 
 # Temporary memory of the decode program at Qwen2.5-1.5B's serving shapes (32

@@ -138,13 +138,13 @@ def eng(shared):
     assert shared.allocator.active_blocks == 0 and not shared._zombie_allocs
 
 
-def mesh_engine(params, **axes):
+def mesh_engine(params, engine_cfg=ENGINE_CFG, **axes):
     from dynamo_tpu.models.llama import param_shardings
     from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
 
     mesh = make_mesh(MeshConfig(**axes))
     return JaxServingEngine(
-        CFG, jax.device_put(params, param_shardings(CFG, mesh)), ENGINE_CFG, mesh=mesh
+        CFG, jax.device_put(params, param_shardings(CFG, mesh)), engine_cfg, mesh=mesh
     )
 
 
@@ -163,14 +163,34 @@ def test_the_ladder_has_max_slots_and_at_most_two_rungs_under_it(slots, want):
 
 @pytest.mark.parametrize("axes", [dict(pp=2), dict(sp=2), dict(tp=2)], ids=["pp", "sp", "tp"])
 def test_an_engine_on_a_mesh_has_the_one_rung_ladder(params, axes):
-    """A pipeline's stages microbatch the row axis, the sp forward has run at
-    one width and a process-spanning mesh broadcasts fixed shapes; and on
-    every mesh the decode lanes still ride the chunk dispatch (``_rides``:
-    the benchmark's tracer, not the engine, asks for it), so every lane can
-    be a row: ``[max_slots]`` through the same host code."""
+    """A pipeline's stages microbatch the row axis and the sp forward has run
+    at one width: on those the decode lanes still ride the chunk dispatch
+    (``_rides``), so every lane can be a row: ``[max_slots]`` through the same
+    host code, and nothing read ahead. A one-process tp mesh fixes no shape of
+    its own and takes the ladder, the rows a lane and the read-ahead that one
+    device has."""
     eng = mesh_engine(params, **axes)
     try:
-        assert eng._rides and eng._chunk_rungs == [ENGINE_CFG.max_slots]
+        if "tp" in axes:
+            assert not eng._rides
+            assert eng._chunk_rungs == chunk_row_ladder(ENGINE_CFG.max_slots)
+            assert eng._sealing_sizes and eng._lane_rows
+        else:
+            assert eng._rides and eng._chunk_rungs == [ENGINE_CFG.max_slots]
+            assert eng._sealing_sizes == [] and not eng._lane_rows
+    finally:
+        eng.close()
+
+
+def test_a_process_spanning_mesh_keeps_the_one_rung_ladder(params, monkeypatch):
+    """The leader broadcasts one chunk shape and one decode shape to its
+    followers: a tp mesh over two processes rides as pp and sp do."""
+    monkeypatch.setattr(jax, "process_count", lambda: 2)
+    eng = mesh_engine(params, tp=2)
+    try:
+        assert eng._multihost and eng._rides
+        assert eng._chunk_rungs == [ENGINE_CFG.max_slots]
+        assert eng._sealing_sizes == [] and not eng._lane_rows
     finally:
         eng.close()
 
@@ -270,32 +290,59 @@ def test_every_request_under_mixed_traffic_answers_as_it_does_alone(eng, alone, 
         assert eng.chunk_dispatches_by_rows[ENGINE_CFG.max_slots] > by_rows.get(ENGINE_CFG.max_slots, 0)
 
 
+def watch_decodes(eng):
+    """Per decode dispatch from here on: did a lane prefill in that host step?"""
+    seen, dispatch = [], eng._decode_dispatch
+    eng._decode_dispatch = lambda **kw: seen.append(
+        any(s is not None and s.prefill_pos is not None for s in eng._slots)
+    ) or dispatch(**kw)
+    return seen
+
+
+def watch_chunks(eng):
+    """(rows, lanes fed) of every chunk dispatch from here on."""
+    seen, build = [], eng._chunk_build
+
+    def watched(paced):
+        rows, lanes = eng.chunk_rows_dispatched, eng.chunk_lanes_fed
+        built = build(paced)
+        if built is not None:
+            seen.append((eng.chunk_rows_dispatched - rows, eng.chunk_lanes_fed - lanes))
+        return built
+
+    eng._chunk_build = watched
+    return seen
+
+
+def held_against_one_at_a_time(one, schedule, salt, got):
+    """``got`` against the same requests served alone on ``one``, an engine on
+    a mesh like the served one's (a sharded sum rounds as a sharded sum)."""
+    for i, (at, n, m, sampling) in enumerate(schedule):
+        seq = submit(one, prompt_of(n, salt + i), m, **sampling)
+        run_out(one)
+        want_toks, want_lps, _ = answer(seq)
+        toks, lps, finish = got[i]
+        assert (toks, finish) == (want_toks, "length"), i
+        if "logprobs" in sampling:
+            np.testing.assert_allclose(lps, want_lps, atol=2e-5)
+
+
 @pytest.mark.parametrize("salt, schedule", [
     (50, MIXED),
     (60, with_sampling(MIXED, {1, 3, 4}, logprobs=0)),
     (70, with_sampling(MIXED, {0, 2}, frequency_penalty=1.5, presence_penalty=0.5)),
 ], ids=["plain", "logprobs", "a_penalised_lane"])
 def test_on_a_mesh_the_decode_lanes_ride_the_chunk_and_answer_as_alone(params, salt, schedule):
-    """``_rides``: a lane that decodes is a row of every chunk dispatch, one
-    token forward, and the decode program runs in no step in which a lane
-    prefills. Held against the same requests served one at a time on a mesh
-    engine of their own (a sharded sum rounds as a sharded sum)."""
-    eng, one = mesh_engine(params, tp=2), mesh_engine(params, tp=2)
+    """``_rides`` (here sp=2; pp and a process-spanning mesh alike): a lane
+    that decodes is a row of every chunk dispatch, one token forward, and the
+    decode program runs in no step in which a lane prefills. Held against the
+    same requests served one at a time on an sp engine of their own."""
+    eng, one = mesh_engine(params, sp=2), mesh_engine(params, sp=2)
     try:
-        decodes = []
-        dispatch = eng._decode_dispatch
-        eng._decode_dispatch = lambda **kw: decodes.append(
-            any(s is not None and s.prefill_pos is not None for s in eng._slots)
-        ) or dispatch(**kw)
+        assert eng._rides
+        decodes = watch_decodes(eng)
         got = serve_schedule(eng, schedule, salt=salt)
-        for i, (at, n, m, sampling) in enumerate(schedule):
-            seq = submit(one, prompt_of(n, salt + i), m, **sampling)
-            run_out(one)
-            want_toks, want_lps, _ = answer(seq)
-            toks, lps, finish = got[i]
-            assert (toks, finish) == (want_toks, "length"), i
-            if "logprobs" in sampling:
-                np.testing.assert_allclose(lps, want_lps, atol=2e-5)
+        held_against_one_at_a_time(one, schedule, salt, got)
         assert decodes and not any(decodes)
         # and its decode program keeps every table's full width
         assert eng.decode_history_tiles_read == eng.decode_history_tiles_full > 0
@@ -361,6 +408,63 @@ def test_every_request_answers_as_alone_where_a_lane_fills_several_rows(wide, al
     assert wide.allocator.active_blocks == 0 and not wide._zombie_allocs
 
 
+@pytest.mark.parametrize("engine_cfg, salt, schedule", [
+    (ENGINE_CFG, 200, MIXED),
+    (ENGINE_CFG, 210, with_sampling(MIXED, {1, 3, 4}, logprobs=0)),
+    (ENGINE_CFG, 220, with_sampling(MIXED, {0, 2}, frequency_penalty=1.5, presence_penalty=0.5)),
+    (WIDE_CFG, 230, SPILLS),
+], ids=["plain", "logprobs", "a_penalised_lane", "a_prompt_longer_than_the_second_rung_holds"])
+def test_a_tp_mesh_takes_the_one_device_step_and_answers_as_alone(params, engine_cfg, salt, schedule):
+    """An engine on a one-process tp mesh takes the host step one device
+    takes, through the same functions and sharded: two programs in flight in
+    one host step, a lane's rows attending their siblings, and the blocks a
+    dispatch fills read right behind it, every member's shards assembled to
+    whole blocks for the checksum. (Its decode program alone keeps the form of
+    every mesh: every table's full width.) Held against the same requests
+    served one at a time on a tp engine of their own."""
+    from dynamo_tpu.kv import pages as kv_pages
+
+    eng, one = mesh_engine(params, engine_cfg, tp=2), mesh_engine(params, engine_cfg, tp=2)
+    try:
+        assert not eng._rides and eng._seal_checksums
+        decodes, chunks = watch_decodes(eng), watch_chunks(eng)
+        ahead, take_sealing = [], eng._take_sealing
+
+        def taken(filled):
+            ahead.append(take_sealing(filled))
+            return ahead[-1]
+
+        eng._take_sealing = taken
+        got = serve_schedule(eng, schedule, salt=salt)
+        held_against_one_at_a_time(one, schedule, salt, got)
+        # the decode program ran beside the chunk program (a mesh's: full width)
+        assert any(decodes)
+        assert eng.decode_history_tiles_read == eng.decode_history_tiles_full > 0
+        # a row for every chunk of every prompt and none for a lane that
+        # decodes; the full width only where more lanes prefill at once than
+        # the rung under it holds (an admission wave)
+        assert eng.chunk_rows_live == sum(-(-n // CHUNK) for _, n, _, _ in schedule)
+        under = eng._chunk_rungs[-2]
+        assert chunks and all(
+            rows < engine_cfg.max_slots or lanes > under for rows, lanes in chunks
+        )
+        assert eng.chunk_rows_live > eng.chunk_lanes_fed
+        if schedule is SPILLS:  # as on one device: the long prompt goes on in the next step
+            assert eng.prompt_dispatches == 1 + 3 + 1 + 2
+            assert eng.chunk_lanes_fed == 7 and eng.chunk_rows_live == 13
+        # every sealed block's checksum, read ahead, is the plain read's
+        # (`extract_blocks`: take, to_host)
+        assert any(a is not None for a in ahead)
+        sealed = dict(eng.allocator._crc_of)
+        assert len(sealed) >= sum(n // engine_cfg.kv_block_size for _, n, _, _ in schedule)
+        for bid, crc in sealed.items():
+            assert crc == kv_pages.checksums(eng.extract_blocks([bid]))[0], bid
+        assert eng.allocator.active_blocks == 0 and not eng._zombie_allocs
+    finally:
+        eng.close()
+        one.close()
+
+
 def test_a_request_cancelled_between_its_lanes_two_dispatches_moves_no_other(wide, alone):
     """Request 1's first three rows went through one dispatch; it is cancelled
     before the step that would take the next three."""
@@ -387,7 +491,7 @@ def test_an_engine_whose_module_or_mesh_does_not_allow_it_gives_a_lane_one_row(
     from dynamo_tpu.models import llama
 
     if which == "rides":
-        eng = mesh_engine(params, tp=2)
+        eng = mesh_engine(params, WIDE_CFG, sp=2)
     else:
         monkeypatch.delattr(llama, "LANE_TAKES_ROWS")
         eng = JaxServingEngine(CFG, params, WIDE_CFG)

@@ -322,7 +322,7 @@ def history_profiles():
     ``block_until_ready`` (median of PROF_ITERS, default 10) at every lane on
     0 / 256 / 1,024 / 1,920 positions of history and at the cell's ragged
     profile: the program the engine serves on one device (live history) beside
-    the one a mesh engine keeps (``engine._rides``: every table's full width).
+    the one a mesh engine keeps (every table's full width).
     With PROF_TRACE=1 each form's ragged dispatches are traced and the op
     events of device 0 counted: the benchmark's tracer has a budget of them
     (PERF.md 7)."""
@@ -352,7 +352,10 @@ def history_profiles():
     rows = {}
     for form in ("live", "full width"):
         if form == "full width":
-            engine._rides = True  # the mesh engines' program, on one device
+            # a mesh engine's program, on a mesh of this one device
+            from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
+
+            engine.mesh = make_mesh(MeshConfig(), jax.devices()[:1])
             engine._decode_fns.clear()
         fn = engine._decode(False, False, False)
         # what a decode program costs set-up: its trace and lowering (Python,
