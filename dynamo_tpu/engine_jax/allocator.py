@@ -204,7 +204,8 @@ class BlockAllocator:
         salt: Optional[bytes] = None,
         host_pool: Optional[HostKvPool] = None,
         offload: Optional[Callable[[List[Tuple[int, int, Any]]], None]] = None,
-        checksum: Optional[Callable[[List[int]], List[int]]] = None,
+        checksum: Optional[Callable[[List[int], int], None]] = None,
+        await_crc: Optional[Callable[[int], None]] = None,
     ):
         self.num_blocks = num_blocks
         self.block_size = block_size
@@ -216,13 +217,23 @@ class BlockAllocator:
         self.host_pool = host_pool
         self._offload = offload
         # integrity plane (runtime/integrity.py, docs/resilience.md §Silent
-        # corruption): ``checksum([block_ids]) -> [crc32]`` is the engine's
-        # callback computing content checksums of freshly SEALED blocks
-        # (the one point where the bytes are final and the owner can vouch
-        # for them). None = integrity off: no crc is ever computed, stored,
-        # or verified — the exact pre-integrity allocator.
+        # corruption): ``checksum([block_ids], generation)`` is the engine's
+        # callback that has the content checksums of freshly SEALED blocks
+        # computed (the one point where the bytes are final and the owner
+        # can vouch for them): it reads the bytes then and there and returns;
+        # each value comes back through :meth:`crc_landed` under the
+        # generation of its seal. Until it has, the block's crc is PENDING,
+        # and whoever needs it calls ``await_crc(block_id)``, which returns
+        # once it has landed. None = integrity off: no crc is ever computed,
+        # stored, or verified — the exact pre-integrity allocator.
         self._checksum = checksum
+        self._await_crc = await_crc
         self._crc_of: Dict[int, int] = {}  # physical page id → seal crc
+        # physical page id → the generation of the seal whose crc is on its
+        # way. A value that lands under another generation is of bytes the
+        # page no longer holds (resealed, evicted, unregistered): dropped.
+        self._crc_pending: Dict[int, int] = {}
+        self._seal_generation = 0
         self._free: List[int] = list(range(num_blocks - 1, -1, -1))
         self._refcount: Dict[int, int] = {}
         # sequence_hash → block id, for every block whose contents are valid
@@ -292,8 +303,23 @@ class BlockAllocator:
     def crc_of_block(self, block_id: int) -> int:
         """Seal-time content checksum of a physical page, or -1 (unsealed,
         or sealed while the integrity plane was off). Ships next to the
-        pages on every transfer tier so receivers can verify them."""
+        pages on every transfer tier so receivers can verify them. A crc
+        that is pending is waited for: never -1 for a block that sealed."""
+        if block_id in self._crc_pending:
+            self._await_crc(block_id)
         return self._crc_of.get(block_id, -1)
+
+    def crc_pending(self, block_id: int) -> bool:
+        """Is this page's seal-time checksum still on its way?"""
+        return block_id in self._crc_pending
+
+    def crc_landed(self, block_id: int, generation: int, crc: int) -> None:
+        """The checksum of the bytes ``block_id`` held at seal ``generation``
+        has been computed. Registered if that seal is still the page's
+        latest; a late value for content since replaced is dropped."""
+        if self._crc_pending.get(block_id) == generation:
+            del self._crc_pending[block_id]
+            self._crc_of[block_id] = crc
 
     def blocks_needed(self, n_tokens: int) -> int:
         return (n_tokens + self.block_size - 1) // self.block_size
@@ -539,12 +565,15 @@ class BlockAllocator:
         alloc.sealed_blocks = len(alloc.token_blocks.blocks)
         if self._checksum is not None and stored:
             # seal-time content checksums (docs/resilience.md §Silent
-            # corruption): computed exactly once, while the owner can still
-            # vouch for the bytes; they travel with the block through every
-            # later tier (host spill, transfer frames, migration staging)
+            # corruption): computed exactly once, of the bytes read while the
+            # owner can still vouch for them; they travel with the block
+            # through every later tier (host spill, transfer frames,
+            # migration staging)
             bids = [self._by_hash[h] for h, _ in stored]
-            for bid, crc in zip(bids, self._checksum(bids)):
-                self._crc_of[bid] = crc
+            self._seal_generation += 1
+            for bid in bids:
+                self._crc_pending[bid] = self._seal_generation
+            self._checksum(bids, self._seal_generation)
         if stored and self._sink is not None:
             self._sink.blocks_stored(parent, stored)
 
@@ -653,11 +682,18 @@ class BlockAllocator:
             self._block_level.pop(bid, None)
             evicted.append(h)
             # the seal-time checksum follows the content into the host tier
-            # (verified at rehit); the page itself is being recycled
+            # (verified at rehit), waited for where it is still pending; the
+            # page itself is being recycled
+            spills = (
+                self._offload is not None and self.host_pool is not None
+                and h not in self.host_pool
+            )
+            if spills and bid in self._crc_pending:
+                self._await_crc(bid)
+            self._crc_pending.pop(bid, None)
             crc = self._crc_of.pop(bid, None)
-            if self._offload is not None and self.host_pool is not None:
-                if h not in self.host_pool:
-                    spill.append((h, bid, crc))
+            if spills:
+                spill.append((h, bid, crc))
             self._free.append(bid)
         if spill:
             self._offload(spill)
@@ -671,8 +707,10 @@ class BlockAllocator:
             self._by_hash.pop(h, None)
             if self._sink is not None:
                 self._sink.blocks_removed([h])
-        # content replaced ⇒ its seal checksum no longer describes the page
+        # content replaced ⇒ its seal checksum no longer describes the page,
+        # and one still on its way must not land on the new bytes
         self._crc_of.pop(bid, None)
+        self._crc_pending.pop(bid, None)
         self._cached.discard(bid)
         # the block's content is being replaced: its class tag must not
         # survive into the new owner's tier (levels only ever go UP via

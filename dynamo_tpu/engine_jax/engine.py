@@ -93,6 +93,7 @@ from dynamo_tpu.models.llama import (
     with_live_history,
 )
 from dynamo_tpu.engine_jax.compile_cache import compile_count, record_compile
+from dynamo_tpu.engine_jax.seal_crc import SealCrcWorker
 from dynamo_tpu.kv import pages as kv_pages
 from dynamo_tpu.kv.pages import KvDtypeMismatch, MigrationRejected
 from dynamo_tpu.runtime import faults as faults_mod
@@ -439,13 +440,14 @@ class _Inflight:
 
 
 class _SealPages:
-    """The pages of the blocks a dispatched program fills to their end, taken
-    off the pool by a program enqueued right behind it, their host copy
-    started then. Those blocks seal when the dispatch's result is processed,
-    and the seal-time checksum needs their bytes on the host: read there and
-    then, the read would queue behind whatever was dispatched since (the
-    decode program of the same host step, 48 ms) and hold the step loop for
-    as long."""
+    """The pages of some blocks, taken off the pool by a program enqueued
+    behind the one that fills them to their end, their host copy started
+    then: the bytes the seal-time checksum is of. Those blocks seal when the
+    dispatch's result is processed; taken as it was dispatched
+    (`_take_sealing`) they are on the host by then, where a read at the seal
+    queues behind whatever was dispatched since (the decode program of the
+    same host step, 48 ms). Either way the checksum worker waits for them
+    (`host`), not the step loop."""
 
     __slots__ = ("pages", "where", "_host")
 
@@ -455,10 +457,12 @@ class _SealPages:
         self._host = None
 
     def host(self):
+        """The set on the host, assembled once. The checksum worker's alone."""
         if self._host is None:
-            # dynlint: allow-host-sync(seal-time checksum: the copy was
-            # started when the pages were taken, a dispatch ago)
+            # dynlint: allow-host-sync(seal-time checksum, off the engine
+            # thread: the copy was started when the pages were taken)
             self._host = kv_pages.to_host(self.pages)
+            self.pages = None
         return self._host
 
 
@@ -476,6 +480,13 @@ class _ChunkInflight:
         self.sealing = sealing  # Optional[_SealPages]: the blocks it fills
         self.t_step = t_step  # for the straggler detector alone
         self.prof = prof  # the timeline samples this dispatch
+
+
+# How many dispatches' worth of sealed pages may wait for the checksum worker
+# before the step loop waits for it: a host step is a chunk and a decode
+# dispatch, so two steps. Enough to ride out a worker that is late by a step,
+# and no more host memory than that (32 blocks, 29 MB, a dispatch under tp=4).
+_SEAL_BACKLOG_DISPATCHES = 4
 
 
 def chunk_row_ladder(max_slots: int) -> List[int]:
@@ -671,6 +682,17 @@ class JaxServingEngine(AsyncEngine):
         # address (device_get raises), so those engines seal unchecked and
         # say so through the kv_seal_checksums gauge
         self._seal_checksums = self._integrity is not None and not self._multihost
+        # who computes them: a thread of its own (engine_jax/seal_crc.py),
+        # none where nothing is checksummed. Cumulative: blocks handed to it,
+        # and how often the engine thread waited for it, as a reader of a crc
+        # still pending and at the bound on what may be pending.
+        self._crc_worker = (
+            SealCrcWorker("jax-engine-seal-crc") if self._seal_checksums else None
+        )
+        self.seal_crc_blocks = 0
+        self.seal_crc_reader_waits = 0
+        self.seal_crc_reader_wait_us = 0.0
+        self.seal_crc_backlog_waits = 0
         # label the fault gates match on ("corrupt"/"poison" drills target
         # ONE worker in a fleet); attach_kv_publishing stamps the worker id
         self._fault_addr = "engine"
@@ -678,7 +700,8 @@ class JaxServingEngine(AsyncEngine):
             self.num_blocks, engine_config.kv_block_size, event_sink=event_sink,
             host_pool=self.host_pool,
             offload=self._offload_blocks if self.host_pool is not None else None,
-            checksum=self._block_checksums if self._seal_checksums else None,
+            checksum=self._seal_crcs if self._seal_checksums else None,
+            await_crc=self._seal_await if self._seal_checksums else None,
         )
 
         # attention impl is auto-selected (platform + head-dim rule,
@@ -1023,7 +1046,7 @@ class JaxServingEngine(AsyncEngine):
                 raise ValueError("pp and sp cannot be combined yet")
 
         # the pages of the blocks that the dispatch being processed fills
-        # (_SealPages), where _block_checksums looks first
+        # (_SealPages), where _seal_crcs looks first
         self._sealing: Optional[_SealPages] = None
 
         # Row counts of the chunk program (`chunk_row_ladder`), and who keeps
@@ -1067,6 +1090,11 @@ class JaxServingEngine(AsyncEngine):
         self._sealing_sizes: List[int] = [] if self._rides else [
             4 ** e for e in range(16) if 4 ** e < most
         ] + [most]
+        # the pages that may wait for the checksum worker, in bytes of the
+        # pool: what _SEAL_BACKLOG_DISPATCHES of the largest dispatches read
+        # ahead fill. Past it the engine thread waits for the oldest.
+        self._block_bytes = sum(a.nbytes for a in self.cache.values()) // self.num_blocks
+        self._seal_backlog_bytes = _SEAL_BACKLOG_DISPATCHES * most * self._block_bytes
 
         # which attention tier the decode programs hold, and whether the
         # kernel is built in Pallas interpret mode (the CPU route of the
@@ -2104,6 +2132,8 @@ class JaxServingEngine(AsyncEngine):
             self._cond.notify()
         if self._thread is not None:
             self._thread.join(timeout=5)
+        if self._crc_worker is not None:
+            self._crc_worker.close()
 
     def _step_loop(self) -> None:
         clock = self._clock
@@ -2132,6 +2162,7 @@ class JaxServingEngine(AsyncEngine):
                     and self._inflight is None
                     and not self._pending_spills
                     and self._counts is None  # idle pass frees it first
+                    and not self._seal_unsettled()  # and registers these
                 ):
                     if self._awaiting or self._staged_migrations:
                         # wake periodically to sweep remote-prefill
@@ -2168,6 +2199,9 @@ class JaxServingEngine(AsyncEngine):
                 or self._pending_spills
             ))
             clock.active = any(self._slots)
+            if self._crc_worker is not None and self._crc_worker.has_done:
+                with clock(P_SEAL_CRC):  # what it finished, or its failure
+                    self._seal_land()
             with clock(P_POSTED):
                 self._run_posted()
             with clock(P_SWEEP):
@@ -2600,10 +2634,27 @@ class JaxServingEngine(AsyncEngine):
         )
 
     def _dispatch_step(self) -> None:
+        if (
+            self._inflight is not None and self._carry_is_stale()
+            and not any(s is not None and s.prefill_pos is not None for s in self._slots)
+        ):
+            # A lane left the decode set and none prefills: the next decode
+            # dispatch is built on the host once what is in flight has been
+            # read (`_decode_build`), a wait of most of a dispatch. The caller
+            # whose stream just ended sends its next request during that
+            # wait: read first and admit again, so the request prefills
+            # behind this wait and not a dispatch later. (While the seal-time
+            # checksum ran on this thread it held the step that long, and the
+            # admission at the top of the next step found the request.)
+            self._drain_inflight()
+            with self._clock(P_ADMIT):
+                self._admit()
+            self._clock.active = any(self._slots)
         active = [s for s in self._slots if s is not None]
         if not active:
             self._prefill_debt = 0.0  # contention episode over
             self._drain_inflight()
+            self._seal_await()  # a quiet engine's registry is whole
             return
         prefilling = any(s.prefill_pos is not None for s in active)
         if not prefilling and self._prefill_debt:
@@ -2667,7 +2718,9 @@ class JaxServingEngine(AsyncEngine):
         of it and advances through the decode program in the same step. The
         chunk goes first (a first token is what a caller waits for), and
         both programs are dispatched before either result is fetched, so
-        the step leaves the device idle once and not twice. (Where `_rides`,
+        the step leaves the device idle once and not twice; the results are
+        read in the device's order, the displaced decode dispatch's before
+        the chunk's. (Where `_rides`,
         the lanes that decode are rows of the chunk dispatch instead and the
         decode program sits the step out.)
 
@@ -2691,14 +2744,18 @@ class JaxServingEngine(AsyncEngine):
             None if self._rides and chunk is not None
             else self._decode_dispatch()
         )
+        # results in the order the device gives them: the decode dispatch
+        # displaced ran before this step's chunk, and a lane that ends in it
+        # frees its slot (and its caller sends the next request) while the
+        # chunk is still waited for
+        prev = decode[0] if decode is not None else None
+        if prev is not None:
+            self._process_chunk(prev, defer_free=True)
         if chunk is not None:
             self._clock.steps[0] += 1
             self._chunk_finish(chunk)
         elif decode is not None:
             self._clock.steps[1] += 1
-        prev = decode[0] if decode is not None else None
-        if prev is not None:
-            self._process_chunk(prev, defer_free=True)
 
     def _chunk_dispatch(self, paced: bool, t_step: float) -> Optional[_ChunkInflight]:
         """Build and dispatch one [rows, prefill_chunk] program over the lanes
@@ -3027,6 +3084,14 @@ class JaxServingEngine(AsyncEngine):
             for s in self._slots
         ]
 
+    def _carry_is_stale(self) -> bool:
+        """Has the live set changed since the in-flight decode dispatch (a
+        lane left, or one finished its prefill: the same _Seq, but inert in
+        that dispatch's carry)?"""
+        return any(
+            a is not b for a, b in zip(self._inflight.lanes, self._live_lanes())
+        )
+
     def _decode_step(self) -> None:
         """Pipelined decode: dispatch chunk N+1 off the previous dispatch's
         device-resident carry, THEN fetch + process chunk N. The host↔device
@@ -3092,16 +3157,10 @@ class JaxServingEngine(AsyncEngine):
         where the live set changed, then the program and its arguments."""
         cfg = self.config
         S, k = cfg.max_slots, cfg.decode_steps
-        live = self._live_lanes()
-        if self._inflight is not None and any(
-            a is not b for a, b in zip(self._inflight.lanes, live)
-        ):
-            # the live set changed since the in-flight dispatch (a lane
-            # left, or one finished its prefill: the same _Seq, but inert
-            # in that dispatch's carry): the carry no longer matches; fall
-            # back to host-built inputs
+        if self._inflight is not None and self._carry_is_stale():
+            # the carry no longer matches; fall back to host-built inputs
             self._drain_inflight()
-            live = self._live_lanes()
+        live = self._live_lanes()
         n_active = sum(1 for s in live if s is not None)
         if not n_active:
             # nothing left to carry forward: what is in flight ends here
@@ -3690,6 +3749,9 @@ class JaxServingEngine(AsyncEngine):
         if seq.slot is not None:
             self._slots[seq.slot] = None
             seq.slot = None
+        if not any(self._slots):
+            # the last stream ends: its caller finds every crc registered
+            self._seal_await()
         if seq.alloc is not None:
             if seq.ctx.id in self._hold_ids:
                 # prefill-worker mode: park the pages for extraction; the
@@ -3812,21 +3874,72 @@ class JaxServingEngine(AsyncEngine):
         on the engine thread."""
         return [self.allocator.crc_of_block(bid) for bid in block_ids]
 
-    def _block_checksums(self, block_ids: List[int]) -> List[int]:
-        """The allocator's seal-time checksum callback: pull the freshly
-        sealed pages' bytes and crc them (runtime/integrity.py). This is
-        the integrity plane's steady-state cost — one small device→host
-        copy per sealed block, knob-gated by DYN_TPU_KV_INTEGRITY. MUST
-        run on the engine thread (note_tokens_computed call sites). Where
-        the dispatch being processed had these blocks' pages taken as it
-        was dispatched (_take_sealing), their bytes are on the host already."""
-        ahead = self._sealing
+    def _seal_crcs(self, block_ids: List[int], generation: int) -> None:
+        """The allocator's seal-time checksum callback: hand the freshly
+        sealed blocks' pages to the checksum worker, which waits for their
+        host copy, hashes each block and publishes its crc under
+        ``generation`` (`_seal_land` registers it). This is the integrity
+        plane's steady-state cost, knob-gated by DYN_TPU_KV_INTEGRITY: one
+        small device→host copy per sealed block, and the hand-over on this
+        thread. Where the dispatch being processed had these blocks' pages
+        taken as it was dispatched (`_take_sealing`), those are the pages;
+        else they are taken here, so the bytes are the seal's either way.
+        With more than `_seal_backlog_bytes` handed over and not hashed, the
+        engine thread waits for the oldest. MUST run on the engine thread
+        (note_tokens_computed call sites)."""
+        worker = self._crc_worker
         with self._clock(P_SEAL_CRC):
-            if ahead is not None and all(b in ahead.where for b in block_ids):
-                return kv_pages.checksums(kv_pages.select(
-                    ahead.host(), [ahead.where[b] for b in block_ids]
-                ))
-            return kv_pages.checksums(kv_pages.to_host(kv_pages.take(self.cache, block_ids)))
+            ahead = self._sealing
+            if ahead is None or not all(b in ahead.where for b in block_ids):
+                ahead = self._seal_read(block_ids)
+            self.seal_crc_blocks += len(block_ids)
+            worker.submit(
+                ahead, block_ids, generation, len(block_ids) * self._block_bytes
+            )
+            if worker.pending_bytes > self._seal_backlog_bytes:
+                self.seal_crc_backlog_waits += 1
+                worker.wait(self._seal_backlog_bytes)
+
+    def _seal_land(self) -> None:
+        """Register the crcs the worker has finished with the allocator,
+        which drops one whose block was resealed or unregistered since.
+        Raises what the worker raised. Engine thread: the top of a host step,
+        and wherever a crc is read."""
+        for bid, generation, crc in self._crc_worker.take_done():
+            self.allocator.crc_landed(bid, generation, crc)
+
+    def _seal_await(self, block_id: Optional[int] = None) -> None:
+        """Wait on the engine thread for the crcs that are pending and
+        register them: the allocator's callback where a reader found
+        ``block_id``'s pending (counted), and with None what makes the
+        registry whole where no slot is live."""
+        worker = self._crc_worker
+        if not self._seal_unsettled():
+            return
+        with self._clock(P_SEAL_CRC):
+            self._seal_land()
+            if block_id is not None:
+                if not self.allocator.crc_pending(block_id):
+                    return  # it was finished, only not yet registered
+                self.seal_crc_reader_waits += 1
+            t0 = time.perf_counter()
+            worker.wait(0)
+            self._seal_land()
+            if block_id is not None:
+                self.seal_crc_reader_wait_us += (time.perf_counter() - t0) * 1e6
+
+    def _seal_unsettled(self) -> bool:
+        """Is a crc with the worker, or finished and not yet registered?"""
+        worker = self._crc_worker
+        return worker is not None and bool(worker.pending_blocks or worker.has_done)
+
+    def _seal_read(self, block_ids: List[int]) -> _SealPages:
+        """Enqueue the read of ``block_ids`` off the pool, behind whatever
+        has been dispatched, and start its copy to the host."""
+        pages = kv_pages.take(self.cache, block_ids)
+        for a in pages.values():
+            a.copy_to_host_async()
+        return _SealPages(pages, {b: j for j, b in enumerate(block_ids)})
 
     def _take_sealing(self, filled: List[int]) -> Optional[_SealPages]:
         """Enqueue, behind the program just dispatched, the read of the blocks
@@ -3838,10 +3951,7 @@ class JaxServingEngine(AsyncEngine):
             return None
         size = next(b for b in self._sealing_sizes if b >= n)
         with self._clock(P_SEAL_READ):
-            pages = kv_pages.take(self.cache, filled + filled[-1:] * (size - n))
-            for a in pages.values():
-                a.copy_to_host_async()
-        return _SealPages(pages, {b: j for j, b in enumerate(filled)})
+            return self._seal_read(filled + filled[-1:] * (size - n))
 
     def _blocks_filled(self, alloc: SequenceAllocation, start: int, n: int) -> List[int]:
         """The blocks of ``alloc`` that positions ``[start, start + n)`` fill
@@ -4356,6 +4466,27 @@ class JaxServingEngine(AsyncEngine):
         with self._cond:
             return self._metrics_locked()
 
+    def _seal_crc_counters(self) -> Dict[str, Any]:
+        """The seal-time checksum off the engine thread (cumulative; none
+        where no block is checksummed): blocks sealed with one and blocks the
+        worker has hashed (equal once nothing is pending), the worker's busy
+        time (what the checksum costs; `host_phase_us.seal_crc` is the engine
+        thread's part: the hand-over and its waits), the most blocks that
+        were ever pending, and the engine thread's waits: as a reader of a
+        crc still pending, and at the bound on pending pages."""
+        worker = self._crc_worker
+        if worker is None:
+            return {}
+        return {
+            "seal_crc_blocks": self.seal_crc_blocks,
+            "seal_crc_blocks_offthread": worker.blocks_hashed,
+            "seal_crc_worker_us": round(worker.busy_us),
+            "seal_crc_pending_peak": worker.pending_peak,
+            "seal_crc_reader_waits": self.seal_crc_reader_waits,
+            "seal_crc_reader_wait_us": round(self.seal_crc_reader_wait_us),
+            "seal_crc_backlog_waits": self.seal_crc_backlog_waits,
+        }
+
     def _attention_tiers(self) -> Dict[str, Dict[str, Any]]:
         """Attention tier per compiled decode/verify variant (dense |
         pallas-v4|v2|v1 | pipeline | chunk-jnp) and whether a kernel in it
@@ -4461,6 +4592,7 @@ class JaxServingEngine(AsyncEngine):
             # quarantine counters ride attach_kv_publishing)
             "watchdog_trips": self.watchdog_trips,
             "kv_seal_checksums": int(self._seal_checksums),
+            **self._seal_crc_counters(),
             "attention_tiers": self._attention_tiers(),
         }
         if self._perf is not None:

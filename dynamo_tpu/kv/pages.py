@@ -17,6 +17,7 @@ process that never touches a device imports this module too.
 from __future__ import annotations
 
 import functools
+import zlib
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
@@ -233,6 +234,25 @@ def checksums(pages: Mapping[str, Any],
         int(c) if c is not None and c >= 0 else block_checksum(block(pages, i))
         for i, c in enumerate(sealed or [None] * count(pages))
     ]
+
+
+def checksums_at(pages: Mapping[str, Any], cols: Sequence[int]) -> List[int]:
+    """The checksums of the blocks at positions ``cols`` of a host set, the
+    values :func:`checksums` gives them, computed the way a thread beside
+    the engine's has to: each member of a block is copied once into one
+    contiguous buffer and hashed from there as bytes. numpy's copy and
+    ``zlib.crc32`` over a buffer both let go of the interpreter lock, which a
+    ``tobytes()`` does not, so between two blocks the thread holds it for a
+    few calls and no longer."""
+    arrays = [pages[m] for m in members(pages)]
+    crcs = []
+    for c in cols:
+        crc = 0
+        for a in arrays:
+            # bytes, because bf16 (ml_dtypes) has no buffer form of its own
+            crc = zlib.crc32(np.ascontiguousarray(a[:, c]).view(np.uint8), crc)
+        crcs.append(crc)
+    return crcs
 
 
 def verify(pages: Mapping[str, Any], crcs: Optional[Sequence[Optional[int]]],

@@ -639,6 +639,61 @@ def test_a_finished_lanes_blocks_wait_for_the_dispatch_in_flight_when_a_chunk_go
     assert len(answer(keep)[0]) == 40
 
 
+def test_a_request_that_arrives_while_a_stale_carry_is_read_prefills_behind_that_read(eng, alone):
+    """A lane leaves the decode set and none prefills: the next decode
+    dispatch is built on the host after what is in flight has been read. A
+    request that arrives during that read (the caller whose stream just ended
+    sends its next) is admitted behind it and its chunk goes in that host
+    step, beside the decode dispatch, not a dispatch later."""
+    a = submit(eng, prompt_of(9, 84), 1 + ENGINE_CFG.decode_steps)
+    keep = submit(eng, prompt_of(11, 85), 40)
+    step(eng)  # both prefill
+    step(eng)  # decode dispatch 1
+    step(eng)  # decode dispatch 2, then 1 processed: A is done
+    assert a.slot is None and eng._inflight is not None and eng._carry_is_stale()
+    arrived, drain = [], eng._drain_inflight
+
+    def read():
+        drain()
+        if not arrived:  # the request lands while the engine thread waits
+            arrived.append(submit(eng, prompt_of(12, 86), 6))
+
+    eng._drain_inflight = read
+    chunks = sum(eng.chunk_dispatches_by_rows.values())
+    try:
+        step(eng)
+    finally:
+        del eng._drain_inflight
+    (c,) = arrived
+    assert c.slot == 0 and c.prefill_pos is None and len(c.generated) == 1
+    assert sum(eng.chunk_dispatches_by_rows.values()) == chunks + 1
+    assert eng._inflight is not None and eng._inflight.lanes[keep.slot] is keep
+    run_out(eng)
+    assert answer(c)[0] == alone(prompt_of(12, 86), 6)[0]
+    assert answer(keep)[0] == alone(prompt_of(11, 85), 40)[0]
+
+
+def test_a_host_step_reads_its_results_in_the_devices_order(eng):
+    """The decode dispatch a prefill step displaces ran before that step's
+    chunk: its tokens go out, and a lane that ends in it frees its slot,
+    before the chunk is waited for."""
+    keep = submit(eng, prompt_of(9, 87), 40)
+    step(eng)
+    step(eng)  # a decode dispatch is in flight
+    before = eng._inflight
+    submit(eng, prompt_of(20, 88), 3)
+    order, process, finish = [], eng._process_chunk, eng._chunk_finish
+    eng._process_chunk = lambda chunk, **kw: (order.append(chunk), process(chunk, **kw))[1]
+    eng._chunk_finish = lambda chunk: (order.append("chunk"), finish(chunk))[1]
+    try:
+        step(eng)
+    finally:
+        del eng._process_chunk, eng._chunk_finish
+    assert order == [before, "chunk"]
+    run_out(eng)
+    assert len(answer(keep)[0]) == 40
+
+
 # -- the counters and the programs ---------------------------------------------
 
 

@@ -261,11 +261,16 @@ class TestZeroOverheadGuard:
 
         monkeypatch.setattr(integrity, "IntegrityTracker", _boom)
         monkeypatch.setattr(integrity, "checksum", _boom)
+        # nor the seal-time checksum's worker: no thread, no queue
+        from dynamo_tpu.engine_jax import engine as engine_mod
+
+        monkeypatch.setattr(engine_mod, "SealCrcWorker", _boom)
+        monkeypatch.setattr(kv_pages, "checksums_at", _boom)
 
         eng = _engine(tiny, host_cache_blocks=8)
         try:
             assert eng._integrity is None and not eng._watchdog
-            assert eng.allocator._checksum is None
+            assert eng.allocator._checksum is None and eng._crc_worker is None
             toks = run(_collect(eng, list(range(3, 27)), 8))
             assert len(toks) == 8
             assert eng.allocator._crc_of == {}
@@ -291,7 +296,9 @@ class TestZeroOverheadGuard:
             bid, crc = next(iter(eng.allocator._crc_of.items()))
             assert eng.allocator.crc_of_block(bid) == crc
             # the registry crc matches a fresh recompute of the live bytes
-            assert _call(eng, lambda: eng._block_checksums([bid]))[0] == crc
+            assert _call(
+                eng, lambda: kv_pages.checksums(eng.extract_blocks([bid]))
+            )[0] == crc
         finally:
             eng.close()
 

@@ -272,7 +272,13 @@ def test_the_engine_moves_pages_it_cannot_name(kind):
         assert _bytes(out) == {m: a[:, [3, 5]].tobytes() for m, a in want.items()}
         dev = _call(eng, lambda: eng.extract_blocks([3, 5], as_device=True))
         assert all(isinstance(a, jax.Array) for a in dev.values())
-        crcs = _call(eng, lambda: eng._block_checksums([3, 5]))
+
+        def seal():  # the seal-time path: a plain read, hashed by the worker
+            eng._seal_crcs([3, 5], 0)
+            eng._crc_worker.wait(0)
+            return [crc for _, _, crc in eng._crc_worker.take_done()]
+
+        crcs = _call(eng, seal)
         assert crcs == kv_pages.checksums(out)
 
         # spill blocks 3 and 5, lose them on the device, hit them again
