@@ -243,6 +243,82 @@ def openpangu_config(mc: Dict[str, Any], dtype: Any = jnp.bfloat16):
     )
 
 
+def xing4_config(mc: Dict[str, Any], dtype: Any = jnp.bfloat16):
+    """``model_type: xing4_0``: openPangu's block at other widths (latent
+    attention in every layer, dense feed-forwards first and sigmoid-routed
+    experts beside one shared expert after them, one multi-token-prediction
+    module), pre-norm, under ``hc_mult`` residual streams mixed by
+    manifold-constrained hyper-connections, rotated with YaRN's frequencies,
+    its experts selected under a bias (``noaux_tc``, one group). The YaRN group
+    is read from the published nested ``rope_scaling`` where the card has it,
+    else from its flat spelling (``rope_scaling_type``, ``rope_scaling_factor``,
+    ...: a harness that writes only scalar and list keys); a card with neither
+    rotates by ``rope_theta`` alone. What ``models/xing4.py`` does not run is
+    refused by name."""
+    from dynamo_tpu.models.xing4 import Xing4Config
+
+    def refuse(key: str, why: str, value=None):
+        raise ValueError(f"model_type 'xing4_0' with {key} = {mc.get(key, value)!r}: models/xing4.py {why}")
+
+    yarn = mc.get("rope_scaling") or {
+        k[len("rope_scaling_"):]: v for k, v in mc.items() if k.startswith("rope_scaling_")}
+    if yarn and yarn.get("type", yarn.get("rope_type")) != "yarn":
+        refuse("rope_scaling", "scales its rotary frequencies by YaRN (type yarn) or not at all", yarn)
+    if yarn and float(yarn.get("mscale", 1.0)) != float(yarn.get("mscale_all_dim", 0.0)):
+        refuse("rope_scaling", "multiplies cosine and sine by 1 (mscale = mscale_all_dim)", yarn)
+    for key in ("n_group", "topk_group"):
+        if int(mc.get(key, 1)) != 1:
+            refuse(key, "chooses its experts in one group (1)")
+    if mc.get("scoring_func", "sigmoid") != "sigmoid":
+        refuse("scoring_func", "scores its experts by a sigmoid")
+    if mc.get("topk_method", "noaux_tc") != "noaux_tc":
+        refuse("topk_method", "chooses under a selection bias (noaux_tc)")
+    if int(mc.get("n_shared_experts", 1)) != 1:
+        refuse("n_shared_experts", "runs one shared expert beside the routed ones")
+    if int(mc.get("num_nextn_predict_layers", 1)) > 1:
+        refuse("num_nextn_predict_layers", "holds one multi-token-prediction module at most")
+    if mc.get("attention_bias"):
+        refuse("attention_bias", "has no bias in its attention projections")
+    if int(mc.get("hc_mult", 4)) < 2:
+        refuse("hc_mult", "mixes two residual streams at least (a one-stream card is another module's)")
+    heads = int(mc["num_attention_heads"])
+    if int(mc.get("num_key_value_heads", heads)) != heads:
+        refuse("num_key_value_heads", "expands every head's keys from the one latent (= num_attention_heads)")
+    experts = int(mc["n_routed_experts"])
+    return Xing4Config(
+        vocab_size=int(mc["vocab_size"]),
+        hidden_size=int(mc["hidden_size"]),
+        intermediate_size=int(mc["intermediate_size"]),
+        num_layers=int(mc["num_hidden_layers"]),
+        num_heads=heads,
+        q_lora_rank=int(mc["q_lora_rank"]),
+        kv_lora_rank=int(mc["kv_lora_rank"]),
+        qk_nope_head_dim=int(mc["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(mc["qk_rope_head_dim"]),
+        v_head_dim=int(mc["v_head_dim"]),
+        rope_theta=float(mc.get("rope_theta", 10000.0)),
+        first_k_dense=int(mc.get("first_k_dense_replace", 2)),
+        moe_intermediate_size=int(mc["moe_intermediate_size"]),
+        num_experts=experts,
+        num_experts_published=int(mc.get("n_routed_experts_published", experts)),
+        num_experts_per_tok=int(mc["num_experts_per_tok"]),
+        routed_scaling_factor=float(mc.get("routed_scaling_factor", 2.0)),
+        moe_renormalize=bool(mc.get("norm_topk_prob", True)),
+        num_mtp_layers=int(mc.get("num_nextn_predict_layers", 1)),
+        rms_norm_eps=float(mc.get("rms_norm_eps", 1e-6)),
+        hc_mult=int(mc.get("hc_mult", 4)),
+        hc_sinkhorn_iters=int(mc.get("hc_sinkhorn_iters", 20)),
+        hc_eps=float(mc.get("hc_eps", 1e-6)),
+        hc_clamp=(float(mc.get("mhc_h_res_clamp_min", -30.0)), float(mc.get("mhc_h_res_clamp_max", 30.0))),
+        yarn_factor=float(yarn["factor"]) if yarn else None,
+        yarn_original_positions=int(yarn.get("original_max_position_embeddings", 4096)),
+        yarn_beta_fast=float(yarn.get("beta_fast", 32.0)),
+        yarn_beta_slow=float(yarn.get("beta_slow", 1.0)),
+        yarn_mscale_all_dim=float(yarn.get("mscale_all_dim", 0.0)),
+        dtype=dtype,
+    )
+
+
 def config_from_card(card: ModelDeploymentCard, dtype: Any = jnp.bfloat16):
     """Derive the model's config from the card's HF config.json contents: a
     LlamaConfig, or by ``model_type`` another module's (models.module_for),
@@ -258,6 +334,8 @@ def config_from_card(card: ModelDeploymentCard, dtype: Any = jnp.bfloat16):
         return qwen3_next_config(mc, dtype)
     if mc.get("model_type") == "pangu_ultra_moe":
         return openpangu_config(mc, dtype)
+    if mc.get("model_type") == "xing4_0":
+        return xing4_config(mc, dtype)
     if "num_experts" in mc and "num_local_experts" not in mc:
         # an expert model of a family this tree has no module for: a
         # LlamaConfig of it would be a dense impostor under its name

@@ -39,7 +39,9 @@ def module_for(model_config):
     ``models/openpangu.py`` has the first and not the second (latent pages and
     nothing else: ``state`` is ``None``), and a prediction module of its own,
     which ``draft_chunk`` and ``decode(..., draft=True)`` run where the engine
-    drafts. A module with state
+    drafts; ``models/xing4.py`` is on the same contract (its four residual
+    streams live inside a dispatch: what it hands the engine is one stream).
+    A module with state
     may set ``LANE_TAKES_ROWS`` once its chunk program, under the full width,
     (1) starts a row whose lane is that of the row above it from what that
     row leaves and not from the slot's stored state, (2) lets a lane's LAST

@@ -352,10 +352,15 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
     return (xf * jax.lax.rsqrt(var + eps) * weight).astype(x.dtype)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding; x: [B, T, H, D], positions: [B, T]."""
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float, inv_freq=None) -> jax.Array:
+    """Rotary embedding; x: [B, T, H, D], positions: [B, T]. ``inv_freq``
+    (``D / 2`` values, a model's scaled table: YaRN's blend of ``theta``'s
+    frequencies) takes the place of ``theta``'s own where given."""
     d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)  # [D/2]
+    if inv_freq is None:
+        freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)  # [D/2]
+    else:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
     angles = jnp.clip(positions, 0).astype(jnp.float32)[..., None] * freqs  # [B,T,D/2]
     cos = jnp.cos(angles)[:, :, None, :]  # [B,T,1,D/2]
     sin = jnp.sin(angles)[:, :, None, :]

@@ -124,6 +124,16 @@ class OpenPanguConfig:
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
+    # what a module on this one's mixer may state otherwise (models/xing4.py: YaRN)
+    @property
+    def score_scale(self) -> float:
+        return self.qk_head_dim ** -0.5
+
+    @property
+    def rope_inv_freq(self):
+        """A table of rotary frequencies in ``rope_theta``'s place, or None."""
+        return None
+
 
 def is_expert_layer(c: OpenPanguConfig, layer: int) -> bool:
     return layer >= c.first_k_dense
@@ -169,8 +179,10 @@ def _init_layer(key, c: OpenPanguConfig, experts: bool) -> Params:
     return lp
 
 
-def init_params(rng: jax.Array, config: OpenPanguConfig) -> Params:
-    """Random init with fan-in scaling; every norm weight one."""
+def init_params(rng: jax.Array, config: OpenPanguConfig, init_layer=_init_layer) -> Params:
+    """Random init with fan-in scaling; every norm weight one. ``init_layer``
+    makes a layer's tree (a module on this one's block brings its own:
+    ``models/xing4.py``)."""
     c = config
     e = c.hidden_size
 
@@ -180,7 +192,7 @@ def init_params(rng: jax.Array, config: OpenPanguConfig) -> Params:
     params = {
         "embed": dense(jax.random.fold_in(rng, 1000), (c.vocab_size, e), e),
         "final_norm": jnp.ones((e,), jnp.float32),
-        "layers": tuple(_init_layer(jax.random.fold_in(rng, i), c, is_expert_layer(c, i))
+        "layers": tuple(init_layer(jax.random.fold_in(rng, i), c, is_expert_layer(c, i))
                         for i in range(c.num_layers)),
         "lm_head": dense(jax.random.fold_in(rng, 1001), (e, c.vocab_size), e),
     }
@@ -189,7 +201,7 @@ def init_params(rng: jax.Array, config: OpenPanguConfig) -> Params:
         params["mtp"] = {
             "e_norm": jnp.ones((e,), jnp.float32), "h_norm": jnp.ones((e,), jnp.float32),
             "w_eh": dense(jax.random.fold_in(key, 0), (2 * e, e), 2 * e),
-            "layer": _init_layer(jax.random.fold_in(key, 1), c, True),
+            "layer": init_layer(jax.random.fold_in(key, 1), c, True),
             "norm": jnp.ones((e,), jnp.float32),
         }
     return params
@@ -266,8 +278,10 @@ def feed_forward(lp: Params, c: OpenPanguConfig, x: jax.Array, valid: jax.Array)
     with jax.named_scope("moe"):
         b, t, e = x.shape
         flat = x.reshape(b * t, e)
+        # a selection bias where the card has one (models/xing4.py: topk_method noaux_tc)
+        bias = lp["e_bias"] if "e_bias" in lp else jnp.zeros((c.num_experts_published,), jnp.float32)
         ids, weights = moe.route_sigmoid_topk(
-            flat, lp["router"], jnp.zeros((c.num_experts_published,), jnp.float32),
+            flat, lp["router"], bias,
             c.num_experts_per_tok, c.routed_scaling_factor, c.moe_renormalize)
         y, stats = moe.dropless_experts(
             flat, ids, weights, lp["w_gate"], lp["w_up"], lp["w_down"],
@@ -283,26 +297,34 @@ def _absorbed(lp: Params, c: OpenPanguConfig):
     around the keys: (``W_kvb``, ``W_o``) in front, (rank, no-position width,
     value width, the scores' scale) behind."""
     return ((lp["w_kvb"], lp["wo"]),
-            (c.kv_lora_rank, c.qk_nope_head_dim, c.v_head_dim, c.qk_head_dim ** -0.5))
+            (c.kv_lora_rank, c.qk_nope_head_dim, c.v_head_dim, c.score_scale))
+
+
+def mixer(lp: Params, c: OpenPanguConfig, a: jax.Array, positions: jax.Array, width: int, attend):
+    """Latent attention over the normed ``a`` ``[B, T, E]`` at ``positions``
+    ``[B, T]`` (< 0: padding). ``attend(lp, q [B, T, H, nope + rope], latent
+    [B, T, W]) -> [B, T, E]`` takes the tokens' cache entries where they belong
+    and attends what the queries see."""
+    eps = c.rms_norm_eps
+    with jax.named_scope("mla"):
+        b, t, _ = a.shape
+        latent = cached_latent(a, lp["w_kva"], lp["kv_norm"], c.kv_lora_rank, eps,
+                               positions, c.rope_theta, width, c.rope_inv_freq)
+        c_q = rms_norm(mm(a, lp["w_qa"]), lp["q_norm"], eps)
+        q = mm(c_q, lp["w_qb"]).reshape(b, t, c.num_heads, c.qk_head_dim)
+        dn = c.qk_nope_head_dim
+        q = jnp.concatenate(
+            [q[..., :dn], apply_rope(q[..., dn:], positions, c.rope_theta, c.rope_inv_freq)], axis=-1)
+        return attend(lp, q, latent)
 
 
 def _layer(lp: Params, c: OpenPanguConfig, x: jax.Array, positions: jax.Array, width: int, attend):
     """One decoder layer over ``x`` ``[B, T, E]`` at ``positions`` ``[B, T]``
-    (< 0: padding). ``attend(lp, q [B, T, H, nope + rope], latent [B, T, W])
-    -> [B, T, E]`` takes the tokens' cache entries where they belong and
-    attends what the queries see. Returns (x, the expert counters)."""
+    (< 0: padding), ``attend`` :func:`mixer`'s. Returns (x, the expert
+    counters)."""
     eps = c.rms_norm_eps
     valid = positions >= 0
-    a = rms_norm(x, lp["in_norm"], eps)
-    with jax.named_scope("mla"):
-        b, t, _ = a.shape
-        latent = cached_latent(a, lp["w_kva"], lp["kv_norm"], c.kv_lora_rank, eps,
-                               positions, c.rope_theta, width)
-        c_q = rms_norm(mm(a, lp["w_qa"]), lp["q_norm"], eps)
-        q = mm(c_q, lp["w_qb"]).reshape(b, t, c.num_heads, c.qk_head_dim)
-        dn = c.qk_nope_head_dim
-        q = jnp.concatenate([q[..., :dn], apply_rope(q[..., dn:], positions, c.rope_theta)], axis=-1)
-        attn = attend(lp, q, latent)
+    attn = mixer(lp, c, rms_norm(x, lp["in_norm"], eps), positions, width, attend)
     x = x + rms_norm(attn, lp["post_attn_norm"], eps)
     y, stats = feed_forward(lp, c, rms_norm(x, lp["pre_mlp_norm"], eps), valid)
     return x + rms_norm(y, lp["post_mlp_norm"], eps), stats
@@ -330,7 +352,7 @@ def _mtp_input(params: Params, c: OpenPanguConfig, hidden: jax.Array, next_token
 
 # -- the step programs --------------------------------------------------------
 
-def _in_groups(rows_fn, pool: jax.Array, arrays: tuple, width: int):
+def _in_groups(rows_fn, pool: jax.Array, arrays: tuple, width: int, counters: int = len(COUNTERS)):
     """``rows_fn(pool, *arrays) -> (h, pool, counters)`` over the rows of
     ``arrays`` (each ``[R, ...]``), all at once where they hold at most
     ``TOKENS_AT_ONCE`` positions and else in groups of that many, one after
@@ -348,7 +370,7 @@ def _in_groups(rows_fn, pool: jax.Array, arrays: tuple, width: int):
         return (pool, sums + more), h
 
     (pool, sums), h = jax.lax.scan(
-        group, (pool, jnp.zeros((len(COUNTERS),), jnp.int32)),
+        group, (pool, jnp.zeros((counters,), jnp.int32)),
         tuple(a.reshape(rows // at_once, at_once, *a.shape[1:]) for a in arrays))
     return h.reshape(rows, *h.shape[2:]), pool, sums
 

@@ -86,16 +86,17 @@ def mm(x: jax.Array, w: jax.Array) -> jax.Array:
 
 def cached_latent(x: jax.Array, w_kva: jax.Array, kv_norm: jax.Array, rank: int, eps: float,
                   positions: Optional[jax.Array] = None, theta: Optional[float] = None,
-                  width: Optional[int] = None) -> jax.Array:
+                  width: Optional[int] = None, inv_freq=None) -> jax.Array:
     """What the cache holds of each token of ``x`` ``[B, T, E]``: ``[RMSNorm(c)
     ; k^r]``, the key part rotated at ``positions`` ``[B, T]`` where ``theta``
-    is given (one head, shared by all), and zeros up to ``width`` where the
-    pool's rows are wider."""
+    is given (one head, shared by all; by ``inv_freq`` where the model scales
+    its frequencies: ``models/llama.py:apply_rope``), and zeros up to ``width``
+    where the pool's rows are wider."""
     kv = mm(x, w_kva)
     lat = rms_norm(kv[..., :rank], kv_norm, eps)
     k_r = kv[..., rank:]
     if theta is not None:
-        k_r = apply_rope(k_r[:, :, None, :], positions, theta)[:, :, 0]
+        k_r = apply_rope(k_r[:, :, None, :], positions, theta, inv_freq)[:, :, 0]
     held = [lat, k_r]
     if width is not None and width > kv.shape[-1]:
         held.append(jnp.zeros((*kv.shape[:-1], width - kv.shape[-1]), kv.dtype))

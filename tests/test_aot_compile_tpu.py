@@ -1068,3 +1068,87 @@ def test_a_576_wide_latent_row_is_padded_and_copied_by_the_chips_compiler(monkey
     hlo = re.sub(r"/\*.*?\*/", "", compiled.as_text())
     copies = re.findall(r"= f32\[(?:5,12288,16,576|61440,16,576|983040,576)\]\{[^}]*\} copy\(", hlo)
     assert copies, "the compiler no longer copies a 576-wide pool: the padding may go (ROADMAP M9b)"
+
+
+@functools.lru_cache(maxsize=None)
+def _compile_xing4(program, one_chip, rows=8):
+    """``models/xing4.py``'s decode or chunk program at ``batch.xing4.0-29b-a4b``'s
+    served shapes (1 dense + 4 expert layers of the 40, ALL 64 experts, 32
+    heads and 131,072 vocabulary rows, the prediction module held, 64 slots,
+    block 16, 12,288 blocks, 2,048 positions; a chunk of ``rows`` x 128
+    tokens), the pool donated, for the described chip."""
+    from dynamo_tpu.models import xing4
+
+    c = xing4.Xing4Config(num_layers=5, first_k_dense=1)
+    slots, mb, chunk = 64, 128, 128
+
+    def sd(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = jax.tree.map(sd, jax.eval_shape(lambda: xing4.init_params(jax.random.PRNGKey(0), c)))
+    cache = jax.tree.map(sd, jax.eval_shape(lambda: xing4.make_kv_cache(c, 12288, 16)))
+    if program == "decode":
+        def greedy(logits, pos, carry, k):
+            return jnp.argmax(logits, -1).astype(jnp.int32), carry, jnp.argmax(logits, -1)
+
+        return jax.jit(
+            lambda p, kv, toks, pos, tables: xing4.decode(p, c, toks, pos, kv, tables, None, 4, 2047, greedy, 0),
+            donate_argnums=(1,),
+        ).lower(params, cache, i32(slots), i32(slots), i32(slots, mb)).compile()
+    return jax.jit(
+        lambda p, kv, toks, pos, tables, lanes: xing4.forward_chunk(p, c, toks, pos, kv, tables, None, lanes),
+        donate_argnums=(1,),
+    ).lower(params, cache, i32(rows, chunk), i32(rows, chunk), i32(rows, mb), i32(rows)).compile()
+
+
+@pytest.mark.timeout(400)
+@pytest.mark.parametrize("program, rows", [("decode", 64), ("chunk", 8), ("chunk", 64)],
+                         ids=["decode", "chunk_8_rows", "chunk_64_rows"])
+def test_xing4s_step_programs_fit_and_hold_no_padded_residual(monkeypatch, one_chip, program, rows):
+    """``models/xing4.py`` at ``batch.xing4.0-29b-a4b``'s served shapes, for the
+    chip's compiler: the programs fit beside 9.64 GB of weights and 2.52 GB of
+    float32 latent pages; NO instruction copies the pool or a view of it, none
+    an expert layer's ``[64, 3584, 1024]`` matrices. The residual path's shapes
+    are new to the chip's tiling, and the guard is on them: no value has an
+    ``[.., 4, 3584]`` minor under the ``mhc`` scope (the four streams are a tuple
+    of ``[.., 3584]`` arrays, whatever the compiler would tile ONE array of them
+    by), ``φ`` ``[24,
+    14336]`` lies in the plain bf16 tiling (held ``[14336, 24]`` its 24 columns
+    would be padded to 128 lanes), the maps' sweeps run over ``[.., rows]`` with
+    the rows in the minor axis, and one sublayer's residual path is some 30
+    kernels, not the 100 that ``m.sum(axis)`` over ``[rows, 4, 4]`` costs."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels compiled, not interpreted
+    compiled = _compile_xing4(program, one_chip, rows)
+    hlo = re.sub(r"/\*.*?\*/", "", compiled.as_text())
+    views = r"5,12288,16,640|61440,16,640|983040,640"
+    big = re.findall(rf"= (?:f32|bf16)\[(?:{views}|64,3584,1024|64,1024,3584)\]\{{[^}}]*\}} copy\(", hlo)
+    assert big == [], big
+    layouts = set(re.findall(rf"= f32\[(?:{views})\](\{{[^}}]*\}})", hlo))
+    assert layouts and all("T(8,128)" in x for x in layouts), layouts
+    # the streams are never ONE [.., 4, 3584] array (the expert layer's four outputs a token are: ops/moe.py's)
+    assert not [line for line in compiled.as_text().splitlines()
+                if "/mhc/" in line and re.search(r"= f32\[(?:\d+,)*4,3584\]", line)]
+    phi = set(re.findall(r"bf16\[24,14336\](\{[^}]*\})", hlo))
+    assert phi and all("T(8,128)(2,1)" in x for x in phi), phi
+    tokens = 64 if program == "decode" else 512  # a decode step's lanes, a chunk group's positions
+    assert re.search(rf"f32\[(?:1,)?{tokens}\]|f32\[\d+,{tokens}\]|f32\[{tokens},1\]", hlo)
+    if program == "decode":
+        assert "f32[64,2048,640]" in hlo
+    else:
+        assert "f32[4,32,128,2048]" not in hlo and "f32[4,32,128,256]" in hlo  # scores: a tile, never a table
+    # 10 sublayers (a decode step's; a chunk group's): the sweeps fuse
+    mhc = len(re.findall(r'fusion\([^\n]*op_name="[^"]*/mhc/', compiled.as_text()))
+    assert 10 * 15 <= mhc <= 10 * 60, mhc
+    memory = compiled.memory_analysis()
+    pool_bytes = 5 * 12288 * 16 * 640 * 4
+    assert memory.alias_size_in_bytes >= pool_bytes
+    kernels = len(re.findall(r"custom_call_target=\"tpu_custom_call\"", hlo))
+    assert "grouped_product" in hlo and kernels == 3 * 4, kernels
+    # beside the arguments: a decode dispatch's dense histories (1.68 GB) and its steps; a chunk's groups
+    # hold what 512 positions need (32 heads: a quarter of openPangu's)
+    limit = {"decode": 2_000_000_000, "chunk": 600_000_000}
+    assert memory.temp_size_in_bytes < limit[program], memory.temp_size_in_bytes
+    assert memory.temp_size_in_bytes + pool_bytes + 9_636_741_384 < 15_750_000_000

@@ -11,8 +11,9 @@ on the chip (:func:`kda_profiles`); ``... mamba`` times a Mamba layer's
 selective scan ALONE over a chunk and over a decode step beside the whole mixer
 (:func:`mamba_profiles`); ``... mla`` times ONE layer's absorbed latent
 attention ALONE at ``batch.openpangu-ultra-moe-718b``'s two shapes, beside its
-bytes and operations (:func:`mla_profiles`); without a word, the round-5
-ablation below.
+bytes and operations (:func:`mla_profiles`); ``... mhc`` times the residual
+path of ONE sublayer of ``xing4.0-29b-a4b`` ALONE, beside the bytes it must
+move (:func:`mhc_profiles`); without a word, the round-5 ablation below.
 
 Method notes:
 - every measurement chains computations via data dependencies and fences
@@ -1039,6 +1040,65 @@ def mla_profiles():
                    b, t, context, trips * tile, carried=trips * 3 * b * t * h * r * 4)
 
 
+def mhc_profiles():
+    """The residual path of ONE sublayer of ``xing4.0-29b-a4b`` (``ops/mhc.py``
+    as ``models/xing4.py`` calls it: four float32 streams of 3,584, ``φ`` bf16
+    ``[24, 14336]``, 20 Sinkhorn sweeps) ALONE, PROF_ITERS (default 8)
+    sublayers chained in one dispatch (the sublayer between mixing in and
+    mixing out is ``y = u``), at each of PROF_ROWS' token rows (default
+    64,512: a decode step's lanes, a chunk group's positions): the whole path,
+    then the maps alone (``x̂ φ`` and the sweeps, no mixing) and the sweeps
+    alone (the 40 dependent normalisations over ``[4, 4, rows]``). Beside each
+    time: the bytes the path must move (``benchmark/bytes_and_flops_xing4.py``:
+    ``mhc_bytes_per_token`` x rows + ``φ``) over the chip's bandwidth."""
+    from benchmark import bytes_and_flops, bytes_and_flops_xing4 as counts
+    from dynamo_tpu.engine_jax.compile_cache import enable_compile_cache
+    from dynamo_tpu.models import xing4
+    from dynamo_tpu.ops import mhc
+
+    on_chip = jax.default_backend() == "tpu"
+    peaks = bytes_and_flops.load_peaks(jax.devices()[0].device_kind) if on_chip else {}
+    enable_compile_cache()
+    n_iter = int(os.environ.get("PROF_ITERS", "8"))
+    c = xing4.Xing4Config(num_layers=1, first_k_dense=1)
+    shape = {"hc_mult": c.hc_mult, "hidden_size": c.hidden_size}
+    hp = xing4._init_hc(jax.random.PRNGKey(0), c)
+    args = (c.hc_sinkhorn_iters, c.hc_eps, c.rms_norm_eps, c.hc_clamp)
+
+    def path(streams, hp):
+        h_pre, h_post, h_res = mhc.mhc_maps(streams, hp["phi"], hp["b"], hp["alpha"], *args)
+        return mhc.mix_out(streams, h_res, h_post, mhc.mix_in(streams, h_pre))
+
+    def maps_alone(streams, hp):
+        h_pre, h_post, h_res = mhc.mhc_maps(streams, hp["phi"], hp["b"], hp["alpha"], *args)
+        nudge = 1e-3 * (h_pre.sum(-1) + h_post.sum(-1) + h_res.sum((-1, -2)))[..., None]
+        return tuple(x + nudge for x in streams)
+
+    def sweeps_alone(m, hp):
+        return jnp.exp(-mhc.sinkhorn(m, c.hc_sinkhorn_iters, c.hc_eps))  # positive again, and dependent
+
+    def ms_a_call(fn, carry) -> float:
+        @jax.jit
+        def chain(carry, hp):
+            return jax.lax.scan(lambda x, _: (fn(x, hp), ()), carry, None, length=n_iter)[0]
+        return median_ms(chain, carry, hp) / n_iter
+
+    for rows in (int(x) for x in os.environ.get("PROF_ROWS", "64,512").split(",")):
+        streams = tuple(jax.random.normal(jax.random.PRNGKey(j), (rows, c.hidden_size)) for j in range(c.hc_mult))
+        logits = jax.random.uniform(jax.random.PRNGKey(9), (c.hc_mult, c.hc_mult, rows), jnp.float32, -3.0, 3.0)
+        must = counts.mhc_bytes_per_token(shape) * rows + counts.mhc_phi_bytes(shape)
+        for name, ms in (("the whole path", ms_a_call(path, streams)),
+                         ("the maps alone", ms_a_call(maps_alone, streams)),
+                         ("the sweeps alone", ms_a_call(sweeps_alone, jnp.exp(logits)))):
+            line = (f"mhc {name}, {rows} rows: {ms:8.4f} ms a sublayer; the path must move "
+                    f"{must / 1e6:7.2f} MB ({counts.mhc_bytes_per_token(shape)} B a token + phi "
+                    f"{counts.mhc_phi_bytes(shape)} B)")
+            if on_chip:
+                floor = must / peaks["hbm_bytes_per_s"] * 1e3
+                line += f": {floor:7.4f} ms at the chip's bandwidth, {floor / ms:5.1%} of this time"
+            print(line, flush=True)
+
+
 if __name__ == "__main__":
     {"history": history_profiles, "experts": expert_profiles, "kda": kda_profiles,
-     "mamba": mamba_profiles, "mla": mla_profiles}.get(" ".join(sys.argv[1:2]), main)()
+     "mamba": mamba_profiles, "mla": mla_profiles, "mhc": mhc_profiles}.get(" ".join(sys.argv[1:2]), main)()
