@@ -1068,10 +1068,14 @@ class JaxServingEngine(AsyncEngine):
         # a lane may fill several rows of a chunk dispatch under the full
         # width with successive pieces of its prompt (`chunk_rows_of`) where
         # the model's module says its chunk program lets a row attend the
-        # earlier rows of its lane. A module that keeps state per slot beside
-        # the pages does not (the state would pass from row to row inside its
-        # kernels), and the engines of one rung keep one row a lane
-        # (`_rides`).
+        # earlier rows of its lane: whatever it keeps, the module is the one
+        # that speaks (`models.module_for`). One WITHOUT state per slot says
+        # so when the rows find each other's fresh keys, in the program's
+        # hands (`models/llama.py`) or through the pool (`openpangu`,
+        # `xing4`); one WITH state when it also hands the state from row to
+        # row (`lfm2`, `jamba`; `kimi_linear` and `qwen3_next` say nothing,
+        # until their kernel does). The engines of one rung keep one row a
+        # lane (`_rides`).
         self._lane_rows = (
             len(self._chunk_rungs) > 1
             and getattr(self.model, "LANE_TAKES_ROWS", False)

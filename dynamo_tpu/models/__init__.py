@@ -29,8 +29,9 @@ def module_for(model_config):
     for the host's count), and beside them says whether a lane may fill
     several rows of one chunk dispatch (``LANE_TAKES_ROWS = True``: its chunk
     program takes the rows' lanes and lets a row attend the fresh keys of the
-    earlier rows of its lane; a module that says nothing keeps one row a
-    lane). A module that brings its OWN step programs has ``COUNTERS`` and
+    earlier rows of its lane, out of its own hands or out of the pool; a
+    module that says nothing keeps one row a lane). A module that brings its
+    OWN step programs has ``COUNTERS`` and
     ``forward_chunk`` / ``decode`` in the form ``engine_jax/engine.py`` calls
     them, with the slots' state in and out; a module whose layers keep state
     per slot beside the pages ALSO has ``make_slot_state``, and only such a
@@ -41,7 +42,16 @@ def module_for(model_config):
     which ``draft_chunk`` and ``decode(..., draft=True)`` run where the engine
     drafts; ``models/xing4.py`` is on the same contract (its four residual
     streams live inside a dispatch: what it hands the engine is one stream).
-    A module with state
+    A module WITHOUT state may set ``LANE_TAKES_ROWS`` when a later row of a
+    lane finds the earlier rows' fresh keys: ``models/llama.py`` hands them
+    over inside the program; ``openpangu`` and ``xing4`` (the fifth and sixth
+    modules that set it) let the rows meet through the pool: a layer writes
+    every row's latents of a group before any row attends, a row reads its
+    block table out of the pool under a causal mask by position, and the
+    groups run in order over one pool. Of the four conditions below (1) and
+    (2) are then empty (nothing is kept by lane), (3) is one read of the
+    table, and (4) holds because ``lanes`` is read by no equation: the program
+    is the same at every rung. A module with state
     may set ``LANE_TAKES_ROWS`` once its chunk program, under the full width,
     (1) starts a row whose lane is that of the row above it from what that
     row leaves and not from the slot's stored state, (2) lets a lane's LAST

@@ -851,9 +851,11 @@ def history_tiles_full(block_size: int, table_blocks: int) -> int:
 # A lane may fill several rows of one chunk dispatch with successive pieces of
 # its prompt (the engine's `_chunk_build` says when): attention through pages
 # is all that carries a sequence's past here, and the fresh keys of a lane's
-# earlier rows are in the program's hands (`chunk_sibling_partial`). A module
-# whose layers hand state from token to token beside the pages does not say
-# this, and keeps one row a lane (docs/kv_cache_manager.md).
+# earlier rows are in the program's hands (`chunk_sibling_partial`). Every
+# module speaks for itself (`models.module_for`): one whose layers hand state
+# from token to token beside the pages says this once it also hands that state
+# from row to row, and keeps one row a lane until then
+# (docs/kv_cache_manager.md).
 LANE_TAKES_ROWS = True
 
 

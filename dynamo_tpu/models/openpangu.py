@@ -19,7 +19,9 @@ So it has no ``make_slot_state``, the engine hands its programs ``state =
 None`` and takes None back, and everything that hands pages over (a prefix hit,
 ``verify``, preemption, the host tier, a transfer) is open to it as to
 ``models/llama.py`` (docs/kv_cache_manager.md, "State per slot": the two facts
-and the table).
+and the table). A lane may fill several rows of one chunk dispatch with
+successive pieces of its prompt (``LANE_TAKES_ROWS``, below: the rows meet
+through the pool, so the chunk program is the one it was at every rung).
 
 A layer on ``x``: ``a = N_in(x)``; ``x += N_post_attn(MLA(a))``; ``m =
 N_pre_mlp(x)``; ``x += N_post_mlp(FF(m))``. The layers differ in kind (dense,
@@ -80,6 +82,21 @@ MOE_COUNTERS = COUNTERS.index("mla_layer_calls")  # the first: what ops/moe.py:d
 # 256 keys are 128 KB of float32 a position)
 TOKENS_AT_ONCE = 512
 LANES = 128  # of a register: the pool's rows are whole registers wide
+# A lane may fill several rows of one chunk dispatch with successive pieces of
+# its prompt (engine_jax/engine.py:chunk_rows_of), each row with the lane's
+# block table. What that rests on: inside a group of rows a layer writes EVERY
+# row's latents into the pool before any row attends (`_paged`), a row reads
+# its table out of the pool under a causal mask by position, and the groups run
+# one after another over the pool the last one left (`_in_groups`); so a later
+# row meets its lane's earlier rows' fresh keys where they lie, in its own
+# group or an earlier one, and the prediction module's own layer is written and
+# read the same way (`draft_chunk`). Of `models.module_for`'s four conditions
+# (1) and (2) are empty (nothing is kept by lane: no row starts from a slot's
+# state and none writes one back), (3) holds through the pool (a row's history
+# and its lane's rows between are ONE read of the table), and (4) holds because
+# the program's text is the same at every rung: `forward_chunk` takes ``lanes``
+# and reads it nowhere.
+LANE_TAKES_ROWS = True
 
 _expert_parts = partial(operand_parts, parts=PASSES)  # ops/moe.py:dropless_experts' ``parts_of``
 
@@ -231,15 +248,18 @@ def make_kv_cache(
         dtype or jnp.float32)}
 
 
-def chunk_history_tiles(positions, block_size: int, table_blocks: int):
+def chunk_history_tiles(positions, block_size: int, table_blocks: int, lanes=None):
     """Trips of the chunk programs' loop over a block table (``ops/latent.py:
     attend_absorbed_tiled``) for rows at ``positions`` ``[B, C]``: the tiles of
     ``models/llama.py:history_tile`` positions up to the one that holds the
     rows' last position (the rows' fresh latents are in the pool before the
     loop, so it reads them there), none for padding rows alone, and no more
     than cover a table. A group of rows makes its own trips; over a whole
-    dispatch this is its longest row's, which is the host's count. Written for
-    a traced array and a numpy one alike, as ``models/llama.py``'s."""
+    dispatch this is its longest row's, which is the host's count. ``lanes``
+    ``[B]`` is the engine's call form where a lane may fill several rows
+    (``models/llama.py``'s reads it); it is taken and NOT read: the count is the
+    dispatch's longest reach, whichever lane a row belongs to. Written for a
+    traced array and a numpy one alike, as ``models/llama.py``'s."""
     tile = history_tile(block_size, table_blocks)
     reach = (positions.max() + 1).clip(0, table_blocks * block_size)
     return (reach + tile - 1) // tile
@@ -407,12 +427,14 @@ def forward_chunk(
     kv_cache: KVCache, block_tables: jax.Array, state: None, lanes: jax.Array,
     raw: bool = False,
 ):
-    """A ``[R, C]`` block of tokens, one row per lane (``lanes`` is the
-    engine's call form; nothing here is kept by lane), valid tokens (position
-    >= 0) a prefix of each row; a row may start at any position (a prefix hit,
-    a later chunk, a verify dispatch): what lies before it is read from the
-    pages, as its own tokens' latents are once written, and no page past the
-    last position of the group of rows it is computed with.
+    """A ``[R, C]`` block of tokens, a row a lane or (``LANE_TAKES_ROWS``)
+    successive pieces of a lane's prompt in consecutive rows, in order, each
+    with the lane's block table (``lanes`` is the engine's call form; nothing
+    here is kept by lane and it is read nowhere), valid tokens (position >= 0)
+    a prefix of each row; a row may start at any position (a prefix hit, a
+    later chunk, a lane's later row, a verify dispatch): what lies before it
+    is read from the pages, as its own tokens' latents are once written, and
+    no page past the last position of the group of rows it is computed with.
 
     Returns (hidden ``[R, C, E]`` after the final norm, or before it where
     ``raw``; the pool with the rows' latents written; ``state`` as it came:

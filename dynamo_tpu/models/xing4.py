@@ -10,9 +10,12 @@ slot) and the contract with the engine are ``models/openpangu.py``'s, taken
 from there as they are: the module brings its own step programs
 (``forward_chunk``, ``draft_chunk``, ``decode``, ``COUNTERS``:
 ``models.module_for``), the engine hands them ``state = None``, and a prefix
-hit, ``verify``, preemption, the host tier and a transfer are open to it. What
-is this module's own is the residual path. Between sublayers a token's state
-is ``hc_mult`` streams (a tuple of ``[B, T, E]`` arrays: ``ops/mhc.py`` says
+hit, ``verify``, preemption, the host tier and a transfer are open to it, and
+a lane may fill several rows of one chunk dispatch (``LANE_TAKES_ROWS`` is
+``models/openpangu.py``'s own statement, imported with the functions it rests
+on: ``_paged`` and ``_in_groups`` are called here as they are, and the maps of
+the residual path are a token's own). What is this module's own is the
+residual path. Between sublayers a token's state is ``hc_mult`` streams (a tuple of ``[B, T, E]`` arrays: ``ops/mhc.py`` says
 why no ``[n, E]``-minor array); they start as copies of the embedding and end
 as their sum, so what the engine sees (``hidden [R, C, E]`` after the final
 norm, or raw for :func:`draft_chunk`) is ONE stream, as any module's.
@@ -52,8 +55,8 @@ import jax.numpy as jnp
 from dynamo_tpu.models import openpangu as base
 from dynamo_tpu.models.llama import embed_lookup, rms_norm
 from dynamo_tpu.models.openpangu import (  # noqa: F401  (the module's contract: models.module_for)
-    MOE_COUNTERS, chunk_history_tiles, decode_history_tiles, feed_forward, final_norm, is_expert_layer,
-    lm_head, make_kv_cache, mixer, param_shardings,
+    LANE_TAKES_ROWS, MOE_COUNTERS, chunk_history_tiles, decode_history_tiles, feed_forward, final_norm,
+    is_expert_layer, lm_head, make_kv_cache, mixer, param_shardings,
 )
 from dynamo_tpu.ops import mhc
 from dynamo_tpu.ops.latent import attend_absorbed, gather_latent, write_latent
@@ -220,10 +223,12 @@ def forward_chunk(
     raw: bool = False,
 ):
     """``models/openpangu.py:forward_chunk``'s contract: a ``[R, C]`` block of
-    tokens, a row a lane, valid tokens a prefix of each row, a row starting at
-    any position. Returns (hidden ``[R, C, E]``: the streams' sum after the
-    final norm, or before it where ``raw``; the pool with the rows' latents
-    written; ``state`` as it came: None; the counters ``[len(COUNTERS)]``)."""
+    tokens, a row a lane or successive pieces of a lane's prompt in consecutive
+    rows (``lanes`` is read nowhere), valid tokens a prefix of each row, a row
+    starting at any position. Returns (hidden ``[R, C, E]``: the streams' sum
+    after the final norm, or before it where ``raw``; the pool with the rows'
+    latents written; ``state`` as it came: None; the counters
+    ``[len(COUNTERS)]``)."""
     c = config
 
     def rows_fn(pool, tokens, positions, block_tables):

@@ -15,6 +15,7 @@ hit, ``verify`` with the module's own drafts, the host tier.
 
 import asyncio
 import dataclasses
+import inspect
 import types
 
 import jax
@@ -134,7 +135,12 @@ def feed(cfg, params, cache, tokens, start, n, table, *, drafting=False, followi
 def test_the_module_is_found_by_its_config_and_keeps_nothing_per_slot(cfg):
     assert module_for(cfg) is op and module_for(llama.LLAMA_PRESETS["tiny"]) is llama
     assert [op.is_expert_layer(cfg, i) for i in range(3)] == [False, True, True]
-    assert not hasattr(op, "make_slot_state") and not hasattr(op, "LANE_TAKES_ROWS")
+    # nothing per slot, and a lane may fill several rows of a dispatch: the rows meet through the pool
+    assert not hasattr(op, "make_slot_state") and op.LANE_TAKES_ROWS is True
+    # the engine's call form where a lane may: the rows' lanes behind the table's width, taken and not read
+    assert list(inspect.signature(op.chunk_history_tiles).parameters)[3:] == ["lanes"]
+    reach = np.asarray([[40, 41, -1], [3, 4, 5], [-1, -1, -1]])
+    assert op.chunk_history_tiles(reach, BS, 16, np.asarray([2, 2, 7])) == op.chunk_history_tiles(reach, BS, 16) == 1
     assert op.COUNTERS[:6] == ("moe_layer_calls", "moe_held_rows", "moe_experts_hit",
                                "moe_routed_pairs", "moe_rows_computed", "moe_expert_reads")
     pool = op.make_kv_cache(cfg, 16, BS)
@@ -217,33 +223,118 @@ def test_a_lane_that_starts_past_position_zero_is_rotated_at_its_own_positions(c
     assert np.abs(np.asarray(fresh) - want[16:]).max() > 100 * ATOL
 
 
-@pytest.mark.parametrize("pieces", [(16, 13), (9, 16), (16, 16, 8)],
-                         ids=["two_rows", "a_short_first_row", "three_rows"])
-def test_a_later_row_of_one_dispatch_attends_the_rows_before_it_through_the_pool(cfg, params, pieces):
+# Successive pieces of a prompt in consecutive rows of ONE chunk dispatch. ``dispatches``: each a list of
+# rows in order, (lane, valid tokens) or None (a padding row: no lane's, its table all zeros); a lane's
+# rows go on where its last one ended, dispatch after dispatch. ``at_once``: rows of a group of
+# ``_in_groups`` (the served 128-token chunk has 4; here TOKENS_AT_ONCE is set so that a lane's rows lie
+# in several groups). ``hits``: lane -> (the lane whose first tokens and blocks it shares, how many
+# positions: a prefix hit, the lane's first row starts behind them on pages an earlier dispatch wrote).
+LANE_ROWS = {
+    "two_rows": dict(dispatches=[[(0, 16), (0, 13), None]]),
+    "a_short_first_row": dict(dispatches=[[(0, 9), (0, 16), None]]),
+    "three_rows": dict(dispatches=[[(0, 16), (0, 16), (0, 8), None]]),
+    "two_lanes_whose_rows_straddle_the_groups": dict(at_once=2, dispatches=[
+        [(0, 16), (0, 16), (0, 16), (0, 7), (1, 16), (1, 16), (1, 3), None]]),
+    "a_padding_row_between_two_lanes": dict(dispatches=[[(0, 16), (0, 5), None, (1, 16), (1, 16), (1, 2)]]),
+    "a_padding_row_between_two_lanes_in_groups": dict(at_once=2, dispatches=[
+        [(0, 16), (0, 5), None, (1, 16), (1, 16), (1, 2)]]),
+    "behind_a_prefix_hit_and_an_earlier_dispatch": dict(hits={1: (0, 32)}, dispatches=[
+        [(0, 16), (0, 16), None, None, None, None], [(0, 16), (0, 9), (1, 16), (1, 5), None, None]]),
+}
+
+
+def check_lane_rows(mod, reference, shape, cfg, params, layout, monkeypatch):
+    """Feeds ``LANE_ROWS[layout]`` through ``mod``'s ``forward_chunk`` and
+    ``draft_chunk`` (every row with its lane's block table, ``lanes`` as the
+    engine hands them) and holds every fed position's logits, and the
+    prediction module's, against ``reference`` over each lane's WHOLE prompt;
+    the program's own sums count every live row's read."""
+    how = LANE_ROWS[layout]
+    if "at_once" in how:  # models/xing4.py runs models/openpangu.py's `_in_groups`: one constant for both
+        monkeypatch.setattr(op, "TOKENS_AT_ONCE", how["at_once"] * C)
+    fed = {}
+    for d in how["dispatches"]:
+        for lane, n in filter(None, d):
+            fed[lane] = fed.get(lane, 0) + n
+    hits = how.get("hits", {})
+    starts = {lane: hits[lane][1] if lane in hits else 0 for lane in fed}  # a lane's first fed position
+    prompts, tables, at = {}, {}, dict(starts)
+    for lane, start in sorted(starts.items()):  # one token more than is fed: the last position's ``following``
+        prompts[lane] = np.asarray(prompt_of(start + fed[lane] + 1, salt=11 + lane), np.int32)
+        tables[lane] = np.arange(1 + MB * lane, 1 + MB * (lane + 1), dtype=np.int32)
+        if start:  # the shared positions' tokens AND the token behind them (the module's last shared page)
+            other = hits[lane][0]
+            prompts[lane][:start + 1] = prompts[other][:start + 1]
+            tables[lane][:start // BS] = tables[other][:start // BS]
+    cache = mod.make_kv_cache(cfg, 1 + MB * len(fed), BS, drafting=True)
+    got = {lane: ([], []) for lane in fed}
+
+    @jax.jit  # as the engine's chunk program calls the two (eagerly the layouts take minutes)
+    def dispatch(cache, toks, pos, tabs, lanes, nxt):
+        x, cache, state, sums = mod.forward_chunk(params, cfg, toks, pos, cache, tabs, None, lanes, raw=True)
+        assert state is None
+        hd, cache, more = mod.draft_chunk(params, cfg, x, nxt, pos, cache, tabs)
+        return (mod.lm_head(params, cfg, mod.final_norm(params, cfg, x)), mod.lm_head(params, cfg, hd),
+                cache, sums, more)
+
+    for d in how["dispatches"]:
+        rows = len(d)
+        toks, pos = np.zeros((rows, C), np.int32), np.full((rows, C), -1, np.int32)
+        nxt, tabs = np.zeros((rows, C), np.int32), np.zeros((rows, MB), np.int32)
+        lanes = np.full((rows,), len(fed), np.int32)  # a padding row is no lane's
+        for r, row in enumerate(d):
+            if row is None:
+                continue
+            lane, n = row
+            a = at[lane]
+            toks[r, :n], nxt[r, :n] = prompts[lane][a:a + n], prompts[lane][a + 1:a + n + 1]
+            pos[r, :n], tabs[r], lanes[r] = np.arange(a, a + n), tables[lane], lane
+            at[lane] += n
+        logits, drafts, cache, sums, more = dispatch(cache, *map(jnp.asarray, (toks, pos, tabs, lanes, nxt)))
+        for r, row in enumerate(d):
+            if row is not None:
+                got[row[0]][0].append(np.asarray(logits[r, :row[1]]))
+                got[row[0]][1].append(np.asarray(drafts[r, :row[1]]))
+        live = [int(pos[r].max()) + 1 for r, row in enumerate(d) if row is not None]
+        counts, drafted = dict(zip(mod.COUNTERS, np.asarray(sums))), dict(zip(mod.COUNTERS, np.asarray(more)))
+        # a live row attends ONE tile (this table is one) in every layer, its positions up to its last of it
+        assert counts["mla_history_positions_read"] == cfg.num_layers * len(live) * MB * BS
+        assert counts["mla_history_positions_live"] == cfg.num_layers * sum(live)
+        assert drafted["mla_history_positions_live"] == sum(live) and drafted["mtp_layer_calls"] >= 1
+    for lane, (logits, drafts) in got.items():
+        tokens, span = jnp.asarray(prompts[lane]), jnp.arange(starts[lane], len(prompts[lane]) - 1)
+        np.testing.assert_allclose(np.concatenate(logits), np.asarray(reference.logits(params, shape, tokens, span)),
+                                   atol=ATOL, err_msg=f"lane {lane}")
+        np.testing.assert_allclose(np.concatenate(drafts),
+                                   np.asarray(reference.draft_logits(params, shape, tokens, span)),
+                                   atol=ATOL, err_msg=f"lane {lane}: the prediction module")
+
+
+@pytest.mark.parametrize("layout", list(LANE_ROWS))
+def test_a_later_row_of_one_dispatch_attends_the_rows_before_it_through_the_pool(cfg, params, monkeypatch, layout):
     """Successive pieces of ONE prompt in successive rows of ONE chunk
     dispatch, every row with the lane's block table: a layer writes all the
-    rows' latents to the pool before any row attends, and a row reads its table
-    out of the pool, so a later row meets the earlier rows' fresh keys there
-    and answers as the reference does over the whole prompt. (What
-    ``LANE_TAKES_ROWS`` would rest on; the module does not set it.)"""
-    n = sum(pieces)
-    tokens = np.asarray(prompt_of(n, salt=len(pieces) + 7), np.int32)
-    want = np.asarray(ref.logits(params, SHAPE, jnp.asarray(tokens), jnp.arange(n)))
-    rows = len(pieces) + 1  # and a padding row
-    toks, pos = np.zeros((rows, C), np.int32), np.full((rows, C), -1, np.int32)
-    at = 0
-    for r, k in enumerate(pieces):
-        toks[r, :k], pos[r, :k] = tokens[at:at + k], np.arange(at, at + k)
-        at += k
-    tables = np.tile(np.arange(1, 9, dtype=np.int32), (rows, 1))
-    x, _, _, sums = op.forward_chunk(
-        params, cfg, jnp.asarray(toks), jnp.asarray(pos), op.make_kv_cache(cfg, 32, BS),
-        jnp.asarray(tables), None, jnp.zeros((rows,), jnp.int32))
-    got = np.concatenate([np.asarray(op.lm_head(params, cfg, x[r, :k])) for r, k in enumerate(pieces)])
-    np.testing.assert_allclose(got, want, atol=ATOL)
-    counts = dict(zip(op.COUNTERS, np.asarray(sums)))
-    assert counts["mla_history_positions_read"] == 3 * len(pieces) * MB * BS
-    assert counts["mla_history_positions_live"] == 3 * sum(np.cumsum(pieces))
+    rows' latents of a group to the pool before any row attends, a row reads
+    its table out of the pool, and the groups run in order over one pool, so a
+    later row meets the earlier rows' fresh keys there, in its own group or an
+    earlier one, beside another lane's rows, across a padding row and behind
+    pages an earlier dispatch or another request wrote, and answers as the
+    reference does over the whole prompt; the prediction module's own layer is
+    written and read by rows in the same way. (What ``LANE_TAKES_ROWS``
+    rests on.)"""
+    check_lane_rows(op, ref, SHAPE, cfg, params, layout, monkeypatch)
+
+
+def test_a_row_that_does_not_find_its_lanes_earlier_rows_in_the_pool_is_wrong(cfg, params, monkeypatch):
+    """What ``_paged``'s write in front of the read is there for: with the
+    rows' latents kept out of the pool, the second piece of a prompt does not
+    find the first (nor a row its own keys) and the answer is wrong by far
+    more than ATOL."""
+    dropped = []
+    monkeypatch.setattr(op, "write_latent", lambda pool, *a: (dropped.append(a), pool)[1])
+    with pytest.raises(AssertionError, match="Not equal to tolerance"):
+        check_lane_rows(op, ref, SHAPE, cfg, params, "two_rows", monkeypatch)
+    assert dropped
 
 
 def test_a_chunk_dispatch_counts_the_tiles_it_reads_and_not_the_tables_width(cfg, params):

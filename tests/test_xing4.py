@@ -17,6 +17,7 @@ the pages: a prefix hit, ``verify`` with the module's own drafts, preemption.
 
 import asyncio
 import dataclasses
+import inspect
 import math
 import types
 
@@ -35,6 +36,7 @@ from dynamo_tpu.ops import mhc
 from .test_chunk_rows import answer, run_out, submit
 from .test_engine_spec import collect
 from .test_jamba import room_for_compiled_programs  # noqa: F401  (autouse: clears JAX's caches past 30,000)
+from .test_openpangu import LANE_ROWS, check_lane_rows
 
 # ATOL: float32 on the CPU, at the highest matmul precision on both sides. The
 # program and the reference order their sums differently (absorbed against
@@ -149,7 +151,10 @@ def program_logits(cfg, params, tokens):
 def test_the_module_is_found_by_its_config_and_keeps_nothing_per_slot(cfg):
     assert module_for(cfg) is xm and module_for(llama.LLAMA_PRESETS["tiny"]) is llama
     assert [xm.is_expert_layer(cfg, i) for i in range(3)] == [False, True, True]
-    assert not hasattr(xm, "make_slot_state") and not hasattr(xm, "LANE_TAKES_ROWS")
+    # nothing per slot, and openPangu's own statement that a lane may fill several rows, with what it rests on
+    assert not hasattr(xm, "make_slot_state") and xm.LANE_TAKES_ROWS is xm.base.LANE_TAKES_ROWS is True
+    assert xm.chunk_history_tiles is xm.base.chunk_history_tiles
+    assert list(inspect.signature(xm.chunk_history_tiles).parameters)[3:] == ["lanes"]
     assert xm.COUNTERS[:10] == xm.base.COUNTERS and xm.COUNTERS[10:] == ("mhc_mix_calls", "mhc_rows_mixed")
     pool = xm.make_kv_cache(cfg, 16, BS)
     assert list(pool) == ["latent"] and pool["latent"].shape == (3, 16, BS, 128)
@@ -226,6 +231,18 @@ def test_a_lane_that_starts_past_position_zero_is_rotated_at_its_own_positions(c
     np.testing.assert_allclose(logits, want[16:], atol=ATOL)
     fresh, _, _, _ = feed(cfg, params, xm.make_kv_cache(cfg, 32, BS), tokens[16:], 0, 13, np.arange(1, 9))
     assert np.abs(np.asarray(fresh) - want[16:]).max() > 100 * ATOL
+
+
+@pytest.mark.parametrize("layout", list(LANE_ROWS))
+def test_a_later_row_of_one_dispatch_attends_the_rows_before_it_through_the_pool(cfg, params, monkeypatch, layout):
+    """``tests/test_openpangu.py``'s layouts through THIS module's programs:
+    successive pieces of a prompt in consecutive rows of one chunk dispatch
+    (within a group of ``_in_groups``, across groups, beside another lane,
+    across a padding row, behind a prefix hit and an earlier dispatch) answer
+    as the reference does over the whole prompt, the prediction module's own
+    layer included: the maps of the residual path are a token's own, so the
+    rows meet in the pool alone, as openPangu's do."""
+    check_lane_rows(xm, ref, SHAPE, cfg, params, layout, monkeypatch)
 
 
 # -- what the tolerance sees: each departure is another model ----------------------
