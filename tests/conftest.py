@@ -82,6 +82,37 @@ def _limited(item):
 pytest_runtest_setup = pytest_runtest_call = pytest_runtest_teardown = _limited
 
 
+# Memory mappings a worker may hold when it leaves a test file before JAX's caches go
+# (``vm.max_map_count`` is 65,530, and the costliest file alone adds some 32,000).
+_MAPPINGS_BETWEEN_FILES = 30_000
+
+
+@pytest.fixture(scope="module", autouse=True)
+def room_for_compiled_programs():
+    """Every compiled program is a few memory mappings of the process, and a
+    process may hold 65,530 (``vm.max_map_count``): past it the CPU's compiler
+    dies with the worker. The programs of the model tests are kept for the
+    life of a worker (``tests/step_programs.py``) and the plain references
+    compile an operation a shape, so a worker's count only grows: alone in a
+    process ``tests/test_xing4.py`` ends at 32,199. So BETWEEN FILES (a
+    module-scoped fixture: torn down when the worker's next test is another
+    file's, after the file's engines have closed), once the process holds
+    30,000, JAX's caches go and the next file's programs compile again. Never
+    between the tests of a file, which would compile a file's programs a test
+    again."""
+    yield
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return
+    try:
+        with open("/proc/self/maps") as f:
+            held = sum(1 for _ in f)
+    except OSError:
+        return
+    if held > _MAPPINGS_BETWEEN_FILES:
+        jax.clear_caches()
+
+
 # runtime modules with process-global state and a reset_for_tests(): one
 # test's outages, quarantine latch, dispatch records, fail-slow verdict or
 # armed chaos observer must not bleed into the next test's assertions

@@ -14,89 +14,18 @@ against the same request served alone."""
 import dataclasses
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from dynamo_tpu.engine_jax.compile_cache import compile_count
-from dynamo_tpu.engine_jax.engine import (
-    _FINISHED,
-    EngineConfig,
-    JaxServingEngine,
-    _Seq,
-    chunk_row_ladder,
-    chunk_rows_of,
-)
-from dynamo_tpu.llm.protocols.common import (
-    PreprocessedRequest,
-    SamplingOptions,
-    StopConditions,
-)
-from dynamo_tpu.models.llama import LLAMA_PRESETS, init_params
-from dynamo_tpu.runtime.engine import Context
+from dynamo_tpu.engine_jax.engine import JaxServingEngine, chunk_row_ladder, chunk_rows_of
+from dynamo_tpu.models.llama import init_params
 
-CFG = dataclasses.replace(LLAMA_PRESETS["tiny"], dtype=jnp.float32)
-# ladder [1, 2, 8]: three prefilling lanes already outnumber the small rungs
-ENGINE_CFG = EngineConfig(
-    max_slots=8, kv_block_size=8, max_model_len=160, prefill_chunk=16, decode_steps=4
-)
+from .dense_harness import CFG, MIXED, mesh_engine, prompt_of, serve_schedule
+from .dense_harness import CHUNK_ROWS_CFG as ENGINE_CFG
+from .step_programs import answer, busy, run_out, step, submit
+
 CHUNK = ENGINE_CFG.prefill_chunk
-
-
-class _Inline:
-    """Stands where a request's event loop does: items land in its queue at once."""
-
-    def is_closed(self):
-        return False
-
-    def call_soon_threadsafe(self, fn, *args):
-        fn(*args)
-
-
-def prompt_of(n, salt):
-    return [(salt * 31 + 7 * i + 3) % 97 + 1 for i in range(n)]
-
-
-def submit(eng, prompt, max_tokens, **sampling):
-    req = PreprocessedRequest(
-        token_ids=list(prompt),
-        stop_conditions=StopConditions(max_tokens=max_tokens, ignore_eos=True),
-        sampling_options=SamplingOptions(**sampling),
-    )
-    seq = _Seq(Context(req), req, _Inline())
-    eng._pending.append(seq)
-    return seq
-
-
-def step(eng):
-    eng._admit()
-    eng._dispatch_step()
-
-
-def busy(eng):
-    return bool(eng._pending or any(eng._slots) or eng._inflight is not None)
-
-
-def run_out(eng, limit=400):
-    for _ in range(limit):
-        if not busy(eng):
-            return
-        step(eng)
-    raise AssertionError("the engine did not come to rest")
-
-
-def answer(seq):
-    """(tokens, log-probabilities, finish reason) of everything emitted so far."""
-    toks, lps, finish = [], [], None
-    while not seq.out_queue.empty():
-        item = seq.out_queue.get_nowait()
-        if item is _FINISHED:
-            continue
-        d = item.data or {}
-        toks.extend(d.get("token_ids", []))
-        lps.extend(d.get("log_probs") or [])
-        finish = d.get("finish_reason") or finish
-    return toks, lps, finish
 
 
 @pytest.fixture(scope="module")
@@ -136,16 +65,6 @@ def eng(shared):
     yield shared
     run_out(shared)
     assert shared.allocator.active_blocks == 0 and not shared._zombie_allocs
-
-
-def mesh_engine(params, engine_cfg=ENGINE_CFG, **axes):
-    from dynamo_tpu.models.llama import param_shardings
-    from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
-
-    mesh = make_mesh(MeshConfig(**axes))
-    return JaxServingEngine(
-        CFG, jax.device_put(params, param_shardings(CFG, mesh)), engine_cfg, mesh=mesh
-    )
 
 
 # -- the ladder ----------------------------------------------------------------
@@ -235,12 +154,6 @@ def test_the_rung_holds_the_lanes_and_as_many_pieces_as_the_second_rung_takes(ne
 
 # -- token for token -------------------------------------------------------------
 
-# (arrives at host step, prompt tokens, answer tokens, sampling): prompts of one
-# to five chunks, so that lanes prefill beside lanes that decode in most steps
-MIXED = [
-    (0, 9, 28, {}), (2, 40, 12, {}), (2, 70, 9, {}), (3, 17, 14, {}),
-    (5, 33, 10, {}), (9, 16, 6, {}), (9, 50, 8, {}),
-]
 # five lanes start their prefill in one step beside one that decodes: more
 # than the largest small rung (2 of 8) holds
 WAVE = [(0, 9, 24, {})] + [(3, 20 + 9 * i, 8, {}) for i in range(5)]
@@ -249,20 +162,6 @@ WAVE = [(0, 9, 24, {})] + [(3, 20 + 9 * i, 8, {}) for i in range(5)]
 def with_sampling(schedule, which, **sampling):
     return [(at, n, m, dict(s, **sampling) if i in which else s)
             for i, (at, n, m, s) in enumerate(schedule)]
-
-
-def serve_schedule(eng, schedule, on_step=None, salt=0):
-    seqs, t = {}, 0
-    while busy(eng) or len(seqs) < len(schedule):
-        for i, (at, n, m, sampling) in enumerate(schedule):
-            if at == t:
-                seqs[i] = submit(eng, prompt_of(n, salt + i), m, **sampling)
-        if on_step is not None:
-            on_step(t, seqs)
-        step(eng)
-        t += 1
-        assert t < 400
-    return [answer(seqs[i]) for i in range(len(schedule))]
 
 
 @pytest.mark.parametrize("salt, schedule", [

@@ -40,7 +40,8 @@ from dynamo_tpu.runtime.faults import FaultInjector, FaultRule
 from dynamo_tpu.runtime.resilience import ResiliencePolicy
 from dynamo_tpu.runtime.statestore import StateStoreClient, StateStoreServer
 
-from tests.test_resume import TokenEngine, _payload, expected_stream
+from tests.token_engine import TokenEngine, expected_stream
+from tests.token_engine import payload as _payload
 
 NO_BUS = "127.0.0.1:1"
 
@@ -152,7 +153,7 @@ class TestControlPlaneState:
         assert st.snapshot()["bus_dropped_events"] == 3  # drops accumulate
 
     def test_render_prometheus_parses(self):
-        from tests.test_promtext import parse_prometheus_text
+        from tests.promtext import parse_prometheus_text
 
         control_plane.reset_for_tests()
         control_plane.note_bus(False)
@@ -760,7 +761,7 @@ class TestWireForm:
         assert entry["control_plane"]["impaired_worker_ids"] == ["w-bad"]
         assert entry["bus_dropped_events"] == 5
         # the new gauges render through the strict parser
-        from tests.test_promtext import parse_prometheus_text
+        from tests.promtext import parse_prometheus_text
 
         fams = parse_prometheus_text(cluster.render_prometheus())
         assert "dynamo_cluster_control_plane_impaired" in fams

@@ -13,16 +13,11 @@ import numpy as np
 import pytest
 
 from dynamo_tpu.engine_jax.engine import EngineConfig, JaxServingEngine
-from dynamo_tpu.llm.protocols.common import (
-    PreprocessedRequest,
-    SamplingOptions,
-    StopConditions,
-)
-from dynamo_tpu.models.llama import LLAMA_PRESETS, forward, init_params, make_kv_cache
+from dynamo_tpu.llm.protocols.common import PreprocessedRequest, StopConditions
+from dynamo_tpu.models.llama import init_params
 from dynamo_tpu.runtime.engine import Context
 
-CFG = dataclasses.replace(LLAMA_PRESETS["tiny"], dtype=jnp.float32)
-ENGINE_CFG = EngineConfig(max_slots=4, kv_block_size=8, max_model_len=128)
+from .dense_harness import CFG, ENGINE_CFG, collect_tokens, reference_greedy
 
 
 @pytest.fixture(scope="module")
@@ -35,41 +30,6 @@ def engine(params):
     eng = JaxServingEngine(CFG, params, ENGINE_CFG)
     yield eng
     eng.close()
-
-
-def reference_greedy(params, prompt, n_steps):
-    """Straight-line greedy generation with a private paged cache."""
-    cache = make_kv_cache(CFG, 16, 8, dtype=jnp.float32)
-    tables = jnp.arange(16, dtype=jnp.int32).reshape(1, 16)
-    toks = jnp.asarray([prompt], jnp.int32)
-    pos = jnp.arange(len(prompt))[None]
-    logits, cache = forward(params, CFG, toks, pos, cache, tables)
-    out = [int(jnp.argmax(logits[0, -1]))]
-    for i in range(n_steps - 1):
-        p = len(prompt) + i
-        logits, cache = forward(
-            params, CFG, jnp.asarray([[out[-1]]], jnp.int32), jnp.asarray([[p]]), cache, tables
-        )
-        out.append(int(jnp.argmax(logits[0, -1])))
-    return out
-
-
-async def collect_tokens(engine, prompt, max_tokens=8, **sampling):
-    req = PreprocessedRequest(
-        token_ids=list(prompt),
-        stop_conditions=StopConditions(max_tokens=max_tokens, ignore_eos=True),
-        sampling_options=SamplingOptions(**sampling),
-    )
-    toks = []
-    finish = None
-    async for item in engine.generate(Context(req)):
-        d = item.data
-        if d is None:
-            continue
-        toks.extend(d.get("token_ids", []))
-        if d.get("finish_reason"):
-            finish = d["finish_reason"]
-    return toks, finish
 
 
 def test_greedy_matches_reference(engine, params, run):

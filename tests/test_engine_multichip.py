@@ -17,7 +17,7 @@ from dynamo_tpu.engine_jax.engine import EngineConfig, JaxServingEngine
 from dynamo_tpu.models.llama import init_params, param_shardings
 from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
 
-from .test_engine_jax import CFG, ENGINE_CFG, collect_tokens, reference_greedy
+from .dense_harness import CFG, ENGINE_CFG, collect_tokens, reference_greedy
 
 PROMPTS = [[3, 1, 4, 1, 5], [9, 2, 6, 5, 3, 5], [8, 9, 7, 9], [2, 7, 1, 8]]
 

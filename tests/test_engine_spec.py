@@ -25,13 +25,11 @@ from dynamo_tpu.engine_jax.drafter import (
     env_spec_ngram,
 )
 from dynamo_tpu.engine_jax.engine import EngineConfig, JaxServingEngine
-from dynamo_tpu.llm.protocols.common import (
-    PreprocessedRequest,
-    SamplingOptions,
-    StopConditions,
-)
+from dynamo_tpu.llm.protocols.common import PreprocessedRequest, StopConditions
 from dynamo_tpu.models.llama import LLAMA_PRESETS, init_params
 from dynamo_tpu.runtime.engine import Context
+
+from .step_programs import collect
 
 CFG = dataclasses.replace(LLAMA_PRESETS["tiny"], dtype=jnp.float32)
 ENGINE_CFG = EngineConfig(max_slots=4, kv_block_size=8, max_model_len=128)
@@ -43,24 +41,6 @@ REP_PROMPT = ([3, 1, 4, 1, 5, 9, 2, 6] * 4)[:24]
 @pytest.fixture(scope="module")
 def params():
     return init_params(jax.random.PRNGKey(0), CFG)
-
-
-async def collect(engine, prompt, max_tokens=20, with_lp=False, **sampling):
-    req = PreprocessedRequest(
-        token_ids=list(prompt),
-        stop_conditions=StopConditions(max_tokens=max_tokens, ignore_eos=True),
-        sampling_options=SamplingOptions(
-            logprobs=2 if with_lp else None, **sampling
-        ),
-    )
-    toks, lps, finish = [], [], None
-    async for item in engine.generate(Context(req)):
-        d = item.data or {}
-        toks.extend(d.get("token_ids", []))
-        lps.extend(d.get("log_probs") or [])
-        if d.get("finish_reason"):
-            finish = d["finish_reason"]
-    return toks, lps, finish
 
 
 def _spec_engine(params, k, **kw):

@@ -662,7 +662,7 @@ class TestQuarantinePlane:
         the control key latches the worker (health → quarantined on the
         instance key, exit 0 with --wait), unquarantine recovers it, and
         --wait exits 2 when the latch can't land in time."""
-        from .test_resume import TokenEngine
+        from .token_engine import TokenEngine
 
         from dynamo_tpu.cli import llmctl
 
@@ -751,7 +751,7 @@ class TestIntegrityGauges:
         from dynamo_tpu.components.mock_worker import MockWorkerStats
         from dynamo_tpu.components.telemetry_aggregator import ClusterTelemetry
 
-        from .test_promtext import parse_prometheus_text
+        from .promtext import parse_prometheus_text
 
         stats = MockWorkerStats(
             seed=1, integrity_failures=4, watchdog_trips=2,

@@ -15,7 +15,6 @@ import os
 import re
 import subprocess
 import sys
-import types
 
 import jax
 import jax.numpy as jnp
@@ -29,87 +28,15 @@ from dynamo_tpu.kv.pages import MigrationRejected, StateNotPortable
 from dynamo_tpu.models import jamba, llama, module_for
 from dynamo_tpu.ops.pallas.selective_scan import ROWS, selective_scan, selective_step
 
-from .test_chunk_rows import answer, run_out, step, submit
+from .jamba_harness import (  # noqa: F401  (the fixtures are this file's too)
+    ATOL, ATOL_BF16, ENGINE_CFG, N_MAMBA, SHAPE, SPARE_BLOCKS, cfg, dispatch_rows, engine,
+    lowered_step_programs, params, recurrence_inputs,
+)
+from .step_programs import (  # noqa: F401  (highest_precision: autouse, for this file's tests)
+    answer, card, highest_precision, patched, prompt_of, reference_program, run_out, served, step, submit,
+)
 
-# ATOL, the float32 build: float32 on the CPU at the highest matmul precision
-# on both sides, so the program and the reference differ by the order of their
-# sums alone (a chunk's convolution against the whole sequence's, flash partials
-# against one softmax): 2e-4 on logits of magnitude 4 is what the dense decoder
-# and Kimi-Linear are allowed for the same reason (measured here: 5e-6). A wrong
-# state, tail or page moves a logit by 1e-1 and more, and the recurrence taken
-# in bfloat16 by 1e-2 (a test below holds that it fails this tolerance).
-ATOL = 2e-4
-# ATOL_BF16, the served build (bfloat16 weights, float32 activations, the
-# Mamba mixers' four projections in two bfloat16 parts, attention, the
-# feed-forwards and the head in one: the MXU's rounding of their inputs, 8 bits
-# of mantissa, six layers deep) against the float32 reference over the same
-# weights: measured 0.04 on logits of magnitude 3.8, held at twice that (the
-# first build, bfloat16 activations throughout, read 0.13 and would fail it).
-# The benchmark's comparison (logprob_rms) is the tight one for this build.
-ATOL_BF16 = 0.08
-
-SHAPE = {
-    "model_type": "jamba", "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 6,
-    "num_attention_heads": 4, "num_key_value_heads": 1, "attn_layer_period": 3,
-    "attn_layer_offset": 1, "expert_layer_period": 2, "expert_layer_offset": 1, "num_experts": 1,
-    "num_experts_per_tok": 1, "mamba_expand": 2, "mamba_d_state": 8, "mamba_dt_rank": 8,
-    "mamba_d_conv": 4, "mamba_conv_bias": True, "mamba_proj_bias": False, "rms_norm_eps": 1e-6,
-    "vocab_size": 96, "tie_word_embeddings": True,
-}
-N_MAMBA = 4
-ENGINE_CFG = EngineConfig(max_slots=4, kv_block_size=8, max_model_len=96,
-                          prefill_chunk=16, decode_steps=4, top_logprobs=5)
 PUBLISHED = "benchmark/configs/jamba2-3b.json"
-
-
-def card(shape):
-    return types.SimpleNamespace(model_config=shape, model_path=None, gguf_path=None)
-
-
-def prompt_of(n, salt=0):
-    return [(salt * 31 + 7 * i + 3) % 95 + 1 for i in range(n)]
-
-
-@pytest.fixture(scope="module", autouse=True)
-def highest_precision():
-    with jax.default_matmul_precision("highest"):
-        yield
-
-
-@pytest.fixture(autouse=True)
-def room_for_compiled_programs():
-    """Every compiled program is a few memory mappings of the process, and a
-    process may hold 65,530 (``vm.max_map_count``): past it the CPU's compiler
-    dies with the worker. Alone in a process this file peaks at 34,093 and
-    ``tests/test_jamba_lane_rows.py`` at 31,532, but a worker brings what its
-    files before left (``tests/test_lfm2.py``: 36,218). So JAX's caches go once
-    the process holds 30,000, and the programs a later test shares compile
-    again."""
-    yield
-    try:
-        with open("/proc/self/maps") as f:
-            held = sum(1 for _ in f)
-    except OSError:
-        return
-    if held > 30_000:
-        jax.clear_caches()
-
-
-@pytest.fixture(scope="module")
-def cfg():
-    return config_from_card(card(SHAPE), jnp.float32)
-
-
-@pytest.fixture(scope="module")
-def params(cfg):
-    return jamba.init_params(jax.random.PRNGKey(3), cfg)
-
-
-@pytest.fixture(scope="module")
-def engine(cfg, params):
-    eng = JaxServingEngine(cfg, params, ENGINE_CFG)
-    yield eng
-    eng.close()
 
 
 def test_the_layer_kinds_are_the_published_pattern(cfg):
@@ -125,83 +52,6 @@ def test_the_layer_kinds_are_the_published_pattern(cfg):
     assert ref.sizes(SHAPE)["kinds"] == jamba.layer_kinds(cfg) == (
         "mamba", "attn", "mamba", "mamba", "attn", "mamba")
     assert module_for(cfg) is jamba and module_for(llama.LLAMA_PRESETS["tiny"]) is llama
-
-
-SPARE_BLOCKS = 8
-
-
-def dispatch_rows(cfg, params, dispatches, rows=8, slots=10, mb=8, n_decode=3, between=None, salt=None):
-    """Chunk dispatches of ``rows`` rows over ``slots`` slots, then ``n_decode``
-    teacher-forced decode steps of every slot fed, off the state and pages the
-    dispatches left. A dispatch is a list of its rows in order, ``(slot, n)``
-    = the slot's next ``n`` prompt tokens (a lane's rows of one dispatch are
-    its successive pieces) or ``None`` = a padding row; the rows left are
-    padding. The k-th slot fed has blocks ``1 + k * mb`` onwards, and the pool
-    holds ``SPARE_BLOCKS`` more that no table names: no page but those a fed
-    slot's tokens reach may be written, block 0 (where a padding row's table
-    points) and the spare ones included, which is held here for every caller.
-    Every slot's state starts stale (``between`` may change it after a
-    dispatch); its tokens are ``prompt_of(., salt or the slot)``. Returns
-    ({slot: (its tokens, hidden states of its prompt, logits ``[prompt +
-    n_decode, V]``)}, state, cache, the dispatches' counters)."""
-    c, bs = 16, 8
-    fed = list(dict.fromkeys(row[0] for d in dispatches for row in d if row))
-    length = {slot: sum(row[1] for d in dispatches for row in d if row and row[0] == slot) for slot in fed}
-    toks_of = {slot: np.asarray(prompt_of(length[slot] + n_decode, salt=salt or slot), np.int32) for slot in fed}
-    table = {slot: 1 + k * mb + np.arange(mb, dtype=np.int32) for k, slot in enumerate(fed)}
-    cache = jamba.make_kv_cache(cfg, 1 + len(fed) * mb + SPARE_BLOCKS, bs)
-    state = jax.tree.map(lambda a: a + 7.0, jamba.make_slot_state(cfg, slots))  # stale, every slot
-    at, hidden, sums = dict.fromkeys(fed, 0), {slot: [] for slot in fed}, []
-    chunk = jax.jit(lambda params, *a: jamba.forward_chunk(params, cfg, *a))  # as the engine runs it
-    for d in dispatches:
-        toks, pos = np.zeros((rows, c), np.int32), np.full((rows, c), -1, np.int32)
-        tables, lanes = np.zeros((rows, mb), np.int32), np.full((rows,), slots, np.int32)
-        for r, row in enumerate(d):
-            if row is None:
-                continue
-            slot, n = row
-            toks[r, :n], pos[r, :n] = toks_of[slot][at[slot]:at[slot] + n], np.arange(at[slot], at[slot] + n)
-            tables[r], lanes[r] = table[slot], slot
-            at[slot] += n
-        h, cache, state, counted = chunk(
-            params, jnp.asarray(toks), jnp.asarray(pos), cache, jnp.asarray(tables),
-            state, jnp.asarray(lanes))
-        for r, row in enumerate(d):
-            if row is not None:
-                hidden[row[0]].append(np.asarray(h[r, :row[1]], np.float32))
-        sums.append(dict(zip(jamba.COUNTERS, np.asarray(counted).tolist())))
-        if between is not None:
-            state = between(state)
-    hidden = {slot: np.concatenate(hidden[slot]) for slot in fed}
-    logits = {slot: [np.asarray(jamba.lm_head(params, cfg, jnp.asarray(hidden[slot])), np.float32)] for slot in fed}
-    if n_decode:
-        lanes_tables = np.zeros((slots, mb), np.int32)
-        toks, pos = np.zeros((slots,), np.int32), np.full((slots,), -1, np.int32)
-        forcing = np.zeros((slots, max(length.values()) + n_decode), np.int32)
-        for slot in fed:
-            lanes_tables[slot], toks[slot], pos[slot] = table[slot], toks_of[slot][length[slot]], length[slot]
-            forcing[slot, :len(toks_of[slot])] = toks_of[slot]
-
-        def forced(logits, p, carry, k):  # teacher forcing: each sequence's own next token
-            nxt = jnp.asarray(forcing)[jnp.arange(slots), jnp.clip(p + 1, 0, forcing.shape[1] - 1)]
-            return jnp.where(p >= 0, nxt, 0), carry, logits
-
-        out = jamba.decode(params, cfg, jnp.asarray(toks), jnp.asarray(pos), cache,
-                           jnp.asarray(lanes_tables), state, n_decode, 8 * mb - 1, forced, None)
-        assert [int(out[1][slot]) for slot in fed] == [length[slot] + n_decode for slot in fed]
-        assert np.asarray(out[6]).tolist() == [n_decode * N_MAMBA, 0, 0, 0, 0]
-        for slot in fed:
-            logits[slot].append(np.asarray(out[3], np.float32)[:, slot])
-        state, cache = out[5], out[4]
-    reached = np.zeros((cache["k"].shape[1],), bool)
-    for slot in fed:
-        reached[table[slot][:-(-(length[slot] + n_decode) // bs)]] = True
-    for name in ("k", "v"):
-        pool = np.asarray(cache[name], np.float32)
-        assert not pool[:, ~reached].any(), f"{name}: a page outside what the fed slots' tokens reach was written"
-        assert all(pool[:, block].any() for block in np.flatnonzero(reached)), name
-    return ({slot: (toks_of[slot], hidden[slot], np.concatenate(logits[slot])) for slot in fed},
-            state, cache, sums)
 
 
 def prefill_then_decode(cfg, params, chunks, n_decode=3, between=None):
@@ -228,7 +78,7 @@ def test_chunked_prefill_then_decode_agrees_with_the_plain_reference(chunks, dty
     cfg = config_from_card(card(SHAPE), dtype)
     params = jamba.init_params(jax.random.PRNGKey(3), cfg)
     tokens, got, state, cache, sums = prefill_then_decode(cfg, params, chunks)
-    want = np.asarray(ref.logits(params, SHAPE, jnp.asarray(tokens), jnp.arange(len(tokens))))
+    want = np.asarray(reference_program(ref, SHAPE)(params, jnp.asarray(tokens), jnp.arange(len(tokens))))
     np.testing.assert_allclose(got, want, atol=atol)
     for leaf in jax.tree.leaves(state):  # slots 0, 1 and 3 of every layer: untouched
         assert float(leaf[:, (0, 1, 3)].min()) == float(leaf[:, (0, 1, 3)].max()) == 7.0
@@ -252,9 +102,9 @@ def test_a_recurrence_taken_in_bfloat16_fails_the_float32_tolerance(cfg, params,
         y, s = scan(lp, *jax.tree.map(low, (s, delta, x, b, c)), valid, above)
         return y, jax.tree.map(low, s)
 
-    monkeypatch.setattr(jamba, "_scan_tokens", rounded)
+    patched(monkeypatch, jamba, "_scan_tokens", rounded)
     tokens, got, *_ = prefill_then_decode(cfg, params, (16, 9))
-    want = np.asarray(ref.logits(params, SHAPE, jnp.asarray(tokens), jnp.arange(len(tokens))))
+    want = np.asarray(reference_program(ref, SHAPE)(params, jnp.asarray(tokens), jnp.arange(len(tokens))))
     assert np.abs(got - want).max() > 5 * ATOL
 
 
@@ -263,7 +113,7 @@ def test_the_convolutions_tail_carries_across_a_chunk_boundary(cfg, params):
     (oldest first), and a second chunk that starts from a zeroed tail is
     wrong by far more than ATOL."""
     tokens, got, *_ = prefill_then_decode(cfg, params, (7, 9))
-    want = np.asarray(ref.logits(params, SHAPE, jnp.asarray(tokens), jnp.arange(len(tokens))))
+    want = np.asarray(reference_program(ref, SHAPE)(params, jnp.asarray(tokens), jnp.arange(len(tokens))))
     np.testing.assert_allclose(got, want, atol=ATOL)
 
     seen = {}
@@ -298,17 +148,6 @@ def test_a_decode_steps_convolution_is_the_chunks_on_a_row_of_one_token(cfg, par
     assert np.array_equal(np.asarray(got_tail), np.asarray(want_tail))
     assert np.array_equal(np.asarray(got_tail[3:]), np.asarray(tail[3:]))
     assert np.array_equal(np.asarray(got_tail[:3, -d:]), np.asarray(x[:3, 0]))
-
-
-def recurrence_inputs(cfg, rows, t, seed=0, step=0.0):
-    """Inputs as ``mamba_mixer`` makes them (the step size after its softplus,
-    ``step`` added before it) and a carried state."""
-    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-    n, d = cfg.mamba_d_state, cfg.d_inner
-    delta = jax.nn.softplus(jax.random.normal(ks[0], (rows, t, d)) + step)
-    x, b, c = (jax.random.normal(k, shape) for k, shape in zip(
-        ks[1:4], [(rows, t, d), (rows, t, n), (rows, t, n)]))
-    return (delta, x, b, c), jax.random.normal(ks[4], (rows, n, d))
 
 
 def one_token_at_a_time(lp, s0, delta, x, b, c, valid):
@@ -514,12 +353,6 @@ def test_serving_a_qwen_card_imports_no_other_models_module():
     assert done.returncode == 0 and done.stdout.strip().endswith("[]"), done.stdout + done.stderr[-800:]
 
 
-def served(engine, prompt, max_tokens, **sampling):
-    seq = submit(engine, prompt, max_tokens, **sampling)
-    run_out(engine)
-    return answer(seq)
-
-
 def test_the_engine_serves_the_reference_greedy_tokens_and_logprobs(engine, params):
     """Through ``JaxServingEngine``: admission, three chunk dispatches,
     pipelined decode dispatches of 4 steps, sampling and log-probabilities,
@@ -527,7 +360,7 @@ def test_the_engine_serves_the_reference_greedy_tokens_and_logprobs(engine, para
     prompt = prompt_of(37)
     toks, lps, finish = served(engine, prompt, 10, logprobs=5)
     seq = jnp.asarray(prompt + toks[:-1], jnp.int32)
-    want = np.asarray(ref.logits(params, SHAPE, seq, jnp.arange(len(prompt) - 1, len(seq))))
+    want = np.asarray(reference_program(ref, SHAPE)(params, seq, jnp.arange(len(prompt) - 1, len(seq))))
     assert toks == want.argmax(-1).tolist() and len(toks) == 10 and finish == "length"
     logp = want - np.log(np.exp(want).sum(-1, keepdims=True))
     np.testing.assert_allclose(lps, logp[np.arange(10), toks], atol=ATOL)
@@ -599,26 +432,6 @@ def test_a_repeated_prompt_takes_no_prefix_hit(engine):
     assert answer(seq)[0] == first
     assert engine.prefix_hits_declined == declined + 1
     assert engine.model_counters["slot_state_resets"] == resets + 1
-
-
-def lowered_step_programs(engine, rows=None):
-    """(the chunk program at ``rows`` rows, the decode program) of the engine's
-    module, lowered from shapes as the engine calls them."""
-    def sd(a):
-        return jax.ShapeDtypeStruct(a.shape, a.dtype)
-
-    s, c, mb = ENGINE_CFG.max_slots, ENGINE_CFG.prefill_chunk, ENGINE_CFG.max_blocks_per_seq
-    r = s if rows is None else rows
-    pool = (jax.tree.map(sd, engine.params), jax.tree.map(sd, engine.cache),
-            jax.tree.map(sd, engine.slot_state), sd(engine._dummy_counts))
-    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
-    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
-    wd = (i32(),) if engine._watchdog else ()
-    chunk = engine._build_chunk_fn(False, False, False).lower(
-        *pool, i32(r, c), i32(r, c), i32(r, mb), i32(r), i32(r), i32(), i32(2, r), f32(4, r), *wd)
-    decode = engine._build_decode_fn(False, False, False).lower(
-        *pool, i32(s), i32(s), i32(s, mb), i32(), i32(2, s), f32(4, s), *wd)
-    return chunk, decode
 
 
 @pytest.mark.parametrize("what", [

@@ -19,10 +19,12 @@ from dynamo_tpu.engine_jax.weights import config_from_card
 from dynamo_tpu.models import jamba
 from dynamo_tpu.ops.pallas.selective_scan import selective_scan
 
-from .test_chunk_rows import answer, busy, step, submit
-from .test_jamba import (  # noqa: F401  (the fixtures are this file's too)
-    ATOL, ATOL_BF16, N_MAMBA, SHAPE, SPARE_BLOCKS, card, cfg, dispatch_rows, engine, highest_precision,
-    lowered_step_programs, params, prompt_of, recurrence_inputs, room_for_compiled_programs, served,
+from .jamba_harness import (  # noqa: F401  (the fixtures are this file's too)
+    ATOL, ATOL_BF16, N_MAMBA, SHAPE, SPARE_BLOCKS, cfg, dispatch_rows, engine, lowered_step_programs, params,
+    recurrence_inputs,
+)
+from .step_programs import (  # noqa: F401  (highest_precision: autouse, for this file's tests)
+    MIXED, answer, busy, card, highest_precision, patched, prompt_of, reference_program, served, step, submit,
 )
 
 # (tokens a row, the rows of a call in order: a lane is a list of its rows' valid tokens, each full but
@@ -122,7 +124,7 @@ def test_a_prompt_whose_pieces_fill_rows_of_one_dispatch_agrees_with_the_plain_r
     how = {"rows": 8, "slots": 10, "mb": 8, **LAYOUTS[layout]}
     served, state, cache, sums = dispatch_rows(cfg, params, **how)
     for slot, (tokens, _, got) in served.items():
-        want = np.asarray(ref.logits(params, SHAPE, jnp.asarray(tokens), jnp.arange(len(tokens))))
+        want = np.asarray(reference_program(ref, SHAPE)(params, jnp.asarray(tokens), jnp.arange(len(tokens))))
         np.testing.assert_allclose(got, want, atol=atol, err_msg=f"slot {slot}")
     idle = tuple(i for i in range(how["slots"]) if i not in served)
     for leaf in jax.tree.leaves(state):  # the slots no row fed, of every layer: untouched
@@ -185,11 +187,11 @@ def test_a_row_that_starts_from_its_slots_stored_state_is_wrong_where_it_should_
     from there on."""
     how = dict(dispatches=[[(2, 16), (2, 9)]], n_decode=0)
     (tokens, _, got), = dispatch_rows(cfg, params, **how)[0].values()
-    want = np.asarray(ref.logits(params, SHAPE, jnp.asarray(tokens), jnp.arange(len(tokens))))
+    want = np.asarray(reference_program(ref, SHAPE)(params, jnp.asarray(tokens), jnp.arange(len(tokens))))
     np.testing.assert_allclose(got, want, atol=ATOL)
     scan, convolve = jamba._scan_tokens, jamba._convolve
     for name, cut in (("_scan_tokens", lambda *a: scan(*a[:7])), ("_convolve", lambda *a: convolve(*a[:5]))):
-        monkeypatch.setattr(jamba, name, cut)
+        patched(monkeypatch, jamba, name, cut)
         (_, _, off), = dispatch_rows(cfg, params, **how)[0].values()
         monkeypatch.undo()
         assert np.abs(off[16:] - want[16:]).max() > 100 * ATOL, name
@@ -199,11 +201,6 @@ def test_a_row_that_starts_from_its_slots_stored_state_is_wrong_where_it_should_
 # ladder [8, 16, 64]: a lane fills up to sixteen rows of a dispatch
 WIDE_CFG = EngineConfig(max_slots=64, kv_block_size=8, max_model_len=192, prefill_chunk=16,
                         decode_steps=4)
-# (the step a request is submitted on, prompt tokens, answered): a prompt of 7 chunks beside lanes that
-# decode, prompts of 1 to 9 chunks at once (more rows than the second rung holds: the pieces left go on
-# in the next step), a late long one behind decoding lanes
-MIXED = [(0, 9, 24), (2, 100, 8), (2, 12, 10), (3, 60, 6), (3, 140, 5), (3, 37, 9), (3, 90, 5),
-         (4, 128, 6), (4, 16, 7), (9, 75, 5)]
 
 
 def test_every_request_answers_as_alone_where_a_lane_fills_several_rows(cfg, params):
@@ -254,13 +251,13 @@ def test_the_full_width_chunk_program_holds_nothing_of_the_hand_over(engine, mon
     text = lowered_step_programs(engine)[0].as_text()
     told = []
     scan = jamba.selective_scan
-    monkeypatch.setattr(jamba, "selective_scan", lambda *a, **kw: told.append(a[7]) or scan(*a, **kw))
+    patched(monkeypatch, jamba, "selective_scan", lambda *a, **kw: told.append(a[7]) or scan(*a, **kw))
 
     def unreachable(*a, **kw):
         raise AssertionError("the hand-over, in a program that has one row a lane")
 
     for name in ("lane_first_positions", "sibling_rows_back", "chunk_sibling_partial"):
-        monkeypatch.setattr(jamba, name, unreachable)
+        patched(monkeypatch, jamba, name, unreachable)
     assert lowered_step_programs(engine)[0].as_text() == text
     assert told and all(above is None for above in told)
     with pytest.raises(AssertionError, match="the hand-over"):

@@ -11,7 +11,6 @@ with per-slot state owes whatever would hand its pages over without it.
 """
 
 import re
-import types
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +26,10 @@ from dynamo_tpu.models import llama, module_for
 from dynamo_tpu.ops import moe
 from dynamo_tpu.ops.pallas.kda_scan import kda_scan
 
-from .test_chunk_rows import answer, run_out, step, submit
+from .step_programs import (  # noqa: F401  (highest_precision: autouse, for this file's tests)
+    answer, card, chunk_program, decode_program, highest_precision, prompt_of, reference_program, run_out, served,
+    step, submit,
+)
 
 # ATOL: float32 on the CPU, at the highest matmul precision on both sides. The
 # program and the reference order their sums differently (absorbed against
@@ -51,20 +53,6 @@ SHAPE = {
 }
 ENGINE_CFG = EngineConfig(max_slots=4, kv_block_size=8, max_model_len=96,
                           prefill_chunk=16, decode_steps=4, top_logprobs=5)
-
-
-def card(shape):
-    return types.SimpleNamespace(model_config=shape, model_path=None, gguf_path=None)
-
-
-def prompt_of(n, salt=0):
-    return [(salt * 31 + 7 * i + 3) % 95 + 1 for i in range(n)]
-
-
-@pytest.fixture(scope="module", autouse=True)
-def highest_precision():
-    with jax.default_matmul_precision("highest"):
-        yield
 
 
 @pytest.fixture(scope="module")
@@ -100,7 +88,7 @@ def test_chunked_prefill_then_decode_agrees_with_the_plain_reference(cfg, params
     other slots stay as they were."""
     n_prompt, n_decode = sum(chunks), 3
     tokens = np.asarray(prompt_of(n_prompt + n_decode, salt=len(chunks)), np.int32)
-    want = np.asarray(ref.logits(params, SHAPE, jnp.asarray(tokens), jnp.arange(len(tokens))))
+    want = np.asarray(reference_program(ref, SHAPE)(params, jnp.asarray(tokens), jnp.arange(len(tokens))))
     slots, c, bs, mb, slot = 4, 16, 8, 8, 2
     cache = kl.make_kv_cache(cfg, 32, bs)
     state = jax.tree.map(lambda a: a + 7.0, kl.make_slot_state(cfg, slots))  # stale, every slot
@@ -110,8 +98,8 @@ def test_chunked_prefill_then_decode_agrees_with_the_plain_reference(cfg, params
     for n in chunks:
         toks, pos = np.zeros((2, c), np.int32), np.full((2, c), -1, np.int32)
         toks[0, :n], pos[0, :n] = tokens[at:at + n], np.arange(at, at + n)
-        h, cache, state, sums = kl.forward_chunk(
-            params, cfg, jnp.asarray(toks), jnp.asarray(pos), cache, jnp.asarray(tables),
+        h, cache, state, sums = chunk_program(kl, cfg)(
+            params, jnp.asarray(toks), jnp.asarray(pos), cache, jnp.asarray(tables),
             state, jnp.asarray([slot, slots], jnp.int32))
         got.append(kl.lm_head(params, cfg, h[0, :n]))
         assert int(sums[-1]) == (at == 0)  # the first chunk resets the slot, once
@@ -124,11 +112,10 @@ def test_chunked_prefill_then_decode_agrees_with_the_plain_reference(cfg, params
     toks, pos = np.zeros((slots,), np.int32), np.full((slots,), -1, np.int32)
     toks[slot], pos[slot] = tokens[n_prompt], n_prompt
 
-    def forced(logits, p, carry, k):  # teacher forcing: the sequence's own next token
-        return jnp.where(p >= 0, jnp.asarray(tokens)[jnp.clip(p + 1, 0, len(tokens) - 1)], 0), carry, logits
-
-    out = kl.decode(params, cfg, jnp.asarray(toks), jnp.asarray(pos), cache,
-                    jnp.asarray(lanes_tables), state, n_decode, 95, forced, None)
+    forcing = np.zeros((slots, bs * mb), np.int32)  # teacher forcing: the sequence's own next token
+    forcing[slot, :len(tokens)] = tokens
+    out = decode_program(kl, cfg, n_decode, 95)(
+        params, jnp.asarray(toks), jnp.asarray(pos), cache, jnp.asarray(lanes_tables), state, jnp.asarray(forcing))
     np.testing.assert_allclose(np.asarray(out[3])[:, slot], want[n_prompt:], atol=ATOL)
     assert float(out[5]["s"][0][0].min()) == 7.0 and int(out[1][slot]) == n_prompt + n_decode
 
@@ -374,19 +361,13 @@ def test_the_chunk_kernel_stands_a_decay_of_minus_twenty_a_token():
     assert_float32_equal(s, want_s)
 
 
-def served(engine, prompt, max_tokens, **sampling):
-    seq = submit(engine, prompt, max_tokens, **sampling)
-    run_out(engine)
-    return answer(seq)
-
-
 def test_the_engine_serves_the_reference_greedy_tokens_and_logprobs(engine, params):
     """(g) Through ``JaxServingEngine``: admission, three chunk dispatches,
     pipelined decode dispatches of 4 steps, sampling and log-probabilities."""
     prompt = prompt_of(37)
     toks, lps, finish = served(engine, prompt, 10, logprobs=5)
     seq = jnp.asarray(prompt + toks[:-1], jnp.int32)
-    want = np.asarray(ref.logits(params, SHAPE, seq, jnp.arange(len(prompt) - 1, len(seq))))
+    want = np.asarray(reference_program(ref, SHAPE)(params, seq, jnp.arange(len(prompt) - 1, len(seq))))
     assert toks == want.argmax(-1).tolist() and len(toks) == 10 and finish == "length"
     logp = want - np.log(np.exp(want).sum(-1, keepdims=True))
     np.testing.assert_allclose(lps, logp[np.arange(10), toks], atol=ATOL)

@@ -11,7 +11,6 @@ the bytes and checksums the parent commit produced.
 """
 
 import concurrent.futures
-import dataclasses
 import hashlib
 import json
 import zlib
@@ -25,36 +24,12 @@ import pytest
 from dynamo_tpu.engine_jax.allocator import HostKvPool
 from dynamo_tpu.kv import pages as kv_pages
 from dynamo_tpu.kv.pages import KvDtypeMismatch, MigrationRejected
-from dynamo_tpu.models.llama import LLAMA_PRESETS, init_params, make_kv_cache
+from dynamo_tpu.models.llama import init_params
 from dynamo_tpu.runtime.integrity import KvIntegrityError
 
-CFG = dataclasses.replace(LLAMA_PRESETS["tiny"], dtype=jnp.float32)
-N_BLOCKS, BLOCK = 12, 8
-POOLS = ["native", "int8", "latent"]
-
-
-def _filled(shape, dtype, salt):
-    """Every element its own value, the same on every numpy."""
-    flat = (np.arange(int(np.prod(shape)), dtype=np.int64) * 37 + salt * 101) % 251
-    return (flat - 125).reshape(shape).astype(dtype)
-
-
-def _pool(kind, block=BLOCK):
-    """A pool with something in every row. The third kind is the native pool
-    and one more member, of another rank and dtype."""
-    shapes = jax.eval_shape(
-        lambda: make_kv_cache(CFG, N_BLOCKS, block, quantized=kind == "int8")
-    )
-    pool = {
-        m: jnp.asarray(_filled(a.shape, a.dtype, i))
-        for i, (m, a) in enumerate(sorted(shapes.items()))
-    }
-    if kind == "latent":
-        pool["latent"] = jnp.asarray(
-            _filled((CFG.num_layers, N_BLOCKS, block, 64), np.float32, 9)
-        )
-    return pool
-
+from .dense_harness import BLOCK, CFG, N_BLOCKS, POOLS
+from .dense_harness import filled as _filled
+from .dense_harness import pool as _pool
 
 def _bytes(pages):
     return {m: np.asarray(a).tobytes() for m, a in pages.items()}

@@ -31,12 +31,13 @@ from dynamo_tpu.engine_jax.engine import EngineConfig, JaxServingEngine
 from dynamo_tpu.engine_jax.weights import config_from_card
 from dynamo_tpu.models import openpangu
 
-from .test_chunk_rows import answer, busy, run_out, step, submit
-from .test_jamba import room_for_compiled_programs, served  # noqa: F401  (autouse: clears JAX's caches past 30,000)
-from .test_jamba_lane_rows import MIXED  # (the step a request is submitted on, prompt tokens, answered)
-from .test_openpangu import ATOL, card, prompt_of
+from .latent_harness import ATOL, OPENPANGU_SHAPE, XING4_SHAPE, C
+from .step_programs import (  # noqa: F401  (highest_precision: autouse, for this file's tests)
+    MIXED, answer, busy, card, highest_precision, patched, prompt_of, reference_program, run_out, served, step,
+    submit,
+)
 
-C = 16
+SHAPES = {"openpangu": OPENPANGU_SHAPE, "xing4": XING4_SHAPE}
 # ladder [8, 16, 64]: a lane fills up to sixteen rows of a dispatch
 WIDE_CFG = EngineConfig(max_slots=64, kv_block_size=8, max_model_len=192, prefill_chunk=C,
                         decode_steps=4, top_logprobs=5)
@@ -44,20 +45,14 @@ WIDE_CFG = EngineConfig(max_slots=64, kv_block_size=8, max_model_len=192, prefil
 MID_CFG = dataclasses.replace(WIDE_CFG, max_slots=16, max_model_len=96)
 
 
-@pytest.fixture(scope="module", autouse=True)
-def highest_precision():
-    with jax.default_matmul_precision("highest"):
-        yield
-
-
 @pytest.fixture(scope="module", params=["openpangu", "xing4"])
 def model(request):
     """(the module, its plain reference, the tiny shape, the config, seeded weights)."""
-    tests = importlib.import_module(f"tests.test_{request.param}")
+    shape = SHAPES[request.param]
     module = importlib.import_module(f"dynamo_tpu.models.{request.param}")
     reference = importlib.import_module(f"benchmark.reference_{request.param}")
-    cfg = config_from_card(card(tests.SHAPE), jnp.float32)
-    return types.SimpleNamespace(module=module, ref=reference, shape=tests.SHAPE, cfg=cfg,
+    cfg = config_from_card(card(shape), jnp.float32)
+    return types.SimpleNamespace(module=module, ref=reference, shape=shape, cfg=cfg,
                                  params=module.init_params(jax.random.PRNGKey(3), cfg))
 
 
@@ -137,8 +132,8 @@ def test_a_prefix_hit_stands_in_front_of_a_lanes_rows(model, wide, one):
     assert (toks, finish) == (want_toks, "length")
     np.testing.assert_allclose(lps, want_lps, atol=ATOL)
     stream = np.asarray(prompt + toks, np.int32)
-    logits = np.asarray(model.ref.logits(model.params, model.shape, jnp.asarray(stream),
-                                         jnp.arange(len(prompt) - 1, len(stream) - 1)))
+    logits = np.asarray(reference_program(model.ref, model.shape)(
+        model.params, jnp.asarray(stream), jnp.arange(len(prompt) - 1, len(stream) - 1)))
     np.testing.assert_allclose(lps, jax.nn.log_softmax(logits)[np.arange(len(toks)), toks], atol=ATOL)
 
 
@@ -203,8 +198,8 @@ def test_the_device_drafter_follows_a_lanes_rows(model, one, monkeypatch):
             toks, _, finish = answer(seq)
             assert (toks, finish) == (served(one, prompt, 14)[0], "length")
             stream = np.asarray(prompt + toks, np.int32)
-            want = np.asarray(model.ref.draft_logits(model.params, model.shape, jnp.asarray(stream),
-                                                     jnp.arange(len(stream) - 1)))
+            want = np.asarray(reference_program(model.ref, model.shape, "draft_logits")(
+                model.params, jnp.asarray(stream), jnp.arange(len(stream) - 1)))
             mine = offered[id(seq.drafter)]
             assert mine[0][1] == len(prompt) + 1  # the first offer: behind the prompt's chunk rows
             for token, at in mine:  # a guess for a stream of `at` tokens: position at - 2's module output
@@ -242,7 +237,9 @@ def test_the_rows_lanes_are_read_by_no_equation_of_the_chunk_program(model, monk
     returns, so the ladder's programs are the parent's to the character and
     ``setup_s`` and the compile cache are untouched. (A change that made a row
     look for its lane would read it, and this test would say so.)"""
-    monkeypatch.setattr(openpangu, "TOKENS_AT_ONCE", 4 * C)  # groups of four rows, as the served chunk of 128 has
+    # groups of four rows, as the served chunk of 128 has; the programs are traced here, under the patch, and
+    # kept nowhere
+    patched(monkeypatch, openpangu, "TOKENS_AT_ONCE", 4 * C)
     mb = 8
     drafting = program != "forward_chunk"
     cache = jax.eval_shape(lambda: model.module.make_kv_cache(model.cfg, 1 + 2 * mb, 8, drafting=drafting))

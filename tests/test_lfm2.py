@@ -18,7 +18,6 @@ import os
 import re
 import subprocess
 import sys
-import types
 
 import jax
 import jax.numpy as jnp
@@ -32,7 +31,10 @@ from dynamo_tpu.kv.pages import MigrationRejected, StateNotPortable
 from dynamo_tpu.models import lfm2, llama, module_for
 from dynamo_tpu.ops import moe
 
-from .test_chunk_rows import answer, busy, run_out, step, submit
+from .step_programs import (  # noqa: F401  (highest_precision: autouse, for this file's tests)
+    MIXED, answer, busy, card, chunk_program, decode_program, highest_precision, patched, prompt_of, published_shape,
+    reference_program, run_out, served, step, submit,
+)
 
 # ATOL, the float32 build: float32 on the CPU at the highest matmul precision
 # on both sides, so the program and the reference differ by the order of their
@@ -67,20 +69,6 @@ ENGINE_CFG = EngineConfig(max_slots=4, kv_block_size=8, max_model_len=96,
                           prefill_chunk=16, decode_steps=4, top_logprobs=5)
 PUBLISHED = "benchmark/configs/lfm2-24b-a2b.json"
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def card(shape):
-    return types.SimpleNamespace(model_config=shape, model_path=None, gguf_path=None)
-
-
-def prompt_of(n, salt=0):
-    return [(salt * 31 + 7 * i + 3) % 95 + 1 for i in range(n)]
-
-
-@pytest.fixture(scope="module", autouse=True)
-def highest_precision():
-    with jax.default_matmul_precision("highest"):
-        yield
 
 
 @pytest.fixture(scope="module")
@@ -159,6 +147,7 @@ def dispatch_rows(cfg, params, dispatches, rows=2, slots=4, mb=8, n_decode=3, be
     cache = lfm2.make_kv_cache(cfg, 1 + len(fed) * mb, bs)
     state = jax.tree.map(lambda a: a + 7.0, lfm2.make_slot_state(cfg, slots))  # stale, every slot
     at, got, sums = dict.fromkeys(fed, 0), {slot: [] for slot in fed}, []
+    chunk = chunk_program(lfm2, cfg)
     for d in dispatches:
         toks, pos = np.zeros((rows, c), np.int32), np.full((rows, c), -1, np.int32)
         tables, lanes = np.zeros((rows, mb), np.int32), np.full((rows,), slots, np.int32)
@@ -169,9 +158,8 @@ def dispatch_rows(cfg, params, dispatches, rows=2, slots=4, mb=8, n_decode=3, be
             toks[r, :n], pos[r, :n] = toks_of[slot][at[slot]:at[slot] + n], np.arange(at[slot], at[slot] + n)
             tables[r], lanes[r] = table[slot], slot
             at[slot] += n
-        h, cache, state, counted = lfm2.forward_chunk(
-            params, cfg, jnp.asarray(toks), jnp.asarray(pos), cache, jnp.asarray(tables),
-            state, jnp.asarray(lanes))
+        h, cache, state, counted = chunk(
+            params, jnp.asarray(toks), jnp.asarray(pos), cache, jnp.asarray(tables), state, jnp.asarray(lanes))
         for r, row in enumerate(d):
             if row is not None:
                 got[row[0]].append(np.asarray(lfm2.lm_head(params, cfg, h[r, :row[1]]), np.float32))
@@ -182,17 +170,13 @@ def dispatch_rows(cfg, params, dispatches, rows=2, slots=4, mb=8, n_decode=3, be
         return {slot: (toks_of[slot], np.concatenate(got[slot])) for slot in fed}, state, cache, sums
     lanes_tables = np.zeros((slots, mb), np.int32)
     toks, pos = np.zeros((slots,), np.int32), np.full((slots,), -1, np.int32)
-    forcing = np.zeros((slots, max(length.values()) + n_decode), np.int32)
+    forcing = np.zeros((slots, bs * mb), np.int32)  # a table's positions wide: one program a geometry
     for slot in fed:
         lanes_tables[slot], toks[slot], pos[slot] = table[slot], toks_of[slot][length[slot]], length[slot]
         forcing[slot, :len(toks_of[slot])] = toks_of[slot]
 
-    def forced(logits, p, carry, k):  # teacher forcing: each sequence's own next token
-        nxt = jnp.asarray(forcing)[jnp.arange(slots), jnp.clip(p + 1, 0, forcing.shape[1] - 1)]
-        return jnp.where(p >= 0, nxt, 0), carry, logits
-
-    out = lfm2.decode(params, cfg, jnp.asarray(toks), jnp.asarray(pos), cache,
-                      jnp.asarray(lanes_tables), state, n_decode, 8 * mb - 1, forced, None)
+    out = decode_program(lfm2, cfg, n_decode, 8 * mb - 1)(  # teacher forcing: each sequence's own next token
+        params, jnp.asarray(toks), jnp.asarray(pos), cache, jnp.asarray(lanes_tables), state, jnp.asarray(forcing))
     counted = dict(zip(lfm2.COUNTERS, np.asarray(out[6]).tolist()))
     assert [int(out[1][slot]) for slot in fed] == [length[slot] + n_decode for slot in fed]
     assert counted["conv_layer_calls"] == n_decode * N_CONV
@@ -217,7 +201,7 @@ def prefill_then_decode(cfg, params, chunks, n_decode=3, between=None):
 
 
 def reference_of(params, tokens):
-    return np.asarray(ref.logits(params, SHAPE, jnp.asarray(tokens), jnp.arange(len(tokens))))
+    return np.asarray(reference_program(ref, SHAPE)(params, jnp.asarray(tokens), jnp.arange(len(tokens))))
 
 
 def a_chunk_a_dispatch(*chunks):
@@ -292,16 +276,16 @@ def test_a_coarser_or_wrong_program_fails_the_float32_tolerance(cfg, params, mon
     tokens: the weights alone), the other two by hundreds."""
     if what == "the_routers_input":
         route = moe.route_sigmoid_topk
-        monkeypatch.setattr(moe, "route_sigmoid_topk", lambda x, *a, **kw: route(
+        patched(monkeypatch, moe, "route_sigmoid_topk", lambda x, *a, **kw: route(
             x.astype(jnp.bfloat16).astype(jnp.float32), *a, **kw))
     elif what == "the_rotation":
         # the tails and the history say 16 tokens came before; the rotation says none did
         real = lfm2.apply_rope
-        monkeypatch.setattr(lfm2, "apply_rope", lambda x, pos, theta: real(
+        patched(monkeypatch, lfm2, "apply_rope", lambda x, pos, theta: real(
             x, jnp.where(pos >= 16, pos - 16, pos), theta))
     else:
         norm = lfm2.rms_norm
-        monkeypatch.setattr(lfm2, "rms_norm", lambda x, w, eps: x if x.ndim == 4 else norm(x, w, eps))
+        patched(monkeypatch, lfm2, "rms_norm", lambda x, w, eps: x if x.ndim == 4 else norm(x, w, eps))
     tokens, got, *_ = prefill_then_decode(cfg, params, (16, 9))
     assert np.abs(got - reference_of(params, tokens)).max() > (3 if what == "the_routers_input" else 100) * ATOL
 
@@ -321,7 +305,7 @@ def test_the_convolutions_tail_carries_across(cfg, params, where, monkeypatch):
         want = reference_of(params, tokens)
         np.testing.assert_allclose(got, want, atol=ATOL)
         mixer = lfm2.conv_mixer
-        monkeypatch.setattr(lfm2, "conv_mixer", lambda *a: mixer(*a[:5]))  # nothing from the row above
+        patched(monkeypatch, lfm2, "conv_mixer", lambda *a: mixer(*a[:5]))  # nothing from the row above
         served, state, *_ = dispatch_rows(cfg, params, **how)
         cut, first, last = served[2][1], 16, 25
         monkeypatch.undo()
@@ -389,13 +373,12 @@ def test_an_empty_rows_state_comes_back_bit_for_bit(cfg, params, where):
     if where == "a_chunk_row":
         toks = np.full((2, 16), 5, np.int32)
         pos = np.stack([np.full((16,), -1), np.arange(16)]).astype(np.int32)
-        out = lfm2.forward_chunk(params, cfg, jnp.asarray(toks), jnp.asarray(pos), cache,
-                                 jnp.asarray(tables[1:3]), state, jnp.asarray([1, 2], jnp.int32))[2]
+        out = chunk_program(lfm2, cfg)(params, jnp.asarray(toks), jnp.asarray(pos), cache,
+                                       jnp.asarray(tables[1:3]), state, jnp.asarray([1, 2], jnp.int32))[2]
     else:
         pos = np.asarray([-1, -1, 20, -1], np.int32)
-        out = lfm2.decode(params, cfg, jnp.full((slots,), 5, jnp.int32), jnp.asarray(pos), cache,
-                          jnp.asarray(tables), state, 3, 95,
-                          lambda logits, p, carry, k: (jnp.argmax(logits, -1).astype(jnp.int32), carry, p), None)[5]
+        out = decode_program(lfm2, cfg, 3, 95)(  # no forcing: a lane's own first choice is its next token
+            params, jnp.full((slots,), 5, jnp.int32), jnp.asarray(pos), cache, jnp.asarray(tables), state, None)[5]
     for was, now in zip(state["conv"], out["conv"]):
         assert np.array_equal(np.asarray(was[(0, 1, 3), :]), np.asarray(now[(0, 1, 3), :]))
         assert not np.array_equal(np.asarray(was[2]), np.asarray(now[2]))
@@ -414,8 +397,8 @@ def test_a_chunk_of_more_rows_is_taken_in_groups_and_gives_each_row_what_it_give
         toks[r, :n], pos[r, :n] = prompt_of(n, salt=r), np.arange(n)
     tables = 1 + np.arange(rows * mb, dtype=np.int32).reshape(rows, mb)
     cache, state = lfm2.make_kv_cache(cfg, 1 + rows * mb, bs), lfm2.make_slot_state(cfg, rows)
-    h, cache, state, counted = lfm2.forward_chunk(
-        params, cfg, jnp.asarray(toks), jnp.asarray(pos), cache, jnp.asarray(tables), state,
+    h, cache, state, counted = chunk_program(lfm2, cfg)(
+        params, jnp.asarray(toks), jnp.asarray(pos), cache, jnp.asarray(tables), state,
         jnp.arange(rows, dtype=jnp.int32))
     counted = dict(zip(lfm2.COUNTERS, np.asarray(counted).tolist()))
     groups = -(-rows // lfm2.ROWS_AT_ONCE)
@@ -423,8 +406,8 @@ def test_a_chunk_of_more_rows_is_taken_in_groups_and_gives_each_row_what_it_give
     assert counted["conv_layer_calls"] == groups * N_CONV and counted["slot_state_resets"] == rows
     assert counted["moe_held_rows"] == 2 * N_EXPERT_LAYERS * sum(lengths)
     for r in (0, rows - 1):
-        one = lfm2.forward_chunk(
-            params, cfg, jnp.asarray(toks[r:r + 1]), jnp.asarray(pos[r:r + 1]),
+        one = chunk_program(lfm2, cfg)(
+            params, jnp.asarray(toks[r:r + 1]), jnp.asarray(pos[r:r + 1]),
             lfm2.make_kv_cache(cfg, 1 + rows * mb, bs), jnp.asarray(tables[r:r + 1]),
             lfm2.make_slot_state(cfg, rows), jnp.asarray([r], jnp.int32))
         n = lengths[r]
@@ -497,10 +480,7 @@ def test_serving_another_card_imports_no_lfm2(model_type):
     layer embeds a Pallas kernel, and Mosaic's import is seconds of
     ``setup_s``): ``config_from_card`` and ``module_for`` import a module in
     its own branch alone."""
-    from .test_jamba import SHAPE as jamba_shape
-    from .test_kimi_linear import SHAPE as kimi_shape
-
-    shape = {"qwen2": {"model_type": "qwen2"}, "kimi_linear": kimi_shape, "jamba": jamba_shape}[model_type]
+    shape = {"model_type": "qwen2"} if model_type == "qwen2" else published_shape(model_type)
     code = (
         "import sys, types, json\n"
         "from dynamo_tpu.engine_jax.weights import config_from_card\n"
@@ -516,12 +496,6 @@ def test_serving_another_card_imports_no_lfm2(model_type):
     assert done.stdout.strip().endswith(f"dynamo_tpu.models.{want} False"), done.stdout
 
 
-def served(engine, prompt, max_tokens, **sampling):
-    seq = submit(engine, prompt, max_tokens, **sampling)
-    run_out(engine)
-    return answer(seq)
-
-
 def test_the_engine_serves_the_reference_greedy_tokens_and_logprobs(engine, params):
     """Through ``JaxServingEngine``: admission, three chunk dispatches,
     pipelined decode dispatches of 4 steps, sampling and log-probabilities,
@@ -529,7 +503,7 @@ def test_the_engine_serves_the_reference_greedy_tokens_and_logprobs(engine, para
     prompt = prompt_of(37)
     toks, lps, finish = served(engine, prompt, 10, logprobs=5)
     seq = jnp.asarray(prompt + toks[:-1], jnp.int32)
-    want = np.asarray(ref.logits(params, SHAPE, seq, jnp.arange(len(prompt) - 1, len(seq))))
+    want = np.asarray(reference_program(ref, SHAPE)(params, seq, jnp.arange(len(prompt) - 1, len(seq))))
     assert toks == want.argmax(-1).tolist() and len(toks) == 10 and finish == "length"
     logp = want - np.log(np.exp(want).sum(-1, keepdims=True))
     np.testing.assert_allclose(lps, logp[np.arange(10), toks], atol=ATOL)
@@ -609,12 +583,6 @@ def test_a_repeated_prompt_takes_no_prefix_hit(engine):
 # ladder [8, 16, 64]: a lane fills up to sixteen rows of a dispatch, in one group of 8 or two
 WIDE_CFG = EngineConfig(max_slots=64, kv_block_size=8, max_model_len=192, prefill_chunk=16,
                         decode_steps=4)
-# (the step a request is submitted on, prompt tokens, answered): a prompt of 7 chunks beside lanes that
-# decode, prompts of 1 to 9 chunks at once (more rows than the second rung holds: the pieces left go on
-# in the next step), a late long one behind decoding lanes
-MIXED = [(0, 9, 24), (2, 100, 8), (2, 12, 10), (3, 60, 6), (3, 140, 5), (3, 37, 9), (3, 90, 5),
-         (4, 128, 6), (4, 16, 7), (9, 75, 5)]
-
 
 def test_every_request_answers_as_alone_where_a_lane_fills_several_rows(cfg, params):
     """Mixed traffic on a ladder whose rungs under the full width hold 8 and 16
@@ -736,7 +704,7 @@ def test_the_full_width_chunk_program_holds_nothing_of_the_hand_over(engine, mon
         raise AssertionError("the hand-over, in a program that has one row a lane")
 
     for name in ("_Layout", "_Left", "lane_first_positions", "sibling_rows_back", "chunk_rows_above_partial"):
-        monkeypatch.setattr(lfm2, name, unreachable)
+        patched(monkeypatch, lfm2, name, unreachable)
     assert lowered_step_programs(engine)[0].as_text() == text
     assert hashlib.sha256(text.encode()).hexdigest() == FULL_WIDTH_CHUNK_SHA256
     with pytest.raises(AssertionError, match="the hand-over"):

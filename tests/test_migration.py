@@ -451,7 +451,7 @@ class TestEngineStageAdopt:
         tests/test_chunk_rows.py drives it (no engine thread): since a chunk
         and a decode dispatch share a host step, a 12-token stream on the
         engine's thread can end before a test on another posts its freeze."""
-        from .test_chunk_rows import answer, run_out, step, submit
+        from .step_programs import answer, run_out, step, submit
 
         prompt = list(range(13, 33))
         control = _engine(tiny)
@@ -1045,7 +1045,7 @@ class TestBlackoutCutComposition:
         can vouch for nothing) with zero client-visible failures and
         byte-equal streams — the two chaos modes composed, which neither
         gate previously exercised together."""
-        from .test_resume import TokenEngine, expected_stream
+        from .token_engine import TokenEngine, expected_stream
 
         async def go():
             resilience.reset_resume_counters()
@@ -1149,7 +1149,7 @@ class TestBlackoutCutComposition:
 
 class TestLlmctlDrainWait:
     def test_wait_exit_codes_and_json(self, run, monkeypatch, capsys):
-        from .test_resume import TokenEngine
+        from .token_engine import TokenEngine
 
         from dynamo_tpu.cli import llmctl
 
@@ -1253,7 +1253,7 @@ class TestMigrationGauges:
             ClusterTelemetry,
         )
 
-        from .test_promtext import parse_prometheus_text
+        from .promtext import parse_prometheus_text
 
         stats = MockWorkerStats(
             seed=1, migrations_total=5, migrations_failed=1,

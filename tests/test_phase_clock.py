@@ -411,7 +411,7 @@ def test_a_traced_engines_xplane_holds_its_spans_on_the_device_events_clock(tmp_
     A CPU run: no device number."""
     import jax
 
-    from .test_chunk_rows import busy, submit
+    from .step_programs import busy, submit
 
     eng = tiny_engine()
     clock = eng._clock

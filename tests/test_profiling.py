@@ -557,7 +557,7 @@ class TestFrontendProfiling:
         assert "serialize" in phases
 
     def test_metrics_exposition_stays_promtext_valid(self, monkeypatch, run):
-        from .test_promtext import parse_prometheus_text
+        from .promtext import parse_prometheus_text
 
         _arm(monkeypatch)
         profiling.frontend_cpu().note("serialize", 10.0, tokens=1)
@@ -646,7 +646,7 @@ class TestGauges:
         return ForwardPassMetrics(**kw)
 
     def test_worker_aggregator_exposition(self):
-        from .test_promtext import parse_prometheus_text
+        from .promtext import parse_prometheus_text
 
         from dynamo_tpu.components.metrics import MetricsAggregator
 
@@ -667,7 +667,7 @@ class TestGauges:
         assert sample and sample[0][2] == 850.5
 
     def test_cluster_rollup_max_and_sum(self):
-        from .test_promtext import parse_prometheus_text
+        from .promtext import parse_prometheus_text
 
         from dynamo_tpu.components.telemetry_aggregator import (
             ClusterTelemetry,
@@ -742,7 +742,7 @@ class TestProfileCapture:
         --json prints per-worker summaries, --trace writes a
         Perfetto-loadable Chrome-trace file whose slices carry the PR5
         ids."""
-        from .test_resume import TokenEngine
+        from .token_engine import TokenEngine
 
         from dynamo_tpu.cli import llmctl
         from dynamo_tpu.runtime.distributed import DistributedRuntime
@@ -800,7 +800,7 @@ class TestProfileCapture:
         run(go())
 
     def test_capture_reports_disarmed_worker(self, run, monkeypatch, capsys):
-        from .test_resume import TokenEngine
+        from .token_engine import TokenEngine
 
         from dynamo_tpu.cli import llmctl
         from dynamo_tpu.runtime.distributed import DistributedRuntime
@@ -832,7 +832,7 @@ class TestProfileCapture:
     def test_rpc_profile_dump_verb(self, run, monkeypatch):
         """The raw RPC verb: profile_dump answers local profiling state
         (safe while the engine is wedged — pure memory read)."""
-        from .test_resume import TokenEngine
+        from .token_engine import TokenEngine
 
         from dynamo_tpu.runtime.distributed import DistributedRuntime
         from dynamo_tpu.runtime.rpc import RpcClient

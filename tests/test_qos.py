@@ -1328,7 +1328,7 @@ class TestTenantTelemetry:
     def test_tenant_gauges_render_and_parse(self):
         from dynamo_tpu.components.telemetry_aggregator import ClusterTelemetry
 
-        from .test_promtext import parse_prometheus_text
+        from .promtext import parse_prometheus_text
 
         ct = ClusterTelemetry("tq", clock=lambda: 100.0)
         ct.ingest("w0", self._metrics({

@@ -20,7 +20,6 @@ import os
 import re
 import subprocess
 import sys
-import types
 
 import jax
 import jax.numpy as jnp
@@ -36,7 +35,10 @@ from dynamo_tpu.models import qwen3_next as qn
 from dynamo_tpu.ops import moe
 from dynamo_tpu.ops.pallas.kda_scan import kda_scan, kda_step
 
-from .test_chunk_rows import answer, run_out, step, submit
+from .step_programs import (  # noqa: F401  (highest_precision: autouse, for this file's tests)
+    answer, card, chunk_program, decode_program, highest_precision, patched, prompt_of, published_shape,
+    reference_program, run_out, served, step, submit,
+)
 
 # ATOL, the float32 build: float32 on the CPU at the highest matmul precision
 # on both sides, so the program and the reference differ by the order of their
@@ -73,20 +75,6 @@ ENGINE_CFG = EngineConfig(max_slots=4, kv_block_size=8, max_model_len=96,
                           prefill_chunk=16, decode_steps=4, top_logprobs=5)
 PUBLISHED = "benchmark/configs/qwen3-next-80b-a3b.json"
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def card(shape):
-    return types.SimpleNamespace(model_config=shape, model_path=None, gguf_path=None)
-
-
-def prompt_of(n, salt=0):
-    return [(salt * 31 + 7 * i + 3) % 95 + 1 for i in range(n)]
-
-
-@pytest.fixture(scope="module", autouse=True)
-def highest_precision():
-    with jax.default_matmul_precision("highest"):
-        yield
 
 
 @pytest.fixture(scope="module")
@@ -176,8 +164,8 @@ def prefill_then_decode(cfg, params, chunks, n_decode=3, between=None):
     for n in chunks:
         toks, pos = np.zeros((2, c), np.int32), np.full((2, c), -1, np.int32)
         toks[0, :n], pos[0, :n] = tokens[at:at + n], np.arange(at, at + n)
-        h, cache, state, counted = qn.forward_chunk(
-            params, cfg, jnp.asarray(toks), jnp.asarray(pos), cache, jnp.asarray(tables),
+        h, cache, state, counted = chunk_program(qn, cfg)(
+            params, jnp.asarray(toks), jnp.asarray(pos), cache, jnp.asarray(tables),
             state, jnp.asarray([slot, slots], jnp.int32))
         got.append(np.asarray(qn.lm_head(params, cfg, h[0, :n]), np.float32))
         sums.append(np.asarray(counted))
@@ -189,11 +177,10 @@ def prefill_then_decode(cfg, params, chunks, n_decode=3, between=None):
     toks, pos = np.zeros((slots,), np.int32), np.full((slots,), -1, np.int32)
     toks[slot], pos[slot] = tokens[n_prompt], n_prompt
 
-    def forced(logits, p, carry, k):  # teacher forcing: the sequence's own next token
-        return jnp.where(p >= 0, jnp.asarray(tokens)[jnp.clip(p + 1, 0, len(tokens) - 1)], 0), carry, logits
-
-    out = qn.decode(params, cfg, jnp.asarray(toks), jnp.asarray(pos), cache,
-                    jnp.asarray(lanes_tables), state, n_decode, 95, forced, None)
+    forcing = np.zeros((slots, bs * mb), np.int32)  # teacher forcing: the sequence's own next token
+    forcing[slot, :len(tokens)] = tokens
+    out = decode_program(qn, cfg, n_decode, 95)(
+        params, jnp.asarray(toks), jnp.asarray(pos), cache, jnp.asarray(lanes_tables), state, jnp.asarray(forcing))
     counted = dict(zip(qn.COUNTERS, np.asarray(out[6]).tolist()))
     assert int(out[1][slot]) == n_prompt + n_decode
     # a decode step advances no chunk and resets nothing; one lane routes 4 pairs a layer and step
@@ -205,7 +192,7 @@ def prefill_then_decode(cfg, params, chunks, n_decode=3, between=None):
 
 
 def reference_of(params, tokens, shape=SHAPE):
-    return np.asarray(ref.logits(params, shape, jnp.asarray(tokens), jnp.arange(len(tokens))))
+    return np.asarray(reference_program(ref, shape)(params, jnp.asarray(tokens), jnp.arange(len(tokens))))
 
 
 @pytest.mark.parametrize("dtype, atol", [(jnp.float32, ATOL), (jnp.bfloat16, ATOL_BF16)],
@@ -257,19 +244,19 @@ def test_a_coarser_or_wrong_program_fails_the_float32_tolerance(monkeypatch, wha
     params = seeded_params(cfg)
     if what == "the_routers_input":
         route = moe.route_softmax_topk
-        monkeypatch.setattr(moe, "route_softmax_topk", lambda x, *a, **kw: route(
+        patched(monkeypatch, moe, "route_softmax_topk", lambda x, *a, **kw: route(
             x.astype(jnp.bfloat16).astype(jnp.float32), *a, **kw))
     elif what == "a_plain_norm_weight":
-        monkeypatch.setattr(qn, "_norm", qn.rms_norm)
+        patched(monkeypatch, qn, "_norm", qn.rms_norm)
     elif what == "a_rotation_of_the_whole_head":
-        monkeypatch.setattr(qn.Qwen3NextConfig, "rotary_dim", property(lambda c: c.head_dim))
+        patched(monkeypatch, qn.Qwen3NextConfig, "rotary_dim", property(lambda c: c.head_dim))
     else:
         def after(x, router, top_k, renormalize):
             logits = jnp.dot(x, router, precision=jax.lax.Precision.HIGHEST)
             chosen, ids = jax.lax.top_k(logits, top_k)
             return ids.astype(jnp.int32), jax.nn.softmax(chosen, axis=-1)
 
-        monkeypatch.setattr(moe, "route_softmax_topk", after)
+        patched(monkeypatch, moe, "route_softmax_topk", after)
     tokens, got, *_ = prefill_then_decode(cfg, params, (16, 9))
     assert np.abs(got - reference_of(params, tokens, shape)).max() > 3 * ATOL
 
@@ -374,13 +361,12 @@ def test_an_empty_rows_state_comes_back_bit_for_bit(cfg, params, where):
     if where == "a_chunk_row":
         toks = np.full((2, 16), 5, np.int32)
         pos = np.stack([np.full((16,), -1), np.arange(16, 32)]).astype(np.int32)
-        out = qn.forward_chunk(params, cfg, jnp.asarray(toks), jnp.asarray(pos), cache,
-                               jnp.asarray(tables[1:3]), state, jnp.asarray([1, 2], jnp.int32))[2]
+        out = chunk_program(qn, cfg)(params, jnp.asarray(toks), jnp.asarray(pos), cache,
+                                     jnp.asarray(tables[1:3]), state, jnp.asarray([1, 2], jnp.int32))[2]
     else:
         pos = np.asarray([-1, -1, 20, -1], np.int32)
-        out = qn.decode(params, cfg, jnp.full((slots,), 5, jnp.int32), jnp.asarray(pos), cache,
-                        jnp.asarray(tables), state, 3, 95,
-                        lambda logits, p, carry, k: (jnp.argmax(logits, -1).astype(jnp.int32), carry, p), None)[5]
+        out = decode_program(qn, cfg, 3, 95)(  # no forcing: a lane's own first choice is its next token
+            params, jnp.full((slots,), 5, jnp.int32), jnp.asarray(pos), cache, jnp.asarray(tables), state, None)[5]
     for name in ("s", "conv"):
         for was, now in zip(state[name], out[name]):
             assert np.array_equal(np.asarray(was[(0, 1, 3), :]), np.asarray(now[(0, 1, 3), :]))
@@ -400,8 +386,8 @@ def test_a_chunk_of_more_rows_is_taken_in_groups_and_gives_each_row_what_it_give
         toks[r, :n], pos[r, :n] = prompt_of(n, salt=r), np.arange(n)
     tables = 1 + np.arange(rows * mb, dtype=np.int32).reshape(rows, mb)
     cache, state = qn.make_kv_cache(cfg, 1 + rows * mb, bs), qn.make_slot_state(cfg, rows)
-    h, cache, state, counted = qn.forward_chunk(
-        params, cfg, jnp.asarray(toks), jnp.asarray(pos), cache, jnp.asarray(tables), state,
+    h, cache, state, counted = chunk_program(qn, cfg)(
+        params, jnp.asarray(toks), jnp.asarray(pos), cache, jnp.asarray(tables), state,
         jnp.arange(rows, dtype=jnp.int32))
     counted = dict(zip(qn.COUNTERS, np.asarray(counted).tolist()))
     groups = -(-rows // qn.ROWS_AT_ONCE)
@@ -409,8 +395,8 @@ def test_a_chunk_of_more_rows_is_taken_in_groups_and_gives_each_row_what_it_give
     assert counted["gdn_chunk_tokens"] == N_GDN * sum(lengths) and counted["gdn_state_passes"] == N_GDN * rows
     assert counted["moe_routed_pairs"] == 4 * N_LAYERS * sum(lengths)
     for r in (0, rows - 1):
-        one = qn.forward_chunk(
-            params, cfg, jnp.asarray(toks[r:r + 1]), jnp.asarray(pos[r:r + 1]),
+        one = chunk_program(qn, cfg)(
+            params, jnp.asarray(toks[r:r + 1]), jnp.asarray(pos[r:r + 1]),
             qn.make_kv_cache(cfg, 1 + rows * mb, bs), jnp.asarray(tables[r:r + 1]),
             qn.make_slot_state(cfg, rows), jnp.asarray([r], jnp.int32))
         n = lengths[r]
@@ -467,12 +453,7 @@ def test_serving_another_card_imports_no_qwen3_next(model_type):
     """A fifth module costs the other four's start-up nothing:
     ``config_from_card`` and ``module_for`` import a module in its own branch
     alone."""
-    from .test_jamba import SHAPE as jamba_shape
-    from .test_kimi_linear import SHAPE as kimi_shape
-    from .test_lfm2 import SHAPE as lfm2_shape
-
-    shape = {"qwen2": {"model_type": "qwen2"}, "kimi_linear": kimi_shape, "jamba": jamba_shape,
-             "lfm2_moe": lfm2_shape}[model_type]
+    shape = {"model_type": "qwen2"} if model_type == "qwen2" else published_shape(model_type)
     code = (
         "import sys, types, json\n"
         "from dynamo_tpu.engine_jax.weights import config_from_card\n"
@@ -488,12 +469,6 @@ def test_serving_another_card_imports_no_qwen3_next(model_type):
     assert done.stdout.strip().endswith(f"dynamo_tpu.models.{want} False"), done.stdout
 
 
-def served(engine, prompt, max_tokens, **sampling):
-    seq = submit(engine, prompt, max_tokens, **sampling)
-    run_out(engine)
-    return answer(seq)
-
-
 def test_the_engine_serves_the_reference_greedy_tokens_and_logprobs(engine, params):
     """Through ``JaxServingEngine``: admission, three chunk dispatches,
     pipelined decode dispatches of 4 steps, sampling and log-probabilities,
@@ -501,7 +476,7 @@ def test_the_engine_serves_the_reference_greedy_tokens_and_logprobs(engine, para
     prompt = prompt_of(37)
     toks, lps, finish = served(engine, prompt, 10, logprobs=5)
     seq = jnp.asarray(prompt + toks[:-1], jnp.int32)
-    want = np.asarray(ref.logits(params, SHAPE, seq, jnp.arange(len(prompt) - 1, len(seq))))
+    want = np.asarray(reference_program(ref, SHAPE)(params, seq, jnp.arange(len(prompt) - 1, len(seq))))
     assert toks == want.argmax(-1).tolist() and len(toks) == 10 and finish == "length"
     logp = want - np.log(np.exp(want).sum(-1, keepdims=True))
     np.testing.assert_allclose(lps, logp[np.arange(10), toks], atol=ATOL)

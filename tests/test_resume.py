@@ -33,6 +33,9 @@ from dynamo_tpu.runtime.resilience import (
 from dynamo_tpu.runtime.rpc import RpcServer
 from dynamo_tpu.runtime.statestore import StateStoreServer
 
+from .token_engine import TokenEngine, expected_stream
+from .token_engine import payload as _payload
+
 NO_BUS = "127.0.0.1:1"
 
 
@@ -65,15 +68,6 @@ class TestResumeKnobs:
 
 
 # -- the journal ---------------------------------------------------------------
-
-
-def _payload(prompt, max_tokens=16, **sc_extra):
-    return {
-        "token_ids": list(prompt),
-        "stop_conditions": dict({"max_tokens": max_tokens}, **sc_extra),
-        "sampling_options": {"temperature": 0.0},
-        "eos_token_ids": [],
-    }
 
 
 class TestStreamJournal:
@@ -141,49 +135,6 @@ class TestStreamCutFault:
 
 
 # -- mock cluster with deterministic token engines -----------------------------
-
-
-def _next_token(toks):
-    """Pure function of the full context — the greedy-decode stand-in. Any
-    two workers continue an identical prefix identically, so resumed
-    output can be byte-compared against an undisturbed control."""
-    return (toks[-1] * 31 + len(toks) * 7 + 13) % 50021
-
-
-def expected_stream(prompt, max_tokens):
-    toks = list(prompt)
-    out = []
-    for _ in range(max_tokens):
-        nxt = _next_token(toks)
-        toks.append(nxt)
-        out.append(nxt)
-    return out
-
-
-class TokenEngine(AsyncEngine):
-    """Token-level mock engine honoring the PreprocessedRequest wire shape:
-    emits one LLMEngineOutput dict per step, each the deterministic
-    function of prompt+generated, finishing at max_tokens."""
-
-    def __init__(self, tag: str, delay: float = 0.0):
-        self.tag = tag
-        self.delay = delay
-
-    async def generate(self, request: Context):
-        req = request.data
-        toks = list(req["token_ids"])
-        max_t = int(req["stop_conditions"]["max_tokens"])
-        for _ in range(max_t):
-            if request.context.is_stopped:
-                return
-            nxt = _next_token(toks)
-            toks.append(nxt)
-            yield Annotated.from_data({"token_ids": [nxt]})
-            if self.delay:
-                await asyncio.sleep(self.delay)
-            else:
-                await asyncio.sleep(0)
-        yield Annotated.from_data({"token_ids": [], "finish_reason": "length"})
 
 
 def _policy(**kw) -> ResiliencePolicy:
@@ -588,7 +539,7 @@ class TestResumeGauges:
         from dynamo_tpu.components.mock_worker import MockWorkerStats
         from dynamo_tpu.components.telemetry_aggregator import ClusterTelemetry
 
-        from .test_promtext import parse_prometheus_text
+        from .promtext import parse_prometheus_text
 
         stats = MockWorkerStats(seed=1, resume_total=7, resume_failed=2)
         stats.tick(requests=3)

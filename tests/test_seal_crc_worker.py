@@ -25,11 +25,10 @@ from dynamo_tpu.models.llama import init_params
 from dynamo_tpu.runtime import integrity
 from dynamo_tpu.runtime.profiling import P_SEAL_CRC
 
-from .test_chunk_rows import (
-    CFG, ENGINE_CFG, MIXED, busy, mesh_engine, prompt_of, run_out, serve_schedule,
-    step, submit,
-)
-from .test_kv_pages import POOLS, _pool
+from .dense_harness import CFG, MIXED, POOLS, mesh_engine, prompt_of, serve_schedule
+from .dense_harness import CHUNK_ROWS_CFG as ENGINE_CFG
+from .dense_harness import pool as _pool
+from .step_programs import busy, run_out, step, submit
 
 BLOCK = ENGINE_CFG.kv_block_size
 WORKER = "jax-engine-seal-crc"

@@ -19,7 +19,6 @@ import asyncio
 import dataclasses
 import inspect
 import math
-import types
 
 import jax
 import jax.numpy as jnp
@@ -33,10 +32,12 @@ from dynamo_tpu.models import llama, module_for
 from dynamo_tpu.models import xing4 as xm
 from dynamo_tpu.ops import mhc
 
-from .test_chunk_rows import answer, run_out, submit
-from .test_engine_spec import collect
-from .test_jamba import room_for_compiled_programs  # noqa: F401  (autouse: clears JAX's caches past 30,000)
-from .test_openpangu import LANE_ROWS, check_lane_rows
+from .latent_harness import BS, LANE_ROWS, MB, YARN, check_lane_rows, feed
+from .latent_harness import XING4_SHAPE as SHAPE
+from .step_programs import (  # noqa: F401  (highest_precision: autouse, for this file's tests)
+    answer, card, collect, decode_program, highest_precision, patched, prompt_of, reference_program, run_out,
+    submit,
+)
 
 # ATOL: float32 on the CPU, at the highest matmul precision on both sides. The
 # program and the reference order their sums differently (absorbed against
@@ -49,38 +50,8 @@ from .test_openpangu import LANE_ROWS, check_lane_rows
 # the maps each move a logit by 2e-3 and more (the tests below).
 ATOL = 2e-4
 
-YARN = {"beta_fast": 32, "beta_slow": 1, "factor": 64, "mscale": 1, "mscale_all_dim": 1,
-        "original_max_position_embeddings": 64, "type": "yarn"}
-SHAPE = {
-    "model_type": "xing4_0", "hidden_size": 64, "intermediate_size": 128,
-    "num_hidden_layers": 3, "num_attention_heads": 4, "num_key_value_heads": 4,
-    "q_lora_rank": 24, "kv_lora_rank": 32, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8,
-    "v_head_dim": 16, "rope_theta": 10000, "max_position_embeddings": 262144, "rope_scaling": YARN,
-    "first_k_dense_replace": 1, "moe_intermediate_size": 32, "n_routed_experts": 8,
-    "n_shared_experts": 1, "num_experts_per_tok": 2, "n_group": 1, "topk_group": 1,
-    "scoring_func": "sigmoid", "topk_method": "noaux_tc", "norm_topk_prob": True,
-    "routed_scaling_factor": 2.0, "num_nextn_predict_layers": 1, "hidden_act": "silu",
-    "rms_norm_eps": 1e-6, "hc_mult": 4, "hc_sinkhorn_iters": 20, "hc_eps": 1e-6,
-    "mhc_h_res_clamp_min": -30, "mhc_h_res_clamp_max": 30, "ep_size": 1,
-    "attention_bias": False, "tie_word_embeddings": False, "vocab_size": 96,
-}
 ENGINE_CFG = EngineConfig(max_slots=4, kv_block_size=8, max_model_len=96,
                           prefill_chunk=16, decode_steps=4, top_logprobs=5)
-BS, MB, C = 8, 8, 16
-
-
-def card(shape):
-    return types.SimpleNamespace(model_config=shape, model_path=None, gguf_path=None)
-
-
-def prompt_of(n, salt=0):
-    return [(salt * 31 + 7 * i + 3) % 95 + 1 for i in range(n)]
-
-
-@pytest.fixture(scope="module", autouse=True)
-def highest_precision():
-    with jax.default_matmul_precision("highest"):
-        yield
 
 
 @pytest.fixture(scope="module")
@@ -120,31 +91,9 @@ def drafting_engine(cfg, params):
     eng.close()
 
 
-def feed(cfg, params, cache, tokens, start, n, table, *, drafting=False, following=None):
-    """One chunk dispatch of ``n`` tokens from ``start`` in row 0 (row 1 is
-    padding): (logits ``[n, V]``, the module's logits or None, the pool, sums)."""
-    toks, pos = np.zeros((2, C), np.int32), np.full((2, C), -1, np.int32)
-    toks[0, :n], pos[0, :n] = tokens[start:start + n], np.arange(start, start + n)
-    tables = np.zeros((2, MB), np.int32)
-    tables[0] = table
-    x, cache, state, sums = xm.forward_chunk(
-        params, cfg, jnp.asarray(toks), jnp.asarray(pos), cache, jnp.asarray(tables),
-        None, jnp.asarray([0, 4], jnp.int32), raw=True)
-    assert state is None and x.shape == (2, C, 64)  # ONE stream leaves the program
-    logits = xm.lm_head(params, cfg, xm.final_norm(params, cfg, x)[0, :n])
-    drafts = None
-    if drafting:
-        nxt = np.zeros((2, C), np.int32)
-        nxt[0, :n] = following[start:start + n]
-        hd, cache, more = xm.draft_chunk(params, cfg, x, jnp.asarray(nxt), jnp.asarray(pos),
-                                         cache, jnp.asarray(tables))
-        drafts, sums = xm.lm_head(params, cfg, hd[0, :n]), sums + more
-    return logits, drafts, cache, np.asarray(sums)
-
-
 def program_logits(cfg, params, tokens):
     """A 14-token prompt's logits from one chunk dispatch."""
-    got, _, _, _ = feed(cfg, params, xm.make_kv_cache(cfg, 32, BS), tokens, 0, len(tokens), np.arange(1, 9))
+    got, _, _, _ = feed(xm, cfg, params, xm.make_kv_cache(cfg, 32, BS), tokens, 0, len(tokens), np.arange(1, 9))
     return np.asarray(got)
 
 
@@ -174,14 +123,14 @@ def test_chunked_prefill_then_decode_agrees_with_the_plain_reference(cfg, params
     prediction module's logits at every position of both; the counters."""
     n_prompt, n_decode = sum(chunks), 3
     tokens = np.asarray(prompt_of(n_prompt + n_decode + 1, salt=len(chunks)), np.int32)
-    want = np.asarray(ref.logits(params, SHAPE, jnp.asarray(tokens), jnp.arange(len(tokens))))
-    want_draft = np.asarray(ref.draft_logits(params, SHAPE, jnp.asarray(tokens),
-                                             jnp.arange(len(tokens) - 1)))
+    want = np.asarray(reference_program(ref, SHAPE)(params, jnp.asarray(tokens), jnp.arange(len(tokens))))
+    want_draft = np.asarray(reference_program(ref, SHAPE, "draft_logits")(
+        params, jnp.asarray(tokens), jnp.arange(len(tokens) - 1)))
     cache = xm.make_kv_cache(cfg, 32, BS, drafting=True)
     table = np.arange(1, 9)
     got, got_draft, at = [], [], 0
     for n in chunks:
-        logits, drafts, cache, sums = feed(cfg, params, cache, tokens, at, n, table,
+        logits, drafts, cache, sums = feed(xm, cfg, params, cache, tokens, at, n, table,
                                            drafting=True, following=tokens[1:])
         got.append(logits), got_draft.append(drafts)
         counts = dict(zip(xm.COUNTERS, sums))
@@ -200,20 +149,19 @@ def test_chunked_prefill_then_decode_agrees_with_the_plain_reference(cfg, params
     toks, pos = np.zeros((slots,), np.int32), np.full((slots,), -1, np.int32)
     toks[slot], pos[slot] = tokens[n_prompt], n_prompt
 
-    def forced(logits, p, carry, k):  # teacher forcing: the sequence's own next token
-        return jnp.where(p >= 0, jnp.asarray(tokens)[jnp.clip(p + 1, 0, len(tokens) - 1)], 0), carry, logits
-
-    out = xm.decode(params, cfg, jnp.asarray(toks), jnp.asarray(pos), cache,
-                    jnp.asarray(lanes_tables), None, n_decode, 95, forced, None, draft=True)
+    forcing = np.zeros((slots, BS * MB), np.int32)  # teacher forcing: the sequence's own next token
+    forcing[slot, :len(tokens)] = tokens
+    out = decode_program(xm, cfg, n_decode, 95, draft=True)(
+        params, jnp.asarray(toks), jnp.asarray(pos), cache, jnp.asarray(lanes_tables), None, jnp.asarray(forcing))
     np.testing.assert_allclose(np.asarray(out[3])[:, slot], want[n_prompt:n_prompt + n_decode], atol=ATOL)
     assert out[5] is None and int(out[1][slot]) == n_prompt + n_decode
     assert int(out[7][slot]) == int(want_draft[n_prompt + n_decode - 1].argmax())
     counts = dict(zip(xm.COUNTERS, np.asarray(out[6])))
     assert counts["mla_layer_calls"] == 4 * n_decode and counts["mtp_layer_calls"] == n_decode
     assert counts["mhc_mix_calls"] == 8 * n_decode and counts["mhc_rows_mixed"] == 8 * n_decode  # one lane decodes
-    plain = xm.decode(params, cfg, jnp.asarray(toks), jnp.asarray(pos),
-                      {"latent": cache["latent"][:3]}, jnp.asarray(lanes_tables), None,
-                      n_decode, 95, forced, None)
+    plain = decode_program(xm, cfg, n_decode, 95)(
+        params, jnp.asarray(toks), jnp.asarray(pos), {"latent": cache["latent"][:3]}, jnp.asarray(lanes_tables),
+        None, jnp.asarray(forcing))
     counts = dict(zip(xm.COUNTERS, np.asarray(plain[6])))
     assert len(plain) == 7 and counts["mtp_layer_calls"] == 0 and counts["mhc_mix_calls"] == 6 * n_decode
     np.testing.assert_allclose(np.asarray(plain[3])[:, slot], np.asarray(out[3])[:, slot], atol=ATOL)
@@ -224,12 +172,12 @@ def test_a_lane_that_starts_past_position_zero_is_rotated_at_its_own_positions(c
     its queries and keys with YaRN's frequencies at 16 on and attends the
     cached, rotated keys before it."""
     tokens = np.asarray(prompt_of(29, salt=5), np.int32)
-    want = np.asarray(ref.logits(params, SHAPE, jnp.asarray(tokens), jnp.arange(29)))
+    want = np.asarray(reference_program(ref, SHAPE)(params, jnp.asarray(tokens), jnp.arange(29)))
     cache = xm.make_kv_cache(cfg, 32, BS)
-    _, _, cache, _ = feed(cfg, params, cache, tokens, 0, 16, np.asarray([5, 6, 0, 0, 0, 0, 0, 0]))
-    logits, _, cache, _ = feed(cfg, params, cache, tokens, 16, 13, np.asarray([5, 6, 9, 10, 0, 0, 0, 0]))
+    _, _, cache, _ = feed(xm, cfg, params, cache, tokens, 0, 16, np.asarray([5, 6, 0, 0, 0, 0, 0, 0]))
+    logits, _, cache, _ = feed(xm, cfg, params, cache, tokens, 16, 13, np.asarray([5, 6, 9, 10, 0, 0, 0, 0]))
     np.testing.assert_allclose(logits, want[16:], atol=ATOL)
-    fresh, _, _, _ = feed(cfg, params, xm.make_kv_cache(cfg, 32, BS), tokens[16:], 0, 13, np.arange(1, 9))
+    fresh, _, _, _ = feed(xm, cfg, params, xm.make_kv_cache(cfg, 32, BS), tokens[16:], 0, 13, np.arange(1, 9))
     assert np.abs(np.asarray(fresh) - want[16:]).max() > 100 * ATOL
 
 
@@ -275,7 +223,7 @@ def test_a_departure_from_the_equations_cannot_hide(cfg, params, monkeypatch, de
     tolerance is asked to see those.)"""
     tokens = np.asarray(prompt_of(14, salt=2), np.int32)
     got = program_logits(cfg, params, tokens)
-    sound = np.asarray(ref.logits(params, SHAPE, jnp.asarray(tokens), jnp.arange(14)))
+    sound = np.asarray(reference_program(ref, SHAPE)(params, jnp.asarray(tokens), jnp.arange(14)))
     np.testing.assert_allclose(got, sound, atol=ATOL)
     departed = DEPARTURES[departure](params, monkeypatch)
     assert np.abs(got - departed).max() > 10 * ATOL, np.abs(got - departed).max()
@@ -285,7 +233,7 @@ def test_a_dropped_selection_bias_cannot_hide(cfg, params):
     """The program WITHOUT the router's selection bias chooses other experts
     in some token-layers, and its logits leave the reference's."""
     tokens = np.asarray(prompt_of(14, salt=2), np.int32)
-    want = np.asarray(ref.logits(params, SHAPE, jnp.asarray(tokens), jnp.arange(14)))
+    want = np.asarray(reference_program(ref, SHAPE)(params, jnp.asarray(tokens), jnp.arange(14)))
     without = jax.tree_util.tree_map_with_path(
         lambda path, leaf: jnp.zeros_like(leaf) if "e_bias" in jax.tree_util.keystr(path) else leaf, params)
     assert np.abs(program_logits(cfg, without, tokens) - want).max() > 10 * ATOL
@@ -297,9 +245,9 @@ def test_bfloat16_activations_in_the_maps_would_fail(cfg, params, monkeypatch):
     tolerance; and so does the reference with its activations rounded in front
     of every weight product."""
     tokens = np.asarray(prompt_of(14, salt=2), np.int32)
-    want = np.asarray(ref.logits(params, SHAPE, jnp.asarray(tokens), jnp.arange(14)))
+    want = np.asarray(reference_program(ref, SHAPE)(params, jnp.asarray(tokens), jnp.arange(14)))
     wdot = mhc.wdot
-    monkeypatch.setattr(mhc, "wdot", lambda spec, x, w: wdot(spec, x.astype(jnp.bfloat16).astype(jnp.float32), w))
+    patched(monkeypatch, mhc, "wdot", lambda spec, x, w: wdot(spec, x.astype(jnp.bfloat16).astype(jnp.float32), w))
     assert np.abs(program_logits(cfg, params, tokens) - want).max() > 10 * ATOL
     monkeypatch.undo()
 
@@ -468,7 +416,7 @@ def test_a_prefix_hit_is_served_from_latent_pages_and_the_counters_rise(cfg, par
     assert after["prefix_hit_tokens"] - before["prefix_hit_tokens"] == 3 * BS
     seq = np.asarray(shared + [4, 5, 6, 7] + toks, np.int32)
     at = np.arange(30, 30 + len(toks))
-    logits = np.asarray(ref.logits(params, SHAPE, jnp.asarray(seq), jnp.asarray(at)))
+    logits = np.asarray(reference_program(ref, SHAPE)(params, jnp.asarray(seq), jnp.asarray(at)))
     want = jax.nn.log_softmax(logits)[np.arange(len(toks)), toks]
     np.testing.assert_allclose(lps, want, atol=ATOL)
     for name in ("mhc_mix_calls", "mhc_rows_mixed", "mla_layer_calls", "moe_layer_calls", "moe_held_rows"):
