@@ -1073,9 +1073,9 @@ class JaxServingEngine(AsyncEngine):
         # so when the rows find each other's fresh keys, in the program's
         # hands (`models/llama.py`) or through the pool (`openpangu`,
         # `xing4`); one WITH state when it also hands the state from row to
-        # row (`lfm2`, `jamba`; `kimi_linear` and `qwen3_next` say nothing,
-        # until their kernel does). The engines of one rung keep one row a
-        # lane (`_rides`).
+        # row (`lfm2` a tail; `jamba`, `kimi_linear` and `qwen3_next` inside
+        # their recurrence's kernel). Every module says so today; the
+        # engines of one rung keep one row a lane (`_rides`).
         self._lane_rows = (
             len(self._chunk_rungs) > 1
             and getattr(self.model, "LANE_TAKES_ROWS", False)

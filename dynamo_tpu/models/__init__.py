@@ -64,8 +64,18 @@ def module_for(model_config):
     the chip from the lane's first row to its last
     (``ops/pallas/selective_scan.py:selective_scan(..., continues)``), so that
     the lane's FIRST row holds the state after its last and writes it, the
-    last row the tail. ``qwen3_next`` and ``kimi_linear`` say nothing until
-    ``kda_scan`` does the same.
+    last row the tail. ``kimi_linear`` and ``qwen3_next`` do (the seventh and
+    eighth, and the last: every module sets it): their recurrence is ONE
+    kernel too, and ``ops/pallas/kda_scan.py:kda_scan(..., continues)`` hands
+    the delta-rule state from row to row in ``selective_scan``'s form. For
+    (3) Kimi's rows meet through the pool as openPangu's do (a layer writes
+    every row's latents before any row gathers its table, under a causal mask
+    by position); Qwen3-Next's attention layers read pages that are written
+    after the loop over its groups of 8 rows, so it has ``lfm2``'s form: a
+    lane's later row attends its earlier rows' fresh K and V in hand, and
+    what a later group needs of an earlier one (those K and V, the last row's
+    tails, and the last row's sequence STATE of each DeltaNet layer, which
+    ``lfm2``'s tails do not need) rides the loop's carry.
 
     A config that is no ``LlamaConfig`` was made by its own module's class
     (``engine_jax/weights.py:config_from_card`` imports that module in its

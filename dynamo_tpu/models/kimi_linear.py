@@ -15,6 +15,10 @@ Two kinds of state live side by side:
   and hand it back; a chunk row whose first position is 0 starts from zeros,
   which is how a slot is reset when a request is admitted to it. Nothing
   outside this module indexes it, and ``pages.take`` / ``put`` never see it.
+  Under the full width a lane may fill several rows of a chunk dispatch
+  (``LANE_TAKES_ROWS``): a later row goes on from the row above it, the
+  state inside the kernel and the convolutions behind that row's last
+  inputs, and the slot is written once (:func:`forward_chunk`).
 
 Every mechanism but two is plain ``jax.numpy`` through XLA (the expert layer's
 products and a chunk's KDA recurrence are kernels, below). The weights are bfloat16
@@ -28,13 +32,14 @@ latent pages are float32 with them: a latent rounded to bfloat16 on its way
 into the pool was enough to swap an expert in three probes of fourteen on the
 chip (PERF.md, the model's section). The chunk form of the KDA
 recurrence is the recurrence itself, token by token, in ONE kernel a layer
-that holds a (row, head) pair's state on the chip from the row's first token
-to its last valid one (``ops/pallas/kda_scan.py``); a decode step, one token,
-is the same step in ``jax.numpy``: the shape picks the path. The MLA
-layer attends in the absorbed form: the query's no-position part goes through
-``W_kvb``'s key half into the latent space, scores and the weighted sum are
-taken against the cached latent, and ``W_kvb``'s value half comes after; the
-same mathematics as expanding keys and values from the latent at every step.
+that holds a (lane, head) pair's state on the chip from the first token of the
+lane's first row to the last valid one of its last (``ops/pallas/kda_scan.py``);
+a decode step, one token, is the same step in ``jax.numpy``: the shape picks
+the path. The MLA layer attends in the absorbed form: the query's no-position
+part goes through ``W_kvb``'s key half into the latent space, scores and the
+weighted sum are taken against the cached latent, and ``W_kvb``'s value half
+comes after; the same mathematics as expanding keys and values from the latent
+at every step.
 
 The expert layer holds ``num_experts`` experts from ``first_expert`` on, of
 the ``num_experts_published`` the router scores (``ops/moe.py``).
@@ -66,9 +71,14 @@ SlotState = Dict[str, Tuple[jax.Array, ...]]  # {"s": per KDA layer, "conv": ...
 # sums the step programs return, in this order (engine: /debug/engine)
 COUNTERS = ("moe_layer_calls", "moe_held_rows", "moe_experts_hit", "moe_routed_pairs",
             "moe_rows_computed", "moe_expert_reads",
-            # what the chunks' KDA kernel advanced: valid tokens, and rows with one (each
-            # a read and a write of a row's state); summed over the KDA layers
-            "kda_chunk_tokens", "kda_state_passes", "slot_state_resets")
+            # what the chunks' KDA kernel advanced: valid tokens, and lanes of a dispatch
+            # with one (each a read and a write of a slot's state); summed over the KDA layers
+            "kda_chunk_tokens", "kda_state_passes", "slot_state_resets",
+            # rows that took their state from the row above them, on the chip
+            "kda_state_handovers")
+# a lane may fill several rows of a chunk dispatch under the full width (engine.py:chunk_rows_of):
+# a row whose lane is that of the row above it goes on where that row ends (`forward_chunk`)
+LANE_TAKES_ROWS = True
 # rows of a chunk computed at once: the rows are independent, and a chunk of
 # more is taken in groups, which bounds what the program holds beside its
 # arguments (64 rows at once: 3.7 GB of temporaries next to 9.4 GB)
@@ -272,10 +282,11 @@ def make_slot_state(config: KimiLinearConfig, slots: int) -> SlotState:
     }
 
 
-def chunk_history_tiles(positions, block_size: int, table_blocks: int) -> int:
+def chunk_history_tiles(positions, block_size: int, table_blocks: int, lanes=None) -> int:
     """Tiles of a block table a chunk dispatch reads, for the host's count
     (``models/llama.py`` has the form): :func:`mla_attend` scores every row's
-    whole table, whatever it holds."""
+    whole table, whatever it holds and whichever rows are one lane's
+    (``lanes`` is taken and not read)."""
     return history_tiles_full(block_size, table_blocks)
 
 
@@ -287,14 +298,24 @@ def decode_history_tiles(base, block_size: int, table_blocks: int) -> int:
 # -- KDA ----------------------------------------------------------------------
 
 def _kda_inputs(lp: Params, c: KimiLinearConfig, x: jax.Array, conv_tail: jax.Array,
-                valid: jax.Array):
+                valid: jax.Array, above=None):
     """Everything the recurrence needs of a ``[B, T, E]`` block of normed
     inputs whose valid tokens are a prefix of each row: q, k (normalised), v,
     log-decay, beta (float32, ``[B, T, H, ...]``), the output gate, and the
-    convolutions' new tails (the last ``K - 1`` valid inputs of each row)."""
+    convolutions' new tails (the last ``K - 1`` valid inputs of each row). A
+    chunk's row that goes on from the row ``above`` it (``[B]`` bool), a FULL
+    row of the same sequence, starts its three convolutions behind that row's
+    last ``K - 1`` inputs and not behind ``conv_tail``: they are the
+    projections of that row's own tokens, so the rows are still convolved all
+    at once."""
     b, t, _ = x.shape
     h, dk, kk = c.kda_heads, c.kda_head_dim, c.conv_kernel
     pre = jnp.concatenate([_mm(x, lp["wq"]), _mm(x, lp["wk"]), _mm(x, lp["wv"])], axis=-1)
+    if above is not None:
+        if t < kk - 1:
+            raise ValueError(f"a row of {t} tokens holds no tail of {kk - 1}")
+        ends = pre[:, t - (kk - 1):]  # what each row, if full, leaves the row under it
+        conv_tail = jnp.where(above[:, None, None], jnp.concatenate([conv_tail[:1], ends[:-1]]), conv_tail)
     seq = jnp.concatenate([conv_tail, pre], axis=1)  # [B, K-1+T, 3D]
     w = jnp.concatenate([lp["conv_q"], lp["conv_k"], lp["conv_v"]], axis=-1)  # [K, 3D]
     # causal depthwise: tap K-1 is the token itself, tap 0 the oldest input
@@ -322,18 +343,22 @@ def _kda_output(lp: Params, c: KimiLinearConfig, o: jax.Array, gate: jax.Array):
 
 
 def kda_mixer(lp: Params, c: KimiLinearConfig, x: jax.Array, valid: jax.Array,
-              s: jax.Array, conv_tail: jax.Array):
+              s: jax.Array, conv_tail: jax.Array, above=None):
     """The KDA mixer over ``[B, T, E]`` normed inputs from the rows' state:
     (output ``[B, T, E]``, state after the last valid token, new tails). One
     token (a decode step) is ``ops/pallas/kda_scan.py:kda_step`` (shared with
     ``models/qwen3_next.py``); more (a chunk) are one call of the kernel that
-    keeps the state on the chip (outputs past a row's valid tokens: zeros)."""
-    q, k, v, log_decay, beta, gate, new_tail = _kda_inputs(lp, c, x, conv_tail, valid)
+    keeps the state on the chip (outputs past a row's valid tokens: zeros). A
+    chunk's row that goes on from the row ``above`` it takes that row's last
+    inputs and state, the state inside the kernel: the state returned is then
+    the SEQUENCE's, after its last row, at its first (the rows that go on have
+    no entry of their own), and the tails are each row's own."""
+    q, k, v, log_decay, beta, gate, new_tail = _kda_inputs(lp, c, x, conv_tail, valid, above)
     if x.shape[1] == 1:
         new, o = _kda_step(s, q[:, 0], k[:, 0], v[:, 0], log_decay[:, 0], beta[:, 0])
         s, o = jnp.where(valid[:, 0, None, None, None], new, s), o[:, None]
     else:
-        o, s = kda_scan(q, k, v, log_decay, beta, s, valid.sum(axis=1),
+        o, s = kda_scan(q, k, v, log_decay, beta, s, valid.sum(axis=1), above,
                         interpret=jax.default_backend() == "cpu")
     return _kda_output(lp, c, o, gate), s, new_tail
 
@@ -388,26 +413,43 @@ def forward_chunk(
     params: Params, config: KimiLinearConfig, tokens: jax.Array, positions: jax.Array,
     kv_cache: KVCache, block_tables: jax.Array, state: SlotState, lanes: jax.Array,
 ):
-    """A ``[R, C]`` block of prompt tokens, one row per prefilling lane
-    (``lanes`` ``[R]``: the row's slot; ``max_slots`` and above = a padding
-    row), valid tokens (position >= 0) a prefix of each row.
+    """A ``[R, C]`` block of prompt tokens (``lanes`` ``[R]``: the row's slot;
+    ``max_slots`` and above = a padding row), valid tokens (position >= 0) a
+    prefix of each row. Under the full width (``R`` < the state's slots) a lane
+    may fill several CONSECUTIVE rows with successive pieces of its prompt, in
+    order, each full but the last; at it, one row a lane.
 
     Returns (hidden ``[R, C, E]`` after the final norm, the pool with the
     rows' latents written, the slot state with the rows' slots advanced, the
     counters ``[len(COUNTERS)]``). A row whose first position is 0 starts from a zeroed
     state: a slot is reset by the first chunk of the request admitted to it.
     More than ``ROWS_AT_ONCE`` rows are taken in groups of that many, one
-    after another (a row touches its own slot and pages only)."""
+    after another over one pool and one state.
+
+    A row whose lane is that of the row above it in its group (both real, with
+    a valid token) goes on where that row ends: its convolutions start behind
+    that row's last inputs and its recurrence from that row's state inside the
+    kernel (:func:`kda_mixer`), and only the lane's FIRST row of the group
+    writes the slot's state (the kernel leaves it there), only its LAST the
+    tail. The latent attention needs nothing said: a layer writes every row's
+    latents into the pool before any row gathers its table, under a causal
+    mask by position, and a lane's rows share a table. A lane whose rows
+    straddle two groups is served by the loop's carry: the later group's first
+    row goes on from nothing inside its group and reads the slot's state and
+    tail, which the group before wrote. At the full width none of this is in
+    the program."""
     rows = tokens.shape[0]
+    handed = rows < (state["s"][0].shape[0] if state["s"] else 0)
     if rows <= ROWS_AT_ONCE:
-        return _chunk_rows(params, config, tokens, positions, kv_cache, block_tables, state, lanes)
+        return _chunk_rows(params, config, tokens, positions, kv_cache, block_tables, state, lanes, handed)
     if rows % ROWS_AT_ONCE:
         raise ValueError(f"{rows} rows are no whole number of groups of {ROWS_AT_ONCE}")
 
     def group(carry, xs):
         kv_cache, state, sums = carry
         toks, pos, tables, lanes = xs
-        h, kv_cache, state, more = _chunk_rows(params, config, toks, pos, kv_cache, tables, state, lanes)
+        h, kv_cache, state, more = _chunk_rows(
+            params, config, toks, pos, kv_cache, tables, state, lanes, handed)
         return (kv_cache, state, sums + more), h
 
     def grouped(a):
@@ -419,15 +461,23 @@ def forward_chunk(
     return h.reshape(rows, *h.shape[2:]), kv_cache, state, sums
 
 
-def _chunk_rows(params, config, tokens, positions, kv_cache, block_tables, state, lanes):
-    """:func:`forward_chunk` of the rows given, all at once."""
+def _chunk_rows(params, config, tokens, positions, kv_cache, block_tables, state, lanes, handed):
+    """:func:`forward_chunk` of the rows given, all at once; ``handed``: a lane
+    may fill several of them (the dispatch is under the full width)."""
     c = config
     valid = positions >= 0
     fresh = positions[:, 0] == 0
     slots = state["s"][0].shape[0] if state["s"] else 0
     lane = jnp.clip(lanes, 0, max(slots - 1, 0))
     # a padding row writes nowhere: its slot index lies past the state
-    back = jnp.where(lanes < slots, lanes, slots)
+    back = s_back = jnp.where(lanes < slots, lanes, slots)
+    above = None
+    if handed:
+        live = (lanes < slots) & valid[:, 0]
+        above = jnp.concatenate([jnp.zeros((1,), bool), (lanes[1:] == lanes[:-1]) & live[1:] & live[:-1]])
+        # nor do a lane's rows but ONE: the first holds the state after the last, the last the tail
+        s_back = jnp.where(above, slots, back)
+        back = jnp.where(jnp.concatenate([above[1:], jnp.zeros((1,), bool)]), slots, back)
     pool = kv_cache["latent"]
     s_out, conv_out = list(state["s"]), list(state["conv"])
     counters = jnp.zeros((MOE_COUNTERS,), jnp.int32)
@@ -445,8 +495,8 @@ def _chunk_rows(params, config, tokens, positions, kv_cache, block_tables, state
                 keep = ~fresh
                 s0 = jnp.where(keep[:, None, None, None], state["s"][i_kda][lane], 0.0)
                 tail0 = jnp.where(keep[:, None, None], state["conv"][i_kda][lane], 0)
-                y, s1, tail1 = kda_mixer(lp, c, x, valid, s0, tail0)
-                s_out[i_kda] = state["s"][i_kda].at[back].set(s1, mode="drop")
+                y, s1, tail1 = kda_mixer(lp, c, x, valid, s0, tail0, above)
+                s_out[i_kda] = state["s"][i_kda].at[s_back].set(s1, mode="drop")
                 conv_out[i_kda] = state["conv"][i_kda].at[back].set(tail1, mode="drop")
             i_kda += 1
         else:
@@ -461,7 +511,11 @@ def _chunk_rows(params, config, tokens, positions, kv_cache, block_tables, state
     h = rms_norm(h, params["final_norm"], c.rms_norm_eps)
     resets = jnp.sum(fresh & (lanes < slots))
     advanced = valid.sum(axis=1)  # a KDA layer's kernel advances each row by its valid tokens
-    own = jnp.stack([i_kda * advanced.sum(), i_kda * jnp.sum(advanced > 0), resets]).astype(jnp.int32)
+    begins = advanced > 0  # a lane's first row of the group: where its state goes in and out
+    if above is not None:
+        begins &= ~above
+    own = jnp.stack([i_kda * advanced.sum(), i_kda * jnp.sum(begins), resets,
+                     jnp.int32(0) if above is None else jnp.sum(above)]).astype(jnp.int32)
     return (h, {"latent": pool}, {"s": tuple(s_out), "conv": tuple(conv_out)},
             jnp.concatenate([counters, own]))
 

@@ -70,9 +70,9 @@ import jax.numpy as jnp
 
 from dynamo_tpu.models.llama import (  # noqa: F401  (the two tile counts are this module's too)
     _chunk_self_partial, _live_window_attention, _merge_partials, _pool_pages, apply_rope,
-    chunk_history_partial, chunk_history_tiles, chunk_rows_above_partial, decode_history_tiles,
-    embed_lookup, flush_window, history_tile, history_tiles_full, lane_first_positions, rms_norm,
-    sibling_rows_back, with_live_history,
+    chunk_history_partial, chunk_history_tiles, chunk_layout, chunk_rows_above_partial,
+    decode_history_tiles, embed_lookup, flush_window, history_tile, history_tiles_full, rms_norm,
+    with_live_history,
 )
 from dynamo_tpu.ops import moe
 from dynamo_tpu.ops.parts import dot_parts, operand_parts
@@ -97,7 +97,7 @@ MOE_COUNTERS = COUNTERS.index("conv_layer_calls")  # the first: what dropless_ex
 # "State per slot", says what a module with state per slot owes for it): under
 # the full width a row whose lane is that of the row above it starts each
 # convolution from that row's last inputs and attends its lane's earlier rows'
-# fresh keys (`_Layout`), and a lane's last row alone leaves the slot its tail
+# fresh keys (`llama.ChunkLayout`), and a lane's last row alone leaves the slot its tail
 LANE_TAKES_ROWS = True
 # rows of a chunk computed at once: the rows are independent, and a chunk of
 # more is taken in groups. 8 rows of 128 positions route 4,096 pairs, 64 rows
@@ -372,17 +372,6 @@ def feed_forward(lp: Params, c: Lfm2Config, layer: int, h: jax.Array, valid: jax
 
 # -- the step programs --------------------------------------------------------
 
-class _Layout(NamedTuple):
-    """A chunk dispatch in which a lane may fill several rows (under the full
-    width), as each group of its rows is told it."""
-
-    positions: jax.Array  # [N, C] of every row of the dispatch
-    lanes: jax.Array  # [N]
-    takes: jax.Array  # [N] a real row whose lane is that of the real row above it
-    starts: jax.Array  # [N] where a row's pool history ends: `lane_first_positions`
-    n_back: jax.Array  # the sibling loop's trips: `sibling_rows_back`
-
-
 class _Left(NamedTuple):
     """What the groups of such a dispatch so far leave the next one: all that
     the loop over the groups carries from group to group."""
@@ -413,7 +402,7 @@ def forward_chunk(
     written after it: one scatter a pool array and one a conv layer.
 
     A row whose lane is that of the row above it (both real) goes on where
-    that row ends, inside the program (``_Layout``; the loop over the groups
+    that row ends, inside the program (``llama.ChunkLayout``; the loop over the groups
     carries what a later group needs of the earlier ones, ``_Left``, and no
     more): its convolutions start from that row's last inputs and not from
     the slot's tail (:func:`conv_mixer`), its pool history ends where its
@@ -431,13 +420,7 @@ def forward_chunk(
     num_blocks = kv_cache["k"].shape[1]
     layout = left = None
     if rows < slots:  # at the full width a lane has one row, and the program is what it was
-        live = (lanes < slots) & (positions[:, 0] >= 0)
-        layout = _Layout(
-            positions=positions, lanes=lanes,
-            takes=jnp.concatenate([
-                jnp.zeros((1,), bool), (lanes[1:] == lanes[:-1]) & live[1:] & live[:-1]]),
-            starts=lane_first_positions(positions, lanes),
-            n_back=sibling_rows_back(positions, lanes))
+        layout = chunk_layout(positions, lanes, slots)
         none_yet = jnp.zeros((c.layer_types.count("full_attention"), *tokens.shape,
                               *_rows_of_heads(c)[1:]), kv_cache["k"].dtype)
         left = _Left(
@@ -504,12 +487,11 @@ def _chunk_rows(params, c, pages, num_blocks, conv, layout, left, tokens, positi
     tile_blocks = history_tile(block_size, table_blocks) // block_size
     # positions whose first says where each row's pool history ends: the row's own, or
     # with rows above those of its lane's first row (the rows between: their keys in hand)
-    ends = positions if layout is None else jax.lax.dynamic_slice_in_dim(layout.starts, left.at, n)[:, None]
+    ends, takes = (positions, None) if layout is None else layout.rows(left.at, n)
     history_len = jnp.clip(ends[:, 0], 0, table_blocks * block_size)
     n_tiles = chunk_history_tiles(ends, block_size, table_blocks)
     tables = jnp.pad(block_tables, (
         (0, 0), (0, history_tiles_full(block_size, table_blocks) * tile_blocks - table_blocks)))
-    takes = None if layout is None else jax.lax.dynamic_slice_in_dim(layout.takes, left.at, n)
 
     h = embed_lookup(params, tokens, c.dtype).astype(jnp.float32)
     tails, fresh_k, fresh_v = [], [], []

@@ -1244,6 +1244,36 @@ def sibling_rows_back(positions, lanes):
     return jnp.where(pair, rows[:, None] - rows[None, :], 0).max()
 
 
+class ChunkLayout(NamedTuple):
+    """A chunk dispatch in which a lane may fill several rows (under the full
+    width), as a chunk program that takes its rows some at a time tells each
+    group of them (``models/lfm2.py``, ``models/qwen3_next.py``)."""
+
+    positions: jax.Array  # [N, C] of every row of the dispatch
+    lanes: jax.Array  # [N]
+    takes: jax.Array  # [N] a real row whose lane is that of the real row above it
+    starts: jax.Array  # [N] where a row's pool history ends: `lane_first_positions`
+    n_back: jax.Array  # the sibling loop's trips: `sibling_rows_back`
+
+    def rows(self, at, n):
+        """Of rows ``at`` .. ``at + n - 1``: (where each one's pool history
+        ends, ``[n, 1]`` as a column of positions; which take, ``[n]``)."""
+        return (jax.lax.dynamic_slice_in_dim(self.starts, at, n)[:, None],
+                jax.lax.dynamic_slice_in_dim(self.takes, at, n))
+
+
+def chunk_layout(positions, lanes, slots: int) -> ChunkLayout:
+    """The layout of a dispatch of ``positions`` ``[N, C]`` whose rows' lanes
+    are ``lanes`` ``[N]`` (``slots`` and above: a padding row)."""
+    live = (lanes < slots) & (positions[:, 0] >= 0)
+    return ChunkLayout(
+        positions=positions, lanes=lanes,
+        takes=jnp.concatenate([
+            jnp.zeros((1,), bool), (lanes[1:] == lanes[:-1]) & live[1:] & live[:-1]]),
+        starts=lane_first_positions(positions, lanes),
+        n_back=sibling_rows_back(positions, lanes))
+
+
 def chunk_rows_above_partial(
     c: LlamaConfig,
     q: jax.Array,  # [B, T, H, D] the queries of rows `at` .. `at + B - 1` of the dispatch

@@ -26,7 +26,11 @@ PR 48.) Two kinds of state live side by side:
   chunk row whose first position is 0 starts from zeros, which is how a slot is
   reset when a request is admitted to it; padding rows, padding positions and
   lanes that do not decode leave it untouched. Nothing outside this module
-  indexes it, and ``pages.take`` / ``put`` never see it.
+  indexes it, and ``pages.take`` / ``put`` never see it. Under the full width
+  a lane may fill several rows of a chunk dispatch (``LANE_TAKES_ROWS``): a
+  later row goes on from the row above it (the state inside the kernel, the
+  convolution from that row's last inputs, attention over that row's fresh
+  keys), and the slot is written once (:func:`forward_chunk`).
 
 The recurrence is Kimi-Linear's kernel, as it is: Gated DeltaNet IS
 ``ops/pallas/kda_scan.py``'s delta rule with the head's one log-decay spread
@@ -54,15 +58,16 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from dynamo_tpu.models.llama import (  # noqa: F401  (the two tile counts are this module's too)
     _chunk_self_partial, _live_window_attention, _merge_partials, _pool_pages, apply_rope,
-    chunk_history_partial, chunk_history_tiles, decode_history_tiles, embed_lookup, flush_window,
-    history_tile, history_tiles_full, rms_norm, with_live_history,
+    chunk_history_partial, chunk_history_tiles, chunk_layout, chunk_rows_above_partial,
+    decode_history_tiles, embed_lookup, flush_window, history_tile, history_tiles_full, rms_norm,
+    with_live_history,
 )
 from dynamo_tpu.ops import moe
 from dynamo_tpu.ops.pallas.kda_scan import kda_scan, kda_step
@@ -76,11 +81,15 @@ SlotState = Dict[str, Tuple[jax.Array, ...]]  # {"s", "conv"}: one array a Delta
 # of ops/moe.py:dropless_experts, under the names models/kimi_linear.py gives
 # them (a call is one expert layer over a decode step's lanes or over a group
 # of a chunk's rows); what the chunks' kernel advanced, as Kimi's ``kda_*``:
-# valid tokens, and rows with one (each a read and a write of a row's state),
-# summed over the DeltaNet layers; rows that started a request
+# valid tokens, and lanes of a group with one (each a read and a write of a
+# sequence's state), summed over the DeltaNet layers; rows that started a
+# request; rows that took their state from the row above them
 COUNTERS = ("moe_layer_calls", "moe_held_rows", "moe_experts_hit", "moe_routed_pairs",
             "moe_rows_computed", "moe_expert_reads",
-            "gdn_chunk_tokens", "gdn_state_passes", "slot_state_resets")
+            "gdn_chunk_tokens", "gdn_state_passes", "slot_state_resets", "gdn_state_handovers")
+# a lane may fill several rows of a chunk dispatch under the full width (engine.py:chunk_rows_of):
+# a row whose lane is that of the row above it goes on where that row ends (`forward_chunk`)
+LANE_TAKES_ROWS = True
 MOE_COUNTERS = COUNTERS.index("gdn_chunk_tokens")  # the first: what dropless_experts counts
 # rows of a chunk computed at once: the rows are independent, and a chunk of
 # more is taken in groups. 8 rows of 128 positions route 10,240 pairs, 20 rows
@@ -283,7 +292,7 @@ def lm_head(params: Params, config: Qwen3NextConfig, h: jax.Array) -> jax.Array:
 
 
 def gdn_mixer(lp: Params, c: Qwen3NextConfig, u: jax.Array, valid: jax.Array,
-              s: jax.Array, tail: jax.Array):
+              s: jax.Array, tail: jax.Array, above=None):
     """The Gated DeltaNet mixer over ``[B, T, E]`` normed inputs whose valid
     tokens are a prefix of each row, from the rows' state ``s`` ``[B, H_v, d_k,
     d_v]`` and the convolution's tail ``[B, (K - 1) * conv_dim]``: (output
@@ -291,12 +300,29 @@ def gdn_mixer(lp: Params, c: Qwen3NextConfig, u: jax.Array, valid: jax.Array,
     the row's last ``K - 1`` valid inputs of the convolution). One token (a
     decode step) is ``kda_step``; more (a chunk) are one call of the kernel
     that keeps the state on the chip (outputs past a row's valid tokens:
-    zeros)."""
+    zeros).
+
+    ``above`` = (``takes`` ``[B]``, ``continues`` ``[B]``, a tail ``[(K - 1) *
+    conv_dim]``): a row that ``takes`` goes on where the row above it ends, a
+    FULL row of the same sequence, so its convolution starts from that row's
+    last ``K - 1`` inputs (row 0 from the tail given) and not from ``tail``:
+    they are the projection of that row's own tokens, so the rows are still
+    convolved all at once. A row that ``continues`` (a row that takes, below
+    row 0) starts its recurrence from that row's state INSIDE the kernel: the
+    state returned is then the SEQUENCE's, after its last row, at its first,
+    and the rows that continue have no entry of their own."""
     b, t, _ = u.shape
     hk, hv, d, kk = (c.linear_num_key_heads, c.linear_num_value_heads, c.linear_key_head_dim,
                      c.linear_conv_kernel_dim)
     qkvz = dot_parts(u, lp["w_qkvz"], PARTS)
     ba = dot_parts(u, lp["w_ba"], PARTS)
+    continues = None
+    if above is not None:
+        takes, continues, first = above
+        if t < kk - 1:
+            raise ValueError(f"a row of {t} tokens holds no tail of {kk - 1}")
+        ends = qkvz[:-1, t - (kk - 1):, :c.conv_dim].reshape(b - 1, (kk - 1) * c.conv_dim)
+        tail = jnp.where(takes[:, None], jnp.concatenate([first[None], ends]), tail)
     # causal depthwise over q, k and v together: tap K-1 is the token itself, tap 0 the oldest input
     seq = jnp.concatenate([tail.reshape(b, kk - 1, c.conv_dim), qkvz[..., :c.conv_dim]], axis=1)
     mixed = jax.nn.silu(sum(seq[:, j:j + t] * lp["conv_w"][j] for j in range(kk)))
@@ -317,7 +343,7 @@ def gdn_mixer(lp: Params, c: Qwen3NextConfig, u: jax.Array, valid: jax.Array,
         s, o = jnp.where(valid[:, 0, None, None, None], new, s), o[:, None]
     else:
         o, s = kda_scan(q, k, v, jnp.broadcast_to(g[..., None], q.shape), beta, s, valid.sum(axis=1),
-                        interpret=jax.default_backend() == "cpu")
+                        continues, interpret=jax.default_backend() == "cpu")
     # the gated norm over each head's d_v, a plain weight shared by the heads, times silu(z)
     o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + c.rms_norm_eps) * lp["o_norm"]
     z = qkvz[..., c.conv_dim:]
@@ -345,11 +371,11 @@ def feed_forward(lp: Params, c: Qwen3NextConfig, h: jax.Array, valid: jax.Array)
     return h + y.reshape(b, t, e), stats
 
 
-def _gdn_layer(lp: Params, c: Qwen3NextConfig, h, valid, s, tail):
+def _gdn_layer(lp: Params, c: Qwen3NextConfig, h, valid, s, tail, above=None):
     """A DeltaNet layer and its expert layer over ``h`` ``[B, T, E]``: (h, the
     rows' state and tail after it, the expert layer's counters)."""
     with jax.named_scope("gdn"):
-        y, s, tail = gdn_mixer(lp, c, _norm(h, lp["mixer_norm"], c.rms_norm_eps), valid, s, tail)
+        y, s, tail = gdn_mixer(lp, c, _norm(h, lp["mixer_norm"], c.rms_norm_eps), valid, s, tail, above)
     h, stats = feed_forward(lp, c, h + y, valid)
     return h, s, tail, stats
 
@@ -388,13 +414,27 @@ def _attn_outputs(lp: Params, c: Qwen3NextConfig, h, attn, gate, valid):
 
 # -- the step programs --------------------------------------------------------
 
+class _Left(NamedTuple):
+    """What the groups of such a dispatch so far leave the next one: all that
+    the loop over the groups carries from group to group. One array a layer of
+    its kind, as the slots' state is."""
+
+    at: jax.Array  # the dispatch's row that is the next group's first
+    tails: Tuple[jax.Array, ...]  # [(K - 1) * conv_dim] the tail the row above that one left
+    s: Tuple[jax.Array, ...]  # [H_v, d_k, d_v] the state of the sequence that row ended
+    k: Tuple[jax.Array, ...]  # [N, C, KVH, D] the dispatch's fresh keys so far, zeros from `at` on
+    v: Tuple[jax.Array, ...]
+
+
 def forward_chunk(
     params: Params, config: Qwen3NextConfig, tokens: jax.Array, positions: jax.Array,
     kv_cache: KVCache, block_tables: jax.Array, state: SlotState, lanes: jax.Array,
 ):
-    """A ``[R, C]`` block of prompt tokens, one row per prefilling lane
-    (``lanes`` ``[R]``: the row's slot; ``max_slots`` and above = a padding
-    row), valid tokens (position >= 0) a prefix of each row.
+    """A ``[R, C]`` block of prompt tokens (``lanes`` ``[R]``: the row's slot;
+    ``max_slots`` and above = a padding row), valid tokens (position >= 0) a
+    prefix of each row. Under the full width (``R`` < the state's slots) a lane
+    may fill several CONSECUTIVE rows with successive pieces of its prompt, in
+    order, each full but the last; at it, one row a lane.
 
     Returns (hidden ``[R, C, E]`` after the final norm, the pool with the
     rows' K and V written, the slot state with the rows' slots advanced, the
@@ -403,7 +443,22 @@ def forward_chunk(
     to it. More than ``ROWS_AT_ONCE`` rows are taken in groups of that many,
     one after another; the pool and the state are only read inside the loop
     (a row touches its own slot and pages only), and what the rows made is
-    written after it: one scatter a pool array and one a state array."""
+    written after it: one scatter a pool array and one a state array.
+
+    A row whose lane is that of the row above it (both real) goes on where
+    that row ends, inside the program (``llama.ChunkLayout``; the loop over the groups
+    carries what a later group needs of the earlier ones, ``_Left``, and no
+    more): its convolution starts from that row's last inputs and its
+    recurrence from that row's state, inside the kernel where the two share a
+    group and as the kernel's ``s0`` where the row is its group's first
+    (:func:`gdn_mixer`); its pool history ends where its lane's FIRST row of
+    the dispatch starts, and one more partial attends the fresh keys of its
+    lane's rows above it (``chunk_rows_above_partial``). One write a slot and
+    layer: the state from the row where the lane's LAST kernel sequence of the
+    dispatch starts (the kernel leaves it there), the tail from the lane's last
+    row. Where every lane has one row nothing is taken from a row above and
+    the sibling loop makes no trip; at the full width none of it is in the
+    program."""
     from dynamo_tpu.ops.attention import write_kv_to_pool
 
     c = config
@@ -411,9 +466,18 @@ def forward_chunk(
     slots = state["s"][0].shape[0]
     pages = _pool_pages(kv_cache)
     num_blocks = kv_cache["k"].shape[1]
-    group = partial(_chunk_rows, params, c, pages, num_blocks, state)
+    layout = left = None
+    if rows < slots:  # at the full width a lane has one row, and none of this is in the program
+        layout = chunk_layout(positions, lanes, slots)
+        none_yet = jnp.zeros((*tokens.shape, c.num_kv_heads, c.head_dim), kv_cache["k"].dtype)
+        left = _Left(
+            at=jnp.int32(0),
+            tails=tuple(jnp.zeros(a.shape[1:], a.dtype) for a in state["conv"]),
+            s=tuple(jnp.zeros(a.shape[1:], a.dtype) for a in state["s"]),
+            k=(none_yet,) * kv_cache["k"].shape[0], v=(none_yet,) * kv_cache["k"].shape[0])
+    group = partial(_chunk_rows, params, c, pages, num_blocks, state, layout)
     if rows <= ROWS_AT_ONCE:
-        h, k, v, s, tails, counters = group(tokens, positions, block_tables, lanes)
+        h, k, v, s, tails, left, counters = group(left, tokens, positions, block_tables, lanes)
     else:
         if rows % ROWS_AT_ONCE:
             raise ValueError(f"{rows} rows are no whole number of groups of {ROWS_AT_ONCE}")
@@ -421,49 +485,79 @@ def forward_chunk(
         def grouped(a):
             return a.reshape(rows // ROWS_AT_ONCE, ROWS_AT_ONCE, *a.shape[1:])
 
-        def step(sums, xs):
-            *made, more = group(*xs)
-            return sums + more, made
+        def step(carry, xs):
+            sums, left = carry
+            h, k, v, s, tails, left, more = group(left, *xs)
+            # with a layout the dispatch's K and V so far go on to the next group in ``left``
+            return (sums + more, left), (h, s, tails, *((k, v) if left is None else ()))
 
-        counters, (h, k, v, s, tails) = jax.lax.scan(
-            step, jnp.zeros((len(COUNTERS),), jnp.int32),
+        (counters, left), (h, s, tails, *kv) = jax.lax.scan(
+            step, (jnp.zeros((len(COUNTERS),), jnp.int32), left),
             (grouped(tokens), grouped(positions), grouped(block_tables), grouped(lanes)))
         # [G, R, ...] -> [G * R, ...], every layer's array by itself: nothing is transposed. The
         # rows' new states stay as the loop stacked them, and their slots take that shape
-        h, k, v = jax.tree.map(lambda a: a.reshape(rows, *a.shape[2:]), (h, k, v))
+        h, kv = jax.tree.map(lambda a: a.reshape(rows, *a.shape[2:]), (h, kv))
+        k, v = kv or (left.k, left.v)
         lanes = grouped(lanes)
     cache = {"k": write_kv_to_pool(kv_cache["k"], jnp.stack(k), positions, block_tables),
              "v": write_kv_to_pool(kv_cache["v"], jnp.stack(v), positions, block_tables)}
     # a padding row writes nowhere: its slot index lies past the state
-    back = jnp.where(lanes < slots, lanes, slots)
+    back = s_back = jnp.where(lanes < slots, lanes, slots)
+    if layout is not None:
+        # nor do a lane's rows but ONE: the tail is its last row's, the state lies where its last
+        # kernel sequence starts (a row that takes nothing, or a group's first row; the lane's
+        # last such row: one that a later group's first row goes on from holds a state since passed)
+        row = jnp.arange(rows)
+        heads = layout.takes & (row % ROWS_AT_ONCE == 0)
+        passed = ((layout.lanes[:, None] == layout.lanes[None, :]) & heads[None, :]
+                  & (row[None, :] > row[:, None])).any(axis=1)
+        sequence = (~layout.takes | heads) & ~passed
+        under = jnp.concatenate([layout.takes[1:], jnp.zeros((1,), bool)])
+        s_back = jnp.where(sequence.reshape(back.shape), back, slots)
+        back = jnp.where(under.reshape(back.shape), slots, back)
 
-    def written(was, new):
-        return tuple(a.at[back].set(b, mode="drop") for a, b in zip(was, new))
+    def written(was, new, at):
+        return tuple(a.at[at].set(b, mode="drop") for a, b in zip(was, new))
 
-    return h, cache, {"s": written(state["s"], s), "conv": written(state["conv"], tails)}, counters
+    return h, cache, {"s": written(state["s"], s, s_back), "conv": written(state["conv"], tails, back)}, counters
 
 
-def _chunk_rows(params, c, pages, num_blocks, state, tokens, positions, block_tables, lanes):
+def _chunk_rows(params, c, pages, num_blocks, state, layout, left, tokens, positions, block_tables,
+                lanes):
     """The layers over the rows given, all at once, the pool (its
     ``_pool_pages`` views) and the slots' ``state`` read and not written:
     (hidden after the final norm, the attention layers' fresh K and V, each a
     tuple of ``[R, C, KVH, D]``, the DeltaNet layers' new states and tails,
-    each a tuple of a layer's rows, the counters)."""
+    each a tuple of a layer's rows, what the next group is left, the
+    counters). With a ``layout`` the rows are ``left.at`` onwards of a
+    dispatch in which a lane may fill several, the K and V returned are the
+    DISPATCH's so far, ``[N, C, KVH, D]``, and a layer's new states are its
+    kernel sequences', each at its first row of the group."""
     valid = positions >= 0
     fresh = positions[:, 0] == 0
     slots = state["s"][0].shape[0]
     lane = jnp.clip(lanes, 0, slots - 1)
     real = lanes < slots
+    n = tokens.shape[0]
 
     dtype = pages["k"].dtype
     scale = c.head_dim ** -0.5
     block_size = pages["k"].shape[1]
     table_blocks = block_tables.shape[1]
     tile_blocks = history_tile(block_size, table_blocks) // block_size
-    history_len = jnp.clip(positions[:, 0], 0, table_blocks * block_size)
-    n_tiles = chunk_history_tiles(positions, block_size, table_blocks)
+    # positions whose first says where each row's pool history ends: the row's own, or
+    # with rows above those of its lane's first row (the rows between: their keys in hand)
+    ends, takes = (positions, None) if layout is None else layout.rows(left.at, n)
+    history_len = jnp.clip(ends[:, 0], 0, table_blocks * block_size)
+    n_tiles = chunk_history_tiles(ends, block_size, table_blocks)
     tables = jnp.pad(block_tables, (
         (0, 0), (0, history_tiles_full(block_size, table_blocks) * tile_blocks - table_blocks)))
+    continues = None
+    if layout is not None:
+        # the kernel's sequences are the group's own: its first row starts one whatever it takes
+        # (what the group before left it comes as its ``s0``), a later row that takes continues
+        continues = takes.at[0].set(False)
+        last = jax.lax.cummax(jnp.where(continues, 0, jnp.arange(n)))[-1]  # where the last row's starts
 
     h = embed_lookup(params, tokens, c.dtype).astype(jnp.float32)
     s_new, tails, fresh_k, fresh_v = [], [], [], []
@@ -473,7 +567,11 @@ def _chunk_rows(params, c, pages, num_blocks, state, tokens, positions, block_ta
             i = len(s_new)
             s0 = jnp.where(fresh[:, None, None, None], 0.0, state["s"][i][lane])
             tail0 = jnp.where(fresh[:, None], 0.0, state["conv"][i][lane])
-            h, s1, tail1, more = _gdn_layer(lp, c, h, valid, s0, tail0)
+            above = None
+            if layout is not None:
+                s0 = s0.at[0].set(jnp.where(takes[0], left.s[i], s0[0]))
+                above = (takes, continues, left.tails[i])
+            h, s1, tail1, more = _gdn_layer(lp, c, h, valid, s0, tail0, above)
             s_new.append(s1)
             tails.append(tail1)
         else:
@@ -483,7 +581,13 @@ def _chunk_rows(params, c, pages, num_blocks, state, tokens, positions, block_ta
                 hist = chunk_history_partial(
                     c, q, pages, j * num_blocks + tables, history_len, n_tiles, positions,
                     scale, tile_blocks, block_size, dtype)
-                num, _, den = _merge_partials(hist, _chunk_self_partial(c, q, k, v, positions, scale))
+                part = _merge_partials(hist, _chunk_self_partial(c, q, k, v, positions, scale))
+                if layout is not None:
+                    k, v = (jax.lax.dynamic_update_slice_in_dim(all_rows[j], mine, left.at, 0)
+                            for all_rows, mine in ((left.k, k), (left.v, v)))
+                    part = chunk_rows_above_partial(
+                        c, q, k, v, layout.positions, layout.lanes, left.at, layout.n_back, scale, part)
+                num, _, den = part
                 attn = jnp.where(
                     (den > 0.0).transpose(0, 2, 1)[..., None],
                     num / jnp.maximum(den, 1e-30).transpose(0, 2, 1)[..., None], 0.0)
@@ -493,9 +597,14 @@ def _chunk_rows(params, c, pages, num_blocks, state, tokens, positions, block_ta
         stats = stats + more
     h = _norm(h, params["final_norm"], c.rms_norm_eps)
     advanced = valid.sum(axis=1)  # a DeltaNet layer's kernel advances each row by its valid tokens
-    own = jnp.stack([len(s_new) * advanced.sum(), len(s_new) * jnp.sum(advanced > 0),
-                     jnp.sum(fresh & real)]).astype(jnp.int32)
-    return (h, tuple(fresh_k), tuple(fresh_v), tuple(s_new), tuple(tails),
+    begins = advanced > 0  # a kernel sequence's first row: where a state goes in and out
+    if layout is not None:
+        begins &= ~continues
+        left = _Left(left.at + n, tuple(t[-1] for t in tails), tuple(s1[last] for s1 in s_new),
+                     tuple(fresh_k), tuple(fresh_v))
+    own = jnp.stack([len(s_new) * advanced.sum(), len(s_new) * jnp.sum(begins), jnp.sum(fresh & real),
+                     jnp.int32(0) if layout is None else jnp.sum(takes)]).astype(jnp.int32)
+    return (h, tuple(fresh_k), tuple(fresh_v), tuple(s_new), tuple(tails), left,
             jnp.concatenate([stats, own]))
 
 

@@ -1,6 +1,6 @@
 """A chunk of the KDA delta rule with the state held on the chip.
 
-``kda_scan(q, k, v, log_decay, beta, s0, n_valid)`` advances every (row, head)
+``kda_scan(q, k, v, log_decay, beta, s0, n_valid, continues)`` advances every (row, head)
 pair's ``[d, d]`` float32 state over the first ``n_valid[row]`` tokens of a
 ``[B, T, H, d]`` chunk by :func:`kda_step`'s recurrence (this file's last
 function: a decode step's one token, in ``jax.numpy``), in that function's
@@ -17,6 +17,22 @@ token; here a grid step holds its pairs' state in the chip's fast memory, reads
 it once and writes it once after the row's last valid token. Valid tokens are a
 prefix of a row; positions past them are never computed and their outputs are
 zeros, and a row without a valid token gets its state back bit for bit.
+
+**A sequence may fill several consecutive rows**, an earlier piece of it in
+an earlier row (a lane's rows of one chunk dispatch:
+``models/kimi_linear.py``, ``models/qwen3_next.py``), as in
+``ops/pallas/selective_scan.py``. A row that ``continues`` the row above it
+starts from the state that row ends with, and not from ``s0[row]``: the grid
+walks the rows IN ORDER for each group of heads, and the state's block is
+indexed by the sequence's FIRST row (a prefetched scalar beside ``n_valid``),
+so the block stays on the chip from that row's first token to the last valid
+token of the sequence's last row. ``s0`` is loaded where a row starts a
+sequence, the state goes to HBM once a SEQUENCE, at its first row, and the
+rows that continue have no entry of their own in the result. A row that
+continues nothing (a padding row between two sequences too) takes nothing and
+hands nothing on: the token's body knows nothing of rows. Told nothing
+(``continues=None``) every row is a sequence, in the same one call: the
+kernel with no row continuing.
 
 **The layout is the kernel.** The state lies key channel by sublane and value
 channel by lane, so both sums over the key channels are plain vector adds. What
@@ -42,7 +58,7 @@ source registers).
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -62,11 +78,15 @@ def kda_scan(
     beta: jax.Array,  # [B, T, H]
     s0: jax.Array,  # [B, H, d, d] float32: the rows' state before the chunk
     n_valid: jax.Array,  # [B] int32: a row's valid tokens, a prefix of it
+    continues: Optional[jax.Array] = None,  # [B] bool: the row goes on where the row above it ends
     *,
     interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """(outputs ``[B, T, H, d]``, zeros past a row's valid tokens; the state
-    after each row's last valid token ``[B, H, d, d]``)."""
+    ``[B, H, d, d]`` after the last valid token of each SEQUENCE, at the
+    sequence's first row: a row that ``continues`` the row above it, a full
+    row, belongs to that row's sequence, and its own entry of the state is
+    never written. ``None``: no row continues, a row a sequence.)"""
     b, t_in, h, d = q.shape
     p = max(n for n in (HEADS, 2, 1) if h % n == 0 and d % n == 0)  # heads a step, parts of a state
     seg = d // p  # value channels of a part, and tokens of a head in a source register
@@ -78,10 +98,16 @@ def kda_scan(
     tile = t if interpret else LANES
     groups = h // p
 
-    def kernel(n, q, k, decay, v, beta, s0, o, s):
-        at = pl.program_id(2)
+    # a sequence's first row: the row itself, or where it continues, that of the row above it
+    first = jnp.arange(b, dtype=jnp.int32)
+    if continues is not None:
+        first = jax.lax.cummax(jnp.where(continues, 0, first))
 
-        @pl.when(at == 0)
+    def kernel(n, first, q, k, decay, v, beta, s0, o, s):
+        r, at = pl.program_id(1), pl.program_id(2)
+
+        # a row that continues finds its sequence's state where the row above left it: in ``s``
+        @pl.when((at == 0) & (first[r] == r))
         def _():
             s[...] = s0[...]
 
@@ -102,7 +128,7 @@ def kda_scan(
                 s[part] = state
             return carry
 
-        tokens = jnp.clip(n[pl.program_id(0)] - at * tile, 0, tile)
+        tokens = jnp.clip(n[r] - at * tile, 0, tile)
         # two tokens a trip: the second's permutes run under the first's arithmetic
         jax.lax.fori_loop(0, tokens // 2, lambda i, c: token(2 * i + 1, token(2 * i, c)), 0)
         jax.lax.fori_loop(tokens // 2 * 2, tokens, token, 0)
@@ -126,25 +152,26 @@ def kda_scan(
     q, k, v, decay = (jnp.pad(a, pad) for a in (q, k, v, jnp.exp(log_decay)))
     beta = jnp.repeat(jnp.pad(beta, pad[:3]).reshape(b, t, groups, p), seg, axis=-1)  # [B, T, groups, d]
 
-    source = pl.BlockSpec((None, None, tile // seg, d, d), lambda i, j, a, n: (i, j, a, 0, 0))
-    rows = pl.BlockSpec((None, None, p, tile, d), lambda i, j, a, n: (i, j, 0, a, 0))
-    state = pl.BlockSpec((None, None, p, d, d), lambda i, j, a, n: (i, j, 0, 0, 0))
+    # the grid: (head group j, row i, token tile a), the rows of a head group in order
+    source = pl.BlockSpec((None, None, tile // seg, d, d), lambda j, i, a, n, first: (i, j, a, 0, 0))
+    rows = pl.BlockSpec((None, None, p, tile, d), lambda j, i, a, n, first: (i, j, 0, a, 0))
+    # one block a sequence: it stays where it is while the rows that follow continue it
+    state = pl.BlockSpec((None, None, p, d, d), lambda j, i, a, n, first: (first[i], j, 0, 0, 0))
     o, s = pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct((b, groups, p, t, d), jnp.float32),
                    jax.ShapeDtypeStruct((b, groups, p, d, d), jnp.float32)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             in_specs=[source, source, source, rows,
-                      pl.BlockSpec((None, None, tile, d), lambda i, j, a, n: (i, j, a, 0)), state],
+                      pl.BlockSpec((None, None, tile, d), lambda j, i, a, n, first: (i, j, a, 0)), state],
             out_specs=(rows, state),
-            grid=(b, groups, t // tile),
+            grid=(groups, b, t // tile),
         ),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
         name="kda_scan",
-    )(n_valid.astype(jnp.int32), by_lane(q), by_lane(k), by_lane(decay), by_row(v),
+    )(n_valid.astype(jnp.int32), first, by_lane(q), by_lane(k), by_lane(decay), by_row(v),
       jnp.transpose(beta, (0, 2, 1, 3)), parts_of(s0))
     o = jnp.transpose(o.reshape(b, groups, p, t, p, seg), (0, 3, 1, 4, 2, 5)).reshape(b, t, h, d)
     return o[:, :t_in], parts_of(s).reshape(b, h, d, d)

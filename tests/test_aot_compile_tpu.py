@@ -638,14 +638,18 @@ def test_the_expert_layer_compiles_at_the_cells_shapes_and_copies_no_expert(
 
 # -- a chunk's KDA recurrence of kimi-linear-48b-a3b ----------------------------
 
-@pytest.mark.parametrize("rows", [8, 16])
-def test_a_chunks_kda_mixer_compiles_with_the_state_on_the_chip(monkeypatch, one_chip, rows):
+@pytest.mark.parametrize("rows, handed", [(8, False), (16, False), (8, True), (16, True)],
+                         ids=["8", "16", "8_rows_handed_over", "16_rows_handed_over"])
+def test_a_chunks_kda_mixer_compiles_with_the_state_on_the_chip(monkeypatch, one_chip, rows, handed):
     """``models/kimi_linear.py:kda_mixer`` of ONE layer as a chunk group of
     ``batch.kimi-linear-48b-a3b`` calls it (``rows`` x 128 tokens, 32 heads of
     128, hidden 2,304, bf16 weights): ``ops/pallas/kda_scan.py`` is in the
     compiled program (its tiling and its fast memory are what interpret mode
     cannot see), and no loop carries the rows' ``f32[rows,32,128,128]`` state,
-    once a token through HBM, as the scan the kernel replaced did."""
+    once a token through HBM, as the scan the kernel replaced did. ``handed``:
+    told which rows go on from the row above them (the rungs under the full
+    width), the kernel walks the rows of a head group in order and a state
+    block stays on the chip over a lane's rows: the same one call."""
     from dynamo_tpu.models import kimi_linear as kl
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # as the expert layer's test
@@ -657,9 +661,10 @@ def test_a_chunks_kda_mixer_compiles_with_the_state_on_the_chip(monkeypatch, one
         shape = a.shape if hasattr(a, "shape") else a
         return jax.ShapeDtypeStruct(shape, dtype or a.dtype, sharding=one_chip)
 
-    compiled = jax.jit(lambda lp, x, valid, s, tail: kl.kda_mixer(lp, c, x, valid, s, tail)).lower(
+    compiled = jax.jit(lambda lp, x, valid, s, tail, *above: kl.kda_mixer(lp, c, x, valid, s, tail, *above)).lower(
         jax.tree.map(sd, lp), sd((rows, t, c.hidden_size), jnp.float32), sd((rows, t), jnp.bool_),
         sd((rows, h, d, d), jnp.float32), sd((rows, c.conv_kernel - 1, 3 * c.kda_dim), jnp.float32),
+        *((sd((rows,), jnp.bool_),) if handed else ()),
     ).compile()
     hlo = re.sub(r"/\*.*?\*/", "", compiled.as_text())
     assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", hlo)) == 1
@@ -908,8 +913,8 @@ def _compile_qwen3_next(program, one_chip, rows=8):
 
 
 @pytest.mark.timeout(400)
-@pytest.mark.parametrize("program, rows", [("decode", 64), ("chunk", 8), ("chunk", 64)],
-                         ids=["decode", "chunk_8_rows", "chunk_64_rows"])
+@pytest.mark.parametrize("program, rows", [("decode", 64), ("chunk", 8), ("chunk", 16), ("chunk", 64)],
+                         ids=["decode", "chunk_8_rows", "chunk_16_rows", "chunk_64_rows"])
 def test_qwen3_nexts_step_programs_copy_neither_the_pool_nor_the_state_nor_an_expert(
         monkeypatch, one_chip, program, rows):
     """``models/qwen3_next.py`` at ``batch.qwen3-next-80b-a3b``'s served shapes,
@@ -923,7 +928,10 @@ def test_qwen3_nexts_step_programs_copy_neither_the_pool_nor_the_state_nor_an_ex
     over groups of 8 rows and scatters the rows' new states after it); the grouped product
     is in the program three times an expert layer (and step, and history
     width), ``kda_scan`` once a DeltaNet layer of a chunk and never in a decode
-    step; the pool and the state are donated."""
+    step; the pool and the state are donated. Under the full width (8 rows,
+    and 16 in two groups) a lane may fill several rows and the program hands
+    the state from row to row, inside the kernel and across the two groups:
+    the same kernels, and no copy more."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels compiled, not interpreted
     compiled = _compile_qwen3_next(program, one_chip, rows)
     hlo = re.sub(r"/\*.*?\*/", "", compiled.as_text())
@@ -945,7 +953,8 @@ def test_qwen3_nexts_step_programs_copy_neither_the_pool_nor_the_state_nor_an_ex
     assert memory.alias_size_in_bytes >= 2 * 805_306_368 + 843_055_104
     # beside the arguments: the dense history of a full-width decode dispatch (1.07 GB) and its
     # steps; a chunk's groups hold what 8 rows need, and at 64 rows the rows' new states (0.8 GB)
-    limit = {("decode", 64): 2_600_000_000, ("chunk", 8): 1_200_000_000, ("chunk", 64): 2_200_000_000}
+    limit = {("decode", 64): 2_600_000_000, ("chunk", 8): 1_200_000_000, ("chunk", 16): 1_200_000_000,
+             ("chunk", 64): 2_200_000_000}
     assert memory.temp_size_in_bytes < limit[program, rows], memory.temp_size_in_bytes
 
 

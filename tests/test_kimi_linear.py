@@ -10,6 +10,7 @@ model-configs guide; the engine against both, and against the refusals a model
 with per-slot state owes whatever would hand its pages over without it.
 """
 
+import dataclasses
 import re
 
 import jax
@@ -26,6 +27,7 @@ from dynamo_tpu.models import llama, module_for
 from dynamo_tpu.ops import moe
 from dynamo_tpu.ops.pallas.kda_scan import kda_scan
 
+from .delta_harness import KIMI_SHAPE as SHAPE
 from .step_programs import (  # noqa: F401  (highest_precision: autouse, for this file's tests)
     answer, card, chunk_program, decode_program, highest_precision, prompt_of, reference_program, run_out, served,
     step, submit,
@@ -40,17 +42,6 @@ from .step_programs import (  # noqa: F401  (highest_precision: autouse, for thi
 # state, page or expert moves a logit by 1e-1 and more.
 ATOL = 2e-4
 
-SHAPE = {
-    "model_type": "kimi_linear", "hidden_size": 64, "intermediate_size": 128,
-    "num_hidden_layers": 5, "num_attention_heads": 4, "kv_lora_rank": 32,
-    "qk_nope_head_dim": 16, "qk_rope_head_dim": 8, "v_head_dim": 16,
-    "linear_attn_num_heads": 2, "linear_attn_head_dim": 16, "short_conv_kernel_size": 4,
-    "kda_layers": [1, 2, 3, 5, 6, 7], "full_attn_layers": [4, 8],
-    "first_k_dense_replace": 1, "moe_intermediate_size": 32, "num_experts": 4,
-    "num_experts_published": 8, "num_experts_per_token": 2, "num_shared_experts": 1,
-    "routed_scaling_factor": 2.446, "moe_renormalize": True, "rms_norm_eps": 1e-5,
-    "vocab_size": 96, "tie_word_embeddings": False,
-}
 ENGINE_CFG = EngineConfig(max_slots=4, kv_block_size=8, max_model_len=96,
                           prefill_chunk=16, decode_steps=4, top_logprobs=5)
 
@@ -102,7 +93,7 @@ def test_chunked_prefill_then_decode_agrees_with_the_plain_reference(cfg, params
             params, jnp.asarray(toks), jnp.asarray(pos), cache, jnp.asarray(tables),
             state, jnp.asarray([slot, slots], jnp.int32))
         got.append(kl.lm_head(params, cfg, h[0, :n]))
-        assert int(sums[-1]) == (at == 0)  # the first chunk resets the slot, once
+        assert int(sums[kl.COUNTERS.index("slot_state_resets")]) == (at == 0)  # the first chunk resets the slot, once
         at += n
     np.testing.assert_allclose(np.concatenate(got), want[:n_prompt], atol=ATOL)
     assert float(state["s"][0][0].min()) == 7.0  # another slot's state is untouched
@@ -381,8 +372,10 @@ def test_the_engine_serves_the_reference_greedy_tokens_and_logprobs(engine, para
     assert snap["moe_expert_reads"] == snap["moe_experts_hit"]
     # both programs read every table's full width, and the counters say so
     assert snap["chunk_history_tiles_read"] == snap["chunk_history_tiles_full"] > 0
-    # state per slot beside the pages: a lane has ONE row of a chunk dispatch
-    assert not engine._lane_rows and snap["chunk_rows_live"] == snap["chunk_lanes_fed"] > 0
+    # state per slot beside the pages, handed from row to row inside the kernel: a lane may fill several
+    # rows of a dispatch (here the ladder is [1, 4]: no rung under the full width holds two)
+    assert kl.LANE_TAKES_ROWS and engine._lane_rows and engine._chunk_rungs == [1, 4]
+    assert snap["chunk_rows_live"] == snap["chunk_lanes_fed"] > 0 and snap["kda_state_handovers"] == 0
     assert snap["decode_history_tiles_read"] == snap["decode_history_tiles_full"] > 0
     tiers = list(snap["attention_tiers"].values())
     assert tiers and all(t == {"tier": "dense", "interpret": False} for t in tiers)
@@ -390,14 +383,23 @@ def test_the_engine_serves_the_reference_greedy_tokens_and_logprobs(engine, para
 
 def test_a_served_prompt_passes_its_state_once_a_chunk(engine):
     """Through the engine (``prefill_chunk`` 16): a prompt of 40 tokens is three
-    chunk dispatches, and each of the four KDA layers reads and writes its
-    slot's state once a dispatch: 40 tokens advanced to 3 passes a layer."""
-    before = engine.metrics_snapshot()
-    served(engine, prompt_of(40, salt=11), 4)
-    after = engine.metrics_snapshot()
-    tokens = after["kda_chunk_tokens"] - before["kda_chunk_tokens"]
-    passes = after["kda_state_passes"] - before["kda_state_passes"]
-    assert (tokens, passes) == (4 * 40, 4 * 3)
+    chunk dispatches on the ladder [1, 4] (a row a dispatch), and each of the
+    four KDA layers reads and writes its slot's state once a LANE of a
+    dispatch: 40 tokens advanced to 3 passes a layer, no row handed its state
+    to the row under it. On the ladder [2, 4, 16] the three pieces share a
+    dispatch of four rows: ONE pass a layer and two handovers."""
+    def rise(eng):
+        before = eng.metrics_snapshot()
+        served(eng, prompt_of(40, salt=11), 4)
+        after = eng.metrics_snapshot()
+        return tuple(after[k] - before[k] for k in ("kda_chunk_tokens", "kda_state_passes", "kda_state_handovers"))
+
+    assert rise(engine) == (4 * 40, 4 * 3, 0)
+    wide = JaxServingEngine(engine.model_config, engine.params, dataclasses.replace(ENGINE_CFG, max_slots=16))
+    try:
+        assert rise(wide) == (4 * 40, 4 * 1, 2)
+    finally:
+        wide.close()
 
 
 def test_a_reused_slot_gives_what_the_request_gives_alone(engine, cfg, params):

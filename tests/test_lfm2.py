@@ -703,7 +703,7 @@ def test_the_full_width_chunk_program_holds_nothing_of_the_hand_over(engine, mon
     def unreachable(*a, **kw):
         raise AssertionError("the hand-over, in a program that has one row a lane")
 
-    for name in ("_Layout", "_Left", "lane_first_positions", "sibling_rows_back", "chunk_rows_above_partial"):
+    for name in ("chunk_layout", "_Left", "chunk_rows_above_partial"):
         patched(monkeypatch, lfm2, name, unreachable)
     assert lowered_step_programs(engine)[0].as_text() == text
     assert hashlib.sha256(text.encode()).hexdigest() == FULL_WIDTH_CHUNK_SHA256

@@ -35,6 +35,7 @@ from dynamo_tpu.models import qwen3_next as qn
 from dynamo_tpu.ops import moe
 from dynamo_tpu.ops.pallas.kda_scan import kda_scan, kda_step
 
+from .delta_harness import QWEN3_NEXT_SHAPE as SHAPE, louder_qwen3_next
 from .step_programs import (  # noqa: F401  (highest_precision: autouse, for this file's tests)
     answer, card, chunk_program, decode_program, highest_precision, patched, prompt_of, published_shape,
     reference_program, run_out, served, step, submit,
@@ -59,17 +60,6 @@ ATOL = 5e-4
 # comparison (logprob_rms) is the tight one for this build.
 ATOL_BF16 = 0.02
 
-SHAPE = {
-    "model_type": "qwen3_next", "hidden_size": 64, "num_hidden_layers": 4, "full_attention_interval": 4,
-    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 32, "partial_rotary_factor": 0.25,
-    "rope_theta": 10000000, "rope_scaling": None,
-    "linear_num_key_heads": 2, "linear_num_value_heads": 4, "linear_key_head_dim": 16,
-    "linear_value_head_dim": 16, "linear_conv_kernel_dim": 4,
-    "decoder_sparse_step": 1, "mlp_only_layers": [], "moe_intermediate_size": 32,
-    "shared_expert_intermediate_size": 32, "num_experts": 4, "num_experts_published": 16,
-    "num_experts_per_tok": 4, "norm_topk_prob": True, "rms_norm_eps": 1e-6, "vocab_size": 96,
-    "tie_word_embeddings": False, "max_position_embeddings": 262144,
-}
 N_GDN, N_LAYERS = 3, 4
 ENGINE_CFG = EngineConfig(max_slots=4, kv_block_size=8, max_model_len=96,
                           prefill_chunk=16, decode_steps=4, top_logprobs=5)
@@ -83,18 +73,7 @@ def cfg():
 
 
 def seeded_params(cfg):
-    """Seeded weights with the zero-centred norm weights and the router large
-    enough to tell (normal x 0.02 as published would hide a plain ``w`` in
-    ``1 + w``'s place only by a little; a flat router no choice)."""
-    made = qn.init_params(jax.random.PRNGKey(3), cfg)
-
-    def louder(path, a):
-        name = path[-1].key if hasattr(path[-1], "key") else ""
-        if name in ("mixer_norm", "ffn_norm", "q_norm", "k_norm", "final_norm"):
-            return a * 15.0
-        return a * 50.0 if name == "router" else a
-
-    return jax.tree_util.tree_map_with_path(louder, made)
+    return louder_qwen3_next(qn.init_params(jax.random.PRNGKey(3), cfg))
 
 
 @pytest.fixture(scope="module")
@@ -482,15 +461,17 @@ def test_the_engine_serves_the_reference_greedy_tokens_and_logprobs(engine, para
     np.testing.assert_allclose(lps, logp[np.arange(10), toks], atol=ATOL)
     snap = engine.metrics_snapshot()
     assert snap["moe_layer_calls"] > 0 and snap["gdn_chunk_tokens"] > 0 and snap["slot_state_resets"] >= 1
-    assert set(qn.COUNTERS) <= set(snap) and len(qn.COUNTERS) == 9 and qn.COUNTERS[:6] == (
+    assert set(qn.COUNTERS) <= set(snap) and len(qn.COUNTERS) == 10 and qn.COUNTERS[:6] == (
         "moe_layer_calls", "moe_held_rows", "moe_experts_hit", "moe_routed_pairs", "moe_rows_computed",
         "moe_expert_reads")
     assert not any(k.startswith(("ssm_", "kda_", "conv_")) for k in snap)
     # the module says what its programs read of the tables: the live part, not all
     assert 0 < snap["chunk_history_tiles_read"] <= snap["chunk_history_tiles_full"]
-    # state per slot beside the pages: a lane has ONE row of a chunk dispatch
-    assert not hasattr(qn, "LANE_TAKES_ROWS")
-    assert not engine._lane_rows and snap["chunk_rows_live"] == snap["chunk_lanes_fed"] > 0
+    # state per slot beside the pages, handed from row to row inside the kernel: a lane may fill several
+    # rows of a dispatch (here the ladder is [1, 4]: no rung under the full width holds two)
+    assert qn.LANE_TAKES_ROWS and qn.COUNTERS[-1] == "gdn_state_handovers"
+    assert engine._lane_rows and engine._chunk_rungs == [1, 4]
+    assert snap["chunk_rows_live"] == snap["chunk_lanes_fed"] > 0 and snap["gdn_state_handovers"] == 0
     assert 0 < snap["decode_history_tiles_read"] <= snap["decode_history_tiles_full"]
     assert set(engine.cache) == {"k", "v"} and engine.cache["k"].shape == (1, engine.num_blocks, 8, 2, 32)
     tiers = list(snap["attention_tiers"].values())
@@ -499,16 +480,28 @@ def test_the_engine_serves_the_reference_greedy_tokens_and_logprobs(engine, para
 
 def test_the_counters_count_what_a_served_prompt_did(engine):
     """A prompt of 40 tokens and 4 answered: three chunk dispatches of one
-    group each, then the decode steps; every one of the four expert layers
-    routes 4 pairs a valid token, some of them to the 4 experts held of 16, and
-    every one of the three DeltaNet layers advances the prompt's 40 tokens in
-    three passes of the slot's state."""
+    group each (the ladder is [1, 4]: a row a dispatch), then the decode steps;
+    every one of the four expert layers routes 4 pairs a valid token, some of
+    them to the 4 experts held of 16, and every one of the three DeltaNet
+    layers advances the prompt's 40 tokens in three passes of the slot's state,
+    one a LANE of a dispatch, no row handing its state to the row under it. On
+    the ladder [2, 4, 16] the three pieces share a dispatch of four rows: ONE
+    pass a layer and two handovers."""
     before = engine.metrics_snapshot()
     served(engine, prompt_of(40, salt=11), 4)
     after = engine.metrics_snapshot()
     rise = {k: after[k] - before[k] for k in qn.COUNTERS}
     assert rise["slot_state_resets"] == 1
     assert rise["gdn_chunk_tokens"] == N_GDN * 40 and rise["gdn_state_passes"] == N_GDN * 3
+    assert rise["gdn_state_handovers"] == 0
+    wide = JaxServingEngine(engine.model_config, engine.params, dataclasses.replace(ENGINE_CFG, max_slots=16))
+    try:
+        served(wide, prompt_of(40, salt=11), 4)
+        snap = wide.metrics_snapshot()
+        assert (snap["gdn_chunk_tokens"], snap["gdn_state_passes"], snap["gdn_state_handovers"]) == (
+            N_GDN * 40, N_GDN * 1, 2)
+    finally:
+        wide.close()
     # 3 chunk dispatches + the decode dispatches' 4 steps each (3 more tokens: 1 or 2 dispatches)
     steps = rise["moe_layer_calls"] // N_LAYERS - 3
     assert steps in (4, 8) and rise["moe_layer_calls"] == N_LAYERS * (3 + steps)
