@@ -319,6 +319,65 @@ def xing4_config(mc: Dict[str, Any], dtype: Any = jnp.bfloat16):
     )
 
 
+def afmoe_config(mc: Dict[str, Any], dtype: Any = jnp.bfloat16):
+    """``model_type: afmoe`` (Trinity): window attention layers (rotary,
+    ``sliding_window`` positions) beside full attention layers (no positional
+    encoding) as ``layer_types`` names them, both with q/k norms and a sigmoid
+    gate; a dense feed-forward in the first ``num_dense_layers`` layers and
+    sigmoid-routed experts beside one shared expert after them.
+    ``num_experts`` is what is HELD here, ids ``first_expert`` onwards, of the
+    ``num_experts_published`` the router scores (a card without the second
+    holds them all). What ``models/trinity.py`` does not run is refused by
+    name."""
+    from dynamo_tpu.models.trinity import FULL, WINDOW, TrinityConfig
+
+    def refuse(key: str, why: str):
+        raise ValueError(f"model_type 'afmoe' with {key} = {mc.get(key)!r}: models/trinity.py {why}")
+
+    if mc.get("score_func", "sigmoid") != "sigmoid":
+        refuse("score_func", "scores its experts by a sigmoid")
+    for key in ("n_group", "topk_group", "num_expert_groups", "num_limited_groups"):
+        if int(mc.get(key, 1)) != 1:
+            refuse(key, "chooses its experts in one group (1)")
+    if int(mc.get("num_shared_experts", 1)) != 1:
+        refuse("num_shared_experts", "runs one shared expert beside the routed ones")
+    if mc.get("rope_scaling"):
+        refuse("rope_scaling", "rotates its window layers by rope_theta alone")
+    if mc.get("tie_word_embeddings"):
+        refuse("tie_word_embeddings", "has a head of its own (untied)")
+    layers = int(mc["num_hidden_layers"])
+    kinds = tuple(str(kind) for kind in mc.get("layer_types") or ())
+    if len(kinds) != layers or set(kinds) - {WINDOW, FULL}:
+        refuse("layer_types", f"wants {layers} (num_hidden_layers) of {WINDOW!r} / {FULL!r}")
+    heads = int(mc["num_attention_heads"])
+    if heads % int(mc.get("num_key_value_heads", heads)):
+        refuse("num_key_value_heads", f"groups whole numbers of its {heads} query heads over a KV head")
+    experts = int(mc["num_experts"])
+    return TrinityConfig(
+        vocab_size=int(mc["vocab_size"]),
+        hidden_size=int(mc["hidden_size"]),
+        intermediate_size=int(mc["intermediate_size"]),
+        num_layers=layers,
+        num_heads=heads,
+        num_kv_heads=int(mc.get("num_key_value_heads", heads)),
+        head_dim=int(mc["head_dim"]),
+        layer_types=kinds,
+        sliding_window=int(mc["sliding_window"]),
+        rope_theta=float(mc.get("rope_theta", 10000.0)),
+        num_dense_layers=int(mc.get("num_dense_layers", 0)),
+        moe_intermediate_size=int(mc["moe_intermediate_size"]),
+        num_experts=experts,
+        num_experts_published=int(mc.get("num_experts_published", experts)),
+        first_expert=int(mc.get("first_expert", 0)),
+        num_experts_per_tok=int(mc["num_experts_per_tok"]),
+        moe_renormalize=bool(mc.get("route_norm", True)),
+        routed_scaling_factor=float(mc.get("route_scale", 1.0)),
+        mup_enabled=bool(mc.get("mup_enabled", False)),
+        rms_norm_eps=float(mc.get("rms_norm_eps", 1e-5)),
+        dtype=dtype,
+    )
+
+
 def config_from_card(card: ModelDeploymentCard, dtype: Any = jnp.bfloat16):
     """Derive the model's config from the card's HF config.json contents: a
     LlamaConfig, or by ``model_type`` another module's (models.module_for),
@@ -336,6 +395,8 @@ def config_from_card(card: ModelDeploymentCard, dtype: Any = jnp.bfloat16):
         return openpangu_config(mc, dtype)
     if mc.get("model_type") == "xing4_0":
         return xing4_config(mc, dtype)
+    if mc.get("model_type") == "afmoe":
+        return afmoe_config(mc, dtype)
     if "num_experts" in mc and "num_local_experts" not in mc:
         # an expert model of a family this tree has no module for: a
         # LlamaConfig of it would be a dense impostor under its name

@@ -806,6 +806,7 @@ def _chunk_self_partial(
     v: jax.Array,
     positions: jax.Array,  # [B, T]; < 0 = padding
     scale: float,
+    window: Optional[int] = None,  # a window layer's: a query sees this many positions, its own the last
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Flash partial of chunk queries against the chunk's OWN keys (causal
     by position): (numerator [B,T,H,D] f32, max [B,H,T], denom [B,H,T])."""
@@ -817,6 +818,8 @@ def _chunk_self_partial(
         "btngd,bsnd->bngts", qg, k, preferred_element_type=jnp.float32
     ) * scale  # [B, KVH, G, T, T]
     causal = positions[:, None, :] <= positions[:, :, None]  # kv_pos <= q_pos
+    if window is not None:
+        causal &= positions[:, None, :] > positions[:, :, None] - window
     valid = (positions >= 0)[:, :, None] & (positions >= 0)[:, None, :]
     mask = (causal & valid)[:, None, None, :, :]
     scores = jnp.where(mask, scores, -jnp.inf)
@@ -1285,6 +1288,7 @@ def chunk_rows_above_partial(
     n_back,  # trips: `sibling_rows_back` of the dispatch
     scale: float,
     acc: Tuple[jax.Array, jax.Array, jax.Array],
+    window: Optional[int] = None,  # as `_chunk_self_partial`'s
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """:func:`chunk_sibling_partial` for a GROUP of a dispatch's rows, for a
     chunk program that takes its rows some at a time: ``acc`` with the flash
@@ -1318,7 +1322,10 @@ def chunk_rows_above_partial(
         mask = (
             met[:, None, None] & (kv_pos >= 0)[:, None, :]
             & (kv_pos[:, None, :] <= q_positions[:, :, None])
-        )[:, None, None, :, :]
+        )
+        if window is not None:
+            mask &= kv_pos[:, None, :] > q_positions[:, :, None] - window
+        mask = mask[:, None, None, :, :]
         scores = jnp.einsum(
             "btngd,bsnd->bngts", qg, above(k2, back),
             preferred_element_type=jnp.float32,

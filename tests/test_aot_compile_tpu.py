@@ -1164,3 +1164,77 @@ def test_xing4s_step_programs_fit_and_hold_no_padded_residual(monkeypatch, one_c
     limit = {"decode": 2_000_000_000, "chunk": 600_000_000}
     assert memory.temp_size_in_bytes < limit[program], memory.temp_size_in_bytes
     assert memory.temp_size_in_bytes + pool_bytes + 9_636_741_384 < 15_750_000_000
+
+
+# -- the step programs of trinity-large-preview ---------------------------------
+
+def _compile_trinity(program, one_chip, rows=8):
+    """``models/trinity.py``'s decode or chunk program at
+    ``long.trinity-large-preview``'s served shapes (the configuration's file: 1
+    dense + 4 expert layers of the 60, 32 of 256 experts held, 48 heads, 25,024
+    vocabulary rows, 8 slots, block 16, 6,144 blocks, 8,192 positions; a chunk
+    of ``rows`` x 128 tokens), the pool and the rings donated, for the
+    described chip."""
+    from dynamo_tpu.engine_jax.weights import afmoe_config
+    from dynamo_tpu.models import trinity
+
+    from .step_programs import published_shape
+
+    c = afmoe_config(published_shape("afmoe"))
+    slots, mb, chunk = 8, 512, 128
+
+    def sd(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = jax.tree.map(sd, jax.eval_shape(lambda: trinity.init_params(jax.random.PRNGKey(0), c)))
+    cache = jax.tree.map(sd, jax.eval_shape(lambda: trinity.make_kv_cache(c, 6144, 16)))
+    state = jax.tree.map(sd, jax.eval_shape(lambda: trinity.make_slot_state(c, slots)))
+    if program == "decode":
+        def greedy(logits, pos, carry, k):
+            return jnp.argmax(logits, -1).astype(jnp.int32), carry, jnp.argmax(logits, -1)
+
+        return jax.jit(
+            lambda p, kv, st, toks, pos, tables: trinity.decode(
+                p, c, toks, pos, kv, tables, st, 4, 8191, greedy, 0),
+            donate_argnums=(1, 2),
+        ).lower(params, cache, state, i32(slots), i32(slots), i32(slots, mb)).compile()
+    return jax.jit(
+        lambda p, kv, st, toks, pos, tables, lanes: trinity.forward_chunk(
+            p, c, toks, pos, kv, tables, st, lanes),
+        donate_argnums=(1, 2),
+    ).lower(params, cache, state, i32(rows, chunk), i32(rows, chunk), i32(rows, mb),
+            i32(rows)).compile()
+
+
+@pytest.mark.timeout(400)
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_trinitys_step_programs_copy_neither_a_ring_nor_the_pool_nor_an_expert(monkeypatch, one_chip, program):
+    """``models/trinity.py`` at ``long.trinity-large-preview``'s served shapes,
+    the first past 2,048 positions, for the chip's compiler: the programs fit
+    beside 8.64 GB of weights, 0.81 GB of pages and 1.08 GB of rings; NO
+    instruction copies a window layer's ring ``[8, 8, 4112, 128]`` or a view of
+    it (a decode step reads the rings in place; a chunk takes a tile of 512
+    entries of its rows' lanes a trip), none the pool, none an expert layer's
+    ``[32, 3072, 3072]`` matrices (handed to the kernel as they lie); the
+    grouped product is in the program three times an expert layer (and step,
+    and history width); pool and rings are donated, and a ring takes what a
+    dispatch made in ONE scatter over ``(slot, head, entry)``."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernel compiled, not interpreted
+    compiled = _compile_trinity(program, one_chip)
+    hlo = re.sub(r"/\*.*?\*/", "", compiled.as_text())
+    views = r"8,8,4112,128|64,4112,128|263168,128|1,6144,16,8,128|6144,16,8,128|98304,8,128|32,3072,3072"
+    big = re.findall(rf"= (?:f32|bf16)\[(?:{views})\]\{{[^}}]*\}} copy\(", hlo)
+    assert big == [], big
+    assert len(re.findall(r"= f32\[263168,128\]\{[^}]*\} scatter\(", hlo)) == 8  # K and V of four window layers
+    kernels = len(re.findall(r"custom_call_target=\"tpu_custom_call\"", hlo))
+    widths = len(llama.history_widths(8 * 32))  # a decode dispatch holds its steps once a history width
+    assert kernels == (3 * 4 * 4 * widths if program == "decode" else 3 * 4) and "grouped_product" in hlo
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * 402_653_184 + 1_077_936_128
+    # beside the arguments: the full layer's dense history of a full-width decode dispatch (0.54 GB)
+    # and its steps; a chunk group's scores against a tile of 512 ring entries and 256 page positions
+    assert memory.temp_size_in_bytes < (1_200_000_000 if program == "decode" else 500_000_000)
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12_000_000_000

@@ -13,7 +13,9 @@ selective scan ALONE over a chunk and over a decode step beside the whole mixer
 attention ALONE at ``batch.openpangu-ultra-moe-718b``'s two shapes, beside its
 bytes and operations (:func:`mla_profiles`); ``... mhc`` times the residual
 path of ONE sublayer of ``xing4.0-29b-a4b`` ALONE, beside the bytes it must
-move (:func:`mhc_profiles`); without a word, the round-5 ablation below.
+move (:func:`mhc_profiles`); ``... swa`` times ONE window layer's attention of
+``trinity-large-preview`` ALONE over its rings at ``long.trinity-large-preview``'s
+two shapes (:func:`swa_profiles`); without a word, the round-5 ablation below.
 
 Method notes:
 - every measurement chains computations via data dependencies and fences
@@ -1099,6 +1101,69 @@ def mhc_profiles():
             print(line, flush=True)
 
 
+def swa_profiles():
+    """ONE window layer's attention of ``trinity-large-preview`` ALONE at
+    ``long.trinity-large-preview``'s shapes (``ops/ring.py`` as
+    ``models/trinity.py`` calls it: 48 query heads over 8 KV heads of 128, a
+    float32 ring of 4,112 positions a slot, 8 slots, every lane past the
+    window, float32's precision), PROF_ITERS (default 8) layers chained in one
+    dispatch: a decode step's 8 queries against the 8 rings read in place, and
+    a chunk group's 8 rows x 128 queries against their lanes' rings a tile a
+    trip plus their own fresh keys. Beside each time: the bytes of the rings
+    over the chip's bandwidth (a decode step's floor) and the scores' and
+    values' operations over its bf16 peak, counted once (the program takes six
+    bf16 passes a float32 product)."""
+    from benchmark import bytes_and_flops
+    from dynamo_tpu.engine_jax.compile_cache import enable_compile_cache
+    from dynamo_tpu.models import trinity
+    from dynamo_tpu.models.llama import _chunk_self_partial, _merge_partials
+    from dynamo_tpu.ops import ring
+
+    on_chip = jax.default_backend() == "tpu"
+    peaks = bytes_and_flops.load_peaks(jax.devices()[0].device_kind) if on_chip else {}
+    enable_compile_cache()
+    n_iter = int(os.environ.get("PROF_ITERS", "8"))
+    c = trinity.TrinityConfig(num_layers=1, layer_types=(trinity.WINDOW,), num_dense_layers=1)
+    slots, rows, chunk = 8, 8, 128
+    w, p, scale = c.sliding_window, c.ring_positions, c.head_dim ** -0.5
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    ring_k, ring_v = (jax.random.normal(k, (slots, c.num_kv_heads, p, c.head_dim), jnp.float32) for k in keys[:2])
+    starts = jnp.asarray([4200 + 300 * i for i in range(slots)], jnp.int32)  # every lane past the window
+    lanes = jnp.arange(rows, dtype=jnp.int32)
+
+    def decode_step(q, rings):
+        sees = ring.in_window(ring.held_positions(starts, p), starts[:, None], w)
+        with jax.default_matmul_precision(trinity.ATTENTION_PRECISION):
+            out = ring.attended(ring.masked_partial(q, *rings, sees, scale))
+        return q + 1e-3 * out
+
+    def chunk_group(q, rings):
+        pos = starts[:, None] + jnp.arange(chunk)[None, :]
+        kv = q[:, :, ::c.num_heads // c.num_kv_heads]
+        with jax.default_matmul_precision(trinity.ATTENTION_PRECISION):
+            part = ring.chunk_ring_partial(q, *rings, lanes, starts, ring.ring_trips(starts, w), pos, w, scale)
+            out = ring.attended(_merge_partials(part, _chunk_self_partial(c, q, kv, kv, pos, scale, w)))
+        return q + 1e-3 * out
+
+    def ms_a_call(fn, q) -> float:
+        @jax.jit
+        def chain(q, rings):
+            return jax.lax.scan(lambda x, _: (fn(x, rings), ()), q, None, length=n_iter)[0]
+        return median_ms(chain, q, (ring_k, ring_v)) / n_iter
+
+    ring_bytes = 2 * slots * c.num_kv_heads * p * c.head_dim * 4
+    for name, fn, t in (("a decode step's 8 lanes", decode_step, 1), ("a chunk group of 8 rows x 128", chunk_group, chunk)):
+        q = jax.random.normal(keys[2], (rows, t, c.num_heads, c.head_dim), jnp.float32)
+        ms = ms_a_call(fn, q)
+        flops = 2 * 2 * rows * t * c.num_heads * c.head_dim * (w + (t - 1) / 2)
+        line = (f"swa {name}: {ms:8.4f} ms a layer; the rings are {ring_bytes / 1e6:7.2f} MB, "
+                f"the scores and values {flops / 1e9:7.3f} GFLOP counted once")
+        if on_chip:
+            line += (f": {ring_bytes / peaks['hbm_bytes_per_s'] * 1e3:7.4f} ms at the chip's bandwidth, "
+                     f"{flops / peaks['bf16_flops_per_s'] * 1e3:7.4f} ms at its bf16 peak")
+        print(line, flush=True)
+
+
 if __name__ == "__main__":
     {"history": history_profiles, "experts": expert_profiles, "kda": kda_profiles,
-     "mamba": mamba_profiles, "mla": mla_profiles, "mhc": mhc_profiles}.get(" ".join(sys.argv[1:2]), main)()
+     "mamba": mamba_profiles, "mla": mla_profiles, "mhc": mhc_profiles, "swa": swa_profiles}.get(" ".join(sys.argv[1:2]), main)()
