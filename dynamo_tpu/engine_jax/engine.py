@@ -13,7 +13,10 @@ Architecture (TPU-first, cf. SURVEY.md §7 stage 4):
   as its prompt needs while a rung under `max_slots` holds them
   (`chunk_rows_of`: a prompt prefills in one host step, a later piece
   attending the earlier rows' fresh keys inside the program), where the
-  model's module allows it; otherwise one row a lane. The lanes that decode
+  model's module allows it; otherwise one row a lane. At `max_slots` rows,
+  which only the number of prefilling lanes forces, the rows left over go to
+  further pieces too where the module's full-width program reads the rows'
+  lanes (`FULL_WIDTH_TAKES_ROWS`); elsewhere they are padding. The lanes that decode
   are never rows of it: they advance through the decode program in the same
   host step, and both programs are dispatched before either result is
   fetched. (On a process-spanning mesh, under pp and under sp the decode
@@ -502,26 +505,35 @@ def chunk_row_ladder(max_slots: int) -> List[int]:
     return sorted({r for r in (max_slots // 8, max_slots // 4) if r >= 1} | {max_slots})
 
 
-def chunk_rows_of(need: List[int], waited: List[float], rungs: List[int]) -> List[int]:
+def chunk_rows_of(need: List[int], waited: List[float], rungs: List[int],
+                  top_takes: bool = False, most: Optional[int] = None) -> List[int]:
     """The rows each prefilling lane takes of a chunk dispatch, where a lane
     may fill several with successive pieces of its prompt (the model module's
     ``LANE_TAKES_ROWS``). ``need[i]`` is the rows lane i's remaining prompt
     asks for, ``waited[i]`` when its request arrived, ``rungs`` the engine's
     ladder; the dispatch is the smallest rung that holds the rows taken.
+    ``top_takes``: the module's full-width program reads the rows' lanes too
+    (its ``FULL_WIDTH_TAKES_ROWS``); ``most``: the rows of one dispatch its
+    module lets one lane fill (its ``lane_rows_most``; None = as many as fit).
 
     That rung is the smallest that holds ``max(n, min(W, r2))``: the n lanes,
     and of the W rows they ask for as many as the largest rung under
     ``max_slots`` holds (r2), so the full width is never taken for the sake
     of pieces. Every lane gets its first row (none is starved); the rows left
     go to further pieces, the lane that has waited longest first, and what
-    does not fit goes on in the next step. At the full width a lane has one
-    row: its program is the one without lanes (that of an engine of one
-    rung, to the character), and row pairs there would be ``[max_slots, max_slots]``."""
+    does not fit goes on in the next step. At the full width, which the
+    number of lanes alone forces, the rows no lane's first piece fills are
+    dealt the same way where ``top_takes`` (the program computes them whether
+    they hold tokens or not); otherwise a lane has one row there: the program
+    is the one without lanes (that of an engine of one rung, to the
+    character), or one that reads them only under the full width."""
+    if most is not None:
+        need = [min(k, most) for k in need]
     n, full = len(need), rungs[-1]
     under = max((r for r in rungs if r < full), default=0)
     rows = next(r for r in rungs if r >= max(n, min(sum(need), under)))
     takes = [1] * n
-    if rows < full:
+    if rows < full or top_takes:
         spare = rows - n
         for i in sorted(range(n), key=lambda i: waited[i]):
             takes[i] += min(need[i] - 1, spare)
@@ -1079,6 +1091,18 @@ class JaxServingEngine(AsyncEngine):
         self._lane_rows = (
             len(self._chunk_rungs) > 1
             and getattr(self.model, "LANE_TAKES_ROWS", False)
+        )
+        # ... and AT the full width too, the rows that the lanes' first
+        # pieces leave empty, where the module says its chunk program is the
+        # same program there (it reads the rows' lanes at every width); and
+        # of one dispatch no more rows a lane than the module says it can
+        # keep apart (a ring a slot holds so many positions; None: no bound)
+        self._top_takes_rows = self._lane_rows and getattr(
+            self.model, "FULL_WIDTH_TAKES_ROWS", False
+        )
+        most = getattr(self.model, "lane_rows_most", None)
+        self._lane_rows_most: Optional[int] = (
+            most(model_config, engine_config.prefill_chunk) if most else None
         )
         # the block counts _take_sealing reads ahead, ascending. The largest
         # is what a decode dispatch or a chunk dispatch of a rung under
@@ -2768,9 +2792,11 @@ class JaxServingEngine(AsyncEngine):
         an empty lane has). A prefilling lane consumes up to a chunk of
         prompt a row, and where `_lane_rows` as many rows as its prompt needs
         and the rung holds (`chunk_rows_of`: a lane's pieces in consecutive
-        rows, in order); at the full width, under pacing and on every other
-        engine one row, so a whole admission wave prefills in
-        ceil(longest_suffix / chunk) dispatches. Where `_rides`, the lanes
+        rows, in order); at the full width the rows its lanes' first pieces
+        leave where `_top_takes_rows` (the module's full-width program is
+        the one that reads the rows' lanes) and one row otherwise; under
+        pacing and on every other engine one row, so a whole admission wave
+        prefills in ceil(longest_suffix / chunk) dispatches. Where `_rides`, the lanes
         that decode are rows too, one token each. Returns the dispatch for
         `_chunk_finish`, or None when no lane takes a prompt token (all
         budgeted out)."""
@@ -2839,12 +2865,14 @@ class JaxServingEngine(AsyncEngine):
         n_lanes = len(take)
         if self._lane_rows and allow is None:
             # a lane takes the rows its prompt needs, as far as a rung under
-            # the full width holds them (`chunk_rows_of`): its pieces in
+            # the full width holds them, or the spare rows of the full width
+            # where its program takes them (`chunk_rows_of`): its pieces in
             # consecutive rows, in order, each full but the last
             fed_lanes = [self._slots[i] for i, _, _ in take]
             takes = chunk_rows_of(
                 [-(-(len(s.prompt) - s.prefill_pos) // C) for s in fed_lanes],
                 [s.enqueue_t for s in fed_lanes], self._chunk_rungs,
+                self._top_takes_rows, self._lane_rows_most,
             )
             take = [
                 (i, at, min(C, len(s.prompt) - at))

@@ -75,7 +75,22 @@ def module_for(model_config):
     lane's later row attends its earlier rows' fresh K and V in hand, and
     what a later group needs of an earlier one (those K and V, the last row's
     tails, and the last row's sequence STATE of each DeltaNet layer, which
-    ``lfm2``'s tails do not need) rides the loop's carry.
+    ``lfm2``'s tails do not need) rides the loop's carry. A module whose
+    chunk program is the SAME program at ``rows == slots`` (it reads ``lanes``
+    at every width, so (4) asks nothing of it) says so beside it:
+    ``FULL_WIDTH_TAKES_ROWS = True``, and the engine then deals the rows of a
+    full-width dispatch that no lane's first piece fills to further pieces
+    (``engine_jax/engine.py:chunk_rows_of``), where they would be computed as
+    padding. ``models/trinity.py`` says so, alone: ``llama``'s full-width
+    program is the one without lanes; ``jamba``, ``lfm2`` and ``qwen3_next``
+    read their lanes under the full width only (``rows < slots``); and
+    ``openpangu``, ``xing4`` and ``kimi_linear``, the same at every rung, serve
+    64 slots, where the full width is an admission wave with no spare row, so
+    no cell could show or check it (ROADMAP S1 (e)). A module that can keep
+    only so many rows of one lane apart in ONE dispatch states the number,
+    ``lane_rows_most(config, width) -> int``, one at the least (``trinity``:
+    what a window layer's ring holds), and the engine deals a lane no more at
+    any rung.
 
     A config that is no ``LlamaConfig`` was made by its own module's class
     (``engine_jax/weights.py:config_from_card`` imports that module in its

@@ -95,6 +95,13 @@ assert COUNTERS.index("swa_layer_calls") == MOE_COUNTERS  # what `feed_forward` 
 # as fresh keys (in a window layer under the window's mask); nothing is handed
 # from row to row, and the rings are written once, after the layers, by position
 LANE_TAKES_ROWS = True
+# ... and AT the full width as under it: `forward_chunk` reads `lanes` and builds
+# its layout from them whatever the rows number, so the rows of a full-width
+# dispatch that no lane's first piece fills (computed either way: 8 rows are one
+# group) go to further pieces of the lanes' prompts. Four modules keep one row a
+# lane there, each for its program's text: `llama`'s full-width program takes no
+# lanes, `jamba`, `lfm2` and `qwen3_next` read theirs under `rows < slots` alone
+FULL_WIDTH_TAKES_ROWS = True
 # rows of a chunk computed at once: the rows are independent, and a chunk of
 # more is taken in groups (8 rows of 128 positions route 4,096 pairs; more at
 # once only adds temporaries)
@@ -153,6 +160,16 @@ class TrinityConfig:
     def ring_positions(self) -> int:
         """Positions a slot holds in a window layer: the window's and one block more."""
         return self.sliding_window + RING_BLOCK
+
+
+def lane_rows_most(config: TrinityConfig, width: int) -> int:
+    """The rows of ``width`` positions that one lane may fill of ONE chunk
+    dispatch, at any rung: what a window layer's ring holds, since the rings
+    are written once a dispatch and each position to its own entry. The
+    engine deals a lane no more (``engine_jax/engine.py:chunk_rows_of``), and
+    ``forward_chunk`` holds the number against the ring where it is traced
+    (one row at the least: a row wider than a ring is refused there)."""
+    return max(1, config.ring_positions // width)
 
 
 # -- parameters ---------------------------------------------------------------
@@ -324,9 +341,9 @@ def forward_chunk(
 ):
     """A ``[R, C]`` block of prompt tokens (``lanes`` ``[R]``: the row's slot;
     ``max_slots`` and above = a padding row), valid tokens (position >= 0) a
-    prefix of each row. Under the full width (``R`` < the state's slots) a lane
-    may fill several CONSECUTIVE rows with successive pieces of its prompt, in
-    order, each full but the last; at it, one row a lane.
+    prefix of each row. A lane may fill several CONSECUTIVE rows with
+    successive pieces of its prompt, in order, each full but the last, at any
+    number of rows (``FULL_WIDTH_TAKES_ROWS``).
 
     Returns (hidden ``[R, C, E]`` after the final norm, the pool with the full
     layers' K and V written, the slot state with the window layers' K and V
@@ -344,14 +361,15 @@ def forward_chunk(
     above it (``chunk_rows_above_partial``) and (c) its own
     (``_chunk_self_partial``), both causal and in a window layer inside the
     window, folded by the flash merge. A lane's rows of one dispatch hold a
-    ring's positions at most (each is written to its own entry), which is
-    asserted here where it is traced."""
+    ring's positions at most (each is written to its own entry): the engine
+    deals a lane ``lane_rows_most`` rows at most, and that number is held
+    against the ring here, where the program is traced."""
     from dynamo_tpu.ops.attention import write_kv_to_pool
 
     c = config
     rows, width = tokens.shape
     slots = state["k"][0].shape[0]
-    a_lane = rows if rows < slots else 1  # the rows one lane may fill of this dispatch
+    a_lane = min(rows, lane_rows_most(c, width))  # the rows one lane may fill of this dispatch
     if a_lane * width > c.ring_positions:
         raise ValueError(
             f"a lane's {a_lane} rows of {width} positions pass the {c.ring_positions} positions a "

@@ -152,6 +152,51 @@ def test_the_rung_holds_the_lanes_and_as_many_pieces_as_the_second_rung_takes(ne
     assert all(1 <= t <= n for t, n in zip(takes, need))
 
 
+@pytest.mark.parametrize("need, waited, rungs, most, want", [
+    # more lanes than the second rung: the full width, and its spare rows dealt as under it
+    ([3] * 5, list(range(5)), [1, 2, 8], None, (8, [3, 2, 1, 1, 1])),
+    ([3] * 5, [4, 3, 2, 1, 0], [1, 2, 8], None, (8, [1, 1, 1, 2, 3])),  # oldest first, wherever it sits
+    ([40] * 3, [0, 1, 2], [1, 2, 8], None, (8, [6, 1, 1])),
+    ([1] * 6, list(range(6)), [1, 2, 8], None, (8, [1] * 6)),  # nothing to deal: two rows stay padding
+    ([8] * 8, list(range(8)), [1, 2, 8], None, (8, [1] * 8)),  # an admission wave: no row spare
+    ([2, 1, 1, 1, 2], [3, 0, 1, 2, 4], [1, 2, 8], None, (8, [2, 1, 1, 1, 2])),  # all asked for, one row left
+    ([3] * 9, list(range(9)), [4, 8, 32], None, (32, [3] * 9)),
+    ([40] * 9, list(range(9)), [4, 8, 32], None, (32, [24] + [1] * 8)),
+    # a lane held to what its module keeps apart of one dispatch, at the full width and under it
+    ([40] * 3, [0, 1, 2], [1, 2, 8], 3, (8, [3, 3, 2])),
+    ([40] * 9, list(range(9)), [4, 8, 32], 16, (32, [16, 9] + [1] * 7)),
+    ([12], [0], [4, 8, 32], 3, (4, [3])),
+    ([8, 8, 2], [3, 1, 2], [4, 8, 32], 4, (8, [2, 4, 2])),
+    ([5, 5], [0, 1], [1, 2, 8], 1, (2, [1, 1])),
+    # pieces alone still never raise a dispatch to the full width
+    ([8, 8], [0, 1], [1, 2, 8], None, (2, [1, 1])),
+    ([40], [0], [1, 2, 8], None, (2, [2])),
+    ([5], [0], [1, 4], None, (1, [1])),
+    # under the full width nothing moves
+    ([3, 2], [5, 1], [4, 8, 32], None, (8, [3, 2])),
+    ([8, 8, 2], [3, 1, 2], [4, 8, 32], None, (8, [1, 6, 1])),
+], ids=lambda v: "-".join(map(str, v)) if isinstance(v, list) else None)
+def test_the_full_width_deals_its_spare_rows_where_its_program_takes_them(need, waited, rungs, most, want):
+    """``top_takes`` (the module's ``FULL_WIDTH_TAKES_ROWS``): the rule of the
+    rungs under the full width, at it; ``most`` (its ``lane_rows_most``) caps a
+    lane at every rung. The rung is the one the rule picks without either."""
+    takes = chunk_rows_of(need, waited, rungs, True, most)
+    rows = next(r for r in rungs if r >= sum(takes))  # as `_chunk_build` picks it
+    assert (rows, takes) == want
+    capped = [min(k, most or k) for k in need]
+    assert all(1 <= t <= k for t, k in zip(takes, capped)) and sum(takes) <= rows
+    # the rung never raised by pieces: what the lanes alone, or the second rung's worth of pieces, ask for
+    under = max((r for r in rungs if r < rungs[-1]), default=0)
+    assert rows == next(r for r in rungs if r >= max(len(need), min(sum(capped), under)))
+    # no spare row is left while a lane still asks for one
+    assert sum(takes) == min(rows, sum(capped))
+    plain = chunk_rows_of(need, waited, rungs)
+    if most is None and rows < rungs[-1]:
+        assert takes == plain  # the statement moves the full width alone
+    if rows == rungs[-1]:
+        assert plain == [1] * len(need)  # what stands without it
+
+
 # -- token for token -------------------------------------------------------------
 
 # five lanes start their prefill in one step beside one that decodes: more

@@ -22,14 +22,14 @@ import numpy as np
 import pytest
 
 from benchmark import reference_trinity as ref
-from dynamo_tpu.engine_jax.engine import EngineConfig, JaxServingEngine
+from dynamo_tpu.engine_jax.engine import EngineConfig, JaxServingEngine, chunk_row_ladder
 from dynamo_tpu.engine_jax.weights import config_from_card
 from dynamo_tpu.kv.pages import StateNotPortable
 from dynamo_tpu.models import module_for, trinity
 from dynamo_tpu.ops import ring
 
 from .step_programs import (  # noqa: F401  (highest_precision: autouse, for this file's tests)
-    answer, card, chunk_program, collect, decode_program, highest_precision, patched, prompt_of,
+    answer, busy, card, chunk_program, collect, decode_program, highest_precision, patched, prompt_of,
     published_shape, reference_program, run_out, served, step, submit,
 )
 
@@ -171,6 +171,10 @@ def prefill_then_decode(cfg, params, chunks=(16, 16, 16, 9), **how):
     return got[2]
 
 
+def i32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+
 def reference_of(params, tokens, shape=SHAPE):
     return np.asarray(reference_program(ref, shape)(params, jnp.asarray(tokens), jnp.arange(len(tokens))))
 
@@ -192,18 +196,25 @@ LAYOUTS = {
     # through the window's mask alone
     "three_pieces_a_dispatch": dict(rows=3, dispatches=[
         [(1, 16), (1, 16), (1, 16)], [(3, 9), (1, 16), (1, 12)], [(3, 16), (3, 16), (3, 16)], [(3, 2)]]),
+    # the full width (4 rows over 4 slots: `FULL_WIDTH_TAKES_ROWS`): a lane's three pieces, a whole
+    # ring of positions, beside other lanes' single rows; slots 1 and 0 pass their rings' ends inside
+    # the second and the fourth dispatch
+    "pieces_at_the_full_width": dict(rows=4, dispatches=[
+        [(1, 16), (1, 16), (1, 16), (3, 9)], [(3, 16), (1, 16), (1, 12), (0, 16)],
+        [(3, 16), (3, 16), (0, 16), (0, 16)], [(3, 2), (0, 16), (0, 16), (0, 3)]]),
 }
 
 
 @pytest.mark.parametrize("layouts", [
-    ("a_chunk_a_dispatch", "a_short_first_chunk", "two_pieces_a_dispatch"), ("three_pieces_a_dispatch",)],
-    ids=["dispatches_of_two_rows", "dispatches_of_three_rows"])
+    ("a_chunk_a_dispatch", "a_short_first_chunk", "two_pieces_a_dispatch"), ("three_pieces_a_dispatch",),
+    ("pieces_at_the_full_width",)],
+    ids=["dispatches_of_two_rows", "dispatches_of_three_rows", "dispatches_of_the_full_width"])
 def test_chunked_prefill_then_decode_agrees_with_the_plain_reference(cfg, params, layouts):
     """A prompt fed in chunks whose boundaries lie inside it, each reading the
     pages and the rings the last one left (keys rotated at their own positions,
     past the ring's end too), then three decode steps off the same caches,
     against the reference's one pass over the whole sequence: logits, at every
-    position. The other slots' rings and the other pages stay as they were. Four
+    position. The other slots' rings and the other pages stay as they were. Five
     chunkings (``LAYOUTS``), those of one geometry in one case: their step
     programs compile once."""
     for layout in layouts:
@@ -371,9 +382,38 @@ def test_the_parent_s_refusal_of_the_model_type_is_gone_and_other_expert_cards_s
         config_from_card(card({**SHAPE, "model_type": "afmoe2"}))
 
 
-def test_a_dispatch_that_would_write_one_ring_entry_twice_is_refused_where_it_is_traced(cfg, params):
-    with pytest.raises(ValueError, match="pass the 48 positions a window layer keeps"):
-        dispatch_rows(cfg, params, [[(2, 16)]], rows=4, slots=8)
+@pytest.mark.parametrize("slots", [8, 4], ids=["under_the_full_width", "at_the_full_width"])
+def test_a_dispatch_that_would_write_one_ring_entry_twice_is_refused_where_it_is_traced(
+        cfg, params, monkeypatch, slots):
+    """A lane's rows of one dispatch are the engine's to deal and the module's
+    to bound (``lane_rows_most``: 3 rows of 16 in a ring of 48). A bound the
+    ring cannot hold, here four rows, is refused where a program of four rows
+    is traced, at the full width as under it."""
+    assert trinity.lane_rows_most(cfg, 16) == 3
+    patched(monkeypatch, trinity, "lane_rows_most", lambda config, width: 4)
+    with pytest.raises(ValueError, match="a lane's 4 rows of 16 positions pass the 48 positions a window layer"):
+        dispatch_rows(cfg, params, [[(2, 16)]], rows=4, slots=slots)
+
+
+def test_a_row_wider_than_a_ring_is_refused_and_the_served_geometries_trace(cfg):
+    """One row is the least a lane takes: a chunk wider than the ring is
+    refused whatever is dealt. What is served traces: the cell's 8 slots and 64
+    slots, at the CLI's chunk of 128 and the published window, every rung, the
+    full width among them (64 rows of 128 would pass a ring of 4,112 if one
+    lane filled them; the deal gives a lane 32)."""
+    def lower(c, rows, slots, width):
+        made = jax.eval_shape(lambda: (trinity.init_params(jax.random.PRNGKey(0), c),
+                                       trinity.make_kv_cache(c, 9, 16), trinity.make_slot_state(c, slots)))
+        return jax.eval_shape(lambda p, cache, st, t, pos, tb, ln: trinity.forward_chunk(
+            p, c, t, pos, cache, tb, st, ln), *made, i32(rows, width), i32(rows, width), i32(rows, 8), i32(rows))
+
+    with pytest.raises(ValueError, match="a lane's 1 rows of 64 positions pass the 48 positions"):
+        lower(cfg, 2, 4, 64)
+    published = dataclasses.replace(config_from_card(card(published_shape("afmoe"))), vocab_size=256)
+    assert trinity.lane_rows_most(published, 128) == 32
+    for slots in (8, 64):
+        for rows in chunk_row_ladder(slots):
+            assert lower(published, rows, slots, 128)[0].shape == (rows, 128, 3072)
 
 
 # -- the engine -----------------------------------------------------------------
@@ -440,6 +480,58 @@ def test_the_engine_serves_what_the_reference_chooses_past_the_window_and_in_a_u
         engine._refuse_for_state("a migration")
 
 
+def test_five_lanes_admitted_at_once_share_the_eight_rows_of_the_full_width(engine, params):
+    """Five prompts of five and six chunks admitted in one step: more lanes than
+    the second rung holds, so the dispatches are the full width's, whose three
+    spare rows go to the oldest lanes' further pieces (``chunk_rows_of`` with
+    the module's ``FULL_WIDTH_TAKES_ROWS``), a lane never more than the ring's
+    three rows (``lane_rows_most``). Every answer is the reference's greedy one."""
+    assert trinity.FULL_WIDTH_TAKES_ROWS and engine._top_takes_rows and engine._lane_rows_most == 3
+    before = engine.metrics_snapshot()
+    wide = engine.chunk_dispatches_by_rows.get(8, 0)
+    prompts = [prompt_of(70 + 3 * i, salt=20 + i) for i in range(5)]
+    seqs = [submit(engine, p, 5) for p in prompts]
+    def prefilled(seq):
+        return len(seq.prompt) if seq.prefill_pos is None else seq.prefill_pos
+
+    rows_a_step = set()  # what a lane took of one dispatch, over the run
+    while busy(engine):
+        was = [prefilled(s) for s in seqs]
+        step(engine)
+        rows_a_step |= {-(-(prefilled(s) - at) // 16) for s, at in zip(seqs, was)}
+    assert max(rows_a_step) == 3
+    for prompt, seq in zip(prompts, seqs):
+        toks = answer(seq)[0]
+        assert len(toks) == 5 and toks == greedy_of_the_reference(params, prompt, toks)
+    snap = engine.metrics_snapshot()
+    rows, lanes = (snap[k] - before[k] for k in ("chunk_rows_live", "chunk_lanes_fed"))
+    assert rows == sum(-(-len(p) // 16) for p in prompts) == 26
+    # 26 rows in three dispatches of the full width (8, 8, 8: fifteen lane-dispatches where
+    # one row a lane took six of five) and one of the second rung for the two rows left
+    assert engine.chunk_dispatches_by_rows[8] - wide == 3 and rows > lanes
+    assert snap["prompt_dispatches"] - before["prompt_dispatches"] < 5 * 5
+
+
+def test_an_engine_of_64_slots_deals_a_lane_no_more_rows_than_a_ring_holds(cfg, params):
+    """Ladder [8, 16, 64]: at 64 rows of 16 a lane could be dealt 47 where a
+    ring holds 3. Eighteen prompts of five chunks at once (more lanes than the
+    16-row rung holds): the full width, three rows a lane, twice."""
+    eng = JaxServingEngine(cfg, params, dataclasses.replace(ENGINE_CFG, max_slots=64))
+    try:
+        assert eng._chunk_rungs == [8, 16, 64] and eng._top_takes_rows and eng._lane_rows_most == 3
+        prompts = [prompt_of(66 + i % 3, salt=40 + i) for i in range(18)]
+        seqs = [submit(eng, p, 3) for p in prompts]
+        step(eng)
+        assert [s.prefill_pos for s in seqs] == [48] * 18 and eng.chunk_dispatches_by_rows == {64: 1}
+        run_out(eng)
+        assert eng.chunk_dispatches_by_rows == {64: 2} and eng.chunk_rows_live == 18 * 5
+    finally:
+        eng.close()
+    for prompt, seq in list(zip(prompts, seqs))[::7]:
+        toks = answer(seq)[0]
+        assert len(toks) == 3 and toks == greedy_of_the_reference(params, prompt, toks)
+
+
 def test_preemption_recomputes_past_the_window(cfg, params, run):
     """Out of blocks, a lane past the window is preempted and recomputed from
     position 0 into the same rings: greedy output as the reference's."""
@@ -462,10 +554,6 @@ def test_the_step_programs_carry_the_two_scopes(cfg):
     """``swa`` and ``full_attn`` around the two kinds of attention, ``moe``
     around the expert layer: what a compile report and a profile tell apart."""
     c, mb = 16, 16
-
-    def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32)
-
     made = jax.eval_shape(lambda: (trinity.init_params(jax.random.PRNGKey(0), cfg),
                                    trinity.make_kv_cache(cfg, 33, 8), trinity.make_slot_state(cfg, 4)))
     text = jax.jit(lambda p, cache, st, t, pos, tb, ln: trinity.forward_chunk(
