@@ -46,8 +46,8 @@ advances ``[rows, N, D]`` token by token (:func:`_scan_tokens`), in one of the t
 kernels of ``ops/pallas/selective_scan.py``: a chunk's holds a row's state on the
 chip from the row's first token to its last valid one (HBM sees it once in and
 once out), and over the rows that go on from it where a lane fills several rows
-of a dispatch with successive pieces of its prompt (``LANE_TAKES_ROWS``;
-:func:`forward_chunk`); a decode step's, one token of every slot, updates a layer of the run's
+of a dispatch with successive pieces of its prompt (``LANE_TAKES_ROWS``; as far
+as a group of ``ROWS_AT_ONCE`` rows reaches: :func:`forward_chunk`); a decode step's, one token of every slot, updates a layer of the run's
 state in place, read once and written once. The token count the code sees picks
 the kernel, the token's arithmetic is one. ``[rows, T, N, D]`` never exists.
 """
@@ -63,7 +63,7 @@ import jax.numpy as jnp
 
 from dynamo_tpu.models.llama import (  # noqa: F401  (the two tile counts are this module's too)
     _chunk_self_partial, _live_window_attention, _merge_partials, _pool_pages,
-    chunk_history_partial, chunk_history_tiles, chunk_sibling_partial, decode_history_tiles,
+    chunk_history_partial, chunk_history_tiles, chunk_rows_above_partial, decode_history_tiles,
     embed_lookup, flush_window, history_tile, history_tiles_full, lane_first_positions, rms_norm,
     sibling_rows_back, with_live_history,
 )
@@ -75,17 +75,20 @@ KVCache = Dict[str, jax.Array]  # {"k", "v"}: [L_attn, N, bs, KVH, D]
 SlotState = Dict[str, Tuple[jax.Array, ...]]  # {"s": per run [n, S, N, D], "conv": [n, S, (K-1)*D]}
 
 # sums the step programs return, in this order (engine: /debug/engine):
-# Mamba layers run (a chunk dispatch or a decode step each count their 26);
+# Mamba layers run (a group of a chunk dispatch's rows or a decode step each count their 26);
 # valid tokens the chunks' recurrences advanced, and the times a chunk row's
-# state went from HBM to the chip and back (one a LANE of a dispatch: a real
-# row that holds a valid token and continues no row above it; the kernel keeps
-# the state there over the tokens of the lane's rows, where the scan it
-# replaced made a pass a token), both summed over the Mamba layers; rows that
+# state went from HBM to the chip and back (one a LANE of a GROUP of a
+# dispatch's rows: a real row that holds a valid token and continues no row
+# above it in its group; the kernel keeps the state there over the tokens of the
+# lane's rows, where the scan it replaced made a pass a token), both summed over
+# the Mamba layers; rows that
 # started a request; real rows of a chunk dispatch that took their state and
 # the convolution's tail from the row above them (the rows of a dispatch less
-# the lanes it fed)
+# the lanes it fed, a lane counted once a GROUP of rows it has a row in); rows of
+# the groups a chunk dispatch computed (ROWS_AT_ONCE a group as far as the last
+# row that holds a token; the engine's own ``chunk_rows_dispatched`` is the rung)
 COUNTERS = ("ssm_layer_calls", "ssm_chunk_tokens", "ssm_state_passes", "slot_state_resets",
-            "ssm_state_handovers")
+            "ssm_state_handovers", "chunk_rows_computed")
 # A lane may fill several rows of one chunk dispatch with successive pieces of
 # its prompt (engine_jax/engine.py:chunk_rows_of; docs/kv_cache_manager.md,
 # "State per slot", says what a module with state per slot owes for it): under
@@ -94,6 +97,14 @@ COUNTERS = ("ssm_layer_calls", "ssm_chunk_tokens", "ssm_state_passes", "slot_sta
 # convolution from that row's last inputs, and attends its lane's earlier rows'
 # fresh keys; one row of a lane alone leaves the slot each part of its state
 LANE_TAKES_ROWS = True
+# Rows of a chunk dispatch computed at once (:func:`forward_chunk`): a dispatch is
+# its groups of this many rows one after another, as far as the last row that
+# holds a token, so three rows of a prompt cost four and not the rung's eight.
+# The model is dense, 1,000 flop a weight byte at four rows where the chip
+# balances at 240, so a group's time is nearly its rows': on the chip 7.2-7.4 ms
+# a row at 4, 7.0 at 8, 8.3-8.9 at 2 (``tools/profile_decode.py groups``;
+# PERF.md 6, PR 67). A loop over groups of 8 aborts the chip's compiler.
+ROWS_AT_ONCE = 4
 
 
 @dataclass(frozen=True)
@@ -355,126 +366,193 @@ def forward_chunk(
     rows' K and V written, the slot state with the rows' slots advanced, the
     counters ``[len(COUNTERS)]``). A row whose first position is 0 starts from
     a zeroed state: a slot is reset by the first chunk of the request admitted
-    to it. A run of Mamba layers is one ``lax.scan`` whose carry holds the
-    run's state whole: a layer gathers its rows' slots by ONE flat index and
-    scatters them back in place, so a dispatch costs what its rows touch. The
-    attention layers read the pool as ``models/llama.py:forward_chunk`` does
-    (history a tile at a time, the chunk's own keys in hand) and their fresh K
-    and V are written after the layers by one scatter a pool array.
+    to it.
 
-    A row whose lane is that of the row above it (both real: ``above``) goes on
-    where that row ends, inside the program. In a Mamba layer the recurrence
-    starts from the state that row ends with, which the kernel keeps on the
-    chip from the lane's first row to its last, and the convolution from that
-    row's last inputs (:func:`mamba_mixer`); one write a slot: the lane's FIRST
-    row holds the state after its last (``selective_scan`` leaves it there) and
-    scatters it, the lane's LAST row scatters the tail, and the lane's other
-    rows send their index past the run's state, as padding rows do. In an
-    attention layer the row's pool history ends where its lane's first row of
-    the dispatch starts and one more partial attends the fresh keys of its
-    lane's rows above it (``chunk_sibling_partial``). Where every lane has one
-    row nothing is taken from a row above and the sibling loop makes no trip;
-    at the full width none of it is in the program."""
+    The rows are taken in groups of ``ROWS_AT_ONCE``, one after another and
+    only as far as the last row that holds a token (the engine packs its rows
+    to the front): a group of padding rows alone is not computed, and its
+    hidden states stay zeros. The loop over the groups carries the runs' state
+    whole and the dispatch's fresh K and V so far. Inside a group a run of Mamba
+    layers is one ``lax.scan`` whose carry holds the run's state: a layer
+    gathers its rows' slots by ONE flat index and scatters them back in place,
+    so a group costs what its rows touch and nothing copies the state. The
+    attention layers read the pool as ``models/llama.py:forward_chunk`` does
+    (history a tile at a time, the group's own keys in hand); the pool is only
+    read inside the loop and takes the dispatch's K and V after it, one scatter
+    a pool array.
+
+    A row whose lane is that of the row above it IN ITS GROUP (both real:
+    ``above``) goes on where that row ends, inside the program. In a Mamba
+    layer the recurrence starts from the state that row ends with, which the
+    kernel keeps on the chip from the lane's first row of the group to its
+    last, and the convolution from that row's last inputs
+    (:func:`mamba_mixer`); one write a slot and group: the lane's FIRST row of
+    the group holds the state after its last (``selective_scan`` leaves it
+    there) and scatters it, the lane's LAST row scatters the tail, and the
+    lane's other rows send their index past the run's state, as padding rows
+    do. A lane whose rows straddle two groups meets itself through its slot's
+    own entries: the later group's first row is not fresh and reads the state
+    and the tail the group before wrote, float32 there and back. In an
+    attention layer a row's pool history ends where its lane's first row of
+    the DISPATCH starts, and one more partial attends the fresh keys of its
+    lane's rows above it, in its group or in an earlier one
+    (``chunk_rows_above_partial`` over the loop's carry). Where every lane has
+    one row nothing is taken from a row above and the sibling loop makes no
+    trip; at the full width none of it is in the program."""
     from dynamo_tpu.ops.attention import write_kv_to_pool
 
     c = config
-    b, t = positions.shape
-    valid = positions >= 0
-    fresh = positions[:, 0] == 0
+    rows, t = positions.shape
     slots = state["s"][0].shape[1]
-    lane = jnp.clip(lanes, 0, slots - 1)
-    real = lanes < slots
+    # a rung that is no whole number of groups is one group, as is one of ROWS_AT_ONCE rows or fewer
+    n = ROWS_AT_ONCE if rows % ROWS_AT_ONCE == 0 else rows
+    handed = rows < slots  # at the full width a lane has one row, and the program holds nothing of this
+    live = (lanes < slots) & (positions[:, 0] >= 0)
 
     scale = c.head_dim ** -0.5
     num_blocks, block_size = kv_cache["k"].shape[1:3]
     table_blocks = block_tables.shape[1]
     pages = _pool_pages(kv_cache)
     tile_blocks = history_tile(block_size, table_blocks) // block_size
-    above = under = None
-    starts, of_lanes = positions[:, 0], ()
-    if b < slots:  # at the full width a lane has one row, and the program holds nothing of this
-        live = real & (starts >= 0)
-        above = jnp.concatenate([jnp.zeros((1,), bool), (lanes[1:] == lanes[:-1]) & live[1:] & live[:-1]])
-        under = jnp.concatenate([above[1:], jnp.zeros((1,), bool)])  # the row under it goes on from it
-        starts, of_lanes = lane_first_positions(positions, lanes), (lanes,)
-        n_back = sibling_rows_back(positions, lanes)
-        # the flash partial of no keys: what the sibling loop starts from (below)
-        no_keys = (jnp.zeros((b, t, c.num_heads, c.head_dim), jnp.float32),
-                   jnp.full((b, c.num_heads, t), -1e30, jnp.float32),
-                   jnp.zeros((b, c.num_heads, t), jnp.float32))
-    history_len = jnp.clip(starts, 0, table_blocks * block_size)
-    n_tiles = chunk_history_tiles(positions, block_size, table_blocks, *of_lanes)
     tables = jnp.pad(block_tables, (
         (0, 0), (0, history_tiles_full(block_size, table_blocks) * tile_blocks - table_blocks)))
+    starts = positions[:, 0]  # where each row's pool history ends
+    goes_on = None
+    if handed:
+        # a row that goes on from the row above it: same lane, both live, and in ONE group
+        goes_on = jnp.concatenate([jnp.zeros((1,), bool), (lanes[1:] == lanes[:-1]) & live[1:] & live[:-1]])
+        goes_on &= jnp.arange(rows) % n != 0
+        starts = lane_first_positions(positions, lanes)  # at its lane's first row of the dispatch
+        n_back = sibling_rows_back(positions, lanes)
+        # the flash partial of no keys: what the sibling loop starts from (below)
+        no_keys = (jnp.zeros((n, t, c.num_heads, c.head_dim), jnp.float32),
+                   jnp.full((n, c.num_heads, t), -1e30, jnp.float32),
+                   jnp.zeros((n, c.num_heads, t), jnp.float32))
 
-    def mamba_layer(carry, xs):
-        h, s_all, conv_all = carry  # [n * S, N, D], [n * S, (K-1) * D]: the run's state, flat
-        lp, layer = xs
-        with jax.named_scope("mamba"):
-            at = layer * slots + lane
-            s0 = jnp.where(fresh[:, None, None], 0.0, s_all[at])
-            tail0 = jnp.where(fresh[:, None], 0.0, conv_all[at])
-            y, s1, tail1 = mamba_mixer(
-                lp, c, rms_norm(h, lp["mixer_norm"], c.rms_norm_eps), valid, s0, tail0, above)
-            # a padding row writes nowhere: its index lies past the run's state
-            back = s_back = jnp.where(real, at, s_all.shape[0])
-            if above is not None:
-                # nor do a lane's rows but ONE: the first holds the state after the last, the last the tail
-                s_back = jnp.where(above, s_all.shape[0], back)
-                back = jnp.where(under, s_all.shape[0], back)
-            s_all = s_all.at[s_back].set(s1, mode="drop")
-            conv_all = conv_all.at[back].set(tail1, mode="drop")
-        with jax.named_scope("mlp"):
-            h = mlp(lp, c, h + y)
-        return (h, s_all, conv_all), None
+    def group(g, carry):
+        h_all, k_all, v_all, s_all, conv_all = carry
+        top = g * n  # the dispatch's row that is the group's first
+        toks, pos, tabs, lns, ends = (
+            jax.lax.dynamic_slice_in_dim(a, top, n) for a in (tokens, positions, tables, lanes, starts))
+        valid = pos >= 0
+        fresh = pos[:, 0] == 0
+        lane = jnp.clip(lns, 0, slots - 1)
+        real = lns < slots
+        above = under = None
+        if handed:
+            above = jax.lax.dynamic_slice_in_dim(goes_on, top, n)
+            under = jnp.concatenate([above[1:], jnp.zeros((1,), bool)])  # the row under it goes on from it
+        history_len = jnp.clip(ends, 0, table_blocks * block_size)
+        n_tiles = chunk_history_tiles(ends[:, None], block_size, table_blocks)
 
-    h = embed_lookup(params, tokens, c.dtype).astype(jnp.float32)
-    s_out, conv_out, fresh_k, fresh_v = [], [], [], []
-    for kind, count in segments(c):
-        if kind == "mamba":
-            i = len(s_out)
-            s_run, conv_run = state["s"][i], state["conv"][i]
-            (h, s_run, conv_run), _ = jax.lax.scan(
-                mamba_layer,
-                (h, s_run.reshape(-1, *s_run.shape[2:]), conv_run.reshape(-1, conv_run.shape[2])),
-                (params["mamba"][i], jnp.arange(count)))
-            s_out.append(s_run.reshape(state["s"][i].shape))
-            conv_out.append(conv_run.reshape(state["conv"][i].shape))
-            continue
-        j = len(fresh_k)
-        lp = params["attn"][j]
-        with jax.named_scope("attn"):
-            q, k, v = _project_qkv(lp, c, rms_norm(h, lp["mixer_norm"], c.rms_norm_eps))
-            hist = chunk_history_partial(
-                c, q, pages, j * num_blocks + tables, history_len, n_tiles, positions, scale,
-                tile_blocks, block_size, c.dtype)
-            part = _merge_partials(hist, _chunk_self_partial(c, q, k, v, positions, scale))
-            if above is not None:
-                # the rows above, folded from the partial of no keys (merging with it is exact): the
-                # loop's carry is kept apart from ``part``, so what stands above compiles as it does
-                # without the loop and rows alone in their lanes keep their bits
-                part = _merge_partials(part, chunk_sibling_partial(
-                    c, q, k, v, positions, lanes, n_back, scale, no_keys))
-            num, _, den = part
-            attn = jnp.where(
-                (den > 0.0).transpose(0, 2, 1)[..., None],
-                num / jnp.maximum(den, 1e-30).transpose(0, 2, 1)[..., None], 0.0)
-            h = h + _dot(attn.reshape(b, t, c.q_dim), lp["wo"])
-            fresh_k.append(k)
-            fresh_v.append(v)
-        with jax.named_scope("mlp"):
-            h = mlp(lp, c, h)
-    cache = {"k": write_kv_to_pool(kv_cache["k"], jnp.stack(fresh_k), positions, block_tables),
-             "v": write_kv_to_pool(kv_cache["v"], jnp.stack(fresh_v), positions, block_tables)}
-    h = rms_norm(h, params["final_norm"], c.rms_norm_eps)
+        def mamba_layer(carry, xs):
+            h, s_run, conv_run = carry  # [n * S, N, D], [n * S, (K-1) * D]: the run's state, flat
+            lp, layer = xs
+            with jax.named_scope("mamba"):
+                at = layer * slots + lane
+                s0 = jnp.where(fresh[:, None, None], 0.0, s_run[at])
+                tail0 = jnp.where(fresh[:, None], 0.0, conv_run[at])
+                y, s1, tail1 = mamba_mixer(
+                    lp, c, rms_norm(h, lp["mixer_norm"], c.rms_norm_eps), valid, s0, tail0, above)
+                # a padding row writes nowhere: its index lies past the run's state
+                back = s_back = jnp.where(real, at, s_run.shape[0])
+                if above is not None:
+                    # nor do a lane's rows but ONE: the first holds the state after the last, the last the tail
+                    s_back = jnp.where(above, s_run.shape[0], back)
+                    back = jnp.where(under, s_run.shape[0], back)
+                s_run = s_run.at[s_back].set(s1, mode="drop")
+                conv_run = conv_run.at[back].set(tail1, mode="drop")
+            with jax.named_scope("mlp"):
+                h = mlp(lp, c, h + y)
+            return (h, s_run, conv_run), None
+
+        h = embed_lookup(params, toks, c.dtype).astype(jnp.float32)
+        s_all, conv_all = list(s_all), list(conv_all)
+        i = j = 0
+        for kind, count in segments(c):
+            if kind == "mamba":
+                (h, s_all[i], conv_all[i]), _ = jax.lax.scan(
+                    mamba_layer, (h, s_all[i], conv_all[i]), (params["mamba"][i], jnp.arange(count)))
+                i += 1
+                continue
+            lp = params["attn"][j]
+            with jax.named_scope("attn"):
+                q, k, v = _project_qkv(lp, c, rms_norm(h, lp["mixer_norm"], c.rms_norm_eps))
+                hist = chunk_history_partial(
+                    c, q, pages, j * num_blocks + tabs, history_len, n_tiles, pos, scale,
+                    tile_blocks, block_size, c.dtype)
+                part = _merge_partials(hist, _chunk_self_partial(c, q, k, v, pos, scale))
+                k_all = jax.lax.dynamic_update_slice(k_all, k[None], (j, top, 0, 0, 0))
+                v_all = jax.lax.dynamic_update_slice(v_all, v[None], (j, top, 0, 0, 0))
+                if handed:
+                    # the rows above, in this group or an earlier one, folded from the partial of no keys
+                    # (merging with it is exact): the loop's carry is kept apart from ``part``, so what
+                    # stands above compiles as it does without the loop and rows alone in their lanes
+                    # keep their bits
+                    part = _merge_partials(part, chunk_rows_above_partial(
+                        c, q, k_all[j], v_all[j], positions, lanes, top, n_back, scale, no_keys))
+                num, _, den = part
+                attn = jnp.where(
+                    (den > 0.0).transpose(0, 2, 1)[..., None],
+                    num / jnp.maximum(den, 1e-30).transpose(0, 2, 1)[..., None], 0.0)
+                h = h + _dot(attn.reshape(n, t, c.q_dim), lp["wo"])
+            with jax.named_scope("mlp"):
+                h = mlp(lp, c, h)
+            j += 1
+        h = rms_norm(h, params["final_norm"], c.rms_norm_eps)
+        return (jax.lax.dynamic_update_slice_in_dim(h_all, h, top, 0), k_all, v_all,
+                tuple(s_all), tuple(conv_all))
+
+    # the dispatch's fresh keys and values, both attention layers': a group's rows go in where they stand
+    none_yet = jnp.zeros((len(params["attn"]), rows, t, c.num_kv_heads, c.head_dim), c.dtype)
+    carry = (jnp.zeros((rows, t, c.hidden_size), jnp.float32), none_yet, none_yet,
+             tuple(s.reshape(-1, *s.shape[2:]) for s in state["s"]),
+             tuple(tail.reshape(-1, tail.shape[2]) for tail in state["conv"]))
+    if rows == n:  # one group: no loop, and computed whether or not a row holds a token
+        n_groups = jnp.int32(1)
+        h, k, v, s_all, conv_all = group(jnp.int32(0), carry)
+    else:
+        _trace_the_chunk_kernel(c, n, t, handed)
+        last = jnp.max(jnp.where(live, jnp.arange(rows) + 1, 0))
+        n_groups = (last + n - 1) // n
+        h, k, v, s_all, conv_all = jax.lax.fori_loop(0, n_groups, group, carry)
+    cache = {"k": write_kv_to_pool(kv_cache["k"], k, positions, block_tables),
+             "v": write_kv_to_pool(kv_cache["v"], v, positions, block_tables)}
+    new_state = {"s": tuple(s.reshape(was.shape) for s, was in zip(s_all, state["s"])),
+                 "conv": tuple(tail.reshape(was.shape) for tail, was in zip(conv_all, state["conv"]))}
+
     n_mamba = sum(_runs(c))
-    begins = valid[:, 0] & real  # a lane's first row of the dispatch: where its state goes in and out
-    if above is not None:
-        begins &= ~above
+    handovers = jnp.sum(goes_on) if handed else jnp.int32(0)
+    # a live row that goes on from none is a lane's first of a GROUP: where its state goes in and out
     counters = jnp.stack([
-        jnp.int32(n_mamba), n_mamba * jnp.sum(valid & real[:, None]), n_mamba * jnp.sum(begins),
-        jnp.sum(fresh & real), jnp.int32(0) if above is None else jnp.sum(above)])
-    return h, cache, {"s": tuple(s_out), "conv": tuple(conv_out)}, counters.astype(jnp.int32)
+        n_mamba * n_groups, n_mamba * jnp.sum((positions >= 0) & (lanes < slots)[:, None]),
+        n_mamba * (jnp.sum(live) - handovers), jnp.sum((positions[:, 0] == 0) & (lanes < slots)),
+        handovers, n_groups * n])
+    return h, cache, new_state, counters.astype(jnp.int32)
+
+
+def _trace_the_chunk_kernel(c: JambaConfig, n: int, t: int, handed: bool) -> None:
+    """Has ``selective_scan`` traced for a group of ``n`` rows of ``t`` tokens,
+    and nothing else: it is jitted, so JAX keeps its trace (the kernel's body:
+    two thirds of what tracing this module's chunk program costs) by the shapes
+    it is called with, and the loops' three runs then find it made. Made inside
+    the loop over the groups and the loop over a run's layers the same trace
+    costs three times as much Python on the chip's host (0.97 s against 0.31,
+    and three times that again beside a start-up's other threads), which a
+    start-up pays once a chunk program, warm compile cache or not: 10 s of 48
+    (PERF.md 6, PR 67). Called where the program is traced, it adds no equation
+    to it."""
+    d, k = c.d_inner, c.mamba_d_state
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+    above = (jax.ShapeDtypeStruct((n,), bool),) if handed else ()
+    jax.eval_shape(
+        lambda a_log, s, delta, x, b, cc, valid, *above: _scan_tokens(
+            {"a_log": a_log}, s, delta, x, b, cc, valid, *above),
+        f32(k, d), f32(n, k, d), f32(n, t, d), f32(n, t, d), f32(n, t, k), f32(n, t, k),
+        jax.ShapeDtypeStruct((n, t), bool), *above)
 
 
 def _convolve(lp: Params, c: JambaConfig, x: jax.Array, valid: jax.Array, tail: jax.Array,

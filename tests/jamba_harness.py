@@ -64,13 +64,17 @@ def engine(cfg, params):
 SPARE_BLOCKS = 8
 
 
-def dispatch_rows(cfg, params, dispatches, rows=8, slots=10, mb=8, n_decode=3, between=None, salt=None):
+def dispatch_rows(cfg, params, dispatches, rows=8, slots=10, mb=8, n_decode=3, between=None, salt=None,
+                  padding_token=0, hidden_rows=None):
     """Chunk dispatches of ``rows`` rows over ``slots`` slots, then ``n_decode``
     teacher-forced decode steps of every slot fed, off the state and pages the
     dispatches left. A dispatch is a list of its rows in order, ``(slot, n)``
     = the slot's next ``n`` prompt tokens (a lane's rows of one dispatch are
     its successive pieces) or ``None`` = a padding row; the rows left are
-    padding. The k-th slot fed has blocks ``1 + k * mb`` onwards, and the pool
+    padding, and every position that holds no prompt token holds
+    ``padding_token`` (under position -1). ``hidden_rows``: a list that takes
+    each dispatch's hidden states whole, padding rows and all. The k-th slot
+    fed has blocks ``1 + k * mb`` onwards, and the pool
     holds ``SPARE_BLOCKS`` more that no table names: no page but those a fed
     slot's tokens reach may be written, block 0 (where a padding row's table
     points) and the spare ones included, which is held here for every caller.
@@ -88,7 +92,7 @@ def dispatch_rows(cfg, params, dispatches, rows=8, slots=10, mb=8, n_decode=3, b
     at, hidden, sums = dict.fromkeys(fed, 0), {slot: [] for slot in fed}, []
     chunk = chunk_program(jamba, cfg)
     for d in dispatches:
-        toks, pos = np.zeros((rows, c), np.int32), np.full((rows, c), -1, np.int32)
+        toks, pos = np.full((rows, c), padding_token, np.int32), np.full((rows, c), -1, np.int32)
         tables, lanes = np.zeros((rows, mb), np.int32), np.full((rows,), slots, np.int32)
         for r, row in enumerate(d):
             if row is None:
@@ -103,6 +107,8 @@ def dispatch_rows(cfg, params, dispatches, rows=8, slots=10, mb=8, n_decode=3, b
         for r, row in enumerate(d):
             if row is not None:
                 hidden[row[0]].append(np.asarray(h[r, :row[1]], np.float32))
+        if hidden_rows is not None:
+            hidden_rows.append(np.asarray(h, np.float32))
         sums.append(dict(zip(jamba.COUNTERS, np.asarray(counted).tolist())))
         if between is not None:
             state = between(state)
@@ -119,7 +125,7 @@ def dispatch_rows(cfg, params, dispatches, rows=8, slots=10, mb=8, n_decode=3, b
         out = decode_program(jamba, cfg, n_decode, 8 * mb - 1)(  # teacher forcing: each sequence's own next token
             params, jnp.asarray(toks), jnp.asarray(pos), cache, jnp.asarray(lanes_tables), state, jnp.asarray(forcing))
         assert [int(out[1][slot]) for slot in fed] == [length[slot] + n_decode for slot in fed]
-        assert np.asarray(out[6]).tolist() == [n_decode * N_MAMBA, 0, 0, 0, 0]
+        assert np.asarray(out[6]).tolist() == [n_decode * N_MAMBA] + [0] * (len(jamba.COUNTERS) - 1)
         for slot in fed:
             logits[slot].append(np.asarray(out[3], np.float32)[:, slot])
         state, cache = out[5], out[4]

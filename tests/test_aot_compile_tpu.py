@@ -725,11 +725,14 @@ def test_jambas_step_programs_never_copy_the_slots_state(monkeypatch, one_chip, 
     why the steps are unrolled and a run's layers scan the state as ``xs`` /
     ``ys`` (PERF.md 6, PR 41). A chunk's rows gather and scatter their slots by
     one flat index on the layer loop's carry, and the view the chunk's kernel
-    wants is made of the rows' state, never of the slots'."""
+    wants is made of the rows' state, never of the slots'; the loop over a
+    dispatch's groups of rows carries the three runs' state around the layer
+    loops (PR 67), and that carry is no copy either."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernel compiled, not interpreted
     compiled = _compile_jamba(program, one_chip, rows)
     hlo = re.sub(r"/\*.*?\*/", "", compiled.as_text())
-    copies = re.findall(r"= f32\[\d+,64,(?:16,5120|15360)\]\{[^}]*\} copy\(", hlo)
+    # a run's state as the slot state holds it, ``[n, 64, ...]``, or flat as the chunk's loops carry it
+    copies = re.findall(r"= f32\[(?:\d+,64|448|832|384),(?:16,5120|15360)\]\{[^}]*\} copy\(", hlo)
     copies += re.findall(r"= bf16\[2,12288,16,1,128\]\{[^}]*\} copy\(", hlo)  # nor the pool
     assert copies == [], copies
     # both donated: the pool and the state come back in the buffers they came in
@@ -774,6 +777,38 @@ def test_jambas_decode_step_passes_over_a_layers_state_once(monkeypatch, one_chi
                 if re.search(r"\breduce\(", line) and "f32[64,16,5120]" in line]
 
 
+@pytest.mark.parametrize("rows", [8, 16])
+def test_jambas_chunk_program_is_one_loop_over_the_groups_around_the_three_run_loops(monkeypatch, one_chip, rows):
+    """The chunk program at the 8- and 16-row rungs: the entry computation holds
+    ONE loop, the one over the dispatch's groups of ``ROWS_AT_ONCE`` rows, whose
+    carry holds the three runs' state flat (``f32[n * 64, 16, 5120]``) and the
+    dispatch's hidden states, K and V; its body holds the three run loops, each
+    carrying its own run's state and with the chunk kernel in its body, and the
+    two attention layers' loops over history tiles and rows above. The two rungs
+    differ in the carry's rows and in nothing a group computes."""
+    from dynamo_tpu.models import jamba
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hlo = re.sub(r"/\*.*?\*/", "", _compile_jamba("chunk", one_chip, rows).as_text())
+    named = {m.group(1): block for block in hlo.split("\n\n")
+             if (m := re.match(r"\s*(?:ENTRY )?%([\w.\-]+) ", block))}
+    entry = next(block for block in named.values() if block.lstrip().startswith("ENTRY"))
+
+    def loops(block):  # (what the loop carries, its body's name) of every loop of a computation
+        return re.findall(r"= (\(.*\)) while\(.*body=%([\w.\-]+)", block)
+
+    (carried, groups), = loops(entry)
+    runs = [f"f32[{n * 64},16,5120]" for n in (7, 13, 6)]
+    assert all(state in carried for state in runs), carried[:300]
+    assert f"f32[{rows},128,2560]" in carried and f"bf16[2,{rows},128,1,128]" in carried
+    inner = loops(named[groups])
+    of_runs = [(shape, body) for shape, body in inner if "16,5120]" in shape]
+    assert len(of_runs) == 3 and len(inner) == 3 + 2 * 2, [body for _, body in inner]
+    for state, (shape, body) in zip(runs, of_runs):
+        assert state in shape and not any(other in shape for other in runs if other != state)
+        assert f"f32[{jamba.ROWS_AT_ONCE},128,2560]" in shape and "tpu_custom_call" in named[body]
+
+
 @pytest.mark.parametrize("rows", [8, 16, 64])
 def test_jambas_chunk_program_holds_a_rows_state_on_the_chip(monkeypatch, one_chip, rows):
     """The chunk program at the 8-, 16- and 64-row rungs of 64 slots (under the
@@ -781,15 +816,17 @@ def test_jambas_chunk_program_holds_a_rows_state_on_the_chip(monkeypatch, one_ch
     lane's rows in order, its state block indexed by a prefetched scalar):
     ``ops/pallas/selective_scan.py`` is in the compiled program, once a run of
     Mamba layers (its grid, its index maps, its SMEM blocks and its fast memory
-    are what interpret mode cannot see), and no loop carries the rows'
-    ``f32[rows,16,5120]`` state once a token through HBM, as the scan the kernel
-    replaced did."""
+    are what interpret mode cannot see), and no loop carries a group's rows'
+    ``f32[ROWS_AT_ONCE,16,5120]`` state once a token through HBM, as the scan
+    the kernel replaced did."""
+    from dynamo_tpu.models import jamba
+
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     hlo = re.sub(r"/\*.*?\*/", "", _compile_jamba("chunk", one_chip, rows).as_text())
     kernels = re.findall(r"custom-call\([^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
     assert len(kernels) == 3 and "selective_scan" in hlo, len(kernels)
     carried = [line.strip()[:160] for line in hlo.splitlines()
-               if re.search(r"\bwhile\(", line) and f"f32[{rows},16,5120]" in line]
+               if re.search(r"\bwhile\(", line) and f"f32[{jamba.ROWS_AT_ONCE},16,5120]" in line]
     assert carried == [], carried
 
 

@@ -370,7 +370,7 @@ def test_the_engine_serves_the_reference_greedy_tokens_and_logprobs(engine, para
     # the module says what its programs read of the tables: the live part, not all
     assert 0 < snap["chunk_history_tiles_read"] <= snap["chunk_history_tiles_full"]
     # the module says a lane may fill several rows; this ladder, [1, 4], has no rung that holds them
-    assert jamba.LANE_TAKES_ROWS and engine._lane_rows and len(jamba.COUNTERS) == 5
+    assert jamba.LANE_TAKES_ROWS and engine._lane_rows and len(jamba.COUNTERS) == 6
     assert snap["chunk_rows_live"] == snap["chunk_lanes_fed"] > 0 == snap["ssm_state_handovers"]
     assert 0 < snap["decode_history_tiles_read"] <= snap["decode_history_tiles_full"]
     assert set(engine.cache) == {"k", "v"} and engine.cache["k"].shape[0] == 2
@@ -378,23 +378,53 @@ def test_the_engine_serves_the_reference_greedy_tokens_and_logprobs(engine, para
     assert tiers and all(t == {"tier": "dense", "interpret": False} for t in tiers)
 
 
-def test_the_counters_count_what_a_served_prompt_did(engine):
-    """A prompt of 40 tokens and 4 answered: every one of the four Mamba layers
-    advances the 40 prompt tokens in the chunk program, and the row's state goes
-    to the chip and back once a layer and dispatch (the kernel holds it there
-    over the dispatch's tokens); three chunk dispatches and the decode steps
-    each run the four layers."""
+@pytest.fixture(scope="module")
+def wide_engine(cfg, params):
+    """64 slots: the ladder [8, 16, 64], where a lane fills several rows of a dispatch."""
+    eng = JaxServingEngine(cfg, params, EngineConfig(
+        max_slots=64, kv_block_size=8, max_model_len=96, prefill_chunk=16, decode_steps=4))
+    yield eng
+    eng.close()
+
+
+# (the engine; prompt tokens; chunk dispatches; rows dispatched; rows that hold a piece; lanes fed; groups
+# the chunk program ran; their rows)
+SERVED = {
+    # ladder [1, 4]: a row a dispatch, and a rung of one row is one group
+    "a_row_a_dispatch": ("engine", 40, 3, 3, 3, 3, 3, 3),
+    # ladder [8, 16, 64], the 8-row rung: three rows are one group of four, five rows two groups (as a
+    # prompt of 300 tokens and one of 600 in chunks of 128), the fifth row going on from its slot's entries
+    "three_rows_of_an_eight_row_rung": ("wide_engine", 40, 1, 8, 3, 1, 1, 4),
+    "five_rows_of_an_eight_row_rung": ("wide_engine", 75, 1, 8, 5, 1, 2, 8),
+}
+
+
+@pytest.mark.parametrize("case", list(SERVED))
+def test_the_counters_count_what_a_served_prompt_did(request, case):
+    """A prompt and 4 tokens answered: every one of the four Mamba layers
+    advances the prompt's tokens in the chunk program, and the lane's state goes
+    to the chip and back once a layer and GROUP of a dispatch's rows it has a
+    row in (the kernel holds it there over the group's tokens); the groups and
+    the decode steps each run the four layers. ``chunk_rows_computed`` rises by
+    the rows of the groups the chunk program ran, as far as the last row that
+    holds a piece, and not at all in a decode dispatch."""
+    which, n, dispatches, dispatched, rows, lanes, groups, computed = SERVED[case]
+    engine = request.getfixturevalue(which)
     before = engine.metrics_snapshot()
-    served(engine, prompt_of(40, salt=11), 4)
+    served(engine, prompt_of(n, salt=11), 4)
     after = engine.metrics_snapshot()
-    rise = {k: after[k] - before[k] for k in jamba.COUNTERS}
-    assert (rise["ssm_chunk_tokens"], rise["ssm_state_passes"]) == (N_MAMBA * 40, N_MAMBA * 3)
+    rise = {k: after[k] - before[k] for k in (
+        *jamba.COUNTERS, "chunk_rows_live", "chunk_lanes_fed", "chunk_rows_dispatched", "prompt_dispatches")}
+    assert (rise["prompt_dispatches"], rise["chunk_rows_dispatched"]) == (dispatches, dispatched)
+    assert (rise["chunk_rows_live"], rise["chunk_lanes_fed"]) == (rows, lanes)
+    assert (rise["ssm_chunk_tokens"], rise["ssm_state_passes"]) == (N_MAMBA * n, N_MAMBA * groups)
     assert rise["slot_state_resets"] == 1
-    # a row a lane on this ladder: the rows that took their state from the row above, none
-    rows, lanes = (after[k] - before[k] for k in ("chunk_rows_live", "chunk_lanes_fed"))
-    assert rise["ssm_state_handovers"] == rows - lanes == 0 and rows == 3
-    # 3 chunk dispatches + the decode dispatches' 4 steps each (3 more tokens: 1 or 2 dispatches)
-    assert rise["ssm_layer_calls"] in (N_MAMBA * (3 + 4), N_MAMBA * (3 + 8))
+    # the rows that took their state from the row above them in their group
+    assert rise["ssm_state_handovers"] == rows - groups
+    # the groups + the decode dispatches' 4 steps each (3 more tokens: 1 or 2 dispatches), which computed
+    # no chunk row
+    assert rise["ssm_layer_calls"] in (N_MAMBA * (groups + 4), N_MAMBA * (groups + 8))
+    assert rise["chunk_rows_computed"] == computed
 
 
 def test_a_reused_slot_gives_what_the_request_gives_alone(engine, cfg, params):

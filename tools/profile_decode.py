@@ -9,8 +9,9 @@ by routing and by grouped product, beside its own roofline
 over a chunk, the scan over the tokens beside the kernel that keeps the state
 on the chip (:func:`kda_profiles`); ``... mamba`` times a Mamba layer's
 selective scan ALONE over a chunk and over a decode step beside the whole mixer
-(:func:`mamba_profiles`); ``... mla`` times ONE layer's absorbed latent
-attention ALONE at ``batch.openpangu-ultra-moe-718b``'s two shapes, beside its
+(:func:`mamba_profiles`); ``... groups`` times ``jamba2-3b``'s chunk program with
+its rows in groups of 2, 4 and 8 (:func:`chunk_group_profiles`); ``... mla``
+times ONE layer's absorbed latent attention ALONE at ``batch.openpangu-ultra-moe-718b``'s two shapes, beside its
 bytes and operations (:func:`mla_profiles`); ``... mhc`` times the residual
 path of ONE sublayer of ``xing4.0-29b-a4b`` ALONE, beside the bytes it must
 move (:func:`mhc_profiles`); ``... swa`` times ONE window layer's attention of
@@ -879,11 +880,12 @@ LANE_LAYOUTS = {
 }
 
 
-def chunk_program_profile(jamba, d_inner):
-    """``jamba2-3b``'s whole chunk program ALONE at the cell's shapes (64 slots,
-    12,288 blocks of 16, tables of 2,048 positions, random bf16 weights) at the
-    layouts of ``LANE_LAYOUTS``, every row full: ms a dispatch (the median of
-    five), beside the same rows a piece of every lane a dispatch."""
+def chunk_program_at_the_cell(jamba, d_inner):
+    """``jamba2-3b``'s whole chunk program at the cell's shapes (64 slots,
+    12,288 blocks of 16, tables of 2,048 positions, random bf16 weights):
+    (params, pool, state, ``dispatch(rows, pieces)`` -> the arrays of a dispatch
+    of ``rows`` rows, ``pieces`` = (lane, which full piece of its prompt) a row
+    from the top, the rest padding)."""
     c = jamba.JambaConfig(hidden_size=d_inner // 2)
     slots, mb, t = 64, 128, 128
     params = jax.jit(lambda: jamba.init_params(jax.random.PRNGKey(0), c))()
@@ -891,11 +893,7 @@ def chunk_program_profile(jamba, d_inner):
     state = jax.tree.map(lambda a: 0.1 * jax.random.normal(jax.random.PRNGKey(1), a.shape, a.dtype),
                          jamba.make_slot_state(c, slots))
 
-    mine = jax.jit(lambda p, kv, st, toks, pos, tables, lanes: jamba.forward_chunk(
-        p, c, toks, pos, kv, tables, st, lanes))
-
     def dispatch(rows, pieces):
-        """The arrays of a dispatch of ``rows`` rows: ``pieces`` = (lane, which piece of its prompt)."""
         toks = np.zeros((rows, t), np.int32)
         pos = np.full((rows, t), -1, np.int32)
         tables, lanes = np.zeros((rows, mb), np.int32), np.full((rows,), slots, np.int32)
@@ -905,6 +903,19 @@ def chunk_program_profile(jamba, d_inner):
             tables[r], lanes[r] = 1 + lane * mb + np.arange(mb), lane
         return tuple(jnp.asarray(a) for a in (toks, pos, tables, lanes))
 
+    def program(donate=()):  # traced when first called: under the ``ROWS_AT_ONCE`` of that moment
+        return jax.jit(lambda p, kv, st, toks, pos, tables, lanes: jamba.forward_chunk(
+            p, c, toks, pos, kv, tables, st, lanes), donate_argnums=donate)
+
+    return params, cache, state, dispatch, program
+
+
+def chunk_program_profile(jamba, d_inner):
+    """``jamba2-3b``'s whole chunk program ALONE at the cell's shapes at the
+    layouts of ``LANE_LAYOUTS``, every row full: ms a dispatch (the median of
+    five), beside the same rows a piece of every lane a dispatch."""
+    params, cache, state, dispatch, program = chunk_program_at_the_cell(jamba, d_inner)
+    mine = program()
     for rows, named in LANE_LAYOUTS.items():
         for name, sizes in named.items():
             once = dispatch(rows, [(lane, k) for lane, m in enumerate(sizes) for k in range(m)])
@@ -922,6 +933,62 @@ def chunk_program_profile(jamba, d_inner):
                          f"state they leave: the first run of Mamba layers {largest(st['s'][0], st_p['s'][0]):.3g}, "
                          f"all {largest(st, st_p):.3g} of {largest(st_p, jax.tree.map(jnp.zeros_like, st_p)):.3g}")
             print(line, flush=True)
+
+
+def chunk_group_profiles():
+    """What chose ``models/jamba.py:ROWS_AT_ONCE`` (PR 67): ``jamba2-3b``'s chunk
+    program at the cell's shapes, the 8-row rung, with the module's groups at
+    each of PROF_ROWS' heights (default 2,4,8), one line a height: a group
+    ALONE (the rung's first ``height`` rows hold full pieces of one prompt, so
+    the loop makes one trip) in ms a group and ms a row, then all 8 rows full
+    (8 / height groups; two lanes of four rows, so a lane straddles the groups
+    of 2) and the 16-row rung with 12 rows full (three lanes of four). Then the
+    module as it is at the rows a prompt of the cell's traffic fills (1-8 of 8,
+    9-16 of 16): ms a dispatch by live rows, which is what the engine's host
+    step waits for. PROF_DINNER (default 5120) cuts the width for a rehearsal
+    on the CPU."""
+    from dynamo_tpu.engine_jax.compile_cache import enable_compile_cache
+    from dynamo_tpu.models import jamba
+
+    enable_compile_cache()
+    params, cache, state, dispatch, program = chunk_program_at_the_cell(
+        jamba, int(os.environ.get("PROF_DINNER", "5120")))
+
+    def lanes_of_four(live):
+        return [(r // 4, r % 4) for r in range(live)]
+
+    def ms_of(mine, once) -> float:
+        """ms a dispatch, the median of five after one that compiles, the pool and the state DONATED as
+        the engine donates them (each call takes what the last one left: the same pieces over again)."""
+        _, kv, st, _ = mine(params, jax.tree.map(jnp.copy, cache), jax.tree.map(jnp.copy, state), *once)
+        times = []
+        for _ in range(5):
+            jax.block_until_ready(st)
+            t0 = time.perf_counter()
+            _, kv, st, _ = mine(params, kv, st, *once)
+            jax.block_until_ready(st)
+            times.append(time.perf_counter() - t0)
+        return float(np.median(times)) * 1e3
+
+    chosen = jamba.ROWS_AT_ONCE
+    for height in (int(x) for x in os.environ.get("PROF_ROWS", "2,4,8").split(",")):
+        jamba.ROWS_AT_ONCE = height
+        mine = program(donate=(1, 2))
+        alone = ms_of(mine, dispatch(8, lanes_of_four(height)))
+        full = ms_of(mine, dispatch(8, lanes_of_four(8)))
+        line = (f"chunk groups of {height} rows: a group alone {alone:8.3f} ms, {alone / height:7.3f} ms a row; "
+                f"8 full rows of 8 in {8 // height} groups {full:8.3f} ms, {full / 8:7.3f} ms a row")
+        if height < 8:  # a loop over groups of 8 rows aborts the chip's compiler: the 8-row rung at once is all of it
+            twelve = ms_of(mine, dispatch(16, lanes_of_four(12)))
+            line += (f"; 12 full rows of 16 in {-(-12 // height)} groups {twelve:8.3f} ms, "
+                     f"{twelve / 12:7.3f} ms a row")
+        print(line, flush=True)
+    jamba.ROWS_AT_ONCE = chosen
+    mine = program(donate=(1, 2))
+    for rows, fewest in ((8, 1), (16, 9)):
+        by_live = [ms_of(mine, dispatch(rows, lanes_of_four(live))) for live in range(fewest, rows + 1)]
+        print(f"chunk program as it is (groups of {chosen}), {rows:2d}-row rung, ms a dispatch by live rows "
+              f"{fewest}..{rows}: " + " ".join(f"{ms:.2f}" for ms in by_live), flush=True)
 
 
 def mla_profiles():
@@ -1166,4 +1233,5 @@ def swa_profiles():
 
 if __name__ == "__main__":
     {"history": history_profiles, "experts": expert_profiles, "kda": kda_profiles,
-     "mamba": mamba_profiles, "mla": mla_profiles, "mhc": mhc_profiles, "swa": swa_profiles}.get(" ".join(sys.argv[1:2]), main)()
+     "mamba": mamba_profiles, "groups": chunk_group_profiles, "mla": mla_profiles, "mhc": mhc_profiles,
+     "swa": swa_profiles}.get(" ".join(sys.argv[1:2]), main)()
