@@ -571,8 +571,14 @@ class JaxServingEngine(AsyncEngine):
         self.model = module_for(model_config)
         self._own_programs = hasattr(self.model, "COUNTERS")
         self._slot_model = hasattr(self.model, "make_slot_state")
+        # - ``SERVES_ON_MESH``: its own programs, its pool and its state run
+        #   sharded: it is handed the mesh (``mesh=``) wherever it makes or
+        #   steps them, and lays itself over it. Any other module with its
+        #   own programs is refused a mesh.
+        self._on_mesh = {"mesh": mesh} if mesh is not None and self._own_programs else {}
         if self._own_programs and (
-            mesh is not None or engine_config.quantize
+            (mesh is not None and not getattr(self.model, "SERVES_ON_MESH", False))
+            or engine_config.quantize
             or (engine_config.kv_dtype or env_kv_dtype()) == "int8"
         ):
             raise ValueError(
@@ -728,7 +734,7 @@ class JaxServingEngine(AsyncEngine):
         # (and the decode window buffers are allocated in it — never in the
         # pool's storage dtype)
         self._compute_dtype = cdtype
-        if mesh is not None:
+        if mesh is not None and not self._own_programs:
             from dynamo_tpu.parallel.mesh import kv_cache_sharding
 
             sh = kv_cache_sharding(mesh)
@@ -743,18 +749,27 @@ class JaxServingEngine(AsyncEngine):
             )
             self.cache = make()
         else:
-            # the caller's dtype or the module's own (llama: the model's)
+            # the caller's dtype or the module's own (llama: the model's);
+            # a module that serves on a mesh makes its pool in its shardings
             self.cache = self.model.make_kv_cache(
                 model_config, self.num_blocks, engine_config.kv_block_size,
                 dtype=cache_dtype, quantized=self._kv_quantized,
                 # the prediction module's own pages, only where it runs
                 **({"drafting": True} if self._device_drafts else {}),
+                **self._on_mesh,
             )
         # the slots' state, one value the model module owns: the step
         # programs take it and hand it back, nothing here looks inside
         self.slot_state = (
-            self.model.make_slot_state(model_config, engine_config.max_slots)
+            self.model.make_slot_state(
+                model_config, engine_config.max_slots, **self._on_mesh)
             if self._slot_model else None
+        )
+        # what of that state ONE chip holds (a module that serves on a mesh
+        # shards it; /debug/engine shows it beside the module's counters)
+        self._slot_state_bytes_a_chip = sum(
+            math.prod(a.sharding.shard_shape(a.shape)) * a.dtype.itemsize
+            for a in jax.tree.leaves(self.slot_state)
         )
         # sums a module's own programs return (its COUNTERS), added up by
         # the host as their dispatches are fetched
@@ -1268,7 +1283,7 @@ class JaxServingEngine(AsyncEngine):
 
                 toks, pos, counts, out, cache, state, sums, *drafts = self.model.decode(
                     params, cfg, tokens, positions, cache, tables, state,
-                    k_steps, max_pos, sample, counts, **drafting,
+                    k_steps, max_pos, sample, counts, **drafting, **self._on_mesh,
                 )
                 return (*slot_major(out), toks, pos, sums, *drafts, cache, state, counts)
 
@@ -1532,6 +1547,7 @@ class JaxServingEngine(AsyncEngine):
                 inputs = sampling_inputs(step_ctr, ipack, fpack)
                 h, cache, state, sums = self.model.forward_chunk(
                     params, cfg, tokens, positions, cache, tables, state, lanes,
+                    **self._on_mesh,
                 )
                 fetch, counts = sample_rows(
                     params, h, counts, sample_at, lanes, inputs, wdf
@@ -1896,9 +1912,11 @@ class JaxServingEngine(AsyncEngine):
         alone (zero trips of its loop is the no-history case; a sampled
         variant at a small rung compiles at first use, like lp/pen).
 
-        Mesh engines keep the executing warmup: AOT avals would need the
-        exact input shardings, and on a multi-process mesh the warmup
-        executions themselves must run in leader/follower lockstep.
+        Mesh engines of ``models/llama.py``'s programs keep the executing
+        warmup: on a multi-process mesh the warmup executions themselves
+        must run in leader/follower lockstep. A module that serves its own
+        programs on a mesh compiles ahead as one device does, from avals
+        that carry each array's sharding.
         Returns per-variant compile seconds (``setup_phase_s`` has the
         start-up's phases by name)."""
         cfg = self.config
@@ -1945,7 +1963,7 @@ class JaxServingEngine(AsyncEngine):
                 )))
             timings["take_blocks"] = round(time.perf_counter() - t0, 2)
 
-        if self.mesh is not None:
+        if self.mesh is not None and not self._own_programs:
             def packs(rows):
                 fpack = np.zeros((4, rows), np.float32)
                 fpack[1] = 1.0  # top_p
@@ -1993,18 +2011,30 @@ class JaxServingEngine(AsyncEngine):
             warm_sealing()
             return done()
 
-        def sd(shape, dtype):
-            return jax.ShapeDtypeStruct(shape, dtype)
+        # a module that serves on a mesh compiles here too: what lives on
+        # the device is described in the sharding it has, what the host
+        # makes as whole on every device (one process: `_put` is a plain
+        # transfer, and the program takes it where it was compiled to)
+        where = {}
+        if self.mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
 
-        p_sd = jax.tree.map(lambda a: sd(a.shape, a.dtype), self.params)
-        pd_sd = jax.tree.map(
-            lambda a: sd(a.shape, a.dtype), self.params_decode
-        )
-        cache_sd = jax.tree.map(lambda a: sd(a.shape, a.dtype), self.cache)
+            where = {"sharding": NamedSharding(self.mesh, PartitionSpec())}
+
+        def sd(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, **where)
+
+        def like(a):
+            if self.mesh is None:
+                return sd(a.shape, a.dtype)
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding)
+
+        p_sd = jax.tree.map(like, self.params)
+        pd_sd = jax.tree.map(like, self.params_decode)
+        cache_sd = jax.tree.map(like, self.cache)
         # the pool, and behind it the slots' state of a module's own programs
-        pool_sd = (cache_sd,) + ((jax.tree.map(
-            lambda a: sd(a.shape, a.dtype), self.slot_state
-        ),) if self._own_programs else ())
+        pool_sd = (cache_sd,) + ((jax.tree.map(like, self.slot_state),)
+                                 if self._own_programs else ())
         counts_sd = jax.tree.map(
             lambda a: sd(a.shape, a.dtype), self._dummy_counts
         )
@@ -3260,9 +3290,12 @@ class JaxServingEngine(AsyncEngine):
             bs, MB = cfg.kv_block_size, cfg.max_blocks_per_seq
             full = S * history_tiles_full(bs, MB)
             self.decode_history_tiles_full += full
-            # a mesh engine gathers every table's full width; one device what
-            # its module says (the live pairs, or every table whole)
-            self.decode_history_tiles_read += full if self.mesh is not None else int(
+            # `models/llama.py`'s programs on a mesh gather every table's full
+            # width; else what the module says (the live pairs, or every table
+            # whole), a shard of a module that serves on a mesh as one device
+            self.decode_history_tiles_read += full if (
+                self.mesh is not None and not self._own_programs
+            ) else int(
                 self.model.decode_history_tiles(
                     np.where(self._positions < 0, -1, self._positions + ahead),
                     bs, MB,
@@ -4581,12 +4614,13 @@ class JaxServingEngine(AsyncEngine):
             "chunk_history_tiles_full": self.chunk_history_tiles_full,
             # the same of the decode program: (lane, tile) slots of history
             # read over the slots of every table's full width (a mesh engine
-            # reads them all)
+            # of `models/llama.py`'s programs reads them all)
             "decode_history_tiles_read": self.decode_history_tiles_read,
             "decode_history_tiles_full": self.decode_history_tiles_full,
             # a slot model's own sums (its module's COUNTERS; none otherwise)
             # and the prefix hits it declined for want of the slot's state
             **self.model_counters,
+            "slot_state_bytes_a_chip": self._slot_state_bytes_a_chip,
             "prefix_hits_declined": self.prefix_hits_declined,
             # how full the chunk dispatches are (cumulative): positions
             # computed (rows x prefill_chunk) and the prompt tokens among

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import glob
 import logging
+import math
 import os
 from typing import Any, Dict, Optional
 
@@ -397,6 +398,8 @@ def config_from_card(card: ModelDeploymentCard, dtype: Any = jnp.bfloat16):
         return xing4_config(mc, dtype)
     if mc.get("model_type") == "afmoe":
         return afmoe_config(mc, dtype)
+    if mc.get("model_type") == "mellum":
+        return mellum_config(mc, dtype)
     if "num_experts" in mc and "num_local_experts" not in mc:
         # an expert model of a family this tree has no module for: a
         # LlamaConfig of it would be a dense impostor under its name
@@ -439,6 +442,77 @@ def _hf_tensors(model_path: str) -> Optional[Dict[str, np.ndarray]]:
             for name in sf.keys():
                 out[name] = sf.get_tensor(name)
     return out
+
+
+def mellum_config(mc: Dict[str, Any], dtype: Any = jnp.bfloat16):
+    """``model_type: mellum`` (Mellum 2): window attention layers beside full
+    ones in whole periods, both rotated, each kind by its own section of
+    ``rope_parameters`` (read from the published nested group where the card
+    has it, else from its flat spelling ``rope_parameters_<kind>_<key>``: a
+    harness that writes only scalar and list keys); softmax-routed experts in
+    every layer, renormalised, all of them served (over the shards of a mesh:
+    what the mesh axis has to divide is held at engine build, by name, in
+    ``models/mellum.py``). What that module does not compute is refused by
+    name."""
+    from dynamo_tpu.models.mellum import FULL, WINDOW, MellumConfig
+
+    def refuse(key: str, why: str, value=None):
+        raise ValueError(f"model_type 'mellum' with {key} = {mc.get(key, value)!r}: models/mellum.py {why}")
+
+    layers = int(mc["num_hidden_layers"])
+    if list(mc.get("mlp_layer_types") or ["sparse"] * layers) != ["sparse"] * layers:
+        refuse("mlp_layer_types", f"runs {layers} (num_hidden_layers) 'sparse' expert layers and no dense one")
+    kinds = tuple(str(kind) for kind in mc.get("layer_types") or ())
+    period = kinds[:kinds.index(FULL) + 1] if FULL in kinds else ()
+    if (len(kinds) != layers or set(kinds) - {WINDOW, FULL} or len(period) < 2
+            or kinds != period * (layers // len(period))):
+        refuse("layer_types", f"wants {layers} (num_hidden_layers) in whole periods of one or more "
+                              f"{WINDOW!r} and then one {FULL!r}")
+    rope = mc.get("rope_parameters") or {kind: {
+        k[len(f"rope_parameters_{kind}_"):]: v for k, v in mc.items()
+        if k.startswith(f"rope_parameters_{kind}_")} for kind in (WINDOW, FULL)}
+    if not rope.get(WINDOW) or not rope.get(FULL):
+        refuse("rope_parameters", f"rotates by a section for {WINDOW!r} and one for {FULL!r}", rope)
+    if rope[WINDOW].get("rope_type", "default") != "default":
+        refuse("rope_parameters", f"rotates its {WINDOW!r} layers by rope_theta alone (rope_type default)", rope)
+    if rope[FULL].get("rope_type") != "yarn":
+        refuse("rope_parameters", f"rotates its {FULL!r} layers by YaRN's table (rope_type yarn)", rope)
+    if float(rope[WINDOW]["rope_theta"]) != float(rope[FULL]["rope_theta"]):
+        refuse("rope_parameters", "keeps one rope_theta for both kinds of layer", rope)
+    if not mc.get("norm_topk_prob", True):
+        refuse("norm_topk_prob", "renormalises the chosen experts' probabilities (true)")
+    if mc.get("attention_bias"):
+        refuse("attention_bias", "has no bias in its attention projections")
+    if mc.get("tie_word_embeddings"):
+        refuse("tie_word_embeddings", "has a head of its own (untied)")
+    if not mc.get("use_sliding_window", True):
+        refuse("use_sliding_window", f"holds its {WINDOW!r} layers to sliding_window (true)")
+    heads = int(mc["num_attention_heads"])
+    if heads % int(mc.get("num_key_value_heads", heads)):
+        refuse("num_key_value_heads", f"groups whole numbers of its {heads} query heads over a KV head")
+    yarn = rope[FULL]
+    factor = float(yarn["factor"])
+    return MellumConfig(
+        vocab_size=int(mc["vocab_size"]),
+        hidden_size=int(mc["hidden_size"]),
+        num_layers=layers,
+        num_heads=heads,
+        num_kv_heads=int(mc.get("num_key_value_heads", heads)),
+        head_dim=int(mc["head_dim"]),
+        layer_types=kinds,
+        sliding_window=int(mc["sliding_window"]),
+        rope_theta=float(yarn["rope_theta"]),
+        yarn_factor=factor,
+        yarn_original_positions=int(yarn["original_max_position_embeddings"]),
+        yarn_beta_fast=float(yarn.get("beta_fast", 32.0)),
+        yarn_beta_slow=float(yarn.get("beta_slow", 1.0)),
+        attention_factor=float(yarn.get("attention_factor", 0.1 * math.log(factor) + 1.0)),
+        moe_intermediate_size=int(mc["moe_intermediate_size"]),
+        num_experts=int(mc["num_experts"]),
+        num_experts_per_tok=int(mc["num_experts_per_tok"]),
+        rms_norm_eps=float(mc.get("rms_norm_eps", 1e-6)),
+        dtype=dtype,
+    )
 
 
 def load_params(

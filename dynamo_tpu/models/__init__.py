@@ -92,6 +92,17 @@ def module_for(model_config):
     what a window layer's ring holds), and the engine deals a lane no more at
     any rung.
 
+    A module with its own programs runs on ONE device unless it says it serves
+    on a mesh: ``SERVES_ON_MESH = True`` (``models/mellum.py``, alone). The
+    engine then hands it the mesh by keyword, ``mesh=``, where it makes the pool
+    and the slots' state (``make_kv_cache``, ``make_slot_state``: every leaf
+    created in its sharding) and where it calls ``forward_chunk`` and
+    ``decode``; ``param_shardings(config, mesh)`` returns real shardings; how
+    the module lays itself over the mesh, and every collective, is the
+    module's own (there: one ``shard_map`` a step program over the mesh's one
+    axis larger than 1). The other modules' calls carry no such keyword, and
+    they are refused a mesh before anything is made on a device.
+
     A config that is no ``LlamaConfig`` was made by its own module's class
     (``engine_jax/weights.py:config_from_card`` imports that module in its
     branch), so the module is the one already loaded: serving one model

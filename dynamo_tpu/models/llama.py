@@ -186,8 +186,16 @@ def param_logical_axes(config: LlamaConfig) -> Params:
 
 
 def param_shardings(config: LlamaConfig, mesh) -> Params:
-    """NamedSharding pytree matching init_params' structure."""
+    """NamedSharding pytree matching init_params' structure. A configuration
+    that is no ``LlamaConfig`` is handed on to its own module's function
+    (``benchmark/reference_child.py`` imports THIS one by name for every
+    model: ROADMAP B11 takes the opening out again)."""
     from dynamo_tpu.parallel.mesh import logical_to_sharding
+
+    if not isinstance(config, LlamaConfig):
+        from dynamo_tpu.models import module_for
+
+        return module_for(config).param_shardings(config, mesh)
 
     return jax.tree.map(
         lambda ax: logical_to_sharding(mesh, *ax),

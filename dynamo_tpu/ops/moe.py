@@ -260,7 +260,19 @@ def rows_per_tile(n_tokens: int, top_k: int, num_experts_total: int) -> int:
     return min(64, 16 * max(1, math.ceil(even / 16)))
 
 
-@functools.partial(jax.jit, static_argnames=("first_expert", "num_experts_total", "parts_of"))
+def shard_pairs(ids: jax.Array, valid: jax.Array, experts_a_shard: int, shards: int) -> jax.Array:
+    """``[shards]`` int32: of the pairs ``ids`` ``[T, k]`` routes (tokens not
+    ``valid`` ``[T]`` route none), how many go to each shard of a layer whose
+    experts lie ``experts_a_shard`` a shard in order of their ids (expert
+    parallelism: ``models/mellum.py``). The router is whole on every shard, so
+    every shard counts every shard's pairs and no collective carries them; the
+    shard with the most is the one the layer's all-reduce waits for."""
+    shard = jnp.where(valid[:, None], ids // experts_a_shard, shards).reshape(-1)
+    return jnp.zeros((shards + 1,), jnp.int32).at[shard].add(1)[:shards]
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "first_expert", "num_experts_total", "parts_of", "experts_a_layer"))
 def dropless_experts(
     x: jax.Array,  # [T, E] float32
     ids: jax.Array,  # [T, k] expert ids over ALL experts
@@ -273,6 +285,8 @@ def dropless_experts(
     num_experts_total: Optional[int] = None,
     token_valid: Optional[jax.Array] = None,  # [T] bool; False = padding
     parts_of: Callable[[jax.Array, Any], List[jax.Array]] = _parts,
+    stacked_at: Optional[jax.Array] = None,  # the layer of a stack ``[L * X, ...]`` of weights
+    experts_a_layer: Optional[int] = None,  # X of such a stack
 ) -> Tuple[jax.Array, jax.Array]:
     """``sum over the chosen experts held here of weight * E_e(x)`` for every
     token, ``[T, E]``, and the counters ``[6]`` int32 (1, pairs computed here,
@@ -292,9 +306,16 @@ def dropless_experts(
     bfloat16 parts, models/kimi_linear.py). The last two counters are the
     products' schedule: its visits x the rows of a tile (a tile that spans
     several runs is computed once a run), and the runs it holds (the tiles of
-    a run follow each other, so a hit expert's matrix is read once)."""
+    a run follow each other, so a hit expert's matrix is read once).
+
+    A caller whose layers' weights are ONE stack (a ``lax.scan`` over layers:
+    ``models/mellum.py``) hands the stack whole, ``[L * experts_a_layer, ...]``,
+    and the layer this call computes as ``stacked_at`` (traced): the products'
+    schedule then names the layer's experts where they lie in the stack, and
+    nothing is sliced out of it (a slice of a stack in front of the kernel is a
+    copy of the layer's matrices on the chip: ``models/lfm2.py``)."""
     t, k = ids.shape
-    x_held = w_gate.shape[0]
+    x_held = experts_a_layer or w_gate.shape[0]
     n_pairs = t * k
     r = rows_per_tile(t, k, num_experts_total or x_held)
     local = ids - first_expert
@@ -308,6 +329,14 @@ def dropless_experts(
     rows = -(-n_pairs // r) * r  # whole tiles
     token_of = jnp.pad((order // k).astype(jnp.int32), (0, rows - n_pairs))
     schedule = make_schedule(counts, rows, r)
+    visits = schedule[3]
+    if stacked_at is not None:
+        # the layer's experts are groups `at` .. `at + x_held - 1` of the stack: the visits name
+        # them there, and the rows' offsets stand where the kernel looks a group's up
+        at = stacked_at * x_held
+        offsets = jax.lax.dynamic_update_slice(
+            jnp.zeros((w_gate.shape[0] + 1,), jnp.int32), schedule[0], (at,))
+        schedule = (offsets, schedule[1] + at, schedule[2], visits)
 
     def product(a, w):
         """Sorted rows ``a`` ``[rows, K]`` float32, each against its expert's
@@ -325,5 +354,5 @@ def dropless_experts(
     routed = (jnp.sum(token_valid) if token_valid is not None else t) * k
     hit = jnp.sum(counts > 0)
     stats = jnp.stack([jnp.int32(1), counts.sum(), hit, jnp.asarray(routed, jnp.int32),
-                       schedule[3] * r, hit]).astype(jnp.int32)
+                       visits * r, hit]).astype(jnp.int32)
     return y.astype(x.dtype), stats

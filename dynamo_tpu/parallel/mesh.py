@@ -10,8 +10,18 @@ engines, SURVEY.md §2.12 — here they are native):
 - ``sp``: sequence/context parallel — ring-attention axis for long context
   (parallel/ring_attention.py; a TPU-native extension — the reference has
   none, SURVEY.md §2.12)
-- ``ep``: expert parallel — MoE expert axis (ops/moe.py GShard-style
-  dispatch/combine; the reference has no EP either, SURVEY.md §2.12)
+- ``ep``: expert parallel — the expert axis of ``ops/moe.py:moe_mlp``, the
+  GShard-style layer with a fixed capacity (``models/llama.py``'s Mixtral
+  form; the reference has no EP either, SURVEY.md §2.12). A caller that
+  builds a ``MeshConfig`` itself sizes it; ``build_jax_serving_engine`` sizes
+  NO ``ep`` (its flags are tp, pp, sp and dp), so on the served path the rule
+  ``experts`` below resolves to "replicated". The DROPLESS layer
+  (``ops/moe.py:dropless_experts``) does not go through these rules: a module
+  that serves it on a mesh lays its experts over the mesh's one axis larger
+  than 1 (:func:`model_axis`: ``--tensor-parallel-size 4`` gives ``tp``), the
+  axis its heads lie over, and sums the shards' parts itself
+  (``models/mellum.py``). An axis of experts beside a data-parallel attention
+  (the all-to-all form) is ROADMAP M1's remainder.
 
 The design follows the standard JAX recipe: pick a mesh, annotate shardings
 with PartitionSpec, let XLA insert the collectives over ICI.
@@ -81,7 +91,7 @@ _LOGICAL_RULES = {
     "kv_heads": AXIS_TP,  # attention kv heads (GQA)
     "mlp": AXIS_TP,  # MLP intermediate dim
     "vocab": AXIS_TP,  # embedding/unembedding vocab dim
-    "experts": AXIS_EP,  # MoE expert axis (ops/moe.py)
+    "experts": AXIS_EP,  # ops/moe.py:moe_mlp's expert axis; no CLI flag sizes ep (header)
     "embed": None,  # model dim: replicated (Megatron-style TP)
     "kv_blocks": None,  # paged-KV physical block axis: replicated across tp
 }
@@ -103,6 +113,19 @@ def logical_to_sharding(mesh: Mesh, *logical_axes: Optional[str]) -> NamedShardi
         else:
             spec.append(None)
     return NamedSharding(mesh, P(*spec))
+
+
+def model_axis(mesh: Mesh) -> Tuple[str, int]:
+    """(name, size) of the mesh's ONE axis larger than 1: what a module that
+    writes its own collectives lays its heads, experts and vocabulary over
+    (``models/mellum.py``). A mesh with several is refused by name: such a
+    module's programs are one ``shard_map`` over one axis."""
+    large = [(name, size) for name, size in mesh.shape.items() if size > 1]
+    if len(large) != 1:
+        raise ValueError(
+            f"a mesh of {dict(mesh.shape)}: a module with its own programs serves over ONE "
+            f"mesh axis larger than 1 (--tensor-parallel-size alone)")
+    return large[0]
 
 
 def kv_cache_sharding(mesh: Mesh) -> NamedSharding:

@@ -27,6 +27,27 @@ import signal  # noqa: E402
 import pytest  # noqa: E402
 
 
+# One case of ``tests/benchmark/test_benchmark.py`` waits for a ``benchmark`` PR, as
+# ``tests/benchmark/conftest.py`` says of Trinity's cell (that file is the benchmark's and a PR that
+# adds a cell edits none of those; this one is not): the test holds every file of
+# ``benchmark/workloads/`` to ``prompt + output <= 2048`` on its last line, and
+# ``code.mellum2-12b-a2.5b-tp4`` has prompts of 1,536-3,072 under ``--max-model-len 4096``. Marked
+# STRICTLY: once the bound is read from the cell's configuration (ROADMAP B11 (c)) the case passes, this
+# mark turns it red, and it goes. ``tests/benchmark/test_mellum_cell.py`` holds everything the case holds
+# before that line, and the bound by the configuration's own flag.
+WAITS_FOR_B11 = ("test_schedule_is_a_pure_function_of_the_seed_and_respects_its_clips"
+                 "[code.mellum2-12b-a2.5b-tp4]")
+
+
+def pytest_collection_modifyitems(items):
+    for item in items:
+        if item.name == WAITS_FOR_B11:
+            item.add_marker(pytest.mark.xfail(
+                strict=True, raises=AssertionError,
+                reason="test_benchmark.py:113 holds every cell to 2,048 positions; this cell's "
+                       "configuration serves 4,096 (ROADMAP B11 (c): read --max-model-len)"))
+
+
 @pytest.fixture(scope="session")
 def model_dir(tmp_path_factory):
     """HF-layout tiny model directory (tokenizer + config), built once."""

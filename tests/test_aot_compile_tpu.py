@@ -1275,3 +1275,95 @@ def test_trinitys_step_programs_copy_neither_a_ring_nor_the_pool_nor_an_expert(m
     # and its steps; a chunk group's scores against a tile of 512 ring entries and 256 page positions
     assert memory.temp_size_in_bytes < (1_200_000_000 if program == "decode" else 500_000_000)
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12_000_000_000
+
+
+def _compile_mellum(program, mesh, rows=16):
+    """``models/mellum.py``'s decode or chunk program at
+    ``code.mellum2-12b-a2.5b-tp4``'s served shapes (the configuration's file:
+    the model WHOLE, 28 layers, 64 experts, 98,304 vocabulary rows, 16 slots,
+    block 16, 6,144 blocks, 4,096 positions; a chunk of ``rows`` x 128 tokens)
+    on the described four-chip mesh, everything in the shardings the engine
+    makes it in, the pool and the rings donated."""
+    from dynamo_tpu.engine_jax.weights import mellum_config
+    from dynamo_tpu.models import mellum
+
+    from .step_programs import published_shape
+
+    c = mellum_config(published_shape("mellum"))
+    slots, mb, chunk = 16, 256, 128
+    rep = NamedSharding(mesh, P())
+    pool, rings = (NamedSharding(mesh, spec) for spec in mellum._cache_specs("tp"))
+
+    def sd(a, sharding):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=rep)
+
+    params = jax.tree.map(sd, jax.eval_shape(lambda: mellum.init_params(jax.random.PRNGKey(0), c)),
+                          mellum.param_shardings(c, mesh))
+    cache = jax.tree.map(lambda a: sd(a, pool), jax.eval_shape(lambda: mellum.make_kv_cache(c, 6144, 16)))
+    state = jax.tree.map(lambda a: sd(a, rings), jax.eval_shape(lambda: mellum.make_slot_state(c, slots)))
+    if program == "decode":
+        def greedy(logits, pos, carry, k):
+            return jnp.argmax(logits, -1).astype(jnp.int32), carry, jnp.argmax(logits, -1)
+
+        return jax.jit(
+            lambda p, kv, st, toks, pos, tables: mellum.decode(
+                p, c, toks, pos, kv, tables, st, 4, 4095, greedy, 0, mesh=mesh),
+            donate_argnums=(1, 2),
+        ).lower(params, cache, state, i32(slots), i32(slots), i32(slots, mb)).compile()
+    return jax.jit(
+        lambda p, kv, st, toks, pos, tables, lanes: mellum.forward_chunk(
+            p, c, toks, pos, kv, tables, st, lanes, mesh=mesh),
+        donate_argnums=(1, 2),
+    ).lower(params, cache, state, i32(rows, chunk), i32(rows, chunk), i32(rows, mb),
+            i32(rows)).compile()
+
+
+@pytest.mark.timeout(400)
+@pytest.mark.parametrize("program, rows", [("decode", 16), ("chunk", 16), ("chunk", 4)],
+                         ids=["decode", "chunk_at_the_full_width", "chunk_of_four_rows"])
+def test_mellums_step_programs_on_four_chips_exchange_activations_and_nothing_else(
+        monkeypatch, tp4_mesh, program, rows):
+    """``models/mellum.py`` at ``code.mellum2-12b-a2.5b-tp4``'s served shapes for
+    the described four-chip v5e, the first module with its own programs on a
+    mesh: a chip's program fits beside its 6.09 GB of weights, 0.70 GB of pages
+    and 0.36 GB of rings; NO instruction copies a chip's part of the pool, of
+    the rings or of the experts' stack, or a layer's part of either (the grouped
+    products take the stack whole and are told the layer; the rings are read in
+    place or a tile a trip); the ONLY collectives are all-reduces of activations
+    ``[rows, 2304]`` float32 (8 in the scan's body, two a layer: attention's and
+    the experts' partial sums; one of the embedding's rows in front of it, a
+    program's body once a group of 8 rows, a step and a history width), ONE of
+    the six expert counters, and in a decode step the all-gather of the logits:
+    no weight, page or ring is gathered. Pool and rings are donated."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernel compiled, not interpreted
+    compiled = _compile_mellum(program, tp4_mesh, rows)
+    hlo = re.sub(r"/\*.*?\*/", "", compiled.as_text())
+    views = (r"7,6144,16,1,128|43008,16,1,128|6144,16,1,128|688128,1,128"  # a chip's pool, a layer's, flat
+             r"|7,3,16,1,1040,128|336,1,1040,128|16,1,1040,128|349440,128"  # a chip's rings, a layer's, flat
+             r"|7,4,16,2304,896|7,4,16,896,2304|448,2304,896|448,896,2304|16,2304,896|16,896,2304")
+    big = re.findall(rf"= (?:f32|bf16)\[(?:{views})\]\{{[^}}]*\}} copy\(", hlo)
+    assert big == [], big
+    found = re.findall(r"= (\S+?)\{[^}]*\} (all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all)"
+                       r"(?:-start)?\(", hlo)
+    n = min(rows, 8) * 128 if program == "chunk" else 16
+    activations = [s for s, op in found if op == "all-reduce" and s.startswith("f32[") and s.endswith(",2304]")]
+    assert all(math.prod(int(d) for d in s[4:-1].split(",")) == n * 2304 for s in activations), set(activations)
+    widths = len(llama.history_widths(16 * 16))  # a decode dispatch holds its steps once a history width
+    bodies = {"decode": 4 * widths, "chunk": -(-rows // 8)}[program]
+    assert len(activations) == (8 + 1) * bodies
+    rest = sorted(set(found) - {(s, "all-reduce") for s in activations})
+    logits = [("f32[16,98304]", "all-gather")] if program == "decode" else []
+    assert rest == sorted([("s32[6]", "all-reduce")] + logits), rest
+    # the scan's body: a computation that holds eight of the activations' all-reduces, and no more
+    per_computation = [len(re.findall(r"= f32\[[\d,]*,2304\]\{[^}]*\} all-reduce(?:-start)?\(", body))
+                       for body in re.split(r"\n(?=(?:ENTRY )?%?[\w.\-]+ \(.*\{\n)", hlo)]
+    assert max(per_computation) == 8 and per_computation.count(8) == bodies, per_computation
+    kernels = len(re.findall(r"custom_call_target=\"tpu_custom_call\"", hlo))
+    assert kernels == 3 * 4 * bodies and "grouped_product" in hlo
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 704_643_072 + 357_826_560  # a chip's pages and rings
+    assert 7_000_000_000 < memory.argument_size_in_bytes < 7_200_000_000
+    assert memory.temp_size_in_bytes < (700_000_000 if program == "decode" else 500_000_000)

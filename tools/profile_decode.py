@@ -16,7 +16,10 @@ bytes and operations (:func:`mla_profiles`); ``... mhc`` times the residual
 path of ONE sublayer of ``xing4.0-29b-a4b`` ALONE, beside the bytes it must
 move (:func:`mhc_profiles`); ``... swa`` times ONE window layer's attention of
 ``trinity-large-preview`` ALONE over its rings at ``long.trinity-large-preview``'s
-two shapes (:func:`swa_profiles`); without a word, the round-5 ablation below.
+two shapes (:func:`swa_profiles`); ``... ep`` times ONE expert layer of
+``mellum2-12b-a2.5b-tp4`` over the four chips of a host, 16 of 64 experts a
+chip, with and without the all-reduce of the chips' partial sums
+(:func:`ep_profiles`); without a word, the round-5 ablation below.
 
 Method notes:
 - every measurement chains computations via data dependencies and fences
@@ -1231,7 +1234,77 @@ def swa_profiles():
         print(line, flush=True)
 
 
+def ep_profiles():
+    """ONE expert layer of ``mellum2-12b-a2.5b-tp4`` ALONE on the chips of a
+    host (``models/mellum.py:_expert_layer`` as its step programs call it: the
+    router whole on every chip, 16 of 64 experts a chip out of a stack of one
+    period's four layers, three bfloat16 parts a product, the chips' partial
+    sums added by an all-reduce of ``[rows, 2304]`` float32), PROF_ITERS
+    (default 8) layers chained in one dispatch, at ``code.mellum2-12b-a2.5b-tp4``'s
+    two shapes: a chunk group's 8 rows x 128 tokens (8,192 pairs, 128 rows an
+    expert) and a decode step's 16 lanes (128 pairs, 2 rows an expert). Each
+    with the all-reduce and with a chip's own part alone, so that their
+    difference is the exchange; beside them the bytes a chip streams (the
+    experts it holds, once) and the bytes it hands the all-reduce over the
+    chip's bandwidth, and the pairs the fullest chip got. On one device (or
+    the CPU) it runs the same at ``tp`` = the devices there are."""
+    from jax import shard_map
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from benchmark import bytes_and_flops
+    from dynamo_tpu.engine_jax.compile_cache import enable_compile_cache
+    from dynamo_tpu.models import mellum
+    from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
+
+    on_chip = jax.default_backend() == "tpu"
+    peaks = bytes_and_flops.load_peaks(jax.devices()[0].device_kind) if on_chip else {}
+    enable_compile_cache()
+    n_iter = int(os.environ.get("PROF_ITERS", "8"))
+    shards = min(4, len(jax.devices()))
+    mesh = make_mesh(MeshConfig(tp=shards)) if shards > 1 else None
+    c = mellum.MellumConfig(num_layers=4, layer_types=(mellum.WINDOW,) * 3 + (mellum.FULL,))
+    axis = "tp" if mesh is not None else None
+    layers = mellum._param_specs(axis)["layers"]
+    made = jax.jit(lambda: mellum.init_params(jax.random.PRNGKey(0), c)["layers"], out_shardings=(
+        jax.tree.map(lambda spec: NamedSharding(mesh, spec), layers, is_leaf=lambda s: isinstance(s, P))
+        if mesh is not None else None))()
+    e = c.hidden_size
+
+    def layers_chained(exchange):
+        def local(params, x):
+            per_period, experts = mellum._split({"layers": params})
+            lp = jax.tree.map(lambda a: a[0, 0], per_period)
+            valid = jnp.ones(x.shape[:2], bool)
+
+            def one(x, i):
+                y, _, pairs = mellum._expert_layer(lp, experts, c, axis if exchange else None, i % 4,
+                                                   mellum.rms_norm(x, lp["mlp_norm"], c.rms_norm_eps), valid)
+                return x + y, pairs
+            x, pairs = jax.lax.scan(one, x, jnp.arange(n_iter))
+            return x, pairs[0]
+
+        if mesh is None:
+            return jax.jit(local)
+        return jax.jit(shard_map(local, mesh=mesh, in_specs=(layers, P()), out_specs=(P(), P()),
+                                 check_vma=False))
+
+    held = c.num_experts // shards * 3 * e * c.moe_intermediate_size * 2
+    for name, rows, t in (("a chunk group of 8 rows x 128", 8, 128), ("a decode step's 16 lanes", 16, 1)):
+        x = jax.random.normal(jax.random.PRNGKey(1), (rows, t, e), jnp.float32)
+        summed, own = layers_chained(True), layers_chained(False)
+        with_sum, alone = (median_ms(fn, made, x) / n_iter for fn in (summed, own))
+        pairs = np.asarray(summed(made, x)[1])
+        sent = rows * t * e * 4
+        line = (f"ep {name}: {with_sum:8.4f} ms a layer with the all-reduce, {alone:8.4f} ms a chip's own part "
+                f"alone; a chip holds {held / 1e6:7.2f} MB of experts and hands the sum {sent / 1e6:6.3f} MB; "
+                f"pairs a chip {pairs.tolist()} of {int(pairs.sum())}")
+        if on_chip:
+            line += (f": {held / peaks['hbm_bytes_per_s'] * 1e3:7.4f} ms and {sent / peaks['hbm_bytes_per_s'] * 1e3:7.4f} ms "
+                     f"at the chip's bandwidth")
+        print(line, flush=True)
+
+
 if __name__ == "__main__":
     {"history": history_profiles, "experts": expert_profiles, "kda": kda_profiles,
      "mamba": mamba_profiles, "groups": chunk_group_profiles, "mla": mla_profiles, "mhc": mhc_profiles,
-     "swa": swa_profiles}.get(" ".join(sys.argv[1:2]), main)()
+     "swa": swa_profiles, "ep": ep_profiles}.get(" ".join(sys.argv[1:2]), main)()
