@@ -58,8 +58,8 @@ import jax.numpy as jnp
 from dynamo_tpu.models.llama import embed_lookup, history_tiles_full, rms_norm
 from dynamo_tpu.ops import moe
 from dynamo_tpu.ops.latent import (
-    PASSES, attend_absorbed, cached_latent, gather_latent as _gather_latent, mm as _mm,
-    write_latent as _write_latent,
+    PASSES, attend_absorbed, attend_absorbed_live, cached_latent, gather_latent as _gather_latent,
+    live_history_tiles, live_latents, mm as _mm, recent_latents, write_latent as _write_latent,
 )
 from dynamo_tpu.ops.pallas.kda_scan import kda_scan, kda_step as _kda_step
 from dynamo_tpu.ops.parts import operand_parts
@@ -290,9 +290,13 @@ def chunk_history_tiles(positions, block_size: int, table_blocks: int, lanes=Non
     return history_tiles_full(block_size, table_blocks)
 
 
-def decode_history_tiles(base, block_size: int, table_blocks: int) -> int:
-    """(lane, tile) slots a decode dispatch gathers: every lane's whole table."""
-    return base.shape[0] * history_tiles_full(block_size, table_blocks)
+def decode_history_tiles(base, block_size: int, table_blocks: int):
+    """(lane, tile) pairs a decode step attends for ``base`` ``[B]`` (a lane's
+    history is the positions < base; -1: the lane does not decode): the tiles
+    that hold history, a block of lanes as far as its longest
+    (``ops/latent.py:live_history_tiles``, for a traced array and a numpy one
+    alike)."""
+    return live_history_tiles(base, block_size, table_blocks)
 
 
 # -- KDA ----------------------------------------------------------------------
@@ -370,16 +374,25 @@ def mla_latent(lp: Params, c: KimiLinearConfig, x: jax.Array) -> jax.Array:
     return cached_latent(x, lp["w_kva"], lp["kv_norm"], c.kv_lora_rank, c.rms_norm_eps)
 
 
+def _absorbed(lp: Params, c: KimiLinearConfig, x: jax.Array):
+    """What ``ops/latent.py``'s forms of absorbed attention take of a layer
+    around the keys: (the queries of ``x`` ``[B, T, E]``, ``W_kvb``, ``W_o``)
+    in front, (rank, no-position width, value width, the scores' scale)
+    behind. No rotation anywhere (``mla_use_nope``): position comes from the
+    KDA layers."""
+    b, t, _ = x.shape
+    q = _mm(x, lp["wq"]).reshape(b, t, c.num_heads, c.qk_head_dim)
+    return ((q, lp["w_kvb"], lp["wo"]),
+            (c.kv_lora_rank, c.qk_nope_head_dim, c.v_head_dim, c.qk_head_dim ** -0.5))
+
+
 def mla_attend(lp: Params, c: KimiLinearConfig, x: jax.Array, latent: jax.Array,
                mask: jax.Array) -> jax.Array:
     """Absorbed latent attention (``ops/latent.py``): queries of ``x`` ``[B, T,
     E]`` against the cached ``latent`` ``[B, P, rank + rope]`` under ``mask``
-    ``[B, T, P]``. No rotation anywhere (``mla_use_nope``): position comes from
-    the KDA layers."""
-    b, t, _ = x.shape
-    q = _mm(x, lp["wq"]).reshape(b, t, c.num_heads, c.qk_head_dim)
-    return attend_absorbed(q, lp["w_kvb"], lp["wo"], latent, mask, c.kv_lora_rank,
-                           c.qk_nope_head_dim, c.v_head_dim, c.qk_head_dim ** -0.5)
+    ``[B, T, P]`` (a chunk's rows, every row's whole table)."""
+    ends, dims = _absorbed(lp, c, x)
+    return attend_absorbed(*ends, latent, mask, *dims)
 
 
 # -- feed-forward -------------------------------------------------------------
@@ -528,24 +541,26 @@ def decode(
     """``steps`` tokens of every slot (``tokens``, ``positions`` ``[S]``;
     position < 0 = the slot does not decode, and its state stays as it is).
 
-    The MLA layers' history is gathered ONCE into a dense ``[S, MB * bs, D]``
-    buffer a layer; a step writes its latent into the buffer at the lane's
-    position and attends the positions up to it, and the pool takes the steps'
-    latents after the loop in one scatter a layer. ``sample(logits [S, V],
-    positions, carry, k) -> (next tokens [S], carry, outputs)`` is the
-    engine's. Returns (tokens, positions, carry, the stacked outputs, pool,
+    The MLA layers' history is gathered ONCE a dispatch, the lanes longest
+    first in blocks (``ops/latent.py:live_latents``), and a step attends what
+    of it is live: a block of lanes the tiles up to its longest lane's
+    (:func:`decode_history_tiles` is the count). A step's latent goes to a
+    small ``[S, steps, D]`` buffer a layer; the step attends the lane's tiles
+    and the buffer's rows up to its own (``attend_absorbed_live``), and the
+    pool takes the buffers after the loop in one scatter a layer.
+    ``sample(logits [S, V], positions, carry, k) -> (next tokens [S], carry,
+    outputs)`` is the engine's. Returns (tokens, positions, carry, the stacked outputs, pool,
     state, counters ``[len(COUNTERS)]``)."""
     c = config
     kinds = layer_kinds(c)
     pool = kv_cache["latent"]
-    lanes = jnp.arange(tokens.shape[0])
-    history = tuple(_gather_latent(pool, j, block_tables) for j in range(kinds.count("mla")))
-    key_pos = jnp.arange(block_tables.shape[1] * pool.shape[2])
+    n_mla = kinds.count("mla")
+    live = live_latents(pool, n_mla, block_tables, positions)
 
     def step(loop, k):
-        toks, pos, carry, s_all, conv_all, history, counters = loop
+        toks, pos, carry, s_all, conv_all, recent, counters = loop
         valid = (pos >= 0)[:, None]
-        s_all, conv_all, history, fresh = list(s_all), list(conv_all), list(history), []
+        s_all, conv_all, recent = list(s_all), list(conv_all), list(recent)
         h = embed_lookup(params, toks, c.dtype).astype(jnp.float32)[:, None]
         i_kda = i_mla = 0
         for i, kind in enumerate(kinds):
@@ -558,12 +573,9 @@ def decode(
                 i_kda += 1
             else:
                 with jax.named_scope("mla"):
-                    lat = mla_latent(lp, c, x)[:, 0].astype(pool.dtype)  # [S, D]
-                    at = jnp.where(pos >= 0, pos, key_pos.shape[0])  # past the buffer: dropped
-                    history[i_mla] = history[i_mla].at[lanes, at].set(lat, mode="drop")
-                    mask = (key_pos[None, None, :] <= pos[:, None, None]) & valid[:, :, None]
-                    y = mla_attend(lp, c, x, history[i_mla], mask)
-                    fresh.append(lat)
+                    ends, dims = _absorbed(lp, c, x)
+                    y, recent[i_mla] = attend_absorbed_live(
+                        *ends, live, i_mla, recent[i_mla], mla_latent(lp, c, x), k, pos >= 0, *dims)
                 i_mla += 1
             h = h + y
             y, stats = feed_forward(lp, c, i, rms_norm(h, lp["mlp_norm"], c.rms_norm_eps), valid)
@@ -572,15 +584,15 @@ def decode(
         h = rms_norm(h, params["final_norm"], c.rms_norm_eps)
         nxt, carry, out = sample(lm_head(params, c, h)[:, 0], pos, carry, k)
         new_pos = jnp.where((pos >= 0) & (pos < max_pos), pos + 1, -1)
-        return ((nxt, new_pos, carry, tuple(s_all), tuple(conv_all), tuple(history), counters),
-                (out, tuple(fresh), pos))
+        return ((nxt, new_pos, carry, tuple(s_all), tuple(conv_all), tuple(recent), counters),
+                (out, pos))
 
-    (toks, pos, carry, s_all, conv_all, _, counters), (out, fresh, at) = jax.lax.scan(
+    (toks, pos, carry, s_all, conv_all, recent, counters), (out, at) = jax.lax.scan(
         step,
-        (tokens, positions, carry, state["s"], state["conv"], history,
-         jnp.zeros((MOE_COUNTERS,), jnp.int32)),
+        (tokens, positions, carry, state["s"], state["conv"],
+         recent_latents(pool, n_mla, tokens.shape[0], steps), jnp.zeros((MOE_COUNTERS,), jnp.int32)),
         jnp.arange(steps))
-    for j, lat in enumerate(fresh):  # [steps, S, D], written at `at` [steps, S]
-        pool = _write_latent(pool, j, jnp.moveaxis(lat, 0, 1), at.T, block_tables)
+    for j, lat in enumerate(recent):  # [S, steps, D], written at `at` [steps, S]
+        pool = _write_latent(pool, j, lat, at.T, block_tables)
     return (toks, pos, carry, out, {"latent": pool}, {"s": s_all, "conv": conv_all},
             jnp.concatenate([counters, jnp.zeros((len(COUNTERS) - MOE_COUNTERS,), jnp.int32)]))

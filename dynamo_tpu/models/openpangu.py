@@ -14,7 +14,8 @@ whole in front of every dispatch's gather: ``tests/test_aot_compile_tpu.py``).
 A chunk's rows (``forward_chunk``, ``draft_chunk``, so ``verify`` too) read
 their block tables a tile of positions a trip and only as far as the group's
 last position (``ops/latent.py:attend_absorbed_tiled``); a decode dispatch
-gathers every lane's whole table once and attends it under a mask.
+gathers the lanes' tables once, and a step attends the tiles of them that hold
+history (``ops/latent.py:attend_absorbed_live``).
 So it has no ``make_slot_state``, the engine hands its programs ``state =
 None`` and takes None back, and everything that hands pages over (a prefix hit,
 ``verify``, preemption, the host tier, a transfer) is open to it as to
@@ -57,11 +58,12 @@ import jax
 import jax.numpy as jnp
 
 from dynamo_tpu.models.llama import (
-    apply_rope, embed_lookup, history_tile, history_tiles_full, rms_norm,
+    apply_rope, embed_lookup, history_tile, rms_norm,
 )
 from dynamo_tpu.ops import moe
 from dynamo_tpu.ops.latent import (
-    PASSES, attend_absorbed, attend_absorbed_tiled, cached_latent, gather_latent, mm, write_latent,
+    PASSES, attend_absorbed_live, attend_absorbed_tiled, cached_latent, live_history_tiles, live_latents,
+    live_positions_attended, mm, recent_latents, write_latent,
 )
 from dynamo_tpu.ops.parts import operand_parts
 
@@ -72,8 +74,9 @@ KVCache = Dict[str, jax.Array]  # {"latent": [L (+ 1 where the engine drafts), N
 COUNTERS = ("moe_layer_calls", "moe_held_rows", "moe_experts_hit", "moe_routed_pairs",
             "moe_rows_computed", "moe_expert_reads",
             # latent attention, summed over the layers' calls: the cached positions a call's
-            # rows attended (a decode lane its whole table, a chunk's row the tiles its group
-            # reads) and, of those, the ones that held history
+            # rows attended (a decode lane the tiles its block of lanes reads and the dispatch's
+            # steps, a chunk's row the tiles its group reads; and every table whole once a decode
+            # dispatch, which its gather reads) and, of those, the ones that held history
             "mla_layer_calls", "mla_history_positions_read", "mla_history_positions_live",
             "mtp_layer_calls")
 MOE_COUNTERS = COUNTERS.index("mla_layer_calls")  # the first: what ops/moe.py:dropless_experts counts
@@ -265,9 +268,13 @@ def chunk_history_tiles(positions, block_size: int, table_blocks: int, lanes=Non
     return (reach + tile - 1) // tile
 
 
-def decode_history_tiles(base, block_size: int, table_blocks: int) -> int:
-    """(lane, tile) slots a decode dispatch gathers: every lane's whole table."""
-    return base.shape[0] * history_tiles_full(block_size, table_blocks)
+def decode_history_tiles(base, block_size: int, table_blocks: int):
+    """(lane, tile) pairs a decode step attends for ``base`` ``[B]`` (a lane's
+    history is the positions < base; -1: the lane does not decode): the tiles
+    that hold history, a block of lanes as far as its longest
+    (``ops/latent.py:live_history_tiles``, for a traced array and a numpy one
+    alike)."""
+    return live_history_tiles(base, block_size, table_blocks)
 
 
 def final_norm(params: Params, config: OpenPanguConfig, x: jax.Array) -> jax.Array:
@@ -353,12 +360,13 @@ def _layer(lp: Params, c: OpenPanguConfig, x: jax.Array, positions: jax.Array, w
 def _mla_counts(layers: int, positions: jax.Array, attended) -> jax.Array:
     """``mla_layer_calls``, ``..._positions_read``, ``..._positions_live`` of
     ``layers`` calls over rows at ``positions`` ``[B, T]``: a row with a token
-    attends ``attended`` cached positions (a decode lane its whole table, a
+    attends ``attended`` cached positions (one number, or one a row ``[B]``: a
+    decode lane the tiles its block of lanes reads and the dispatch's steps, a
     chunk's row the tiles its group reads: trips x tile), of which the
     positions up to its last held history."""
     last = positions.max(axis=1)
     fed = last >= 0
-    return jnp.stack([jnp.int32(layers), layers * fed.sum() * attended,
+    return jnp.stack([jnp.int32(layers), layers * jnp.sum(fed * attended),
                       layers * jnp.sum(jnp.where(fed, last + 1, 0))]).astype(jnp.int32)
 
 
@@ -498,11 +506,16 @@ def decode(
     """``steps`` tokens of every slot (``tokens``, ``positions`` ``[S]``;
     position < 0 = the slot does not decode).
 
-    Every layer's history is gathered ONCE into a dense ``[S, MB * bs, W]``
-    buffer; a step writes its latent into the buffer at the lane's position
-    and attends the positions up to it, and the pool takes the steps' latents
-    after the loop in one scatter a layer. ``sample(logits [S, V], positions,
-    carry, k) -> (next tokens [S], carry, outputs)`` is the engine's. Where
+    Every layer's history is gathered ONCE a dispatch, the lanes longest
+    first in blocks (``ops/latent.py:live_latents``), and a step attends what
+    of it is live: a block of lanes the tiles of
+    ``models/llama.py:history_tile`` positions up to its longest lane's
+    (:func:`decode_history_tiles` is the count). A step's latent goes to a
+    small ``[S, steps, W]`` buffer a layer; the step attends the lane's tiles
+    and the buffer's rows up to its own (``attend_absorbed_live``), and the
+    pool takes the buffers after the loop in one scatter a layer.
+    ``sample(logits [S, V], positions, carry, k) -> (next tokens [S], carry,
+    outputs)`` is the engine's. Where
     ``draft``, every step also runs the prediction module on its raw output
     and the token it sampled (the module's own history beside the layers').
     Returns (tokens, positions, carry, the stacked outputs, pool, ``state`` as
@@ -513,24 +526,21 @@ def decode(
     n_hist = c.num_layers + bool(draft)
     if pool.shape[0] < n_hist:
         raise ValueError("the pool holds no pages for the prediction module (make_kv_cache(drafting=True))")
-    lanes = jnp.arange(tokens.shape[0])
-    history = tuple(gather_latent(pool, j, block_tables) for j in range(n_hist))
-    key_pos = jnp.arange(block_tables.shape[1] * pool.shape[2])
+    live = live_latents(pool, n_hist, block_tables, positions)
+    attended = live_positions_attended(live, steps)
+    # read ONCE a dispatch, whoever decodes: every lane's whole table, by the gather
+    gathered = n_hist * block_tables.size * pool.shape[2]
 
     def step(loop, k):
-        toks, pos, carry, history, counters, drafts = loop
-        history, fresh = list(history), []
+        toks, pos, carry, recent, counters, drafts = loop
+        recent = list(recent)
         pos2 = pos[:, None]
-        mask = (key_pos[None, None, :] <= pos[:, None, None]) & (pos2 >= 0)[:, :, None]
-        at = jnp.where(pos >= 0, pos, key_pos.shape[0])  # past the buffer: dropped
 
         def buffered(j):
             def attend(lp, q, latent):
-                lat = latent[:, 0].astype(pool.dtype)  # [S, W]
-                history[j] = history[j].at[lanes, at].set(lat, mode="drop")
-                fresh.append(lat)
                 ends, dims = _absorbed(lp, c)
-                return attend_absorbed(q, *ends, history[j], mask, *dims)
+                out, recent[j] = attend_absorbed_live(q, *ends, live, j, recent[j], latent, k, pos >= 0, *dims)
+                return out
             return attend
 
         x = embed_lookup(params, toks, c.dtype).astype(jnp.float32)[:, None]
@@ -538,7 +548,7 @@ def decode(
             x, stats = _layer(lp, c, x, pos2, pool.shape[-1], buffered(i))
             counters = counters.at[:MOE_COUNTERS].add(stats)
         nxt, carry, out = sample(lm_head(params, c, final_norm(params, c, x))[:, 0], pos, carry, k)
-        own = _mla_counts(n_hist, pos2, key_pos.shape[0])
+        own = _mla_counts(n_hist, pos2, attended)
         if draft:
             with jax.named_scope("mtp"):
                 u = _mtp_input(params, c, x, nxt[:, None])
@@ -551,14 +561,15 @@ def decode(
         counters = counters.at[MOE_COUNTERS:].add(
             jnp.concatenate([own, jnp.full((1,), int(draft), jnp.int32)]))
         new_pos = jnp.where((pos >= 0) & (pos < max_pos), pos + 1, -1)
-        return (nxt, new_pos, carry, tuple(history), counters, drafts), (out, tuple(fresh), pos)
+        return (nxt, new_pos, carry, tuple(recent), counters, drafts), (out, pos)
 
-    (toks, pos, carry, _, counters, drafts), (out, fresh, at) = jax.lax.scan(
+    (toks, pos, carry, recent, counters, drafts), (out, at) = jax.lax.scan(
         step,
-        (tokens, positions, carry, history, jnp.zeros((len(COUNTERS),), jnp.int32),
+        (tokens, positions, carry, recent_latents(pool, n_hist, tokens.shape[0], steps),
+         jnp.zeros((len(COUNTERS),), jnp.int32).at[COUNTERS.index("mla_history_positions_read")].set(gathered),
          jnp.zeros_like(tokens)),
         jnp.arange(steps))
-    for j, lat in enumerate(fresh):  # [steps, S, W], written at `at` [steps, S]
-        pool = write_latent(pool, j, jnp.moveaxis(lat, 0, 1), at.T, block_tables)
+    for j, lat in enumerate(recent):  # [S, steps, W], written at `at` [steps, S]
+        pool = write_latent(pool, j, lat, at.T, block_tables)
     done = (toks, pos, carry, out, {"latent": pool}, state, counters)
     return (*done, drafts) if draft else done

@@ -59,7 +59,9 @@ from dynamo_tpu.models.openpangu import (  # noqa: F401  (the module's contract:
     is_expert_layer, lm_head, make_kv_cache, mixer, param_shardings,
 )
 from dynamo_tpu.ops import mhc
-from dynamo_tpu.ops.latent import attend_absorbed, gather_latent, write_latent
+from dynamo_tpu.ops.latent import (
+    attend_absorbed_live, live_latents, live_positions_attended, recent_latents, write_latent,
+)
 
 Params = Dict[str, Any]
 KVCache = Dict[str, jax.Array]  # {"latent": [L (+ 1 where the engine drafts), N, bs, W]} float32
@@ -287,35 +289,33 @@ def decode(
     sample, carry, draft: bool = False,
 ):
     """``steps`` tokens of every slot (``models/openpangu.py:decode``'s
-    contract and form: every layer's history gathered ONCE into a dense
-    buffer, a step writes its latent there and attends the positions up to it,
-    the pool takes the steps' latents after the loop). Returns (tokens,
-    positions, carry, the stacked outputs, pool, ``state`` as it came, counters
-    ``[len(COUNTERS)]``) and, where ``draft``, the module's first choice for
-    the token AFTER the last one sampled, ``[S]`` int32."""
+    contract and form: every layer's history gathered ONCE a dispatch, a
+    step's latent written to a small buffer a layer, the lane's tiles that
+    hold history and the buffer attended, the pool takes the buffers after the
+    loop). Returns (tokens, positions, carry, the stacked outputs, pool,
+    ``state`` as it came, counters ``[len(COUNTERS)]``) and, where ``draft``,
+    the module's first choice for the token AFTER the last one sampled,
+    ``[S]`` int32."""
     c = config
     pool = kv_cache["latent"]
     n_hist = c.num_layers + bool(draft)
     if pool.shape[0] < n_hist:
         raise ValueError("the pool holds no pages for the prediction module (make_kv_cache(drafting=True))")
-    lanes = jnp.arange(tokens.shape[0])
-    history = tuple(gather_latent(pool, j, block_tables) for j in range(n_hist))
-    key_pos = jnp.arange(block_tables.shape[1] * pool.shape[2])
+    live = live_latents(pool, n_hist, block_tables, positions)
+    attended = live_positions_attended(live, steps)
+    # read ONCE a dispatch, whoever decodes: every lane's whole table, by the gather
+    gathered = n_hist * block_tables.size * pool.shape[2]
 
     def step(loop, k):
-        toks, pos, carry, history, counters, drafts = loop
-        history, fresh = list(history), []
+        toks, pos, carry, recent, counters, drafts = loop
+        recent = list(recent)
         pos2 = pos[:, None]
-        mask = (key_pos[None, None, :] <= pos[:, None, None]) & (pos2 >= 0)[:, :, None]
-        at = jnp.where(pos >= 0, pos, key_pos.shape[0])  # past the buffer: dropped
 
         def buffered(j):
             def attend(lp, q, latent):
-                lat = latent[:, 0].astype(pool.dtype)  # [S, W]
-                history[j] = history[j].at[lanes, at].set(lat, mode="drop")
-                fresh.append(lat)
                 ends, dims = base._absorbed(lp, c)
-                return attend_absorbed(q, *ends, history[j], mask, *dims)
+                out, recent[j] = attend_absorbed_live(q, *ends, live, j, recent[j], latent, k, pos >= 0, *dims)
+                return out
             return attend
 
         streams = _embedded(params, c, toks[:, None])
@@ -334,16 +334,17 @@ def decode(
             drafts = jnp.where(pos >= 0, guess, drafts)
             counters = counters.at[:MOE_COUNTERS].add(stats)
         counters = counters.at[MOE_COUNTERS:].add(_own_sums(
-            base._mla_counts(n_hist, pos2, key_pos.shape[0]), int(draft), n_hist, pos2))
+            base._mla_counts(n_hist, pos2, attended), int(draft), n_hist, pos2))
         new_pos = jnp.where((pos >= 0) & (pos < max_pos), pos + 1, -1)
-        return (nxt, new_pos, carry, tuple(history), counters, drafts), (out, tuple(fresh), pos)
+        return (nxt, new_pos, carry, tuple(recent), counters, drafts), (out, pos)
 
-    (toks, pos, carry, _, counters, drafts), (out, fresh, at) = jax.lax.scan(
+    (toks, pos, carry, recent, counters, drafts), (out, at) = jax.lax.scan(
         step,
-        (tokens, positions, carry, history, jnp.zeros((len(COUNTERS),), jnp.int32),
+        (tokens, positions, carry, recent_latents(pool, n_hist, tokens.shape[0], steps),
+         jnp.zeros((len(COUNTERS),), jnp.int32).at[COUNTERS.index("mla_history_positions_read")].set(gathered),
          jnp.zeros_like(tokens)),
         jnp.arange(steps))
-    for j, lat in enumerate(fresh):  # [steps, S, W], written at `at` [steps, S]
-        pool = write_latent(pool, j, jnp.moveaxis(lat, 0, 1), at.T, block_tables)
+    for j, lat in enumerate(recent):  # [S, steps, W], written at `at` [steps, S]
+        pool = write_latent(pool, j, lat, at.T, block_tables)
     done = (toks, pos, carry, out, {"latent": pool}, state, counters)
     return (*done, drafts) if draft else done

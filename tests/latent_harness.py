@@ -9,7 +9,7 @@ import numpy as np
 
 from dynamo_tpu.models import openpangu as op
 
-from .step_programs import chunk_program, draft_program, patched, prompt_of, reference_program
+from .step_programs import chunk_program, decode_program, draft_program, patched, prompt_of, reference_program
 
 # ATOL: float32 on the CPU, at the highest matmul precision on both sides. The
 # program and the reference order their sums differently (absorbed against
@@ -71,6 +71,59 @@ def feed(mod, cfg, params, cache, tokens, start, n, table, *, drafting=False, fo
             params, x, jnp.asarray(nxt), jnp.asarray(pos), cache, jnp.asarray(tables))
         drafts, sums = mod.lm_head(params, cfg, hd[0, :n]), sums + more
     return logits, drafts, cache, np.asarray(sums)
+
+
+
+def check_lanes_decode_as_each_does_alone(mod, cfg, params, drafting):
+    """Two lanes of one decode dispatch, the LONGER in the higher slot (the
+    live form takes the lanes longest first, so both move) and an idle slot
+    between and beside them: each lane's logits, its drafts and what it leaves
+    in the pool are what it gives alone in a dispatch of its own, and the
+    program's counters say what it read (every slot's whole table once, by
+    the gather, and a step's row against the block's one tile of this table
+    and the dispatch's steps; ``decode_history_tiles`` is the host's count of
+    the tiles)."""
+    n_decode, slots, lengths = 3, 4, {1: 21, 3: 45}
+    cache = mod.make_kv_cache(cfg, 32, BS, drafting=drafting)
+    tokens = {slot: np.asarray(prompt_of(n + n_decode + 1, salt=slot), np.int32) for slot, n in lengths.items()}
+    tables = np.zeros((slots, MB), np.int32)
+    for slot, n in lengths.items():
+        tables[slot] = 1 + (slot // 2) * MB + np.arange(MB)
+        for at in range(0, n, C):
+            _, _, cache, _ = feed(mod, cfg, params, cache, tokens[slot], at, min(C, n - at), tables[slot],
+                                  drafting=drafting, following=tokens[slot][1:])
+    forcing = np.zeros((slots, BS * MB), np.int32)
+    for slot in lengths:
+        forcing[slot, :len(tokens[slot])] = tokens[slot]
+    program = decode_program(mod, cfg, n_decode, 95, **({"draft": True} if drafting else {}))
+
+    def dispatch(fed):
+        toks, pos = np.zeros((slots,), np.int32), np.full((slots,), -1, np.int32)
+        for slot in fed:
+            toks[slot], pos[slot] = tokens[slot][lengths[slot]], lengths[slot]
+        out = program(params, jnp.asarray(toks), jnp.asarray(pos), cache, jnp.asarray(tables), None,
+                      jnp.asarray(forcing))
+        return out, dict(zip(mod.COUNTERS, np.asarray(out[6]).tolist())), pos
+
+    both, counts, pos = dispatch(lengths)
+    n_hist = cfg.num_layers + bool(drafting)
+    tile = BS * MB  # this table is one tile
+    assert mod.decode_history_tiles(pos, BS, MB) == slots  # one block of four lanes, one tile
+    gathered = n_hist * slots * tile  # once a dispatch: every slot's whole table
+    assert counts["mla_history_positions_read"] == gathered + n_hist * n_decode * len(lengths) * (tile + n_decode)
+    assert counts["mla_history_positions_live"] == n_hist * sum(
+        n + k + 1 for n in lengths.values() for k in range(n_decode))
+    for slot, n in lengths.items():
+        alone, counts, _ = dispatch([slot])
+        assert counts["mla_history_positions_read"] == gathered + n_hist * n_decode * (tile + n_decode)
+        np.testing.assert_allclose(np.asarray(both[3])[:, slot], np.asarray(alone[3])[:, slot], atol=ATOL)
+        assert int(both[1][slot]) == int(alone[1][slot]) == n + n_decode
+        if drafting:
+            assert int(both[7][slot]) == int(alone[7][slot])
+        pages = tables[slot][n // BS:(n + n_decode - 1) // BS + 1]
+        np.testing.assert_allclose(np.asarray(both[4]["latent"])[:, pages], np.asarray(alone[4]["latent"])[:, pages],
+                                   atol=ATOL)
+        assert np.asarray(both[4]["latent"])[:, pages].any()
 
 
 # Successive pieces of a prompt in consecutive rows of ONE chunk dispatch. ``dispatches``: each a list of

@@ -1057,15 +1057,19 @@ def test_openpangus_step_programs_neither_pad_nor_copy_the_latent_pool(monkeypat
     place. A chunk's rows read their tables out of the pool a tile a trip, the
     gather INSIDE the loop (``ops/latent.py:attend_absorbed_tiled``), and the
     guard holds there too; the chunk program holds no float32 value of a
-    group's scores over a whole table ``[4, 128, 128, 2048]``, and the decode
-    program still holds its dense histories ``[64, 2048, 640]``."""
+    group's scores over a whole table ``[4, 128, 128, 2048]``; the decode
+    program holds its histories as the live form reads them (``ops/latent.py:
+    live_latents``: 16 blocks of 4 lanes, a block's lanes side by side under
+    each of 8 tiles), copies none of them, and scores no lane against a whole
+    table."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels compiled, not interpreted
     compiled = _compile_openpangu(program, one_chip, rows, draft)
     hlo = re.sub(r"/\*.*?\*/", "", compiled.as_text())
     if program == "chunk":
         assert "f32[4,128,128,2048]" not in hlo and "f32[4,128,128,256]" in hlo
     else:
-        assert "f32[64,2048,640]" in hlo
+        assert "f32[16,8,4,256,640]" in hlo and "f32[64,2048,640]" not in hlo and "f32[64,128,1,2048]" not in hlo
+        assert not re.findall(r"= f32\[16,8,4,256,640\]\{[^}]*\} copy\(", hlo)
     layers = 6 if draft else 5
     views = rf"{layers},12288,16,640|{layers * 12288},16,640|{layers * 12288 * 16},640"
     big = re.findall(
@@ -1185,7 +1189,7 @@ def test_xing4s_step_programs_fit_and_hold_no_padded_residual(monkeypatch, one_c
     tokens = 64 if program == "decode" else 512  # a decode step's lanes, a chunk group's positions
     assert re.search(rf"f32\[(?:1,)?{tokens}\]|f32\[\d+,{tokens}\]|f32\[{tokens},1\]", hlo)
     if program == "decode":
-        assert "f32[64,2048,640]" in hlo
+        assert "f32[16,8,4,256,640]" in hlo and "f32[64,2048,640]" not in hlo  # ops/latent.py:live_latents
     else:
         assert "f32[4,32,128,2048]" not in hlo and "f32[4,32,128,256]" in hlo  # scores: a tile, never a table
     # 10 sublayers (a decode step's; a chunk group's): the sweeps fuse

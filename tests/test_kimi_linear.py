@@ -370,13 +370,18 @@ def test_the_engine_serves_the_reference_greedy_tokens_and_logprobs(engine, para
     # the products ran over whole tiles of 16 rows, and read a hit expert once
     assert snap["moe_rows_computed"] % 16 == 0 and snap["moe_rows_computed"] >= snap["moe_held_rows"]
     assert snap["moe_expert_reads"] == snap["moe_experts_hit"]
-    # both programs read every table's full width, and the counters say so
+    # the chunk program reads every table's full width, and the counters say so
     assert snap["chunk_history_tiles_read"] == snap["chunk_history_tiles_full"] > 0
     # state per slot beside the pages, handed from row to row inside the kernel: a lane may fill several
     # rows of a dispatch (here the ladder is [1, 4]: no rung under the full width holds two)
     assert kl.LANE_TAKES_ROWS and engine._lane_rows and engine._chunk_rungs == [1, 4]
     assert snap["chunk_rows_live"] == snap["chunk_lanes_fed"] > 0 and snap["kda_state_handovers"] == 0
-    assert snap["decode_history_tiles_read"] == snap["decode_history_tiles_full"] > 0
+    # the decode program walks the tiles its blocks of lanes hold: here ONE block of four lanes under a table
+    # of one tile, so a dispatch counts the four pairs that are all there are (an engine of 16 slots reads a
+    # quarter: test_a_served_prompt_passes_its_state_once_a_chunk)
+    dispatches = snap["decode_history_tiles_full"] // ENGINE_CFG.max_slots
+    assert 0 < snap["decode_history_tiles_read"] == dispatches * kl.decode_history_tiles(
+        np.asarray([len(prompt), -1, -1, -1]), ENGINE_CFG.kv_block_size, ENGINE_CFG.max_blocks_per_seq)
     tiers = list(snap["attention_tiers"].values())
     assert tiers and all(t == {"tier": "dense", "interpret": False} for t in tiers)
 
@@ -398,6 +403,10 @@ def test_a_served_prompt_passes_its_state_once_a_chunk(engine):
     wide = JaxServingEngine(engine.model_config, engine.params, dataclasses.replace(ENGINE_CFG, max_slots=16))
     try:
         assert rise(wide) == (4 * 40, 4 * 1, 2)
+        # one lane decodes among 16: the decode program walks its block of four lanes' one tile and none of
+        # the three idle blocks', and the host's count says so
+        snap = wide.metrics_snapshot()
+        assert 0 < 4 * snap["decode_history_tiles_read"] == snap["decode_history_tiles_full"]
     finally:
         wide.close()
 

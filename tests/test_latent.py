@@ -1,18 +1,22 @@
 """``ops/latent.py:attend_absorbed_tiled`` (a chunk's latent attention, read
 out of the pool a tile of the block table a trip, as far as the rows' last
-position) against ``attend_absorbed`` over the whole gathered table under a
-mask, at a tiny size on the CPU in float32: 4 heads of 16 + 8, a latent of 32
-in a row of 48 (padded), blocks of 8, tiles of 2 blocks = 16 positions, a
-table of 7 blocks (three tiles and a half)."""
+position) and ``attend_absorbed_live`` (a decode step's, over the tiles of the
+lanes' tables that hold history and the dispatch's own steps) against
+``attend_absorbed`` over the whole gathered table under a mask, at a tiny size
+on the CPU in float32: 4 heads of 16 + 8, a latent of 32 in a row of 48
+(padded), blocks of 8, tiles of 2 blocks = 16 positions, a table of 7 blocks
+(three tiles and a half)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dynamo_tpu.models import openpangu as op
+from dynamo_tpu.models import kimi_linear, llama, openpangu as op, xing4
+from dynamo_tpu.ops import latent as ops
 from dynamo_tpu.ops.latent import (
-    attend_absorbed, attend_absorbed_tiled, gather_latent, write_latent,
+    attend_absorbed, attend_absorbed_live, attend_absorbed_tiled, gather_latent, live_latents,
+    live_positions_attended, write_latent,
 )
 
 H, NOPE, ROPE, V, RANK, W, E = 4, 16, 8, 16, 32, 48, 64
@@ -149,4 +153,121 @@ def test_the_trips_are_the_tiles_up_to_the_last_position(last, block_size, table
     assert op.chunk_history_tiles(positions, block_size, table_blocks) == trips
     traced = jax.jit(op.chunk_history_tiles, static_argnums=(1, 2))(jnp.asarray(positions), block_size, table_blocks)
     assert int(traced) == trips
-    assert trips <= op.history_tiles_full(block_size, table_blocks)
+    assert trips <= llama.history_tiles_full(block_size, table_blocks)
+
+
+# -- a decode dispatch's live form -------------------------------------------------
+#
+# Lanes by the history they hold (-1: the lane does not decode). The table holds 56 positions in four
+# tiles of 16 (the last hangs over it) and a dispatch makes 4 steps, so a lane may start at 52 at most.
+STEPS, LAST = 4, MB * BS - 1
+LANES = {
+    "lanes_of_every_kind": [-1, 0, TILE - 1, TILE, LAST + 1 - STEPS, 30, 1, 2 * TILE + 1, 5, 3 * TILE],
+    "every_lane_empty": [-1, -1, 0, -1, 0, -1, -1, -1],
+    "every_lane_full": [LAST + 1 - STEPS] * 8,
+    "one_lane": [21],
+}
+
+
+@pytest.fixture
+def tiles_of_16(monkeypatch):
+    monkeypatch.setattr(llama, "HISTORY_TILE", TILE)
+    assert (llama.history_tile(BS, MB), llama.history_tiles_full(BS, MB)) == (TILE, 4)
+
+
+def dispatch_of(base, seed=0):
+    """(the lanes' base, block tables, a pool with every page of every layer
+    random, the steps' queries ``[STEPS, B, 1, H, D]`` and fresh latents ``[B,
+    STEPS, W]``)."""
+    base = np.asarray(base, np.int32)
+    b = len(base)
+    k = jax.random.split(jax.random.PRNGKey(seed), 3)
+    tables = 1 + np.arange(b * MB, dtype=np.int32).reshape(b, MB)  # page 0 is nobody's
+    pool = jax.random.normal(k[0], (LAYERS, b * MB + 1, BS, W)).at[..., RANK + ROPE:].set(0.0)
+    fresh = jax.random.normal(k[1], (b, STEPS, W)).at[..., RANK + ROPE:].set(0.0)
+    q = jax.random.normal(k[2], (STEPS, b, 1, H, NOPE + ROPE))
+    return base, jnp.asarray(tables), pool, q, fresh
+
+
+def live_steps(weights, base, tables, pool, q, fresh):
+    """The four steps of a dispatch through the live form, as a decode
+    program makes them: the history gathered once, a step's latent into the
+    buffer with the lanes' tiles and the buffer attended. ``[STEPS, B, E]``."""
+    def steps(base, tables, pool, q, fresh):
+        live = live_latents(pool, LAYERS, tables, base)
+        recent, out = jnp.zeros_like(fresh), []
+        for k in range(STEPS):
+            fed = (base >= 0) & (base + k <= LAST)
+            got, recent = attend_absorbed_live(q[k], *weights, live, LAYER, recent, fresh[:, k:k + 1], k, fed, *DIMS)
+            out.append(got[:, 0])
+        return jnp.stack(out)
+    return jax.jit(steps)(jnp.asarray(base), tables, pool, q, fresh)
+
+
+def full_steps(weights, base, tables, pool, q, fresh):
+    """The same steps over every lane's WHOLE table under a mask, the step's
+    latent written into the dense buffer at the lane's position: the form the
+    decode programs had."""
+    history = gather_latent(pool, LAYER, tables)
+    key_pos, lanes, out = jnp.arange(MB * BS), jnp.arange(len(base)), []
+    for k in range(STEPS):
+        pos = jnp.where((base >= 0) & (base + k <= LAST), base + k, -1)
+        history = history.at[lanes, jnp.where(pos >= 0, pos, MB * BS)].set(fresh[:, k], mode="drop")
+        mask = (key_pos[None, None, :] <= pos[:, None, None]) & (pos >= 0)[:, None, None]
+        out.append(attend_absorbed(q[k], *weights, history, mask, *DIMS)[:, 0])
+    return jnp.stack(out)
+
+
+@pytest.mark.parametrize("name", list(LANES))
+def test_the_live_form_is_the_full_form_over_whole_tables(weights, tiles_of_16, name):
+    """Lanes of mixed histories in one dispatch (a padding lane, a lane at 0,
+    one under and one at a tile's edge, one that ends at the table's last
+    position; every lane empty; every lane full: the last trip of every
+    block), the steps' buffer folded in over four steps: every lane's answer
+    at every step is what the softmax over its whole table gives."""
+    base, tables, pool, q, fresh = dispatch_of(LANES[name])
+    want = np.asarray(full_steps(weights, base, tables, pool, q, fresh))
+    got = np.asarray(live_steps(weights, base, tables, pool, q, fresh))
+    assert got.shape == (STEPS, len(base), E) and np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=ATOL)
+    assert not got[:, base < 0].any()
+    if (base >= 0).any():
+        assert np.abs(got[:, base >= 0]).max() > 0.1
+
+
+def test_the_live_form_attends_no_tile_past_a_blocks_trips(weights, tiles_of_16):
+    """The bound is real, not a mask: with every page of the tiles past each
+    block's trips NaN in the pool, the live form answers as it did; the full
+    form, which multiplies them by a weight of zero, answers NaN."""
+    base, tables, pool, q, fresh = dispatch_of(LANES["lanes_of_every_kind"])
+    want = np.asarray(full_steps(weights, base, tables, pool, q, fresh))
+    order, held, trips = ops._live_blocks(base, BS, MB)
+    assert trips.tolist() == [4, 3, 1, 1, 0] and held.shape == (5, 2)  # ten lanes: blocks of two
+    past = np.concatenate([np.asarray(tables)[lane, trips[r // 2] * TILE_BLOCKS:] for r, lane in enumerate(order)])
+    poisoned = pool.at[:, past].set(jnp.nan)
+    got = np.asarray(live_steps(weights, base, tables, poisoned, q, fresh))
+    np.testing.assert_allclose(got, want, atol=ATOL)
+    assert np.isnan(np.asarray(full_steps(weights, base, tables, poisoned, q, fresh))[:, base == 5]).all()
+
+
+@pytest.mark.parametrize("module", [op, xing4, kimi_linear], ids=lambda m: m.__name__.rsplit(".", 1)[-1])
+@pytest.mark.parametrize("base, tiles", [
+    ([-1, -1, -1, -1], 0), ([0, -1, 0, -1], 0), ([1, -1, -1, -1], 4), ([256, 255, 1, 0], 4), ([257, 0, 0, 0], 8),
+    ([2047] * 8, 64), ([300, -1, 700, 20, 5, 1100, 256, 90], 4 * 5 + 4 * 1),
+    ([513, 10, 20, 30, 40, 50, 60, 70, 80, 90, -1, -1], 4 * 3 + 4 * 1 + 4 * 1),
+])
+def test_the_decode_count_is_the_tiles_the_live_form_walks(module, base, tiles):
+    """``decode_history_tiles`` of the three latent modules: the lanes longest
+    first in blocks of ``lanes_at_once``, a block the tiles of 256 its longest
+    lane holds; the host's numpy array and the program's traced one alike,
+    and what the program's own counters are fed (``live_positions_attended``:
+    a lane's row is scored against its block's tiles and the steps' buffer)."""
+    base = np.asarray(base, np.int32)
+    assert module.decode_history_tiles(base, 16, 128) == tiles
+    traced = jax.jit(module.decode_history_tiles, static_argnums=(1, 2))(jnp.asarray(base), 16, 128)
+    assert int(traced) == tiles <= len(base) * llama.history_tiles_full(16, 128)
+    pool = jnp.zeros((1, 2, 16, 8))
+    live = live_latents(pool, 1, jnp.zeros((len(base), 128), jnp.int32), jnp.asarray(base))
+    attended = np.asarray(live_positions_attended(live, STEPS))
+    assert attended.shape == base.shape and (attended - STEPS).sum() == tiles * 256
+    assert (attended - STEPS >= base.clip(0)).all()  # no lane holds history past what its block walks

@@ -32,7 +32,7 @@ from dynamo_tpu.models import llama, module_for
 from dynamo_tpu.models import xing4 as xm
 from dynamo_tpu.ops import mhc
 
-from .latent_harness import BS, LANE_ROWS, MB, YARN, check_lane_rows, feed
+from .latent_harness import BS, LANE_ROWS, MB, YARN, check_lane_rows, check_lanes_decode_as_each_does_alone, feed
 from .latent_harness import XING4_SHAPE as SHAPE
 from .step_programs import (  # noqa: F401  (highest_precision: autouse, for this file's tests)
     answer, card, collect, decode_program, highest_precision, patched, prompt_of, reference_program, run_out,
@@ -159,12 +159,21 @@ def test_chunked_prefill_then_decode_agrees_with_the_plain_reference(cfg, params
     counts = dict(zip(xm.COUNTERS, np.asarray(out[6])))
     assert counts["mla_layer_calls"] == 4 * n_decode and counts["mtp_layer_calls"] == n_decode
     assert counts["mhc_mix_calls"] == 8 * n_decode and counts["mhc_rows_mixed"] == 8 * n_decode  # one lane decodes
+    # the gather reads every slot's whole table once; a step's row is scored against its block's tiles (one
+    # block of four lanes, this table's one tile: the host's count) and the dispatch's steps
+    assert xm.decode_history_tiles(pos, BS, MB) == slots
+    assert counts["mla_history_positions_read"] == 4 * (slots * MB * BS + n_decode * (MB * BS + n_decode))
     plain = decode_program(xm, cfg, n_decode, 95)(
         params, jnp.asarray(toks), jnp.asarray(pos), {"latent": cache["latent"][:3]}, jnp.asarray(lanes_tables),
         None, jnp.asarray(forcing))
     counts = dict(zip(xm.COUNTERS, np.asarray(plain[6])))
     assert len(plain) == 7 and counts["mtp_layer_calls"] == 0 and counts["mhc_mix_calls"] == 6 * n_decode
     np.testing.assert_allclose(np.asarray(plain[3])[:, slot], np.asarray(out[3])[:, slot], atol=ATOL)
+
+
+@pytest.mark.parametrize("drafting", [False, True], ids=["decode", "decode_drafting"])
+def test_two_lanes_of_a_decode_dispatch_answer_as_each_does_alone(cfg, params, drafting):
+    check_lanes_decode_as_each_does_alone(xm, cfg, params, drafting)
 
 
 def test_a_lane_that_starts_past_position_zero_is_rotated_at_its_own_positions(cfg, params):

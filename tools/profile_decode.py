@@ -994,6 +994,168 @@ def chunk_group_profiles():
               f"{fewest}..{rows}: " + " ".join(f"{ms:.2f}" for ms in by_live), flush=True)
 
 
+def draw_contexts(lanes: int, decoding: int, seed: int = 0) -> np.ndarray:
+    """``[lanes]``: the history ``decoding`` of them hold in the middle of a run
+    of the ``batch`` traffic (a prompt of lognormal length, median 256, sigma
+    0.7, in 32 ... 1,024, and an even share of an output of median 128, sigma
+    0.5, in 16 ... 384: ``benchmark/workloads/batch.*.json``), -1 for the
+    lanes that prefill or wait."""
+    rng = np.random.default_rng(seed)
+    prompts = np.exp(np.log(256) + 0.7 * rng.standard_normal(lanes)).round().clip(32, 1024)
+    outputs = np.exp(np.log(128) + 0.5 * rng.standard_normal(lanes)).round().clip(16, 384)
+    held = (prompts + np.floor(rng.random(lanes) * outputs)).astype(np.int32)
+    held[rng.permutation(lanes)[decoding:]] = -1
+    return held
+
+
+def mla_decode_profiles(h: int, peaks: dict):
+    """ONE layer's absorbed attention of a decode step's 64 lanes at ``h``
+    heads, at the cell's OCCUPANCY (:func:`draw_contexts`: 58 lanes decode,
+    each at its own context), PROF_ITERS (default 8) layers chained in one
+    dispatch, beside the bytes each form must read and does:
+
+    - the full-width form the decode programs had until PR 69
+      (``attend_absorbed`` over every lane's whole table under a mask);
+    - form (i), ``models/llama.py``'s: the (lane, tile) pairs that hold
+      history packed to the front, attended at a static width (all, half and a
+      quarter of the pairs; the narrowest that holds the live ones is what a
+      ``lax.switch`` would run), a lane's queries taken out to its slots and
+      the slots' partials brought back by a product with ``own``;
+    - form (i)'s slots under ONE text of the step loop: PROF_WIDTHS' widths
+      (of 512 slots) in a ``lax.switch`` around the attention's core alone;
+    - form (ii), what the programs run (``ops/latent.py:
+      attend_absorbed_live``): one text of the step loop, the lanes longest
+      first in blocks of PROF_LANES' lanes (default 4,8), a block's tiles a
+      tile a trip under a traced trip count, the steps' buffer folded in;
+    - what a dispatch pays ONCE: the gather of every table whole, one layer,
+      in the tables' order and in the live form's (``live_latents``)."""
+    from dynamo_tpu.models.llama import history_tile, history_tiles_full
+    from dynamo_tpu.ops import latent as ops
+    from dynamo_tpu.ops.latent import wdot
+
+    on_chip = bool(peaks)
+    n_iter = int(os.environ.get("PROF_ITERS", "8"))
+    b, steps = int(os.environ.get("PROF_SLOTS", "64")), 4
+    r, dn, dr, dv, w, e, table, bs = 512, 128, 64, 128, 640, 7680, 2048, 16
+    dims = (r, dn, dv, (dn + dr) ** -0.5)
+    key = jax.random.split(jax.random.PRNGKey(0), 6)
+    w_kvb = (jax.random.normal(key[0], (r, h * (dn + dv)), jnp.float32) / r ** 0.5).astype(jnp.bfloat16)
+    wo = (jax.random.normal(key[1], (h * dv, e), jnp.float32) / (h * dv) ** 0.5).astype(jnp.bfloat16)
+    weights = 2 * (w_kvb.size + wo.size)
+    base = draw_contexts(b, b - b // 10)
+    mb = table // bs
+    tile, tiles = history_tile(bs, mb), history_tiles_full(bs, mb)
+    pairs = int((-(-base.clip(0) // tile)).sum())
+    need = 2 * int(base.clip(0).sum()) * (r + dr) * 4 + weights
+    print(f"mla decode, {h} heads, {b} lanes: {int((base >= 0).sum())} decode at contexts "
+          f"{int(base[base >= 0].min())} ... {int(base.max())} (mean {base[base >= 0].mean():.0f}); "
+          f"{pairs} of {b * tiles} (lane, tile) pairs hold history; a layer and step must read "
+          f"{need / 1e6:.1f} MB", flush=True)
+
+    def ms_a_layer(attend, q, *args) -> float:
+        @jax.jit
+        def chain(q, *args):
+            def layer(q, _):
+                y = attend(q, *args)
+                return q + 1e-3 * y[..., None, :dn + dr], y[:, :, 0]
+            return jax.lax.scan(layer, q, None, length=n_iter)
+        return median_ms(lambda *a: chain(*a)[0], q, *args) / n_iter
+
+    def report(name, ms, slots, carried=0):
+        read = 2 * slots * tile * w * 4 + weights + carried
+        line = (f"mla decode, {h} heads, {name}: {ms:8.3f} ms a layer and step; reads {slots:3d} pairs of "
+                f"{b * tiles} ({slots / (b * tiles):5.1%}), {read / 1e6:7.1f} MB where {need / 1e6:.1f} are needed")
+        if on_chip:
+            line += (f"; of the chip's bytes a second {need / peaks['hbm_bytes_per_s'] / ms * 1e3:5.1%} needed, "
+                     f"{read / peaks['hbm_bytes_per_s'] / ms * 1e3:5.1%} read")
+        print(line, flush=True)
+        return ms
+
+    pool = jax.random.normal(key[3], (1, b * mb + 1, bs, w), jnp.float32).at[..., r + dr:].set(0.0)
+    tables = 1 + jnp.arange(b * mb, dtype=jnp.int32).reshape(b, mb)
+    q = jax.random.normal(key[2], (b, 1, h, dn + dr), jnp.float32)
+    pos = jnp.asarray(base)
+    recent = jax.random.normal(key[4], (b, steps, w), jnp.float32).at[..., r + dr:].set(0.0)
+    fed = pos >= 0
+
+    # the form the programs had: every table whole, the mask over it
+    whole = ops.gather_latent(pool, 0, tables)
+    mask = (jnp.arange(table)[None, None, :] < pos[:, None, None])
+    report("every table whole (attend_absorbed)", ms_a_layer(
+        lambda q, latent, mask, w_kvb, wo: ops.attend_absorbed(q, w_kvb, wo, latent, mask, *dims),
+        q, whole, mask, w_kvb, wo), b * tiles)
+
+    # form (i): the live pairs packed first, a static width of slots
+    held = base.clip(0)
+    live_pair = (np.arange(tiles)[None, :] * tile < held[:, None]).reshape(-1)
+    order = np.argsort(~live_pair, kind="stable")
+    lane, first = order // tiles, order % tiles * tile
+    length = jnp.asarray((held[lane] - first).clip(0, tile))
+    packed_all = whole.reshape(b * tiles, tile, w)[order]
+
+    def packed_core(q_all, slots, lane, length):
+        """The slots' partials brought back to their lanes: the weighted sum ``[B, 1, H, rank]``."""
+        own = lane[None, :] == jnp.arange(b)[:, None]  # [B, n]
+        scores = wdot("nhc,npc->nhp", q_all[lane, 0], slots) * dims[3]
+        scores = jnp.where((jnp.arange(tile)[None, :] < length[:, None])[:, None], scores, -jnp.inf)
+        top = jnp.maximum(jnp.where(own[:, :, None], scores.max(axis=-1)[None], -jnp.inf).max(axis=1), -1e30)
+        p = jnp.exp(scores - top[lane][:, :, None])
+        den = jnp.einsum("bn,nh->bh", own.astype(jnp.float32), p.sum(axis=-1), precision="highest")
+        num = jnp.einsum("bn,nhr->bhr", own.astype(jnp.float32), wdot("nhp,npr->nhr", p, slots[..., :r]),
+                         precision="highest")
+        return (num / jnp.maximum(den, 1e-30)[..., None])[:, None]
+
+    def packed(q, slots, lane, length, w_kvb, wo):
+        q_all, w_kvb = ops._into_latent_space(q, w_kvb, r, dn, dv, w)
+        return ops._out_of_latent_space(packed_core(q_all, slots, lane, length), w_kvb, wo, dn)
+
+    for n in sorted({b * tiles // 4, b * tiles // 2, b * tiles}):
+        if n >= pairs:
+            report(f"form (i), {n} packed slots", ms_a_layer(
+                packed, q, packed_all[:n], jnp.asarray(lane[:n]), length[:n], w_kvb, wo), n)
+
+    # between the two: form (i)'s packed slots, one step-loop text, the widths around the core alone
+    ladder = [int(x) * b * tiles // 512 for x in os.environ.get("PROF_WIDTHS", "0,128,256,512").split(",")]
+
+    def packed_by_rung(q, slots, lane, length, rung, w_kvb, wo):
+        q_all, w_kvb = ops._into_latent_space(q, w_kvb, r, dn, dv, w)
+        out_lat = jax.lax.switch(rung, [
+            (lambda n=n: packed_core(q_all, slots[:n], lane[:n], length[:n]) if n
+             else jnp.zeros((b, 1, h, r), jnp.float32)) for n in ladder])
+        return ops._out_of_latent_space(out_lat, w_kvb, wo, dn)
+
+    rung = int(np.searchsorted(ladder, pairs))
+    report(f"form (i) slots, the widths {ladder} around the core alone, at {ladder[rung]}", ms_a_layer(
+        packed_by_rung, q, packed_all, jnp.asarray(lane), length, jnp.int32(rung), w_kvb, wo), ladder[rung])
+
+    # form (ii): the lanes longest first in blocks, one step-loop text
+    def lively(q, live, recent, fed, w_kvb, wo):  # the dispatch's second step
+        return ops.attend_absorbed_live(q, w_kvb, wo, live, 0, recent, recent[:, 1:2], 1, fed, *dims)[0]
+
+    checked = False
+    for lanes in (int(x) for x in os.environ.get("PROF_LANES", "4,8").split(",")):
+        ops.LANES_AT_ONCE = lanes
+        lb = ops.lanes_at_once(b)
+        gather = jax.jit(lambda pool, tables, pos: ops.live_latents(pool, 1, tables, pos))  # this block's own trace
+        live = gather(pool, tables, pos)
+        read = int(ops.live_history_tiles(base, bs, mb))
+        if not checked:
+            checked = True
+            want = packed(q, packed_all, jnp.asarray(lane), length, w_kvb, wo)
+            got = lively(q, live, recent, fed & False, w_kvb, wo)
+            print(f"mla decode, {h} heads: form (ii) against form (i) at full width, no step folded in: largest "
+                  f"difference {largest(got, want):.2e} of {float(jnp.abs(want).max()):.2f}", flush=True)
+        trips = int(live.trips.sum())
+        report(f"form (ii), blocks of {lb} lanes, {trips} trips (attend_absorbed_live)", ms_a_layer(
+            lively, q, live, recent, fed, w_kvb, wo), read, carried=trips * 3 * lb * h * r * 4)
+        ms = median_ms(gather, pool, tables, pos)
+        print(f"mla decode, once a dispatch: every table of one layer gathered whole, the lanes longest first in "
+              f"blocks of {lb} {ms:8.3f} ms", flush=True)
+    ms = median_ms(jax.jit(ops.gather_latent, static_argnums=1), pool, 0, tables)
+    print(f"mla decode, once a dispatch: every table of one layer gathered whole {ms:8.3f} ms "
+          f"({b * tiles * tile * w * 4 / 1e6:.0f} MB)", flush=True)
+
+
 def mla_profiles():
     """ONE layer's absorbed latent attention of ``openpangu-ultra-moe-718b``
     (``ops/latent.py`` as ``models/openpangu.py`` calls it: 128 heads, a latent
@@ -1018,8 +1180,11 @@ def mla_profiles():
     history, twice: scores and values, ``W_kvb`` and ``W_o``) and what it does
     read (the positions attended; the tiled form also carries its running
     numerator through the trips), the operations it needs (the live keys) and
-    does, and their shares of the chip's peaks. PROF_HEADS (default 128) cuts
-    the heads for a rehearsal on the CPU."""
+    does, and their shares of the chip's peaks. PROF_HEADS (default 128,32: a
+    comma list) are the head counts; the decode step's forms at the cell's
+    occupancy come first, at every head count (:func:`mla_decode_profiles`),
+    and the forms above at the first (not at all where PROF_FORMS is
+    ``decode``)."""
     from benchmark import bytes_and_flops
 
     on_chip = jax.default_backend() == "tpu"
@@ -1029,7 +1194,12 @@ def mla_profiles():
 
     enable_compile_cache()
     n_iter = int(os.environ.get("PROF_ITERS", "8"))
-    h = int(os.environ.get("PROF_HEADS", "128"))
+    heads = [int(x) for x in os.environ.get("PROF_HEADS", "128,32").split(",")]
+    for h in heads:
+        mla_decode_profiles(h, peaks)
+    if os.environ.get("PROF_FORMS") == "decode":
+        return
+    h = heads[0]
     contexts = [int(x) for x in os.environ.get("PROF_CONTEXT", "192,320,640,1024").split(",")]
     tiles = [int(x) for x in os.environ.get("PROF_TILES", "128,256,512").split(",")]
     group = int(os.environ.get("PROF_ROWS", "4"))
